@@ -83,8 +83,9 @@ per-arm tok/s + TTFT p95, the decision counts, and greedy token parity
 (must be 1.0 — K only moves at drain barriers) with zero serving-stage
 XLA compiles.
 
-Platform: probed in a subprocess (a wedged TPU runtime cannot hang the
-bench — round-1 failure mode); BENCH_PLATFORM overrides.
+Platform: what this process finds, or BENCH_PLATFORM (bench.pin_platform
+— a run asked for tpu without one fails). ``mfu`` / ``hbm_roofline_frac``
+are printed only where ``device_kind`` is a v5e, ``null`` elsewhere.
 """
 
 from __future__ import annotations
@@ -103,8 +104,7 @@ from bench import pin_platform  # noqa: E402
 # roofline peaks: single source of truth shared with the engine's live
 # gauges (tpu_local/roofline.py is jax-free, so importing it here cannot
 # pin the platform before pin_platform runs)
-from mcp_context_forge_tpu.tpu_local.roofline import (  # noqa: E402
-    V5E_HBM_GBPS, V5E_PEAK_BF16_TFLOPS)
+from mcp_context_forge_tpu.tpu_local.roofline import v5e_peaks  # noqa: E402
 
 
 def count_params(config) -> int:
@@ -171,10 +171,7 @@ async def run(platform: str, kv_quant: str = "", superstep: int = 0) -> dict:
                           step_sample_every=sample_every,
                           spec_decode=spec, quant=quant, kv_quant=kv_quant,
                           batch_buckets=buckets, moe_impl=moe_impl,
-                          moe_block=moe_block,
-                          compile_cache_dir=os.environ.get(
-                              "MCPFORGE_TPU_LOCAL_COMPILE_CACHE_DIR",
-                              "/tmp/mcpforge-xla-cache"))
+                          moe_block=moe_block)
     if replicas > 1:
         from mcp_context_forge_tpu.tpu_local.pool import EnginePool
 
@@ -277,7 +274,18 @@ async def run(platform: str, kv_quant: str = "", superstep: int = 0) -> dict:
         # warmed engine), and — under BENCH_SAMPLE_EVERY — the last few
         # sampled phase-attribution rows
         eng0 = engine.replicas[0].engine if replicas > 1 else engine
+        import jax
+
+        device = jax.devices()[0]
+        out["device_kind"] = device.device_kind
+        peaks = v5e_peaks(device.device_kind)
         out["live_roofline"] = eng0.roofline_snapshot()
+        if peaks is None:
+            # the engine's gauges divide by v5e peaks whatever runs them
+            # (roofline.py): off a v5e the fractions mean nothing
+            for key in ("mfu", "hbm_roofline_frac"):
+                if key in out["live_roofline"]:
+                    out["live_roofline"][key] = None
         out["xla_compiles"] = {k: v for k, v in eng0.compile_stats().items()
                                if k != "recent"}
         if sample_every:
@@ -304,16 +312,14 @@ async def run(platform: str, kv_quant: str = "", superstep: int = 0) -> dict:
                 } for r in engine.replicas],
             }
         if platform == "tpu":
-            import jax
-
             n_chips = len(jax.devices())  # engine meshes over every chip
             model_config = MODEL_CONFIGS[model]
             n_params = count_params(model_config)
             achieved_tflops = 2 * n_params * tokens_per_s / 1e12
             out["n_params"] = n_params
             out["n_chips"] = n_chips
-            out["mfu"] = round(
-                achieved_tflops / (V5E_PEAK_BF16_TFLOPS * n_chips), 5)
+            out["mfu"] = (round(achieved_tflops / (peaks[0] * n_chips), 5)
+                          if peaks else None)
             # HBM roofline: params stream once per STEP (all slots share the
             # read); KV pages touched scale with resident context
             param_bytes = (1 if quant == "int8" else 2) * n_params
@@ -323,8 +329,9 @@ async def run(platform: str, kv_quant: str = "", superstep: int = 0) -> dict:
             steps_per_s = steps / wall if wall else 0.0
             achieved_gbps = (param_bytes + kv_bytes) * steps_per_s / 1e9
             out["achieved_hbm_gbps"] = round(achieved_gbps, 1)
-            out["hbm_roofline_frac"] = round(
-                achieved_gbps / (V5E_HBM_GBPS * n_chips), 4)
+            out["hbm_roofline_frac"] = (
+                round(achieved_gbps / (peaks[1] * n_chips), 4)
+                if peaks else None)
         return out
     finally:
         await engine.stop()
@@ -372,10 +379,7 @@ async def _run_prefix_tiers_arm(platform: str, tiers: bool) -> dict:
         dtype="bfloat16" if platform == "tpu" else "float32",
         attn_impl="auto", prefix_cache=True, prefix_tiers=tiers,
         tier_host_bytes=64 * 1024 * 1024, tier_disk_bytes=64 * 1024 * 1024,
-        kv_quant=kv_quant,
-        compile_cache_dir=os.environ.get(
-            "MCPFORGE_TPU_LOCAL_COMPILE_CACHE_DIR",
-            "/tmp/mcpforge-xla-cache"))
+        kv_quant=kv_quant)
     engine = TPUEngine(config)
     await engine.start()
     try:
@@ -472,10 +476,7 @@ def _fabric_engine_config(platform: str, page_size: int, tmpl_pages: int,
         dtype="bfloat16" if platform == "tpu" else "float32",
         attn_impl="auto", prefix_cache=True, prefix_tiers=True,
         tier_host_bytes=host_bytes, tier_disk_bytes=0,
-        tier_spill_quant="", tier_object_url=object_url,
-        compile_cache_dir=os.environ.get(
-            "MCPFORGE_TPU_LOCAL_COMPILE_CACHE_DIR",
-            "/tmp/mcpforge-xla-cache"))
+        tier_spill_quant="", tier_object_url=object_url)
 
 
 async def _fabric_prefill_host(platform: str, object_url: str,
@@ -631,10 +632,7 @@ async def _run_controller_arm(platform: str, controlled: bool) -> dict:
         page_size=16, num_pages=1024, prefill_buckets=(64,),
         dtype="bfloat16" if platform == "tpu" else "float32",
         attn_impl="auto", superstep=base_k,
-        k_ladder=ladder if controlled else (),
-        compile_cache_dir=os.environ.get(
-            "MCPFORGE_TPU_LOCAL_COMPILE_CACHE_DIR",
-            "/tmp/mcpforge-xla-cache"))
+        k_ladder=ladder if controlled else ())
     bus = SignalBus()
     engine = TPUEngine(config, signals=bus if controlled else None)
     await engine.start()
@@ -756,10 +754,7 @@ async def _run_disagg_arm(platform: str, roles: str) -> dict:
         prefill_buckets=(page_size, page_size * 8),
         dtype="bfloat16" if platform == "tpu" else "float32",
         attn_impl="auto", prefix_cache=True, prefix_tiers=True,
-        tier_host_bytes=64 * 1024 * 1024, tier_disk_bytes=0,
-        compile_cache_dir=os.environ.get(
-            "MCPFORGE_TPU_LOCAL_COMPILE_CACHE_DIR",
-            "/tmp/mcpforge-xla-cache"))
+        tier_host_bytes=64 * 1024 * 1024, tier_disk_bytes=0)
     pool = EnginePool(config, replicas=2, roles=roles,
                       disagg_prompt_tokens=page_size * 2)
     await pool.start()

@@ -1,31 +1,21 @@
 """CLI entry point (reference: mcpgateway/cli.py uvicorn launcher).
 
-Subcommands: serve (default), token (mint an admin JWT), export/import
-(config snapshot — wired when export_service lands)."""
+Subcommands: serve (default), supervise, token (mint an admin JWT),
+version.
+
+One process per chip: nothing here imports jax. ``serve`` reaches it only
+inside ``build_app`` (engine construction), and ``supervise`` never does —
+its workers are spawned ``serve`` processes, so the parent holds no
+accelerator a worker could then fail to open. The platform is JAX's own
+business (``JAX_PLATFORMS``)."""
 
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 
-def _pin_jax_platform() -> None:
-    """Honor MCPFORGE_JAX_PLATFORM before any backend init.
-
-    Site hooks that force a hardware PJRT plugin can override the plain
-    ``JAX_PLATFORMS`` env var; ``jax.config.update`` wins over both, so an
-    operator can pin ``cpu`` to serve through a dead/absent accelerator
-    runtime (pairs with the engine's init watchdog)."""
-    platform = os.environ.get("MCPFORGE_JAX_PLATFORM")
-    if platform:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
-
-
 def main(argv: list[str] | None = None) -> int:
-    _pin_jax_platform()
     parser = argparse.ArgumentParser(prog="mcpforge",
                                      description="TPU-native MCP gateway")
     sub = parser.add_subparsers(dest="command")

@@ -450,19 +450,16 @@ class Settings(BaseModel):
     tpu_local_decode_overlap: bool = True
     tpu_local_dtype: str = "bfloat16"
     tpu_local_embedding_model: str = "encoder-tiny"
-    # backend-init watchdog: a dead TPU runtime/tunnel can block jax.devices()
-    # forever; past this budget the engine raises EngineInitTimeout so the
-    # gateway fails fast instead of never binding its port (0 = no watchdog)
+    # backend-init watchdog: a wedged accelerator runtime (a chip another
+    # process holds) can block jax.devices() forever; past this budget the
+    # engine raises EngineInitTimeout so the gateway FAILS instead of never
+    # binding its port — it never moves to another platform (0 = no watchdog)
     tpu_local_init_timeout_s: float = 120.0
     # precompile the full shape grid (prefill buckets x pow-2 admission
     # batches + decode block) at boot so first traffic never pays XLA
     # compile latency (~20-40s/shape on TPU); off by default because it
     # lengthens gateway boot
     tpu_local_warmup: bool = False
-    # persistent XLA compilation cache dir ('' = disabled): compiled
-    # executables survive process restarts, so a gateway/bench rerun skips
-    # recompilation entirely
-    tpu_local_compile_cache_dir: str = ""
     # warmup grid scope: 'full' (no mid-traffic compiles ever) or 'fast'
     # (cold-TPU-friendly subset; a rare cache miss pays one compile)
     tpu_local_warmup_mode: Literal["full", "fast"] = "full"

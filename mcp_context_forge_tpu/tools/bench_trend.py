@@ -1,7 +1,7 @@
 """Bench-history trend gate: fail the build when a capture regresses.
 
 The repo checks in one bench JSON per round and family
-(``BENCH_TPU_r05.json``, ``BENCH_r03.json``, ``BENCH_LOCAL_r04.json``,
+(``BENCH_r03.json``, ``BENCH_LOCAL_r04.json``,
 ...). Nothing read them back — a tok/s or roofline regression only
 surfaced when a human diffed the numbers. This CLI turns the history
 into a gate (``make bench-check``, wired into the ``test`` chain and the

@@ -9,8 +9,13 @@ kv-head dim so decode attention never crosses chips.
 Design notes (BASELINE.json north star):
 - prefill: [B, S] bucketed static shapes; causal attention via the Pallas
   flash kernel (ops/attention.py) on TPU, jnp reference elsewhere.
-- decode: fixed-capacity [B, 1] step over the paged cache; pages gathered by
-  block table — fixed shapes, no recompilation per step.
+- decode: fixed-capacity [B, 1] step over the paged cache; pages walked by
+  the Pallas paged kernel on TPU, gathered by block table elsewhere — fixed
+  shapes, no recompilation per step.
+- which implementation runs is the CALLER's choice (``attn_impl`` /
+  ``paged_impl``): the engine resolves it from its mesh's devices through
+  ops/attention.py's ``select_*`` functions. The defaults here are the
+  references, so nothing in this module looks at ``jax.devices()``.
 """
 
 from __future__ import annotations
@@ -59,55 +64,79 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 # ------------------------------------------------------------------------ params
 
-def init_params(config: LlamaConfig, key: jax.Array,
-                dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (1.0 / math.sqrt(fan_in))).astype(dtype)
+def _dense(key: jax.Array, shape: tuple[int, ...], fan_in: int,
+           dtype: jnp.dtype) -> jax.Array:
+    return (jax.random.normal(key, shape, dtype=jnp.float32)
+            * (1.0 / math.sqrt(fan_in))).astype(dtype)
 
-    keys = jax.random.split(key, config.n_layers + 2)
+
+def init_layer(config: LlamaConfig, key: jax.Array,
+               dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
+    """One decoder layer's random weights. Every layer has the same shapes,
+    so a caller that jits this compiles it once (engine._init_params)."""
     hd = config.head_dim
-    layers = []
-    for i in range(config.n_layers):
-        k = jax.random.split(keys[i], 7)
-        layer = {
-            "attn_norm": jnp.ones((config.dim,), dtype=jnp.float32),
-            "wq": dense(k[0], (config.dim, config.n_heads * hd), config.dim),
-            "wk": dense(k[1], (config.dim, config.n_kv_heads * hd), config.dim),
-            "wv": dense(k[2], (config.dim, config.n_kv_heads * hd), config.dim),
-            "wo": dense(k[3], (config.n_heads * hd, config.dim), config.n_heads * hd),
-            "ffn_norm": jnp.ones((config.dim,), dtype=jnp.float32),
-        }
-        if config.n_experts:  # Mixtral: stacked expert FFN + router
-            ek = jax.random.split(k[4], 3)
-            E = config.n_experts
-            layer["router"] = dense(k[5], (config.dim, E), config.dim)
-            layer["w1"] = dense(ek[0], (E, config.dim, config.ffn_hidden),
-                                config.dim)
-            layer["w3"] = dense(ek[1], (E, config.dim, config.ffn_hidden),
-                                config.dim)
-            layer["w2"] = dense(ek[2], (E, config.ffn_hidden, config.dim),
-                                config.ffn_hidden)
-        else:
-            layer["w1"] = dense(k[4], (config.dim, config.ffn_hidden),
-                                config.dim)
-            layer["w3"] = dense(k[5], (config.dim, config.ffn_hidden),
-                                config.dim)
-            layer["w2"] = dense(k[6], (config.ffn_hidden, config.dim),
-                                config.ffn_hidden)
-        if config.attn_bias:  # Qwen2-style q/k/v projection biases
-            layer["bq"] = jnp.zeros((config.n_heads * hd,), dtype=dtype)
-            layer["bk"] = jnp.zeros((config.n_kv_heads * hd,), dtype=dtype)
-            layer["bv"] = jnp.zeros((config.n_kv_heads * hd,), dtype=dtype)
-        layers.append(layer)
-    params = {
-        "embed": dense(keys[-2], (config.vocab_size, config.dim), config.dim),
-        "layers": layers,
+    k = jax.random.split(key, 7)
+    layer = {
+        "attn_norm": jnp.ones((config.dim,), dtype=jnp.float32),
+        "wq": _dense(k[0], (config.dim, config.n_heads * hd), config.dim, dtype),
+        "wk": _dense(k[1], (config.dim, config.n_kv_heads * hd), config.dim, dtype),
+        "wv": _dense(k[2], (config.dim, config.n_kv_heads * hd), config.dim, dtype),
+        "wo": _dense(k[3], (config.n_heads * hd, config.dim),
+                     config.n_heads * hd, dtype),
+        "ffn_norm": jnp.ones((config.dim,), dtype=jnp.float32),
+    }
+    if config.n_experts:  # Mixtral: stacked expert FFN + router
+        ek = jax.random.split(k[4], 3)
+        E = config.n_experts
+        layer["router"] = _dense(k[5], (config.dim, E), config.dim, dtype)
+        layer["w1"] = _dense(ek[0], (E, config.dim, config.ffn_hidden),
+                             config.dim, dtype)
+        layer["w3"] = _dense(ek[1], (E, config.dim, config.ffn_hidden),
+                             config.dim, dtype)
+        layer["w2"] = _dense(ek[2], (E, config.ffn_hidden, config.dim),
+                             config.ffn_hidden, dtype)
+    else:
+        layer["w1"] = _dense(k[4], (config.dim, config.ffn_hidden),
+                             config.dim, dtype)
+        layer["w3"] = _dense(k[5], (config.dim, config.ffn_hidden),
+                             config.dim, dtype)
+        layer["w2"] = _dense(k[6], (config.ffn_hidden, config.dim),
+                             config.ffn_hidden, dtype)
+    if config.attn_bias:  # Qwen2-style q/k/v projection biases
+        layer["bq"] = jnp.zeros((config.n_heads * hd,), dtype=dtype)
+        layer["bk"] = jnp.zeros((config.n_kv_heads * hd,), dtype=dtype)
+        layer["bv"] = jnp.zeros((config.n_kv_heads * hd,), dtype=dtype)
+    return layer
+
+
+def init_trunk(config: LlamaConfig, embed_key: jax.Array,
+               head_key: jax.Array,
+               dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
+    """Everything outside the layer stack: embedding, final norm, lm head."""
+    trunk = {
+        "embed": _dense(embed_key, (config.vocab_size, config.dim),
+                        config.dim, dtype),
         "final_norm": jnp.ones((config.dim,), dtype=jnp.float32),
     }
     if not config.tie_embeddings:
-        params["lm_head"] = dense(keys[-1], (config.dim, config.vocab_size),
-                                  config.dim)
+        trunk["lm_head"] = _dense(head_key, (config.dim, config.vocab_size),
+                                  config.dim, dtype)
+    return trunk
+
+
+def init_keys(config: LlamaConfig, key: jax.Array) -> jax.Array:
+    """[n_layers + 2] keys: one per layer, then the embedding's and the lm
+    head's — the one derivation every init path shares, so a model built
+    layer by layer has the weights of one built whole."""
+    return jax.random.split(key, config.n_layers + 2)
+
+
+def init_params(config: LlamaConfig, key: jax.Array,
+                dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
+    keys = init_keys(config, key)
+    params = init_trunk(config, keys[-2], keys[-1], dtype)
+    params["layers"] = [init_layer(config, keys[i], dtype)
+                        for i in range(config.n_layers)]
     return params
 
 
@@ -195,7 +224,7 @@ def _ffn(layer: dict[str, Any], x: jax.Array,
 
 
 def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
-               x: jax.Array) -> jax.Array:
+               x: jax.Array, mesh=None) -> jax.Array:
     """Dense SwiGLU/GeGLU, or top-k routed MoE when the layer carries a
     router (Mixtral family).
 
@@ -224,16 +253,15 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
                 and T * config.moe_top_k >= config.n_experts * block):
             # block-sparse grouped GEMM: ~top_k/E of the dense-mask
             # FLOPs, exact-parity (ops/grouped_moe.py). The kernel path
-            # interprets off-TPU so the code path exists everywhere.
-            import jax as _jax
-
+            # interprets off-TPU (the caller's mesh says which) so the
+            # code path exists everywhere.
+            from ..ops.attention import on_tpu
             from ..ops.grouped_moe import moe_ffn_grouped
             use_pallas = impl == "grouped_pallas"
             return moe_ffn_grouped(
                 moe_params, x, moe_cfg, act=config.hidden_act,
                 impl="pallas" if use_pallas else "xla", block=block,
-                interpret=(use_pallas
-                           and _jax.default_backend() != "tpu"))
+                interpret=use_pallas and not on_tpu(mesh))
         return moe_ffn_dense_mask(moe_params, x, moe_cfg,
                                   act=config.hidden_act)
     return _ffn(layer, x, config.hidden_act)
@@ -241,13 +269,14 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
 
 def prefill(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
             positions: jax.Array, kv: PagedKVState, slot_ids: jax.Array,
-            attn_impl: str = "auto", mesh=None,
+            attn_impl: str = "reference", mesh=None,
             last_idx: jax.Array | None = None) -> tuple[jax.Array, PagedKVState]:
     """Full-sequence forward writing KV into the paged cache.
 
     tokens/positions: [B, S]; slot_ids: [B] row into the block table.
-    ``attn_impl`` may select the sequence-parallel paths (ring/ulysses)
-    for long-context prefill — requires ``mesh`` (SURVEY.md §5.7).
+    ``attn_impl``: reference | pallas, or the sequence-parallel paths
+    (ring/ulysses) for long-context prefill — those and a TP-sharded
+    pallas call need ``mesh`` (SURVEY.md §5.7).
     ``last_idx`` ([B], optional): project ONLY those positions through the
     lm head, returning [B, vocab] — serving needs one next-token
     distribution per row, and materializing [B, S, vocab] f32 is S x the
@@ -266,7 +295,7 @@ def prefill(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
                                 mesh=mesh)  # [B,S,H,hd]
         x = x + qmm(attn.reshape(*attn.shape[:2], -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], config.norm_eps, config.norm_plus_one)
-        x = x + _ffn_block(layer, config, h)
+        x = x + _ffn_block(layer, config, h, mesh)
     x = rms_norm(x, params["final_norm"], config.norm_eps, config.norm_plus_one)
     if last_idx is not None:
         x = x[jnp.arange(x.shape[0]), last_idx]  # [B, D] before the lm head
@@ -278,7 +307,8 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
                          tokens: jax.Array, positions: jax.Array,
                          kv: PagedKVState, slot_ids: jax.Array,
                          ctx_pages: int | None = None,
-                         last_idx: jax.Array | None = None
+                         last_idx: jax.Array | None = None,
+                         paged_impl: str = "gather", mesh=None
                          ) -> tuple[jax.Array, PagedKVState]:
     """Suffix/chunk prefill attending over cached history (prefix-cache
     path — reference analog: the response_cache_by_prompt plugin caches
@@ -294,6 +324,9 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
     context-width bucket (see gather_kv) — without it a prefix-cache hit
     with 40 resident tokens pays attention over the full table width,
     costing MORE than the dense prefill it was meant to save.
+    ``paged_impl``: "gather" (jnp reference) or "pallas" (the paged chunk
+    kernel, under shard_map over ``mesh``'s model axis when it is wider
+    than one device).
     Returns (logits [B,S,V] fp32, kv)."""
     B, S = tokens.shape
     x = embed_rows(params["embed"], tokens, config.embed_multiplier)
@@ -301,14 +334,13 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
     safe_positions = jnp.maximum(positions, 0)
     G = config.n_heads // config.n_kv_heads
     # Attention is tiled over S (queries only — the chunk's KV is written
-    # first, causality rides absolute positions): the Pallas chunk kernel
-    # keeps (T*G, hd) f32 accumulators + a (T*G, page) score tile in VMEM,
-    # and the gather fallback materializes a [B,KV,G,T,C] f32 score tensor;
-    # untiled, a 2048-token chunk against a long resident context is
-    # multi-GB per layer (round-2 ADVICE medium). T divides S because both
-    # are powers of two.
+    # first, causality rides absolute positions): the gather reference
+    # materializes a [B,KV,G,T,C] f32 score tensor; untiled, a 2048-token
+    # chunk against a long resident context is multi-GB per layer (round-2
+    # ADVICE medium). The Pallas chunk kernel blocks its own rows in VMEM
+    # and takes the same tiles. T divides S because both are powers of two.
     tile = _history_tile(S, G)
-    use_pallas = _use_pallas_paged(config, kv) and tile * G <= 2048
+    use_pallas = paged_impl == "pallas"
     for idx, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], config.norm_eps, config.norm_plus_one)
         q, k, v = _attention_block(layer, config, h, safe_positions)
@@ -328,11 +360,8 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
                 from ..ops.paged_attention import paged_chunk_attention_pallas
                 qg = qs.reshape(B, -1, config.n_kv_heads, G, config.head_dim)
                 at = paged_chunk_attention_pallas(
-                    qg, kv.k_pages[idx], kv.v_pages[idx],
-                    tables, ps,
-                    page_size=kv.page_size,
-                    k_scales=(kv.k_scales[idx] if kv.quantized else None),
-                    v_scales=(kv.v_scales[idx] if kv.quantized else None))
+                    qg, kv.k_pages, kv.v_pages, tables, ps, layer=idx,
+                    k_scales=kv.k_scales, v_scales=kv.v_scales, mesh=mesh)
                 at = at.reshape(B, -1, config.n_heads, config.head_dim)
             else:
                 at = _history_attention(
@@ -342,7 +371,7 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
         attn = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
         x = x + qmm(attn.reshape(B, S, -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], config.norm_eps, config.norm_plus_one)
-        x = x + _ffn_block(layer, config, h)
+        x = x + _ffn_block(layer, config, h, mesh)
     x = rms_norm(x, params["final_norm"], config.norm_eps, config.norm_plus_one)
     if last_idx is not None:  # serving: one next-token row per request
         x = x[jnp.arange(B), last_idx]
@@ -352,10 +381,9 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
 
 def _history_tile(S: int, G: int) -> int:
     """Query-tile width for chunk/history attention: large enough to keep
-    the MXU busy, small enough that T*G fits the Pallas kernel's VMEM
-    budget (and the gather fallback's [B,KV,G,T,C] f32 scores stay
-    bounded). S and the returned tile are powers of two, so the tile
-    always divides S."""
+    the MXU busy, small enough that the gather reference's [B,KV,G,T,C]
+    f32 scores stay bounded. S and the returned tile are powers of two,
+    so the tile always divides S."""
     tile = max(128, 2048 // max(1, G))
     t = 128
     while t * 2 <= min(tile, S):
@@ -388,7 +416,8 @@ def _history_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
 def decode_step(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
                 positions: jax.Array, kv: PagedKVState, slot_ids: jax.Array,
                 seq_lens: jax.Array, ctx_pages: int | None = None,
-                write_mask: jax.Array | None = None
+                write_mask: jax.Array | None = None,
+                paged_impl: str = "gather", mesh=None
                 ) -> tuple[jax.Array, PagedKVState]:
     """One decode step over the paged cache.
 
@@ -399,12 +428,13 @@ def decode_step(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
     engine guarantees every active row fits); write_mask: [B] bool —
     False rows write to the trash page (a slot can be allocated but NOT
     decoding, e.g. mid-chunk-prefill, and must never be written by
-    decode). Returns (logits [B,V], kv).
+    decode); ``paged_impl``/``mesh`` as in :func:`prefill_with_history`.
+    Returns (logits [B,V], kv).
     """
     B = tokens.shape[0]
     x = embed_rows(params["embed"], tokens, config.embed_multiplier)[:, None, :]  # [B,1,D]
     pos = positions[:, None]                 # [B,1]
-    use_pallas = _use_pallas_paged(config, kv)
+    use_pallas = paged_impl == "pallas"
     for idx, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], config.norm_eps, config.norm_plus_one)
         q, k, v = _attention_block(layer, config, h, pos)
@@ -418,33 +448,18 @@ def decode_step(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
             if ctx_pages is not None:
                 tables = tables[:, :ctx_pages]
             attn = paged_decode_attention_pallas(
-                qg, kv.k_pages[idx], kv.v_pages[idx],
-                tables, seq_lens,
-                page_size=kv.page_size,
-                k_scales=(kv.k_scales[idx] if kv.quantized else None),
-                v_scales=(kv.v_scales[idx] if kv.quantized else None))
+                qg, kv.k_pages, kv.v_pages, tables, seq_lens, layer=idx,
+                k_scales=kv.k_scales, v_scales=kv.v_scales, mesh=mesh)
             attn = attn.reshape(B, 1, config.n_heads, config.head_dim)
         else:
             keys, values = gather_kv(kv, idx, slot_ids, ctx_pages)
             attn = _paged_decode_attention(q[:, 0], keys, values, seq_lens, config)
         x = x + qmm(attn.reshape(B, 1, -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], config.norm_eps, config.norm_plus_one)
-        x = x + _ffn_block(layer, config, h)
+        x = x + _ffn_block(layer, config, h, mesh)
     x = rms_norm(x, params["final_norm"], config.norm_eps, config.norm_plus_one)
     logits = lm_logits(params, x[:, 0])
     return logits, kv
-
-
-def _use_pallas_paged(config: LlamaConfig, kv: PagedKVState) -> bool:
-    """Pallas paged kernel on real TPU with tile-friendly shapes; the gather
-    reference elsewhere (CPU CI, odd geometries). Evaluated at trace time.
-    Int8 pools need page_size % 32 == 0 (the int8 sublane tile is 32 vs 8
-    for wider dtypes) — smaller pages fall back to the dequant gather."""
-    from ..ops.attention import _on_tpu
-
-    min_page = 32 if kv.quantized else 8
-    return (_on_tpu() and config.head_dim % 128 == 0
-            and kv.page_size % min_page == 0)
 
 
 def _paged_decode_attention(q: jax.Array, keys: jax.Array, values: jax.Array,

@@ -134,127 +134,143 @@ def _expert_blocks_xla(x_pad: jax.Array, w1, w3, w2,
 
 # ------------------------------------------------------------ Pallas path
 
+# F-tile and scoped-VMEM budget. At mixtral widths (D=4096) one grid step
+# holds three double-buffered [D, f_tile] weight tiles plus the [Bt, D]
+# x/out blocks and the f32 accumulator: f_tile=256 is ~17 MiB, over the
+# compiler's 16 MiB default and far under a v5e core's 128 MiB.
+_F_TILE = 256
+_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+
+
+def _f_tiles(F: int) -> tuple[int, int]:
+    f_tile = min(_F_TILE, F)
+    if F % f_tile:
+        raise ValueError(f"expert hidden {F} must divide the F-tile {f_tile}")
+    return f_tile, F // f_tile
+
+
+def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
 def _moe_block_kernel(block_expert_ref, x_ref, w1_ref, w3_ref, w2_ref,
-                      o_ref, acc_ref, *, act: str, f_tiles: int):
+                      o_ref, acc_ref, *, act: str):
     """One (row-block, F-tile) step: h = act(x@w1_f) * (x@w3_f); the
     [Bt, D] output accumulates h @ w2_f in VMEM scratch across F-tiles.
     The expert's weight tiles arrive via the BlockSpec index maps reading
     the scalar-prefetched ``block_expert`` — the kernel body never
-    gathers."""
+    gathers. Operands go to the MXU in their stored dtype; sums are f32."""
     f = pl.program_id(1)
 
     @pl.when(f == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)                  # [Bt, D]
-    w1 = w1_ref[0].astype(jnp.float32)                # [D, Ft]
-    w3 = w3_ref[0].astype(jnp.float32)
-    w2 = w2_ref[0].astype(jnp.float32)                # [Ft, D]
-    h = _act(x @ w1, act) * (x @ w3)                  # [Bt, Ft]
-    acc_ref[...] += h @ w2                            # [Bt, D]
+    x = x_ref[...]                                    # [Bt, D]
+    h = _act(_dot(x, w1_ref[...]), act) * _dot(x, w3_ref[...])   # [Bt, Ft]
+    acc_ref[...] += _dot(h.astype(x.dtype), w2_ref[...])         # [Bt, D]
 
-    @pl.when(f == f_tiles - 1)
+    @pl.when(f == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("act", "block", "f_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("act", "block", "interpret"))
 def _expert_blocks_pallas(x_pad: jax.Array, w1: jax.Array, w3: jax.Array,
                           w2: jax.Array, block_expert: jax.Array,
                           act: str = "silu", block: int = 128,
-                          f_tile: int = 512,
                           interpret: bool = False) -> jax.Array:
     NB = block_expert.shape[0]
     D = x_pad.shape[-1]
-    F = w1.shape[-1]
-    f_tile = min(f_tile, F)
-    assert F % f_tile == 0, (F, f_tile)
-    f_tiles = F // f_tile
+    f_tile, f_tiles = _f_tiles(w1.shape[-1])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                 # block_expert
         grid=(NB, f_tiles),
         in_specs=[
-            pl.BlockSpec((1, block, D), lambda b, f, be: (b, 0, 0)),
-            pl.BlockSpec((1, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((1, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((1, f_tile, D), lambda b, f, be: (be[b], f, 0)),
+            pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
+            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, f_tile, D), lambda b, f, be: (be[b], f, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block, D), lambda b, f, be: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_moe_block_kernel, act=act, f_tiles=f_tiles),
+        functools.partial(_moe_block_kernel, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NB, block, D), x_pad.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        name="grouped_moe",
         interpret=interpret,
     )(block_expert, x_pad, w1, w3, w2)
 
 
 def _moe_block_kernel_q8(block_expert_ref, x_ref, q1_ref, s1_ref, q3_ref,
                          s3_ref, q2_ref, s2_ref, o_ref, acc_ref, *,
-                         act: str, f_tiles: int):
+                         act: str):
     """Int8 expert stacks: HBM reads stay int8-sized (the decode
-    bottleneck quantization exists to halve); scales apply per F-tile on
-    the hidden and once on the output (s2 factors out of the F sum)."""
+    bottleneck quantization exists to halve); scales ([1, Ft] / [1, D]
+    rows) apply per F-tile on the hidden and once on the output (s2
+    factors out of the F sum)."""
     f = pl.program_id(1)
 
     @pl.when(f == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)                    # [Bt, D]
-    q1 = q1_ref[0].astype(jnp.float32)                  # [D, Ft]
-    q3 = q3_ref[0].astype(jnp.float32)
-    q2 = q2_ref[0].astype(jnp.float32)                  # [Ft, D]
-    s1 = s1_ref[0].astype(jnp.float32)                  # [Ft]
-    s3 = s3_ref[0].astype(jnp.float32)
-    h = _act((x @ q1) * s1[None, :], act) * ((x @ q3) * s3[None, :])
-    acc_ref[...] += h @ q2
+    x = x_ref[...]                                      # [Bt, D]
+    s1 = s1_ref[...].astype(jnp.float32)                # [1, Ft]
+    s3 = s3_ref[...].astype(jnp.float32)
+    h = (_act(_dot(x, q1_ref[...].astype(x.dtype)) * s1, act)
+         * (_dot(x, q3_ref[...].astype(x.dtype)) * s3))
+    acc_ref[...] += _dot(h.astype(x.dtype), q2_ref[...].astype(x.dtype))
 
-    @pl.when(f == f_tiles - 1)
+    @pl.when(f == pl.num_programs(1) - 1)
     def _finish():
-        s2 = s2_ref[0].astype(jnp.float32)              # [D]
-        o_ref[0] = (acc_ref[...] * s2[None, :]).astype(o_ref.dtype)
+        s2 = s2_ref[...].astype(jnp.float32)            # [1, D]
+        o_ref[...] = (acc_ref[...] * s2).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("act", "block", "f_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("act", "block", "interpret"))
 def _expert_blocks_pallas_q8(x_pad, w1, w3, w2, block_expert,
                              act: str = "silu", block: int = 128,
-                             f_tile: int = 512,
                              interpret: bool = False) -> jax.Array:
     NB = block_expert.shape[0]
     D = x_pad.shape[-1]
-    F = w1["q"].shape[-1]
-    f_tile = min(f_tile, F)
-    assert F % f_tile == 0, (F, f_tile)
-    f_tiles = F // f_tile
+    f_tile, f_tiles = _f_tiles(w1["q"].shape[-1])
+
+    def scale_rows(s):
+        # [E, C] -> [E, 1, C]: a (1, tile) block is legal only where 1 is
+        # the array's own dim
+        return s[:, None, :]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(NB, f_tiles),
         in_specs=[
-            pl.BlockSpec((1, block, D), lambda b, f, be: (b, 0, 0)),
-            pl.BlockSpec((1, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((1, f_tile), lambda b, f, be: (be[b], f)),
-            pl.BlockSpec((1, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((1, f_tile), lambda b, f, be: (be[b], f)),
-            pl.BlockSpec((1, f_tile, D), lambda b, f, be: (be[b], f, 0)),
-            pl.BlockSpec((1, D), lambda b, f, be: (be[b], 0)),
+            pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
+            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, 1, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, 1, f_tile), lambda b, f, be: (be[b], 0, f)),
+            pl.BlockSpec((None, f_tile, D), lambda b, f, be: (be[b], f, 0)),
+            pl.BlockSpec((None, 1, D), lambda b, f, be: (be[b], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block, D), lambda b, f, be: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_moe_block_kernel_q8, act=act, f_tiles=f_tiles),
+        functools.partial(_moe_block_kernel_q8, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NB, block, D), x_pad.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        name="grouped_moe_q8",
         interpret=interpret,
-    )(block_expert, x_pad, w1["q"], w1["s"], w3["q"], w3["s"],
-      w2["q"], w2["s"])
+    )(block_expert, x_pad, w1["q"], scale_rows(w1["s"]), w3["q"],
+      scale_rows(w3["s"]), w2["q"], scale_rows(w2["s"]))
 
 
 # ----------------------------------------------------------- public entry

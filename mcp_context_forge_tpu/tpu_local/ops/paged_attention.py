@@ -1,21 +1,32 @@
-"""Paged decode attention (Pallas).
+"""Paged attention over the KV pool (Pallas).
 
-Replaces the gather-based decode attention (`models/llama.py:
-_paged_decode_attention` + `kv/paged_cache.py:gather_kv`) on TPU: instead of
-materializing each slot's whole context ([B, C, KV, hd] per layer) in HBM,
-the kernel walks the block table page-by-page — the page index is scalar-
-prefetched so Pallas can DMA exactly the pages a sequence uses from HBM into
-VMEM — maintaining online-softmax stats in VMEM scratch. HBM traffic drops
-from O(B·C_max·hd) copies to the pages actually referenced.
+Replaces the gather-based attention (`models/llama.py:
+_paged_decode_attention` / `_history_attention` + `kv/paged_cache.py:
+gather_kv`) on TPU: instead of materializing each slot's whole context
+([B, C, KV, hd] per layer) in HBM, the kernel walks the block table
+page-by-page — the page index is scalar-prefetched so Pallas can DMA
+exactly the pages a sequence uses from HBM into VMEM — maintaining
+online-softmax stats in VMEM scratch. HBM traffic drops from
+O(B·C_max·hd) copies to the pages actually referenced.
+
+One kernel body serves both entry points: decode is the chunk kernel with
+one query position per sequence (``seq_len - 1``).
+
+The kernel takes the WHOLE pool ``[L, num_pages, page, KV, hd]`` plus a
+static layer index that rides the BlockSpec index map: a per-layer slice
+outside the kernel would make XLA copy that layer's pool before every call.
+A K/V block is one page with ALL its kv heads (the TPU lowering wants the
+last two block dims to equal the array's), and the head loop runs inside
+the kernel.
 
 Int8 pages (kv/paged_cache.py quant mode) dequantize IN VMEM: the
-per-(page, kv-head) scales ride the same scalar-prefetch-indexed DMA path
-as the pages themselves (BlockSpec indexed by the block table), so the HBM
-side of decode attention moves 1 byte/element instead of 2 and the
-dequant multiply fuses into the f32 score math the kernel already does.
+per-(page, kv-head) scales arrive in blocks of ``_SCALE_ROWS`` pages
+indexed by the same block-table entry, and apply to the f32 scores and
+the f32 P·V product (one scale per (page, head), so it factors out of
+both matmuls) — the HBM side of attention moves 1 byte/element.
 
-Grid: (batch, kv_head, page). Scalar prefetch: block tables [B, P] and
-seq_lens [B]. Output: [B, KV, G, hd] attention for the single decode token.
+Grid: (batch, query-row block, page). Scalar prefetch: block tables
+[B, P] and the highest query position per (batch, row block).
 """
 
 from __future__ import annotations
@@ -27,18 +38,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from .attention import on_model_axis
 
 NEG_INF = -1e30
+# pages per scale block: a multiple of every dtype's sublane tile (f32 8,
+# bf16 16), so the [rows, KV] block is legal whatever the scale dtype
+_SCALE_ROWS = 32
+# query rows (positions x group) one grid step holds: bounds the f32
+# accumulator scratch at KV * _ROW_BLOCK * hd * 4 bytes
+_ROW_BLOCK = 256
 
 
-def _kernel(block_tables_ref, seq_lens_ref, q_ref, k_ref, v_ref, *rest,
-            page_size: int, num_pages_per_seq: int, quantized: bool):
+def _kernel(tables_ref, max_pos_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+            page_size: int, quantized: bool):
+    """Refs: pos [R, 1] int32 (absolute position of each query row, -1 =
+    padding); q/o [KV, R, hd]; k/v [page, KV, hd]; scales
+    [_SCALE_ROWS, KV]; scratch acc [KV, R, hd], m/l [KV, R, 1] f32."""
     if quantized:
         k_scale_ref, v_scale_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    page_idx = pl.program_id(2)
+    b, r, page_idx = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_kv, n_rows, hd = q_ref.shape
 
     @pl.when(page_idx == 0)
     def _init():
@@ -46,196 +69,169 @@ def _kernel(block_tables_ref, seq_lens_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    seq_len = seq_lens_ref[b]
     page_start = page_idx * page_size
-    # tokens this page actually holds for the sequence
-    valid_in_page = seq_len - page_start
 
-    @pl.when(valid_in_page > 0)
+    # the page holds live context iff some query position reaches it
+    @pl.when(max_pos_ref[b, r] >= page_start)
     def _process():
-        q = q_ref[0, 0].astype(jnp.float32)           # [G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)        # [page, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)        # [page, hd]
-        if quantized:  # fused dequant: one scalar per (page, head) tile
-            k = k * k_scale_ref[0, 0].astype(jnp.float32)
-            v = v * v_scale_ref[0, 0].astype(jnp.float32)
-        hd = q.shape[-1]
-        scores = (q @ k.T) / math.sqrt(hd)            # [G, page]
-        position = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores = jnp.where(position < valid_in_page, scores, NEG_INF)
-        m_prev = m_ref[...]                           # [G, 1]
-        l_prev = l_ref[...]
-        m_tile = jnp.max(scores, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_tile)
-        correction = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        l_new = l_prev * correction + jnp.sum(probs, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * correction + probs @ v
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-
-    @pl.when(page_idx == num_pages_per_seq - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _chunk_kernel(block_tables_ref, q_pos_ref, q_ref, k_ref, v_ref, *rest,
-                  page_size: int, num_pages_per_seq: int, quantized: bool):
-    """Chunk (multi-query) variant of _kernel: S queries per sequence walk
-    the same page list with online softmax; causality rides the absolute
-    query positions (cache position c attends iff c <= q_pos). Serves the
-    prefix-cache suffix prefill and the spec-decode verify step."""
-    if quantized:
-        k_scale_ref, v_scale_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    page_idx = pl.program_id(2)
-
-    @pl.when(page_idx == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    pos = q_pos_ref[0]                                # [S] (-1 = padding row)
-    page_start = page_idx * page_size
-    # the page holds live context iff any query position reaches it
-    @pl.when(jnp.max(pos) + 1 - page_start > 0)
-    def _process():
-        q = q_ref[0, :, 0].astype(jnp.float32)        # [S, G, hd]
-        S, G, hd = q.shape
-        q2 = q.reshape(S * G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # [page, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        col = page_start + jax.lax.broadcasted_iota(
+            jnp.int32, (n_rows, page_size), 1)
+        live = col <= pos_ref[...]                    # causal, on position
         if quantized:
-            k = k * k_scale_ref[0, 0].astype(jnp.float32)
-            v = v * v_scale_ref[0, 0].astype(jnp.float32)
-        scores = (q2 @ k.T) / math.sqrt(hd)           # [S*G, page]
-        col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) + page_start
-        row_pos = jnp.broadcast_to(pos[:, None], (S, G)).reshape(S * G, 1)
-        scores = jnp.where(col <= row_pos, scores, NEG_INF)
-        m_prev = m_ref[...]                           # [S*G, 1]
-        l_prev = l_ref[...]
-        m_tile = jnp.max(scores, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_tile)
-        correction = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(scores - m_new)
-        l_new = l_prev * correction + jnp.sum(probs, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * correction + probs @ v
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+            # this page's row of the scale block, as a masked reduce: a
+            # dynamic sublane slice of a packed 16-bit tile does not lower
+            row = tables_ref[b, page_idx] % _SCALE_ROWS
+            pick = jax.lax.broadcasted_iota(
+                jnp.int32, k_scale_ref.shape, 0) == row
 
-    @pl.when(page_idx == num_pages_per_seq - 1)
+            def page_scale(ref):
+                return jnp.sum(jnp.where(pick, ref[...].astype(jnp.float32),
+                                         0.0), axis=0, keepdims=True)
+            k_scale, v_scale = page_scale(k_scale_ref), page_scale(v_scale_ref)
+        for h in range(n_kv):
+            q = q_ref[h]                              # [R, hd]
+            k = k_ref[:, h, :].astype(q.dtype)        # [page, hd]
+            v = v_ref[:, h, :].astype(q.dtype)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / math.sqrt(hd)
+            if quantized:
+                scores = scores * k_scale[:, h:h + 1]
+            scores = jnp.where(live, scores, NEG_INF)     # [R, page]
+            m_prev = m_ref[h]                             # [R, 1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(scores, axis=1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            probs = jnp.exp(scores - m_new)
+            l_ref[h] = (l_ref[h] * correction
+                        + jnp.sum(probs, axis=1, keepdims=True))
+            pv = jnp.dot(probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+            if quantized:
+                pv = pv * v_scale[:, h:h + 1]
+            acc_ref[h] = acc_ref[h] * correction + pv
+            m_ref[h] = m_new
+
+    @pl.when(page_idx == pl.num_programs(2) - 1)
     def _finish():
-        S = q_pos_ref.shape[1]
-        G, hd = o_ref.shape[3], o_ref.shape[4]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = out.reshape(S, G, hd).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] /
+                      jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _scale_spec(n_index: int):
-    """BlockSpec for a [num_pages, KV] scale array: one (1, 1) scalar tile
-    per grid step, DMA'd from the SAME block-table-indexed page the K/V
-    specs fetch. ``n_index``: arity of the index_map (grid dims + scalar
-    prefetch refs)."""
-    if n_index == 5:  # decode grid: (b, k, j) + (bt, sl)
-        return pl.BlockSpec((1, 1), lambda b, k, j, bt, sl: (bt[b, j], k))
-    return pl.BlockSpec((1, 1), lambda b, k, j, bt: (bt[b, j], k))
-
-
-@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
-def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
-                                 q_positions, page_size: int,
-                                 interpret: bool = False,
-                                 k_scales=None, v_scales=None):
-    """q: [B, S, KV, G, hd]; k_pages/v_pages: [num_pages, page, KV, hd];
-    block_tables: [B, P] int32; q_positions: [B, S] int32 absolute
-    positions (-1 = padding); k_scales/v_scales: [num_pages, KV] dequant
-    scales for int8 pages (None = full-precision pages)
-    -> [B, S, KV, G, hd]."""
-    B, S, KV, G, hd = q.shape
-    P = block_tables.shape[1]
+def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
+                     k_scales, v_scales, interpret):
+    """q: [B, KV, R, hd] (R query rows per kv head); row_pos: [B, R] int32
+    absolute position of each row (-1 = padding) -> [B, KV, R, hd]."""
+    B, KV, R, hd = q.shape
+    n_pages = block_tables.shape[1]
+    page_size = k_pages.shape[2]
     quantized = k_scales is not None
+    rows = min(R, _ROW_BLOCK)
+    if R % rows:
+        raise ValueError(f"query rows {R} must divide into blocks of {rows}")
+    n_blocks = R // rows
 
-    grid = (B, KV, P)
-    kernel = functools.partial(_chunk_kernel, page_size=page_size,
-                               num_pages_per_seq=P, quantized=quantized)
+    def page_map(b, r, j, tables, max_pos):
+        return (layer, tables[b, j], 0, 0, 0)
+
+    def row_map(b, r, j, tables, max_pos):
+        return (b, 0, r, 0)
+
     in_specs = [
-        pl.BlockSpec((1, S), lambda b, k, j, bt: (b, 0)),
-        pl.BlockSpec((1, S, 1, G, hd),
-                     lambda b, k, j, bt: (b, 0, k, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, k, j, bt: (bt[b, j], 0, k, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, k, j, bt: (bt[b, j], 0, k, 0)),
+        pl.BlockSpec((None, rows, 1), lambda b, r, j, t, m: (b, r, 0)),
+        pl.BlockSpec((None, KV, rows, hd), row_map),
+        pl.BlockSpec((None, None, page_size, KV, hd), page_map),
+        pl.BlockSpec((None, None, page_size, KV, hd), page_map),
     ]
-    inputs = [q_positions, q, k_pages, v_pages]
+    inputs = [row_pos[:, :, None], q, k_pages, v_pages]
     if quantized:
-        in_specs += [_scale_spec(4), _scale_spec(4)]
+        scale_spec = pl.BlockSpec(
+            (None, _SCALE_ROWS, KV),
+            lambda b, r, j, t, m: (layer, t[b, j] // _SCALE_ROWS, 0))
+        in_specs += [scale_spec, scale_spec]
         inputs += [k_scales, v_scales]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, S, 1, G, hd),
-                                   lambda b, k, j, bt: (b, 0, k, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((S * G, hd), jnp.float32),
-                pltpu.VMEM((S * G, 1), jnp.float32),
-                pltpu.VMEM((S * G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, G, hd), q.dtype),
-        interpret=interpret,
-    )(block_tables, *inputs)
-    return out
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("page_size", "interpret"))
-def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                                  page_size: int, interpret: bool = False,
-                                  k_scales=None, v_scales=None):
-    """q: [B, KV, G, hd]; k_pages/v_pages: [num_pages, page, KV, hd];
-    block_tables: [B, P] int32; seq_lens: [B] int32; k_scales/v_scales:
-    [num_pages, KV] dequant scales for int8 pages (None = full precision)
-    -> [B, KV, G, hd]."""
-    B, KV, G, hd = q.shape
-    P = block_tables.shape[1]
-    quantized = k_scales is not None
-
-    grid = (B, KV, P)
-    kernel = functools.partial(_kernel, page_size=page_size,
-                               num_pages_per_seq=P, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, k, j, bt, sl: (b, k, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, k, j, bt, sl: (bt[b, j], 0, k, 0)),
-        pl.BlockSpec((1, page_size, 1, hd),
-                     lambda b, k, j, bt, sl: (bt[b, j], 0, k, 0)),
-    ]
-    inputs = [q, k_pages, v_pages]
-    if quantized:
-        in_specs += [_scale_spec(5), _scale_spec(5)]
-        inputs += [k_scales, v_scales]
-    out = pl.pallas_call(
-        kernel,
+    max_pos = jnp.max(row_pos.reshape(B, n_blocks, rows), axis=2)
+    return pl.pallas_call(
+        functools.partial(_kernel, page_size=page_size, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B, n_blocks, n_pages),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, hd),
-                                   lambda b, k, j, bt, sl: (b, k, 0, 0)),
+            out_specs=pl.BlockSpec((None, KV, rows, hd), row_map),
             scratch_shapes=[
-                pltpu.VMEM((G, hd), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, hd), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
+                pltpu.VMEM((KV, rows, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
+        name="paged_attention",
         interpret=interpret,
-    )(block_tables, seq_lens, *inputs)
-    return out
+    )(block_tables, max_pos, *inputs)
+
+
+_POOL_SPEC = P(None, None, None, "model", None)   # kv_pages, per shard
+
+
+def _shard_specs(q_spec: P, quantized: bool) -> tuple:
+    """shard_map in_specs for (q, k_pages, v_pages, block_tables,
+    positions-or-lens, k_scales, v_scales): everything with a kv-head dim
+    splits over ``model`` like the pool (parallel/sharding.py)."""
+    scales = P(None, None, "model") if quantized else None
+    return (q_spec, _POOL_SPEC, _POOL_SPEC, P(), P(), scales, scales)
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh"))
+def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
+                                 q_positions, layer: int = 0,
+                                 interpret: bool = False,
+                                 k_scales=None, v_scales=None, mesh=None):
+    """Chunk (multi-query) attention: S queries per sequence walk the page
+    list; causality rides the absolute query positions (cache position c
+    attends iff c <= q_pos). Serves the prefix-cache suffix prefill, chunked
+    prefill and the spec-decode verify step.
+
+    q: [B, S, KV, G, hd]; k_pages/v_pages: [L, num_pages, page, KV, hd];
+    block_tables: [B, P] int32; q_positions: [B, S] int32 absolute
+    positions (-1 = padding); k_scales/v_scales: [L, num_pages, KV] dequant
+    scales for int8 pages (None = full-precision pages); ``mesh``: the
+    engine's mesh when the pool is sharded over its ``model`` axis
+    -> [B, S, KV, G, hd]."""
+    def per_shard(q, k_pages, v_pages, block_tables, q_positions,
+                  k_scales, v_scales):
+        B, S, KV, G, hd = q.shape
+        # head-major rows: the kernel reads one [S*G, hd] matrix per kv head
+        rows = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, S * G, hd)
+        out = _paged_attention(rows, jnp.repeat(q_positions, G, axis=1),
+                               k_pages, v_pages, block_tables, layer,
+                               k_scales, v_scales, interpret)
+        return out.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)
+
+    q_spec = P(None, None, "model", None, None)
+    return on_model_axis(
+        per_shard, mesh, _shard_specs(q_spec, k_scales is not None), q_spec)(
+            q, k_pages, v_pages, block_tables, q_positions, k_scales, v_scales)
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh"))
+def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
+                                  layer: int = 0, interpret: bool = False,
+                                  k_scales=None, v_scales=None, mesh=None):
+    """One query token per sequence, attending its first ``seq_len`` cache
+    positions (0 = inactive row, output zeros).
+
+    q: [B, KV, G, hd]; k_pages/v_pages: [L, num_pages, page, KV, hd];
+    block_tables: [B, P] int32; seq_lens: [B] int32; k_scales/v_scales:
+    [L, num_pages, KV] (None = full precision); ``mesh`` as in
+    :func:`paged_chunk_attention_pallas` -> [B, KV, G, hd]."""
+    def per_shard(q, k_pages, v_pages, block_tables, seq_lens,
+                  k_scales, v_scales):
+        row_pos = jnp.broadcast_to(seq_lens[:, None] - 1,
+                                   (q.shape[0], q.shape[2]))
+        return _paged_attention(q, row_pos, k_pages, v_pages, block_tables,
+                                layer, k_scales, v_scales, interpret)
+
+    q_spec = P(None, "model", None, None)
+    return on_model_axis(
+        per_shard, mesh, _shard_specs(q_spec, k_scales is not None), q_spec)(
+            q, k_pages, v_pages, block_tables, seq_lens, k_scales, v_scales)

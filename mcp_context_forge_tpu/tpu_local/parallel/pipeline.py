@@ -134,7 +134,6 @@ def build_pp_forward(mesh: Mesh, config: LlamaConfig, n_stages: int,
       embed → pipelined layers (M microbatches) → final norm + head.
     B must divide by ``microbatches``.
     """
-    from jax.experimental.shard_map import shard_map
 
     stage_spec = P(axis_name)      # leading stage axis
     replicated = P()
@@ -154,12 +153,12 @@ def build_pp_forward(mesh: Mesh, config: LlamaConfig, n_stages: int,
     layer_names = ("attn_norm", "wq", "wk", "wv", "wo", "ffn_norm",
                    "w1", "w3", "w2") + (
         ("bq", "bk", "bv") if config.attn_bias else ())
-    body = shard_map(
+    body = jax.shard_map(
         partial(_pipeline_body, config=config, axis_name=axis_name),
         mesh=mesh,
         in_specs=({name: stage_spec for name in layer_names},
                   replicated, replicated),
-        out_specs=replicated, check_rep=False)
+        out_specs=replicated, check_vma=False)
 
     def forward(stacked: dict[str, Any], tokens: jax.Array,
                 positions: jax.Array) -> jax.Array:

@@ -122,7 +122,6 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "model", causal: bool = Tru
     key = ("ring", mesh, axis_name, causal)
     if key in _MAKER_CACHE:
         return _MAKER_CACHE[key]
-    from jax.experimental.shard_map import shard_map
 
     spec = P(None, axis_name, None, None)  # [B, S, H, hd] sharded on S
     valid_spec = P(None, axis_name)
@@ -131,9 +130,9 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "model", causal: bool = Tru
         return ring_attention_sharded(q, k, v, axis_name=axis_name,
                                       causal=causal, k_valid=valid)
 
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=(spec, spec, spec, valid_spec),
-                        out_specs=spec, check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(spec, spec, spec, valid_spec),
+                            out_specs=spec, check_vma=False)
     _MAKER_CACHE[key] = jax.jit(sharded)
     return _MAKER_CACHE[key]
 
@@ -185,7 +184,6 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = "model",
     key = ("ulysses", mesh, axis_name, causal)
     if key in _MAKER_CACHE:
         return _MAKER_CACHE[key]
-    from jax.experimental.shard_map import shard_map
 
     spec = P(None, axis_name, None, None)
     valid_spec = P(None, axis_name)
@@ -194,8 +192,8 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = "model",
         return ulysses_attention_sharded(q, k, v, axis_name=axis_name,
                                          causal=causal, k_valid=valid)
 
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=(spec, spec, spec, valid_spec),
-                        out_specs=spec, check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(spec, spec, spec, valid_spec),
+                            out_specs=spec, check_vma=False)
     _MAKER_CACHE[key] = jax.jit(sharded)
     return _MAKER_CACHE[key]

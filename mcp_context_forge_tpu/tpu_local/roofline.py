@@ -10,9 +10,11 @@ executable's cost by its measured wall to feed the
 ``mcpforge_llm_mfu`` / ``mcpforge_llm_hbm_roofline_frac`` gauges.
 
 The peaks are per-chip and configurable (``EngineConfig.peak_tflops_per_
-chip`` / ``hbm_gbps_per_chip``); defaults are TPU v5e. On CPU backends
-the fractions are meaningless against TPU peaks but harmless — the A/B
-signal (did a change move the fraction) survives any constant.
+chip`` / ``hbm_gbps_per_chip``); defaults are TPU v5e, whatever device
+runs — so the live gauges mean something only on a v5e (ROADMAP Queue 3
+item 1 replaces the two settings with a table keyed by ``device_kind``).
+Anything that PRINTS a fraction of a peak asks :func:`v5e_peaks` first and
+prints ``null`` for any other device.
 
 Pure stdlib on purpose: imported by ``bench_engine.py`` before the jax
 platform is pinned, so it must not import jax at module scope.
@@ -23,33 +25,44 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any
 
-# TPU v5e, per chip (also the single source for bench_engine.py)
+# TPU v5e, per chip (Google Cloud documentation, "TPU v5e"; also the
+# single source for bench_engine.py)
 V5E_PEAK_BF16_TFLOPS = 197.0
 V5E_HBM_GBPS = 819.0
+V5E_DEVICE_KIND = "TPU v5 lite"   # what jax reports as device_kind
+
+
+def v5e_peaks(device_kind: str) -> tuple[float, float] | None:
+    """(peak bf16 TFLOP/s, HBM GB/s) per chip where ``device_kind`` is a
+    v5e, None for every other device — a caller that gets None prints no
+    fraction of a peak."""
+    if device_kind == V5E_DEVICE_KIND:
+        return V5E_PEAK_BF16_TFLOPS, V5E_HBM_GBPS
+    return None
 
 
 @dataclass(frozen=True)
 class CostEntry:
     """One executable's XLA cost model: total FLOPs and HBM bytes touched
-    per dispatch (the whole batch, not per row)."""
+    per dispatch (the whole batch, not per row), plus how many Pallas
+    kernel calls (``tpu_custom_call``) its compiled text holds — 0 says
+    the step runs the XLA reference paths."""
 
     flops: float
     bytes_accessed: float
+    kernel_calls: int = 0
 
 
-def normalize_cost_analysis(analysis: Any) -> CostEntry | None:
-    """``Compiled.cost_analysis()`` returns a dict on recent jax and a
-    one-element list of dicts on older versions; extract the two numbers
-    the roofline needs, or None when the backend has no cost model."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
-    if not isinstance(analysis, dict):
-        return None
+def normalize_cost_analysis(analysis: dict[str, Any],
+                            kernel_calls: int = 0) -> CostEntry | None:
+    """The numbers the roofline needs out of ``Compiled.cost_analysis()``,
+    or None when XLA priced the executable at nothing."""
     flops = float(analysis.get("flops", 0.0) or 0.0)
     byts = float(analysis.get("bytes accessed", 0.0) or 0.0)
     if flops <= 0.0 and byts <= 0.0:
         return None
-    return CostEntry(flops=flops, bytes_accessed=byts)
+    return CostEntry(flops=flops, bytes_accessed=byts,
+                     kernel_calls=kernel_calls)
 
 
 def roofline_fractions(flops: float, bytes_accessed: float, dur_s: float,
@@ -81,13 +94,12 @@ class CostRegistry:
                 *args: Any) -> CostEntry | None:
         """Record ``fn``'s XLA cost at this shape (``fn`` is a jitted
         callable; ``args`` the exact example arguments warmup dispatches).
-        Swallows every failure: a backend without a cost model must not
-        break warmup."""
-        try:
-            analysis = fn.lower(*args).compile().cost_analysis()
-        except Exception:
-            return None
-        entry = normalize_cost_analysis(analysis)
+        A shape the compiler refuses raises here, as the warming call
+        right after it would."""
+        compiled = fn.lower(*args).compile()
+        entry = normalize_cost_analysis(
+            compiled.cost_analysis(),
+            kernel_calls=compiled.as_text().count("tpu_custom_call"))
         if entry is not None:
             self._entries.setdefault(kind, {})[(width, ctx)] = entry
         return entry
@@ -115,7 +127,8 @@ class CostRegistry:
         """Serializable registry view for /admin/engine/steps + bench."""
         return {
             kind: {f"{w}x{c}": {"flops": entry.flops,
-                                "bytes_accessed": entry.bytes_accessed}
+                                "bytes_accessed": entry.bytes_accessed,
+                                "kernel_calls": entry.kernel_calls}
                    for (w, c), entry in sorted(table.items())}
             for kind, table in sorted(self._entries.items())
         }

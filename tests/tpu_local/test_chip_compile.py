@@ -1,0 +1,199 @@
+"""What the chip's compiler says, asked without a chip.
+
+The TPU compiler is installed here and compiles for a device that is
+DESCRIBED, not attached (``jax.experimental.topologies``): every Pallas kernel
+in ``tpu_local/ops`` at real widths (Llama-3-8B / Mistral-7B head geometry,
+Mixtral-8x7B expert widths), about two seconds each. Interpret-mode tests
+cannot see what these see — block shapes the lowering refuses, primitives
+Mosaic lacks, scoped-VMEM overruns. A compile that passes is not a chip run:
+results and times come from ``chip_smoke.py`` on the chip.
+
+Plus the CPU rehearsal of ``chip_smoke.py``'s phase functions at tiny sizes,
+and the check that the script refuses to run without a TPU.
+"""
+
+import asyncio
+import json
+import os
+import subprocess
+import sys
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mcp_context_forge_tpu.tpu_local.ops import attention, grouped_moe
+from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+KV, G, HD, PAGE = 8, 4, 128, 128          # mistral-7b / llama3-8b heads
+LAYERS, PAGES = 32, 384
+E, D, F, BLOCK = 8, 4096, 14336, 128      # mixtral-8x7b experts
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip as a sharding for abstract arguments."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _paged_case(kv_dtype, chunk: int | None, batch: int, table: int,
+                kv_heads: int = KV):
+    """(fn, shapes) for the paged kernel: decode when ``chunk`` is None,
+    else ``chunk`` queries per row."""
+    pool = ((LAYERS, PAGES, PAGE, kv_heads, HD), kv_dtype)
+    shapes = [pool, pool, ((batch, table), jnp.int32)]
+    if chunk is None:
+        fn = paged.paged_decode_attention_pallas
+        shapes = [((batch, kv_heads, G, HD), jnp.bfloat16)] + shapes + [
+            ((batch,), jnp.int32)]
+    else:
+        fn = paged.paged_chunk_attention_pallas
+        shapes = [((batch, chunk, kv_heads, G, HD), jnp.bfloat16)] + shapes + [
+            ((batch, chunk), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        scale = ((LAYERS, PAGES, kv_heads), jnp.bfloat16)
+        return (lambda *a: fn(*a[:5], layer=7, k_scales=a[5], v_scales=a[6]),
+                shapes + [scale, scale])
+    return partial(fn, layer=7), shapes
+
+
+def _flash_case(seq: int):
+    q = ((2, seq, KV * G, HD), jnp.bfloat16)
+    kv = ((2, seq, KV, HD), jnp.bfloat16)
+    return attention.flash_attention_pallas, [q, kv, kv, ((2, seq), jnp.bool_)]
+
+
+def _moe_case(quantized: bool):
+    n_blocks = 2048 * 2 // BLOCK + E
+    x = ((n_blocks, BLOCK, D), jnp.bfloat16)
+    owner = ((n_blocks,), jnp.int32)
+    if not quantized:
+        up, down = ((E, D, F), jnp.bfloat16), ((E, F, D), jnp.bfloat16)
+        return (partial(grouped_moe._expert_blocks_pallas, block=BLOCK),
+                [x, up, up, down, owner])
+    up = [((E, D, F), jnp.int8), ((E, F), jnp.bfloat16)]
+    down = [((E, F, D), jnp.int8), ((E, D), jnp.bfloat16)]
+
+    def fn(x, q1, s1, q3, s3, q2, s2, owner):
+        return grouped_moe._expert_blocks_pallas_q8(
+            x, {"q": q1, "s": s1}, {"q": q3, "s": s3}, {"q": q2, "s": s2},
+            owner, block=BLOCK)
+    return fn, [x, *up, *up, *down, owner]
+
+
+# _history_tile(S, G=4) yields query tiles of 128..512 (and spec-verify
+# chunks of spec_k=4); kv_heads=2 is one shard of a 1x4 TP mesh
+KERNEL_CASES = {
+    "paged_decode_bf16": lambda: _paged_case(jnp.bfloat16, None, 16, 8),
+    "paged_decode_int8": lambda: _paged_case(jnp.int8, None, 16, 8),
+    "paged_decode_bf16_tp_shard": lambda: _paged_case(
+        jnp.bfloat16, None, 16, 8, kv_heads=2),
+    "paged_chunk_bf16_tile128": lambda: _paged_case(jnp.bfloat16, 128, 4, 8),
+    "paged_chunk_bf16_tile512": lambda: _paged_case(jnp.bfloat16, 512, 2, 16),
+    "paged_chunk_bf16_spec4": lambda: _paged_case(jnp.bfloat16, 4, 16, 8),
+    "paged_chunk_int8_tile128": lambda: _paged_case(jnp.int8, 128, 4, 8),
+    "flash_prefill_s512": lambda: _flash_case(512),
+    "flash_prefill_s2048": lambda: _flash_case(2048),
+    "grouped_moe_bf16": lambda: _moe_case(False),
+    "grouped_moe_int8": lambda: _moe_case(True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    fn, shapes = KERNEL_CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    # the persistent cache is off in this suite (conftest); a compile for
+    # a described device could be written to one but never read back
+    assert not jax.config.jax_enable_compilation_cache
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- chip_smoke.py, rehearsed
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke as module
+    finally:
+        sys.path.remove(REPO)
+    return module
+
+
+def test_chip_smoke_kernel_parity_rehearsal(chip_smoke):
+    facts = chip_smoke.phase_kernel_parity("llama3-test", page_size=16,
+                                           interpret=True)
+    assert set(facts["kernels"]) == {"paged_decode_bf16", "paged_decode_int8",
+                                     "paged_chunk_bf16", "flash_prefill"}
+
+
+def test_chip_smoke_gateway_rehearsal(chip_smoke, capsys, monkeypatch):
+    """The whole one-chip gateway phase — build as ``cli serve`` does, bind a
+    socket, chat / stream / burst / embeddings / moderations, every check —
+    on the CPU at llama3-test / encoder-tiny."""
+    env = {**chip_smoke.GATEWAY_ENV, **chip_smoke.ENGINE_ENV,
+           "MCPFORGE_TPU_LOCAL_MODEL": "llama3-test",
+           "MCPFORGE_TPU_LOCAL_DTYPE": "float32",
+           "MCPFORGE_TPU_LOCAL_EMBEDDING_MODEL": "encoder-tiny",
+           "MCPFORGE_TPU_LOCAL_NUM_PAGES": "96"}
+    for key in env:     # phase_gateway writes os.environ, as cli serve reads it
+        monkeypatch.setenv(key, os.environ.get(key, ""))
+    from mcp_context_forge_tpu.config import reset_settings_cache
+    try:
+        asyncio.run(chip_smoke.phase_gateway(
+            env, chip_smoke.CompileMeter(), expect_kernels=False))
+    finally:
+        monkeypatch.undo()
+        reset_settings_cache()
+    phases = [json.loads(line)["phase"]
+              for line in capsys.readouterr().out.splitlines()
+              if line.startswith("{")]
+    assert phases == ["build", "health", "chat", "greedy_determinism",
+                      "chat_stream", "prefix_primer", "burst", "logits",
+                      "embeddings", "moderations", "serving_compiles"]
+
+
+def test_chip_smoke_four_chip_rehearsal(chip_smoke, capsys):
+    """``--chips 4``'s two phases on four of the suite's virtual CPU
+    devices: a TP engine against a one-device engine, then one replica per
+    device."""
+    meter = chip_smoke.CompileMeter()
+    devices = jax.devices()[:4]
+    asyncio.run(chip_smoke.phase_tp_engine(
+        meter, model="llama3-test", expect_kernels=False, devices=devices))
+    asyncio.run(chip_smoke.phase_replica_pool(
+        meter, model="llama3-test", devices=devices))
+    facts = {json.loads(line)["phase"]: json.loads(line)
+             for line in capsys.readouterr().out.splitlines()
+             if line.startswith("{")}
+    assert set(facts) == {"tp_engine", "tp_vs_one_device", "replica_pool"}
+    assert facts["tp_engine"]["mesh"] == {"data": 1, "model": 4}
+    assert facts["replica_pool"]["replica_devices"] == [[0], [1], [2], [3]]
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """Under JAX_PLATFORMS=cpu the script fails at once — non-zero, and
+    ``"ok": false`` on its last line — and never continues on the CPU."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                          env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False and last["device"]["platform"] == "cpu"
+    assert len(proc.stdout.strip().splitlines()) == 1   # no phase ever ran

@@ -273,7 +273,7 @@ def test_wedged_replica_detected_and_failed_over():
                     fn = real(ctx_pages, batch)
 
                     def wrapper(*args, **kwargs):
-                        release.wait(30)  # simulated dead device tunnel
+                        release.wait(30)  # simulated wedged device call
                         return fn(*args, **kwargs)
                     return wrapper
                 return blocking
@@ -307,7 +307,7 @@ def test_wedge_verdict_matrix():
     in an XLA compile longer than the heartbeat bar, and killing a
     compiling replica cascades onto an equally unwarmed survivor. A
     WARMED replica with a stale heartbeat and in-flight work is a wedge
-    even before its first step — without that arm a tunnel that dies
+    even before its first step — without that arm a device that dies
     between warmup and the first request hangs its requests forever
     (step_age never becomes non-None on a replica that cannot retire a
     step)."""

@@ -4,6 +4,7 @@ single full-sequence forward (the classic incremental-decoding invariant)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mcp_context_forge_tpu.tpu_local.kv import PageAllocator, init_kv_state
 from mcp_context_forge_tpu.tpu_local.models import MODEL_CONFIGS
@@ -96,14 +97,15 @@ def test_padding_does_not_leak_between_slots():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_flash_attention_matches_reference():
+@pytest.mark.parametrize("KV", [4, 2])  # MHA, and GQA through the index map
+def test_flash_attention_matches_reference(KV):
     from mcp_context_forge_tpu.tpu_local.ops.attention import (
         attention_reference, flash_attention_pallas)
     B, S, H, hd = 2, 64, 4, 32
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (B, S, H, hd), dtype=jnp.float32)
-    k = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, hd), dtype=jnp.float32)
-    v = jax.random.normal(jax.random.PRNGKey(2), (B, S, H, hd), dtype=jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, hd), dtype=jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, hd), dtype=jnp.float32)
     valid = jnp.ones((B, S), dtype=bool).at[1, 50:].set(False)
     ref = attention_reference(q, k, v, valid)
     out = flash_attention_pallas(q, k, v, valid, block_q=32, block_k=32,

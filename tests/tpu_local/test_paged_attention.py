@@ -48,11 +48,9 @@ def _check_against_gather(CFG, page_size, num_pages, slots, per_slot, seq_lens,
     ref = jnp.einsum("bkgc,bckh->bkgh", probs, values_g)
 
     out = paged_decode_attention_pallas(
-        q, kv.k_pages[0], kv.v_pages[0], kv.block_tables,
-        jnp.asarray(seq_lens, dtype=jnp.int32), page_size=page_size,
-        interpret=True,
-        k_scales=kv.k_scales[0] if quant else None,
-        v_scales=kv.v_scales[0] if quant else None)
+        q, kv.k_pages, kv.v_pages, kv.block_tables,
+        jnp.asarray(seq_lens, dtype=jnp.int32), layer=0, interpret=True,
+        k_scales=kv.k_scales, v_scales=kv.v_scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -144,10 +142,9 @@ def test_paged_chunk_matches_history_reference(quant):
 
     qg = q.reshape(slots, S, KV, G, hd)
     out = paged_chunk_attention_pallas(
-        qg, kv.k_pages[0], kv.v_pages[0], kv.block_tables, positions,
-        page_size=page_size, interpret=True,
-        k_scales=kv.k_scales[0] if quant else None,
-        v_scales=kv.v_scales[0] if quant else None)
+        qg, kv.k_pages, kv.v_pages, kv.block_tables, positions,
+        layer=0, interpret=True,
+        k_scales=kv.k_scales, v_scales=kv.v_scales)
     out = out.reshape(slots, S, KV * G, hd)
     # compare only valid rows (padding rows are garbage in both paths)
     for slot in range(slots):
@@ -155,3 +152,52 @@ def test_paged_chunk_matches_history_reference(quant):
         np.testing.assert_allclose(np.asarray(out[slot, :n]),
                                    np.asarray(ref[slot, :n]),
                                    rtol=2e-5, atol=2e-5)
+
+
+def test_kernels_per_model_shard_match_unsharded():
+    """On a TP mesh the kernels run under shard_map over ``model`` — each
+    shard on the kv heads it holds (ops/attention.on_model_axis) — and must
+    give what one unsharded call gives. Layer 1 of 2, so the layer index
+    that rides the BlockSpec index map is exercised too."""
+    from functools import partial
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mcp_context_forge_tpu.tpu_local.ops.attention import (
+        flash_attention_pallas, on_model_axis)
+    from mcp_context_forge_tpu.tpu_local.ops.paged_attention import (
+        paged_chunk_attention_pallas)
+    from mcp_context_forge_tpu.tpu_local.parallel import make_mesh
+
+    mesh = make_mesh("1x4", devices=jax.devices()[:4])
+    L, N, page, KV, G, hd, B, per_slot, S = 2, 9, 8, 4, 2, 16, 2, 4, 8
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 8))
+    pool_sharding = NamedSharding(mesh, P(None, None, None, "model", None))
+    k_pages, v_pages = (jax.device_put(
+        jax.random.normal(next(keys), (L, N, page, KV, hd)), pool_sharding)
+        for _ in range(2))
+    tables = 1 + jnp.arange(B * per_slot, dtype=jnp.int32).reshape(B, per_slot)
+    seq_lens = jnp.asarray([per_slot * page, 11], jnp.int32)
+    q = jax.random.normal(next(keys), (B, KV, G, hd))
+    decode = partial(paged_decode_attention_pallas, q, k_pages, v_pages,
+                     tables, seq_lens, layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(decode(mesh=mesh)),
+                               np.asarray(decode()), rtol=1e-6, atol=1e-6)
+
+    positions = jnp.stack([16 + jnp.arange(S), jnp.arange(S)]).astype(jnp.int32)
+    qc = jax.random.normal(next(keys), (B, S, KV, G, hd))
+    chunk = partial(paged_chunk_attention_pallas, qc, k_pages, v_pages,
+                    tables, positions, layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(chunk(mesh=mesh)),
+                               np.asarray(chunk()), rtol=1e-6, atol=1e-6)
+
+    heads = P(None, None, "model", None)
+    qf = jax.random.normal(next(keys), (B, 16, KV * G, hd))
+    kf, vf = (jax.random.normal(next(keys), (B, 16, KV, hd)) for _ in range(2))
+    valid = jnp.ones((B, 16), bool).at[1, 12:].set(False)
+    flash = partial(flash_attention_pallas, block_q=8, block_k=8,
+                    interpret=True)
+    sharded = on_model_axis(flash, mesh, (heads, heads, heads, P()), heads)
+    np.testing.assert_allclose(np.asarray(sharded(qf, kf, vf, valid)),
+                               np.asarray(flash(qf, kf, vf, valid)),
+                               rtol=1e-6, atol=1e-6)
