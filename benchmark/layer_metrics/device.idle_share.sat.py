@@ -1,0 +1,2 @@
+"""Device: share of the traced window in which no operation ran (saturated cells)."""
+from benchmark.harness.layers import idle_share as read  # noqa: F401
