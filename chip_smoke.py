@@ -551,7 +551,8 @@ async def _drive(http, app, meter: CompileMeter, new_tokens: int) -> None:
 
     started, before, tokens0 = (time.monotonic(), meter.snapshot(),
                                 stats.completion_tokens)
-    hits0, seq0 = engine.allocator.prefix_hit_tokens, engine._step_seq
+    hits0 = engine.allocator.prefix_hit_tokens
+    seq0 = max((s["seq"] for s in engine.recent_steps()), default=0)
     burst = [shared(i) for i in range(1, 9)] + [
         [{"role": "user", "content": f"Unshared short chat number {i}."}]
         for i in range(4)]

@@ -236,8 +236,10 @@ class PrometheusRegistry:
         )
         self.llm_device_idle_frac = Gauge(
             "mcpforge_llm_device_idle_fraction",
-            "Fraction of recent decode wall time the device waited on host "
-            "bookkeeping (0..1; ~0 with the overlapped pipeline)",
+            "Host dispatch-gap share of decode wall: host-clock gaps before "
+            "host-fed decode dispatches / (gaps + dispatch-to-retire wall), "
+            "0..1. Not the device's idle time: that comes from a profiler "
+            "trace",
             ["replica"], registry=self.registry,
         )
         # decode-step phase attribution (opt-in sampling via

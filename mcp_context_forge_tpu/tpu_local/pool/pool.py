@@ -537,6 +537,7 @@ class EnginePool:
             stop_ids=request.stop_ids,
             priority=request.priority,
             created=request.created,
+            t_submit=request.t_submit,
             # billing identity must ride EVERY shadow, including requeued
             # continuations — a failover must not turn a tenant's tail
             # tokens into unattributed work (token-conservation gate)
