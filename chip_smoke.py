@@ -10,7 +10,9 @@ probe child, no watchdog that turns into a CPU run. Then, with no arguments:
 
 1. on-chip parity of every Pallas kernel on the serving path (paged decode
    bf16 and int8, paged chunk, flash prefill) against its ``jax.numpy``
-   reference at the model's head geometry;
+   reference at the model's head geometry, and the paged kernel's time
+   alone at the benchmark cells' three shapes (``timing``: microseconds a
+   call and a live page);
 2. the gateway app built in-process exactly as ``cli serve`` builds it
    (``get_settings`` -> ``install_event_loop`` -> ``build_app``), bound to
    a real socket on 127.0.0.1 and driven by an HTTP client: ``/health``,
@@ -310,7 +312,101 @@ def phase_kernel_parity(model: str = MODEL, page_size: int = 128,
               f"(atol {PARITY_ATOL}, rtol {PARITY_RTOL})")
     return {"geometry": {"kv_heads": KV, "group": G, "head_dim": hd,
                          "page_size": page_size},
-            "atol": PARITY_ATOL, "rtol": PARITY_RTOL, "kernels": results}
+            "atol": PARITY_ATOL, "rtol": PARITY_RTOL, "kernels": results,
+            "timing": time_paged_kernel(KV, G, hd, page_size, interpret)}
+
+
+# the benchmark cells' kernel shapes: (slots, block-table width, queries per
+# row or None for decode, live rows, live context range in tokens)
+TIMED_SHAPES = {
+    "decode_32x8": (32, 8, None, 12, (64, 712)),       # mistral-7b.chat
+    "decode_8x32": (8, 32, None, 6, (1024, 4048)),     # docs-closed decode
+    "chunk_2x512x32": (2, 32, 512, 2, (1024, 4000)),   # docs-closed chunk tile
+}
+
+
+def time_paged_kernel(KV: int, G: int, hd: int, page_size: int,
+                      interpret: bool = False) -> dict[str, Any]:
+    """The paged kernel ALONE at ``TIMED_SHAPES``, bf16 pages: microseconds
+    a call and a live page. 32 kernel calls (a layer each) run in series
+    in one jitted program, as in a step; the host clock around it ends in
+    ``block_until_ready``, the fastest of 10 counts. Contexts are drawn
+    log-uniform; idle slots and block-table entries past a row's pages are
+    0 like the engine's, so their grid steps fetch nothing. Under
+    ``interpret`` (the CPU rehearsal) the shapes shrink and the numbers say
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mcp_context_forge_tpu.tpu_local.ops.paged_attention import (
+        _ROW_BLOCK, paged_chunk_attention_pallas,
+        paged_decode_attention_pallas)
+
+    L = 4
+    calls, reps = (2, 1) if interpret else (32, 10)
+    rng = np.random.default_rng(25)
+    key = jax.random.PRNGKey(25)
+    out = {}
+    for name, (B, table, chunk, live_rows, (lo, hi)) in TIMED_SHAPES.items():
+        if interpret:
+            B, table, chunk = min(B, 2), 4, chunk and 2 * page_size
+            live_rows, lo, hi = min(live_rows, B), page_size, table * page_size
+        n_pages = 1 + B * table
+        pool = (L, n_pages, page_size, KV, hd)
+        k_pages, v_pages = (jax.random.normal(
+            jax.random.fold_in(key, i), pool, jnp.float32).astype(jnp.bfloat16)
+            for i in range(2))
+        lens = np.zeros(B, int)
+        lens[rng.choice(B, live_rows, replace=False)] = np.minimum(
+            np.exp(rng.uniform(np.log(lo), np.log(hi), live_rows)).astype(int),
+            table * page_size)
+        held = -(-lens // page_size)                    # pages a row holds
+        tables = 1 + np.arange(B * table).reshape(B, table)
+        tables = jnp.asarray(
+            np.where(np.arange(table)[None] < held[:, None], tables, 0),
+            jnp.int32)
+        if chunk is None:
+            q = jax.random.normal(key, (B, KV, G, hd), jnp.float32)
+            extra = jnp.asarray(lens, jnp.int32)
+            kernel, live_pages, row_blocks = (
+                paged_decode_attention_pallas, int(held.sum()), 1)
+        else:
+            # a tile of ``chunk`` queries somewhere inside each prompt
+            start = np.array([rng.integers(0, max(1, n // chunk)) * chunk
+                              for n in lens])
+            pos = start[:, None] + np.arange(chunk)[None]
+            pos = np.where(pos < lens[:, None], pos, -1)
+            q = jax.random.normal(key, (B, chunk, KV, G, hd), jnp.float32)
+            extra = jnp.asarray(pos, jnp.int32)
+            rows = min(chunk * G, _ROW_BLOCK)
+            top = np.repeat(pos, G, axis=1).reshape(B, -1, rows).max(axis=2)
+            kernel, live_pages, row_blocks = (
+                paged_chunk_attention_pallas,
+                int(np.where(top >= 0, top // page_size + 1, 0).sum()),
+                top.shape[1])
+        q = q.astype(jnp.bfloat16)
+
+        @jax.jit
+        def series(q, k_pages, v_pages):
+            for i in range(calls):
+                res = kernel(q, k_pages, v_pages, tables, extra,
+                             layer=i % L, interpret=interpret)
+                q = q + (res * 1e-3).astype(q.dtype)    # in series
+            return q
+
+        series(q, k_pages, v_pages).block_until_ready()
+        seconds = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            series(q, k_pages, v_pages).block_until_ready()
+            seconds.append(time.perf_counter() - t0)
+        us = min(seconds) / calls * 1e6
+        out[name] = {"us_per_call": round(us, 2),
+                     "us_per_live_page": round(us / max(1, live_pages), 3),
+                     "live_pages": live_pages,
+                     "grid_steps": B * row_blocks * table}
+    return out
 
 
 # --------------------------------------------------------------------- helpers

@@ -10,6 +10,15 @@ every step.
 
 Page 0 is reserved as the trash page: masked/padding writes land there.
 
+The layout is token-major on purpose: ``(KV, hd)`` are the two minor dims,
+so one token's kv heads are one contiguous tile and a token write (decode,
+prefill scatter) is one whole-tile update. Head-major pages
+(``[.., KV, page, hd]``) would make a token 8 half-word row updates: on a
+v5e a decode write of 32 tokens took 197 us a layer against 32 us (my chip
+run, PR 25, PERF.md). The paged kernel pays nothing for this order: it
+reads one head of a page as sublane-strided word loads
+(``ops/paged_attention._heads``).
+
 Int8 storage mode (``quant="int8"``): pages hold int8 values plus a
 per-page, per-kv-head scale array [L, num_pages, KV], halving the pool's
 HBM footprint and the per-step KV traffic. Scales are RUNNING MAXIMA over

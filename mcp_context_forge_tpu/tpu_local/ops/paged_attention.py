@@ -19,6 +19,18 @@ A K/V block is one page with ALL its kv heads (the TPU lowering wants the
 last two block dims to equal the array's), and the head loop runs inside
 the kernel.
 
+Reading ONE head out of that block is the kernel's inner cost. In the
+pool's layout the kv head is the sublane dim (a token's heads are one
+tile), so ``k_ref[:, h, :]`` is one sublane out of each of ``page`` tiles:
+Mosaic realises it as ~18,000 load / unpack / rotate / select / pack
+instructions a page (bf16, 8 heads). ``_heads`` reads the same bytes
+as sublane-STRIDED loads of 32-bit words instead — the page viewed as
+``[page * KV / packing, hd]`` words, every ``KV / packing``-th row — and
+splits a word into its 2 (bf16) or 4 (int8) heads with shifts: a tenth of
+the instructions, and the page step is bound by its DMA (PERF.md, PR 25).
+The pool's layout, its writers, its sharding and the page payload that
+leaves the device are what they were.
+
 Int8 pages (kv/paged_cache.py quant mode) dequantize IN VMEM: the
 per-(page, kv-head) scales arrive in blocks of ``_SCALE_ROWS`` pages
 indexed by the same block-table entry, and apply to the f32 scores and
@@ -51,10 +63,41 @@ _SCALE_ROWS = 32
 _ROW_BLOCK = 256
 
 
+def _heads(ref, out_dtype):
+    """Yield ``[page, hd]`` of each kv head in turn, as ``out_dtype``, from
+    a page block ``ref`` [1, 1, page, KV, hd].
+
+    Heads that fill whole 32-bit words (f32; bf16 with an even head count;
+    int8 with a multiple of 4) are read as strided word loads, see the
+    module docstring. Mosaic has no strided load of narrower types, so any
+    other geometry takes the per-token sublane gather."""
+    _, _, page, n_kv, hd = ref.shape
+    packing = 4 // ref.dtype.itemsize
+    if ref.dtype not in (jnp.float32, jnp.bfloat16, jnp.int8) \
+            or n_kv % packing:
+        for h in range(n_kv):
+            yield ref[0, 0, :, h, :].astype(out_dtype)
+        return
+    words = ref if packing == 1 else ref.bitcast(jnp.int32)
+    stride = n_kv // packing            # words a token
+    words = words.reshape(page * stride, hd)
+    bits = 32 // packing
+    for word in range(stride):
+        x = words[pl.ds(word, page, stride=stride), :]
+        for sub in range(packing):
+            if packing == 1:
+                head = x
+            elif ref.dtype == jnp.bfloat16:  # the high half of an f32
+                head = pltpu.bitcast((x >> (bits * sub)) << bits, jnp.float32)
+            else:                            # int8: sign-extend byte ``sub``
+                head = (x << (32 - bits * (sub + 1))) >> (32 - bits)
+            yield head.astype(out_dtype)
+
+
 def _kernel(tables_ref, max_pos_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             page_size: int, quantized: bool):
     """Refs: pos [R, 1] int32 (absolute position of each query row, -1 =
-    padding); q/o [KV, R, hd]; k/v [page, KV, hd]; scales
+    padding); q/o [KV, R, hd]; k/v [1, 1, page, KV, hd]; scales
     [_SCALE_ROWS, KV]; scratch acc [KV, R, hd], m/l [KV, R, 1] f32."""
     if quantized:
         k_scale_ref, v_scale_ref, o_ref, acc_ref, m_ref, l_ref = rest
@@ -88,10 +131,9 @@ def _kernel(tables_ref, max_pos_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                 return jnp.sum(jnp.where(pick, ref[...].astype(jnp.float32),
                                          0.0), axis=0, keepdims=True)
             k_scale, v_scale = page_scale(k_scale_ref), page_scale(v_scale_ref)
-        for h in range(n_kv):
-            q = q_ref[h]                              # [R, hd]
-            k = k_ref[:, h, :].astype(q.dtype)        # [page, hd]
-            v = v_ref[:, h, :].astype(q.dtype)
+        for h, (k, v) in enumerate(zip(_heads(k_ref, q_ref.dtype),
+                                       _heads(v_ref, q_ref.dtype))):
+            q = q_ref[h]                              # [R, hd]; k, v [page, hd]
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) / math.sqrt(hd)
@@ -140,8 +182,9 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
     in_specs = [
         pl.BlockSpec((None, rows, 1), lambda b, r, j, t, m: (b, r, 0)),
         pl.BlockSpec((None, KV, rows, hd), row_map),
-        pl.BlockSpec((None, None, page_size, KV, hd), page_map),
-        pl.BlockSpec((None, None, page_size, KV, hd), page_map),
+        # not squeezed: a ref with squeezed dims cannot be bitcast
+        pl.BlockSpec((1, 1, page_size, KV, hd), page_map),
+        pl.BlockSpec((1, 1, page_size, KV, hd), page_map),
     ]
     inputs = [row_pos[:, :, None], q, k_pages, v_pages]
     if quantized:

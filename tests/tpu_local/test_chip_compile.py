@@ -105,6 +105,12 @@ KERNEL_CASES = {
     "paged_chunk_bf16_tile512": lambda: _paged_case(jnp.bfloat16, 512, 2, 16),
     "paged_chunk_bf16_spec4": lambda: _paged_case(jnp.bfloat16, 4, 16, 8),
     "paged_chunk_int8_tile128": lambda: _paged_case(jnp.int8, 128, 4, 8),
+    # the benchmark cells' own shapes: mistral-7b.chat (32 slots, 8-page
+    # bucket), mistral-7b.docs-closed decode steps and chunk rounds
+    "paged_decode_bf16_cell_32x8": lambda: _paged_case(jnp.bfloat16, None, 32, 8),
+    "paged_decode_bf16_cell_8x32": lambda: _paged_case(jnp.bfloat16, None, 8, 32),
+    "paged_chunk_bf16_cell_tile512x32": lambda: _paged_case(
+        jnp.bfloat16, 512, 2, 32),
     "flash_prefill_s512": lambda: _flash_case(512),
     "flash_prefill_s2048": lambda: _flash_case(2048),
     "grouped_moe_bf16": lambda: _moe_case(False),
@@ -141,6 +147,11 @@ def test_chip_smoke_kernel_parity_rehearsal(chip_smoke):
                                            interpret=True)
     assert set(facts["kernels"]) == {"paged_decode_bf16", "paged_decode_int8",
                                      "paged_chunk_bf16", "flash_prefill"}
+    # the kernel alone at the cells' shapes (tiny here: fields, not times)
+    assert set(facts["timing"]) == set(chip_smoke.TIMED_SHAPES)
+    for timed in facts["timing"].values():
+        assert timed["us_per_call"] > 0 and timed["us_per_live_page"] > 0
+        assert 0 < timed["live_pages"] <= timed["grid_steps"]
 
 
 def test_chip_smoke_gateway_rehearsal(chip_smoke, capsys, monkeypatch):

@@ -187,17 +187,63 @@ def test_tier_hits_conserve_against_prefix_hit_tokens():
 
 def _engine(tiers: bool, *, num_pages=5, kv_quant="", prefix_cache=True,
             host_bytes=1 << 20, disk_bytes=1 << 20, disk_dir="",
-            spill_quant=""):
+            spill_quant="", dtype="float32"):
     # spill_quant="" (resident-precision spill) is the LOSSLESS mode the
     # byte-identical gates run under; the "int8" default's bounded drift
     # has its own test below
     return TPUEngine(EngineConfig(
         model="llama3-test", max_batch=2, max_seq_len=128, page_size=PS,
-        num_pages=num_pages, prefill_buckets=(16, 64), dtype="float32",
+        num_pages=num_pages, prefill_buckets=(16, 64), dtype=dtype,
         attn_impl="reference", prefix_cache=prefix_cache,
         prefix_tiers=tiers, tier_host_bytes=host_bytes,
         tier_disk_bytes=disk_bytes, tier_disk_dir=disk_dir,
         kv_quant=kv_quant, tier_spill_quant=spill_quant))
+
+
+@pytest.mark.parametrize("kv_quant,dtype", [("int8", "float32"),
+                                            ("", "bfloat16")])
+def test_tier_page_payload_is_token_major_and_round_trips(kv_quant, dtype):
+    """The page payload that leaves the device is ``[L, page, KV, hd]``
+    (what kv/tiers.py, the fabric and anything already on disk hold), and
+    read -> write -> read returns the same bytes (int8 pool: verbatim
+    values and scales) or values (bf16 pool, resident-precision spill)."""
+    import jax.numpy as jnp
+
+    engine = _engine(True, kv_quant=kv_quant, dtype=dtype)
+    cfg = engine.model_config
+    shape = (cfg.n_layers, PS, cfg.n_kv_heads, cfg.head_dim)
+    rng = np.random.default_rng(7)
+    pool_dtype = engine.kv.k_pages.dtype
+    if kv_quant == "int8":
+        pages = [rng.integers(-127, 128, shape).astype(np.int8)
+                 for _ in range(2)]
+        scales = [rng.uniform(0.01, 0.02, (cfg.n_layers, cfg.n_kv_heads))
+                  .astype(np.float32) for _ in range(2)]
+        engine.kv = engine.kv._replace(
+            k_scales=engine.kv.k_scales.at[:, 1].set(scales[0]),
+            v_scales=engine.kv.v_scales.at[:, 1].set(scales[1]))
+    else:
+        pages = [np.asarray(jnp.asarray(rng.standard_normal(shape),
+                                        pool_dtype).astype(jnp.float32))
+                 for _ in range(2)]
+    # page 1 of the pool holds token t, head h of layer l at [l, 1, t, h]
+    engine.kv = engine.kv._replace(
+        k_pages=engine.kv.k_pages.at[:, 1].set(jnp.asarray(pages[0], pool_dtype)),
+        v_pages=engine.kv.v_pages.at[:, 1].set(jnp.asarray(pages[1], pool_dtype)))
+    first = engine._read_page_payload(1)
+    assert first.k.shape == first.v.shape == shape
+    np.testing.assert_array_equal(first.k, pages[0])
+    np.testing.assert_array_equal(first.v, pages[1])
+    if kv_quant == "int8":
+        assert first.k.dtype == np.int8
+        np.testing.assert_array_equal(first.k_scales, scales[0])
+        np.testing.assert_array_equal(first.v_scales, scales[1])
+    engine._upload_page(3, first)
+    again = engine._read_page_payload(3)
+    for name in ("k", "v", "k_scales", "v_scales"):
+        a, b = getattr(first, name), getattr(again, name)
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
 
 
 async def _gen(engine, ids, n=6):

@@ -12,9 +12,9 @@ from mcp_context_forge_tpu.tpu_local.ops.paged_attention import (
 
 
 def _check_against_gather(CFG, page_size, num_pages, slots, per_slot, seq_lens,
-                          quant=""):
+                          quant="", dtype=jnp.float32):
     kv = init_kv_state(CFG, num_pages, page_size, slots, per_slot,
-                       dtype=jnp.float32, quant=quant)
+                       dtype=dtype, quant=quant)
     alloc = PageAllocator(num_pages, page_size, slots, per_slot)
     for slot, n in enumerate(seq_lens):
         assert alloc.allocate_slot(slot, n)
@@ -28,8 +28,8 @@ def _check_against_gather(CFG, page_size, num_pages, slots, per_slot, seq_lens,
     for slot, n in enumerate(seq_lens):
         for pos in range(n):
             key, k1, k2 = jax.random.split(key, 3)
-            k_tok = jax.random.normal(k1, (1, KV, hd), dtype=jnp.float32)
-            v_tok = jax.random.normal(k2, (1, KV, hd), dtype=jnp.float32)
+            k_tok = jax.random.normal(k1, (1, KV, hd)).astype(dtype)
+            v_tok = jax.random.normal(k2, (1, KV, hd)).astype(dtype)
             kv = write_decode_kv(kv, 0, k_tok, v_tok,
                                  jnp.array([slot]), jnp.array([pos]))
 
@@ -41,6 +41,7 @@ def _check_against_gather(CFG, page_size, num_pages, slots, per_slot, seq_lens,
     # kernel's FUSED dequant is held to the same stored values)
     import math
     keys_g, values_g = gather_kv(kv, 0, jnp.arange(slots))
+    keys_g, values_g = (a.astype(jnp.float32) for a in (keys_g, values_g))
     scores = jnp.einsum("bkgh,bckh->bkgc", q, keys_g) / math.sqrt(hd)
     valid = jnp.arange(keys_g.shape[1])[None, :] < jnp.asarray(seq_lens)[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
@@ -90,8 +91,39 @@ def test_paged_decode_llama1b_geometry():
 import pytest
 
 
-@pytest.mark.parametrize("quant", ["", "int8"])
-def test_paged_chunk_matches_history_reference(quant):
+class _Heads:
+    """Head geometry alone (what init_kv_state and the kernel read)."""
+
+    def __init__(self, n_kv_heads, group=2, head_dim=16):
+        self.n_kv_heads, self.n_heads = n_kv_heads, n_kv_heads * group
+        self.head_dim, self.n_layers = head_dim, 1
+
+
+# how the kernel reads ONE kv head out of a page block depends on the
+# pool's dtype and head count (ops/paged_attention._heads): strided
+# loads of 32-bit words where the heads fill whole words, the per-token
+# sublane gather where they do not. The stored values are the same in the
+# kernel and in the gather oracle, so the comparison is exact-tolerance.
+@pytest.mark.parametrize("n_kv,dtype,quant", [
+    (8, jnp.bfloat16, ""),      # two heads a word, four words a token
+    (2, jnp.bfloat16, ""),      # one word a token: a TP shard of 8 heads
+    (3, jnp.bfloat16, ""),      # heads do not fill words: the gather
+    (1, jnp.bfloat16, ""),      # one head a shard: the gather
+    (8, jnp.float32, ""),       # a head is a word
+    # (int8 pools: f32 scales, so the oracle's dequant does not round)
+    (4, jnp.float32, "int8"),   # four heads a word, one word a token
+    (6, jnp.float32, "int8"),   # int8 heads that do not fill words
+], ids=["bf16x8", "bf16x2", "bf16x3", "bf16x1", "f32x8", "int8x4", "int8x6"])
+def test_paged_decode_reads_every_head_of_packed_pools(n_kv, dtype, quant):
+    _check_against_gather(_Heads(n_kv), page_size=16, num_pages=12, slots=2,
+                          per_slot=4, seq_lens=[37, 9], quant=quant,
+                          dtype=dtype)
+
+
+@pytest.mark.parametrize("quant,dtype", [("", jnp.float32), ("int8", jnp.float32),
+                                         ("", jnp.bfloat16)],
+                         ids=["f32", "int8", "bf16"])
+def test_paged_chunk_matches_history_reference(quant, dtype):
     """Chunk kernel (S queries over the page list) vs _history_attention:
     per-row history offsets, padding rows, multi-page contexts. The int8
     variant pins the kernel's fused dequant against the gather epilogue
@@ -112,7 +144,7 @@ def test_paged_chunk_matches_history_reference(quant):
     chunk_lens = [6, 6, 3]
 
     kv = init_kv_state(CFG, num_pages, page_size, slots, per_slot,
-                       dtype=jnp.float32, quant=quant)
+                       dtype=dtype, quant=quant)
     alloc = PageAllocator(num_pages, page_size, slots, per_slot)
     for slot in range(slots):
         assert alloc.allocate_slot(slot, hists[slot] + chunk_lens[slot])
@@ -123,8 +155,8 @@ def test_paged_chunk_matches_history_reference(quant):
         for pos in range(hists[slot] + chunk_lens[slot]):
             key, k1, k2 = jax.random.split(key, 3)
             kv = write_decode_kv(
-                kv, 0, jax.random.normal(k1, (1, KV, hd), dtype=jnp.float32),
-                jax.random.normal(k2, (1, KV, hd), dtype=jnp.float32),
+                kv, 0, jax.random.normal(k1, (1, KV, hd)).astype(dtype),
+                jax.random.normal(k2, (1, KV, hd)).astype(dtype),
                 jnp.array([slot]), jnp.array([pos]))
 
     key, kq = jax.random.split(key)
@@ -138,7 +170,8 @@ def test_paged_chunk_matches_history_reference(quant):
     safe = jnp.maximum(positions, 0)
 
     keys_g, values_g = gather_kv(kv, 0, jnp.arange(slots))
-    ref = _history_attention(q, keys_g, values_g, safe, valid, CFG)
+    ref = _history_attention(q, keys_g.astype(jnp.float32),
+                             values_g.astype(jnp.float32), safe, valid, CFG)
 
     qg = q.reshape(slots, S, KV, G, hd)
     out = paged_chunk_attention_pallas(
@@ -154,11 +187,15 @@ def test_paged_chunk_matches_history_reference(quant):
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_kernels_per_model_shard_match_unsharded():
+@pytest.mark.parametrize("KV,pool_dtype", [(4, jnp.float32),
+                                           (8, jnp.bfloat16)],
+                         ids=["f32-1head", "bf16-2heads"])
+def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype):
     """On a TP mesh the kernels run under shard_map over ``model`` — each
     shard on the kv heads it holds (ops/attention.on_model_axis) — and must
     give what one unsharded call gives. Layer 1 of 2, so the layer index
-    that rides the BlockSpec index map is exercised too."""
+    that rides the BlockSpec index map is exercised too. A bf16 shard of
+    two heads reads them as one 32-bit word a token."""
     from functools import partial
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -170,12 +207,12 @@ def test_kernels_per_model_shard_match_unsharded():
     from mcp_context_forge_tpu.tpu_local.parallel import make_mesh
 
     mesh = make_mesh("1x4", devices=jax.devices()[:4])
-    L, N, page, KV, G, hd, B, per_slot, S = 2, 9, 8, 4, 2, 16, 2, 4, 8
+    L, N, page, G, hd, B, per_slot, S = 2, 9, 8, 2, 16, 2, 4, 8
     keys = iter(jax.random.split(jax.random.PRNGKey(3), 8))
     pool_sharding = NamedSharding(mesh, P(None, None, None, "model", None))
     k_pages, v_pages = (jax.device_put(
-        jax.random.normal(next(keys), (L, N, page, KV, hd)), pool_sharding)
-        for _ in range(2))
+        jax.random.normal(next(keys), (L, N, page, KV, hd)).astype(pool_dtype),
+        pool_sharding) for _ in range(2))
     tables = 1 + jnp.arange(B * per_slot, dtype=jnp.int32).reshape(B, per_slot)
     seq_lens = jnp.asarray([per_slot * page, 11], jnp.int32)
     q = jax.random.normal(next(keys), (B, KV, G, hd))
