@@ -2,6 +2,15 @@
 and decode logits against the plain reference, greedy repeatability, and
 (after the window) exact token accounting and zero serving-stage compiles.
 
+The yardstick's part of the logits check is here: the prompts drawn from the
+configuration's ``check_seed``, the check's lengths (``Check``: the
+configuration's ``"check"`` group, or the defaults below, the one place they
+exist), the tolerance rule, the verdict and the facts printed. How logits are
+produced on each side is the configuration's family's
+(``benchmark/families/<family>.py``: the program's own prefill and decode
+through its own cache, and the name of the plain reference); this module names
+no model family.
+
 Tolerance (the configuration file's ``logits_tolerance``; reason): the engine
 computes in bfloat16 (8 bits of mantissa, a relative step of 2**-8 = 0.4 %)
 through ``n_layers`` residual blocks and rounds attention weights to bf16
@@ -14,91 +23,59 @@ logits by O(1); int8 or fp8 activations would move them by several tenths.
 
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any
 
 import numpy as np
 
-CHECK_SEQ = 128          # the check's prefill length (one page, flash-able)
-DECODE_POSITIONS = 8
+from .. import families
 
 
-class EngineLogits:
-    """Last-position prefill logits, then one decode step per forced token
-    through the paged cache — ``models.llama.prefill`` and ``decode_step``
-    with the engine's own params, mesh and attention choice, on a scratch pool
-    laid out like the engine's. Two programs, traced once for every prompt."""
+@dataclasses.dataclass(frozen=True)
+class Check:
+    """The lengths the logits check runs at. A configuration whose mechanism
+    starts at some context length states lengths above it."""
+    prompt_lengths: tuple[int, ...] = (96, 40)
+    decode_positions: int = 8
 
-    def __init__(self, engine) -> None:
-        from functools import partial
+    @property
+    def tokens(self) -> int:
+        """The longest sequence the check holds in the cache."""
+        return max(self.prompt_lengths) + self.decode_positions
 
-        import jax
-        import jax.numpy as jnp
 
-        from mcp_context_forge_tpu.tpu_local.kv import init_kv_state
-        from mcp_context_forge_tpu.tpu_local.models.llama import decode_step, prefill
-        from mcp_context_forge_tpu.tpu_local.ops.attention import (
-            select_paged_attention, select_prefill_attention)
-
-        cfg, econf, mesh = engine.model_config, engine.config, engine.mesh
-        self.engine, self.page = engine, econf.page_size
-        self.per_slot = (CHECK_SEQ + DECODE_POSITIONS + self.page - 1) // self.page
-        self.impl = {
-            "prefill": select_prefill_attention(
-                econf.attn_impl, mesh, CHECK_SEQ, cfg.head_dim, cfg.n_kv_heads),
-            "decode": select_paged_attention(
-                mesh, cfg.head_dim, self.page, cfg.n_kv_heads, bool(econf.kv_quant))}
-        slot = jnp.zeros((1,), jnp.int32)
-        self._scratch = jax.jit(
-            partial(init_kv_state, cfg, 1 + self.per_slot, self.page, 1,
-                    self.per_slot, dtype=engine._kv_dtype, quant=econf.kv_quant),
-            out_shardings=jax.tree.map(lambda a: a.sharding, engine.kv))
-        self._prefill = jax.jit(lambda params, kv, tok, pos, last: prefill(
-            params, cfg, tok, pos, kv, slot, attn_impl=self.impl["prefill"],
-            mesh=mesh, last_idx=last))
-        self._decode = jax.jit(lambda params, kv, tok, pos: decode_step(
-            params, cfg, tok, pos, kv, slot, pos + 1, ctx_pages=self.per_slot,
-            paged_impl=self.impl["decode"], mesh=mesh))
-
-    def __call__(self, prompt: list[int], forced: list[int]) -> np.ndarray:
-        """[1 + len(forced), V] float32."""
-        import jax
-        import jax.numpy as jnp
-
-        engine, n = self.engine, len(prompt)
-        if n > CHECK_SEQ:
-            raise ValueError(f"check prompt of {n} tokens exceeds {CHECK_SEQ}")
-        tokens = np.full((1, CHECK_SEQ), engine.tokenizer.pad_id, np.int32)
-        tokens[0, :n] = prompt
-        positions = np.full((1, CHECK_SEQ), -1, np.int32)
-        positions[0, :n] = np.arange(n)
-        with engine.mesh:
-            scratch = self._scratch()
-            scratch = scratch._replace(block_tables=jax.device_put(
-                1 + np.arange(self.per_slot, dtype=np.int32)[None, :],
-                scratch.block_tables.sharding))
-            logits, scratch = self._prefill(
-                engine.params, scratch, jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray([n - 1], jnp.int32))
-            rows = [np.asarray(logits, np.float32)[0]]
-            for j, token in enumerate(forced):
-                logits, scratch = self._decode(
-                    engine.params, scratch, jnp.asarray([token], jnp.int32),
-                    jnp.asarray([n + j], jnp.int32))
-                rows.append(np.asarray(logits, np.float32)[0])
-        for leaf in jax.tree.leaves(scratch):
-            leaf.delete()
-        return np.stack(rows)
+def check_of(config: dict[str, Any], mix: dict[str, Any] | None = None) -> Check:
+    """The configuration file's optional ``"check": {"prompt_lengths": [...],
+    "decode_positions": n}`` (absent keys: the defaults), refused where it is
+    malformed or longer than the mix's ``max_seq_len``."""
+    group = config.get("check", {})
+    unknown = set(group) - {"prompt_lengths", "decode_positions"}
+    if unknown:
+        raise ValueError(f"check: unknown keys {sorted(unknown)}")
+    check = Check(tuple(group.get("prompt_lengths", Check.prompt_lengths)),
+                  group.get("decode_positions", Check.decode_positions))
+    if not (check.prompt_lengths
+            and all(type(n) is int and n >= 2 for n in check.prompt_lengths)
+            and type(check.decode_positions) is int and check.decode_positions >= 1):
+        raise ValueError(f"check: prompt_lengths are whole numbers from 2, "
+                         f"decode_positions one from 1; got {group}")
+    limit = (mix or {}).get("engine", {}).get("max_seq_len")
+    if limit is not None and check.tokens > int(limit):
+        raise ValueError(
+            f"check: {max(check.prompt_lengths)} prompt + {check.decode_positions} "
+            f"decode tokens exceed the mix's max_seq_len {limit}")
+    return check
 
 
 def logits_check(engine, seed: int, tolerance: dict[str, float],
-                 prompt_lengths: tuple[int, ...] = (96, 40)) -> dict[str, Any]:
-    """Two prompts from ``seed`` (the configuration's ``check_seed``, not the
-    run's: weights and check prompts are then the same in every run, so the
-    tolerance was validated on exactly the comparison each run makes): engine
-    logits (prefill's last position and
-    ``DECODE_POSITIONS`` decode steps through the cache) against the
-    reference's full forward over the same tokens.
+                 check: Check = Check(), family: str | None = None) -> dict[str, Any]:
+    """One prompt per ``check.prompt_lengths`` from ``seed`` (the
+    configuration's ``check_seed``, not the run's: weights and check prompts
+    are then the same in every run, so the tolerance was validated on exactly
+    the comparison each run makes): the family's engine logits (prefill's last
+    position and ``check.decode_positions`` decode steps through the cache)
+    against its reference's full forward over the same tokens.
 
     A position passes where every logit is within ``atol + rtol * |ref|``.
     ``positions_within`` (default 1: all of them) is the share of positions
@@ -111,26 +88,26 @@ def logits_check(engine, seed: int, tolerance: dict[str, float],
     """
     import jax
 
-    from ..reference import decoder
-
+    family_module = families.load(family)
+    reference = families.reference_of(family_module)
     rng = np.random.default_rng([seed % (2 ** 63), 7])
     atol, rtol = float(tolerance["atol"]), float(tolerance["rtol"])
     need = float(tolerance.get("positions_within", 1.0))
     atol_any = float(tolerance.get("atol_any", np.inf))
     started = time.monotonic()
-    engine_logits = EngineLogits(engine)
+    engine_logits = family_module.engine_logits(engine, check)
     position_err: list[float] = []
     position_ok: list[bool] = []
     position_margin: list[float] = []
     per_prompt = []
-    for length in prompt_lengths:
+    for length in check.prompt_lengths:
         prompt = [engine.tokenizer.bos_id] + rng.integers(
             32, 127, length - 1).tolist()
-        forced = rng.integers(32, 127, DECODE_POSITIONS).tolist()
+        forced = rng.integers(32, 127, check.decode_positions).tolist()
         got = engine_logits(prompt, forced)
-        ref, margins = jax.device_get(decoder.forward(
+        ref, margins = jax.device_get(reference.forward(
             engine.params, engine.model_config, prompt + forced,
-            list(range(length - 1, length + DECODE_POSITIONS))))
+            list(range(length - 1, length + check.decode_positions))))
         ref = np.asarray(ref, np.float32)
         if margins is not None:
             position_margin += [round(float(m), 4) for m in margins]
@@ -151,7 +128,7 @@ def logits_check(engine, seed: int, tolerance: dict[str, float],
             "max_abs_err": max(position_err),
             "position_max_abs_err": position_err,
             "position_routing_margin": position_margin, "per_prompt": per_prompt,
-            "attn": engine_logits.impl,
+            "attn": getattr(engine_logits, "impl", None),
             "wall_s": round(time.monotonic() - started, 2)}
 
 
@@ -161,7 +138,9 @@ async def greedy_repeats(engine, seed: int, new_tokens: int = 8) -> dict[str, An
     prompt = [engine.tokenizer.bos_id] + rng.integers(32, 127, 47).tolist()
     runs = [[t async for t in engine.generate(list(prompt), max_tokens=new_tokens)]
             for _ in range(2)]
-    return {"ok": len(runs[0]) >= 1 and runs[0] == runs[1], "tokens": runs[0]}
+    differing = sum(a != b for a, b in zip(*runs)) + abs(len(runs[0]) - len(runs[1]))
+    return {"ok": len(runs[0]) >= 1 and differing == 0, "tokens": runs[0],
+            "differing": differing}
 
 
 def accounting(before: dict[str, int], after: dict[str, int], records,
@@ -176,6 +155,24 @@ def accounting(before: dict[str, int], after: dict[str, int], records,
     whole = all(r.ok for r in records)
     return {"ok": got == want or not whole, "held": whole, "engine": got,
             "client": want}
+
+
+def compared(logits: dict[str, Any], greedy: dict[str, Any], books: dict[str, Any],
+             serving_compiles: int) -> list[str]:
+    """Each number ``correct`` compares, beside its limit, one a line: the last
+    lines a run leaves on standard error."""
+    say = lambda ok: "ok" if ok else "NOT CORRECT"
+    return [
+        f"correct: logits_vs_reference positions_within {logits['positions_within']:.4f}"
+        f" >= {logits['positions_within_needed']} (a position: every logit within "
+        f"{logits['atol']} + {logits['rtol']} * |ref|), max_abs_err "
+        f"{logits['max_abs_err']} <= atol_any {logits['atol_any']}: {say(logits['ok'])}",
+        f"correct: greedy_repeats tokens differing between two runs "
+        f"{greedy['differing']} <= 0, tokens a run {len(greedy['tokens'])} >= 1: "
+        f"{say(greedy['ok'])}",
+        f"correct: accounting engine {books['engine']} == client {books['client']}"
+        f" (held to it: {books['held']}): {say(books['ok'])}",
+        f"correct: serving_compiles {serving_compiles} <= 0: {say(serving_compiles == 0)}"]
 
 
 def stats_snapshot(engine) -> dict[str, Any]:

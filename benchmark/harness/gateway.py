@@ -6,7 +6,9 @@ lives under the benchmark's own directory). The only things the harness puts
 between the program and the socket are stated here:
 
 * ``register_model``: the configuration's file becomes the engine's model
-  config before ``build_app`` (a depth cut is a file, not an edit);
+  config before ``build_app`` (a depth cut is a file, not an edit). Which keys
+  mean what is the configuration's family's to say
+  (``benchmark/families/<family>.py``); this module names no model family;
 * ``RenderEveryToken``: the repo has no vocabulary file, and its byte-level
   tokenizer drops every sampled id above 255 — with random weights nearly all
   of them — so a stream would carry no content event. The harness renders
@@ -17,7 +19,6 @@ between the program and the socket are stated here:
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import os
 import sys
 import time
@@ -47,16 +48,6 @@ ENGINE_ENV = {
     # feeds only the live gauges, which the benchmark does not read
     "MCPFORGE_TPU_LOCAL_COST_ANALYSIS": "false",
 }
-# HF config.json key -> models/configs.py LlamaConfig field
-HF_TO_LLAMA = {
-    "vocab_size": "vocab_size", "hidden_size": "dim",
-    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "intermediate_size": "ffn_hidden",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "max_position_embeddings": "max_seq_len", "hidden_act": "hidden_act",
-    "tie_word_embeddings": "tie_embeddings",
-    "num_local_experts": "n_experts", "num_experts_per_tok": "moe_top_k",
-}
 
 
 def engine_env(name: str, *groups: dict[str, Any]) -> dict[str, str]:
@@ -74,15 +65,13 @@ def engine_env(name: str, *groups: dict[str, Any]) -> dict[str, str]:
 
 
 def register_model(name: str, config: dict[str, Any]):
-    """``MODEL_CONFIGS[name]`` from the configuration file's published keys."""
+    """``MODEL_CONFIGS[name]`` from the configuration file's keys, as its
+    family (``"family"``, absent: ``"llama"``) reads them."""
     from mcp_context_forge_tpu.tpu_local.models import MODEL_CONFIGS
-    from mcp_context_forge_tpu.tpu_local.models.configs import LlamaConfig
 
-    fields = {ours: config[theirs] for theirs, ours in HF_TO_LLAMA.items()
-              if theirs in config}
-    model = LlamaConfig(name=name, **fields)
-    if config.get("head_dim", model.head_dim) != model.head_dim:
-        model = dataclasses.replace(model, head_dim_override=config["head_dim"])
+    from .. import families
+
+    model = families.of(config).model_config(name, config)
     MODEL_CONFIGS[name] = model
     return model
 

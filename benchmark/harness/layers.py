@@ -4,12 +4,11 @@ that finds nothing to read returns None and the metric is left out."""
 
 from __future__ import annotations
 
-import importlib.util
 import os
 from dataclasses import dataclass, field
 from typing import Any
 
-from .manifest import BENCH_DIR
+from .manifest import BENCH_DIR, load_by_name
 from .stats import Record
 from .trace_reduce import Reduced
 
@@ -34,14 +33,8 @@ class LayerContext:
 
 
 def load_reader(name: str):
-    path = os.path.join(BENCH_DIR, "layer_metrics", name + ".py")
-    if not os.path.isfile(path):
-        raise FileNotFoundError(f"per-layer metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark.layer_metrics." + name.replace(".", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_by_name(os.path.join(BENCH_DIR, "layer_metrics"), name,
+                        "per-layer reader", ("read",)).read
 
 
 def read_all(metrics: tuple[dict, ...], ctx: LayerContext) -> dict[str, dict]:

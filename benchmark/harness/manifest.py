@@ -6,10 +6,13 @@ them here means a later PR's new entry fails in a unit test, not on the chip.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import re
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Any
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,6 +188,35 @@ def load(path: str = MANIFEST) -> dict[str, Any]:
 def read_json(path: str) -> dict[str, Any]:
     with open(path, encoding="utf-8") as handle:
         return json.load(handle)
+
+
+def load_by_name(directory: str, name: str, what: str,
+                 needs: tuple[str, ...]) -> ModuleType:
+    """``<directory>/<name>.py`` as a module: how a per-layer reader, a family
+    and a reference are found from the name in a data file. Loaded once a
+    path (a reference's jitted functions then trace once). No file there is a
+    ``FileNotFoundError`` that says where it looked; a file that lacks one of
+    ``needs`` is a ``ManifestError`` that says which."""
+    if not (isinstance(name, str) and NAME.match(name)):
+        raise ManifestError(f"{what} {name!r} is not a name")
+    path = os.path.join(directory, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {what} {name!r}: looked at {path}")
+    qualified = f"benchmark.{os.path.basename(directory)}.{name.replace('.', '_')}"
+    module = sys.modules.get(qualified)
+    if module is None or getattr(module, "__file__", None) != path:
+        spec = importlib.util.spec_from_file_location(qualified, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[qualified] = module
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[qualified]
+            raise
+    missing = [n for n in needs if not hasattr(module, n)]
+    if missing:
+        raise ManifestError(f"{what} {name!r} at {path} lacks {missing}")
+    return module
 
 
 def cell(doc: dict[str, Any], name: str, root: str = ROOT) -> Cell:

@@ -3,7 +3,9 @@ for prompts above the bucket). Least time for the causal attention of the
 prompts prefetched in the traced span over the summed device time of the
 attention calls inside prefill programs, in %. A prompt's prefill is taken to
 run between its send and its first token; the part of that span inside the
-trace is the part of its cost counted."""
+trace is the part of its cost counted. The cost is a GQA trunk's (kv heads x
+head_dim of K and V), so BENCHMARK.json lists the cells it is read in: a cell
+of another family brings a reader of its own for its own kernels."""
 from benchmark.harness import kernel_cost
 from benchmark.harness.layers import PREFILL_PROGRAMS, overlap
 

@@ -16,7 +16,8 @@ tests' rehearsal calls :func:`measure` directly with a tiny configuration.
 
 Earlier lines of stdout are JSON facts (``{"note": ...}``); the LAST line is
 the result: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
-and, with ``--trace 1``, ``breakdown``.
+and, with ``--trace 1``, ``breakdown``. The last lines of stderr are each
+number ``correct`` compared, beside its limit (``correct.compared``).
 """
 
 from __future__ import annotations
@@ -41,8 +42,8 @@ ROOT = os.path.dirname(BENCH_DIR)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import traffic  # noqa: E402
-from benchmark.harness import manifest, stats  # noqa: E402
+from benchmark import families, traffic  # noqa: E402
+from benchmark.harness import correct, manifest, stats  # noqa: E402
 from benchmark.harness.draw import Planned, nonce_index, text  # noqa: E402
 
 OUT_DIR = os.path.join(ROOT, ".bench_out")      # git-ignored; traces live here
@@ -161,7 +162,7 @@ async def ready(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, Any]
     """Everything before the window: build, warm, check. Yields a Session."""
     import aiohttp
 
-    from benchmark.harness import correct, gateway
+    from benchmark.harness import gateway
 
     meter = gateway.CompileMeter()
     model = gateway.register_model(cell.config, config)
@@ -170,7 +171,7 @@ async def ready(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, Any]
         engine = served["engine"]
         tracker = engine.compile_tracker.snapshot()
         note("build", wall_s=round(served["build_s"], 1), **meter.facts(),
-             model=cell.config, layers=model.n_layers,
+             model=cell.config, layers=getattr(model, "n_layers", None),
              kv_pages=engine.num_kv_pages, max_batch=engine.config.max_batch,
              max_seq_len=engine.config.max_seq_len,
              prefill_buckets=list(engine.config.prefill_buckets),
@@ -181,8 +182,9 @@ async def ready(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, Any]
              cache_dir=engine.compile_cache_dir,
              memory_peak_bytes=gateway.memory_peak_bytes())
 
-        logits = correct.logits_check(engine, int(config["check_seed"]),
-                                     config["logits_tolerance"])
+        logits = correct.logits_check(
+            engine, int(config["check_seed"]), config["logits_tolerance"],
+            correct.check_of(config, mix), config.get("family"))
         note("logits_vs_reference", **logits)
         greedy = await correct.greedy_repeats(engine, seed)
         note("greedy_repeats", **greedy)
@@ -221,7 +223,6 @@ async def window(session: Session, plan: dict[str, Any], seconds: float,
     """Offer ``plan`` for ``seconds`` and drain: the records, the window on the
     client's clock, EngineStats at its edges and after the drain, and with
     ``trace`` the reduced trace of a span in its middle."""
-    from benchmark.harness import correct
     from benchmark.harness.client import run_traffic
 
     snapshots: dict[str, dict] = {}
@@ -256,7 +257,7 @@ async def measure(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, An
     """Set up, check, measure one cell; returns the result object. Does not
     look at the platform: :func:`main` has, and the tests' rehearsal runs
     this on the CPU at a tiny size (its result says ``"platform": "cpu"``)."""
-    from benchmark.harness import correct, gateway, kernel_cost, layers
+    from benchmark.harness import gateway, kernel_cost, layers
 
     async with ready(cell, config, mix, seed, trace) as session:
         plan = traffic.plan(mix, cell_params, seconds, seed, session.overhead)
@@ -282,6 +283,8 @@ async def measure(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, An
 
         metrics: dict[str, dict[str, Any]] = {}
         checks = session.checks
+        verdict = correct.compared(checks["logits"], checks["greedy"], books,
+                                   serving_compiles)
         result: dict[str, Any] = {
             "correct": bool(checks["logits"]["ok"] and checks["greedy"]["ok"]
                             and books["ok"] and serving_compiles == 0),
@@ -310,7 +313,9 @@ async def measure(cell: manifest.Cell, config: dict[str, Any], mix: dict[str, An
             device["window_s"] = reduced.window_s
             result["breakdown"] = {"device_ops": reduced.top_ops(10),
                                    "idle_gaps": reduced.idle_gaps(5)}
-        return result
+    # after the tear-down's own lines, so that these end standard error
+    print("\n".join(verdict), file=sys.stderr, flush=True)
+    return result
 
 
 def _prefill_programs(engine) -> int:
@@ -342,6 +347,8 @@ def main(argv: list[str] | None = None) -> int:
         config = manifest.read_json(cell.config_file)
         mix = manifest.read_json(cell.traffic_file)
         cell_params = manifest.read_json(cell.cell_file)
+        families.of(config)                 # the family file and its three names
+        correct.check_of(config, mix)       # check lengths the mix can hold
     except (OSError, ValueError) as exc:
         raise Refused(str(exc))
     device = require_tpu(cell.chips)
