@@ -8,8 +8,11 @@ and nowhere else. Three kinds of event share one bounded ring:
   phase beside the device's programs on one clock) and appends
   ``("span", name, t0, t1, step, kind, replica)``;
 - **steps** — one ``("step", seq, kind, width, rows, shape, t_dispatched,
-  t_retired, replica)`` per device dispatch (``shape`` is the prefill
-  bucket or the decode context pages);
+  t_retired, replica, counts)`` per device dispatch (``shape`` is the prefill
+  bucket or the decode context pages; ``counts`` is None, or for a model
+  family whose step programs count on the device (``models/deepseek.py``)
+  the step's ``StepCounts``: mean selected / context share of its rows,
+  tokens through expert layers, token-expert pairs on held experts);
 - **request stamps** — ``("req", phase, t, request_id, slot, replica)`` for
   ``submit`` / ``admit`` / ``first`` / ``done``.
 
@@ -44,6 +47,13 @@ class SpanEvent(NamedTuple):
     replica: str
 
 
+class StepCounts(NamedTuple):
+    """What a step program counted on the device, read back with its tokens."""
+    selected_share: float   # mean over the step's rows of selected / context
+    moe_tokens: float       # tokens through expert layers
+    moe_local_pairs: float  # token-expert pairs that landed on held experts
+
+
 class StepEvent(NamedTuple):
     seq: int
     kind: str
@@ -53,6 +63,7 @@ class StepEvent(NamedTuple):
     t_dispatched: float
     t_retired: float
     replica: str
+    counts: StepCounts | None = None
 
 
 class RequestEvent(NamedTuple):
@@ -120,9 +131,10 @@ class StepTimeline:
         self._ring.append((SPAN, name, t0, t1, step, kind, self.replica))
 
     def step(self, seq: int, kind: str, width: int, rows: int,
-             shape: int | None, t_dispatched: float, t_retired: float) -> None:
+             shape: int | None, t_dispatched: float, t_retired: float,
+             counts: StepCounts | None = None) -> None:
         self._ring.append((STEP, seq, kind, width, rows, shape, t_dispatched,
-                           t_retired, self.replica))
+                           t_retired, self.replica, counts))
         self.last_retired = t_retired
 
     def stamp(self, phase: str, request_id: str, slot: int,

@@ -41,15 +41,13 @@ import numpy as np
 
 from ..observability.faults import fault_point
 from ..observability.logging import trace_extra
-from ..observability.timeline import StepTimeline
+from ..observability.timeline import StepCounts, StepTimeline
 from .compile_events import (CompileTracker, install_listener,
                              restore_thread, track_thread)
-from .kv import PageAllocator, init_kv_state, kv_logical
+from .kv import PageAllocator
 from .models import MODEL_CONFIGS, LlamaConfig
-from .models.llama import (decode_step, init_keys, init_layer, init_trunk,
-                           params_logical, prefill, prefill_with_history)
-from .ops.attention import (on_tpu, select_paged_attention,
-                            select_prefill_attention)
+from .models import family_of
+from .ops.attention import on_tpu
 from .parallel import make_mesh, param_specs
 from .roofline import (V5E_HBM_GBPS, V5E_PEAK_BF16_TFLOPS, CostRegistry,
                        roofline_fractions)
@@ -398,6 +396,10 @@ class EngineStats:
         self.pipeline_drains = 0      # overlap barriers that forced a drain
         self.dispatch_gap_ms_total = 0.0  # host-side stall between dispatches
         self.phase_samples = 0        # decode steps with phase attribution
+        # counted on the device by families whose step programs do
+        # (models/deepseek.py), read back with each step's tokens
+        self.moe_tokens = 0           # tokens through expert layers
+        self.moe_local_pairs = 0      # token-expert pairs on held experts
 
 
 def _named(jitted, name: str):
@@ -408,6 +410,18 @@ def _named(jitted, name: str):
     ``jax.jit(partial(f, ...))`` shape the linter's jit rules read."""
     jitted.__wrapped__.__name__ = name
     return jitted
+
+
+def _beside(tokens, counts):
+    """What a prefill step program returns beside ``kv``: the sampled tokens
+    alone (the GQA family: the program it always was), or ``(tokens,
+    counts)`` for a family whose step functions count on the device
+    (``STEP_AUX``); :func:`_apart` splits the host's copy again."""
+    return (tokens, *counts) if counts else tokens
+
+
+def _apart(host_out):
+    return host_out if isinstance(host_out, tuple) else (host_out,)
 
 
 class EngineInitTimeout(RuntimeError):
@@ -595,6 +609,9 @@ class TPUEngine:
                 overrides["moe_block"] = config.moe_block
             self.model_config = dataclasses.replace(self.model_config,
                                                     **overrides)
+        # the model family (models/__init__.py): the module whose step
+        # functions, weight tree and cache pools serve this config's class
+        self._family = family_of(self.model_config)
         self.tokenizer = load_tokenizer(config.checkpoint,
                                         vocab_size=self.model_config.vocab_size)
         self.stats = EngineStats()
@@ -725,6 +742,12 @@ class TPUEngine:
             devices = probe_devices(config.init_timeout_s)
         self.mesh = make_mesh(config.mesh_shape, devices=devices)
         logger.info("tpu_local: mesh %s, model %s", self.mesh.shape, config.model)
+        refused = self._family.refusals(self.model_config, config, self.mesh,
+                                        tiers=self._tier_client is not None)
+        if refused:
+            raise NotImplementedError(
+                f"model {config.model!r} ({type(self.model_config).__name__}) "
+                f"cannot be served with: " + "; ".join(refused))
         if config.sp_impl != "none":
             # SP shard_map requires the sequence (bucket) to divide the axis;
             # reject at construction instead of killing the dispatch thread
@@ -761,7 +784,7 @@ class TPUEngine:
         # params: load checkpoint or random-init, placed with TP shardings;
         # quant="int8" swaps in the {"q","s"} tree (quantize.py)
         with self.mesh:
-            logical = params_logical(self.model_config)
+            logical = self._family.params_logical(self.model_config)
             if config.quant == "int8":
                 from .quantize import quantize_logical
                 shardings = param_specs(quantize_logical(logical), self.mesh)
@@ -834,17 +857,28 @@ class TPUEngine:
         trunk_logical = {k: v for k, v in logical.items() if k != "layers"}
         trunk_shardings = {k: v for k, v in shardings.items()
                            if k != "layers"}
-        layer_fn = jax.jit(
-            lambda key: finish(init_layer(cfg, key, dtype),
-                               logical["layers"][0]),
-            out_shardings=shardings["layers"][0])
+        family = self._family
+        # one executable per KIND of layer (a family whose layers differ —
+        # leading dense layers before expert layers — names them)
+        layer_fns: dict[str, Any] = {}
+
+        def layer_fn(i: int):
+            kind = family.layer_kind(cfg, i)
+            if kind not in layer_fns:
+                layer_fns[kind] = jax.jit(
+                    lambda key: finish(
+                        family.init_layer(cfg, key, dtype, kind=kind),
+                        logical["layers"][i]),
+                    out_shardings=shardings["layers"][i])
+            return layer_fns[kind]
+
         trunk_fn = jax.jit(
-            lambda ek, hk: finish(init_trunk(cfg, ek, hk, dtype),
+            lambda ek, hk: finish(family.init_trunk(cfg, ek, hk, dtype),
                                   trunk_logical),
             out_shardings=trunk_shardings)
-        keys = init_keys(cfg, jax.random.PRNGKey(0))
+        keys = family.init_keys(cfg, jax.random.PRNGKey(0))
         params = trunk_fn(keys[-2], keys[-1])
-        params["layers"] = [layer_fn(keys[i]) for i in range(cfg.n_layers)]
+        params["layers"] = [layer_fn(i)(keys[i]) for i in range(cfg.n_layers)]
         return params
 
     def _build_tier_fns(self) -> None:
@@ -949,10 +983,11 @@ class TPUEngine:
         allocator are sized by the converted, dtype-aware page count."""
         config = self.config
         max_pages_per_slot = config.max_seq_len // config.page_size
-        from .kv import kv_page_bytes, num_pages_for_budget
+        from .kv import num_pages_for_budget
         from .parallel.sharding import (kv_pages_sharding, kv_scales_sharding,
                                         logical_to_sharding)
         # bytes one page costs under the ACTIVE storage mode (gauge unit)
+        kv_page_bytes = self._family.kv_page_bytes
         self._kv_page_bytes = kv_page_bytes(
             self.model_config, config.page_size, self._kv_dtype,
             config.kv_quant)
@@ -968,19 +1003,21 @@ class TPUEngine:
             # kv_logical is the single source of the state's structure;
             # the page/scale rules route through the divisibility-aware
             # helpers (kv heads that don't divide the TP degree replicate)
-            n_kv = self.model_config.n_kv_heads
-
             def to_sharding(name: str):
                 if name == "kv_pages":
-                    return kv_pages_sharding(self.mesh, n_kv)
+                    return kv_pages_sharding(self.mesh,
+                                             self.model_config.n_kv_heads)
                 if name == "kv_scales":
-                    return kv_scales_sharding(self.mesh, n_kv)
+                    return kv_scales_sharding(self.mesh,
+                                              self.model_config.n_kv_heads)
                 return logical_to_sharding(name, self.mesh)
 
-            kv_shardings = jax.tree.map(to_sharding,
-                                        kv_logical(config.kv_quant))
+            kv_shardings = jax.tree.map(
+                to_sharding, self._family.kv_logical(
+                    config.kv_quant, config=self.model_config))
             kv_init = jax.jit(partial(
-                init_kv_state, self.model_config, self.num_kv_pages,
+                self._family.init_kv_state, self.model_config,
+                self.num_kv_pages,
                 config.page_size, config.max_batch, max_pages_per_slot,
                 dtype=self._kv_dtype, quant=config.kv_quant),
                 out_shardings=kv_shardings)
@@ -1187,7 +1224,7 @@ class TPUEngine:
                 jnp.full((1, b0), -1, jnp.int32),
                 jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
                 settle, jax.random.PRNGKey(0))
-            first.block_until_ready()
+            jax.block_until_ready(first)
             # utility-kernel warmup: the dispatch thread's first
             # jax.random.split UNPACK (a slice program) and _sync_tables'
             # sharded block-table device_put would otherwise be tiny
@@ -1255,7 +1292,7 @@ class TPUEngine:
                             self.cost_registry.capture(kind, B, bucket,
                                                        fn, *args)
                         first, self.kv = fn(*args)
-                        first.block_until_ready()
+                        jax.block_until_ready(first)
                         shapes += 1
                     B *= 2
             B = self.config.max_batch
@@ -1317,7 +1354,7 @@ class TPUEngine:
                                 "decode" + suffix, batch, ctx_pages,
                                 self._decode_fn(ctx_pages, batch, k_rung),
                                 *args)
-                        (block, _, _), self.kv = \
+                        (block, *_), self.kv = \
                             self._decode_fn(ctx_pages, batch, k_rung)(*args)
                         block.block_until_ready()
                         shapes += 1
@@ -1343,7 +1380,7 @@ class TPUEngine:
                                     self._decode_fb_fn(ctx_pages, batch,
                                                        k_rung),
                                     *fb_args)
-                            (block, _, _), self.kv = self._decode_fb_fn(
+                            (block, *_), self.kv = self._decode_fb_fn(
                                 ctx_pages, batch, k_rung)(*fb_args)
                             block.block_until_ready()
                             shapes += 1
@@ -1366,9 +1403,7 @@ class TPUEngine:
         """Which paged-attention implementation ``step`` traces — decided
         from THIS engine's mesh (ops/attention.py), and recorded so
         ``attn_traced`` can say what every compiled step runs."""
-        cfg = self.model_config
-        impl = select_paged_attention(self.mesh, cfg.head_dim, kv.page_size,
-                                      cfg.n_kv_heads, kv.quantized)
+        impl = self._family.paged_impl(self.mesh, self.model_config, kv)
         self.attn_traced[step] = impl
         return impl
 
@@ -1377,19 +1412,21 @@ class TPUEngine:
                             sp: bool = False):
         """Batched prefill + on-device first-token sampling (same sampler and
         PRNG stream as decode — round-1 VERDICT weak #5). ``sp=True`` runs
-        the sequence-parallel attention path for long prompts."""
+        the sequence-parallel attention path for long prompts. Returns
+        ``(first tokens, kv)``, the tokens with the step's counts beside
+        them for a family that counts on the device (:func:`_beside`)."""
         cfg = self.model_config
-        impl = self.config.sp_impl if sp else select_prefill_attention(
-            self.config.attn_impl, self.mesh, tokens.shape[1], cfg.head_dim,
-            cfg.n_kv_heads, jnp.dtype(self._kv_dtype).itemsize)
+        impl = self.config.sp_impl if sp else self._family.prefill_impl(
+            self.config.attn_impl, self.mesh, tokens.shape[1], cfg,
+            jnp.dtype(self._kv_dtype).itemsize)
         self.attn_traced["prefill"] = impl
         # last_idx inside the forward: only those rows go through the lm
         # head — [B,S,V] f32 logits would be gigabytes at real vocab sizes
-        logits, kv = prefill(params, cfg, tokens, positions, kv,
-                             slot_ids, attn_impl=impl, mesh=self.mesh,
-                             last_idx=last_idx)
+        logits, kv, *aux = self._family.prefill(
+            params, cfg, tokens, positions, kv, slot_ids, attn_impl=impl,
+            mesh=self.mesh, last_idx=last_idx)
         first = sample_tokens(logits, sampling, key)
-        return first, kv
+        return _beside(first, aux), kv
 
     def _prefill_hist_and_sample(self, params, kv, tokens, positions, slot_ids,
                                  last_idx, sampling: SamplingParams, key,
@@ -1398,12 +1435,12 @@ class TPUEngine:
         same surface as _prefill_and_sample, but attention spans the slot's
         paged context up to the static ``ctx_pages`` bucket, so rows start
         at their history offset."""
-        logits, kv = prefill_with_history(
+        logits, kv, *aux = self._family.prefill_with_history(
             params, self.model_config, tokens, positions, kv, slot_ids,
             ctx_pages=ctx_pages, last_idx=last_idx,
             paged_impl=self._paged_impl("prefill_hist", kv), mesh=self.mesh)
         first = sample_tokens(logits, sampling, key)
-        return first, kv
+        return _beside(first, aux), kv
 
     def _verify_fn(self, ctx_pages: int):
         fn = self._verify_fns.get(ctx_pages)
@@ -1423,7 +1460,7 @@ class TPUEngine:
         Position j's sample is the model's true next token given the chunk
         prefix up to j — the host accepts drafts while they agree. Returns
         ([B, K] sampled tokens, kv)."""
-        logits, kv = prefill_with_history(
+        logits, kv, *_ = self._family.prefill_with_history(
             params, self.model_config, tokens, positions, kv, slot_ids,
             ctx_pages=ctx_pages,
             paged_impl=self._paged_impl("spec_verify", kv), mesh=self.mesh)
@@ -1460,7 +1497,10 @@ class TPUEngine:
 
         Returns ((tokens [k, B], valid [k, B] bool, done [B] bool), kv):
         valid[j, b] marks a token the host should emit; done[b] is the
-        device's end-of-stream verdict, retired in ONE readback."""
+        device's end-of-stream verdict, retired in ONE readback. A family
+        whose step functions count on the device (``STEP_AUX``) appends
+        those counts, summed over the k sub-steps, as a fourth element of
+        the same readback."""
         # k is partial-bound by _decode_fn so the scan length is part of
         # the executable identity (adaptive K); the self._k fallback
         # serves direct (unjitted) callers in tests
@@ -1481,14 +1521,11 @@ class TPUEngine:
             # and a done row never writes its terminal token's KV
             # (exactly the serial engine, which never re-dispatches a
             # finished request)
-            logits, step_kv = decode_step(params, self.model_config,
-                                          step_tokens, step_positions,
-                                          step_kv, slot_ids, step_lens,
-                                          ctx_pages=ctx_pages,
-                                          write_mask=(active & prev_valid
-                                                      & ~done),
-                                          paged_impl=paged_impl,
-                                          mesh=self.mesh)
+            logits, step_kv, *aux = self._family.decode_step(
+                params, self.model_config, step_tokens, step_positions,
+                step_kv, slot_ids, step_lens, ctx_pages=ctx_pages,
+                write_mask=(active & prev_valid & ~done),
+                paged_impl=paged_impl, mesh=self.mesh)
             sampled = sample_tokens(logits, sampling, step_key)
             valid = active & ~done & (j < budgets)
             hit_stop = jnp.any(sampled[:, None] == stop_tbl, axis=1)
@@ -1497,15 +1534,16 @@ class TPUEngine:
                                        step_positions)
             next_lens = jnp.where(valid, step_lens + 1, step_lens)
             return ((sampled, next_positions, next_lens, done, valid,
-                     step_kv), (sampled, valid))
+                     step_kv), (sampled, valid, *aux))
 
         B = tokens.shape[0]
         keys = jax.random.split(key, k)
         carry0 = (tokens, positions, seq_lens,
                   jnp.zeros((B,), dtype=bool), active, kv)
-        (_, _, _, done, _, kv), (all_tokens, all_valid) = jax.lax.scan(
+        (_, _, _, done, _, kv), (all_tokens, all_valid, *aux) = jax.lax.scan(
             step, carry0, (jnp.arange(k), keys))
-        return (all_tokens, all_valid, done), kv
+        return (all_tokens, all_valid, done,
+                *(a.sum(axis=0) for a in aux)), kv
 
     def _decode_and_sample_fb(self, params, kv, prev_block, positions,
                               slot_ids, seq_lens, budgets, stop_tbl,
@@ -2391,13 +2429,15 @@ class TPUEngine:
                 self.params, self.kv, tokens, positions,
                 slot_ids, last_idx, sampling, key)
         with tl.span("prefill.sync", seq, kind) as sync:
-            first_host = jax.device_get(first)  # lint: allow[host-sync-in-hot-path] first-token fetch: prefill result feeds host-side admission
+            first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] first-token fetch: prefill result feeds host-side admission
+        counts = self._step_counts(aux_host)
         elapsed_ms = (sync.t1 - build.t0) * 1000
         self.stats.prefill_ms_total += elapsed_ms
         self.stats.prefill_batches += 1
         self.stats.prefill_requests += len(admitted)
         width = int(tokens.shape[0])  # the dispatched pad
-        tl.step(seq, kind, width, len(admitted), bucket, dispatch.t0, sync.t1)
+        tl.step(seq, kind, width, len(admitted), bucket, dispatch.t0, sync.t1,
+                counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
                           width=width, dur_ms=elapsed_ms,
                           tokens=len(admitted), bucket=bucket)
@@ -2484,12 +2524,14 @@ class TPUEngine:
                 self.params, self.kv, tokens, positions,
                 slot_ids, last_idx, sampling, key)
         with tl.span("prefill.sync", seq, "chunk") as sync:
-            first_host = jax.device_get(first)  # lint: allow[host-sync-in-hot-path] chunk-round boundary: host decides next chunk from these tokens
+            first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] chunk-round boundary: host decides next chunk from these tokens
+        counts = self._step_counts(aux_host)
         elapsed_ms = (sync.t1 - build.t0) * 1000
         self.stats.prefill_batches += 1
         self.stats.prefill_ms_total += elapsed_ms
         width = int(tokens.shape[0])
-        tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1)
+        tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1,
+                counts)
         self._record_step(
             "chunk_prefill", seq=seq, batch=len(batch), width=width,
             dur_ms=elapsed_ms,
@@ -2896,7 +2938,7 @@ class TPUEngine:
             self._sync_tables()
         with tl.span("decode.dispatch", seq, kind) as dispatch:
             if feed is None:
-                (block_tokens, block_valid, block_done), self.kv = \
+                (block_tokens, block_valid, block_done, *block_aux), self.kv = \
                     self._decode_fn(ctx_pages, B)(
                         self.params, self.kv, jnp.asarray(tokens),
                         jnp.asarray(positions),
@@ -2904,7 +2946,7 @@ class TPUEngine:
                         jnp.asarray(seq_lens), jnp.asarray(budget_arr),
                         jnp.asarray(stop_tbl), sampling, key)
             else:
-                (block_tokens, block_valid, block_done), self.kv = \
+                (block_tokens, block_valid, block_done, *block_aux), self.kv = \
                     self._decode_fb_fn(ctx_pages, B)(
                         self.params, self.kv, feed["block"],
                         jnp.asarray(positions),
@@ -2946,7 +2988,8 @@ class TPUEngine:
         self.stats.decode_steps += k
         self.stats.decode_dispatches += 1
         return {"block": block_tokens, "valid": block_valid,
-                "done": block_done, "budgets": budgets, "reqs": reqs,
+                "done": block_done, "aux": block_aux,
+                "budgets": budgets, "reqs": reqs,
                 "truncated": truncated, "B": B, "k": k,
                 "ctx_pages": ctx_pages, "batch": len(reqs), "seq": seq,
                 "kind": kind, "t_dispatched": dispatch.t0, "gap_s": gap_s,
@@ -3022,8 +3065,10 @@ class TPUEngine:
         tl = self.timeline
         seq, kind = inflight["seq"], inflight["kind"]
         with tl.span("decode.readback", seq, kind) as readback:
-            block_host, valid_host, done_host = jax.device_get(  # lint: allow[host-sync-in-hot-path] retire-side read-back — the ONE host sync per K-token super-step, overlapped by the in-flight dispatch
-                (inflight["block"], inflight["valid"], inflight["done"]))
+            block_host, valid_host, done_host, *aux_host = jax.device_get(  # lint: allow[host-sync-in-hot-path] retire-side read-back — the ONE host sync per K-token super-step, overlapped by the in-flight dispatch
+                (inflight["block"], inflight["valid"], inflight["done"],
+                 *inflight.get("aux", ())))
+        counts = self._step_counts(aux_host)
         t_dispatched, t_retired = inflight["t_dispatched"], readback.t1
         decode_elapsed_ms = (t_retired - t_dispatched) * 1000
         # the per-step wall: under the depth-2 pipeline this step was
@@ -3034,7 +3079,7 @@ class TPUEngine:
         step_wall_ms = (t_retired - max(t_dispatched,
                                         tl.last_retired or 0.0)) * 1000
         tl.step(seq, kind, inflight["B"], inflight["batch"],
-                inflight["ctx_pages"], t_dispatched, t_retired)
+                inflight["ctx_pages"], t_dispatched, t_retired, counts)
         self.stats.decode_ms_total += step_wall_ms
         decode_emitted = 0
         with tl.span("decode.emit", seq, kind) as emit:
@@ -3212,6 +3257,19 @@ class TPUEngine:
         """Warmup/serving XLA compile counts + timings (admin surfaces,
         pool status, support bundle)."""
         return self.compile_tracker.snapshot()
+
+    def _step_counts(self, aux: list) -> StepCounts | None:
+        """What a step program counted on the device, from what it returned
+        beside its tokens in the one readback: nothing (``aux`` empty: the
+        GQA family), or one vector ``[moe_tokens, moe_local_pairs, summed
+        selected / context share, rows]`` (``STEP_AUX``). The counts go to
+        ``EngineStats`` and onto the step's timeline record."""
+        if not aux:
+            return None
+        moe_tokens, pairs, share_sum, rows = (float(v) for v in aux[0])
+        self.stats.moe_tokens += int(moe_tokens)
+        self.stats.moe_local_pairs += int(pairs)
+        return StepCounts(share_sum / rows if rows else 0.0, moe_tokens, pairs)
 
     def _record_step(self, kind: str, *, seq: int, batch: int, width: int,
                      dur_ms: float, tokens: int, bucket: int | None = None,

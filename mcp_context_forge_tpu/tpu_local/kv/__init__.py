@@ -2,6 +2,11 @@
 
 from .paged_cache import (
     PagedKVState,
+    LatentKVState,
+    PoolSpec,
+    kv_pools,
+    write_latent_kv,
+    gather_pool,
     PageAllocator,
     PrefixEvictionPolicy,
     init_kv_state,
@@ -15,7 +20,8 @@ from .paged_cache import (
 from .prefix_index import PrefixIndex, chain_hash, chain_hashes
 from .tiers import SpilledPage, TierClient, TieredPageStore
 
-__all__ = ["PagedKVState", "PageAllocator", "PrefixEvictionPolicy",
+__all__ = ["PagedKVState", "LatentKVState", "PoolSpec", "kv_pools",
+           "write_latent_kv", "gather_pool", "PageAllocator", "PrefixEvictionPolicy",
            "init_kv_state", "kv_page_bytes",
            "num_pages_for_budget", "write_prefill_kv", "write_decode_kv",
            "gather_kv", "kv_logical",

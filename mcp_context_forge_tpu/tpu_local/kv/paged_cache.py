@@ -10,6 +10,13 @@ every step.
 
 Page 0 is reserved as the trash page: masked/padding writes land there.
 
+What a page holds a token is the model family's to declare (``kv_pools``):
+the GQA trunk keeps ``k`` and ``v`` of ``[KV, hd]`` (``PagedKVState``, below,
+with every rule of this docstring); the latent family keeps ``latent``
+(``c || k_r``) and the selector's ``index_key``, one vector each
+(``LatentKVState``). Both ride the ONE block table and the ONE
+``PageAllocator``, which deals in page ids and knows nothing of the pools.
+
 The layout is token-major on purpose: ``(KV, hd)`` are the two minor dims,
 so one token's kv heads are one contiguous tile and a token write (decode,
 prefill scatter) is one whole-tile update. Head-major pages
@@ -36,12 +43,13 @@ earlier tokens of that row.
 
 from __future__ import annotations
 
+import math
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ..models.configs import LlamaConfig
+from ..models.configs import DeepseekConfig, LlamaConfig
 from ..quantize import KV_SCALE_EPS, kv_dequantize, kv_int8_scale, kv_quantize
 
 
@@ -72,21 +80,78 @@ class PagedKVState(NamedTuple):
         return self.k_scales is not None
 
 
-def kv_logical(quant: str = "") -> PagedKVState:
+class PoolSpec(NamedTuple):
+    """One per-token pool of the cache: ``[L, num_pages, page, *shape]``."""
+    name: str
+    shape: tuple[int, ...]
+
+
+def kv_pools(config: LlamaConfig | DeepseekConfig) -> tuple[PoolSpec, ...]:
+    """The pools a family's cache holds, in the order of its state's fields."""
+    if isinstance(config, DeepseekConfig):
+        return (PoolSpec("latent", (config.latent_dim,)),
+                PoolSpec("index_key", (config.index_head_dim,)))
+    heads = (config.n_kv_heads, config.head_dim)
+    return (PoolSpec("k", heads), PoolSpec("v", heads))
+
+
+class LatentKVState(NamedTuple):
+    """Device state of the latent family: one attention vector and one
+    selector key a token a layer, shared by all heads (nothing to shard over
+    ``model``; full precision only)."""
+
+    latent_pages: jax.Array   # [L, num_pages, page_size, kv_lora_rank + rope]
+    index_pages: jax.Array    # [L, num_pages, page_size, index_head_dim]
+    block_tables: jax.Array   # [slots, max_pages_per_slot] int32
+
+    @property
+    def page_size(self) -> int:
+        return self.latent_pages.shape[2]
+
+    @property
+    def max_context(self) -> int:
+        return self.block_tables.shape[1] * self.page_size
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+
+def _latent_only(config, quant: str) -> bool:
+    latent = isinstance(config, DeepseekConfig)
+    if latent and quant:
+        raise NotImplementedError(
+            f"kv_quant={quant!r}: the latent pools are full precision only "
+            f"(no per-page scale rule for a vector all heads share yet)")
+    return latent
+
+
+def kv_logical(quant: str = "",
+               config: LlamaConfig | DeepseekConfig | None = None
+               ) -> PagedKVState | LatentKVState:
     """Logical sharding names for the state tree."""
+    if _latent_only(config, quant):
+        return LatentKVState(latent_pages="latent_pages",
+                             index_pages="latent_pages",
+                             block_tables="replicated")
     scales = "kv_scales" if quant == "int8" else None
     return PagedKVState(k_pages="kv_pages", v_pages="kv_pages",
                         block_tables="replicated",
                         k_scales=scales, v_scales=scales)
 
 
-def init_kv_state(config: LlamaConfig, num_pages: int, page_size: int,
+def init_kv_state(config: LlamaConfig | DeepseekConfig, num_pages: int, page_size: int,
                   max_slots: int, max_pages_per_slot: int,
                   dtype: jnp.dtype = jnp.bfloat16,
-                  quant: str = "") -> PagedKVState:
+                  quant: str = "") -> PagedKVState | LatentKVState:
+    tables = jnp.zeros((max_slots, max_pages_per_slot), dtype=jnp.int32)
+    if _latent_only(config, quant):
+        latent, index_key = (
+            jnp.zeros((config.n_layers, num_pages, page_size, *pool.shape),
+                      dtype=dtype) for pool in kv_pools(config))
+        return LatentKVState(latent, index_key, tables)
     shape = (config.n_layers, num_pages, page_size, config.n_kv_heads,
              config.head_dim)
-    tables = jnp.zeros((max_slots, max_pages_per_slot), dtype=jnp.int32)
     if quant == "int8":
         scale_shape = (config.n_layers, num_pages, config.n_kv_heads)
         return PagedKVState(
@@ -103,12 +168,14 @@ def init_kv_state(config: LlamaConfig, num_pages: int, page_size: int,
     )
 
 
-def kv_page_bytes(config: LlamaConfig, page_size: int,
+def kv_page_bytes(config: LlamaConfig | DeepseekConfig, page_size: int,
                   dtype: jnp.dtype = jnp.bfloat16, quant: str = "") -> int:
-    """HBM bytes ONE page (K and V, all layers) costs under a storage
-    mode — the unit _init_kv's byte-denominated budget divides by."""
-    elems = (2 * config.n_layers * page_size * config.n_kv_heads
-             * config.head_dim)
+    """HBM bytes ONE page (every pool the family declares, all layers)
+    costs under a storage mode — the unit _init_kv's byte-denominated
+    budget divides by."""
+    _latent_only(config, quant)
+    elems = config.n_layers * page_size * sum(
+        math.prod(pool.shape) for pool in kv_pools(config))
     if quant == "int8":
         scale_bytes = (2 * config.n_layers * config.n_kv_heads
                        * jnp.dtype(dtype).itemsize)
@@ -116,7 +183,7 @@ def kv_page_bytes(config: LlamaConfig, page_size: int,
     return elems * jnp.dtype(dtype).itemsize
 
 
-def num_pages_for_budget(config: LlamaConfig, page_size: int,
+def num_pages_for_budget(config: LlamaConfig | DeepseekConfig, page_size: int,
                          budget_bytes: int, dtype: jnp.dtype = jnp.bfloat16,
                          quant: str = "") -> int:
     """Pages a fixed HBM byte budget holds under a storage mode (~2x under
@@ -277,6 +344,49 @@ def gather_kv(kv: PagedKVState, layer: int, slot_ids: jax.Array,
         v = kv_dequantize(v, vs, dt)
     B, P, page, KV, hd = k.shape
     return k.reshape(B, P * page, KV, hd), v.reshape(B, P * page, KV, hd)
+
+
+def _token_pages(kv, slot_ids: jax.Array, positions: jax.Array,
+                 valid: jax.Array | None) -> tuple[jax.Array, jax.Array]:
+    """(page id, offset in page) of each position of each row; positions
+    [B] or [B, S]. Rows or tokens that are not ``valid`` get the trash page."""
+    page_size = kv.page_size
+    rows = kv.block_tables[slot_ids]                        # [B, P]
+    slot = positions // page_size
+    pages = jnp.take_along_axis(
+        rows, slot if slot.ndim == 2 else slot[:, None], axis=1)
+    pages = pages.reshape(positions.shape)
+    offset = positions % page_size
+    if valid is not None:
+        pages = jnp.where(valid, pages, 0)
+        offset = jnp.where(valid, offset, 0)
+    return pages, offset
+
+
+def write_latent_kv(kv: LatentKVState, layer: int, latent: jax.Array,
+                    index_key: jax.Array, slot_ids: jax.Array,
+                    positions: jax.Array,
+                    valid: jax.Array | None = None) -> LatentKVState:
+    """Scatter tokens' latent vectors and selector keys into their pages: a
+    [B, S] block (prefill, chunk rounds; ``valid`` [B, S]) or one token a slot
+    (decode; positions and ``valid`` [B], False rows MUST be masked for the
+    reason ``write_decode_kv`` gives). latent: [..., latent_dim]; index_key:
+    [..., index_head_dim]."""
+    pages, offset = _token_pages(kv, slot_ids, positions, valid)
+    pages, offset = pages.reshape(-1), offset.reshape(-1)
+    flat = lambda a, pool: a.reshape(-1, a.shape[-1]).astype(pool.dtype)
+    return kv._replace(
+        latent_pages=kv.latent_pages.at[layer, pages, offset].set(
+            flat(latent, kv.latent_pages), mode="drop"),
+        index_pages=kv.index_pages.at[layer, pages, offset].set(
+            flat(index_key, kv.index_pages), mode="drop"))
+
+
+def gather_pool(pages: jax.Array, layer: int, tables: jax.Array) -> jax.Array:
+    """One pool's context of each row: pages [L, N, page, d], tables [B, P]
+    -> [B, P * page, d] (the jnp reference path; the kernels walk the table)."""
+    ctx = pages[layer][tables]                              # [B, P, page, d]
+    return ctx.reshape(ctx.shape[0], -1, ctx.shape[-1])
 
 
 class PrefixEvictionPolicy:

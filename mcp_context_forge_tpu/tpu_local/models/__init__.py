@@ -1,6 +1,32 @@
-"""Model zoo: Llama-3-class decoder (chat) + small encoder (embeddings /
-moderation classifier), pure-pytree params for pjit."""
+"""Model zoo: decoder families (chat) + small encoder (embeddings /
+moderation classifier), pure-pytree params for pjit.
 
-from .configs import LlamaConfig, EncoderConfig, MODEL_CONFIGS, ENCODER_CONFIGS
+A decoder family is one module (``llama``: GQA + RoPE + SwiGLU/Mixtral
+experts; ``deepseek``: latent attention, sparse selector, shared + routed
+experts) with the same set of names: ``init_keys``, ``init_layer``,
+``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
+``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
+``kv_logical`` / ``kv_page_bytes``, the kernel choices ``prefill_impl`` /
+``paged_impl``, and ``refusals`` (engine settings the family cannot serve
+yet). The engine finds the module from the model config's CLASS
+(:func:`family_of`): nothing else chooses it."""
 
-__all__ = ["LlamaConfig", "EncoderConfig", "MODEL_CONFIGS", "ENCODER_CONFIGS"]
+from importlib import import_module
+from types import ModuleType
+
+from .configs import (DeepseekConfig, EncoderConfig, LlamaConfig,
+                      ENCODER_CONFIGS, MODEL_CONFIGS)
+
+_FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek"}
+
+
+def family_of(model_config) -> ModuleType:
+    """The family module that serves ``model_config``, by its class."""
+    name = _FAMILY_MODULES.get(type(model_config))
+    if name is None:
+        raise TypeError(f"no model family for {type(model_config).__name__}")
+    return import_module(f"{__name__}.{name}")
+
+
+__all__ = ["LlamaConfig", "DeepseekConfig", "EncoderConfig", "MODEL_CONFIGS",
+           "ENCODER_CONFIGS", "family_of"]

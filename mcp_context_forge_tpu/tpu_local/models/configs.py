@@ -63,7 +63,72 @@ class LlamaConfig:
         return float(self.dim) ** 0.5 if self.embed_scale else 1.0
 
 
-MODEL_CONFIGS: dict[str, LlamaConfig] = {
+@dataclass(frozen=True)
+class DeepseekConfig:
+    """Geometry for the latent-attention + learned-sparse-selector + shared/
+    routed-expert family (DeepSeek-V3.2 ``config.json`` keys in brackets).
+
+    Attention goes through low-rank latents: the cache holds one
+    ``kv_lora_rank + qk_rope_head_dim`` vector a token a layer, shared by all
+    heads, plus the selector's ``index_head_dim`` key. The first
+    ``n_dense_layers`` [first_k_dense_replace] layers carry a dense SwiGLU of
+    ``ffn_hidden`` [intermediate_size]; the rest route over ``n_routed_experts``
+    (sigmoid scores, bias-corrected group-limited top-k) beside
+    ``n_shared_experts`` always-on ones of ``moe_ffn_hidden``
+    [moe_intermediate_size]. ``experts_held`` is the half-open range of routed
+    experts THIS engine computes: routing is over all of them, a pair that
+    lands outside the range adds nothing here (its chip of an expert-parallel
+    deployment would). ``moe_impl``/``moe_block`` as in :class:`LlamaConfig`;
+    steps narrower than ``moe_block`` tokens take the expert scan."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    ffn_hidden: int
+    moe_ffn_hidden: int
+    n_routed_experts: int
+    experts_held: tuple[int, int]
+    n_dense_layers: int = 1
+    n_shared_experts: int = 1
+    moe_top_k: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    rope_theta: float = 10_000.0
+    rope_factor: float = 40.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    max_seq_len: int = 163_840
+    moe_impl: str = "grouped_pallas"
+    moe_block: int = 128
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def latent_dim(self) -> int:
+        """What the cache holds a token a layer for attention: c || k_r."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def ffn_kind(self, layer: int) -> str:
+        return "dense" if layer < self.n_dense_layers else "experts"
+
+
+MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -132,6 +197,21 @@ MODEL_CONFIGS: dict[str, LlamaConfig] = {
         norm_eps=1e-6, max_seq_len=512, tie_embeddings=True,
         head_dim_override=32, hidden_act="gelu", embed_scale=True,
         norm_plus_one=True),
+    # latent attention + sparse selector + shared/routed experts at CI
+    # scale: 3 layers (1 dense), 16 experts in 4 groups of which 2 are kept,
+    # top-4, 4 held here; index_topk 8 so that the selected set is a strict
+    # subset at test lengths; 16 selector heads so that no two index scores
+    # tie (few heads leave rows of exact zeros behind the ReLU); the grouped
+    # experts through XLA (the kernel interprets slowly off the chip)
+    "deepseek-test": DeepseekConfig(
+        name="deepseek-test", vocab_size=512, dim=64, n_layers=3, n_heads=4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=16, index_n_heads=16,
+        index_head_dim=16, index_topk=8, ffn_hidden=128, moe_ffn_hidden=32,
+        n_routed_experts=16, experts_held=(0, 4), n_dense_layers=1,
+        n_shared_experts=1, moe_top_k=4, n_group=4, topk_group=2,
+        rope_factor=4.0, rope_original_max=64, max_seq_len=512,
+        moe_impl="grouped", moe_block=8),
 }
 
 

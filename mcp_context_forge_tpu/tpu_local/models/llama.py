@@ -28,8 +28,11 @@ import jax
 import jax.numpy as jnp
 
 from .configs import LlamaConfig
-from ..ops.attention import causal_attention
-from ..kv.paged_cache import PagedKVState, write_prefill_kv, write_decode_kv, gather_kv
+from ..ops.attention import (causal_attention, select_paged_attention,
+                             select_prefill_attention)
+from ..kv.paged_cache import (PagedKVState, write_prefill_kv, write_decode_kv,  # noqa: F401 (family names)
+                              gather_kv, init_kv_state, kv_logical,
+                              kv_page_bytes)
 from ..quantize import embed_rows, qmm, qmm_t
 
 
@@ -70,8 +73,38 @@ def _dense(key: jax.Array, shape: tuple[int, ...], fan_in: int,
             * (1.0 / math.sqrt(fan_in))).astype(dtype)
 
 
+# ------------------------------------------------ what the engine looks up
+# (models/__init__.py: the names every decoder family gives)
+
+STEP_AUX = False    # step functions return (logits, kv), no counts beside them
+
+
+def layer_kind(config: LlamaConfig, layer: int) -> str:
+    """Every layer of this family has the same tree."""
+    return "block"
+
+
+def prefill_impl(impl: str, mesh, seq: int, config: LlamaConfig,
+                 itemsize: int = 2) -> str:
+    return select_prefill_attention(impl, mesh, seq, config.head_dim,
+                                    config.n_kv_heads, itemsize)
+
+
+def paged_impl(mesh, config: LlamaConfig, kv: PagedKVState) -> str:
+    return select_paged_attention(mesh, config.head_dim, kv.page_size,
+                                  config.n_kv_heads, kv.quantized)
+
+
+def refusals(config: LlamaConfig, engine_config, mesh,
+             tiers: bool) -> list[str]:
+    """Engine settings the family cannot serve (none beyond the engine's own
+    checks)."""
+    return []
+
+
 def init_layer(config: LlamaConfig, key: jax.Array,
-               dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
+               dtype: jnp.dtype = jnp.bfloat16,
+               kind: str = "block") -> dict[str, Any]:
     """One decoder layer's random weights. Every layer has the same shapes,
     so a caller that jits this compiles it once (engine._init_params)."""
     hd = config.head_dim

@@ -51,46 +51,67 @@ from jax.experimental.pallas import tpu as pltpu
 
 def route_sorted_blocks(probs: jax.Array, top_k: int, block: int
                         ) -> dict[str, jax.Array]:
-    """Static-shape block-sparse routing plan from router probabilities.
-
-    Returns:
-      sorted_token  [NP]  flat-token index feeding each padded row
-      row_valid     [NP]  1.0 for live rows, 0.0 for group padding
-      gates         [NP]  renormalized gate of the (token, slot) pair
-      block_expert  [NB]  owning expert of each row-block
-      (NP = NB·block; NB = ceil(T·k/block) + E — both static)
-    """
-    T, E = probs.shape
-    N = T * top_k
-    NB = -(-N // block) + E
-    NP = NB * block
-
+    """Static-shape block-sparse routing plan from router probabilities:
+    the top ``top_k`` of each row, gates renormalized to sum to one. See
+    :func:`plan_sorted_blocks` for what it returns."""
     _, top_idx = jax.lax.top_k(probs, top_k)                   # [T, k]
     gates = jnp.take_along_axis(probs, top_idx, axis=1)        # [T, k]
     gates = gates / jnp.maximum(
         jnp.sum(gates, axis=-1, keepdims=True), 1e-9)          # renorm
+    return plan_sorted_blocks(top_idx, gates, probs.shape[1], block)
 
-    expert_flat = top_idx.reshape(N)                           # [N]
+
+def plan_sorted_blocks(expert_ids: jax.Array, gates: jax.Array,
+                       n_experts: int, block: int) -> dict[str, jax.Array]:
+    """Static-shape block-sparse plan from routing CHOICES: ids and weights,
+    however the router made them (softmax top-k, sigmoid group-limited, ...).
+
+    ``expert_ids`` [T, k] index the ``n_experts`` expert stacks held HERE; an
+    id outside ``[0, n_experts)`` is a pair routed to an expert some other
+    holder computes (expert parallelism's share) and gets no row. ``gates``
+    [T, k] are the pairs' final weights.
+
+    Returns:
+      sorted_token  [NP]  flat-token index feeding each padded row
+      row_valid     [NP]  1.0 for live rows, 0.0 for group padding
+      gates         [NP]  weight of the (token, slot) pair
+      block_expert  [NB]  owning expert of each row-block
+      live_blocks   [1]   blocks that hold a live row (the rest is padding
+                          past every group: nothing to compute or fetch)
+      (NP = NB·block; NB = ceil(T·k/block) + E — both static: every pair
+      may land here)
+    """
+    T, top_k = expert_ids.shape
+    E = n_experts
+    N = T * top_k
+    NB = -(-N // block) + E
+    NP = NB * block
+
+    expert_flat = expert_ids.reshape(N).astype(jnp.int32)      # [N]
+    here = (expert_flat >= 0) & (expert_flat < E)
+    expert_flat = jnp.where(here, expert_flat, E)              # E: elsewhere
     token_flat = jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k)
     gate_flat = gates.reshape(N)
 
     order = jnp.argsort(expert_flat, stable=True)              # [N]
     sorted_expert = expert_flat[order]
-    counts = jnp.bincount(sorted_expert, length=E)             # [E]
+    counts = jnp.bincount(sorted_expert, length=E + 1)[:E]     # [E]
     group_start = jnp.cumsum(counts) - counts                  # [E]
     padded_counts = -(-counts // block) * block
     padded_start = jnp.cumsum(padded_counts) - padded_counts   # [E]
     # padded destination of sorted row j: its rank within the group,
-    # offset by the group's padded start
+    # offset by the group's padded start; pairs held elsewhere sort last
+    # and are dropped (destination past the buffer)
     j = jnp.arange(N)
-    rank = j - group_start[sorted_expert]
-    dest = padded_start[sorted_expert] + rank                  # [N] < NP
+    local = jnp.minimum(sorted_expert, E - 1)
+    rank = j - group_start[local]
+    dest = jnp.where(sorted_expert < E, padded_start[local] + rank, NP)
 
     sorted_token = jnp.zeros((NP,), jnp.int32).at[dest].set(
-        token_flat[order])
-    row_valid = jnp.zeros((NP,), jnp.float32).at[dest].set(1.0)
+        token_flat[order], mode="drop")
+    row_valid = jnp.zeros((NP,), jnp.float32).at[dest].set(1.0, mode="drop")
     gates_padded = jnp.zeros((NP,), jnp.float32).at[dest].set(
-        gate_flat[order])
+        gate_flat[order].astype(jnp.float32), mode="drop")
 
     # owning expert per block: block b starts at row b·block; an expert
     # owns it iff padded_start[e] <= b·block < padded_start[e]+padded.
@@ -101,8 +122,10 @@ def route_sorted_blocks(probs: jax.Array, top_k: int, block: int
     block_expert = jnp.clip(
         jnp.searchsorted(padded_end, block_starts, side="right"),
         0, E - 1).astype(jnp.int32)
+    live_blocks = (padded_end[-1:] // block).astype(jnp.int32)
     return {"sorted_token": sorted_token, "row_valid": row_valid,
-            "gates": gates_padded, "block_expert": block_expert}
+            "gates": gates_padded, "block_expert": block_expert,
+            "live_blocks": live_blocks}
 
 
 def _act(h: jax.Array, act: str) -> jax.Array:
@@ -153,22 +176,27 @@ def _dot(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _moe_block_kernel(block_expert_ref, x_ref, w1_ref, w3_ref, w2_ref,
-                      o_ref, acc_ref, *, act: str):
+def _moe_block_kernel(block_expert_ref, live_ref, x_ref, w1_ref, w3_ref,
+                      w2_ref, o_ref, acc_ref, *, act: str):
     """One (row-block, F-tile) step: h = act(x@w1_f) * (x@w3_f); the
     [Bt, D] output accumulates h @ w2_f in VMEM scratch across F-tiles.
     The expert's weight tiles arrive via the BlockSpec index maps reading
     the scalar-prefetched ``block_expert`` — the kernel body never
-    gathers. Operands go to the MXU in their stored dtype; sums are f32."""
-    f = pl.program_id(1)
+    gathers. Operands go to the MXU in their stored dtype; sums are f32.
+    A block past ``live_ref[0]`` is padding beyond every group: it
+    computes nothing, its index maps ask for the tiles already resident
+    (no fetch), and it writes zeros."""
+    b, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(f == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                                    # [Bt, D]
-    h = _act(_dot(x, w1_ref[...]), act) * _dot(x, w3_ref[...])   # [Bt, Ft]
-    acc_ref[...] += _dot(h.astype(x.dtype), w2_ref[...])         # [Bt, D]
+    @pl.when(b < live_ref[0])
+    def _compute():
+        x = x_ref[...]                                # [Bt, D]
+        h = _act(_dot(x, w1_ref[...]), act) * _dot(x, w3_ref[...])  # [Bt, Ft]
+        acc_ref[...] += _dot(h.astype(x.dtype), w2_ref[...])       # [Bt, D]
 
     @pl.when(f == pl.num_programs(1) - 1)
     def _finish():
@@ -178,22 +206,45 @@ def _moe_block_kernel(block_expert_ref, x_ref, w1_ref, w3_ref, w2_ref,
 @functools.partial(jax.jit, static_argnames=("act", "block", "interpret"))
 def _expert_blocks_pallas(x_pad: jax.Array, w1: jax.Array, w3: jax.Array,
                           w2: jax.Array, block_expert: jax.Array,
+                          live_blocks: jax.Array | None = None,
                           act: str = "silu", block: int = 128,
                           interpret: bool = False) -> jax.Array:
     NB = block_expert.shape[0]
     D = x_pad.shape[-1]
     f_tile, f_tiles = _f_tiles(w1.shape[-1])
+    if live_blocks is None:
+        live_blocks = jnp.full((1,), NB, jnp.int32)
+
+    def rows(b, f, be, live):
+        return (jnp.minimum(b, jnp.maximum(live[0] - 1, 0)), 0, 0)
+
+    def tile_of(b, f, be, live):
+        """(expert, F-tile) a step reads: its own while live, else the last
+        live step's, which is resident."""
+        dead = b >= live[0]
+        last = jnp.maximum(live[0] - 1, 0)
+        return (be[jnp.where(dead, last, b)],
+                jnp.where(dead, f_tiles - 1, f))
+
+    def up(b, f, be, live):
+        e, ft = tile_of(b, f, be, live)
+        return (e, 0, ft)
+
+    def down(b, f, be, live):
+        e, ft = tile_of(b, f, be, live)
+        return (e, ft, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                 # block_expert
+        num_scalar_prefetch=2,                 # block_expert, live_blocks
         grid=(NB, f_tiles),
         in_specs=[
-            pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
-            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((None, D, f_tile), lambda b, f, be: (be[b], 0, f)),
-            pl.BlockSpec((None, f_tile, D), lambda b, f, be: (be[b], f, 0)),
+            pl.BlockSpec((None, block, D), rows),
+            pl.BlockSpec((None, D, f_tile), up),
+            pl.BlockSpec((None, D, f_tile), up),
+            pl.BlockSpec((None, f_tile, D), down),
         ],
-        out_specs=pl.BlockSpec((None, block, D), lambda b, f, be: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, block, D),
+                               lambda b, f, be, live: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
     )
     return pl.pallas_call(
@@ -204,7 +255,7 @@ def _expert_blocks_pallas(x_pad: jax.Array, w1: jax.Array, w3: jax.Array,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         name="grouped_moe",
         interpret=interpret,
-    )(block_expert, x_pad, w1, w3, w2)
+    )(block_expert, live_blocks, x_pad, w1, w3, w2)
 
 
 def _moe_block_kernel_q8(block_expert_ref, x_ref, q1_ref, s1_ref, q3_ref,
@@ -294,9 +345,21 @@ def moe_ffn_grouped(params: dict[str, Any], x: jax.Array, config,
     flat = x.reshape(-1, D)
     probs = router_probs(params["router"], flat)                # [T, E]
     plan = route_sorted_blocks(probs, config.top_k, block)
+    return experts_grouped(params, flat, plan, act=act, impl=impl,
+                           block=block, interpret=interpret).reshape(B, S, D)
 
+
+def experts_grouped(params: dict[str, Any], flat: jax.Array,
+                    plan: dict[str, jax.Array], act: str = "silu",
+                    impl: str = "xla", block: int = 128,
+                    interpret: bool = False) -> jax.Array:
+    """The held experts' weighted part of every token, from a routing plan
+    (:func:`plan_sorted_blocks`): flat [T, D] -> [T, D]. ``params`` holds the
+    stacks ``w1``/``w3`` [E, D, F] and ``w2`` [E, F, D] of the E experts the
+    plan's ids index."""
+    D = flat.shape[-1]
     x_pad = flat[plan["sorted_token"]]                          # [NP, D]
-    x_pad = x_pad * plan["row_valid"][:, None].astype(x.dtype)
+    x_pad = x_pad * plan["row_valid"][:, None].astype(flat.dtype)
     NB = plan["block_expert"].shape[0]
 
     quantized = isinstance(params["w1"], dict)
@@ -308,17 +371,16 @@ def moe_ffn_grouped(params: dict[str, Any], x: jax.Array, config,
     elif impl == "pallas":
         out_blocks = _expert_blocks_pallas(
             x_pad.reshape(NB, block, D), params["w1"], params["w3"],
-            params["w2"], plan["block_expert"], act=act, block=block,
-            interpret=interpret)
+            params["w2"], plan["block_expert"], plan["live_blocks"],
+            act=act, block=block, interpret=interpret)
     else:
         out_blocks = _expert_blocks_xla(
             x_pad.reshape(NB, block, D), params["w1"], params["w3"],
             params["w2"], plan["block_expert"], act)
     out_rows = out_blocks.reshape(NB * block, D)
     weighted = out_rows * (plan["gates"]
-                           * plan["row_valid"])[:, None].astype(x.dtype)
-    out = jnp.zeros_like(flat).at[plan["sorted_token"]].add(weighted)
-    return out.reshape(B, S, D)
+                           * plan["row_valid"])[:, None].astype(flat.dtype)
+    return jnp.zeros_like(flat).at[plan["sorted_token"]].add(weighted)
 
 
 def grouped_flops(T: int, top_k: int, n_experts: int, dim: int,

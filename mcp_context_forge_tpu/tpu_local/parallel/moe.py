@@ -160,8 +160,6 @@ def moe_ffn_dense_mask(params: dict[str, Any], x: jax.Array,
     Quantized expert stacks work unchanged: the scan slices the [E,...]
     int8/scale leaves into the 2D shapes ``qmm`` handles.
     """
-    from ..quantize import qmm
-
     B, S, D = x.shape
     flat = x.reshape(-1, D)
     probs = router_probs(params["router"], flat)              # [T, E]
@@ -173,6 +171,16 @@ def moe_ffn_dense_mask(params: dict[str, Any], x: jax.Array,
     gates = gates / jnp.maximum(
         jnp.sum(gates, axis=-1, keepdims=True), 1e-9)         # renormalized
     gates = gates.astype(x.dtype)
+    return expert_scan(params, flat, gates, act).reshape(B, S, D)
+
+
+def expert_scan(params: dict[str, Any], flat: jax.Array, gates: jax.Array,
+                act: str = "silu") -> jax.Array:
+    """Every held expert over every token, weighted by its gate column: flat
+    [T, D], gates [T, E] (zero where a token did not choose the expert) ->
+    [T, D]. ``params`` holds the stacks ``w1``/``w3``/``w2`` of the E experts
+    the gate columns name — all of a model's, or the share held here."""
+    from ..quantize import qmm
 
     def one_expert(acc, weights):
         w1, w3, w2, gate_col = weights                        # gate_col [T]
@@ -185,7 +193,7 @@ def moe_ffn_dense_mask(params: dict[str, Any], x: jax.Array,
     out, _ = jax.lax.scan(
         one_expert, jnp.zeros_like(flat),
         (params["w1"], params["w3"], params["w2"], gates.T))
-    return out.reshape(B, S, D)
+    return out
 
 
 def moe_ffn_reference(params: dict[str, Any], x: jax.Array,

@@ -37,6 +37,9 @@ LOGICAL_RULES: dict[str, P] = {
     # int8 KV-page dequant scales (L, pages, kv_heads): shard the kv-head
     # dim with the pages they scale
     "kv_scales": P(None, None, "model"),
+    # the latent family's pools (L, pages, page, d): one vector a token
+    # shared by all heads, so nothing to split over ``model``
+    "latent_pages": P(),
     "activations": P("data", None, None),  # (batch, seq, dim)
     "decode_heads": P("data", None, "model", None),  # (batch, seq, heads, hd)
 }

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 
 from mcp_context_forge_tpu.tpu_local.ops import attention, grouped_moe
+from mcp_context_forge_tpu.tpu_local.ops import mla_attention as mla
 from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -94,6 +95,54 @@ def _moe_case(quantized: bool):
     return fn, [x, *up, *up, *down, owner]
 
 
+# deepseek-v3.2-d5-ep16.longctx-closed: 5 layers, 1152 pages of 128, latent
+# 512 + 64, selector 64 heads x 128, top-2048; chunk rounds of 2 x 1024 and
+# decode steps of 8 rows over the full 128-page table; 16 held experts
+DS_LAYERS, DS_PAGES, DS_TABLE, DS_LATENT, DS_VALUE = 5, 1152, 128, 576, 512
+DS_HEADS, DS_IDX_HEADS, DS_IDX_DIM, DS_TOPK = 128, 64, 128, 2048
+
+
+def _mla_attention_case(chunk: int | None):
+    """Chunk round (``chunk`` queries a row, 2 rows) or decode (8 rows)."""
+    pool = ((DS_LAYERS, DS_PAGES, PAGE, DS_LATENT), jnp.bfloat16)
+    fn = partial(mla.mla_paged_attention_pallas, layer=3, value_dim=DS_VALUE)
+    if chunk is None:
+        B = 8
+        return fn, [((B, 1, DS_HEADS, DS_LATENT), jnp.bfloat16),
+                    ((B, 1, DS_TABLE * PAGE), jnp.float32), pool,
+                    ((B, DS_TABLE), jnp.int32), ((B, 1), jnp.int32)]
+    B = 2
+    return fn, [((B, DS_HEADS, chunk, DS_LATENT), jnp.bfloat16),
+                ((B, chunk, DS_TABLE * PAGE), jnp.float32), pool,
+                ((B, DS_TABLE), jnp.int32),
+                ((B, chunk // mla._ATTN_QUERY_TILE), jnp.int32)]
+
+
+def _index_case():
+    B, S = 2, 1024
+    return (partial(mla.sparse_index_scores_pallas, layer=3),
+            [((B, S, DS_IDX_HEADS, DS_IDX_DIM), jnp.bfloat16),
+             ((B, S, DS_IDX_HEADS), jnp.float32),
+             ((DS_LAYERS, DS_PAGES, PAGE, DS_IDX_DIM), jnp.bfloat16),
+             ((B, DS_TABLE), jnp.int32), ((B, S), jnp.int32)])
+
+
+def _select_case(rows: int):
+    return (partial(mla.sparse_select_pallas, k=DS_TOPK),
+            [((rows, DS_TABLE * PAGE), jnp.float32)])
+
+
+def _held_experts_case():
+    """16 held experts x width 2048 x hidden 7168, a chunk round's plan: every
+    one of 2048 x 8 pairs may land here."""
+    held, dim, width = 16, 7168, 2048
+    n_blocks = 2048 * 8 // BLOCK + held
+    up, down = ((held, dim, width), jnp.bfloat16), ((held, width, dim), jnp.bfloat16)
+    return (partial(grouped_moe._expert_blocks_pallas, block=BLOCK),
+            [((n_blocks, BLOCK, dim), jnp.bfloat16), up, up, down,
+             ((n_blocks,), jnp.int32), ((1,), jnp.int32)])
+
+
 # _history_tile(S, G=4) yields query tiles of 128..512 (and spec-verify
 # chunks of spec_k=4); kv_heads=2 is one shard of a 1x4 TP mesh
 KERNEL_CASES = {
@@ -113,6 +162,12 @@ KERNEL_CASES = {
         jnp.bfloat16, 512, 2, 32),
     "flash_prefill_s512": lambda: _flash_case(512),
     "flash_prefill_s2048": lambda: _flash_case(2048),
+    "mla_attention_cell_decode_8x128": lambda: _mla_attention_case(None),
+    "mla_attention_cell_chunk_2x1024x128": lambda: _mla_attention_case(1024),
+    "sparse_index_cell_chunk_2x1024x128": _index_case,
+    "sparse_select_cell_chunk_rows": lambda: _select_case(2 * 1024),
+    "sparse_select_cell_decode_rows": lambda: _select_case(8),
+    "grouped_moe_cell_16_held_experts": _held_experts_case,
     "grouped_moe_bf16": lambda: _moe_case(False),
     "grouped_moe_int8": lambda: _moe_case(True),
 }
