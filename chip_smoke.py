@@ -332,7 +332,8 @@ def time_paged_kernel(KV: int, G: int, hd: int, page_size: int,
     in one jitted program, as in a step; the host clock around it ends in
     ``block_until_ready``, the fastest of 10 counts. Contexts are drawn
     log-uniform; idle slots and block-table entries past a row's pages are
-    0 like the engine's, so their grid steps fetch nothing. Under
+    0 like the engine's, so their grid steps fetch nothing; a grid step
+    holds ``block_pages`` table pages. Under
     ``interpret`` (the CPU rehearsal) the shapes shrink and the numbers say
     nothing."""
     import jax
@@ -340,7 +341,7 @@ def time_paged_kernel(KV: int, G: int, hd: int, page_size: int,
     import numpy as np
 
     from mcp_context_forge_tpu.tpu_local.ops.paged_attention import (
-        _ROW_BLOCK, paged_chunk_attention_pallas,
+        _ROW_BLOCK, _kv_block_pages, paged_chunk_attention_pallas,
         paged_decode_attention_pallas)
 
     L = 4
@@ -369,6 +370,7 @@ def time_paged_kernel(KV: int, G: int, hd: int, page_size: int,
         if chunk is None:
             q = jax.random.normal(key, (B, KV, G, hd), jnp.float32)
             extra = jnp.asarray(lens, jnp.int32)
+            rows = G
             kernel, live_pages, row_blocks = (
                 paged_decode_attention_pallas, int(held.sum()), 1)
         else:
@@ -402,10 +404,12 @@ def time_paged_kernel(KV: int, G: int, hd: int, page_size: int,
             series(q, k_pages, v_pages).block_until_ready()
             seconds.append(time.perf_counter() - t0)
         us = min(seconds) / calls * 1e6
+        block_pages = _kv_block_pages(table, rows, page_size)
         out[name] = {"us_per_call": round(us, 2),
                      "us_per_live_page": round(us / max(1, live_pages), 3),
                      "live_pages": live_pages,
-                     "grid_steps": B * row_blocks * table}
+                     "block_pages": block_pages,
+                     "grid_steps": B * row_blocks * table // block_pages}
     return out
 
 

@@ -31,6 +31,23 @@ second per-token key for the selector. Three kernels serve a step:
     that spends against the selected set alone is the kernel's roofline share
     in the benchmark (``mla_*_attention_roofline``).
 
+    A grid step holds a KV BLOCK of N consecutive block-table pages (N * page
+    tokens): the pool is passed N times, slot ``i`` fetching the row's table
+    entry ``j * N + i``, so Pallas keeps its one-block-ahead pipeline, N DMAs
+    deep. The step scores the whole block, the block's heads as rows of one
+    matmul against the vectors they share, and makes ONE online-softmax
+    update: the ``[R, 1]`` ``m`` / ``l`` stats and the correction of the f32
+    accumulator (four times a page's score tile) are paid once a block, not
+    once a page. A slot the row block does not reach (a page past its last
+    position inside a live block, a dead block, an idle row) costs no DMA: it
+    keeps the page it last fetched (``_block_entries``; an unchanged block
+    index fetches nothing), and what it then holds is masked by the bias,
+    which is ``NEG_INF`` past every row's position. N comes from what the
+    call can see (``_kv_block_pages``): as many pages as keep a head's f32
+    score tile ``[rows, N * page]`` within ``_ATTN_SCORE_TILE`` entries, at
+    most ``_ATTN_BLOCK_PAGES``, and the largest such number that divides the
+    block-table width (any width is served; a prime one a page a step).
+
 One body serves chunk rounds (a block is some heads x a tile of queries, the
 bias a row a query) and decode (a block is one query's heads, the bias one row
 for all of them).
@@ -52,6 +69,11 @@ _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 _INDEX_QUERY_TILE = 256
 _ATTN_QUERY_TILE = 256
 _ATTN_HEAD_BLOCK = 8
+# pages a grid step of the attention kernel holds (a KV block): as many as
+# keep one head's f32 score tile [rows, pages * page] at this many entries, and
+# never more than _ATTN_BLOCK_PAGES
+_ATTN_SCORE_TILE = 256 * 512
+_ATTN_BLOCK_PAGES = 8
 # rows of scores one grid step of the selection holds whole ([rows, C] f32)
 _SELECT_ROWS = 128
 
@@ -234,13 +256,39 @@ def sparse_select_pallas(scores: jax.Array, k: int,
 
 # ---------------------------------------------------------------- attention
 
-def _mla_kernel(tables_ref, max_pos_ref, q_ref, bias_ref, kv_ref, o_ref,
-                acc_ref, m_ref, l_ref, *, page_size: int, value_dim: int):
-    """Refs: q/o [G, R, Dk] / [G, R, value_dim]; bias [Rb, page] f32 (Rb in
-    (1, R)); kv [1, 1, page, Dk]; scratch acc [G, R, value_dim], m/l [G, R, 1]
-    f32."""
+def _kv_block_pages(n_pages: int, rows: int, page_size: int) -> int:
+    """Pages a grid step holds: see the module docstring."""
+    want = max(1, min(_ATTN_BLOCK_PAGES,
+                      _ATTN_SCORE_TILE // (rows * page_size)))
+    return max(n for n in range(1, want + 1) if n_pages % n == 0)
+
+
+def _block_entries(block_tables, max_pos, n: int, page_size: int):
+    """The pool page every slot of every KV block fetches: block_tables
+    [B, P], max_pos [B, row blocks] -> [B * row blocks, P] int32. Entry
+    ``j * n + i`` (slot ``i`` of block ``j``) is the row's table entry while
+    the row block reaches that page; a slot it does not reach (a dead page
+    inside a live block, a dead block, an idle row) keeps the entry the slot
+    last fetched, in the order the grid walks, so it fetches nothing."""
+    B, P = block_tables.shape
+    row_blocks = max_pos.shape[1]
+    live = (jnp.arange(P, dtype=jnp.int32)
+            <= (max_pos // page_size)[..., None]).reshape(-1, n)
+    entries = jnp.broadcast_to(
+        block_tables[:, None, :], (B, row_blocks, P)).reshape(-1, n)
+    step = jnp.arange(live.shape[0], dtype=jnp.int32)[:, None]
+    fetched = jax.lax.cummax(jnp.where(live, step, 0), axis=0)
+    return jnp.take_along_axis(entries, fetched, axis=0).reshape(-1, P)
+
+
+def _mla_kernel(entries_ref, max_pos_ref, q_ref, bias_ref, *rest,
+                page_size: int, value_dim: int):
+    """Refs: q/o [G, R, Dk] / [G, R, value_dim]; bias [Rb, N * page] f32 (Rb
+    in (1, R)); N kv refs [1, 1, page, Dk], the block's pages in table order;
+    scratch acc [G, R, value_dim], m/l [G, R, 1] f32."""
+    *kv_refs, o_ref, acc_ref, m_ref, l_ref = rest
     b, r, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    n_groups = q_ref.shape[0]
+    n_groups, n_rows, _ = q_ref.shape
 
     @pl.when(j == 0)
     def _init():
@@ -248,29 +296,31 @@ def _mla_kernel(tables_ref, max_pos_ref, q_ref, bias_ref, kv_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(max_pos_ref[b, r] >= j * page_size)
+    @pl.when(max_pos_ref[b, r] >= j * len(kv_refs) * page_size)
     def _process():
-        kv = kv_ref[0, 0]                                  # [page, Dk]
-        values = kv[:, :value_dim]
-        bias = bias_ref[...]
-        live = bias > 0.5 * NEG_INF
-        for g in range(n_groups):
-            scores = jax.lax.dot_general(                  # [R, page]
-                q_ref[g], kv, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) + bias
-            m_prev = m_ref[g]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(scores, axis=1, keepdims=True))
-            correction = jnp.exp(m_prev - m_new)
-            # a page none of whose tokens a row selected leaves m at NEG_INF:
-            # exp(0) there must not count
-            probs = jnp.where(live, jnp.exp(scores - m_new), 0.0)
-            l_ref[g] = (l_ref[g] * correction
-                        + jnp.sum(probs, axis=1, keepdims=True))
-            acc_ref[g] = acc_ref[g] * correction + jnp.dot(
-                probs.astype(values.dtype), values,
-                preferred_element_type=jnp.float32)
-            m_ref[g] = m_new
+        kv = jnp.concatenate([ref[0, 0] for ref in kv_refs])  # [N * page, Dk]
+        block = kv.shape[0]
+        # every head attends the same latent vectors: the block's heads are
+        # rows of ONE matmul against them, and of one against the values
+        scores = jax.lax.dot_general(                  # [G * R, N * page]
+            q_ref[...].reshape(n_groups * n_rows, -1), kv,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        scores = scores.reshape(n_groups, n_rows, block) + bias_ref[...][None]
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
+        correction = jnp.exp(m_prev - m_new)
+        # a block none of whose tokens a row selected leaves m at NEG_INF:
+        # exp(0) there must not count, so the exponent's base stays above
+        # every masked score (NEG_INF + a dot product) and they read 0
+        probs = jnp.exp(scores - jnp.maximum(m_new, 0.5 * NEG_INF))
+        l_ref[...] = (l_ref[...] * correction
+                      + jnp.sum(probs, axis=2, keepdims=True))
+        pv = jnp.dot(
+            probs.astype(kv.dtype).reshape(n_groups * n_rows, block),
+            kv[:, :value_dim], preferred_element_type=jnp.float32)
+        acc_ref[...] = (acc_ref[...] * correction
+                        + pv.reshape(n_groups, n_rows, value_dim))
+        m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
@@ -286,10 +336,11 @@ def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
                                layer: int = 0, value_dim: int = 512,
                                interpret: bool = False) -> jax.Array:
     """q: [B, G, R, Dk] absorbed queries, scale folded in; bias: [B, Rb, P *
-    page] float32, 0 where a row attends and NEG_INF elsewhere, Rb = R (a row
-    each) or 1 (one row for all); latent_pages: [L, N, page, Dk];
-    block_tables: [B, P]; max_pos: [B, R // row tile] int32, the last position
-    any row of the tile attends to (pages past it are skipped)
+    page] float32, 0 where a row attends and NEG_INF elsewhere (so NEG_INF
+    past the row's last position), Rb = R (a row each) or 1 (one row for all);
+    latent_pages: [L, N, page, Dk]; block_tables: [B, P]; max_pos: [B, R //
+    row tile] int32, the last position any row of the tile attends to (blocks
+    past it are skipped, pages past it inside its block not fetched)
     -> [B, G, R, value_dim]."""
     B, G, R, Dk = q.shape
     n_pages = block_tables.shape[1]
@@ -301,20 +352,27 @@ def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
                          f"[{groups}, {rows}]")
     shared = bias.shape[1] == 1 and R > 1
     bias_rows = 1 if shared else rows
+    n = _kv_block_pages(n_pages, rows, page_size)
+    n_tiles = R // rows
+
+    def page_map(slot):
+        return lambda b, g, r, j, en, mp: (
+            layer, en[b * n_tiles + r, j * n + slot], 0, 0)
+
     return pl.pallas_call(
         functools.partial(_mla_kernel, page_size=page_size,
                           value_dim=value_dim),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, G // groups, R // rows, n_pages),
+            grid=(B, G // groups, n_tiles, n_pages // n),
             in_specs=[
                 pl.BlockSpec((None, groups, rows, Dk),
                              lambda b, g, r, j, tb, mp: (b, g, r, 0)),
-                pl.BlockSpec((None, bias_rows, page_size),
+                pl.BlockSpec((None, bias_rows, n * page_size),
                              (lambda b, g, r, j, tb, mp: (b, 0, j)) if shared
                              else (lambda b, g, r, j, tb, mp: (b, r, j))),
-                pl.BlockSpec((1, 1, page_size, Dk),
-                             lambda b, g, r, j, tb, mp: (layer, tb[b, j], 0, 0)),
+                *(pl.BlockSpec((1, 1, page_size, Dk), page_map(slot))
+                  for slot in range(n)),
             ],
             out_specs=pl.BlockSpec((None, groups, rows, value_dim),
                                    lambda b, g, r, j, tb, mp: (b, g, r, 0)),
@@ -329,4 +387,5 @@ def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         name="mla_paged_attention",
         interpret=interpret,
-    )(block_tables, max_pos, q, bias, latent_pages)
+    )(_block_entries(block_tables, max_pos, n, page_size), max_pos, q, bias,
+      *([latent_pages] * n))

@@ -15,30 +15,52 @@ one query position per sequence (``seq_len - 1``).
 The kernel takes the WHOLE pool ``[L, num_pages, page, KV, hd]`` plus a
 static layer index that rides the BlockSpec index map: a per-layer slice
 outside the kernel would make XLA copy that layer's pool before every call.
-A K/V block is one page with ALL its kv heads (the TPU lowering wants the
+A pool block is one page with ALL its kv heads (the TPU lowering wants the
 last two block dims to equal the array's), and the head loop runs inside
 the kernel.
+
+A grid step holds a KV BLOCK of N consecutive block-table pages (N * page
+tokens): each pool is passed N times, slot ``i`` fetching the row's table
+entry ``j * N + i``, so Pallas keeps its one-block-ahead pipeline, 2 N DMAs
+deep. The step scores the whole block a kv head and makes ONE online-softmax
+update: the lane-sparse ``[R, 1]`` ``m`` / ``l`` stats and the accumulator's
+correction are paid once a block, not once a page. A slot the row block
+does not reach (a page past its last position inside a live block, a dead
+block, an idle row) costs no DMA: it keeps the page it last fetched
+(``_block_entries``; an unchanged block index fetches nothing), and what it
+then holds is masked by position. N comes from what the call can see
+(``_kv_block_pages``): as many pages as keep a head's f32 score tile
+``[rows, N * page]`` within ``_SCORE_TILE`` entries, at most
+``_BLOCK_PAGES``, and the largest such number that divides the block-table
+width (any width is served; a prime one a page a step). More DMAs in flight
+do not make a page's DMA faster (470 of 819 GB/s either way: PERF.md, PR
+30); what a block saves is the bookkeeping and the dead grid steps.
 
 Reading ONE head out of that block is the kernel's inner cost. In the
 pool's layout the kv head is the sublane dim (a token's heads are one
 tile), so ``k_ref[:, h, :]`` is one sublane out of each of ``page`` tiles:
 Mosaic realises it as ~18,000 load / unpack / rotate / select / pack
-instructions a page (bf16, 8 heads). ``_heads`` reads the same bytes
+instructions a page (bf16, 8 heads). ``_word_heads`` reads the same bytes
 as sublane-STRIDED loads of 32-bit words instead — the page viewed as
 ``[page * KV / packing, hd]`` words, every ``KV / packing``-th row — and
 splits a word into its 2 (bf16) or 4 (int8) heads with shifts: a tenth of
 the instructions, and the page step is bound by its DMA (PERF.md, PR 25).
+The loop over a token's words is a ``fori_loop`` that the lowering unrolls:
+its body is traced once, not once a head, and a step program traces 32 of
+these kernels on every start (PERF.md, PR 30).
 The pool's layout, its writers, its sharding and the page payload that
 leaves the device are what they were.
 
 Int8 pages (kv/paged_cache.py quant mode) dequantize IN VMEM: the
 per-(page, kv-head) scales arrive in blocks of ``_SCALE_ROWS`` pages
-indexed by the same block-table entry, and apply to the f32 scores and
-the f32 P·V product (one scale per (page, head), so it factors out of
-both matmuls) — the HBM side of attention moves 1 byte/element.
+indexed by the same block-table entry (a scale block a slot), and apply to
+the f32 scores and the f32 probabilities of the page's columns (one scale
+per (page, head), so it factors out of both matmuls) — the HBM side of
+attention moves 1 byte/element.
 
-Grid: (batch, query-row block, page). Scalar prefetch: block tables
-[B, P] and the highest query position per (batch, row block).
+Grid: (batch, query-row block, KV block). Scalar prefetch: the pool page of
+every slot of every block per (batch, row block), and the highest query
+position per (batch, row block).
 """
 
 from __future__ import annotations
@@ -61,85 +83,141 @@ _SCALE_ROWS = 32
 # query rows (positions x group) one grid step holds: bounds the f32
 # accumulator scratch at KV * _ROW_BLOCK * hd * 4 bytes
 _ROW_BLOCK = 256
+# pages a grid step holds (a KV block): as many as keep one head's f32 score
+# tile [rows, pages * page] at this many entries, never more than
+# _BLOCK_PAGES
+_SCORE_TILE = 256 * 512
+_BLOCK_PAGES = 4
 
 
-def _heads(ref, out_dtype):
-    """Yield ``[page, hd]`` of each kv head in turn, as ``out_dtype``, from
-    a page block ``ref`` [1, 1, page, KV, hd].
-
-    Heads that fill whole 32-bit words (f32; bf16 with an even head count;
-    int8 with a multiple of 4) are read as strided word loads, see the
-    module docstring. Mosaic has no strided load of narrower types, so any
-    other geometry takes the per-token sublane gather."""
-    _, _, page, n_kv, hd = ref.shape
+def _word_packed(ref) -> bool:
+    """Whether the kv heads of a page block fill whole 32-bit words (f32;
+    bf16 with an even head count; int8 with a multiple of 4): they are then
+    read as strided word loads, see the module docstring. Mosaic has no
+    strided load of narrower types, so any other geometry takes the
+    per-token sublane gather."""
     packing = 4 // ref.dtype.itemsize
-    if ref.dtype not in (jnp.float32, jnp.bfloat16, jnp.int8) \
-            or n_kv % packing:
-        for h in range(n_kv):
-            yield ref[0, 0, :, h, :].astype(out_dtype)
-        return
-    words = ref if packing == 1 else ref.bitcast(jnp.int32)
+    return (ref.dtype in (jnp.float32, jnp.bfloat16, jnp.int8)
+            and ref.shape[3] % packing == 0)
+
+
+def _word_heads(refs, word, out_dtype):
+    """The ``4 // itemsize`` kv heads that share 32-bit word ``word`` of a
+    token (a traced index), each ``[N * page, hd]`` as ``out_dtype``, from
+    the N page blocks ``refs`` [1, 1, page, KV, hd] of a KV block, in table
+    order."""
+    _, _, page, n_kv, hd = refs[0].shape
+    dtype = refs[0].dtype
+    packing = 4 // dtype.itemsize
     stride = n_kv // packing            # words a token
-    words = words.reshape(page * stride, hd)
+    x = jnp.concatenate([
+        (ref if packing == 1 else ref.bitcast(jnp.int32))
+        .reshape(page * stride, hd)[pl.ds(word, page, stride=stride), :]
+        for ref in refs])
     bits = 32 // packing
-    for word in range(stride):
-        x = words[pl.ds(word, page, stride=stride), :]
-        for sub in range(packing):
-            if packing == 1:
-                head = x
-            elif ref.dtype == jnp.bfloat16:  # the high half of an f32
-                head = pltpu.bitcast((x >> (bits * sub)) << bits, jnp.float32)
-            else:                            # int8: sign-extend byte ``sub``
-                head = (x << (32 - bits * (sub + 1))) >> (32 - bits)
-            yield head.astype(out_dtype)
+    for sub in range(packing):
+        if packing == 1:
+            head = x
+        elif dtype == jnp.bfloat16:      # the high half of an f32
+            head = pltpu.bitcast((x >> (bits * sub)) << bits, jnp.float32)
+        else:                            # int8: sign-extend byte ``sub``
+            head = (x << (32 - bits * (sub + 1))) >> (32 - bits)
+        yield head.astype(out_dtype)
 
 
-def _kernel(tables_ref, max_pos_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-            page_size: int, quantized: bool):
+def _kv_block_pages(n_pages: int, rows: int, page_size: int) -> int:
+    """Pages a grid step holds: see the module docstring."""
+    want = max(1, min(_BLOCK_PAGES, _SCORE_TILE // (rows * page_size)))
+    return max(n for n in range(1, want + 1) if n_pages % n == 0)
+
+
+def _block_entries(block_tables, max_pos, n: int, page_size: int):
+    """The pool page every slot of every KV block fetches: block_tables
+    [B, P], max_pos [B, row blocks] -> [B * row blocks, P] int32. Entry
+    ``j * n + i`` (slot ``i`` of block ``j``) is the row's table entry while
+    the row block reaches that page; a slot it does not reach (a dead page
+    inside a live block, a dead block, an idle row) keeps the entry the slot
+    last fetched, in the order the grid walks, so it fetches nothing. Worked
+    out here and not in the index maps: a map is traced and lowered once a
+    pool operand a layer, and cannot see what its slot held a step before."""
+    B, P = block_tables.shape
+    row_blocks = max_pos.shape[1]
+    live = (jnp.arange(P, dtype=jnp.int32)
+            <= (max_pos // page_size)[..., None]).reshape(-1, n)
+    entries = jnp.broadcast_to(
+        block_tables[:, None, :], (B, row_blocks, P)).reshape(-1, n)
+    step = jnp.arange(live.shape[0], dtype=jnp.int32)[:, None]
+    fetched = jax.lax.cummax(jnp.where(live, step, 0), axis=0)
+    return jnp.take_along_axis(entries, fetched, axis=0).reshape(-1, P)
+
+
+def _kernel(entries_ref, max_pos_ref, pos_ref, q_ref, *rest,
+            page_size: int, n: int, quantized: bool):
     """Refs: pos [R, 1] int32 (absolute position of each query row, -1 =
-    padding); q/o [KV, R, hd]; k/v [1, 1, page, KV, hd]; scales
-    [_SCALE_ROWS, KV]; scratch acc [KV, R, hd], m/l [KV, R, 1] f32."""
+    padding); q/o [KV, R, hd]; N k then N v refs [1, 1, page, KV, hd], the
+    block's pages in table order; N k then N v scale refs [_SCALE_ROWS, KV];
+    scratch acc [KV, R, hd], m/l [KV, R, 1] f32."""
+    k_refs, v_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
     if quantized:
-        k_scale_ref, v_scale_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    b, r, page_idx = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        k_scale_refs, v_scale_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
+    o_ref, acc_ref, m_ref, l_ref = rest
+    b, r, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_kv, n_rows, hd = q_ref.shape
+    block = n * page_size
 
-    @pl.when(page_idx == 0)
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    page_start = page_idx * page_size
-
-    # the page holds live context iff some query position reaches it
-    @pl.when(max_pos_ref[b, r] >= page_start)
+    # the block holds live context iff some query position reaches it
+    @pl.when(max_pos_ref[b, r] >= j * block)
     def _process():
-        col = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (n_rows, page_size), 1)
+        col = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (n_rows, block), 1)
         live = col <= pos_ref[...]                    # causal, on position
         if quantized:
-            # this page's row of the scale block, as a masked reduce: a
-            # dynamic sublane slice of a packed 16-bit tile does not lower
-            row = tables_ref[b, page_idx] % _SCALE_ROWS
-            pick = jax.lax.broadcasted_iota(
-                jnp.int32, k_scale_ref.shape, 0) == row
+            slot_of = jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1) // page_size
+            kv_lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_kv), 1)
 
-            def page_scale(ref):
-                return jnp.sum(jnp.where(pick, ref[...].astype(jnp.float32),
-                                         0.0), axis=0, keepdims=True)
-            k_scale, v_scale = page_scale(k_scale_ref), page_scale(v_scale_ref)
-        for h, (k, v) in enumerate(zip(_heads(k_ref, q_ref.dtype),
-                                       _heads(v_ref, q_ref.dtype))):
-            q = q_ref[h]                              # [R, hd]; k, v [page, hd]
+            def page_scales(refs):
+                """A ``[1, KV]`` row a slot: its page's scales."""
+                rows = []
+                for slot, ref in enumerate(refs):
+                    # the slot's row of its scale block, as a masked reduce:
+                    # a dynamic sublane slice of a packed 16-bit tile does
+                    # not lower
+                    row = entries_ref[b * pl.num_programs(1) + r,
+                                      j * n + slot] % _SCALE_ROWS
+                    pick = jax.lax.broadcasted_iota(
+                        jnp.int32, ref.shape, 0) == row
+                    rows.append(jnp.sum(jnp.where(
+                        pick, ref[...].astype(jnp.float32), 0.0),
+                        axis=0, keepdims=True))
+                return rows
+
+            def column_scale(rows, h):
+                """Head ``h``'s scale of each column's page, [1, block]."""
+                at = [jnp.sum(jnp.where(kv_lane == h, row, 0.0), axis=1,
+                              keepdims=True) for row in rows]
+                out = at[0]
+                for slot in range(1, n):
+                    out = jnp.where(slot_of >= slot, at[slot], out)
+                return out
+            k_scales, v_scales = (page_scales(k_scale_refs),
+                                  page_scales(v_scale_refs))
+
+        def attend(h, k, v):
+            """One online-softmax update of kv head ``h`` (may be traced)
+            over the block: k, v [block, hd]."""
             scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) / math.sqrt(hd)
+                q_ref[h], k, (((1,), (1,)), ((), ())),    # q_ref[h]: [R, hd]
+                preferred_element_type=jnp.float32) * (1.0 / math.sqrt(hd))
             if quantized:
-                scores = scores * k_scale[:, h:h + 1]
-            scores = jnp.where(live, scores, NEG_INF)     # [R, page]
+                scores = scores * column_scale(k_scales, h)
+            scores = jnp.where(live, scores, NEG_INF)     # [R, block]
             m_prev = m_ref[h]                             # [R, 1]
             m_new = jnp.maximum(m_prev,
                                 jnp.max(scores, axis=1, keepdims=True))
@@ -147,14 +225,32 @@ def _kernel(tables_ref, max_pos_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
             probs = jnp.exp(scores - m_new)
             l_ref[h] = (l_ref[h] * correction
                         + jnp.sum(probs, axis=1, keepdims=True))
-            pv = jnp.dot(probs.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)
             if quantized:
-                pv = pv * v_scale[:, h:h + 1]
-            acc_ref[h] = acc_ref[h] * correction + pv
+                probs = probs * column_scale(v_scales, h)
+            acc_ref[h] = acc_ref[h] * correction + jnp.dot(
+                probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
-    @pl.when(page_idx == pl.num_programs(2) - 1)
+        if _word_packed(k_refs[0]):
+            # a loop over a token's words that the lowering unrolls (static
+            # word indices, as scheduled as a Python loop over heads) but
+            # that is TRACED once: 32 kernels a step program are set-up time
+            packing = 4 // k_refs[0].dtype.itemsize
+
+            def word_step(word, carry):
+                for sub, (k, v) in enumerate(zip(
+                        _word_heads(k_refs, word, q_ref.dtype),
+                        _word_heads(v_refs, word, q_ref.dtype))):
+                    attend(word * packing + sub, k, v)
+                return carry
+            jax.lax.fori_loop(0, n_kv // packing, word_step, 0, unroll=True)
+        else:
+            for h in range(n_kv):
+                attend(h, *(jnp.concatenate(
+                    [ref[0, 0, :, h, :] for ref in refs]).astype(q_ref.dtype)
+                    for refs in (k_refs, v_refs)))
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         o_ref[...] = (acc_ref[...] /
                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
@@ -172,33 +268,40 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
     if R % rows:
         raise ValueError(f"query rows {R} must divide into blocks of {rows}")
     n_blocks = R // rows
+    n = _kv_block_pages(n_pages, rows, page_size)
 
-    def page_map(b, r, j, tables, max_pos):
-        return (layer, tables[b, j], 0, 0, 0)
+    def slot_entry(slot, b, r, j, entries, max_pos):
+        return entries[b * n_blocks + r, j * n + slot]
 
-    def row_map(b, r, j, tables, max_pos):
+    def page_map(slot):
+        return lambda *at: (layer, slot_entry(slot, *at), 0, 0, 0)
+
+    def scale_map(slot):
+        return lambda *at: (layer, slot_entry(slot, *at) // _SCALE_ROWS, 0)
+
+    def row_map(b, r, j, entries, max_pos):
         return (b, 0, r, 0)
 
+    # not squeezed: a ref with squeezed dims cannot be bitcast
+    page_specs = [pl.BlockSpec((1, 1, page_size, KV, hd), page_map(slot))
+                  for slot in range(n)]
     in_specs = [
         pl.BlockSpec((None, rows, 1), lambda b, r, j, t, m: (b, r, 0)),
         pl.BlockSpec((None, KV, rows, hd), row_map),
-        # not squeezed: a ref with squeezed dims cannot be bitcast
-        pl.BlockSpec((1, 1, page_size, KV, hd), page_map),
-        pl.BlockSpec((1, 1, page_size, KV, hd), page_map),
+        *page_specs, *page_specs,
     ]
-    inputs = [row_pos[:, :, None], q, k_pages, v_pages]
+    inputs = [row_pos[:, :, None], q, *[k_pages] * n, *[v_pages] * n]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (None, _SCALE_ROWS, KV),
-            lambda b, r, j, t, m: (layer, t[b, j] // _SCALE_ROWS, 0))
-        in_specs += [scale_spec, scale_spec]
-        inputs += [k_scales, v_scales]
+        in_specs += 2 * [pl.BlockSpec((None, _SCALE_ROWS, KV), scale_map(slot))
+                         for slot in range(n)]
+        inputs += [*[k_scales] * n, *[v_scales] * n]
     max_pos = jnp.max(row_pos.reshape(B, n_blocks, rows), axis=2)
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, quantized=quantized),
+        functools.partial(_kernel, page_size=page_size, n=n,
+                          quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, n_blocks, n_pages),
+            grid=(B, n_blocks, n_pages // n),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, KV, rows, hd), row_map),
             scratch_shapes=[
@@ -210,7 +313,7 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
         name="paged_attention",
         interpret=interpret,
-    )(block_tables, max_pos, *inputs)
+    )(_block_entries(block_tables, max_pos, n, page_size), max_pos, *inputs)
 
 
 _POOL_SPEC = P(None, None, None, "model", None)   # kv_pages, per shard

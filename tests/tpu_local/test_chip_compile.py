@@ -102,19 +102,20 @@ DS_LAYERS, DS_PAGES, DS_TABLE, DS_LATENT, DS_VALUE = 5, 1152, 128, 576, 512
 DS_HEADS, DS_IDX_HEADS, DS_IDX_DIM, DS_TOPK = 128, 64, 128, 2048
 
 
-def _mla_attention_case(chunk: int | None):
-    """Chunk round (``chunk`` queries a row, 2 rows) or decode (8 rows)."""
+def _mla_attention_case(chunk: int | None, table: int = DS_TABLE):
+    """Chunk round (``chunk`` queries a row, 2 rows) or decode (8 rows) over
+    a block table of ``table`` pages (a context or history bucket)."""
     pool = ((DS_LAYERS, DS_PAGES, PAGE, DS_LATENT), jnp.bfloat16)
     fn = partial(mla.mla_paged_attention_pallas, layer=3, value_dim=DS_VALUE)
     if chunk is None:
         B = 8
         return fn, [((B, 1, DS_HEADS, DS_LATENT), jnp.bfloat16),
-                    ((B, 1, DS_TABLE * PAGE), jnp.float32), pool,
-                    ((B, DS_TABLE), jnp.int32), ((B, 1), jnp.int32)]
+                    ((B, 1, table * PAGE), jnp.float32), pool,
+                    ((B, table), jnp.int32), ((B, 1), jnp.int32)]
     B = 2
     return fn, [((B, DS_HEADS, chunk, DS_LATENT), jnp.bfloat16),
-                ((B, chunk, DS_TABLE * PAGE), jnp.float32), pool,
-                ((B, DS_TABLE), jnp.int32),
+                ((B, chunk, table * PAGE), jnp.float32), pool,
+                ((B, table), jnp.int32),
                 ((B, chunk // mla._ATTN_QUERY_TILE), jnp.int32)]
 
 
@@ -160,10 +161,29 @@ KERNEL_CASES = {
     "paged_decode_bf16_cell_8x32": lambda: _paged_case(jnp.bfloat16, None, 8, 32),
     "paged_chunk_bf16_cell_tile512x32": lambda: _paged_case(
         jnp.bfloat16, 512, 2, 32),
+    # a KV block of N pages a grid step (PR 30): the cells' other context
+    # buckets (a block is the whole 4-page table; 2, 4 and 8 blocks a row),
+    # int8 pages at a cell's widths (N scale blocks a pool), and a table
+    # width 4 does not divide (blocks of 3)
+    "paged_decode_bf16_cell_32x4": lambda: _paged_case(jnp.bfloat16, None, 32, 4),
+    "paged_decode_bf16_cell_8x16": lambda: _paged_case(jnp.bfloat16, None, 8, 16),
+    "paged_chunk_bf16_cell_tile512x4": lambda: _paged_case(
+        jnp.bfloat16, 512, 2, 4),
+    "paged_chunk_bf16_cell_tile512x8": lambda: _paged_case(
+        jnp.bfloat16, 512, 2, 8),
+    "paged_decode_int8_8x32": lambda: _paged_case(jnp.int8, None, 8, 32),
+    "paged_chunk_int8_tile512x32": lambda: _paged_case(jnp.int8, 512, 2, 32),
+    "paged_decode_bf16_table6": lambda: _paged_case(jnp.bfloat16, None, 8, 6),
     "flash_prefill_s512": lambda: _flash_case(512),
     "flash_prefill_s2048": lambda: _flash_case(2048),
     "mla_attention_cell_decode_8x128": lambda: _mla_attention_case(None),
     "mla_attention_cell_chunk_2x1024x128": lambda: _mla_attention_case(1024),
+    # the cell's smallest and a middle decode bucket, the logits check's
+    # 8-page history bucket, and a table 8 does not divide (blocks of 6)
+    "mla_attention_cell_decode_8x4": lambda: _mla_attention_case(None, 4),
+    "mla_attention_cell_decode_8x32": lambda: _mla_attention_case(None, 32),
+    "mla_attention_cell_chunk_2x1024x8": lambda: _mla_attention_case(1024, 8),
+    "mla_attention_decode_table12": lambda: _mla_attention_case(None, 12),
     "sparse_index_cell_chunk_2x1024x128": _index_case,
     "sparse_select_cell_chunk_rows": lambda: _select_case(2 * 1024),
     "sparse_select_cell_decode_rows": lambda: _select_case(8),
@@ -206,7 +226,8 @@ def test_chip_smoke_kernel_parity_rehearsal(chip_smoke):
     assert set(facts["timing"]) == set(chip_smoke.TIMED_SHAPES)
     for timed in facts["timing"].values():
         assert timed["us_per_call"] > 0 and timed["us_per_live_page"] > 0
-        assert 0 < timed["live_pages"] <= timed["grid_steps"]
+        assert 0 < timed["live_pages"] <= (timed["grid_steps"]
+                                           * timed["block_pages"])
 
 
 def test_chip_smoke_gateway_rehearsal(chip_smoke, capsys, monkeypatch):

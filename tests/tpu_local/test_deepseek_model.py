@@ -245,12 +245,12 @@ def test_plan_drops_pairs_held_elsewhere_and_counts_live_blocks():
 
 # ------------------------------------------------------------------ kernels
 
-def _paged(key, batch, dim, contexts):
-    pages = jax.random.normal(key, (2, 1 + batch * TABLE, PAGE, dim), jnp.float32)
-    tables = np.zeros((batch, TABLE), np.int32)
+def _paged(key, batch, dim, contexts, table=TABLE):
+    pages = jax.random.normal(key, (2, 1 + batch * table, PAGE, dim), jnp.float32)
+    tables = np.zeros((batch, table), np.int32)
     for b, n in enumerate(contexts):
         used = -(-n // PAGE)
-        tables[b, :used] = 1 + b * TABLE + np.arange(used)
+        tables[b, :used] = 1 + b * table + np.arange(used)
     return pages, jnp.asarray(tables)
 
 
@@ -280,21 +280,38 @@ def test_index_kernel_and_selection_kernel_match_their_references():
     assert chosen.sum(axis=1).tolist() == np.minimum(visible, k).tolist()
 
 
+# The attention kernel holds a KV block of N table pages a grid step
+# (mla._kv_block_pages: 8 for these 128-row blocks, or the largest divisor of
+# the table width under it): (table width, the two rows' contexts). A context
+# that ends inside a block leaves its tail dead (slots fall back to pages they
+# held), one that ends before a block leaves the block dead.
+LATENT_WALKS = {
+    "w8": (TABLE, [TABLE * PAGE, 40]),
+    "w4": (4, [4 * PAGE, 23]),
+    "w128-dead-trailing-blocks": (128, [128 * PAGE, 8 * PAGE * 5 + 3]),
+    "w6-blocks-of-6": (6, [6 * PAGE, PAGE + 1]),
+    "w12-blocks-of-6": (12, [12 * PAGE, 7 * PAGE + 2]),
+    "w7-one-block-of-7": (7, [7 * PAGE, 50]),
+    "w11-a-page-a-step": (11, [11 * PAGE, 50]),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(LATENT_WALKS))
 @pytest.mark.parametrize("decode", [False, True])
-def test_latent_attention_kernel_matches_its_reference(decode):
+def test_latent_attention_kernel_matches_its_reference(decode, walk):
+    table, contexts = LATENT_WALKS[walk]
     ks = jax.random.split(jax.random.PRNGKey(8), 4)
     B, G, R, Dk, Dv = 2, 2, 128, 48, 32
-    contexts = [TABLE * PAGE, 40]
-    pages, tables = _paged(ks[0], B, Dk, contexts)
-    C = TABLE * PAGE
+    pages, tables = _paged(ks[0], B, Dk, contexts, table)
+    C = table * PAGE
     if decode:      # one bias row for all of a query's heads
         q = jax.random.normal(ks[1], (B, 1, R, Dk), jnp.float32) * 0.3
         last = jnp.asarray([[c - 1] for c in contexts], jnp.int32)
         visible = jnp.arange(C)[None, None, :] <= last[:, :, None]
-    else:
+    else:           # the last R positions of each context
         q = jax.random.normal(ks[1], (B, G, R, Dk), jnp.float32) * 0.3
-        pos = jnp.asarray(np.stack([np.arange(R), np.minimum(np.arange(R), 39)]),
-                          jnp.int32)
+        pos = jnp.asarray(np.stack([np.maximum(np.arange(c - R, c), 0)
+                                    for c in contexts]), jnp.int32)
         last = jnp.max(pos, axis=1, keepdims=True)
         visible = jnp.arange(C)[None, None, :] <= pos[:, :, None]
     keep = jax.random.bernoulli(ks[2], 0.3, visible.shape) & visible
@@ -304,6 +321,46 @@ def test_latent_attention_kernel_matches_its_reference(decode):
                                          value_dim=Dv, interpret=True)
     want = mla.mla_attention_reference(q, bias, kv_mod.gather_pool(pages, 1, tables), Dv)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("decode", [False, True])
+def test_a_block_a_row_selects_nothing_of_counts_nothing(decode):
+    """Rows that select nothing of the first KV block (``m`` stays NEG_INF
+    there: exp(0) must not count) and something later; rows that select
+    nothing at all read zeros, never NaN."""
+    table, Dk, Dv, B, R = 16, 48, 32, 2, 128
+    block = mla._kv_block_pages(table, R, PAGE) * PAGE
+    assert block < table * PAGE                      # more than one block
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    pages, tables = _paged(ks[0], B, Dk, [table * PAGE] * B, table)
+    C = table * PAGE
+    rows = 1 if decode else R
+    keep = jax.random.bernoulli(ks[2], 0.2, (B, rows, C))
+    keep = keep.at[0, :, :block].set(False)          # nothing of block 0
+    keep = keep.at[1, rows // 2:].set(False)         # nothing at all
+    keep = keep.at[0, :, block + 3].set(True)
+    bias = jnp.where(keep, 0.0, mla.NEG_INF).astype(jnp.float32)
+    q = jax.random.normal(ks[1], (B, 1 if decode else 2, R, Dk), jnp.float32) * 0.3
+    last = jnp.full((B, 1), C - 1, jnp.int32)
+    got = np.asarray(mla.mla_paged_attention_pallas(
+        q, bias, pages, tables, last, layer=1, value_dim=Dv, interpret=True))
+    want = mla.mla_attention_reference(q, bias, kv_mod.gather_pool(pages, 1, tables), Dv)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
+    nothing = got[1] if decode else got[1, :, rows // 2:]
+    assert not nothing.any()
+    assert got[0].any()
+
+
+def test_latent_kv_block_is_a_divisor_of_any_table_width():
+    for width in range(1, 130):
+        for rows in (128, 256):
+            n = mla._kv_block_pages(width, rows, 128)
+            assert 1 <= n <= mla._ATTN_BLOCK_PAGES and width % n == 0
+    # the cell's tables: chunk blocks of 256 rows, decode blocks of 128
+    assert [mla._kv_block_pages(w, 256, 128) for w in (8, 128)] == [4, 4]
+    assert [mla._kv_block_pages(w, 128, 128)
+            for w in (4, 8, 16, 32, 64, 128)] == [4, 8, 8, 8, 8, 8]
 
 
 # ------------------------------------------------------------ cache and seam

@@ -100,7 +100,7 @@ class _Heads:
 
 
 # how the kernel reads ONE kv head out of a page block depends on the
-# pool's dtype and head count (ops/paged_attention._heads): strided
+# pool's dtype and head count (ops/paged_attention._word_heads): strided
 # loads of 32-bit words where the heads fill whole words, the per-token
 # sublane gather where they do not. The stored values are the same in the
 # kernel and in the gather oracle, so the comparison is exact-tolerance.
@@ -187,10 +187,11 @@ def test_paged_chunk_matches_history_reference(quant, dtype):
                                    rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("KV,pool_dtype", [(4, jnp.float32),
-                                           (8, jnp.bfloat16)],
-                         ids=["f32-1head", "bf16-2heads"])
-def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype):
+@pytest.mark.parametrize("KV,pool_dtype,per_slot", [
+    (4, jnp.float32, 4), (8, jnp.bfloat16, 4),
+    (8, jnp.bfloat16, 8),   # two KV blocks a row, the second partly dead
+], ids=["f32-1head", "bf16-2heads", "bf16-2heads-2blocks"])
+def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype, per_slot):
     """On a TP mesh the kernels run under shard_map over ``model`` — each
     shard on the kv heads it holds (ops/attention.on_model_axis) — and must
     give what one unsharded call gives. Layer 1 of 2, so the layer index
@@ -207,7 +208,8 @@ def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype):
     from mcp_context_forge_tpu.tpu_local.parallel import make_mesh
 
     mesh = make_mesh("1x4", devices=jax.devices()[:4])
-    L, N, page, G, hd, B, per_slot, S = 2, 9, 8, 2, 16, 2, 4, 8
+    L, page, G, hd, B, S = 2, 8, 2, 16, 2, 8
+    N = 1 + B * per_slot
     keys = iter(jax.random.split(jax.random.PRNGKey(3), 8))
     pool_sharding = NamedSharding(mesh, P(None, None, None, "model", None))
     k_pages, v_pages = (jax.device_put(
@@ -221,7 +223,8 @@ def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype):
     np.testing.assert_allclose(np.asarray(decode(mesh=mesh)),
                                np.asarray(decode()), rtol=1e-6, atol=1e-6)
 
-    positions = jnp.stack([16 + jnp.arange(S), jnp.arange(S)]).astype(jnp.int32)
+    positions = jnp.stack([per_slot * page - S + jnp.arange(S),
+                           jnp.arange(S)]).astype(jnp.int32)
     qc = jax.random.normal(next(keys), (B, S, KV, G, hd))
     chunk = partial(paged_chunk_attention_pallas, qc, k_pages, v_pages,
                     tables, positions, layer=1, interpret=True)
@@ -238,3 +241,145 @@ def test_kernels_per_model_shard_match_unsharded(KV, pool_dtype):
     np.testing.assert_allclose(np.asarray(sharded(qf, kf, vf, valid)),
                                np.asarray(flash(qf, kf, vf, valid)),
                                rtol=1e-6, atol=1e-6)
+
+
+# ------------------------------------------------- the walk by KV blocks
+#
+# A grid step holds N block-table pages (ops/paged_attention._kv_block_pages:
+# 4 here, or the largest divisor of the table width under it). What has to
+# hold whatever the width and the rows' lengths: a block whose tail is dead
+# (its slots fall back to pages they held before), blocks that are wholly
+# dead, idle rows, widths N does not divide, and a scale row a page for int8
+# pools.
+
+def _random_state(n_kv, hd, page_size, slots, per_slot, dtype, quant, key):
+    """Pools filled at random (the kernel and the gather oracle read the
+    same stored values), row ``b`` holding pages 1 + b * per_slot ..., in a
+    shuffled order so that a block's pages are not neighbours."""
+    from mcp_context_forge_tpu.tpu_local.kv import PagedKVState
+
+    n_pages = 1 + slots * per_slot
+    shape = (2, n_pages, page_size, n_kv, hd)
+    ks = jax.random.split(key, 5)
+    order = np.asarray(jax.random.permutation(ks[4], slots * per_slot))
+    tables = jnp.asarray(1 + order.reshape(slots, per_slot), jnp.int32)
+    if quant:
+        pools = [jax.random.randint(k, shape, -127, 128, jnp.int8)
+                 for k in ks[:2]]
+        scales = [(0.002 + 0.01 * jax.random.uniform(
+            k, (2, n_pages, n_kv))).astype(dtype) for k in ks[2:4]]
+        return PagedKVState(*pools, tables, *scales)
+    pools = [jax.random.normal(k, shape).astype(dtype) for k in ks[:2]]
+    return PagedKVState(*pools, tables)
+
+
+def _masked_softmax_reference(q, kv, layer, positions):
+    """q [B, S, KV, G, hd]; positions [B, S] (-1: none) -> same shape."""
+    import math
+
+    from mcp_context_forge_tpu.tpu_local.kv import gather_kv
+
+    keys_g, values_g = (a.astype(jnp.float32) for a in gather_kv(
+        kv, layer, jnp.arange(q.shape[0])))
+    scores = jnp.einsum("bskgh,bckh->bskgc", q, keys_g) / math.sqrt(q.shape[-1])
+    seen = jnp.arange(keys_g.shape[1])[None, None, :] <= positions[:, :, None]
+    scores = jnp.where(seen[:, :, None, None, :], scores, -1e30)
+    return jnp.einsum("bskgc,bckh->bskgh", jax.nn.softmax(scores, axis=-1),
+                      values_g)
+
+
+BLOCK_WALKS = {
+    # id: (table width, page, rows' live lengths (0: an idle row), kv heads,
+    #      pool dtype, quant, queries a row or None for decode)
+    "w4-partly-dead-block": (4, 8, [13, 5, 32], 2, jnp.float32, "", None),
+    "w8-dead-trailing-block": (8, 8, [19, 33, 64, 0], 2, jnp.float32, "", None),
+    "w8-int8": (8, 8, [19, 33, 64, 0], 4, jnp.float32, "int8", None),
+    "w8-bf16x8": (8, 8, [19, 33, 64, 0], 8, jnp.bfloat16, "", None),
+    "w8-bf16x3-gather": (8, 8, [19, 33, 64, 0], 3, jnp.bfloat16, "", None),
+    "w128": (128, 8, [1024, 8 * 37 + 3, 1], 2, jnp.float32, "", None),
+    "w128-int8": (128, 8, [1024, 8 * 37 + 3, 1], 4, jnp.float32, "int8", None),
+    "w6-blocks-of-3": (6, 8, [48, 25, 7], 2, jnp.float32, "", None),
+    "w7-a-page-a-step": (7, 8, [56, 25, 7], 2, jnp.float32, "", None),
+    "w2": (2, 8, [16, 3], 2, jnp.float32, "", None),
+    "chunk-w4": (4, 8, [32, 13, 6], 2, jnp.float32, "", 6),
+    "chunk-w8-dead-trailing-block": (8, 8, [64, 30, 17], 2, jnp.float32, "", 16),
+    "chunk-w8-int8": (8, 8, [64, 30, 17], 4, jnp.float32, "int8", 16),
+    "chunk-w8-bf16x8": (8, 8, [64, 30, 17], 8, jnp.bfloat16, "", 16),
+    "chunk-w128-two-row-blocks": (128, 8, [1024, 500, 300], 2, jnp.float32,
+                                  "", 256),
+    "chunk-w6-blocks-of-3": (6, 8, [48, 25, 9], 2, jnp.float32, "", 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_WALKS))
+def test_block_walk_matches_gather_reference(case):
+    from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
+
+    width, page, lens, n_kv, dtype, quant, chunk = BLOCK_WALKS[case]
+    G, hd, B = 2, 16, len(lens)
+    kv = _random_state(n_kv, hd, page, B, width, dtype, quant,
+                       jax.random.PRNGKey(len(case)))
+    S = chunk or 1
+    lens = np.asarray(lens)
+    # the last S positions of each row's live context (fewer: padding)
+    positions = lens[:, None] - S + np.arange(S)[None, :]
+    positions = jnp.asarray(np.where(positions >= 0, positions, -1), jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(5), (B, S, n_kv, G, hd))
+    ref = _masked_softmax_reference(q, kv, 1, positions)
+    if chunk is None:
+        out = paged.paged_decode_attention_pallas(
+            q[:, 0], kv.k_pages, kv.v_pages, kv.block_tables,
+            jnp.asarray(lens, jnp.int32), layer=1, interpret=True,
+            k_scales=kv.k_scales, v_scales=kv.v_scales)[:, None]
+        idle = np.asarray(out)[lens == 0]
+        assert not idle.any()                       # an idle row: zeros
+    else:
+        out = paged.paged_chunk_attention_pallas(
+            q, kv.k_pages, kv.v_pages, kv.block_tables, positions, layer=1,
+            interpret=True, k_scales=kv.k_scales, v_scales=kv.v_scales)
+    assert np.isfinite(np.asarray(out)).all()
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    valid = np.asarray(positions >= 0)
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
+                               rtol=tol, atol=tol)
+
+
+def test_kv_block_is_a_divisor_of_any_table_width():
+    from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
+
+    for width in range(1, 130):
+        for rows in (4, 32, 256):
+            n = paged._kv_block_pages(width, rows, 128)
+            assert 1 <= n <= paged._BLOCK_PAGES and width % n == 0
+    assert [paged._kv_block_pages(w, 256, 128)
+            for w in (4, 8, 16, 32, 64, 128, 2, 3, 6)] == [4] * 6 + [2, 3, 3]
+
+
+@pytest.mark.parametrize("n", [1, 3, 4, 8])
+def test_a_slot_the_row_does_not_reach_keeps_what_it_fetched(n):
+    """Slot ``i`` of block ``j`` names table entry j * n + i while the row
+    block reaches that page; where it does not (a dead page inside a live
+    block, a dead block, an idle row) it names what the slot named a grid
+    step before, so the pipeline fetches nothing. Both kernels keep their own
+    copy of the rule."""
+    from mcp_context_forge_tpu.tpu_local.ops import mla_attention as mla
+    from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
+
+    width, page_size = 2 * n if n > 1 else 3, 8
+    # rows x row blocks: full, ends inside its first block, idle, one token
+    max_pos = np.asarray([[width * page_size - 1, 5 * page_size // 2],
+                          [-1, -1], [0, page_size * (width - 1)]])
+    tables = 100 + np.arange(3 * width).reshape(3, width)
+    want, held = [], list(tables[0, :n])
+    for b in range(3):
+        for r in range(2):
+            for page in range(width):
+                if page <= max_pos[b, r] // page_size:
+                    held[page % n] = tables[b, page]
+                want.append(held[page % n])
+    for module in (paged, mla):
+        got = module._block_entries(jnp.asarray(tables, jnp.int32),
+                                    jnp.asarray(max_pos, jnp.int32), n,
+                                    page_size)
+        assert got.shape == (6, width)
+        assert np.asarray(got).reshape(-1).tolist() == want
