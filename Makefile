@@ -1,6 +1,6 @@
 # mcp-context-forge-tpu (reference: 8.7k-line Makefile; the targets that matter)
 
-.PHONY: serve hub lint bench-check test test-py test-fast test-two-process bench bench-engine bench-superstep bench-scenarios bench-workers-real bench-fabric bench-chaos wrapper masking clean \
+.PHONY: serve hub lint test test-py test-fast test-two-process bench-scenarios bench-workers-real bench-fabric bench-chaos wrapper masking clean \
 	sanitize sanitize-tsan sanitize-asan
 
 serve:
@@ -25,15 +25,8 @@ compose-config:
 lint:
 	python -m mcp_context_forge_tpu.tools.lint mcp_context_forge_tpu
 
-# bench-history trend gate (pure stdlib, like lint): fails on
-# tolerance-breaking regressions of tok/s, hbm_roofline_frac, or p95
-# latency across the checked-in BENCH_*.json rounds
-bench-check:
-	python -m mcp_context_forge_tpu.tools.bench_trend
-
-# full gate: lint + bench trend + python suite + the C++ tier under TSAN
-# and ASAN/UBSAN
-test: lint bench-check test-py sanitize
+# full gate: lint + python suite + the C++ tier under TSAN and ASAN/UBSAN
+test: lint test-py sanitize
 
 test-py:
 	python -m pytest tests/ -q
@@ -41,23 +34,12 @@ test-py:
 test-fast:
 	python -m pytest tests/unit tests/fuzz -q
 
-bench:
-	python bench.py
-
-bench-engine:
-	python bench_engine.py
-
-# token-loop-fusion A/B: one arm per K, greedy parity + host-syncs-per-
-# token + live roofline per arm (ROADMAP item 1 acceptance sweep)
-bench-superstep:
-	BENCH_SUPERSTEP=1,4,8,16 python bench_engine.py
-
 # SLO-asserting gateway scenario harness (docs/load_harness.md): burst /
 # diurnal ramp / mixed chat+tools+A2A+federation / tenant (skewed
 # per-tenant mix with SLO classes + token-conservation gate) / chaos
 # replica-kill under load, each gated through /admin/slo delta windows;
-# captures land as BENCH_SCENARIO_*_r<N>.json and bench-check gates
-# them per arm.
+# one JSON report of verdicts on stdout — checks of behaviour, not
+# speeds (the benchmark is `python3 benchmark/run.py`, BENCHMARK.json).
 # CPU smoke variant runs in tier-1 (tests/unit/test_bench_scenarios_smoke.py).
 bench-scenarios:
 	python bench_gateway_scenarios.py
@@ -66,8 +48,7 @@ bench-scenarios:
 # topology"): forks N `mcpforge serve` workers on one SO_REUSEPORT
 # socket behind a hub process — the same path `mcpforge supervise`
 # runs in production — and gates scaleup against the honest
-# 0.8*min(workers, host_cpus) bar. Capture carries in_process:false so
-# bench-check judges it as its own arm, never against in-process rounds.
+# 0.8*min(workers, host_cpus) bar.
 bench-workers-real:
 	BENCH_SCENARIO_ONLY=workers-real BENCH_REAL_PROCS=1 \
 	BENCH_SCENARIO_ENFORCE_SLO=1 \
@@ -78,8 +59,7 @@ bench-workers-real:
 # store — host B must serve the chains host A prefilled (byte-identical
 # continuations, exact per-tenant ledger conservation) and a forced
 # tier.object breaker-open phase must finish with zero request
-# failures. Capture carries fabric:true so bench-check judges it as
-# its own arm.
+# failures.
 bench-fabric:
 	BENCH_SCENARIO_ONLY=fabric BENCH_REAL_PROCS=1 \
 	python bench_gateway_scenarios.py

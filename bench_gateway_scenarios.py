@@ -43,17 +43,15 @@ and the warmed K ladder must mean zero serving-stage XLA compiles.
 
 Each scenario evaluates TTFT/TPOT/queue-wait/http-phase SLOs through
 ``GET /admin/slo`` per-consumer delta windows (its own named window, so
-nothing shreds the deltas) and writes a ``BENCH_SCENARIO_<NAME>_r<N>.json``
-capture; ``tools/bench_trend.py`` gates each scenario series per arm in
-``make bench-check``. A run that produces ZERO captures exits non-zero —
-the PR-6 no-vacuous-pass rule.
+nothing shreds the deltas) and returns its verdict; ``run_scenarios``
+gathers them into one JSON report on stdout and writes no file. A run
+that produces ZERO verdicts exits non-zero — the PR-6 no-vacuous-pass
+rule. These are checks of the gateway's behaviour, not speeds: the
+repo's one benchmark is ``benchmark/run.py``.
 
 Env knobs:
     BENCH_SCENARIO_SMOKE=1       tiny totals (tier-1 CPU smoke)
     BENCH_SCENARIO_MODEL         model (default llama3-tiny / llama3-1b on tpu)
-    BENCH_SCENARIO_ROUND=N       capture round suffix (default: next free)
-    BENCH_SCENARIO_DIR           capture directory (default: repo root)
-    BENCH_SCENARIO_WRITE=0       skip writing captures (still prints JSON)
     BENCH_SCENARIO_PARITY=0      skip the chaos token-parity reference run
                                  (double-commits device memory; off on TPU)
     BENCH_SCENARIO_ENFORCE_SLO=1 breached SLO windows fail the run
@@ -64,13 +62,14 @@ Env knobs:
                                  both spawn REAL supervised process fleets
     BENCH_GW_REAL_WORKERS=N      real-process fleet size (default 4)
     BENCH_PIN_CPUS=1             pass --pin-cpus semantics to the real fleet
+    BENCH_PLATFORM               jax platform to pin (default: what jax finds;
+                                 asked for a chip that is absent, it fails)
 """
 
 from __future__ import annotations
 
 import asyncio
 import dataclasses
-import glob
 import json
 import os
 import re
@@ -152,15 +151,149 @@ def _scale() -> dict:
             "fabric_requests": 32, "fabric_concurrency": 6}
 
 
+class _HttpClient:
+    """aiohttp-style ``post`` / ``get`` / ``delete`` against one
+    ``host:port`` over a real ClientSession (the duck type
+    ``tools/loadgen.py`` drives)."""
+
+    class _Addr:
+        def __init__(self, host: str, port: int):
+            self.host, self.port = host, port
+
+    def __init__(self, session, host: str, port: int):
+        self._session = session
+        self._base = f"http://{host}:{port}"
+        self.server = self._Addr(host, port)
+
+    def post(self, path: str, **kwargs):
+        return self._session.post(self._base + path, **kwargs)
+
+    def get(self, path: str, **kwargs):
+        return self._session.get(self._base + path, **kwargs)
+
+    def delete(self, path: str, **kwargs):
+        return self._session.delete(self._base + path, **kwargs)
+
+    async def close(self) -> None:
+        await self._session.close()
+
+
+class _SocketClient(_HttpClient):
+    """Client bound to a live TCP listener this process serves: every
+    gateway the harness builds binds an ephemeral localhost port via
+    AppRunner/TCPSite (no in-process TestClient — sockets and the TCP
+    stack are in the path); ``close()`` also tears the listener down."""
+
+    def __init__(self, app, runner, session, host: str, port: int):
+        super().__init__(session, host, port)
+        self.app = app
+        self._runner = runner
+
+    async def close(self) -> None:
+        await super().close()
+        await self._runner.cleanup()
+
+
+class _RemoteClient(_HttpClient):
+    """Client of a port this process does NOT serve — the real-process
+    arm's workers live in their own PIDs, so there is no app/runner to
+    own; ``close()`` only closes the session."""
+
+    def __init__(self, host: str, port: int, force_close: bool = False,
+                 limit: int | None = None,
+                 keepalive_timeout_s: float | None = None):
+        import aiohttp
+        kwargs = {}
+        if keepalive_timeout_s is not None and not force_close:
+            kwargs["keepalive_timeout"] = keepalive_timeout_s
+        super().__init__(aiohttp.ClientSession(
+            connector=aiohttp.TCPConnector(
+                # fresh connection per request when asked: each new
+                # connection re-rolls the kernel's SO_REUSEPORT hash, so
+                # readiness probes actually visit DIFFERENT workers
+                force_close=force_close,
+                limit=limit if limit is not None else int(
+                    os.environ.get("BENCH_CLIENT_CONN_LIMIT", "512")),
+                **kwargs)), host, port)
+
+
+async def _serve_tcp(app) -> _SocketClient:
+    import aiohttp
+    from aiohttp import web
+
+    runner = web.AppRunner(app)
+    await runner.setup()
+    # deep accept backlog: the open-loop burst arm offers thousands of
+    # connections inside one RTT, and the 128 default resets the excess
+    site = web.TCPSite(runner, "127.0.0.1", 0,
+                       backlog=int(os.environ.get("BENCH_LISTEN_BACKLOG",
+                                                  "4096")))
+    await site.start()
+    host, port = runner.addresses[0][:2]
+    session = aiohttp.ClientSession(
+        connector=aiohttp.TCPConnector(
+            # the 10k-concurrent open-loop arm needs more sockets than
+            # the default cap (fd rlimit permitting)
+            limit=int(os.environ.get("BENCH_CLIENT_CONN_LIMIT", "512"))))
+    return _SocketClient(app, runner, session, host, port)
+
+
+async def _make_peer_gateway():
+    """Engine-less gateway on a real socket: the mixed scenario's
+    federation peer."""
+    from mcp_context_forge_tpu.config import load_settings
+    from mcp_context_forge_tpu.gateway.app import build_app
+
+    settings = load_settings(env={
+        "MCPFORGE_DATABASE_URL": "sqlite:///:memory:",
+        "MCPFORGE_PLUGINS_ENABLED": "true",
+        "MCPFORGE_TPU_LOCAL_ENABLED": "false",
+        "MCPFORGE_GATEWAY_HEALTH_INTERVAL": "3600",
+        "MCPFORGE_OTEL_EXPORTER": "none",
+        "MCPFORGE_LOG_LEVEL": "WARNING",
+    }, env_file=None)
+    return await _serve_tcp(await build_app(settings))
+
+
+async def _echo_upstream() -> _SocketClient:
+    from aiohttp import web
+
+    upstream = web.Application()
+
+    async def echo(request: web.Request) -> web.Response:
+        return web.json_response({"ok": True, "echo": await request.json()})
+
+    upstream.router.add_post("/echo", echo)
+    return await _serve_tcp(upstream)
+
+
+async def _register_tool(gateway, upstream, auth, name: str) -> None:
+    url = f"http://{upstream.server.host}:{upstream.server.port}/echo"
+    resp = await gateway.post("/tools", json={
+        "name": name, "integration_type": "REST", "url": url}, auth=auth)
+    assert resp.status == 201, await resp.text()
+
+
+def pin_platform() -> str:
+    """The platform this process runs on: ``BENCH_PLATFORM`` where set —
+    pinned before the backend starts, so a run asked for a chip that is
+    not there fails in ``jax.devices()`` — else whatever jax finds. One
+    process, no probe child, no move to the CPU."""
+    import jax
+
+    asked = os.environ.get("BENCH_PLATFORM")
+    if asked:
+        jax.config.update("jax_platforms", asked)
+    return jax.devices()[0].platform
+
+
 async def _make_gateway(platform: str, replicas: int = 2,
                         extra_env: dict | None = None):
     """Engine-enabled gateway with the replica pool, on a real socket
-    (bench.py's AppRunner/TCPSite plumbing). ``extra_env`` overlays the
+    (``_serve_tcp``). ``extra_env`` overlays the
     base env — the dedicated chaos-matrix gateways (tier-fault's tiny
     host tier, overload-shed's tiny admission queue) shape themselves
     with it."""
-    from bench import _serve_tcp
-
     from mcp_context_forge_tpu.config import load_settings
     from mcp_context_forge_tpu.gateway.app import build_app
 
@@ -295,7 +428,6 @@ def _rebind_resilience_plane(app):
 
 
 async def _register_echo_tool(client, auth, name: str):
-    from bench import _echo_upstream, _register_tool
     upstream = await _echo_upstream()
     await _register_tool(client, upstream, auth, name)
     return upstream
@@ -304,7 +436,7 @@ async def _register_echo_tool(client, auth, name: str):
 # phase-bucket accounting (docs/observability.md): every hot-path claim
 # in this harness is justified by a BEFORE/AFTER delta of the
 # mcpforge_gw_request_phase_seconds sums — "serialize went from 18% to
-# 6% of wall" is readable straight from the capture, per arm
+# 6% of wall" is readable straight from the report, per arm
 _PHASE_SUM_RE = re.compile(
     r'^mcpforge_gw_request_phase_seconds_sum\{([^}]*)\}\s+([0-9eE+.\-]+)',
     re.MULTILINE)
@@ -327,7 +459,7 @@ def _phase_sums(text: str) -> dict[str, float]:
 def _phase_delta(before: dict[str, float],
                  after: dict[str, float]) -> dict[str, float]:
     """Seconds each phase accrued between two scrapes, zero-phases
-    dropped; the capture field hot-path PRs point at."""
+    dropped; the report field hot-path PRs point at."""
     out = {}
     for phase in sorted(set(before) | set(after)):
         delta = after.get(phase, 0.0) - before.get(phase, 0.0)
@@ -349,47 +481,6 @@ def _free_port() -> int:
     with socket.socket() as sock:
         sock.bind(("127.0.0.1", 0))
         return sock.getsockname()[1]
-
-
-class _RemoteClient:
-    """bench._SocketClient's interface over a port this process does NOT
-    serve — the real-process arm's workers live in their own PIDs, so
-    there is no app/runner to own; ``close()`` only closes the session."""
-
-    class _Addr:
-        def __init__(self, host: str, port: int):
-            self.host, self.port = host, port
-
-    def __init__(self, host: str, port: int, force_close: bool = False,
-                 limit: int | None = None,
-                 keepalive_timeout_s: float | None = None):
-        import aiohttp
-        kwargs = {}
-        if keepalive_timeout_s is not None and not force_close:
-            kwargs["keepalive_timeout"] = keepalive_timeout_s
-        self._session = aiohttp.ClientSession(
-            connector=aiohttp.TCPConnector(
-                # fresh connection per request when asked: each new
-                # connection re-rolls the kernel's SO_REUSEPORT hash, so
-                # readiness probes actually visit DIFFERENT workers
-                force_close=force_close,
-                limit=limit if limit is not None else int(
-                    os.environ.get("BENCH_CLIENT_CONN_LIMIT", "512")),
-                **kwargs))
-        self._base = f"http://{host}:{port}"
-        self.server = self._Addr(host, port)
-
-    def post(self, path: str, **kwargs):
-        return self._session.post(self._base + path, **kwargs)
-
-    def get(self, path: str, **kwargs):
-        return self._session.get(self._base + path, **kwargs)
-
-    def delete(self, path: str, **kwargs):
-        return self._session.delete(self._base + path, **kwargs)
-
-    async def close(self) -> None:
-        await self._session.close()
 
 
 # ------------------------------------------------------------------ scenarios
@@ -417,8 +508,7 @@ async def scenario_burst(app, client, auth, model, scale) -> dict:
     # absorb the overage while every ADMITTED request completes — not
     # that the box magically serves 1500 rps. Saturation shedding for
     # the admin's "default" class is armed only for this arm (the
-    # closed-loop arms above measure unshedded behavior, and the trend
-    # history was recorded that way).
+    # closed-loop arms above measure unshedded behavior).
     shedder = app.get("overload_shedder")
     saved_order = list(shedder.class_order) if shedder is not None else []
     shed_log: dict = {}
@@ -441,12 +531,11 @@ async def scenario_burst(app, client, auth, model, scale) -> dict:
     result["slo"] = await window.close()
     burst_phase = next(p for p in result["phases"] if p["name"] == "burst")
     open_summary = open_phase.summary()
-    return {"scenario": "burst", "value": burst_phase["rps"],
+    return {"scenario": "burst",
             "p50_ms": burst_phase.get("p50_ms"),
             "p95_ms": burst_phase.get("p95_ms"),
-            # not trend-gated alongside value/p95_ms: open-loop latency
-            # is measured from SCHEDULED arrival and is incomparable
-            # with the closed-loop history by construction
+            # open-loop latency is measured from SCHEDULED arrival, so
+            # it is kept apart from the closed-loop phases' p50/p95
             "open_loop": {"offered_rps": scale["burst_open_rate"],
                           "max_in_flight": scale["burst_open_inflight"],
                           "peak_in_flight": open_phase.concurrency,
@@ -471,7 +560,7 @@ async def scenario_ramp(app, client, auth, model, scale) -> dict:
               for conc in scale["ramp_steps"]]
     result = await run_phases(client, auth, kinds, phases)
     result["slo"] = await window.close()
-    return {"scenario": "ramp", "value": result["rps"],
+    return {"scenario": "ramp",
             "p50_ms": result.get("p50_ms"), "p95_ms": result.get("p95_ms"),
             **_strip(result)}
 
@@ -492,7 +581,7 @@ async def scenario_mixed(app, client, auth, model, scale) -> dict:
     result = await run_phases(client, auth, kinds, [
         ("mixed", scale["mixed_concurrency"], scale["mixed_requests"])])
     result["slo"] = await window.close()
-    return {"scenario": "mixed", "value": result["rps"],
+    return {"scenario": "mixed",
             "p50_ms": result.get("p50_ms"), "p95_ms": result.get("p95_ms"),
             "traffic": ["chat", "tools_call", "federation", "a2a"],
             **_strip(result)}
@@ -638,7 +727,7 @@ async def scenario_tenant(app, client, auth, model, scale) -> dict:
     summary = load.summary()
     heavy = slos[ids["tenant-a@scenario.local"]]
     return {
-        "scenario": "tenant", "value": summary["rps"],
+        "scenario": "tenant",
         "p50_ms": summary.get("p50_ms"), "p95_ms": summary.get("p95_ms"),
         "requests": load.requests, "failures": load.failures,
         "wall_s": summary["wall_s"],
@@ -650,7 +739,7 @@ async def scenario_tenant(app, client, auth, model, scale) -> dict:
         "tenant_label_children": sorted(labels),
         "clamp": usage_body["clamp"],
         "rollup_rows": rollup_rows,
-        # the heavy tenant's class window doubles as the capture's
+        # the heavy tenant's class window doubles as the scenario's
         # gate-facing slo block (driver asserts it was MEASURED)
         "slo": heavy, "slo_ok": all(s["ok"] for s in slos.values()),
         "hard_fail": (
@@ -743,7 +832,6 @@ async def scenario_db_outage(app, client, auth, model, scale) -> dict:
     latencies = sorted(x for p in loads + [tail] for x in p.latencies_ms)
     return {
         "scenario": "db-outage",
-        "value": round(requests / wall_s, 2) if wall_s else 0.0,
         "p50_ms": round(latencies[len(latencies) // 2], 2)
         if latencies else None,
         "p95_ms": round(latencies[min(int(len(latencies) * 0.95),
@@ -893,7 +981,6 @@ async def scenario_tier_fault(app, client, auth, model, scale,
                            for x in p.latencies_ms)
         return {
             "scenario": "tier-fault",
-            "value": round(requests / wall_s, 2) if wall_s else 0.0,
             "p50_ms": round(latencies[len(latencies) // 2], 2)
             if latencies else None,
             "p95_ms": round(latencies[min(int(len(latencies) * 0.95),
@@ -1031,8 +1118,6 @@ async def scenario_overload_shed(app, client, auth, model, scale,
                                               since_ts=started_ts)
         return {
             "scenario": "overload-shed",
-            "value": round(load.requests / load.wall_s, 2)
-            if load.wall_s else 0.0,
             "p50_ms": load.summary().get("p50_ms"),
             "p95_ms": load.summary().get("p95_ms"),
             "requests": load.requests,
@@ -1177,7 +1262,6 @@ async def scenario_controller(app, client, auth, model, scale,
             latencies = sorted(x for p in phases for x in p.latencies_ms)
             return {
                 "controller": controller_on,
-                "value": round(requests / wall_s, 2) if wall_s else 0.0,
                 "requests": requests, "failures": failures,
                 "wall_s": round(wall_s, 3),
                 "p50_ms": round(latencies[len(latencies) // 2], 2)
@@ -1222,10 +1306,6 @@ async def scenario_controller(app, client, auth, model, scale,
     off.pop("slo", None)
     return {
         "scenario": "controller",
-        # self-describing for tools/bench_trend.py: a controller round
-        # partitions away from frozen-config history
-        "controller": True,
-        "value": on["value"],
         "p50_ms": on["p50_ms"], "p95_ms": on["p95_ms"],
         "requests": off["requests"] + on["requests"],
         "failures": off["failures"] + on["failures"],
@@ -1344,12 +1424,15 @@ async def scenario_chaos(app, client, auth, model, scale) -> dict:
     post_slow_ts = time.time()
 
     killed: dict = {}
+    traffic_done = asyncio.Event()
 
     async def kill_when_busy():
         # fire once a replica holds in-flight work that has already
         # emitted tokens — the kill must interrupt MID-STREAM, or the
-        # scenario proves nothing about requeue continuations
-        for _ in range(5000):
+        # scenario proves nothing about requeue continuations. Polls
+        # for as long as the phase's traffic runs (a condition, not a
+        # clock: a first-request compile on a busy box may take long)
+        while not traffic_done.is_set():
             ready = [r for r in pool.replicas if r.state == "ready"]
             busy = max(ready, key=lambda r: len(r.outstanding),
                        default=None)
@@ -1371,11 +1454,14 @@ async def scenario_chaos(app, client, auth, model, scale) -> dict:
 
     kill_task = asyncio.ensure_future(kill_when_busy())
     streams_task = asyncio.ensure_future(token_streams())
-    load = await run_phase(
-        client, auth, [chat_kind(model, max_tokens=max_tokens)],
-        name="chaos-load", concurrency=scale["chaos_concurrency"],
-        requests=scale["chaos_requests"])
-    outs = await streams_task
+    try:
+        load = await run_phase(
+            client, auth, [chat_kind(model, max_tokens=max_tokens)],
+            name="chaos-load", concurrency=scale["chaos_concurrency"],
+            requests=scale["chaos_requests"])
+        outs = await streams_task
+    finally:
+        traffic_done.set()
     await kill_task
 
     # rolling reload of the dead replica while residual traffic flows
@@ -1398,7 +1484,7 @@ async def scenario_chaos(app, client, auth, model, scale) -> dict:
                                           since_ts=post_slow_ts)
     return {
         "forensics": forensics,
-        "scenario": "chaos", "value": load.summary()["rps"],
+        "scenario": "chaos",
         "p50_ms": load.summary().get("p50_ms"),
         "p95_ms": load.summary().get("p95_ms"),
         "requests": load.requests + slow.requests
@@ -1441,7 +1527,7 @@ async def scenario_workers(platform, scale) -> dict:
     (a) throughput: the same open-loop offered load against one worker
         vs client-side-LB'd across all N (``scaleup`` = fleet/single;
         on a single-core host the GIL bounds this near 1.0 for
-        in-process workers — the capture records ``in_process`` so the
+        in-process workers — the verdict records ``in_process`` so the
         number is read honestly);
     (b) fleet SLO truth: the scenario window is evaluated at
         ``/admin/slo?scope=fleet`` on worker 0 — TTFT samples live in
@@ -1458,7 +1544,6 @@ async def scenario_workers(platform, scale) -> dict:
 
     from aiohttp import BasicAuth
 
-    from bench import _serve_tcp
     from mcp_context_forge_tpu.config import load_settings
     from mcp_context_forge_tpu.gateway.app import build_app
     from mcp_context_forge_tpu.tools.loadgen import (
@@ -1646,7 +1731,6 @@ async def scenario_workers(platform, scale) -> dict:
         return {
             "scenario": "workers", "workers": workers_n,
             "in_process": True,
-            "value": fleet_summary["rps"],
             "p50_ms": fleet_summary.get("p50_ms"),
             "p95_ms": fleet_summary.get("p95_ms"),
             "requests": single.requests + fleet.requests,
@@ -1721,9 +1805,8 @@ async def scenario_workers_real(platform, scale) -> dict:
     socket, the coordination hub in its own process, the shared engine
     plane electing one pool owner — driven over real TCP from outside
     the fleet. The in-process "workers" arm shares one event loop and
-    one GIL across its "workers"; this arm is the honest complement:
-    ``in_process: false`` in the capture, and tools/bench_trend.py
-    partitions the two histories so neither is judged against the other.
+    one GIL across its "workers"; this arm is the honest complement
+    (``in_process: false`` in its verdict).
 
     Verdicts:
 
@@ -1971,7 +2054,6 @@ async def scenario_workers_real(platform, scale) -> dict:
         "host_cpus": host_cpus,
         "pinned": pin,
         "jax_platform": "cpu",
-        "value": (fleet_summary or {}).get("rps", 0.0),
         "p50_ms": (fleet_summary or {}).get("p50_ms"),
         "p95_ms": (fleet_summary or {}).get("p95_ms"),
         "requests": requests,
@@ -2033,9 +2115,7 @@ async def scenario_fabric(platform, scale) -> dict:
         MISSes (recompute), and ZERO requests fail while it is open.
 
     Engines pin to JAX cpu like workers-real: this arm measures the
-    fabric seam, not device speed. The capture carries ``fabric: true``
-    + ``in_process: false`` so tools/bench_trend.py judges it as its
-    own arm, never against single-host history.
+    fabric seam, not device speed.
     """
     import shutil
     import tempfile
@@ -2391,9 +2471,8 @@ async def scenario_fabric(platform, scale) -> dict:
         shutil.rmtree(tmp, ignore_errors=True)
 
     return {
-        "scenario": "fabric", "fabric": True, "in_process": False,
+        "scenario": "fabric", "in_process": False,
         "workers": 1, "jax_platform": "cpu",
-        "value": summary.get("rps", 0.0),
         "p50_ms": summary.get("p50_ms"), "p95_ms": summary.get("p95_ms"),
         "requests": requests_total, "failures": failures,
         "wall_s": round(time.monotonic() - started, 3),
@@ -2421,37 +2500,6 @@ def _strip(result: dict) -> dict:
 
 # --------------------------------------------------------------------- driver
 
-def _next_round(out_dir: str) -> int:
-    rounds = [0]
-    for path in glob.glob(os.path.join(out_dir, "BENCH_SCENARIO_*_r*.json")):
-        match = re.search(r"_r(\d+)\.json$", path)
-        if match:
-            rounds.append(int(match.group(1)))
-    return max(rounds) + 1
-
-
-def _write_capture(out_dir: str, rnd: int, capture: dict) -> str:
-    # non-CPU platforms get their own filename prefix (the repo's
-    # BENCH_TPU_ vs BENCH_LOCAL_ convention): bench_trend groups series
-    # by prefix, and a TPU round must never be median'd into the CPU
-    # history — the cross-platform delta would read as a regression
-    platform = str(capture.get("platform", "cpu")).upper()
-    arm = "" if platform == "CPU" else f"_{platform}"
-    scenario = capture["scenario"].upper().replace("-", "_")
-    name = f"BENCH_SCENARIO{arm}_{scenario}_r{rnd:02d}.json"
-    # ATOMIC per-arm write, issued as soon as the scenario completes —
-    # a crash / OOM mid-round keeps every finished arm's capture on
-    # disk, and os.replace can never leave a half-written JSON for
-    # bench_trend to choke on
-    path = os.path.join(out_dir, name)
-    tmp = path + ".tmp"
-    with open(tmp, "w") as fh:
-        json.dump(capture, fh, indent=1, sort_keys=True)
-        fh.write("\n")
-    os.replace(tmp, path)
-    return name
-
-
 async def run_scenarios(platform: str) -> dict:
     from aiohttp import BasicAuth
 
@@ -2475,8 +2523,7 @@ async def run_scenarios(platform: str) -> dict:
     if not wanted:
         # nothing selected (BENCH_SCENARIO_ONLY names no real scenario):
         # report the vacuous run without paying a gateway build
-        return {"metric": "gateway_scenario_slo", "scenarios": {},
-                "captures_written": [], "platform": platform,
+        return {"scenarios": {}, "platform": platform,
                 "problems": [f"BENCH_SCENARIO_ONLY={sorted(only)} matches "
                              f"no scenario (have {list(SCENARIOS)})"],
                 "ok": False}
@@ -2484,17 +2531,13 @@ async def run_scenarios(platform: str) -> dict:
     auth = BasicAuth("admin", "changeme")
     app, client, model = await _make_gateway(platform, replicas=2)
     peer = upstream = None
-    captures: list[dict] = []
+    verdicts: list[dict] = []
     problems: list[str] = []
-    written: list[str] = []
     try:
         upstream = await _register_echo_tool(client, auth, "scenario-echo")
         if "mixed" in wanted:
             # federation peer + engine-backed A2A agent for mixed traffic
-            from bench import _make_gateway as _bench_gateway
-            from bench import _register_tool
-            _, peer, _ = await _bench_gateway(engine=False,
-                                              platform=platform)
+            peer = await _make_peer_gateway()
             await _register_tool(peer, upstream, auth, "fed-echo")
             resp = await client.post("/gateways", json={
                 "name": "scenario-peer",
@@ -2533,38 +2576,23 @@ async def run_scenarios(platform: str) -> dict:
             "workers-real": lambda: scenario_workers_real(platform, scale),
             "fabric": lambda: scenario_fabric(platform, scale),
         }
-        out_dir = os.environ.get(
-            "BENCH_SCENARIO_DIR",
-            os.path.dirname(os.path.abspath(__file__)) or ".")
-        write = os.environ.get("BENCH_SCENARIO_WRITE") != "0"
-        rnd = int(os.environ.get("BENCH_SCENARIO_ROUND",
-                                 _next_round(out_dir)))
         for name in wanted:
             started = time.monotonic()
             scenario_t0 = time.time()  # forensics probe window anchor
             try:
-                capture = await runners[name]()
+                verdict = await runners[name]()
             except Exception as exc:
                 problems.append(f"{name}: {type(exc).__name__}: {exc}")
                 continue
-            capture.update({
-                "metric": "gateway_scenario_slo", "unit": "req/s",
+            verdict.update({
                 "platform": platform, "model": model,
                 "smoke": _smoke(),
                 "scenario_wall_s": round(time.monotonic() - started, 2),
             })
-            # worker-count arm partition (tools/bench_trend.py): a
-            # 4-worker round must never median against 1-worker history
-            capture.setdefault("workers", 1)
-            # topology honesty (tools/bench_trend.py): every arm that
-            # did NOT set in_process itself ran inside this process —
-            # real-process rounds must never be judged against (or
-            # seed) the in-process history, and vice versa
-            capture.setdefault("in_process", True)
             # no-vacuous-pass: the scenario must have actually pushed
             # samples through the objectives it claims verdicts for
             unmeasured = assert_slo_measured(
-                capture.get("slo", {}), ["http_p95", "ttft_p95"])
+                verdict.get("slo", {}), ["http_p95", "ttft_p95"])
             if unmeasured:
                 problems.append(f"{name}: " + "; ".join(unmeasured))
             # request forensics (same no-vacuous spirit): the scenario's
@@ -2573,7 +2601,7 @@ async def run_scenarios(platform: str) -> dict:
             # cross-layer stitching proven against real scenario load.
             # since_ts scopes the pick to THIS scenario's rows (the
             # rings span the whole run)
-            if "forensics" not in capture:
+            if "forensics" not in verdict:
                 # dedicated-gateway arms (tier-fault, overload-shed)
                 # probe their OWN gateway's forensics inside the
                 # scenario; everyone else probes the shared one here
@@ -2581,26 +2609,22 @@ async def run_scenarios(platform: str) -> dict:
                     probe_slowest_trace
                 forensics = await probe_slowest_trace(
                     client, auth, since_ts=scenario_t0)
-                capture["forensics"] = forensics
-                for problem in capture["forensics"]["problems"]:
+                verdict["forensics"] = forensics
+                for problem in verdict["forensics"]["problems"]:
                     problems.append(f"{name}: forensics: {problem}")
-            hard = capture.pop("hard_fail", None)
+            hard = verdict.pop("hard_fail", None)
             if hard:
                 problems.append(f"{name}: {hard}")
             # EVERY scenario's request failures gate the run (the chaos
-            # reload-tail included — its failures fold into the capture)
-            if capture.get("failures"):
+            # reload-tail included — its failures fold into the verdict)
+            if verdict.get("failures"):
                 problems.append(
-                    f"{name}: {capture['failures']} request(s) failed")
+                    f"{name}: {verdict['failures']} request(s) failed")
             if (os.environ.get("BENCH_SCENARIO_ENFORCE_SLO") == "1"
-                    and not capture.get("slo_ok", True)):
+                    and not verdict.get("slo_ok", True)):
                 problems.append(f"{name}: SLO window breached "
                                 f"(enforcement on)")
-            captures.append(capture)
-            if write:
-                # durable per-arm capture: written the moment the arm
-                # finishes, not at end-of-round (atomic rename inside)
-                written.append(_write_capture(out_dir, rnd, capture))
+            verdicts.append(verdict)
     finally:
         for c in (peer, upstream, client):
             if c is not None:
@@ -2609,24 +2633,21 @@ async def run_scenarios(platform: str) -> dict:
                 except Exception:
                     pass
     return {
-        "metric": "gateway_scenario_slo",
-        "scenarios": {c["scenario"]: c for c in captures},
-        "captures_written": written,
+        "scenarios": {v["scenario"]: v for v in verdicts},
         "problems": problems,
         "platform": platform,
-        "ok": not problems and bool(captures),
+        "ok": not problems and bool(verdicts),
     }
 
 
 def main() -> int:
-    from bench import pin_platform
     platform = pin_platform()
     report = asyncio.run(run_scenarios(platform))
     print(json.dumps(report))
     if not report["scenarios"]:
         # the no-vacuous-pass rule: a harness that ran nothing must not
         # exit 0 (exit 2, distinct from scenario failures)
-        print("bench-scenarios: FAIL no scenario produced a capture",
+        print("bench-scenarios: FAIL no scenario produced a verdict",
               file=sys.stderr)
         return 2
     for problem in report["problems"]:

@@ -28,24 +28,18 @@ case "$1" in
     shift
     exec python -m mcp_context_forge_tpu.tools.lint "$@"
     ;;
-  bench-check)
-    # bench-history trend gate (tools/bench_trend.py): non-zero exit on
-    # tolerance-breaking regressions across the BENCH_*.json rounds
-    shift
-    exec python -m mcp_context_forge_tpu.tools.bench_trend "$@"
-    ;;
   bench-scenarios)
     # SLO-asserting gateway scenario harness (docs/load_harness.md):
     # burst/ramp/mixed/chaos with /admin/slo verdicts; exits non-zero on
-    # scenario hard-failures or a zero-capture (vacuous) run
+    # scenario hard-failures or a zero-verdict (vacuous) run
     shift
     exec python bench_gateway_scenarios.py "$@"
     ;;
   bench-workers-real)
     # real-process fleet arm (docs/load_harness.md "real-process
     # topology"): N forked serve workers on one SO_REUSEPORT socket
-    # behind a hub process; capture lands with in_process:false and
-    # gates scaleup against 0.8*min(workers, host_cpus)
+    # behind a hub process; gates scaleup against
+    # 0.8*min(workers, host_cpus)
     shift
     BENCH_SCENARIO_ONLY=workers-real BENCH_REAL_PROCS=1 \
       BENCH_SCENARIO_ENFORCE_SLO=1 \

@@ -1,8 +1,6 @@
 """Scenario-shaping gateway load generator (SLO-asserting harness core).
 
-``testing/loadgen.py`` is the raw multi-process throughput worker (the
-1k-concurrency north-star driver); THIS module is the shape layer above
-it: named traffic scenarios — burst, diurnal ramp, mixed workloads,
+Named traffic scenarios — burst, diurnal ramp, mixed workloads,
 chaos — driven against an in-process gateway client, with SLO verdicts
 pulled from ``GET /admin/slo`` per-consumer delta windows instead of
 re-deriving percentiles client-side. ROADMAP item 5 names exactly this:
@@ -13,8 +11,8 @@ the precondition for serving-tier scale-out.
 
 The client contract is duck-typed: anything with aiohttp-style
 ``post(path, json=..., auth=...)`` / ``get(path, ...)`` — an
-``aiohttp.test_utils.TestClient`` in tier-1 smoke, ``bench.py``'s
-real-socket ``_SocketClient`` in the bench driver. Pure asyncio; never
+``aiohttp.test_utils.TestClient`` in tier-1 smoke, the real-socket
+``_SocketClient`` of ``bench_gateway_scenarios.py``. Pure asyncio; never
 imports jax (the harness builds the gateway, not this module).
 
 Usage shape (see ``bench_gateway_scenarios.py``)::

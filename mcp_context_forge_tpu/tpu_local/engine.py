@@ -3205,7 +3205,7 @@ class TPUEngine:
 
     def roofline_snapshot(self) -> dict[str, Any]:
         """Aggregate cost-model roofline over the recent decode window
-        (the live twin of bench_engine's post-hoc mfu/hbm numbers)."""
+        (the live gauges' numbers, read at ``/admin/engine/steps``)."""
         flops = byts = dur = 0.0
         window = list(self._roofline_window)
         for f, b, d in window:

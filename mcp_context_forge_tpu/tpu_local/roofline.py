@@ -1,9 +1,9 @@
 """XLA cost-model registry + roofline math for the live MFU / HBM gauges.
 
-``bench_engine.py`` computes MFU and ``hbm_roofline_frac`` after the fact
-from analytic byte counts — useful for captures, invisible in production.
-This module makes the same numbers ALWAYS-ON: at warmup the engine lowers
-each compiled executable once more through the AOT path and records XLA's
+MFU and ``hbm_roofline_frac`` computed after the fact from analytic byte
+counts are invisible in production. This module makes the same numbers
+ALWAYS-ON: at warmup the engine lowers each compiled executable once
+more through the AOT path and records XLA's
 own ``cost_analysis()`` (FLOPs, bytes accessed) into a per-engine
 :class:`CostRegistry`; every decode retire then divides the dispatched
 executable's cost by its measured wall to feed the
@@ -16,8 +16,8 @@ item 1 replaces the two settings with a table keyed by ``device_kind``).
 Anything that PRINTS a fraction of a peak asks :func:`v5e_peaks` first and
 prints ``null`` for any other device.
 
-Pure stdlib on purpose: imported by ``bench_engine.py`` before the jax
-platform is pinned, so it must not import jax at module scope.
+Pure stdlib on purpose: importable before the jax platform is pinned, so
+it must not import jax at module scope.
 """
 
 from __future__ import annotations
@@ -25,8 +25,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any
 
-# TPU v5e, per chip (Google Cloud documentation, "TPU v5e"; also the
-# single source for bench_engine.py)
+# TPU v5e, per chip (Google Cloud documentation, "TPU v5e")
 V5E_PEAK_BF16_TFLOPS = 197.0
 V5E_HBM_GBPS = 819.0
 V5E_DEVICE_KIND = "TPU v5 lite"   # what jax reports as device_kind

@@ -103,9 +103,11 @@ def test_full_machine_mesh_shape_falls_back_per_replica():
         assert replica.engine.mesh.size >= 1
 
 
-def test_pool_greedy_parity_with_single_engine():
-    """Seeded greedy token parity: routing across 2 replicas must be
-    invisible in the token streams."""
+@pytest.fixture(scope="module")
+def healthy_pool_run():
+    """Six greedy prompts gathered through a healthy pool of 2, beside
+    what a single uninterrupted engine emits for them; the stopped pool
+    rides along for its counters."""
     prompts = [f"parity prompt {i} with a few extra words" for i in range(6)]
 
     async def main():
@@ -120,13 +122,34 @@ def test_pool_greedy_parity_with_single_engine():
             outs = await asyncio.gather(*[gen(p) for p in prompts])
         finally:
             await pool.stop()
-        assert [list(o) for o in outs] == refs
-        # both replicas actually served (least-outstanding spreads load)
-        assert all(r.routed > 0 for r in pool.replicas), \
-            [r.routed for r in pool.replicas]
-        assert pool.requeues == 0
+        return refs, [list(o) for o in outs], pool
 
-    asyncio.run(main())
+    return asyncio.run(main())
+
+
+def test_pool_greedy_parity_with_single_engine(healthy_pool_run):
+    """Seeded greedy token parity: routing across 2 replicas must be
+    invisible in the token streams."""
+    refs, outs, pool = healthy_pool_run
+    assert outs == refs
+    # both replicas actually served (least-outstanding spreads load)
+    assert all(r.routed > 0 for r in pool.replicas), \
+        [r.routed for r in pool.replicas]
+    assert pool.requeues == 0
+
+
+def test_pool_accounts_every_token_to_the_replica_that_served_it(
+        healthy_pool_run):
+    """The pool's balance report adds up: every request was routed once,
+    every emitted token is counted on exactly one replica's stats, and a
+    uniform pool (no roles) never migrates."""
+    _, outs, pool = healthy_pool_run
+    assert pool.router.counters()["routed"] == len(outs)
+    assert sum(r.routed for r in pool.replicas) == len(outs)
+    assert [r.id for r in pool.replicas] == ["0", "1"]
+    assert sum(r.engine.stats.completion_tokens
+               for r in pool.replicas) == sum(len(o) for o in outs)
+    assert pool.migrations == {"ok": 0, "degraded": 0}
 
 
 def test_prefix_affinity_routes_to_cached_replica():

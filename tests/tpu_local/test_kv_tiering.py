@@ -291,8 +291,11 @@ def test_tier_roundtrip_byte_identical_continuation(kv_quant, num_pages,
         assert stats["spills"] >= 1 and stats["restores"] >= 1
         alloc = tiered.allocator
         assert alloc.tier_hit_tokens["host"] >= 2 * PS
-        # tiers held hits the page budget alone could not
-        assert alloc.prefix_hit_tokens > plain.allocator.prefix_hit_tokens
+        # tiers held hits the page budget alone could not: at the same
+        # fixed page budget, at least twice the tier-less engine's
+        assert tiered.num_kv_pages == plain.num_kv_pages
+        assert alloc.prefix_hit_tokens \
+            >= 2 * max(1, plain.allocator.prefix_hit_tokens)
         # conservation: the tier split sums to the headline counter the
         # tenant ledger's cache_hit accounting mirrors
         assert sum(alloc.tier_hit_tokens.values()) == alloc.prefix_hit_tokens

@@ -194,7 +194,12 @@ def test_kill_mid_generation_requeues_through_tier_restore():
         finally:
             await ref.stop()
 
-        pool = _pool(replicas=2, num_pages=5)
+        # resident-precision spill: the float32 pool's default
+        # (tier_spill_quant="int8") quantizes pages on spill, so a
+        # restored prefix is close but not bit-equal and 16 greedy tokens
+        # may drift (test_kv_tiering pins that drift); byte identity
+        # through the requeue is promised for the lossless mode only
+        pool = _pool(replicas=2, num_pages=5, tier_spill_quant="")
         await pool.start()
         try:
             r0 = pool.replicas[0].engine

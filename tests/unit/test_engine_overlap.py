@@ -87,6 +87,9 @@ def test_overlap_matches_serial_token_streams():
         if overlap:
             assert engine.stats.overlap_steps > 0, \
                 "pipeline never engaged (no device-fed dispatches)"
+        else:
+            # the serial arm feeds no dispatch from the device
+            assert engine.stats.overlap_steps == 0
     assert outs[True] == outs[False]
 
 
