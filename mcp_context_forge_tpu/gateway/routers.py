@@ -841,6 +841,15 @@ document.getElementById("f").onsubmit = async (e) => {
                 "steps": stats.spec_steps,
                 "extra_tokens": stats.spec_tokens,
             },
+            # routed experts: tokens through expert layers and pairs on
+            # held experts (counted on the device by families that do),
+            # and steps by the formulation their expert FFN took
+            "moe": {
+                "tokens": stats.moe_tokens,
+                "local_pairs": stats.moe_local_pairs,
+                "grouped_steps": stats.moe_grouped_steps,
+                "scan_steps": stats.moe_scan_steps,
+            },
         })
 
     @routes.get("/admin/slo")

@@ -47,7 +47,6 @@ from .compile_events import (CompileTracker, install_listener,
 from .kv import PageAllocator
 from .models import MODEL_CONFIGS, LlamaConfig
 from .models import family_of
-from .ops.attention import on_tpu
 from .parallel import make_mesh, param_specs
 from .roofline import (V5E_HBM_GBPS, V5E_PEAK_BF16_TFLOPS, CostRegistry,
                        roofline_fractions)
@@ -174,8 +173,8 @@ class EngineConfig:
     # MoE serving formulation override ("" = model default; see
     # models/configs.py moe_impl): dense | grouped | grouped_pallas.
     # moe_block overrides the kernel row-block AND the T·k >= E·block
-    # engagement gate (0 = model default) — small models/benches need a
-    # smaller block or every dispatch falls back to the dense scan.
+    # engagement gate (0 = model default) — small models need a smaller
+    # block or every dispatch takes the expert scan.
     moe_impl: str = ""
     moe_block: int = 0
     # decode batch-width bucketing: size decode arrays by the ACTIVE slot
@@ -400,6 +399,10 @@ class EngineStats:
         # (models/deepseek.py), read back with each step's tokens
         self.moe_tokens = 0           # tokens through expert layers
         self.moe_local_pairs = 0      # token-expert pairs on held experts
+        # steps whose expert FFN took each formulation (the family's
+        # ``expert_path``: static a program, so counted at dispatch)
+        self.moe_grouped_steps = 0    # chosen experts only, row-blocks
+        self.moe_scan_steps = 0       # every held expert, gate-masked
 
 
 def _named(jitted, name: str):
@@ -771,16 +774,6 @@ class TPUEngine:
             raise ValueError(
                 f"moe_impl must be dense|grouped|grouped_pallas, "
                 f"got {config.moe_impl!r}")
-        if (self.model_config.moe_impl == "grouped_pallas"
-                and on_tpu(self.mesh)
-                and self.mesh.shape.get("model", 1) > 1):
-            # under plain jit XLA would gather the sharded expert stacks
-            # to every chip (or refuse to partition the kernel): say so
-            # instead of serving that
-            raise NotImplementedError(
-                "moe_impl='grouped_pallas' on a TPU mesh wider than one "
-                "device: the grouped kernel is not wrapped in shard_map "
-                "over the model axis — use moe_impl='grouped' there")
         # params: load checkpoint or random-init, placed with TP shardings;
         # quant="int8" swaps in the {"q","s"} tree (quantize.py)
         with self.mesh:
@@ -2436,6 +2429,7 @@ class TPUEngine:
         self.stats.prefill_batches += 1
         self.stats.prefill_requests += len(admitted)
         width = int(tokens.shape[0])  # the dispatched pad
+        self._count_expert_path(width * bucket)
         tl.step(seq, kind, width, len(admitted), bucket, dispatch.t0, sync.t1,
                 counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
@@ -2530,6 +2524,7 @@ class TPUEngine:
         self.stats.prefill_batches += 1
         self.stats.prefill_ms_total += elapsed_ms
         width = int(tokens.shape[0])
+        self._count_expert_path(width * S)
         tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1,
                 counts)
         self._record_step(
@@ -2614,6 +2609,7 @@ class TPUEngine:
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 1
         self.stats.spec_steps += 1
+        self._count_expert_path(B * K)
         with tl.span("decode.readback", seq, "spec") as readback:
             block_host = jax.device_get(block)  # [B, K]  # lint: allow[host-sync-in-hot-path] spec verify: host must compare drafts to accept
         spec_elapsed_ms = (readback.t1 - dispatch.t0) * 1000
@@ -2987,6 +2983,7 @@ class TPUEngine:
             pass
         self.stats.decode_steps += k
         self.stats.decode_dispatches += 1
+        self._count_expert_path(B, steps=k)
         return {"block": block_tokens, "valid": block_valid,
                 "done": block_done, "aux": block_aux,
                 "budgets": budgets, "reqs": reqs,
@@ -3257,6 +3254,16 @@ class TPUEngine:
         """Warmup/serving XLA compile counts + timings (admin surfaces,
         pool status, support bundle)."""
         return self.compile_tracker.snapshot()
+
+    def _count_expert_path(self, tokens: int, steps: int = 1) -> None:
+        """Count ``steps`` steps of ``tokens`` tokens each by the expert
+        formulation their program traced (nothing for a model without
+        routed experts)."""
+        path = self._family.expert_path(self.model_config, self.mesh, tokens)
+        if path == "grouped":
+            self.stats.moe_grouped_steps += steps
+        elif path == "scan":
+            self.stats.moe_scan_steps += steps
 
     def _step_counts(self, aux: list) -> StepCounts | None:
         """What a step program counted on the device, from what it returned

@@ -7,8 +7,8 @@ experts) with the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
 ``kv_logical`` / ``kv_page_bytes``, the kernel choices ``prefill_impl`` /
-``paged_impl``, and ``refusals`` (engine settings the family cannot serve
-yet). The engine finds the module from the model config's CLASS
+``paged_impl`` / ``expert_path``, and ``refusals`` (engine settings the
+family cannot serve yet). The engine finds the module from the model config's CLASS
 (:func:`family_of`): nothing else chooses it."""
 
 from importlib import import_module

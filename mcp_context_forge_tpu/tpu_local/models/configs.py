@@ -36,22 +36,28 @@ class LlamaConfig:
     embed_scale: bool = False     # multiply embeddings by sqrt(dim)
     norm_plus_one: bool = False   # RMSNorm scales by (1 + weight)
     # MoE (Mixtral family): n_experts > 0 replaces the dense FFN with a
-    # top-k routed expert FFN. ``moe_impl`` picks the drop-free serving
-    # formulation (all compute the same per-token function):
-    #   dense          — expert scan with gate masks (E/k x FLOPs waste;
-    #                    no gathers — safe default everywhere)
-    #   grouped        — block-sparse grouped GEMM, XLA gathered weights
-    #                    (~k/E FLOPs; gathers materialize — small models)
-    #   grouped_pallas — block-sparse grouped GEMM, Pallas kernel (TPU:
-    #                    weight tiles DMA per block via scalar prefetch)
+    # top-k routed expert FFN. Two drop-free serving formulations compute
+    # the same per-token function, and models/llama.py: expert_path picks
+    # one a STEP from what it can see — no caller tunes it:
+    #   the row-block kernel (ops/grouped_moe.py: each token's chosen
+    #   experts only, the bucket's padding tokens given no row, weight
+    #   tiles DMA'd a block via scalar prefetch) for a step wide enough
+    #   that T·k >= E·moe_block, on a mesh whose ``model`` axis is one
+    #   device: prefills, chunk rounds, wide history suffixes;
+    #   the expert scan with gate masks (parallel/moe.py: E/k x the FLOPs,
+    #   no gathers) for every other step — decode and verify steps
+    #   (T = batch width), where it is at its floor of one read of every
+    #   expert's weights, a TP mesh, and callers that differentiate.
+    # ``moe_impl`` names the family's widest choice: grouped_pallas (the
+    # rule above), grouped (the same plan through XLA's gathered-weights
+    # einsum, which materializes [NB, D, F]: small models and tests) or
+    # dense (the scan always). The engine's ``moe_impl`` override exists
+    # for an A/B until ROADMAP Queue 3 ``unmeasured-options`` removes it.
+    # moe_block is the kernel's row-block and the gate's width.
     # parallel/moe.py's capacity dispatch stays the EP-training path.
-    # The grouped path only pays when T·k >= E·moe_block (its padded-row
-    # bound is T·k + E·moe_block vs dense's E·T): prefill clears the bar,
-    # decode (T = batch width) never does — those steps fall back to the
-    # dense scan automatically. moe_block is also the kernel's row-block.
     n_experts: int = 0
     moe_top_k: int = 2
-    moe_impl: str = "dense"
+    moe_impl: str = "grouped_pallas"
     moe_block: int = 128
 
     @property

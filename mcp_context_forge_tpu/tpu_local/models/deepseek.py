@@ -302,6 +302,16 @@ def route(layer: dict[str, Any], config: DeepseekConfig,
     return ids.astype(jnp.int32), weights, biased
 
 
+def expert_path(config: DeepseekConfig, mesh, tokens: int) -> str:
+    """Which formulation the routed experts of a step of ``tokens`` tokens
+    trace (the engine counts its steps by the same call): the dropless
+    grouped row-blocks for steps of at least ``moe_block`` tokens, the
+    expert scan for narrower ones (decode)."""
+    wide = tokens >= config.moe_block
+    return ("grouped" if config.moe_impl.startswith("grouped") and wide
+            else "scan")
+
+
 def _expert_ffn(layer: dict[str, Any], config: DeepseekConfig, x: jax.Array,
                 valid: jax.Array, mesh=None) -> tuple[jax.Array, jax.Array]:
     """The held routed experts' part + the shared expert. x: [B, S, D];
@@ -316,7 +326,7 @@ def _expert_ffn(layer: dict[str, Any], config: DeepseekConfig, x: jax.Array,
     here = (ids >= lo) & (ids < hi)
     pairs = jnp.sum((here & valid.reshape(-1, 1)).astype(jnp.float32))
     stacks = {k: layer[k] for k in ("w1", "w3", "w2")}
-    if c.moe_impl.startswith("grouped") and T >= c.moe_block:
+    if expert_path(c, mesh, T) == "grouped":
         from ..ops.grouped_moe import experts_grouped, plan_sorted_blocks
         plan = plan_sorted_blocks(local, weights, c.n_held, c.moe_block)
         use_pallas = c.moe_impl == "grouped_pallas"

@@ -256,48 +256,69 @@ def _ffn(layer: dict[str, Any], x: jax.Array,
     return qmm(gate * qmm(x, layer["w3"]), layer["w2"])
 
 
+def expert_path(config: LlamaConfig, mesh, tokens: int) -> str | None:
+    """Which formulation the expert FFN of a step of ``tokens`` tokens
+    traces, from what is visible before tracing — ``"grouped"`` (each
+    token's chosen experts only, ops/grouped_moe.py), ``"scan"`` (every
+    expert over every token, gate-masked: parallel/moe.py) — or None for a
+    model without a router. The engine counts its steps by the same call.
+
+    Grouped row-blocks pay when T·k >= E·block (padded rows T·k + E·block
+    against the scan's E·T): prefills, chunk rounds and wide history
+    suffixes clear it, decode and verify steps (T = batch width) do not, and
+    for them the scan is at its floor anyway — a step reads every expert's
+    weights once. The row-block KERNEL runs where the caller's mesh says
+    one device holds the whole stacks: it is not wrapped in shard_map, so on
+    a ``model`` axis wider than one device XLA would gather the sharded
+    stacks to every chip, and it has no gradient rule, so a caller that
+    names no mesh (training, the pipeline stages) keeps the scan too."""
+    if not config.n_experts:
+        return None
+    wide = tokens * config.moe_top_k >= config.n_experts * config.moe_block
+    whole = mesh is not None and mesh.shape.get("model", 1) == 1
+    if (not config.moe_impl.startswith("grouped") or not wide
+            or (config.moe_impl == "grouped_pallas" and not whole)):
+        return "scan"
+    return "grouped"
+
+
 def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
-               x: jax.Array, mesh=None) -> jax.Array:
+               x: jax.Array, mesh=None,
+               valid: jax.Array | None = None) -> jax.Array:
     """Dense SwiGLU/GeGLU, or top-k routed MoE when the layer carries a
     router (Mixtral family).
 
-    The SERVING trunk runs the drop-free expert-scan formulation
-    (parallel/moe.py moe_ffn_dense_mask): capacity drops make a layer's
-    output a function of the BATCH SHAPE — a token dropped in an
-    11-token prefill but kept in a 1-token decode would break the
+    Both serving formulations are DROP-FREE and compute the same per-token
+    function (:func:`expert_path` says which a step takes): capacity drops
+    make a layer's output a function of the BATCH SHAPE — a token dropped
+    in an 11-token prefill but kept in a 1-token decode would break the
     incremental-decode invariant (prefill + decode must equal one long
-    prefill). EP fleets with an 'expert' mesh axis use moe_ffn's
-    capacity dispatch instead (all_to_all lowering, Switch drop
-    policy)."""
-    if "router" in layer:
-        from ..parallel.moe import MoEConfig, moe_ffn_dense_mask
+    prefill). EP fleets with an 'expert' mesh axis use moe_ffn's capacity
+    dispatch instead (all_to_all lowering, Switch drop policy).
 
-        moe_cfg = MoEConfig(dim=config.dim, n_experts=config.n_experts,
-                            expert_hidden=config.ffn_hidden,
-                            top_k=config.moe_top_k)
-        moe_params = {k: layer[k] for k in ("router", "w1", "w3", "w2")}
-        impl = getattr(config, "moe_impl", "dense")
-        block = getattr(config, "moe_block", 128)
-        T = x.shape[0] * x.shape[1]
-        # grouped pays only when T·k >= E·block (padded rows T·k+E·block
-        # vs dense's E·T): prefill yes, decode (T = batch width) no —
-        # decode steps ALWAYS run the dense scan
-        if (impl.startswith("grouped")
-                and T * config.moe_top_k >= config.n_experts * block):
-            # block-sparse grouped GEMM: ~top_k/E of the dense-mask
-            # FLOPs, exact-parity (ops/grouped_moe.py). The kernel path
-            # interprets off-TPU (the caller's mesh says which) so the
-            # code path exists everywhere.
-            from ..ops.attention import on_tpu
-            from ..ops.grouped_moe import moe_ffn_grouped
-            use_pallas = impl == "grouped_pallas"
-            return moe_ffn_grouped(
-                moe_params, x, moe_cfg, act=config.hidden_act,
-                impl="pallas" if use_pallas else "xla", block=block,
-                interpret=use_pallas and not on_tpu(mesh))
-        return moe_ffn_dense_mask(moe_params, x, moe_cfg,
-                                  act=config.hidden_act)
-    return _ffn(layer, x, config.hidden_act)
+    ``valid`` [B, S]: False marks the bucket's padding tokens. The grouped
+    path gives their pairs no row and their output is zero; the scan
+    computes them like any token. Nothing reads either (no KV write, no
+    sample)."""
+    if "router" not in layer:
+        return _ffn(layer, x, config.hidden_act)
+    from ..parallel.moe import MoEConfig, moe_ffn_dense_mask
+
+    moe_cfg = MoEConfig(dim=config.dim, n_experts=config.n_experts,
+                        expert_hidden=config.ffn_hidden,
+                        top_k=config.moe_top_k)
+    moe_params = {k: layer[k] for k in ("router", "w1", "w3", "w2")}
+    if expert_path(config, mesh, x.shape[0] * x.shape[1]) == "grouped":
+        # the kernel interprets off-TPU (the caller's mesh says which) so
+        # the code path exists everywhere
+        from ..ops.attention import on_tpu
+        from ..ops.grouped_moe import moe_ffn_grouped
+        use_pallas = config.moe_impl == "grouped_pallas"
+        return moe_ffn_grouped(
+            moe_params, x, moe_cfg, act=config.hidden_act,
+            impl="pallas" if use_pallas else "xla", block=config.moe_block,
+            interpret=use_pallas and not on_tpu(mesh), valid=valid)
+    return moe_ffn_dense_mask(moe_params, x, moe_cfg, act=config.hidden_act)
 
 
 def prefill(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
@@ -328,7 +349,7 @@ def prefill(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
                                 mesh=mesh)  # [B,S,H,hd]
         x = x + qmm(attn.reshape(*attn.shape[:2], -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], config.norm_eps, config.norm_plus_one)
-        x = x + _ffn_block(layer, config, h, mesh)
+        x = x + _ffn_block(layer, config, h, mesh, mask_valid)
     x = rms_norm(x, params["final_norm"], config.norm_eps, config.norm_plus_one)
     if last_idx is not None:
         x = x[jnp.arange(x.shape[0]), last_idx]  # [B, D] before the lm head
@@ -404,7 +425,7 @@ def prefill_with_history(params: dict[str, Any], config: LlamaConfig,
         attn = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
         x = x + qmm(attn.reshape(B, S, -1), layer["wo"])
         h = rms_norm(x, layer["ffn_norm"], config.norm_eps, config.norm_plus_one)
-        x = x + _ffn_block(layer, config, h, mesh)
+        x = x + _ffn_block(layer, config, h, mesh, mask_valid)
     x = rms_norm(x, params["final_norm"], config.norm_eps, config.norm_plus_one)
     if last_idx is not None:  # serving: one next-token row per request
         x = x[jnp.arange(B), last_idx]

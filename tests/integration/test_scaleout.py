@@ -198,16 +198,23 @@ async def test_fleet_metrics_and_slo_aggregate_both_workers():
                 break
             await asyncio.sleep(0.05)
         assert fleet_a.live_peers(), "worker A never saw B's frames"
-        resp = await a.get("/metrics/prometheus?scope=fleet", auth=AUTH)
-        assert resp.status == 200
-        text = await resp.text()
+        # counters sum across workers: both workers served /health. A frame
+        # B published before its /health request may be the one A holds, so
+        # wait for the sum and not for a peer alone
+        for _ in range(50):
+            resp = await a.get("/metrics/prometheus?scope=fleet", auth=AUTH)
+            assert resp.status == 200
+            text = await resp.text()
+            line = next(l for l in text.splitlines()
+                        if l.startswith("mcpforge_http_requests_total")
+                        and 'path="/health"' in l)
+            if float(line.rsplit(" ", 1)[1]) >= 2.0:
+                break
+            await b.app["fleet_metrics"].publish_once()
+            await asyncio.sleep(0.05)
+        assert float(line.rsplit(" ", 1)[1]) >= 2.0
         # gauges keep per-worker truth under an added worker label
         assert 'worker="' in text
-        # counters sum across workers: both workers served /health
-        line = next(l for l in text.splitlines()
-                    if l.startswith("mcpforge_http_requests_total")
-                    and 'path="/health"' in l)
-        assert float(line.rsplit(" ", 1)[1]) >= 2.0
         resp = await a.get("/admin/slo?scope=fleet", auth=AUTH)
         assert resp.status == 200
         assert (await resp.json())["scope"] == "fleet"

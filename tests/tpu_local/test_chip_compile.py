@@ -77,22 +77,24 @@ def _flash_case(seq: int):
     return attention.flash_attention_pallas, [q, kv, kv, ((2, seq), jnp.bool_)]
 
 
-def _moe_case(quantized: bool):
-    n_blocks = 2048 * 2 // BLOCK + E
+def _moe_case(quantized: bool, n_blocks: int = 2048 * 2 // BLOCK + E,
+              live: bool = False):
+    """The row-block kernel over bf16 or int8 stacks; ``live``: with the
+    count of live blocks given (dead ones skip)."""
     x = ((n_blocks, BLOCK, D), jnp.bfloat16)
-    owner = ((n_blocks,), jnp.int32)
+    tail = [((n_blocks,), jnp.int32)] + ([((1,), jnp.int32)] if live else [])
     if not quantized:
         up, down = ((E, D, F), jnp.bfloat16), ((E, F, D), jnp.bfloat16)
         return (partial(grouped_moe._expert_blocks_pallas, block=BLOCK),
-                [x, up, up, down, owner])
+                [x, up, up, down, *tail])
     up = [((E, D, F), jnp.int8), ((E, F), jnp.bfloat16)]
     down = [((E, F, D), jnp.int8), ((E, D), jnp.bfloat16)]
 
-    def fn(x, q1, s1, q3, s3, q2, s2, owner):
-        return grouped_moe._expert_blocks_pallas_q8(
+    def fn(x, q1, s1, q3, s3, q2, s2, *tail):
+        return grouped_moe._expert_blocks_pallas(
             x, {"q": q1, "s": s1}, {"q": q3, "s": s3}, {"q": q2, "s": s2},
-            owner, block=BLOCK)
-    return fn, [x, *up, *up, *down, owner]
+            *tail, block=BLOCK)
+    return fn, [x, *up, *up, *down, *tail]
 
 
 # deepseek-v3.2-d5-ep16.longctx-closed: 5 layers, 1152 pages of 128, latent
@@ -190,6 +192,11 @@ KERNEL_CASES = {
     "grouped_moe_cell_16_held_experts": _held_experts_case,
     "grouped_moe_bf16": lambda: _moe_case(False),
     "grouped_moe_int8": lambda: _moe_case(True),
+    # mixtral-8x7b-d8.chat's prefills: [1, 2, 4] x 512 tokens top-2 are
+    # 16, 24 and 40 row-blocks of which the live ones follow the prompts
+    "grouped_moe_int8_cell_1x512": lambda: _moe_case(True, 16, live=True),
+    "grouped_moe_int8_cell_2x512": lambda: _moe_case(True, 24, live=True),
+    "grouped_moe_int8_cell_4x512": lambda: _moe_case(True, 40, live=True),
 }
 
 

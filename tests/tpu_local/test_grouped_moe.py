@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from mcp_context_forge_tpu.tpu_local.ops.grouped_moe import (
-    grouped_flops, moe_ffn_grouped, route_sorted_blocks)
+    _expert_blocks_pallas, _expert_blocks_xla, grouped_flops, moe_ffn_grouped,
+    plan_sorted_blocks, top_k_gates)
 from mcp_context_forge_tpu.tpu_local.parallel.moe import (
     MoEConfig, init_moe_params, moe_ffn_dense_mask, router_probs)
 
@@ -32,7 +33,8 @@ def test_routing_plan_invariants():
     probs = jax.nn.softmax(
         jax.random.normal(jax.random.PRNGKey(3), (50, CFG.n_experts)),
         axis=-1)
-    plan = route_sorted_blocks(probs, CFG.top_k, block=16)
+    plan = plan_sorted_blocks(*top_k_gates(probs, CFG.top_k), CFG.n_experts,
+                              block=16)
     NB = plan["block_expert"].shape[0]
     assert NB == -(-50 * CFG.top_k // 16) + CFG.n_experts
     valid = np.asarray(plan["row_valid"])
@@ -118,15 +120,11 @@ def test_gelu_activation_parity():
 
 
 def test_quantized_experts_route_through_xla_path():
-    from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
-
     params = _params()
     # the serving trunk's logical names (models/llama.py moe layer): the
     # _QUANT_RULES table covers moe_up/moe_down — NOT the EP-training
     # "expert_stack" name, which would silently skip quantization
-    logical = {"router": "replicated", "w1": "moe_up", "w3": "moe_up",
-               "w2": "moe_down"}
-    qparams = quantize_tree(dict(params), logical)
+    qparams = _quantized(params)
     from mcp_context_forge_tpu.tpu_local.quantize import is_quant
     assert is_quant(qparams["w1"]) and is_quant(qparams["w2"])
     x = _x()
@@ -141,6 +139,84 @@ def test_quantized_experts_route_through_xla_path():
                                rtol=2e-5, atol=2e-6)
 
 
+def _quantized(params):
+    from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
+
+    return quantize_tree(dict(params), {"router": "replicated", "w1": "moe_up",
+                                        "w3": "moe_up", "w2": "moe_down"})
+
+
+@pytest.mark.parametrize("stacks", ["full", "int8"])
+def test_kernel_skips_dead_blocks_and_writes_zeros(stacks):
+    """One skip rule in both kernel variants: a block at or past
+    ``live_blocks`` computes nothing and writes zeros, whatever its rows
+    hold; the live blocks equal the XLA path's. Dead blocks carry NaN rows
+    here, so a block that WAS computed would show."""
+    params = _params() if stacks == "full" else _quantized(_params())
+    NB, block, live = 7, 16, 4
+    x_pad = jax.random.normal(jax.random.PRNGKey(5), (NB, block, CFG.dim))
+    x_pad = x_pad.at[live:].set(jnp.nan)
+    owner = jnp.asarray([0, 2, 2, 5, 7, 7, 7], jnp.int32)
+    weights = (params["w1"], params["w3"], params["w2"])
+    out = _expert_blocks_pallas(x_pad, *weights, owner,
+                                jnp.asarray([live], jnp.int32),
+                                block=block, interpret=True)
+    want = _expert_blocks_xla(x_pad[:live], *weights, owner[:live], "silu")
+    np.testing.assert_allclose(np.asarray(out[:live]), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    assert not np.asarray(out[live:]).any()
+    # no count given: every block is live (and the NaN rows come through)
+    every = _expert_blocks_pallas(x_pad, *weights, owner, block=block,
+                                  interpret=True)
+    assert np.isnan(np.asarray(every[live:])).all()
+
+
+@pytest.mark.parametrize("impl,stacks", [("xla", "full"), ("pallas", "full"),
+                                         ("pallas", "int8")])
+def test_padding_tokens_get_no_row(impl, stacks):
+    """The step's validity mask reaches the plan: a padding token's pairs
+    get no row, so the live blocks follow the live pairs and not the
+    bucket; valid tokens equal the scan's; padding tokens read zero; and
+    the same tokens under another padding give the same rows."""
+    params = _params() if stacks == "full" else _quantized(_params())
+    tol = dict(rtol=2e-5, atol=2e-6) if stacks == "full" else dict(
+        rtol=1e-3, atol=1e-4)
+    block, live = 16, 21
+    x = _x((2, 48), seed=13)
+    valid = jnp.arange(48)[None, :] < jnp.asarray([[live], [9]])
+
+    flat = x.reshape(-1, CFG.dim)
+    ids, gates = top_k_gates(router_probs(params["router"], flat), CFG.top_k)
+    masked = jnp.where(valid.reshape(-1, 1), ids, CFG.n_experts)
+    plan = plan_sorted_blocks(masked, gates, CFG.n_experts, block)
+    rows = np.asarray(plan["row_valid"])
+    assert rows.sum() == (live + 9) * CFG.top_k
+    counts = np.bincount(np.asarray(masked).ravel(),
+                         minlength=CFG.n_experts + 1)[:CFG.n_experts]
+    assert int(plan["live_blocks"][0]) == int(np.ceil(counts / block).sum())
+    full = plan_sorted_blocks(ids, gates, CFG.n_experts, block)
+    assert int(plan["live_blocks"][0]) < int(full["live_blocks"][0])
+    # the inverse: a live pair's row feeds from its token, a dropped pair
+    # points past the buffer
+    pair_row = np.asarray(plan["pair_row"])
+    assert (pair_row[~np.asarray(valid).ravel()] == rows.size).all()
+    token_of = np.asarray(plan["sorted_token"])
+    for t in np.nonzero(np.asarray(valid).ravel())[0]:
+        assert (token_of[pair_row[t]] == t).all()
+
+    kw = dict(impl=impl, block=block, interpret=impl == "pallas")
+    out = moe_ffn_grouped(params, x, CFG, valid=valid, **kw)
+    scan = moe_ffn_dense_mask(params, x, CFG)
+    m = np.asarray(valid)
+    np.testing.assert_allclose(np.asarray(out)[m], np.asarray(scan)[m], **tol)
+    assert not np.asarray(out)[~m].any()
+    # the first row's live tokens alone, padded to another bucket
+    alone = moe_ffn_grouped(params, jnp.pad(x[:1, :live], ((0, 0), (0, 11), (0, 0))),
+                            CFG, valid=jnp.arange(32)[None, :] < live, **kw)
+    np.testing.assert_allclose(np.asarray(alone[0, :live]),
+                               np.asarray(out[0, :live]), rtol=2e-5, atol=2e-6)
+
+
 def test_flops_accounting_near_topk_over_e():
     """The whole point: ~top_k/E of dense cost, padding vanishing with T."""
     acct = grouped_flops(T=2048, top_k=2, n_experts=8, dim=512,
@@ -153,41 +229,69 @@ def test_flops_accounting_near_topk_over_e():
     assert big["grouped"] / big["ideal"] < 1.01   # padding term vanishes
 
 
-def test_mixtral_trunk_parity_across_impls():
-    """The serving trunk end-to-end: a mixtral-test engine generates the
-    SAME greedy tokens under dense / grouped / grouped_pallas — the MoE
-    formulation is a perf knob, never a numerics knob. moe_block is
-    shrunk so the CI-scale prefill clears the T·k >= E·block gate (at the
-    default 128 the tiny prompt would fall back to dense)."""
+def _greedy_tokens(moe_impl: str, devices: int = 1) -> tuple[list[int], object]:
+    """Eight greedy tokens of a mixtral-test engine over ``devices`` of the
+    conftest's virtual devices (its mesh's model axis), and its stats.
+    moe_block is shrunk so the CI-scale prefill clears the T·k >= E·block
+    gate (at the default 128 the tiny prompt would take the scan)."""
     import asyncio
     import dataclasses
 
     from mcp_context_forge_tpu.tpu_local.engine import (EngineConfig,
                                                         TPUEngine)
 
-    def generate(moe_impl: str) -> list[int]:
-        config = EngineConfig(model="mixtral-test", max_batch=2,
-                              max_seq_len=128, page_size=16, num_pages=32,
-                              prefill_buckets=(32,), dtype="float32",
-                              attn_impl="reference", moe_impl=moe_impl)
-        engine = TPUEngine(config)
-        engine.model_config = dataclasses.replace(engine.model_config,
-                                                  moe_block=8)
+    config = EngineConfig(model="mixtral-test", max_batch=2,
+                          max_seq_len=128, page_size=16, num_pages=32,
+                          prefill_buckets=(32,), dtype="float32",
+                          attn_impl="reference", moe_impl=moe_impl)
+    engine = TPUEngine(config, devices=jax.devices()[:devices])
+    engine.model_config = dataclasses.replace(engine.model_config,
+                                              moe_block=8)
 
-        async def run():
-            await engine.start()
-            try:
-                ids = engine.tokenizer.encode("route me through experts")
-                return [t async for t in engine.generate(ids, max_tokens=8)]
-            finally:
-                await engine.stop()
+    async def run():
+        await engine.start()
+        try:
+            ids = engine.tokenizer.encode("route me through experts")
+            return [t async for t in engine.generate(ids, max_tokens=8)]
+        finally:
+            await engine.stop()
 
-        return asyncio.run(run())
+    return asyncio.run(run()), engine.stats
 
-    dense = generate("dense")
-    assert len(dense) == 8
-    assert generate("grouped") == dense
-    assert generate("grouped_pallas") == dense  # interprets off-TPU
+
+@pytest.fixture(scope="module")
+def scan_tokens():
+    tokens, stats = _greedy_tokens("dense")
+    assert len(tokens) == 8
+    # the scan always: no step took row-blocks
+    assert stats.moe_grouped_steps == 0 and stats.moe_scan_steps >= 8
+    return tokens
+
+
+@pytest.mark.parametrize("moe_impl", ["", "grouped", "grouped_pallas"])
+def test_mixtral_trunk_parity_across_impls(scan_tokens, moe_impl):
+    """The serving trunk end-to-end: a mixtral-test engine generates the
+    SAME greedy tokens on its DEFAULT path ("": the row-block kernel for
+    the prefill, interpreted off-TPU, the scan for decode steps), under the
+    XLA grouped path and under the scan alone — the MoE formulation is a
+    perf choice, never a numerics one. The counters say which steps took
+    which: the one prefill grouped, every decode step the scan."""
+    tokens, stats = _greedy_tokens(moe_impl)
+    assert tokens == scan_tokens
+    assert stats.moe_grouped_steps == stats.prefill_batches == 1
+    assert stats.moe_scan_steps == stats.decode_steps >= 7
+
+
+def test_default_takes_the_scan_on_a_model_axis_wider_than_one_device(
+        scan_tokens):
+    """A mesh whose model axis holds two devices (the conftest's virtual
+    ones) builds the family with its default — it used to be refused on a
+    TPU mesh — and every step takes the scan: the kernel is not wrapped in
+    shard_map. Same tokens as on one device."""
+    tokens, stats = _greedy_tokens("", devices=2)
+    assert tokens == scan_tokens
+    assert stats.moe_grouped_steps == 0
+    assert stats.moe_scan_steps == stats.prefill_batches + stats.decode_steps
 
 
 def test_decode_shapes_fall_back_to_dense():

@@ -229,6 +229,13 @@ async def test_gateway_http_span_is_ancestor_of_llm_request():
         assert body["steps"] and body["steps"][-1]["kind"] in (
             "prefill", "decode", "spec_decode", "chunk_prefill")
         assert {"kv", "queue_depth"} <= set(body)
+        # the counters' route: a model without routed experts counts no
+        # expert step on either formulation
+        resp = await gateway.get("/admin/engine/stats", auth=auth)
+        assert resp.status == 200
+        assert (await resp.json())["moe"] == {
+            "tokens": 0, "local_pairs": 0, "grouped_steps": 0,
+            "scan_steps": 0}
 
         # profiler capture is opt-in: default-off config gates it
         resp = await gateway.post("/admin/engine/profile/start", auth=auth)
