@@ -814,6 +814,11 @@ document.getElementById("f").onsubmit = async (e) => {
             "kv_pages_free": alloc.free_pages,
             "kv_quant": engine.config.kv_quant or "off",
             "kv_bytes_in_use": engine.kv_bytes_in_use(),
+            # per-sequence recurrent state beside the pages (0 for a family
+            # whose cache grows a token only)
+            "state_rows_in_use": alloc.rows_in_use,
+            "state_rows_total": stats.state_rows_total,
+            "state_bytes_in_use": engine.state_bytes_in_use(),
             "prefill_ms_total": round(stats.prefill_ms_total, 1),
             "decode_ms_total": round(stats.decode_ms_total, 1),
             "engine_restarts": stats.engine_restarts,

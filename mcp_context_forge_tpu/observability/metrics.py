@@ -100,6 +100,20 @@ class PrometheusRegistry:
             "HBM bytes the in-use KV pages occupy under the active KV dtype",
             ["replica"], registry=self.registry,
         )
+        # per-sequence state beside the pages (a model family whose cache
+        # keeps a fixed-size recurrent state a sequence): rows live slots
+        # own, and the HBM bytes those rows occupy. Zero for families whose
+        # cache grows a token only.
+        self.llm_state_rows_in_use = Gauge(
+            "mcpforge_llm_state_rows_in_use",
+            "Per-sequence recurrent-state rows live slots own",
+            ["replica"], registry=self.registry,
+        )
+        self.llm_state_bytes = Gauge(
+            "mcpforge_llm_state_bytes",
+            "HBM bytes the live per-sequence state rows occupy",
+            ["replica"], registry=self.registry,
+        )
         # token-level SLO signals (fed by the engine dispatch thread):
         # TTFT = submit -> first token (queue + prefill), TPOT = mean
         # inter-token latency over the decode phase of one request.

@@ -12,7 +12,9 @@ and nowhere else. Three kinds of event share one bounded ring:
   bucket or the decode context pages; ``counts`` is None, or for a model
   family whose step programs count on the device (``models/deepseek.py``)
   the step's ``StepCounts``: mean selected / context share of its rows,
-  tokens through expert layers, token-expert pairs on held experts);
+  tokens through expert layers, token-expert pairs on held experts, and for
+  a family with per-sequence state (``models/olmo_hybrid.py``) the live state
+  rows and the real tokens its recurrence scanned);
 - **request stamps** — ``("req", phase, t, request_id, slot, replica)`` for
   ``submit`` / ``admit`` / ``first`` / ``done``.
 
@@ -52,6 +54,8 @@ class StepCounts(NamedTuple):
     selected_share: float   # mean over the step's rows of selected / context
     moe_tokens: float       # tokens through expert layers
     moe_local_pairs: float  # token-expert pairs that landed on held experts
+    state_rows_live: float = 0.0   # live per-sequence state rows the step touched
+    scanned_tokens: float = 0.0    # real (unpadded) tokens the recurrence scanned
 
 
 class StepEvent(NamedTuple):

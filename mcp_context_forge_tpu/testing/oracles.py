@@ -526,6 +526,46 @@ def page_allocator_oracle(mod: types.ModuleType) -> None:
     assert int(np.asarray(table)[3, 0]) == 0
 
 
+def _state_row_spec(mod: types.ModuleType) -> None:
+    """State-row contract (families with per-sequence pools): a slot is
+    dealt exactly one row beside its pages, never row 0 (the trash row), the
+    row follows the slot through ``move_slot`` and returns to the free list
+    with the slot. A surviving mutant is two sequences sharing a recurrent
+    state, or a row leak that runs the pool dry."""
+    import numpy as np
+
+    PA = mod.PageAllocator
+    plain = PA(num_pages=8, page_size=4, max_slots=3, max_pages_per_slot=4)
+    assert plain.state_rows == 0 and plain.allocate_slot(0, 4)
+    assert plain.slot_row(0) == 0 and plain.rows_in_use == 0
+    assert not np.asarray(plain.state_row_table()).any()
+
+    alloc = PA(num_pages=8, page_size=4, max_slots=3, max_pages_per_slot=4,
+               state_rows=4)
+    assert alloc.slot_row(1) == 0 and alloc.rows_in_use == 0
+    for slot in range(3):
+        assert alloc.allocate_slot(slot, 4)
+    rows = [alloc.slot_row(slot) for slot in range(3)]
+    assert sorted(rows) == [1, 2, 3] and rows[0] == 1   # every row but trash
+    assert alloc.rows_in_use == 3
+    assert np.asarray(alloc.state_row_table()).tolist() == rows
+    # growing a slot keeps its row; freeing returns it for the next tenant
+    assert alloc.grow_slot(1, 8) == 8 and alloc.slot_row(1) == rows[1]
+    alloc.free_slot(1)
+    assert alloc.slot_row(1) == 0 and alloc.rows_in_use == 2
+    assert np.asarray(alloc.state_row_table()).tolist() == [rows[0], 0, rows[2]]
+    alloc.free_slot(1)                           # freeing twice adds no row
+    alloc.move_slot(2, 1)                        # the row follows by id
+    assert alloc.slot_row(1) == rows[2] and alloc.slot_row(2) == 0
+    assert alloc.allocate_slot(2, 4) and alloc.slot_row(2) == rows[1]
+    for slot in range(3):
+        alloc.free_slot(slot)
+    assert alloc.rows_in_use == 0
+    for slot in range(3):                        # a full second round fits
+        assert alloc.allocate_slot(slot, 4)
+    assert sorted(alloc.slot_row(slot) for slot in range(3)) == [1, 2, 3]
+
+
 def _dirty_tracking_spec(mod: types.ModuleType) -> None:
     """Dirty-row contract: the engine skips the block-table upload iff no
     row changed, so a mutant that over- or under-reports dirt is either a
@@ -1902,6 +1942,7 @@ TARGETS: dict[str, MutationTarget] = {
         oracle=lambda mod: (page_allocator_oracle(mod),
                             _avg_slot_pages_spec(mod),
                             _dirty_tracking_spec(mod),
+                            _state_row_spec(mod),
                             _pregrant_block_spec(mod),
                             _prefix_tier_spec(mod)),
         class_name="PageAllocator",

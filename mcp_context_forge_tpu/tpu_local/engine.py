@@ -403,6 +403,12 @@ class EngineStats:
         # ``expert_path``: static a program, so counted at dispatch)
         self.moe_grouped_steps = 0    # chosen experts only, row-blocks
         self.moe_scan_steps = 0       # every held expert, gate-masked
+        # per-sequence state rows (a family with "sequence" cache pools):
+        # rows live now, rows the pool holds beside the trash row, and the
+        # real tokens the recurrence scanned (counted on the device)
+        self.state_rows_in_use = 0
+        self.state_rows_total = 0
+        self.state_scanned_tokens = 0
 
 
 def _named(jitted, name: str):
@@ -976,7 +982,7 @@ class TPUEngine:
         allocator are sized by the converted, dtype-aware page count."""
         config = self.config
         max_pages_per_slot = config.max_seq_len // config.page_size
-        from .kv import num_pages_for_budget
+        from .kv import kv_state_bytes, num_pages_for_budget, state_rows_for
         from .parallel.sharding import (kv_pages_sharding, kv_scales_sharding,
                                         logical_to_sharding)
         # bytes one page costs under the ACTIVE storage mode (gauge unit)
@@ -1024,9 +1030,14 @@ class TPUEngine:
         # snapshot must too, or post-rebuild hits are swallowed until the
         # new totals pass the old ones (counters would silently flatline)
         self._tier_hits_exported.clear()
+        state_rows = state_rows_for(self.model_config, config.max_batch)
+        self._state_row_bytes = kv_state_bytes(self.model_config, 1,
+                                               self._kv_dtype)
+        self.stats.state_rows_total = max(0, state_rows - 1)
         self.allocator = PageAllocator(self.num_kv_pages, config.page_size,
                                        config.max_batch, max_pages_per_slot,
-                                       tiers=self._tier_client)
+                                       tiers=self._tier_client,
+                                       state_rows=state_rows)
 
     def _ctx_buckets(self) -> list[int]:
         """The page-width buckets decode compiles for: powers of two from
@@ -1099,9 +1110,10 @@ class TPUEngine:
 
     def _compact_slots(self) -> None:
         """Move the highest-slot requests into the lowest free slots so the
-        active ceiling equals the active COUNT. Only block-table rows move
-        (pages are slot-agnostic); the device table refreshes on the next
-        _sync_tables. Runs between dispatches on the dispatch thread."""
+        active ceiling equals the active COUNT. Only block-table rows and
+        state-row ids move (pages and state rows are slot-agnostic: no state
+        is copied); the device tables refresh on the next _sync_tables. Runs
+        between dispatches on the dispatch thread."""
         if not self._running:
             return
         # dense prefix already (the steady state at ANY constant load):
@@ -1228,6 +1240,9 @@ class TPUEngine:
             del _k1, _k2
             jax.device_put(self.allocator.tables(),
                            self.kv.block_tables.sharding)
+            if self.allocator.state_rows:
+                jax.device_put(self.allocator.state_row_table(),
+                               self.kv.state_rows.sharding)
             if self._tier_read_fn is not None:
                 # spill/restore executables: compile both directions now
                 # (against the trash page — contents are zeros either
@@ -3269,14 +3284,19 @@ class TPUEngine:
         """What a step program counted on the device, from what it returned
         beside its tokens in the one readback: nothing (``aux`` empty: the
         GQA family), or one vector ``[moe_tokens, moe_local_pairs, summed
-        selected / context share, rows]`` (``STEP_AUX``). The counts go to
-        ``EngineStats`` and onto the step's timeline record."""
+        selected / context share, rows]`` (``STEP_AUX``), which a family
+        with per-sequence state extends by ``[live state rows, real tokens
+        scanned]``. The counts go to ``EngineStats`` and onto the step's
+        timeline record."""
         if not aux:
             return None
-        moe_tokens, pairs, share_sum, rows = (float(v) for v in aux[0])
+        moe_tokens, pairs, share_sum, rows, *state = (float(v) for v in aux[0])
         self.stats.moe_tokens += int(moe_tokens)
         self.stats.moe_local_pairs += int(pairs)
-        return StepCounts(share_sum / rows if rows else 0.0, moe_tokens, pairs)
+        live, scanned = state or (0.0, 0.0)
+        self.stats.state_scanned_tokens += int(scanned)
+        return StepCounts(share_sum / rows if rows else 0.0, moe_tokens, pairs,
+                          live, scanned)
 
     def _record_step(self, kind: str, *, seq: int, batch: int, width: int,
                      dur_ms: float, tokens: int, bucket: int | None = None,
@@ -3294,6 +3314,7 @@ class TPUEngine:
         the deque (recent_steps), never mutates it."""
         depth = self._work.qsize() + len(self._pending)
         pages_in_use = self.allocator.pages_in_use
+        self.stats.state_rows_in_use = self.allocator.rows_in_use
         self.step_log.append({
             "seq": seq,                         # the timeline's step number
             "ts": time.time(),
@@ -3344,6 +3365,11 @@ class TPUEngine:
             # dashboard even though their page counts differ 2x
             m.llm_kv_bytes_in_use.labels(
                 replica=self.config.replica_id).set(self.kv_bytes_in_use())
+            if self.allocator.state_rows:
+                m.llm_state_rows_in_use.labels(replica=rid).set(
+                    self.allocator.rows_in_use)
+                m.llm_state_bytes.labels(replica=rid).set(
+                    self.state_bytes_in_use())
             m.llm_queue_depth.labels(replica=rid).set(depth)
             # tokens/s over the TRUE per-step wall (retire-to-retire under
             # the depth-2 overlap — dur_ms there spans ~2 device steps and
@@ -3559,8 +3585,14 @@ class TPUEngine:
             # placement: the pjit cache keys on input shardings, so a bare
             # jnp.array here — single-device, uncommitted — would recompile
             # every warmup-built executable at its first traffic hit
-            self.kv = self.kv._replace(block_tables=jax.device_put(
-                self.allocator.tables(), self.kv.block_tables.sharding))
+            fresh = {"block_tables": jax.device_put(
+                self.allocator.tables(), self.kv.block_tables.sharding)}
+            if self.allocator.state_rows:
+                # a slot's state row rides beside its block-table row
+                fresh["state_rows"] = jax.device_put(
+                    self.allocator.state_row_table(),
+                    self.kv.state_rows.sharding)
+            self.kv = self.kv._replace(**fresh)
 
     def _emit(self, request: GenRequest, token: int) -> None:
         request.generated.append(token)
@@ -3687,3 +3719,7 @@ class TPUEngine:
     def kv_bytes_capacity(self) -> int:
         """HBM bytes the whole KV pool occupies (fixed at construction)."""
         return self.num_kv_pages * self._kv_page_bytes
+
+    def state_bytes_in_use(self) -> int:
+        """HBM bytes of the per-sequence pools the live rows occupy."""
+        return self.allocator.rows_in_use * self._state_row_bytes

@@ -3,6 +3,9 @@
 from .paged_cache import (
     PagedKVState,
     LatentKVState,
+    HybridKVState,
+    state_rows_for,
+    kv_state_bytes,
     PoolSpec,
     kv_pools,
     write_latent_kv,
@@ -20,7 +23,8 @@ from .paged_cache import (
 from .prefix_index import PrefixIndex, chain_hash, chain_hashes
 from .tiers import SpilledPage, TierClient, TieredPageStore
 
-__all__ = ["PagedKVState", "LatentKVState", "PoolSpec", "kv_pools",
+__all__ = ["PagedKVState", "LatentKVState", "HybridKVState", "PoolSpec",
+           "kv_pools", "state_rows_for", "kv_state_bytes",
            "write_latent_kv", "gather_pool", "PageAllocator", "PrefixEvictionPolicy",
            "init_kv_state", "kv_page_bytes",
            "num_pages_for_budget", "write_prefill_kv", "write_decode_kv",

@@ -17,6 +17,19 @@ with every rule of this docstring); the latent family keeps ``latent``
 (``LatentKVState``). Both ride the ONE block table and the ONE
 ``PageAllocator``, which deals in page ids and knows nothing of the pools.
 
+A pool also declares WHICH layers hold it and whether it grows a token or is
+a fixed size a sequence (``PoolSpec.layers`` / ``.per``). The hybrid family
+(``HybridKVState``) keeps ``k`` and ``v`` pages in its full-attention layers
+only, and in its linear-attention layers a ``"sequence"`` pool: the recurrent
+``state`` ``[L_lin, rows, d_k, H * d_v]`` float32 and the convolution's
+``conv_tail`` ``[L_lin, rows, taps, channels]``. A decode slot then owns a
+state ROW as well as pages: the allocator deals row ids with the slot
+(``state_rows = slots + 1``; row 0 is the trash row as page 0 is the trash
+page), the row follows its request through compaction by id
+(``HybridKVState.state_rows``, uploaded with the block table), and nothing
+copies a state. ``kv_page_bytes`` counts the per-token pools by their own
+layer counts; ``kv_state_bytes`` the per-sequence ones.
+
 The layout is token-major on purpose: ``(KV, hd)`` are the two minor dims,
 so one token's kv heads are one contiguous tile and a token write (decode,
 prefill scatter) is one whole-tile update. Head-major pages
@@ -49,7 +62,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..models.configs import DeepseekConfig, LlamaConfig
+from ..models.configs import DeepseekConfig, LlamaConfig, OlmoHybridConfig
 from ..quantize import KV_SCALE_EPS, kv_dequantize, kv_int8_scale, kv_quantize
 
 
@@ -81,18 +94,54 @@ class PagedKVState(NamedTuple):
 
 
 class PoolSpec(NamedTuple):
-    """One per-token pool of the cache: ``[L, num_pages, page, *shape]``."""
+    """One pool of the cache. ``per="token"``: ``[layers, num_pages, page,
+    *shape]``, reached through the block table. ``per="sequence"``: ``[layers,
+    rows, *shape]``, a fixed size a sequence, reached through the slot's row
+    id. ``layers``: how many layers hold it (None: every layer of the model).
+    ``dtype``: None for the engine's cache dtype."""
     name: str
     shape: tuple[int, ...]
+    layers: int | None = None
+    per: str = "token"
+    dtype: Any = None
 
 
-def kv_pools(config: LlamaConfig | DeepseekConfig) -> tuple[PoolSpec, ...]:
+AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig
+
+
+def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
     """The pools a family's cache holds, in the order of its state's fields."""
     if isinstance(config, DeepseekConfig):
         return (PoolSpec("latent", (config.latent_dim,)),
                 PoolSpec("index_key", (config.index_head_dim,)))
     heads = (config.n_kv_heads, config.head_dim)
+    if isinstance(config, OlmoHybridConfig):
+        heads = (config.kv_pool_heads, config.head_dim)
+        full = len(config.layers_of("full_attention"))
+        linear = config.n_layers - full
+        return (PoolSpec("k", heads, full), PoolSpec("v", heads, full),
+                PoolSpec("state", (config.linear_key_dim, config.linear_n_heads
+                                   * config.linear_value_dim),
+                         linear, "sequence", jnp.float32),
+                PoolSpec("conv_tail", (config.conv_kernel - 1, config.conv_dim),
+                         linear, "sequence"))
     return (PoolSpec("k", heads), PoolSpec("v", heads))
+
+
+def state_rows_for(config: AnyConfig, max_slots: int) -> int:
+    """Rows of the per-sequence pools: one a slot and the trash row, or 0 for
+    a family that keeps nothing a sequence."""
+    per_sequence = any(pool.per == "sequence" for pool in kv_pools(config))
+    return max_slots + 1 if per_sequence else 0
+
+
+def kv_state_bytes(config: AnyConfig, rows: int,
+                   dtype: jnp.dtype = jnp.bfloat16) -> int:
+    """HBM bytes ``rows`` rows of the per-sequence pools cost (all layers)."""
+    return rows * sum(
+        (pool.layers or config.n_layers) * math.prod(pool.shape)
+        * jnp.dtype(pool.dtype or dtype).itemsize
+        for pool in kv_pools(config) if pool.per == "sequence")
 
 
 class LatentKVState(NamedTuple):
@@ -117,6 +166,46 @@ class LatentKVState(NamedTuple):
         return False
 
 
+class HybridKVState(NamedTuple):
+    """Device state of the hybrid family: K/V pages of the full-attention
+    layers (indexed by the layer's ordinal AMONG them) under the block table,
+    and the linear-attention layers' per-sequence pools under ``state_rows``
+    (slot -> row id, 0 = none: the trash row). Full precision only; the
+    ``*_scales`` fields are the GQA trunk's attention functions' (always
+    None here)."""
+
+    k_pages: jax.Array       # [L_full, num_pages, page_size, KV, hd]
+    v_pages: jax.Array
+    block_tables: jax.Array  # [slots, max_pages_per_slot] int32
+    state: jax.Array         # [L_lin, rows, d_k, H * d_v] float32
+    conv_tail: jax.Array     # [L_lin, rows, taps, channels]
+    state_rows: jax.Array    # [slots] int32
+    k_scales: None = None
+    v_scales: None = None
+
+    @property
+    def page_size(self) -> int:
+        return self.k_pages.shape[2]
+
+    @property
+    def max_context(self) -> int:
+        return self.block_tables.shape[1] * self.page_size
+
+    @property
+    def quantized(self) -> bool:
+        return False
+
+
+def _full_precision_only(config, quant: str) -> bool:
+    """True for the hybrid family (which then refuses ``quant``)."""
+    hybrid = isinstance(config, OlmoHybridConfig)
+    if hybrid and quant:
+        raise NotImplementedError(
+            f"kv_quant={quant!r}: the hybrid family's pools are full "
+            f"precision only")
+    return hybrid
+
+
 def _latent_only(config, quant: str) -> bool:
     latent = isinstance(config, DeepseekConfig)
     if latent and quant:
@@ -126,10 +215,13 @@ def _latent_only(config, quant: str) -> bool:
     return latent
 
 
-def kv_logical(quant: str = "",
-               config: LlamaConfig | DeepseekConfig | None = None
-               ) -> PagedKVState | LatentKVState:
+def kv_logical(quant: str = "", config: AnyConfig | None = None
+               ) -> PagedKVState | LatentKVState | HybridKVState:
     """Logical sharding names for the state tree."""
+    if _full_precision_only(config, quant):
+        return HybridKVState(k_pages="kv_pages", v_pages="kv_pages",
+                             block_tables="replicated", state="state_pool",
+                             conv_tail="state_pool", state_rows="replicated")
     if _latent_only(config, quant):
         return LatentKVState(latent_pages="latent_pages",
                              index_pages="latent_pages",
@@ -140,11 +232,21 @@ def kv_logical(quant: str = "",
                         k_scales=scales, v_scales=scales)
 
 
-def init_kv_state(config: LlamaConfig | DeepseekConfig, num_pages: int, page_size: int,
+def init_kv_state(config: AnyConfig, num_pages: int, page_size: int,
                   max_slots: int, max_pages_per_slot: int,
                   dtype: jnp.dtype = jnp.bfloat16,
-                  quant: str = "") -> PagedKVState | LatentKVState:
+                  quant: str = ""
+                  ) -> PagedKVState | LatentKVState | HybridKVState:
     tables = jnp.zeros((max_slots, max_pages_per_slot), dtype=jnp.int32)
+    if _full_precision_only(config, quant):
+        rows = state_rows_for(config, max_slots)
+        k, v, state, conv_tail = (
+            jnp.zeros((pool.layers, *((num_pages, page_size)
+                                      if pool.per == "token" else (rows,)),
+                       *pool.shape), dtype=pool.dtype or dtype)
+            for pool in kv_pools(config))
+        return HybridKVState(k, v, tables, state, conv_tail,
+                             jnp.zeros((max_slots,), dtype=jnp.int32))
     if _latent_only(config, quant):
         latent, index_key = (
             jnp.zeros((config.n_layers, num_pages, page_size, *pool.shape),
@@ -168,14 +270,16 @@ def init_kv_state(config: LlamaConfig | DeepseekConfig, num_pages: int, page_siz
     )
 
 
-def kv_page_bytes(config: LlamaConfig | DeepseekConfig, page_size: int,
+def kv_page_bytes(config: AnyConfig, page_size: int,
                   dtype: jnp.dtype = jnp.bfloat16, quant: str = "") -> int:
-    """HBM bytes ONE page (every pool the family declares, all layers)
-    costs under a storage mode — the unit _init_kv's byte-denominated
-    budget divides by."""
+    """HBM bytes ONE page (every per-token pool the family declares, each
+    over the layers that hold it) costs under a storage mode — the unit
+    _init_kv's byte-denominated budget divides by."""
+    _full_precision_only(config, quant)
     _latent_only(config, quant)
-    elems = config.n_layers * page_size * sum(
-        math.prod(pool.shape) for pool in kv_pools(config))
+    elems = page_size * sum(
+        (pool.layers or config.n_layers) * math.prod(pool.shape)
+        for pool in kv_pools(config) if pool.per == "token")
     if quant == "int8":
         scale_bytes = (2 * config.n_layers * config.n_kv_heads
                        * jnp.dtype(dtype).itemsize)
@@ -183,7 +287,7 @@ def kv_page_bytes(config: LlamaConfig | DeepseekConfig, page_size: int,
     return elems * jnp.dtype(dtype).itemsize
 
 
-def num_pages_for_budget(config: LlamaConfig | DeepseekConfig, page_size: int,
+def num_pages_for_budget(config: AnyConfig, page_size: int,
                          budget_bytes: int, dtype: jnp.dtype = jnp.bfloat16,
                          quant: str = "") -> int:
     """Pages a fixed HBM byte budget holds under a storage mode (~2x under
@@ -456,9 +560,15 @@ class PageAllocator:
     conservation contract is unchanged)."""
 
     def __init__(self, num_pages: int, page_size: int, max_slots: int,
-                 max_pages_per_slot: int, tiers=None):
+                 max_pages_per_slot: int, tiers=None, state_rows: int = 0):
         import numpy as np
         self.num_pages = num_pages
+        # per-sequence state rows (a family with "sequence" pools): a row id
+        # is dealt with the slot's pages and freed with them; row 0 is the
+        # trash row. state_rows > max_slots, so a slot never waits for one.
+        self.state_rows = state_rows
+        self._free_rows = list(range(state_rows - 1, 0, -1))
+        self._row: dict[int, int] = {}                  # slot -> state row
         self.page_size = page_size
         self.max_slots = max_slots
         self.max_pages_per_slot = max_pages_per_slot
@@ -517,6 +627,23 @@ class PageAllocator:
     @property
     def cached_pages(self) -> int:
         return len(self._cached)
+
+    @property
+    def rows_in_use(self) -> int:
+        return len(self._row)
+
+    def slot_row(self, slot: int) -> int:
+        """The slot's state row (0: none)."""
+        return self._row.get(slot, 0)
+
+    def state_row_table(self) -> "np.ndarray":
+        """slot -> state row id, [max_slots] int32 (0 = the trash row): the
+        device's ``state_rows``, uploaded whenever the block table is."""
+        import numpy as np
+        table = np.zeros((self.max_slots,), dtype=np.int32)
+        for slot, row in self._row.items():
+            table[slot] = row
+        return table
 
     def pages_needed(self, n_tokens: int) -> int:
         return (n_tokens + self.page_size - 1) // self.page_size
@@ -800,6 +927,8 @@ class PageAllocator:
             self._ref[page] = self._ref.get(page, 0) + 1
             pages.append(page)
         self._slots[slot] = pages
+        if self.state_rows and slot not in self._row:
+            self._row[slot] = self._free_rows.pop()
         self._dirty.add(slot)
         self._track_peak()
         return True
@@ -853,12 +982,15 @@ class PageAllocator:
         return max(0, min(k, capacity - (n_ctx - 1)))
 
     def move_slot(self, old: int, new: int) -> None:
-        """Reassign a slot's pages to another (free) slot id — pages are
-        slot-agnostic, so compaction moves only this mapping (the device
-        block table refreshes from tables())."""
+        """Reassign a slot's pages (and its state row) to another (free)
+        slot id — pages and rows are slot-agnostic, so compaction moves only
+        these mappings (the device tables refresh from tables() /
+        state_row_table())."""
         assert new not in self._slots, f"slot {new} occupied"
         if old in self._slots:
             self._slots[new] = self._slots.pop(old)
+            if old in self._row:        # the state row follows by id
+                self._row[new] = self._row.pop(old)
             self._dirty.add(old)
             self._dirty.add(new)
 
@@ -866,6 +998,9 @@ class PageAllocator:
         pages = self._slots.pop(slot, [])
         if pages:
             self._dirty.add(slot)
+        row = self._row.pop(slot, None)
+        if row is not None:
+            self._free_rows.append(row)
         for page in reversed(pages):
             self._release_page(page)
 

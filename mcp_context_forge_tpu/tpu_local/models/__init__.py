@@ -3,21 +3,31 @@ moderation classifier), pure-pytree params for pjit.
 
 A decoder family is one module (``llama``: GQA + RoPE + SwiGLU/Mixtral
 experts; ``deepseek``: latent attention, sparse selector, shared + routed
-experts) with the same set of names: ``init_keys``, ``init_layer``,
+experts; ``olmo_hybrid``: gated delta-rule linear-attention layers between
+full-attention layers) with the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
 ``kv_logical`` / ``kv_page_bytes``, the kernel choices ``prefill_impl`` /
 ``paged_impl`` / ``expert_path``, and ``refusals`` (engine settings the
 family cannot serve yet). The engine finds the module from the model config's CLASS
-(:func:`family_of`): nothing else chooses it."""
+(:func:`family_of`): nothing else chooses it.
+
+What a family may declare: a KIND a layer (``layer_kind(config, i)``; the
+engine compiles one weight-init program a kind) that names the layer's FFN
+(``deepseek``: dense | experts) or its MIXER (``olmo_hybrid``:
+linear_attention | full_attention); cache pools that only some layers hold,
+and pools of a fixed size a sequence beside the per-token ones
+(``kv/paged_cache.py: kv_pools``); and, with ``STEP_AUX``, a float32 vector of
+counts its step programs return beside the tokens (``engine._step_counts``)."""
 
 from importlib import import_module
 from types import ModuleType
 
 from .configs import (DeepseekConfig, EncoderConfig, LlamaConfig,
-                      ENCODER_CONFIGS, MODEL_CONFIGS)
+                      OlmoHybridConfig, ENCODER_CONFIGS, MODEL_CONFIGS)
 
-_FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek"}
+_FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek",
+                   OlmoHybridConfig: "olmo_hybrid"}
 
 
 def family_of(model_config) -> ModuleType:
@@ -28,5 +38,6 @@ def family_of(model_config) -> ModuleType:
     return import_module(f"{__name__}.{name}")
 
 
-__all__ = ["LlamaConfig", "DeepseekConfig", "EncoderConfig", "MODEL_CONFIGS",
+__all__ = ["LlamaConfig", "DeepseekConfig", "OlmoHybridConfig",
+           "EncoderConfig", "MODEL_CONFIGS",
            "ENCODER_CONFIGS", "family_of"]

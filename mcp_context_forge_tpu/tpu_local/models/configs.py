@@ -134,7 +134,65 @@ class DeepseekConfig:
         return "dense" if layer < self.n_dense_layers else "experts"
 
 
-MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig] = {
+@dataclass(frozen=True)
+class OlmoHybridConfig:
+    """Geometry for the hybrid family whose layers differ in their MIXER
+    (Olmo-Hybrid ``config.json`` keys in brackets): layer ``i`` is a gated
+    delta-rule linear-attention layer unless ``i % full_attention_interval ==
+    full_attention_interval - 1`` [layer_types], when it is full softmax
+    attention over ``n_kv_heads`` heads of ``head_dim`` with QK-norm and no
+    rotary embedding. A linear layer holds ``linear_n_heads``
+    [linear_num_key_heads = linear_num_value_heads] heads of a
+    ``linear_key_dim`` x ``linear_value_dim`` float32 state a SEQUENCE and the
+    last ``conv_kernel - 1`` pre-convolution inputs; it keeps nothing a token.
+    Both kinds: post-norm residual blocks (``x + norm(f(x))``) and SwiGLU."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    ffn_hidden: int
+    linear_n_heads: int
+    linear_key_dim: int
+    linear_value_dim: int
+    conv_kernel: int = 4
+    full_attention_interval: int = 4
+    allow_neg_eigval: bool = True   # beta = 2 * sigmoid(b)
+    norm_eps: float = 1e-6
+    max_seq_len: int = 65_536
+
+    def mixer_kind(self, layer: int) -> str:
+        full = layer % self.full_attention_interval \
+            == self.full_attention_interval - 1
+        return "full_attention" if full else "linear_attention"
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.mixer_kind(i) == kind)
+
+    @property
+    def kv_pool_heads(self) -> int:
+        """kv heads a K/V PAGE holds: the model's, rounded up to whole
+        16-row sublane tiles once they pass one (30 -> 32). A token's heads
+        are the page's sublane dim; with 30 of them the TPU's default layout
+        of the pool is no longer row-major (it transposes page and heads to
+        save the 2 padding rows), and every kernel call over the pool then
+        copies the whole pool into the layout it needs. The padding heads
+        are zeros that no query reads."""
+        n = self.n_kv_heads
+        return n if n <= 16 else -(-n // 16) * 16
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q, k and v."""
+        return self.linear_n_heads * (2 * self.linear_key_dim
+                                      + self.linear_value_dim)
+
+
+MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -218,6 +276,13 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig] = {
         n_shared_experts=1, moe_top_k=4, n_group=4, topk_group=2,
         rope_factor=4.0, rope_original_max=64, max_seq_len=512,
         moe_impl="grouped", moe_block=8),
+    # the hybrid family at CI scale: two periods of (3 gated delta-rule layers,
+    # 1 full-attention layer); 4 linear heads of a 16 x 32 state
+    "olmo-hybrid-test": OlmoHybridConfig(
+        name="olmo-hybrid-test", vocab_size=512, dim=64, n_layers=8,
+        n_heads=4, n_kv_heads=4, head_dim=16, ffn_hidden=128,
+        linear_n_heads=4, linear_key_dim=16, linear_value_dim=32,
+        max_seq_len=512),
 }
 
 

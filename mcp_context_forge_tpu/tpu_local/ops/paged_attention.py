@@ -88,6 +88,11 @@ _ROW_BLOCK = 256
 # _BLOCK_PAGES
 _SCORE_TILE = 256 * 512
 _BLOCK_PAGES = 4
+# kv heads x pages a grid step holds: the head loop is unrolled, and every
+# head's [pages * page, hd] operands of a block are live on the kernel's
+# VMEM stack, so a pool of many kv heads takes fewer pages a step (8 heads:
+# all 4; the hybrid family's 32: 1, where 4 overran the scoped VMEM at compile)
+_BLOCK_HEAD_PAGES = 32
 
 
 def _word_packed(ref) -> bool:
@@ -125,9 +130,11 @@ def _word_heads(refs, word, out_dtype):
         yield head.astype(out_dtype)
 
 
-def _kv_block_pages(n_pages: int, rows: int, page_size: int) -> int:
+def _kv_block_pages(n_pages: int, rows: int, page_size: int,
+                    n_kv: int = 1) -> int:
     """Pages a grid step holds: see the module docstring."""
-    want = max(1, min(_BLOCK_PAGES, _SCORE_TILE // (rows * page_size)))
+    want = max(1, min(_BLOCK_PAGES, _SCORE_TILE // (rows * page_size),
+                      _BLOCK_HEAD_PAGES // n_kv))
     return max(n for n in range(1, want + 1) if n_pages % n == 0)
 
 
@@ -268,7 +275,7 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
     if R % rows:
         raise ValueError(f"query rows {R} must divide into blocks of {rows}")
     n_blocks = R // rows
-    n = _kv_block_pages(n_pages, rows, page_size)
+    n = _kv_block_pages(n_pages, rows, page_size, KV)
 
     def slot_entry(slot, b, r, j, entries, max_pos):
         return entries[b * n_blocks + r, j * n + slot]

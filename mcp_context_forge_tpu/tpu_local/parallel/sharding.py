@@ -40,6 +40,10 @@ LOGICAL_RULES: dict[str, P] = {
     # the latent family's pools (L, pages, page, d): one vector a token
     # shared by all heads, so nothing to split over ``model``
     "latent_pages": P(),
+    # the hybrid family's per-sequence pools (L, rows, ...): the recurrent
+    # state and the convolution tail, whole on every chip (the family
+    # refuses a model axis wider than one device)
+    "state_pool": P(),
     "activations": P("data", None, None),  # (batch, seq, dim)
     "decode_heads": P("data", None, "model", None),  # (batch, seq, heads, hd)
 }

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mcp_context_forge_tpu.tpu_local.ops import attention, grouped_moe
+from mcp_context_forge_tpu.tpu_local.ops import attention, gated_delta, grouped_moe
 from mcp_context_forge_tpu.tpu_local.ops import mla_attention as mla
 from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
 
@@ -146,6 +146,30 @@ def _held_experts_case():
              ((n_blocks,), jnp.int32), ((1,), jnp.int32)])
 
 
+def _gated_delta_case(batch: int, seq: int):
+    """olmo-hybrid-7b.chat: 30 heads of a 96 x 192 float32 state, 24 linear
+    layers x 33 rows; decode steps of 32 rows, prefills of up to 4 x 512."""
+    H, dk, dv = 30, 96, 192
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [((batch, seq, H, dk), f32)] * 2 + [((batch, seq, H, dv), f32)] \
+        + [((batch, seq, H), f32)] * 2 + [((24, 33, dk, H * dv), f32)] \
+        + [((batch,), i32)] * 3
+    return partial(gated_delta.gated_delta_pallas, layer=7), shapes
+
+
+def _hybrid_paged_case(chunk: int | None, batch: int, table: int):
+    """The paged kernel over the hybrid's pool: 8 attending layers, 32 kv
+    heads a page (30 padded to whole tiles), one query head a kv head."""
+    pool = ((8, 256, PAGE, 32, HD), jnp.bfloat16)
+    if chunk is None:
+        return (partial(paged.paged_decode_attention_pallas, layer=3),
+                [((batch, 32, 1, HD), jnp.bfloat16), pool, pool,
+                 ((batch, table), jnp.int32), ((batch,), jnp.int32)])
+    return (partial(paged.paged_chunk_attention_pallas, layer=3),
+            [((batch, chunk, 32, 1, HD), jnp.bfloat16), pool, pool,
+             ((batch, table), jnp.int32), ((batch, chunk), jnp.int32)])
+
+
 # _history_tile(S, G=4) yields query tiles of 128..512 (and spec-verify
 # chunks of spec_k=4); kv_heads=2 is one shard of a 1x4 TP mesh
 KERNEL_CASES = {
@@ -197,6 +221,12 @@ KERNEL_CASES = {
     "grouped_moe_int8_cell_1x512": lambda: _moe_case(True, 16, live=True),
     "grouped_moe_int8_cell_2x512": lambda: _moe_case(True, 24, live=True),
     "grouped_moe_int8_cell_4x512": lambda: _moe_case(True, 40, live=True),
+    # olmo-hybrid-7b.chat: the recurrence's two kernels, and the paged kernel
+    # at 32 kv heads a page (decode buckets; a chunk round's 128-query tiles)
+    "gated_delta_step_cell_32": lambda: _gated_delta_case(32, 1),
+    "gated_delta_chunk_cell_4x512": lambda: _gated_delta_case(4, 512),
+    "paged_decode_bf16_hybrid_32x8": lambda: _hybrid_paged_case(None, 32, 8),
+    "paged_chunk_bf16_hybrid_tile128x8": lambda: _hybrid_paged_case(128, 4, 8),
 }
 
 
