@@ -855,6 +855,12 @@ document.getElementById("f").onsubmit = async (e) => {
                 "grouped_steps": stats.moe_grouped_steps,
                 "scan_steps": stats.moe_scan_steps,
             },
+            # sampled steps by the work their rows' parameters asked for
+            "sampling": {
+                "argmax_steps": stats.sample_argmax_steps,
+                "plain_steps": stats.sample_plain_steps,
+                "filtered_steps": stats.sample_filtered_steps,
+            },
         })
 
     @routes.get("/admin/slo")

@@ -403,6 +403,11 @@ class EngineStats:
         # ``expert_path``: static a program, so counted at dispatch)
         self.moe_grouped_steps = 0    # chosen experts only, row-blocks
         self.moe_scan_steps = 0       # every held expert, gate-masked
+        # sampled steps by the work their rows' parameters asked of
+        # ``sample_tokens`` (counted on the host from the same rows)
+        self.sample_argmax_steps = 0    # no row samples: the argmax alone
+        self.sample_plain_steps = 0     # some row samples, none filters
+        self.sample_filtered_steps = 0  # some sampled row has a top-k / top-p
         # per-sequence state rows (a family with "sequence" cache pools):
         # rows live now, rows the pool holds beside the trash row, and the
         # real tokens the recurrence scanned (counted on the device)
@@ -2488,8 +2493,7 @@ class TPUEngine:
             temperature[i] = request.temperature
             top_k[i] = request.top_k
             top_p[i] = request.top_p
-        sampling = SamplingParams(jnp.asarray(temperature), jnp.asarray(top_k),
-                                  jnp.asarray(top_p))
+        sampling = self._sampling_params(temperature, top_k, top_p)
         return (jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(last_idx), jnp.asarray(slot_ids), sampling)
 
@@ -2703,8 +2707,7 @@ class TPUEngine:
             temperature[slot] = request.temperature
             top_k[slot] = request.top_k
             top_p[slot] = request.top_p
-        sampling = SamplingParams(jnp.asarray(temperature), jnp.asarray(top_k),
-                                  jnp.asarray(top_p))
+        sampling = self._sampling_params(temperature, top_k, top_p)
         return tokens, positions, sampling, widths, chunks
 
     # ------------------------------------------------------------ decode step
@@ -3063,8 +3066,7 @@ class TPUEngine:
             stops = (self.tokenizer.eos_id,) + tuple(
                 request.stop_ids)[:self._STOP_TBL_WIDTH - 1]
             stop_tbl[slot, :len(stops)] = stops
-        sampling = SamplingParams(jnp.asarray(temperature), jnp.asarray(top_k),
-                                  jnp.asarray(top_p))
+        sampling = self._sampling_params(temperature, top_k, top_p, steps=k)
         return (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
                 budgets, truncated, reqs)
 
@@ -3279,6 +3281,20 @@ class TPUEngine:
             self.stats.moe_grouped_steps += steps
         elif path == "scan":
             self.stats.moe_scan_steps += steps
+
+    def _sampling_params(self, temperature: np.ndarray, top_k: np.ndarray,
+                         top_p: np.ndarray, steps: int = 1) -> SamplingParams:
+        """A dispatch's per-row parameters on the device, its ``steps``
+        sampled steps counted by the tier the step program will take."""
+        rows = SamplingParams(temperature, top_k, top_p)
+        samples, filters = rows.tiers()
+        if filters:
+            self.stats.sample_filtered_steps += steps
+        elif samples:
+            self.stats.sample_plain_steps += steps
+        else:
+            self.stats.sample_argmax_steps += steps
+        return SamplingParams(*map(jnp.asarray, rows))
 
     def _step_counts(self, aux: list) -> StepCounts | None:
         """What a step program counted on the device, from what it returned

@@ -16,7 +16,8 @@ second per-token key for the selector. Three kernels serve a step:
 ``sparse_select``
     The EXACT ``k``-th largest score of every row, by a 32-step bitwise search
     over the order-preserving integer image of the float32 scores (count the
-    entries at or above a candidate, keep the bit if at least ``k`` are). The
+    entries at or above a candidate, keep the bit if at least ``k`` are:
+    ``ops/threshold_search.py``, which sampling's cuts share). The
     selected set of a row is then ``score >= threshold``: the top ``k``, ties
     at the threshold all kept (continuous scores tie with probability zero).
     No sort, no indices: what attention needs is the mask.
@@ -62,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .threshold_search import from_ordered_bits, kth_largest_key, ordered_bits
+
 NEG_INF = -1e30
 _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 # queries a grid step of the index kernel scores against one page, and of the
@@ -93,42 +96,17 @@ def index_scores_reference(q: jax.Array, w: jax.Array, keys: jax.Array,
     return jnp.where(cache_pos <= positions[:, :, None], scores, NEG_INF)
 
 
-def _ordered_bits(x: jax.Array) -> jax.Array:
-    """float32 -> int32 whose signed order is the floats' order."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
-
-
-def _from_ordered_bits(key: jax.Array) -> jax.Array:
-    bits = key ^ ((key >> 31) & jnp.int32(0x7FFFFFFF))
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)
-
-
-def _kth_largest_bits(keys: jax.Array, k: int) -> jax.Array:
-    """keys: [R, C] int32 -> [R, 1] int32, the k-th largest of each row (the
-    smallest when the row has fewer than k entries above it)."""
-    k = min(k, keys.shape[-1])
-
-    def count_ge(cand):
-        return jnp.sum((keys >= cand).astype(jnp.int32), axis=-1,
-                       keepdims=True)
-
-    # the sign first: at least k non-negative entries put the answer there
-    prefix = jnp.where(count_ge(jnp.zeros_like(keys[:, :1])) >= k,
-                       jnp.int32(0), jnp.int32(-2 ** 31))
-
-    def body(i, prefix):
-        cand = prefix | (jnp.int32(1) << (30 - i))
-        return jnp.where(count_ge(cand) >= k, cand, prefix)
-
-    return jax.lax.fori_loop(0, 31, body, prefix)
+def _kth_largest(scores: jax.Array, k: int) -> jax.Array:
+    """scores: [R, C] float32 -> [R, 1]: the k-th largest of each row (the
+    smallest when the row has fewer than k entries)."""
+    keys = ordered_bits(scores)
+    return from_ordered_bits(kth_largest_key(keys, min(k, keys.shape[-1])))
 
 
 def topk_threshold_reference(scores: jax.Array, k: int) -> jax.Array:
     """scores: [..., C] float32 -> [..., 1]: the k-th largest of each row."""
     flat = scores.reshape(-1, scores.shape[-1])
-    thr = _from_ordered_bits(_kth_largest_bits(_ordered_bits(flat), k))
-    return thr.reshape(*scores.shape[:-1], 1)
+    return _kth_largest(flat, k).reshape(*scores.shape[:-1], 1)
 
 
 def mla_attention_reference(q: jax.Array, bias: jax.Array, latent: jax.Array,
@@ -229,8 +207,7 @@ def sparse_index_scores_pallas(q: jax.Array, w: jax.Array,
 
 def _select_kernel(s_ref, o_ref, *, k: int):
     """s [R, C] f32 -> o [R, 1] f32: each row's k-th largest."""
-    thr = _from_ordered_bits(_kth_largest_bits(_ordered_bits(s_ref[...]), k))
-    o_ref[...] = thr
+    o_ref[...] = _kth_largest(s_ref[...], k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))

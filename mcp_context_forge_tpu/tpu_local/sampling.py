@@ -2,6 +2,11 @@
 
 Fully vectorized over the decode batch with per-slot parameters so one
 compiled function serves heterogeneous requests (SURVEY.md §7.1 phase 3.4).
+A step does the work its batch's parameters ask for, chosen inside the step
+program from two scalars of them (``SamplingParams.tiers``): the argmax and
+nothing else while no row samples, one categorical draw while no sampled row
+filters, and otherwise the top-k / top-p masks found by a threshold search
+(``ops/threshold_search.py``). No tier sorts the vocabulary.
 """
 
 from __future__ import annotations
@@ -11,47 +16,62 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .ops.threshold_search import (kth_largest_key, largest_passing_key,
+                                   ordered_bits)
+
 
 class SamplingParams(NamedTuple):
-    """Per-slot device arrays, all [B]."""
+    """Per-slot arrays, all [B]."""
 
     temperature: jax.Array  # 0 => greedy
     top_k: jax.Array        # 0 => disabled
     top_p: jax.Array        # 1.0 => disabled
 
+    def tiers(self):
+        """(some row samples, some sampled row filters): what decides a
+        step's work, as scalars of the arrays' own kind, so the host counts
+        from its numpy rows what the step program branches on."""
+        samples = self.temperature > 0.0
+        filters = samples & ((self.top_k > 0) | (self.top_p < 1.0))
+        return samples.any(), filters.any()
 
-def default_sampling(batch: int) -> SamplingParams:
-    return SamplingParams(
-        temperature=jnp.zeros((batch,), dtype=jnp.float32),
-        top_k=jnp.zeros((batch,), dtype=jnp.int32),
-        top_p=jnp.ones((batch,), dtype=jnp.float32),
-    )
+
+def _filtered(scaled: jax.Array, params: SamplingParams) -> jax.Array:
+    """scaled: [B, V] -> the same with everything outside a row's top-k and
+    nucleus at -inf. Both cuts are the largest threshold whose kept set is
+    still large enough (k entries; ``top_p`` of the softmax mass), ties at
+    the threshold kept: what a descending sort and a running sum find."""
+    V = scaled.shape[-1]
+    # -0.0 ties +0.0, as in a sort
+    keys = ordered_bits(jnp.where(scaled == 0.0, 0.0, scaled))
+    k = jnp.clip(params.top_k, 0, V)
+    kth = kth_largest_key(keys, jnp.where(k > 0, k, V)[:, None])
+
+    probs = jax.nn.softmax(scaled, axis=-1)
+
+    def heavy_enough(cand):
+        mass = jnp.sum(jnp.where(keys >= cand, probs, 0.0), axis=-1,
+                       keepdims=True)
+        return mass >= params.top_p[:, None]
+
+    # no threshold passes where rounding keeps the whole mass under top_p:
+    # the smallest key, which keeps the row whole
+    cut = largest_passing_key(heavy_enough, kth.shape)
+    return jnp.where(keys >= jnp.maximum(kth, cut), scaled, -jnp.inf)
 
 
 def sample_tokens(logits: jax.Array, params: SamplingParams,
                   key: jax.Array) -> jax.Array:
     """logits: [B, V] fp32 -> token ids [B]."""
-    B, V = logits.shape
     greedy = jnp.argmax(logits, axis=-1)
+    samples, filters = params.tiers()
 
-    temp = jnp.maximum(params.temperature, 1e-6)[:, None]
-    scaled = logits / temp
+    def draw():
+        temp = jnp.maximum(params.temperature, 1e-6)[:, None]
+        scaled = logits / temp
+        masked = jax.lax.cond(filters, _filtered, lambda s, _: s,
+                              scaled, params)
+        sampled = jax.random.categorical(key, masked, axis=-1)
+        return jnp.where(params.temperature <= 0.0, greedy, sampled)
 
-    # top-k: mask everything below the k-th logit (k=0 disables)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]                # [B,V]
-    k = jnp.clip(params.top_k, 0, V)
-    kth_index = jnp.where(k > 0, k - 1, V - 1)
-    kth_value = jnp.take_along_axis(sorted_desc, kth_index[:, None], axis=1)
-    topk_mask = jnp.where((k > 0)[:, None], scaled >= kth_value, True)
-
-    # top-p (nucleus): smallest set with cumulative prob >= p
-    probs_sorted = jax.nn.softmax(sorted_desc, axis=-1)
-    cumulative = jnp.cumsum(probs_sorted, axis=-1)
-    cutoff_count = jnp.sum(cumulative < params.top_p[:, None], axis=-1) + 1  # [B]
-    cutoff_index = jnp.clip(cutoff_count - 1, 0, V - 1)
-    cutoff_value = jnp.take_along_axis(sorted_desc, cutoff_index[:, None], axis=1)
-    topp_mask = scaled >= cutoff_value
-
-    masked = jnp.where(topk_mask & topp_mask, scaled, -jnp.inf)
-    sampled = jax.random.categorical(key, masked, axis=-1)
-    return jnp.where(params.temperature <= 0.0, greedy, sampled)
+    return jax.lax.cond(samples, draw, lambda: greedy)
