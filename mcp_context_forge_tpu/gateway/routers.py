@@ -855,6 +855,19 @@ document.getElementById("f").onsubmit = async (e) => {
                 "grouped_steps": stats.moe_grouped_steps,
                 "scan_steps": stats.moe_scan_steps,
             },
+            # a family whose decode dispatch is a block step (generation by
+            # diffusion over blocks): dispatches, the passes inside them that
+            # sampled, tokens emitted, positions the threshold filled; and
+            # tokens a block committed per forward pass it went through is
+            # the benchmark's diffusion.tokens_per_pass. Zeros elsewhere
+            "diffusion": {
+                "block_length": getattr(engine.model_config, "block_length", 0),
+                "block_steps": stats.block_steps,
+                "denoise_passes": stats.denoise_passes,
+                "block_tokens": stats.block_tokens,
+                "positions_filled_by_threshold":
+                    stats.block_positions_filled_by_threshold,
+            },
             # sampled steps by the work their rows' parameters asked for
             "sampling": {
                 "argmax_steps": stats.sample_argmax_steps,

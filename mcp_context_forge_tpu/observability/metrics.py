@@ -152,6 +152,31 @@ class PrometheusRegistry:
             "Fraction of the paged KV pool in use (0..1)",
             ["replica"], registry=self.registry,
         )
+        # a model family whose decode dispatch is a BLOCK step (generation
+        # by diffusion over blocks, models/sdar.py): dispatches, the forward
+        # passes inside them that sampled, the tokens they emitted and the
+        # positions filled by the confidence threshold (the rest by rank).
+        # Counted at the step's retire; never incremented by another family.
+        self.llm_block_steps = Counter(
+            "mcpforge_llm_block_steps_total",
+            "Block-step dispatches (one block a live row each)",
+            ["replica"], registry=self.registry,
+        )
+        self.llm_denoise_passes = Counter(
+            "mcpforge_llm_denoise_passes_total",
+            "Forward passes of block steps that sampled (commit passes excluded)",
+            ["replica"], registry=self.registry,
+        )
+        self.llm_block_tokens = Counter(
+            "mcpforge_llm_block_tokens_total",
+            "Tokens block steps emitted",
+            ["replica"], registry=self.registry,
+        )
+        self.llm_block_threshold_fills = Counter(
+            "mcpforge_llm_block_threshold_fills_total",
+            "Block positions filled because their confidence passed the threshold",
+            ["replica"], registry=self.registry,
+        )
         self.llm_kv_alloc_failures = Counter(
             "mcpforge_llm_kv_alloc_failures_total",
             "Admissions deferred or requests truncated for lack of KV pages",

@@ -14,7 +14,9 @@ and nowhere else. Three kinds of event share one bounded ring:
   the step's ``StepCounts``: mean selected / context share of its rows,
   tokens through expert layers, token-expert pairs on held experts, and for
   a family with per-sequence state (``models/olmo_hybrid.py``) the live state
-  rows and the real tokens its recurrence scanned);
+  rows and the real tokens its recurrence scanned, and for a family whose
+  decode dispatch is a block step (``models/sdar.py``) its denoise passes,
+  the tokens it emitted and the positions its threshold filled);
 - **request stamps** — ``("req", phase, t, request_id, slot, replica)`` for
   ``submit`` / ``admit`` / ``first`` / ``done``.
 
@@ -56,6 +58,12 @@ class StepCounts(NamedTuple):
     moe_local_pairs: float  # token-expert pairs that landed on held experts
     state_rows_live: float = 0.0   # live per-sequence state rows the step touched
     scanned_tokens: float = 0.0    # real (unpadded) tokens the recurrence scanned
+    # a block step (a family that generates by diffusion over blocks; the
+    # record's ``rows`` are its blocks): forward passes that sampled, the
+    # tokens the step emitted, positions filled by the confidence threshold
+    denoise_passes: float = 0.0
+    block_tokens: float = 0.0
+    filled_by_threshold: float = 0.0
 
 
 class StepEvent(NamedTuple):

@@ -414,6 +414,15 @@ class EngineStats:
         self.state_rows_in_use = 0
         self.state_rows_total = 0
         self.state_scanned_tokens = 0
+        # a family whose decode dispatch is a BLOCK step (models/sdar.py):
+        # dispatches, the forward passes they made that sampled (the commit
+        # pass of each dispatch is not one of them), the tokens they emitted,
+        # and the positions filled because their confidence passed the
+        # threshold (the rest were filled by rank)
+        self.block_steps = 0
+        self.denoise_passes = 0
+        self.block_tokens = 0
+        self.block_positions_filled_by_threshold = 0
 
 
 def _named(jitted, name: str):
@@ -626,6 +635,13 @@ class TPUEngine:
         # the model family (models/__init__.py): the module whose step
         # functions, weight tree and cache pools serve this config's class
         self._family = family_of(self.model_config)
+        # positions a decode dispatch fills a row where the family's step
+        # is a block step (models/__init__.py: STEP_KIND), else 0: what the
+        # scheduler asks the family, once
+        self._block = (self.model_config.block_length
+                       if self._family.STEP_KIND == "block" else 0)
+        # ... whose prefill programs run no head (nothing is sampled there)
+        self._prefill_head = {"head": False} if self._block else {}
         self.tokenizer = load_tokenizer(config.checkpoint,
                                         vocab_size=self.model_config.vocab_size)
         self.stats = EngineStats()
@@ -823,6 +839,9 @@ class TPUEngine:
         # same grid as _decode_fns, but the input token comes from the
         # PREVIOUS dispatch's on-device sampled block instead of the host
         self._decode_fb_fns: dict[tuple[int, int], Any] = {}
+        # a block family's decode grid: (batch-width, context-width) ->
+        # its block step (and then neither dict above ever fills)
+        self._block_fns: dict[tuple[int, int], Any] = {}
         # the chunk/history prefill is a core primitive (prefix-cache hits
         # AND chunked prefill of prompts longer than the largest bucket);
         # compiled per context-width bucket like decode (a hit with 40
@@ -1113,6 +1132,17 @@ class TPUEngine:
             self._decode_fb_fns[key] = fn
         return fn
 
+    def _block_fn(self, ctx_pages: int, batch: int | None = None):
+        key = (batch or self.config.max_batch, ctx_pages)
+        fn = self._block_fns.get(key)
+        if fn is None:
+            fn = _named(jax.jit(partial(self._decode_and_sample_block,
+                                        ctx_pages=ctx_pages),
+                                donate_argnames=("kv",)),
+                        "_decode_and_sample_block")
+            self._block_fns[key] = fn
+        return fn
+
     def _compact_slots(self) -> None:
         """Move the highest-slot requests into the lowest free slots so the
         active ceiling equals the active COUNT. Only block-table rows and
@@ -1338,6 +1368,11 @@ class TPUEngine:
             # ever lands on pre-warmed executables. With no ladder
             # configured this is exactly the static-K grid (one rung).
             k_rungs = self.config.k_rungs()
+            if self._block:
+                # a block family has no one-token decode program: its grid
+                # is the block step, one a (width, context) pair
+                shapes += self._warmup_block_steps(widths)
+                widths = []
             for batch in widths:
                 bsamp = SamplingParams(jnp.zeros((batch,), jnp.float32),
                                        jnp.zeros((batch,), jnp.int32),
@@ -1410,6 +1445,27 @@ class TPUEngine:
         logger.info("tpu_local warmup: %d shapes compiled in %.1fs",
                     shapes, time.monotonic() - started)
 
+    def _warmup_block_steps(self, widths: list[int]) -> int:
+        """Compile the block step for every (width, context bucket): rows
+        with positions -1 are idle, write the trash page and mask nothing,
+        so the loop makes no pass and the commit pass runs once."""
+        Bl = self._block
+        for batch in widths:
+            samp = SamplingParams(jnp.zeros((batch,), jnp.float32),
+                                  jnp.zeros((batch,), jnp.int32),
+                                  jnp.ones((batch,), jnp.float32))
+            for ctx_pages in self._ctx_buckets():
+                (block, *_), self.kv = self._block_fn(ctx_pages, batch)(
+                    self.params, self.kv,
+                    jnp.zeros((batch, Bl), jnp.int32),
+                    jnp.full((batch, Bl), -1, jnp.int32),
+                    jnp.zeros((batch, Bl), bool),
+                    jnp.arange(batch, dtype=jnp.int32), samp,
+                    jax.random.PRNGKey(0))
+                block.block_until_ready()
+            self._warmed_widths.add(batch)
+        return len(widths) * len(self._ctx_buckets())
+
     # ------------------------------------------------------------- device fns
 
     def _paged_impl(self, step: str, kv) -> str:
@@ -1437,9 +1493,17 @@ class TPUEngine:
         # head — [B,S,V] f32 logits would be gigabytes at real vocab sizes
         logits, kv, *aux = self._family.prefill(
             params, cfg, tokens, positions, kv, slot_ids, attn_impl=impl,
-            mesh=self.mesh, last_idx=last_idx)
-        first = sample_tokens(logits, sampling, key)
-        return _beside(first, aux), kv
+            mesh=self.mesh, last_idx=last_idx, **self._prefill_head)
+        return _beside(self._first_tokens(logits, last_idx, sampling, key),
+                       aux), kv
+
+    def _first_tokens(self, logits, last_idx, sampling, key):
+        """What a prefill program samples: each row's next token, or under a
+        block family nothing (every position it ran is known, the head was
+        skipped, and the request's first tokens come from its first block)."""
+        if self._block:
+            return jnp.zeros_like(last_idx)
+        return sample_tokens(logits, sampling, key)
 
     def _prefill_hist_and_sample(self, params, kv, tokens, positions, slot_ids,
                                  last_idx, sampling: SamplingParams, key,
@@ -1451,9 +1515,10 @@ class TPUEngine:
         logits, kv, *aux = self._family.prefill_with_history(
             params, self.model_config, tokens, positions, kv, slot_ids,
             ctx_pages=ctx_pages, last_idx=last_idx,
-            paged_impl=self._paged_impl("prefill_hist", kv), mesh=self.mesh)
-        first = sample_tokens(logits, sampling, key)
-        return _beside(first, aux), kv
+            paged_impl=self._paged_impl("prefill_hist", kv), mesh=self.mesh,
+            **self._prefill_head)
+        return _beside(self._first_tokens(logits, last_idx, sampling, key),
+                       aux), kv
 
     def _verify_fn(self, ctx_pages: int):
         fn = self._verify_fns.get(ctx_pages)
@@ -1557,6 +1622,25 @@ class TPUEngine:
             step, carry0, (jnp.arange(k), keys))
         return (all_tokens, all_valid, done,
                 *(a.sum(axis=0) for a in aux)), kv
+
+    def _decode_and_sample_block(self, params, kv, tokens, positions, masked,
+                                 slot_ids, sampling: SamplingParams, key,
+                                 ctx_pages: int | None = None):
+        """One BLOCK step (a family whose ``STEP_KIND`` is ``"block"``): the
+        family fills and commits ``block_length`` positions a row on the
+        device, passes and fill rule included (``models/sdar.py:
+        block_step``), and the host reads one block back. tokens, positions,
+        masked: [B, Bl]. Returns what :meth:`_decode_and_sample` returns, so
+        that one retire serves both: ``((tokens [Bl, B], valid [Bl, B]: the
+        position was generated here, done [B]: never (the host finds stops),
+        [denoise passes, positions filled by the threshold]), kv)``."""
+        (block, passes, by_threshold), kv = self._family.block_step(
+            params, self.model_config, tokens, positions, masked, kv,
+            slot_ids, sampling, key, ctx_pages=ctx_pages,
+            paged_impl=self._paged_impl("decode", kv), mesh=self.mesh)
+        counts = jnp.stack([passes, by_threshold]).astype(jnp.float32)
+        return (block.T, masked.T, jnp.zeros((tokens.shape[0],), bool),
+                counts), kv
 
     def _decode_and_sample_fb(self, params, kv, prev_block, positions,
                               slot_ids, seq_lens, budgets, stop_tbl,
@@ -2411,17 +2495,24 @@ class TPUEngine:
                 self._shrink_peak = 0
         return admitted, bucket
 
+    def _prefill_end(self, request: GenRequest) -> int:
+        """Where a request's prefill ends: its prompt's length, or under a
+        block family the whole blocks of it (the tokens left open the first
+        generation block as known positions)."""
+        n = len(request.prompt_ids)
+        return n - n % self._block if self._block else n
+
     def _prefill_admitted(self, admitted: list[GenRequest],
                           bucket: int) -> None:
         """One prefill dispatch over the just-admitted group, through to
-        each request's first token."""
+        each request's first token (a block family's prefill yields none)."""
         tl = self.timeline
         any_hist = any(r.hist > 0 for r in admitted)
         kind = "prefill_hist" if any_hist else "prefill"
         seq = tl.next_seq()
         with tl.span("prefill.build", seq, kind) as build:
             tokens, positions, last_idx, slot_ids, sampling = self._pack_rows(
-                [(r, r.hist, len(r.prompt_ids)) for r in admitted], bucket)
+                [(r, r.hist, self._prefill_end(r)) for r in admitted], bucket)
             self._rng, key = jax.random.split(self._rng)
             # long buckets route through the sequence-parallel attention
             # path (shape-deterministic: SP-ness is a property of the
@@ -2454,7 +2545,8 @@ class TPUEngine:
                 counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
                           width=width, dur_ms=elapsed_ms,
-                          tokens=len(admitted), bucket=bucket)
+                          tokens=0 if self._block else len(admitted),
+                          bucket=bucket)
         with tl.span("prefill.emit", seq, kind):
             for i, request in enumerate(admitted):
                 request.prefill_ms = elapsed_ms
@@ -2465,7 +2557,8 @@ class TPUEngine:
                 if self.config.prefix_cache:
                     self.allocator.register_prefix(request.slot,
                                                    request.prompt_ids)
-                self._emit(request, int(first_host[i]))
+                if not self._block:
+                    self._emit(request, int(first_host[i]))
 
     def _pack_rows(self, rows: list[tuple[GenRequest, int, int]], S: int):
         """Pack [(request, start, end)] prompt spans into padded [B, S]
@@ -2488,7 +2581,7 @@ class TPUEngine:
             n = end - start
             tokens[i, :n] = request.prompt_ids[start:end]
             positions[i, :n] = np.arange(start, end)
-            last_idx[i] = n - 1
+            last_idx[i] = max(n - 1, 0)
             slot_ids[i] = request.slot
             temperature[i] = request.temperature
             top_k[i] = request.top_k
@@ -2514,7 +2607,7 @@ class TPUEngine:
         # the smallest bucket covering the WIDEST remaining span this
         # round — rows all on short final chunks must not pay a
         # max-bucket-wide dispatch (every (B, bucket) pair is warmed)
-        max_remaining = max(len(r.prompt_ids) - r.chunk_pos for r in batch)
+        max_remaining = max(self._prefill_end(r) - r.chunk_pos for r in batch)
         S = next((b for b in sorted(config.prefill_buckets)
                   if max_remaining <= b), max(config.prefill_buckets))
         tl = self.timeline
@@ -2524,7 +2617,7 @@ class TPUEngine:
             max_end = 1
             for request in batch:
                 start = request.chunk_pos
-                end = min(start + S, len(request.prompt_ids))
+                end = min(start + S, self._prefill_end(request))
                 rows.append((request, start, end))
                 request.chunk_pos = end
                 max_end = max(max_end, end)
@@ -2549,13 +2642,13 @@ class TPUEngine:
         self._record_step(
             "chunk_prefill", seq=seq, batch=len(batch), width=width,
             dur_ms=elapsed_ms,
-            tokens=sum(1 for r in batch
-                       if r.chunk_pos >= len(r.prompt_ids)),
+            tokens=0 if self._block else sum(
+                1 for r in batch if r.chunk_pos >= len(r.prompt_ids)),
             bucket=S)
         with tl.span("prefill.emit", seq, "chunk"):
             for i, request in enumerate(batch):
                 request.prefill_ms += elapsed_ms
-                if request.chunk_pos < len(request.prompt_ids):
+                if request.chunk_pos < self._prefill_end(request):
                     continue  # more chunks to go; sample discarded
                 del self._chunking[request.slot]
                 # register BEFORE emitting: a first token that finishes
@@ -2566,7 +2659,8 @@ class TPUEngine:
                                                    request.prompt_ids)
                 self.stats.prefill_requests += 1
                 self._running[request.slot] = request
-                self._emit(request, int(first_host[i]))
+                if not self._block:
+                    self._emit(request, int(first_host[i]))
 
     # ------------------------------------------------------- speculative step
 
@@ -2931,7 +3025,11 @@ class TPUEngine:
         its FULL budget (a short budget means max_tokens or the page pool
         ended it, i.e. the row dies at that step's retire), so surviving
         rows advance by exactly ``budget`` tokens and dead rows' lookahead
-        output is discarded wholesale."""
+        output is discarded wholesale.
+
+        Under a block family (``self._block``) the dispatch is a BLOCK step:
+        never device-fed, its rows are blocks (:meth:`_block_rows`) and its
+        program the family's block step, which returns the same record."""
         k = self._k
         tl = self.timeline
         seq = tl.next_seq()
@@ -2941,17 +3039,30 @@ class TPUEngine:
         # off the same spans every dispatch leaves
         sampled = self._phase_sample_due()
         self._dispatch_count += 1
+        first: dict[int, int] = {}
         with tl.span("decode.build", seq, kind) as build:
-            (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
-             budgets, truncated, reqs) = self._decode_rows(B, feed, k)
+            if self._block:
+                (tokens, positions, masked, sampling, budgets, first,
+                 truncated, reqs) = self._block_rows(B)
+                reach = int(positions.max()) + 1
+            else:
+                (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
+                 budgets, truncated, reqs) = self._decode_rows(B, feed, k)
+                # the longest row this block can reach (seq_lens counts the
+                # incoming token; k-1 more may be written)
+                reach = int(seq_lens.max()) + k
             self._rng, key = jax.random.split(self._rng)
-            # context-width bucket: the longest row this block can reach
-            # (seq_lens counts the incoming token; k-1 more may be written)
-            ctx_pages = self._ctx_bucket_for(int(seq_lens.max()) + k)
+            ctx_pages = self._ctx_bucket_for(reach)   # context-width bucket
         with tl.span("decode.table_sync", seq, kind) as table_sync:
             self._sync_tables()
         with tl.span("decode.dispatch", seq, kind) as dispatch:
-            if feed is None:
+            if self._block:
+                (block_tokens, block_valid, block_done, *block_aux), self.kv = \
+                    self._block_fn(ctx_pages, B)(
+                        self.params, self.kv, jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(masked),
+                        jnp.arange(B, dtype=jnp.int32), sampling, key)
+            elif feed is None:
                 (block_tokens, block_valid, block_done, *block_aux), self.kv = \
                     self._decode_fn(ctx_pages, B)(
                         self.params, self.kv, jnp.asarray(tokens),
@@ -3001,10 +3112,11 @@ class TPUEngine:
             pass
         self.stats.decode_steps += k
         self.stats.decode_dispatches += 1
-        self._count_expert_path(B, steps=k)
+        if not self._block:     # a block step's passes are known at retire
+            self._count_expert_path(B, steps=k)
         return {"block": block_tokens, "valid": block_valid,
                 "done": block_done, "aux": block_aux,
-                "budgets": budgets, "reqs": reqs,
+                "budgets": budgets, "reqs": reqs, "first": first,
                 "truncated": truncated, "B": B, "k": k,
                 "ctx_pages": ctx_pages, "batch": len(reqs), "seq": seq,
                 "kind": kind, "t_dispatched": dispatch.t0, "gap_s": gap_s,
@@ -3070,6 +3182,53 @@ class TPUEngine:
         return (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
                 budgets, truncated, reqs)
 
+    def _block_rows(self, B: int):
+        """Pack one block step's [B, Bl] rows from the running set and grant
+        each block its page. A row's block starts at the last block boundary
+        at or below its context: its first ``known`` positions hold the
+        prompt's remainder (only a request's first block has one), the rest
+        hold the mask token and are flagged masked. All ``Bl`` positions are
+        computed and written whatever ``max_tokens`` leaves to emit, so the
+        block's whole page must be granted or the request truncates. Returns
+        the host arrays, the sampling params, and by slot the tokens to emit
+        (``budgets``), where they start in the block (``first``), the rows
+        the pool refused (``truncated``) and the requests."""
+        Bl, cfg = self._block, self.model_config
+        tokens = np.full((B, Bl), cfg.mask_token_id, dtype=np.int32)
+        positions = np.full((B, Bl), -1, dtype=np.int32)
+        masked = np.zeros((B, Bl), dtype=bool)
+        temperature = np.zeros((B,), dtype=np.float32)
+        top_k = np.zeros((B,), dtype=np.int32)
+        top_p = np.ones((B,), dtype=np.float32)
+        budgets: dict[int, int] = {}
+        first: dict[int, int] = {}
+        truncated: set[int] = set()
+        reqs = dict(self._running)
+        for slot, request in reqs.items():
+            n_ctx = len(request.prompt_ids) + len(request.generated)
+            known = n_ctx % Bl
+            start = n_ctx - known
+            want = min(Bl - known, request.max_tokens - len(request.generated))
+            # capacity for the whole block: positions start .. start + Bl - 1
+            if self.allocator.pregrant_block(slot, start + 1, Bl) < Bl:
+                truncated.add(slot)
+                budgets[slot] = 0
+                if self.metrics is not None:
+                    self.metrics.llm_kv_alloc_failures.inc()
+                continue
+            if known:
+                tokens[slot, :known] = (request.prompt_ids
+                                        + request.generated)[start:]
+            positions[slot] = np.arange(start, start + Bl)
+            masked[slot, known:] = True
+            temperature[slot] = request.temperature
+            top_k[slot] = request.top_k
+            top_p[slot] = request.top_p
+            budgets[slot], first[slot] = max(0, want), known
+        sampling = self._sampling_params(temperature, top_k, top_p)
+        return (tokens, positions, masked, sampling, budgets, first,
+                truncated, reqs)
+
     def _decode_retire(self, inflight: dict[str, Any]) -> None:  # lint: hot-path
         """Fetch and emit one dispatched decode SUPER-STEP: the [k, B]
         token block plus the device's valid/done masks come back in ONE
@@ -3082,7 +3241,7 @@ class TPUEngine:
             block_host, valid_host, done_host, *aux_host = jax.device_get(  # lint: allow[host-sync-in-hot-path] retire-side read-back — the ONE host sync per K-token super-step, overlapped by the in-flight dispatch
                 (inflight["block"], inflight["valid"], inflight["done"],
                  *inflight.get("aux", ())))
-        counts = self._step_counts(aux_host)
+        counts = None if self._block else self._step_counts(aux_host)
         t_dispatched, t_retired = inflight["t_dispatched"], readback.t1
         decode_elapsed_ms = (t_retired - t_dispatched) * 1000
         # the per-step wall: under the depth-2 pipeline this step was
@@ -3092,9 +3251,11 @@ class TPUEngine:
         # device was idle at dispatch (serial path / first after drain)
         step_wall_ms = (t_retired - max(t_dispatched,
                                         tl.last_retired or 0.0)) * 1000
-        tl.step(seq, kind, inflight["B"], inflight["batch"],
-                inflight["ctx_pages"], t_dispatched, t_retired, counts)
+        if not self._block:     # a block step's record waits for its tokens
+            tl.step(seq, kind, inflight["B"], inflight["batch"],
+                    inflight["ctx_pages"], t_dispatched, t_retired, counts)
         self.stats.decode_ms_total += step_wall_ms
+        first = inflight["first"]       # by slot; empty for token steps
         decode_emitted = 0
         with tl.span("decode.emit", seq, kind) as emit:
             for slot, request in inflight["reqs"].items():
@@ -3105,7 +3266,8 @@ class TPUEngine:
                         request.finish_reason = "length"
                     self._finish(request)
                     continue
-                for step_i in range(inflight["budgets"][slot]):
+                at = first.get(slot, 0)   # a block's known positions lead it
+                for step_i in range(at, at + inflight["budgets"][slot]):
                     if not valid_host[step_i][slot]:
                         # the device froze this row mid-super-step
                         # (EOS/stop sampled earlier in the block): nothing
@@ -3115,6 +3277,28 @@ class TPUEngine:
                     decode_emitted += 1
                     if self._running.get(slot) is not request:
                         break  # finished (EOS/stop/max): rest discarded
+        if self._block:
+            passes, by_threshold = (int(v) for v in aux_host[0])
+            self.stats.block_steps += 1
+            self.stats.denoise_passes += passes
+            self.stats.block_tokens += decode_emitted
+            self.stats.block_positions_filled_by_threshold += by_threshold
+            if self.metrics is not None:
+                rid = self.config.replica_id
+                self.metrics.llm_block_steps.labels(replica=rid).inc()
+                self.metrics.llm_denoise_passes.labels(replica=rid).inc(passes)
+                self.metrics.llm_block_tokens.labels(replica=rid).inc(
+                    decode_emitted)
+                self.metrics.llm_block_threshold_fills.labels(
+                    replica=rid).inc(by_threshold)
+            # every pass and the commit ran the experts over the whole width
+            self._count_expert_path(inflight["B"] * self._block,
+                                    steps=passes + 1)
+            tl.step(seq, kind, inflight["B"], inflight["batch"],
+                    inflight["ctx_pages"], t_dispatched, t_retired,
+                    StepCounts(0.0, 0.0, 0.0, denoise_passes=float(passes),
+                               block_tokens=float(decode_emitted),
+                               filled_by_threshold=float(by_threshold)))
         phases = inflight.get("phases")
         if phases is not None:
             # a phase row exists only when the SAMPLED dispatch reached

@@ -4,12 +4,20 @@ moderation classifier), pure-pytree params for pjit.
 A decoder family is one module (``llama``: GQA + RoPE + SwiGLU/Mixtral
 experts; ``deepseek``: latent attention, sparse selector, shared + routed
 experts; ``olmo_hybrid``: gated delta-rule linear-attention layers between
-full-attention layers) with the same set of names: ``init_keys``, ``init_layer``,
+full-attention layers; ``sdar``: the GQA trunk with QK-norm and many small
+experts under a block-causal mask, generating by diffusion over blocks) with
+the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
 ``kv_logical`` / ``kv_page_bytes``, the kernel choices ``prefill_impl`` /
-``paged_impl`` / ``expert_path``, and ``refusals`` (engine settings the
-family cannot serve yet). The engine finds the module from the model config's CLASS
+``paged_impl`` / ``expert_path``, ``refusals`` (engine settings the
+family cannot serve yet), and ``STEP_KIND``: what one decode dispatch of the
+family is. ``"token"``: ``decode_step`` yields one token a row (a super-step
+scans it K times). ``"block"``: the family gives ``block_step`` in its place,
+which fills and commits ``config.block_length`` positions a row in one or
+more forward passes on the device; its prefill samples nothing (logits at a
+position predict that position), so a request's first tokens come from its
+first block. The engine finds the module from the model config's CLASS
 (:func:`family_of`): nothing else chooses it.
 
 What a family may declare: a KIND a layer (``layer_kind(config, i)``; the
@@ -24,10 +32,11 @@ from importlib import import_module
 from types import ModuleType
 
 from .configs import (DeepseekConfig, EncoderConfig, LlamaConfig,
-                      OlmoHybridConfig, ENCODER_CONFIGS, MODEL_CONFIGS)
+                      OlmoHybridConfig, SdarConfig, ENCODER_CONFIGS,
+                      MODEL_CONFIGS)
 
 _FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek",
-                   OlmoHybridConfig: "olmo_hybrid"}
+                   OlmoHybridConfig: "olmo_hybrid", SdarConfig: "sdar"}
 
 
 def family_of(model_config) -> ModuleType:
@@ -39,5 +48,5 @@ def family_of(model_config) -> ModuleType:
 
 
 __all__ = ["LlamaConfig", "DeepseekConfig", "OlmoHybridConfig",
-           "EncoderConfig", "MODEL_CONFIGS",
+           "SdarConfig", "EncoderConfig", "MODEL_CONFIGS",
            "ENCODER_CONFIGS", "family_of"]

@@ -192,7 +192,47 @@ class OlmoHybridConfig:
                                       + self.linear_value_dim)
 
 
-MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig] = {
+@dataclass(frozen=True)
+class SdarConfig:
+    """Geometry and generation settings of the block-diffusion family (SDAR
+    ``config.json`` keys in brackets): the Qwen3-MoE trunk (GQA with a
+    per-head RMSNorm on q and k before the rotation, every layer an expert
+    layer of ``n_experts`` [num_experts] experts of ``ffn_hidden``
+    [moe_intermediate_size], top ``moe_top_k`` [num_experts_per_tok],
+    softmax over all then renormalised over the chosen) under a BLOCK-CAUSAL
+    mask: key j is visible to query i iff ``p_j // block_length <= p_i //
+    block_length``. ``logits_i`` are the distribution of token i itself, so a
+    prompt yields no token and generation fills blocks of ``block_length``
+    positions that start as ``mask_token_id``: at most ``denoising_steps``
+    passes, each committing the masked positions whose confidence passes
+    ``confidence_threshold`` or, failing enough of those, the most confident
+    ones (``models/sdar.py``). ``moe_impl``/``moe_block`` as in
+    :class:`LlamaConfig`."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    ffn_hidden: int
+    n_experts: int
+    moe_top_k: int
+    block_length: int = 4
+    denoising_steps: int = 4
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 151_669
+    rope_theta: float = 1_000_000.0
+    norm_eps: float = 1e-6
+    max_seq_len: int = 32_768
+    hidden_act: str = "silu"
+    moe_impl: str = "grouped_pallas"
+    moe_block: int = 128
+
+
+MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
+                    | SdarConfig] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -283,6 +323,14 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig] = {
         n_heads=4, n_kv_heads=4, head_dim=16, ffn_hidden=128,
         linear_n_heads=4, linear_key_dim=16, linear_value_dim=32,
         max_seq_len=512),
+    # the block-diffusion family at CI scale: 8 experts top-2, blocks of 4
+    # positions in at most 4 passes; the mask token is an id of the tiny
+    # vocabulary; the grouped experts through XLA as deepseek-test
+    "sdar-test": SdarConfig(
+        name="sdar-test", vocab_size=512, dim=64, n_layers=2, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_hidden=32, n_experts=8, moe_top_k=2,
+        mask_token_id=511, max_seq_len=512, moe_impl="grouped",
+        moe_block=8),
 }
 
 

@@ -54,6 +54,7 @@ from ..ops.attention import on_tpu
 from ..quantize import embed_rows, qmm
 
 STEP_AUX = True
+STEP_KIND = "token"  # a decode step yields one token a row (models/__init__.py)
 NEG_INF = mla.NEG_INF
 
 

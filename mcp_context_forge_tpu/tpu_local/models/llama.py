@@ -77,6 +77,7 @@ def _dense(key: jax.Array, shape: tuple[int, ...], fan_in: int,
 # (models/__init__.py: the names every decoder family gives)
 
 STEP_AUX = False    # step functions return (logits, kv), no counts beside them
+STEP_KIND = "token"  # a decode step yields one token a row (models/__init__.py)
 
 
 def layer_kind(config: LlamaConfig, layer: int) -> str:

@@ -54,6 +54,7 @@ from ..ops.attention import (causal_attention, on_tpu, select_paged_attention,
 from ..quantize import embed_rows, qmm
 
 STEP_AUX = True
+STEP_KIND = "token"  # a decode step yields one token a row (models/__init__.py)
 L2_EPS = 1e-6
 # Random gate projections W_a, W_b are drawn this much smaller than the other
 # dense weights. The reordered-norm block feeds the mixer the UN-normed

@@ -30,9 +30,25 @@ NEG_INF = -1e30
 
 # ------------------------------------------------------------------- reference
 
+def block_last(positions: jax.Array, mask_block: int) -> jax.Array:
+    """The position a query at ``positions`` masks on under a block-causal
+    mask of ``mask_block`` positions a block: its block's LAST position, so
+    that ``k_pos <= block_last(q_pos)`` lets a query see its whole block and
+    everything before it (RoPE keeps the true position). 1 is the causal
+    mask and returns ``positions`` itself, untouched; -1 (padding) stays -1."""
+    if mask_block == 1:
+        return positions
+    if mask_block & (mask_block - 1) == 0:
+        return positions | (mask_block - 1)
+    return jnp.where(positions < 0, positions,
+                     positions // mask_block * mask_block + mask_block - 1)
+
+
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
-                        valid: jax.Array | None = None) -> jax.Array:
-    """q: [B,S,H,hd]; k/v: [B,S,KV,hd] (GQA); valid: [B,S] bool. -> [B,S,H,hd]."""
+                        valid: jax.Array | None = None,
+                        mask_block: int = 1) -> jax.Array:
+    """q: [B,S,H,hd]; k/v: [B,S,KV,hd] (GQA); valid: [B,S] bool;
+    ``mask_block``: :func:`block_last` (1: causal). -> [B,S,H,hd]."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     group = H // KV
@@ -41,6 +57,9 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
     vf = v.astype(jnp.float32)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qf, kf) / math.sqrt(hd)
     causal = jnp.tril(jnp.ones((S, S), dtype=bool))
+    if mask_block != 1:
+        at = jnp.arange(S)
+        causal = at[None, :] <= block_last(at, mask_block)[:, None]
     mask = causal[None, None, None]
     if valid is not None:
         mask = mask & valid[:, None, None, None, :]
@@ -53,14 +72,17 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------- pallas
 
 def _flash_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, *, block_q: int,
-                  block_k: int, head_dim: int):
+                  block_k: int, head_dim: int, mask_block: int = 1):
     """One (batch, head, q-block) program. Refs:
     q [block_q, hd]; k/v [S, hd] (the head's kv head); valid [1, S] int32;
-    o [block_q, hd]."""
+    o [block_q, hd]. ``mask_block`` divides ``block_q``, so a query's block
+    ends inside its q block and the k blocks walked are the causal ones."""
     q_start = pl.program_id(2) * block_q
 
     q = q_ref[...]
-    q_positions = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    q_positions = block_last(
+        q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0),
+        mask_block)
 
     num_k_blocks = (q_start + block_q + block_k - 1) // block_k
 
@@ -94,19 +116,24 @@ def _flash_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, *, block_q: int,
     o_ref[...] = (acc / jnp.maximum(row_sum, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret",
+                                             "mask_block"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            valid: jax.Array, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = False) -> jax.Array:
+                           block_k: int = 128, interpret: bool = False,
+                           mask_block: int = 1) -> jax.Array:
     """q: [B,S,H,hd]; k/v: [B,S,KV,hd] (GQA: the index map hands each q
     head its kv head, so the repeated heads never materialize);
-    valid: [B,S] bool."""
+    valid: [B,S] bool; ``mask_block``: :func:`block_last` (1: causal)."""
     B, S, H, hd = q.shape
     group = H // k.shape[2]
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     if S % block_q or S % block_k:
         raise ValueError(f"seq {S} must divide blocks ({block_q}, {block_k})")
+    if block_q % mask_block:
+        raise ValueError(f"mask_block {mask_block} must divide the q block "
+                         f"{block_q}")
     qt = q.transpose(0, 2, 1, 3)                          # [B, H, S, hd]
     kt = k.transpose(0, 2, 1, 3)                          # [B, KV, S, hd]
     vt = v.transpose(0, 2, 1, 3)
@@ -116,7 +143,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            lambda b, h, qb: (b, h // group, 0, 0))
     out = pl.pallas_call(
         functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
-                          head_dim=hd),
+                          head_dim=hd, mask_block=mask_block),
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         grid=(B, H, S // block_q),
         in_specs=[
@@ -218,10 +245,13 @@ def on_model_axis(kernel, mesh, in_specs, out_specs):
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid: jax.Array | None = None,
-                     impl: str = "reference", mesh=None) -> jax.Array:
+                     impl: str = "reference", mesh=None,
+                     mask_block: int = 1) -> jax.Array:
     """Dispatch: impl in {pallas, reference, ring, ulysses} ("auto" is
     resolved by the caller that knows its mesh — see
-    :func:`select_prefill_attention`).
+    :func:`select_prefill_attention`). ``mask_block`` > 1 is the
+    block-causal mask (:func:`block_last`), which the kernel and the
+    reference take and the sequence-parallel paths do not.
 
     ring/ulysses are the sequence-parallel paths (SURVEY.md §5.7): the
     sequence dim is sharded over the mesh's ``model`` axis via shard_map —
@@ -235,6 +265,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl in ("ring", "ulysses"):
         if mesh is None:
             raise ValueError(f"attn impl {impl!r} requires a mesh")
+        if mask_block != 1:
+            raise ValueError(f"attn impl {impl!r} has no block-causal mask")
         from ..parallel.ring_attention import (make_ring_attention,
                                                make_ulysses_attention)
         # GQA k/v stay at KV width: the SP bodies expand per device, so the
@@ -250,8 +282,10 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return maker(mesh, axis_name="model")(q, k, v, valid)
     if impl == "pallas":
         heads = P(None, None, "model", None)
-        return on_model_axis(flash_attention_pallas, mesh,
+        kernel = functools.partial(flash_attention_pallas,
+                                   mask_block=mask_block)
+        return on_model_axis(kernel, mesh,
                              (heads, heads, heads, P()), heads)(q, k, v, valid)
     if impl != "reference":
         raise ValueError(f"unknown attention impl {impl!r}")
-    return attention_reference(q, k, v, valid)
+    return attention_reference(q, k, v, valid, mask_block)

@@ -170,6 +170,43 @@ def _hybrid_paged_case(chunk: int | None, batch: int, table: int):
              ((batch, table), jnp.int32), ((batch, chunk), jnp.int32)])
 
 
+# sdar-30b-a3b-d12.chat: 4 kv heads of 8 query heads, 12 layers x 512 pages;
+# 128 experts of 2048 x 768 top-8
+SDAR_KV, SDAR_G, SDAR_E, SDAR_D, SDAR_F = 4, 8, 128, 2048, 768
+
+
+def _sdar_flash_case(batch: int):
+    """A prefill's flash kernel under the block-causal mask (blocks of 4)."""
+    q = ((batch, 512, SDAR_KV * SDAR_G, HD), jnp.bfloat16)
+    kv = ((batch, 512, SDAR_KV, HD), jnp.bfloat16)
+    return (partial(attention.flash_attention_pallas, mask_block=4),
+            [q, kv, kv, ((batch, 512), jnp.bool_)])
+
+
+def _sdar_paged_case(chunk: int, batch: int, table: int):
+    """The paged chunk kernel at a block step's shape (4 positions a row, 32
+    rows) and at a chunk round's query tile."""
+    pool = ((12, 512, PAGE, SDAR_KV, HD), jnp.bfloat16)
+    return (partial(paged.paged_chunk_attention_pallas, layer=7),
+            [((batch, chunk, SDAR_KV, SDAR_G, HD), jnp.bfloat16), pool, pool,
+             ((batch, table), jnp.int32), ((batch, chunk), jnp.int32)])
+
+
+def _sdar_moe_case():
+    """The row-block kernel over 128 int8 experts: a [4, 512] prefill's plan,
+    2048 x 8 pairs in blocks of 128 rows, one spare block an expert."""
+    n_blocks = 2048 * 8 // BLOCK + SDAR_E
+    up = [((SDAR_E, SDAR_D, SDAR_F), jnp.int8), ((SDAR_E, SDAR_F), jnp.bfloat16)]
+    down = [((SDAR_E, SDAR_F, SDAR_D), jnp.int8), ((SDAR_E, SDAR_D), jnp.bfloat16)]
+
+    def fn(x, q1, s1, q3, s3, q2, s2, *tail):
+        return grouped_moe._expert_blocks_pallas(
+            x, {"q": q1, "s": s1}, {"q": q3, "s": s3}, {"q": q2, "s": s2},
+            *tail, block=BLOCK)
+    return fn, [((n_blocks, BLOCK, SDAR_D), jnp.bfloat16), *up, *up, *down,
+                ((n_blocks,), jnp.int32), ((1,), jnp.int32)]
+
+
 # _history_tile(S, G=4) yields query tiles of 128..512 (and spec-verify
 # chunks of spec_k=4); kv_heads=2 is one shard of a 1x4 TP mesh
 KERNEL_CASES = {
@@ -227,6 +264,15 @@ KERNEL_CASES = {
     "gated_delta_chunk_cell_4x512": lambda: _gated_delta_case(4, 512),
     "paged_decode_bf16_hybrid_32x8": lambda: _hybrid_paged_case(None, 32, 8),
     "paged_chunk_bf16_hybrid_tile128x8": lambda: _hybrid_paged_case(128, 4, 8),
+    # sdar-30b-a3b-d12.chat: the masked prefill's flash kernel, the block
+    # step's chunk kernel at both decode buckets, a chunk round's 256-query
+    # tile, and the grouped experts of a [4, 512] prefill
+    "flash_prefill_block_mask_1x512": lambda: _sdar_flash_case(1),
+    "flash_prefill_block_mask_4x512": lambda: _sdar_flash_case(4),
+    "paged_chunk_bf16_block_step_32x4": lambda: _sdar_paged_case(4, 32, 4),
+    "paged_chunk_bf16_block_step_32x8": lambda: _sdar_paged_case(4, 32, 8),
+    "paged_chunk_bf16_sdar_tile256x8": lambda: _sdar_paged_case(256, 4, 8),
+    "grouped_moe_int8_sdar_4x512": _sdar_moe_case,
 }
 
 
@@ -240,6 +286,59 @@ def test_kernel_compiles_for_v5e(v5e, case):
     assert not jax.config.jax_enable_compilation_cache
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_block_step_and_masked_prefill_compile_for_v5e(v5e):
+    """The block-diffusion family's two step programs, whole (the while loop
+    with the pool in its carry, the paged kernel, the expert scan over 128
+    int8 experts, the head and the confidence sampling; the masked flash
+    prefill with the grouped experts), at the cell's shapes and widths, two
+    layers deep: 32 rows x 4 positions over an 8-page bucket, and [4, 512]."""
+    from mcp_context_forge_tpu.tpu_local.kv import init_kv_state
+    from mcp_context_forge_tpu.tpu_local.models import sdar
+    from mcp_context_forge_tpu.tpu_local.models.configs import SdarConfig
+    from mcp_context_forge_tpu.tpu_local.parallel.mesh import make_mesh
+    from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
+    from mcp_context_forge_tpu.tpu_local.sampling import SamplingParams
+
+    cfg = SdarConfig(name="sdar-d2", vocab_size=151936, dim=SDAR_D, n_layers=2,
+                     n_heads=SDAR_KV * SDAR_G, n_kv_heads=SDAR_KV, head_dim=HD,
+                     ffn_hidden=SDAR_F, n_experts=SDAR_E, moe_top_k=8)
+    mesh = make_mesh("", devices=[next(iter(v5e.device_set))])
+    like = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    params = like(jax.eval_shape(lambda: quantize_tree(
+        sdar.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16),
+        sdar.params_logical(cfg), scale_dtype=jnp.bfloat16)))
+    kv = like(jax.eval_shape(partial(init_kv_state, cfg, 512, PAGE, 32, 8,
+                                     dtype=jnp.bfloat16)))
+    B, Bl = 32, cfg.block_length
+    rows = SamplingParams(spec((B,), jnp.float32), spec((B,), jnp.int32),
+                          spec((B,), jnp.float32))
+    with mesh:
+        step = jax.jit(
+            lambda p, kv, tok, pos, m, slots, samp, key: sdar.block_step(
+                p, cfg, tok, pos, m, kv, slots, samp, key, ctx_pages=8,
+                paged_impl="pallas", mesh=mesh), donate_argnums=(1,))
+        text = step.lower(
+            params, kv, spec((B, Bl), jnp.int32), spec((B, Bl), jnp.int32),
+            spec((B, Bl), jnp.bool_), spec((B,), jnp.int32), rows,
+            spec((2,), jnp.uint32)).compile().as_text()
+        assert text.count("tpu_custom_call") >= cfg.n_layers   # the paged kernel
+        assert " while(" in text
+        prefill = jax.jit(
+            lambda p, kv, tok, pos, slots: sdar.prefill(
+                p, cfg, tok, pos, kv, slots, attn_impl="pallas", mesh=mesh,
+                head=False)[1], donate_argnums=(1,))
+        text = prefill.lower(
+            params, kv, spec((4, 512), jnp.int32), spec((4, 512), jnp.int32),
+            spec((4,), jnp.int32)).compile().as_text()
+        # the masked flash kernel and the grouped experts
+        assert text.count("tpu_custom_call") >= cfg.n_layers
+        assert "flash_attention" in text and "grouped_moe_q8" in text
+    assert sdar.expert_path(cfg, mesh, 4 * 512) == "grouped"
+    assert sdar.expert_path(cfg, mesh, B * Bl) == "scan"
 
 
 # ------------------------------------------------- chip_smoke.py, rehearsed
