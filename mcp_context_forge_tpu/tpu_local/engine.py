@@ -172,9 +172,10 @@ class EngineConfig:
     kv_quant: str = ""
     # MoE serving formulation override ("" = model default; see
     # models/configs.py moe_impl): dense | grouped | grouped_pallas.
-    # moe_block overrides the kernel row-block AND the T·k >= E·block
-    # engagement gate (0 = model default) — small models need a smaller
-    # block or every dispatch takes the expert scan.
+    # moe_block overrides the model's (0 = model default): the kernel's
+    # widest row-block and the T·k >= E·block width from which a step takes
+    # it (models/llama.py: expert_path) — small models need a smaller block
+    # or every dispatch takes the expert scan.
     moe_impl: str = ""
     moe_block: int = 0
     # decode batch-width bucketing: size decode arrays by the ACTIVE slot
@@ -3459,8 +3460,9 @@ class TPUEngine:
     def _count_expert_path(self, tokens: int, steps: int = 1) -> None:
         """Count ``steps`` steps of ``tokens`` tokens each by the expert
         formulation their program traced (nothing for a model without
-        routed experts)."""
-        path = self._family.expert_path(self.model_config, self.mesh, tokens)
+        routed experts); the engine's dtype is its activations'."""
+        path = self._family.expert_path(self.model_config, self.mesh, tokens,
+                                        self._kv_dtype)
         if path == "grouped":
             self.stats.moe_grouped_steps += steps
         elif path == "scan":

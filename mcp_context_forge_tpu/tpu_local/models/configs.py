@@ -40,20 +40,27 @@ class LlamaConfig:
     # the same per-token function, and models/llama.py: expert_path picks
     # one a STEP from what it can see — no caller tunes it:
     #   the row-block kernel (ops/grouped_moe.py: each token's chosen
-    #   experts only, the bucket's padding tokens given no row, weight
-    #   tiles DMA'd a block via scalar prefetch) for a step wide enough
-    #   that T·k >= E·moe_block, on a mesh whose ``model`` axis is one
-    #   device: prefills, chunk rounds, wide history suffixes;
+    #   experts only, padding tokens and idle rows given no row, weight
+    #   tiles DMA'd a block via scalar prefetch) on a mesh whose ``model``
+    #   axis is one device, for a step wide enough that T·k >= E·moe_block
+    #   (prefills, chunk rounds, wide history suffixes) at row-block
+    #   moe_block, and for a narrower step whose padded rows
+    #   T·k + E·b(T) are at most a quarter of the scan's E·T, at the
+    #   row-block b(T) its width gives it (expert_block: the power of two
+    #   that holds T·k / E pairs, from the activations' sublane tile up
+    #   to moe_block) — a decode-width step over many small experts;
     #   the expert scan with gate masks (parallel/moe.py: E/k x the FLOPs,
-    #   no gathers) for every other step — decode and verify steps
-    #   (T = batch width), where it is at its floor of one read of every
-    #   expert's weights, a TP mesh, and callers that differentiate.
+    #   no gathers) for every other step — this family's decode and
+    #   verify steps (8 x top-2: T·k alone is E·T / 4), where it is at
+    #   its floor of one read of every expert's weights, a TP mesh, and
+    #   callers that differentiate.
     # ``moe_impl`` names the family's widest choice: grouped_pallas (the
     # rule above), grouped (the same plan through XLA's gathered-weights
     # einsum, which materializes [NB, D, F]: small models and tests) or
     # dense (the scan always). The engine's ``moe_impl`` override exists
     # for an A/B until ROADMAP Queue 3 ``unmeasured-options`` removes it.
-    # moe_block is the kernel's row-block and the gate's width.
+    # moe_block is the kernel's WIDEST row-block and the width (in pairs
+    # an expert) from which a step takes it whatever its rows.
     # parallel/moe.py's capacity dispatch stays the EP-training path.
     n_experts: int = 0
     moe_top_k: int = 2
@@ -84,8 +91,10 @@ class DeepseekConfig:
     [moe_intermediate_size]. ``experts_held`` is the half-open range of routed
     experts THIS engine computes: routing is over all of them, a pair that
     lands outside the range adds nothing here (its chip of an expert-parallel
-    deployment would). ``moe_impl``/``moe_block`` as in :class:`LlamaConfig`;
-    steps narrower than ``moe_block`` tokens take the expert scan."""
+    deployment would). ``moe_impl`` as in :class:`LlamaConfig`; ``moe_block``
+    is this family's one row-block, and by its own rule
+    (``models/deepseek.py: expert_path``) steps narrower than ``moe_block``
+    tokens take the expert scan."""
 
     name: str
     vocab_size: int
@@ -207,7 +216,8 @@ class SdarConfig:
     passes, each committing the masked positions whose confidence passes
     ``confidence_threshold`` or, failing enough of those, the most confident
     ones (``models/sdar.py``). ``moe_impl``/``moe_block`` as in
-    :class:`LlamaConfig`."""
+    :class:`LlamaConfig`: with 128 x top-8 a block step of 128 tokens is a
+    narrow step the rule sends to the row-block kernel at 16 rows."""
 
     name: str
     vocab_size: int

@@ -303,11 +303,13 @@ def route(layer: dict[str, Any], config: DeepseekConfig,
     return ids.astype(jnp.int32), weights, biased
 
 
-def expert_path(config: DeepseekConfig, mesh, tokens: int) -> str:
+def expert_path(config: DeepseekConfig, mesh, tokens: int,
+                dtype=None) -> str:
     """Which formulation the routed experts of a step of ``tokens`` tokens
     trace (the engine counts its steps by the same call): the dropless
     grouped row-blocks for steps of at least ``moe_block`` tokens, the
-    expert scan for narrower ones (decode)."""
+    expert scan for narrower ones (decode). The activations' ``dtype`` does
+    not enter this family's rule."""
     wide = tokens >= config.moe_block
     return ("grouped" if config.moe_impl.startswith("grouped") and wide
             else "scan")

@@ -257,27 +257,59 @@ def _ffn(layer: dict[str, Any], x: jax.Array,
     return qmm(gate * qmm(x, layer["w3"]), layer["w2"])
 
 
-def expert_path(config: LlamaConfig, mesh, tokens: int) -> str | None:
+def expert_block(config: LlamaConfig, tokens: int,
+                 dtype: Any = jnp.bfloat16) -> int:
+    """The grouped kernel's row-block for a step of ``tokens`` tokens: the
+    power of two that holds an expert's mean share of the step's pairs
+    (T·k / E), no smaller than the sublane tile of the activations (16 rows
+    of bfloat16, 8 of float32) and no larger than ``config.moe_block``, which
+    stands where it is under the tile (the tiny test configurations). A step
+    of T·k >= E·moe_block pairs gets ``moe_block`` itself."""
+    tile = 32 // jnp.dtype(dtype).itemsize
+    share = -(-tokens * config.moe_top_k // config.n_experts)
+    return min(config.moe_block, max(tile, 1 << (share - 1).bit_length()))
+
+
+def expert_path(config: LlamaConfig, mesh, tokens: int,
+                dtype: Any = jnp.bfloat16) -> str | None:
     """Which formulation the expert FFN of a step of ``tokens`` tokens
     traces, from what is visible before tracing — ``"grouped"`` (each
-    token's chosen experts only, ops/grouped_moe.py), ``"scan"`` (every
-    expert over every token, gate-masked: parallel/moe.py) — or None for a
-    model without a router. The engine counts its steps by the same call.
+    token's chosen experts only, ops/grouped_moe.py, at the row-block
+    :func:`expert_block` gives the step), ``"scan"`` (every expert over
+    every token, gate-masked: parallel/moe.py) — or None for a model without
+    a router. The engine counts its steps by the same call.
 
-    Grouped row-blocks pay when T·k >= E·block (padded rows T·k + E·block
-    against the scan's E·T): prefills, chunk rounds and wide history
-    suffixes clear it, decode and verify steps (T = batch width) do not, and
-    for them the scan is at its floor anyway — a step reads every expert's
-    weights once. The row-block KERNEL runs where the caller's mesh says
-    one device holds the whole stacks: it is not wrapped in shard_map, so on
-    a ``model`` axis wider than one device XLA would gather the sharded
-    stacks to every chip, and it has no gradient rule, so a caller that
-    names no mesh (training, the pipeline stages) keeps the scan too."""
+    The rule is one of ROWS, a function of the step's shape alone (T, k, E,
+    ``moe_block``, the activations' dtype): grouped row-blocks run at most
+    T·k + E·b rows, the scan E·T.
+    - A wide step, T·k >= E·moe_block (prefills, chunk rounds, wide history
+      suffixes), is grouped at b = ``moe_block``.
+    - A narrower step is grouped at b = b(T) when its padded rows are at
+      most a quarter of the scan's: T·k + E·b(T) <= E·T / 4. That needs many
+      small experts: a block step of 128 x top-8 (128 tokens: 1024 + 128·16
+      rows against 16384) clears it, and there an expert iteration of the
+      scan is NOT at its floor, because an int8 expert's matmuls (bound by
+      pushing its 4.7 MB of weights through the MXU, whatever the rows) take
+      as long as its weight read and the two serialise, while the kernel's
+      pipeline fetches the next expert's tiles under this one's matmuls and
+      never reads an expert no live token chose (on a v5e 6.6 us a live
+      expert against the scan's 16 an expert: PERF.md §6, PR 38). A Mixtral
+      decode or verify step (8 x top-2: T·k alone is E·T / 4) never clears
+      it, and for it the scan is at its floor anyway — a step reads every
+      expert's weights once, far longer than its matmuls.
+    The row-block KERNEL runs where the caller's mesh says one device holds
+    the whole stacks: it is not wrapped in shard_map, so on a ``model`` axis
+    wider than one device XLA would gather the sharded stacks to every chip,
+    and it has no gradient rule, so a caller that names no mesh (training,
+    the pipeline stages) keeps the scan too."""
     if not config.n_experts:
         return None
-    wide = tokens * config.moe_top_k >= config.n_experts * config.moe_block
+    pairs, experts = tokens * config.moe_top_k, config.n_experts
+    rows = pairs + experts * expert_block(config, tokens, dtype)
+    pays = (pairs >= experts * config.moe_block
+            or 4 * rows <= experts * tokens)
     whole = mesh is not None and mesh.shape.get("model", 1) == 1
-    if (not config.moe_impl.startswith("grouped") or not wide
+    if (not config.moe_impl.startswith("grouped") or not pays
             or (config.moe_impl == "grouped_pallas" and not whole)):
         return "scan"
     return "grouped"
@@ -287,20 +319,23 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
                x: jax.Array, mesh=None,
                valid: jax.Array | None = None) -> jax.Array:
     """Dense SwiGLU/GeGLU, or top-k routed MoE when the layer carries a
-    router (Mixtral family).
+    router (Mixtral family, and the block-diffusion family that imports this
+    block as its own).
 
     Both serving formulations are DROP-FREE and compute the same per-token
-    function (:func:`expert_path` says which a step takes): capacity drops
-    make a layer's output a function of the BATCH SHAPE — a token dropped
-    in an 11-token prefill but kept in a 1-token decode would break the
-    incremental-decode invariant (prefill + decode must equal one long
-    prefill). EP fleets with an 'expert' mesh axis use moe_ffn's capacity
-    dispatch instead (all_to_all lowering, Switch drop policy).
+    function (:func:`expert_path` says which a step takes, and
+    :func:`expert_block` the grouped one's row-block: both follow the step's
+    shape): capacity drops make a layer's output a function of the BATCH
+    SHAPE — a token dropped in an 11-token prefill but kept in a 1-token
+    decode would break the incremental-decode invariant (prefill + decode
+    must equal one long prefill). EP fleets with an 'expert' mesh axis use
+    moe_ffn's capacity dispatch instead (all_to_all lowering, Switch drop
+    policy).
 
-    ``valid`` [B, S]: False marks the bucket's padding tokens. The grouped
-    path gives their pairs no row and their output is zero; the scan
-    computes them like any token. Nothing reads either (no KV write, no
-    sample)."""
+    ``valid`` [B, S]: False marks the bucket's padding tokens and the idle
+    rows of a decode-width dispatch. The grouped path gives their pairs no
+    row and their output is zero; the scan computes them like any token.
+    Nothing reads either (no KV write, no sample)."""
     if "router" not in layer:
         return _ffn(layer, x, config.hidden_act)
     from ..parallel.moe import MoEConfig, moe_ffn_dense_mask
@@ -309,7 +344,8 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
                         expert_hidden=config.ffn_hidden,
                         top_k=config.moe_top_k)
     moe_params = {k: layer[k] for k in ("router", "w1", "w3", "w2")}
-    if expert_path(config, mesh, x.shape[0] * x.shape[1]) == "grouped":
+    tokens = x.shape[0] * x.shape[1]
+    if expert_path(config, mesh, tokens, x.dtype) == "grouped":
         # the kernel interprets off-TPU (the caller's mesh says which) so
         # the code path exists everywhere
         from ..ops.attention import on_tpu
@@ -317,7 +353,8 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
         use_pallas = config.moe_impl == "grouped_pallas"
         return moe_ffn_grouped(
             moe_params, x, moe_cfg, act=config.hidden_act,
-            impl="pallas" if use_pallas else "xla", block=config.moe_block,
+            impl="pallas" if use_pallas else "xla",
+            block=expert_block(config, tokens, x.dtype),
             interpret=use_pallas and not on_tpu(mesh), valid=valid)
     return moe_ffn_dense_mask(moe_params, x, moe_cfg, act=config.hidden_act)
 

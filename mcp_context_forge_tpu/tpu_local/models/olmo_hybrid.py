@@ -209,7 +209,8 @@ def delta_impl(mesh, config: OlmoHybridConfig) -> str:
     return "pallas" if on_tpu(mesh) and aligned else "jnp"
 
 
-def expert_path(config: OlmoHybridConfig, mesh, tokens: int) -> None:
+def expert_path(config: OlmoHybridConfig, mesh, tokens: int,
+                dtype=None) -> None:
     """No routed experts: as the dense trunk answers."""
     return None
 

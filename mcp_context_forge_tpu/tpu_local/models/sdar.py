@@ -15,7 +15,9 @@ reference are handed the rounded positions in place of the true ones. ``Bl``
 divides the page size and every kernel tile, so no block straddles a page or
 a tile. FFN: every layer routes over ``n_experts`` small experts (softmax
 over all, the top ``moe_top_k`` renormalised), through the trunk's
-``_ffn_block`` and its two formulations (``expert_path``).
+``_ffn_block`` and its two formulations (``expert_path``: with 128 x top-8 a
+block step of 32 rows takes the row-block kernel at 16 rows, like a prefill
+at ``moe_block``; idle rows, position -1, get no row).
 
 **What the logits mean.** ``logits_i`` are the distribution of token i ITSELF
 (no shift): a position holding ``mask_token_id`` is predicted from its own

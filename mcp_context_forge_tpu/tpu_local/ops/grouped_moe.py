@@ -2,11 +2,17 @@
 
 The expert scan (`parallel/moe.py: expert_scan`) runs EVERY expert over
 EVERY token and masks — E/k x the needed FFN FLOPs (4 x for Mixtral
-8 x top-2). That is free where a step is bound by reading every expert's
-weights once (decode), and the whole cost where it is bound by the MXU
-(prefill). This module computes the same per-token function at ~k/E of the
-FLOPs with STATIC shapes (XLA requirement), by the block-sparse trick of
-MegaBlocks-style grouped GEMMs:
+8 x top-2, 16 x for 128 x top-8). That is free where an expert's weight
+read is far longer than its matmul over the step's rows (a Mixtral decode
+step: 176 MB an expert, at most 32 rows), and the whole cost where a step is
+bound by the MXU (prefill). Between the two lies a decode-width step over
+MANY SMALL experts (a block step of 128 tokens over 128 int8 experts of
+4.7 MB): an iteration's matmul takes as long as its weight read, the scan
+serialises the two, and the row-block kernel below at a block of 16 rows
+overlaps them (one pipelined kernel a layer: 6.6 us a live expert against
+the scan's 16 on a v5e). This module computes the same per-token function at
+~k/E of the FLOPs with STATIC shapes (XLA requirement), by the block-sparse
+trick of MegaBlocks-style grouped GEMMs:
 
 1. flatten the T x k (token, expert) choices and sort them by expert —
    each expert's tokens become contiguous. A pair whose id lies outside
@@ -36,9 +42,12 @@ MegaBlocks-style grouped GEMMs:
    has a row), or the weighted rows scatter-add to their tokens (a held
    range: most pairs are elsewhere).
 
-Who takes which path is the model family's to say from what it can see
-(`models/llama.py: expert_path`, `models/deepseek.py: expert_path`): step
-width against ``E·Bt``, the mesh, the stacks' dtype.
+Who takes which path, and at which row-block, is the model family's to say
+from what it can see (`models/llama.py: expert_path` / `expert_block`,
+`models/deepseek.py: expert_path`): the step's rows ``T·k + E·Bt`` against
+the scan's ``E·T``, the mesh, the activations' dtype. ``Bt`` follows the
+step: ``moe_block`` for a wide one, the power of two that holds an expert's
+share of the pairs for a narrow one, down to the activations' sublane tile.
 
 Per-token outputs are EXACTLY the scan's (same router math via
 ``router_probs``, same renormalized gates), whatever rows share a block or
@@ -49,7 +58,9 @@ tests/tpu_local/test_grouped_moe.py.
 FLOPs accounting: the scan runs E·T rows through the FFN; grouped runs at
 most NB·Bt = T·k + E·Bt rows (+ router), and the live blocks only. For
 Mixtral-shape 8 x top-2 with T=2048, Bt=128: (2048·2 + 8·128) / (8·2048) =
-31.3% vs 25% ideal — the E·Bt padding term vanishes as T grows.
+31.3% vs 25% ideal — the E·Bt padding term vanishes as T grows. A block
+step of 128 tokens over 128 x top-8 at Bt=16: (1024 + 2048) / 16384 = 18.8%
+vs 6.25% ideal, and the padded blocks past the live ones are skipped.
 """
 
 from __future__ import annotations

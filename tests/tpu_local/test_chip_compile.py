@@ -192,18 +192,19 @@ def _sdar_paged_case(chunk: int, batch: int, table: int):
              ((batch, table), jnp.int32), ((batch, chunk), jnp.int32)])
 
 
-def _sdar_moe_case():
+def _sdar_moe_case(tokens: int = 2048, block: int = BLOCK):
     """The row-block kernel over 128 int8 experts: a [4, 512] prefill's plan,
-    2048 x 8 pairs in blocks of 128 rows, one spare block an expert."""
-    n_blocks = 2048 * 8 // BLOCK + SDAR_E
+    2048 x 8 pairs in blocks of 128 rows, one spare block an expert; or a
+    block step's (128 tokens) at the narrow row-block its width gives it."""
+    n_blocks = tokens * 8 // block + SDAR_E
     up = [((SDAR_E, SDAR_D, SDAR_F), jnp.int8), ((SDAR_E, SDAR_F), jnp.bfloat16)]
     down = [((SDAR_E, SDAR_F, SDAR_D), jnp.int8), ((SDAR_E, SDAR_D), jnp.bfloat16)]
 
     def fn(x, q1, s1, q3, s3, q2, s2, *tail):
         return grouped_moe._expert_blocks_pallas(
             x, {"q": q1, "s": s1}, {"q": q3, "s": s3}, {"q": q2, "s": s2},
-            *tail, block=BLOCK)
-    return fn, [((n_blocks, BLOCK, SDAR_D), jnp.bfloat16), *up, *up, *down,
+            *tail, block=block)
+    return fn, [((n_blocks, block, SDAR_D), jnp.bfloat16), *up, *up, *down,
                 ((n_blocks,), jnp.int32), ((1,), jnp.int32)]
 
 
@@ -266,13 +267,15 @@ KERNEL_CASES = {
     "paged_chunk_bf16_hybrid_tile128x8": lambda: _hybrid_paged_case(128, 4, 8),
     # sdar-30b-a3b-d12.chat: the masked prefill's flash kernel, the block
     # step's chunk kernel at both decode buckets, a chunk round's 256-query
-    # tile, and the grouped experts of a [4, 512] prefill
+    # tile, the grouped experts of a [4, 512] prefill, and those of a block
+    # step (32 rows x 4 positions) at the 16-row block its width gives it
     "flash_prefill_block_mask_1x512": lambda: _sdar_flash_case(1),
     "flash_prefill_block_mask_4x512": lambda: _sdar_flash_case(4),
     "paged_chunk_bf16_block_step_32x4": lambda: _sdar_paged_case(4, 32, 4),
     "paged_chunk_bf16_block_step_32x8": lambda: _sdar_paged_case(4, 32, 8),
     "paged_chunk_bf16_sdar_tile256x8": lambda: _sdar_paged_case(256, 4, 8),
     "grouped_moe_int8_sdar_4x512": _sdar_moe_case,
+    "grouped_moe_int8_sdar_block_step_b16": lambda: _sdar_moe_case(128, 16),
 }
 
 
@@ -290,13 +293,16 @@ def test_kernel_compiles_for_v5e(v5e, case):
 
 def test_block_step_and_masked_prefill_compile_for_v5e(v5e):
     """The block-diffusion family's two step programs, whole (the while loop
-    with the pool in its carry, the paged kernel, the expert scan over 128
-    int8 experts, the head and the confidence sampling; the masked flash
-    prefill with the grouped experts), at the cell's shapes and widths, two
-    layers deep: 32 rows x 4 positions over an 8-page bucket, and [4, 512]."""
+    with the pool in its carry, the paged kernel, the grouped experts over 128
+    int8 stacks at the 16-row block a step of 128 tokens takes, the head and
+    the confidence sampling; the masked flash prefill with the grouped
+    experts at the configuration's block), at the cell's shapes and widths,
+    two layers deep: 32 rows x 4 positions over an 8-page bucket, and
+    [4, 512]. A lowering Mosaic refuses at the narrow block is found here."""
     from mcp_context_forge_tpu.tpu_local.kv import init_kv_state
     from mcp_context_forge_tpu.tpu_local.models import sdar
     from mcp_context_forge_tpu.tpu_local.models.configs import SdarConfig
+    from mcp_context_forge_tpu.tpu_local.models.llama import expert_block
     from mcp_context_forge_tpu.tpu_local.parallel.mesh import make_mesh
     from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
     from mcp_context_forge_tpu.tpu_local.sampling import SamplingParams
@@ -325,8 +331,9 @@ def test_block_step_and_masked_prefill_compile_for_v5e(v5e):
             params, kv, spec((B, Bl), jnp.int32), spec((B, Bl), jnp.int32),
             spec((B, Bl), jnp.bool_), spec((B,), jnp.int32), rows,
             spec((2,), jnp.uint32)).compile().as_text()
-        assert text.count("tpu_custom_call") >= cfg.n_layers   # the paged kernel
-        assert " while(" in text
+        # the paged kernel and the row-block kernel, a layer each
+        assert text.count("tpu_custom_call") >= 2 * cfg.n_layers
+        assert " while(" in text and "grouped_moe_q8" in text
         prefill = jax.jit(
             lambda p, kv, tok, pos, slots: sdar.prefill(
                 p, cfg, tok, pos, kv, slots, attn_impl="pallas", mesh=mesh,
@@ -338,7 +345,8 @@ def test_block_step_and_masked_prefill_compile_for_v5e(v5e):
         assert text.count("tpu_custom_call") >= cfg.n_layers
         assert "flash_attention" in text and "grouped_moe_q8" in text
     assert sdar.expert_path(cfg, mesh, 4 * 512) == "grouped"
-    assert sdar.expert_path(cfg, mesh, B * Bl) == "scan"
+    assert sdar.expert_path(cfg, mesh, B * Bl) == "grouped"
+    assert (expert_block(cfg, 4 * 512), expert_block(cfg, B * Bl)) == (128, 16)
 
 
 # ------------------------------------------------- chip_smoke.py, rehearsed

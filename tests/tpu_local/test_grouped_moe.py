@@ -171,31 +171,68 @@ def test_kernel_skips_dead_blocks_and_writes_zeros(stacks):
     assert np.isnan(np.asarray(every[live:])).all()
 
 
-@pytest.mark.parametrize("impl,stacks", [("xla", "full"), ("pallas", "full"),
-                                         ("pallas", "int8")])
-def test_padding_tokens_get_no_row(impl, stacks):
+# a block step of the block-diffusion family in small widths: 32 rows x 4
+# positions over 128 experts top-8
+STEP = MoEConfig(dim=32, n_experts=128, expert_hidden=64, top_k=8)
+EVERY = [("xla", "full"), ("xla", "int8"), ("pallas", "full"), ("pallas", "int8")]
+PADDING_CASES = [
+    # a prefill bucket: two rows of 48 positions, 21 and 9 of them live
+    ("bucket", "xla", "full"), ("bucket", "pallas", "full"),
+    ("bucket", "pallas", "int8"),
+    # the narrow plan of a decode-width step: 22 of 32 rows live (an idle row
+    # has no valid position), at the row-block a bfloat16 step of this width
+    # takes (16) and a float32 one (8), one expert holding more than a block
+    *[(f"block_step_b{block}", impl, stacks) for block in (16, 8)
+      for impl, stacks in EVERY],
+]
+
+
+def _padding_case(shape: str, stacks: str):
+    """(config, params, block, x [B, S, D], live positions a row)."""
+    if shape == "bucket":
+        cfg, block, dims, live = CFG, 16, (2, 48), [21, 9]
+    else:
+        cfg, block, dims = STEP, int(shape.rsplit("b", 1)[1]), (32, 4)
+        live = [4] * 22 + [0] * 10
+    params = init_moe_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(13), (*dims, cfg.dim), jnp.float32)
+    if cfg is STEP:
+        # every token's first choice is expert 5: 88 pairs in one group, more
+        # than five blocks of 16, while its other seven follow the router
+        x = x.at[..., 0].set(3.0)
+        params["router"] = params["router"].at[0, 5].set(10.0)
+    return (cfg, params if stacks == "full" else _quantized(params), block, x,
+            live)
+
+
+@pytest.mark.parametrize("shape,impl,stacks", PADDING_CASES)
+def test_padding_tokens_get_no_row(shape, impl, stacks):
     """The step's validity mask reaches the plan: a padding token's pairs
-    get no row, so the live blocks follow the live pairs and not the
-    bucket; valid tokens equal the scan's; padding tokens read zero; and
-    the same tokens under another padding give the same rows."""
-    params = _params() if stacks == "full" else _quantized(_params())
+    (a bucket's tail, a decode-width step's idle rows) get no row, so the
+    live blocks follow the live pairs and not the bucket; valid tokens equal
+    the scan's; padding tokens read zero; and the same tokens under another
+    padding give the same rows."""
+    cfg, params, block, x, live = _padding_case(shape, stacks)
     tol = dict(rtol=2e-5, atol=2e-6) if stacks == "full" else dict(
         rtol=1e-3, atol=1e-4)
-    block, live = 16, 21
-    x = _x((2, 48), seed=13)
-    valid = jnp.arange(48)[None, :] < jnp.asarray([[live], [9]])
+    B, S = x.shape[:2]
+    valid = jnp.arange(S)[None, :] < jnp.asarray(live)[:, None]
 
-    flat = x.reshape(-1, CFG.dim)
-    ids, gates = top_k_gates(router_probs(params["router"], flat), CFG.top_k)
-    masked = jnp.where(valid.reshape(-1, 1), ids, CFG.n_experts)
-    plan = plan_sorted_blocks(masked, gates, CFG.n_experts, block)
+    flat = x.reshape(-1, cfg.dim)
+    ids, gates = top_k_gates(router_probs(params["router"], flat), cfg.top_k)
+    masked = jnp.where(valid.reshape(-1, 1), ids, cfg.n_experts)
+    plan = plan_sorted_blocks(masked, gates, cfg.n_experts, block)
     rows = np.asarray(plan["row_valid"])
-    assert rows.sum() == (live + 9) * CFG.top_k
+    assert rows.sum() == sum(live) * cfg.top_k
     counts = np.bincount(np.asarray(masked).ravel(),
-                         minlength=CFG.n_experts + 1)[:CFG.n_experts]
+                         minlength=cfg.n_experts + 1)[:cfg.n_experts]
     assert int(plan["live_blocks"][0]) == int(np.ceil(counts / block).sum())
-    full = plan_sorted_blocks(ids, gates, CFG.n_experts, block)
+    full = plan_sorted_blocks(ids, gates, cfg.n_experts, block)
     assert int(plan["live_blocks"][0]) < int(full["live_blocks"][0])
+    if cfg is STEP:
+        assert counts[5] == sum(live) > 5 * block
+        # an idle row's pairs own no block: every live block holds a live row
+        assert rows.reshape(-1, block)[:int(plan["live_blocks"][0])].any(1).all()
     # the inverse: a live pair's row feeds from its token, a dropped pair
     # points past the buffer
     pair_row = np.asarray(plan["pair_row"])
@@ -205,16 +242,17 @@ def test_padding_tokens_get_no_row(impl, stacks):
         assert (token_of[pair_row[t]] == t).all()
 
     kw = dict(impl=impl, block=block, interpret=impl == "pallas")
-    out = moe_ffn_grouped(params, x, CFG, valid=valid, **kw)
-    scan = moe_ffn_dense_mask(params, x, CFG)
+    out = moe_ffn_grouped(params, x, cfg, valid=valid, **kw)
+    scan = moe_ffn_dense_mask(params, x, cfg)
     m = np.asarray(valid)
     np.testing.assert_allclose(np.asarray(out)[m], np.asarray(scan)[m], **tol)
     assert not np.asarray(out)[~m].any()
     # the first row's live tokens alone, padded to another bucket
-    alone = moe_ffn_grouped(params, jnp.pad(x[:1, :live], ((0, 0), (0, 11), (0, 0))),
-                            CFG, valid=jnp.arange(32)[None, :] < live, **kw)
-    np.testing.assert_allclose(np.asarray(alone[0, :live]),
-                               np.asarray(out[0, :live]), rtol=2e-5, atol=2e-6)
+    alone = moe_ffn_grouped(
+        params, jnp.pad(x[:1, :live[0]], ((0, 0), (0, 11), (0, 0))), cfg,
+        valid=jnp.arange(live[0] + 11)[None, :] < live[0], **kw)
+    np.testing.assert_allclose(np.asarray(alone[0, :live[0]]),
+                               np.asarray(out[0, :live[0]]), rtol=2e-5, atol=2e-6)
 
 
 def test_flops_accounting_near_topk_over_e():
@@ -295,9 +333,12 @@ def test_default_takes_the_scan_on_a_model_axis_wider_than_one_device(
 
 
 def test_decode_shapes_fall_back_to_dense():
-    """The gate: grouped pays only when T·k >= E·block — a decode-shaped
-    [B, 1] call must route through the dense scan (block padding would
-    cost MORE than dense there), without changing outputs."""
+    """The rule at decode width (``expert_path``): a Mixtral-shape [B, 1] call
+    (8 experts top-2: T·k alone is the quarter of the scan's E·T rows the
+    rule allows) routes through the dense scan, without changing outputs; a
+    prefill-shape call takes the grouped path at ``moe_block``; and a
+    block-step-shape call over many small experts (128 x top-8, 32 rows x 4
+    positions) takes the kernel at the narrow block its width gives it."""
     from unittest import mock
 
     params = _params()
@@ -314,21 +355,108 @@ def test_decode_shapes_fall_back_to_dense():
 
     from mcp_context_forge_tpu.tpu_local.models.llama import _ffn_block
     layer = dict(params)
-    with mock.patch(
-            "mcp_context_forge_tpu.tpu_local.ops.grouped_moe."
-            "moe_ffn_grouped") as spy:
+    grouped_fn = ("mcp_context_forge_tpu.tpu_local.ops.grouped_moe."
+                  "moe_ffn_grouped")
+    with mock.patch(grouped_fn) as spy:
         out = _ffn_block(layer, _Cfg(), x)
+        # as wide as a Mixtral decode batch gets, and a verify step's width
+        _ffn_block(layer, _Cfg(), _x((32, 1), seed=11))
+        _ffn_block(layer, _Cfg(), _x((12, 4), seed=11))
         spy.assert_not_called()
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(moe_ffn_dense_mask(params, x, CFG)),
         rtol=2e-5, atol=2e-6)
     # a prefill-shaped call with the same config DOES take the grouped path
     big = _x((4, 32), seed=12)  # T=128, k=2 -> 256 >= E*block=128
-    grouped = _ffn_block(layer, _Cfg(), big)
+    with mock.patch(grouped_fn, wraps=moe_ffn_grouped) as spy:
+        grouped = _ffn_block(layer, _Cfg(), big)
+        assert spy.call_args.kwargs["block"] == 16
     np.testing.assert_allclose(
         np.asarray(grouped),
         np.asarray(moe_ffn_dense_mask(params, big, CFG)),
         rtol=2e-5, atol=2e-6)
+
+    # the block step: 1024 pairs + 128 blocks of 8 rows (float32 activations)
+    # against the scan's 16384 rows, far under moe_block's width (4096 pairs)
+    class _Step(_Cfg):
+        dim, n_experts, ffn_hidden, moe_top_k = (
+            STEP.dim, STEP.n_experts, STEP.expert_hidden, STEP.top_k)
+        moe_block = 32
+
+    _, step_params, _, step_x, live = _padding_case("block_step_b8", "full")
+    valid = jnp.arange(4)[None, :] < jnp.asarray(live)[:, None]
+    with mock.patch(grouped_fn, wraps=moe_ffn_grouped) as spy:
+        narrow = _ffn_block(dict(step_params), _Step(), step_x, valid=valid)
+        assert spy.call_args.kwargs["block"] == 8
+    m = np.asarray(valid)
+    np.testing.assert_allclose(
+        np.asarray(narrow)[m],
+        np.asarray(moe_ffn_dense_mask(step_params, step_x, STEP))[m],
+        rtol=2e-5, atol=2e-6)
+    assert not np.asarray(narrow)[~m].any()
+
+
+def _family_config(name: str, **fields):
+    import dataclasses
+
+    from mcp_context_forge_tpu.tpu_local.models.configs import MODEL_CONFIGS
+
+    return dataclasses.replace(MODEL_CONFIGS[name], moe_impl="grouped_pallas",
+                               **fields)
+
+
+ONE_DEVICE = type("Mesh", (), {"shape": {"data": 1, "model": 1}})()
+RULE_CONFIGS = {
+    "mixtral": lambda: _family_config("mixtral-test", n_experts=8, moe_top_k=2,
+                                      moe_block=128),
+    "sdar_block32": lambda: _family_config("sdar-test", n_experts=128,
+                                           moe_top_k=8, moe_block=32),
+    "sdar_block128": lambda: _family_config("sdar-test", n_experts=128,
+                                            moe_top_k=8, moe_block=128),
+}
+# (configuration, tokens of the step, activations) -> (path, row-block): the
+# block matters where the path is grouped, and is what the rule weighed else
+RULE_TABLE = [
+    # 8 x top-2: decode and verify widths scan (T·k alone is E·T / 4),
+    # prefills and history suffixes take the kernel at moe_block, as before
+    ("mixtral", 8, "bfloat16", "scan", 16),
+    ("mixtral", 16, "bfloat16", "scan", 16),
+    ("mixtral", 32, "bfloat16", "scan", 16),
+    ("mixtral", 128, "bfloat16", "scan", 32),
+    ("mixtral", 512, "bfloat16", "grouped", 128),
+    ("mixtral", 2048, "bfloat16", "grouped", 128),
+    # 128 x top-8: a block step (32 rows x 4) takes the kernel at the
+    # sublane tile of its activations; narrower dispatches keep the scan;
+    # every step that cleared T·k >= E·moe_block keeps moe_block
+    ("sdar_block32", 32, "bfloat16", "scan", 16),
+    ("sdar_block32", 64, "bfloat16", "scan", 16),
+    ("sdar_block32", 128, "bfloat16", "grouped", 16),
+    ("sdar_block32", 128, "float32", "grouped", 8),
+    ("sdar_block32", 256, "bfloat16", "grouped", 16),
+    ("sdar_block32", 512, "bfloat16", "grouped", 32),
+    ("sdar_block32", 2048, "bfloat16", "grouped", 32),
+    ("sdar_block128", 128, "bfloat16", "grouped", 16),
+    ("sdar_block128", 512, "bfloat16", "grouped", 32),
+    ("sdar_block128", 1024, "bfloat16", "grouped", 64),
+    ("sdar_block128", 2048, "bfloat16", "grouped", 128),
+]
+
+
+@pytest.mark.parametrize("name,tokens,dtype,path,block", RULE_TABLE)
+def test_expert_rule_table(name, tokens, dtype, path, block):
+    """``expert_path`` / ``expert_block`` over (configuration, step width): a
+    pure function of the step's shape, the same for the trunk and for the
+    family that imports it, and the scan wherever one device does not hold
+    the stacks or no mesh is named."""
+    from mcp_context_forge_tpu.tpu_local.models import llama, sdar
+
+    config = RULE_CONFIGS[name]()
+    assert llama.expert_path(config, ONE_DEVICE, tokens, dtype) == path
+    assert llama.expert_block(config, tokens, dtype) == block
+    assert sdar.expert_path is llama.expert_path
+    two = type("Mesh", (), {"shape": {"data": 1, "model": 2}})()
+    assert llama.expert_path(config, two, tokens, dtype) == "scan"
+    assert llama.expert_path(config, None, tokens, dtype) == "scan"
 
 
 def test_grouped_matches_dense_on_virtual_expert_mesh():

@@ -6,6 +6,7 @@ the fill rule, the engine serving it token for token, and that the mask
 parameter at 1 leaves the other families' programs what they were."""
 
 import asyncio
+import dataclasses
 import os
 import sys
 from functools import partial
@@ -349,6 +350,59 @@ def test_engine_generates_the_references_tokens_and_counts_them():
     ring = engine.recent_steps()
     assert {r["kind"] for r in ring} <= {"prefill", "chunk_prefill", "decode"}
     assert all(r["tokens"] == 0 for r in ring if r["kind"] != "decode")
+
+
+# 32 experts top-2: the narrow side of ``expert_path`` within CI's reach
+WIDE = dataclasses.replace(CFG, name="sdar-test-e32", n_experts=32)
+
+
+@pytest.mark.parametrize("model,batch,bucket,block_path", [
+    ("sdar-test", 4, BUCKET, "scan"), ("sdar-test-e32", 16, 64, "grouped")])
+def test_block_steps_take_the_path_the_rule_gives(model, batch, bucket,
+                                                  block_path, monkeypatch):
+    """A few block dispatches under the family's rule (``expert_path``, a
+    function of the step's shape) against the scan always (``moe_impl=
+    "dense"``): the same tokens at temperature 0, and the counters say which
+    path the block steps took.
+
+    ``sdar-test`` is 8 experts top-2 at ``moe_block`` 8: T·k alone is E·T/4,
+    the most padded rows the rule grants a narrow step, so its block steps
+    (4 rows x 4 positions) SCAN, as a Mixtral decode step does, and only its
+    prefills (32 tokens: 64 pairs = E·moe_block) are grouped. With 32 experts
+    top-2 a block step of 16 rows x 4 positions is narrow (128 pairs under
+    32·8) and its rows 128 + 32·8 = 384 are under 32·64/4 = 512: the row-block
+    plan at the block its width gives (8), idle rows given no row, and with
+    prefills of 64 tokens (grouped by the same rule) no step scans at all."""
+    monkeypatch.setitem(MODEL_CONFIGS, WIDE.name, WIDE)
+    config = MODEL_CONFIGS[model]
+    one = type("Mesh", (), {"shape": {"model": 1}})()
+    assert sdar.expert_path(config, one, batch * BL, jnp.float32) == block_path
+    assert sdar.expert_path(config, one, bucket, jnp.float32) == "grouped"
+    prompts = [[1] + prompt_of(n - 1, n) for n in (22, 29, 9)]
+
+    async def run(**overrides):
+        engine = _engine(model=model, max_batch=batch,
+                         prefill_buckets=(bucket,), **overrides)
+        await engine.start()
+        try:
+            tokens = await asyncio.gather(*[
+                _generate(engine, p, 9) for p in prompts])
+            return tokens, engine.stats
+        finally:
+            await engine.stop()
+
+    tokens, stats = asyncio.run(run())
+    scanned, scan_stats = asyncio.run(run(moe_impl="dense"))
+    assert tokens == scanned and all(len(t) == 9 for t in tokens)
+    assert scan_stats.moe_grouped_steps == 0 < scan_stats.moe_scan_steps
+    assert stats.block_steps >= 3
+    block_passes = stats.denoise_passes + stats.block_steps
+    assert stats.moe_grouped_steps + stats.moe_scan_steps == (
+        block_passes + stats.prefill_batches)
+    if block_path == "grouped":
+        assert stats.moe_scan_steps == 0
+    else:
+        assert stats.moe_scan_steps == block_passes
 
 
 def test_a_stop_token_inside_a_block_ends_the_stream_there():
