@@ -546,13 +546,8 @@ class Settings(BaseModel):
     # step-introspection ring size (per-dispatch summaries served by
     # GET /admin/engine/steps)
     tpu_local_step_log_size: int = 256
-    # --- decode-step attribution & live roofline (docs/observability.md,
-    # "Step attribution, live roofline, and SLOs") ---
-    # every Nth decode dispatch runs serially with a timed
-    # block_until_ready window and splits into host-dispatch/table-sync/
-    # device-compute/read-back/emission phases (step ring + Prometheus +
-    # llm.decode span events); 0 = off, steady-state traffic unperturbed
-    tpu_local_step_sample_every: int = 0
+    # --- live roofline (docs/observability.md, "Step attribution, live
+    # roofline, and SLOs") ---
     # capture XLA cost_analysis() per warmed executable so live step
     # timing feeds mcpforge_llm_mfu / mcpforge_llm_hbm_roofline_frac
     tpu_local_cost_analysis: bool = True

@@ -308,7 +308,7 @@ async function renderEngine(stats){
           <div class="card"><b>${cell((xc.warmup||{}).count)}</b><span>warmup_xla_compiles</span></div>
           <div class="card"><b>${fnum(rf.mfu)}</b><span>live_mfu</span></div>
           <div class="card"><b>${fnum(rf.hbm_roofline_frac)}</b><span>live_hbm_roofline_frac</span></div>
-          <div class="card"><b>${cell((intro.phase_sampling||{}).samples)}</b><span>phase_samples</span></div>
+          <div class="card"><b>${cell(intro.dispatch_stalls)}</b><span>dispatch_stalls</span></div>
         </div>`;
       const cols = ["seq","kind","batch","width","bucket","ctx_pages",
                     "duration_ms","gap_ms","tokens","superstep","frozen",

@@ -874,6 +874,11 @@ document.getElementById("f").onsubmit = async (e) => {
                 "plain_steps": stats.sample_plain_steps,
                 "filtered_steps": stats.sample_filtered_steps,
             },
+            # host-fed dispatches that held the drained device past
+            # timeline.STALL_S (each logged with the part that held it), and
+            # the collector's pauses of the whole process by generation
+            "dispatch_stalls": stats.dispatch_stalls,
+            "gc": engine.timeline.gc_stats(),
         })
 
     @routes.get("/admin/slo")

@@ -10,11 +10,40 @@ from prometheus_client import (
     generate_latest,
     CONTENT_TYPE_LATEST,
 )
+from prometheus_client.core import HistogramMetricFamily
 from prometheus_client.openmetrics import exposition as openmetrics
+from prometheus_client.utils import floatToGoString
 
 from .. import __version__
 from .tenant import TenantClamp
 from .trace_store import ExemplarLedger
+
+
+class _GcPauseCollector:
+    """``mcpforge_gc_pause_seconds{generation}``: the collector's pauses as
+    the step timeline's gc watch counted them (``observability/timeline.py:
+    _GcWatch``; its hook runs on every collection, so the counting is plain
+    additions there and the histogram is put together here, at scrape
+    time). Process-wide, like the collector itself."""
+
+    def __init__(self, watch) -> None:
+        self._watch = watch
+
+    def collect(self):
+        watch = self._watch
+        family = HistogramMetricFamily(
+            "mcpforge_gc_pause_seconds",
+            "Seconds a garbage collection held the process, by generation",
+            labels=["generation"])
+        bounds = [floatToGoString(b) for b in watch.BOUNDS] + ["+Inf"]
+        for generation in range(watch.GENERATIONS):
+            seen, buckets = 0, []
+            for bound, count in zip(bounds, watch.buckets[generation]):
+                seen += count
+                buckets.append((bound, seen))
+            family.add_metric([str(generation)], buckets,
+                              sum_value=watch.total_s[generation])
+        yield family
 
 
 class PrometheusRegistry:
@@ -39,6 +68,7 @@ class PrometheusRegistry:
         # OpenMetrics click-through never dangles
         self.exemplars = exemplars if exemplars is not None \
             else ExemplarLedger()
+        self._gc_collector: _GcPauseCollector | None = None
         self.app_info = Gauge(  # lint: allow[dead-metric] fully populated at registration
             "mcpforge_app_info", "Application info", ["version"], registry=self.registry
         )
@@ -281,15 +311,15 @@ class PrometheusRegistry:
             "trace",
             ["replica"], registry=self.registry,
         )
-        # decode-step phase attribution (opt-in sampling via
-        # tpu_local_step_sample_every): how a sampled step's wall splits
-        # between host dispatch, block-table sync, device compute,
-        # read-back, and emission bookkeeping — the "where do the 87 ms
-        # go" histogram the roofline gap analysis needs
+        # step phase attribution: how the host's side of every HOST-FED
+        # dispatch (the device sits drained while it is built) splits into
+        # the parts its timeline spans name, and how long the host then
+        # waited for the result — read off the spans, no forced sync
         self.llm_step_phase = Histogram(
             "mcpforge_llm_step_phase_seconds",
-            "Sampled decode-step phase durations (host_dispatch, "
-            "table_sync, device_compute, readback, emit)",
+            "Host phases of every host-fed dispatch, off its timeline "
+            "spans (rows, sampling, rng, table_sync, upload, launch, "
+            "readback)",
             ["replica", "phase"], registry=self.registry,
             buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025,
                      0.005, 0.01, 0.025, 0.05, 0.1, 0.25),
@@ -571,6 +601,13 @@ class PrometheusRegistry:
             return self.exemplars.note(metric, value, trace_id, labels)
         except Exception:
             return None  # telemetry must never break an observe site
+
+    def watch_gc(self, watch) -> None:
+        """Expose the gc watch an engine's timeline feeds (once a registry,
+        however many engines share it)."""
+        if self._gc_collector is None:
+            self._gc_collector = _GcPauseCollector(watch)
+            self.registry.register(self._gc_collector)
 
     def render(self, accept: str = "") -> tuple[bytes, str]:
         """Exposition bytes + content type. A scraper that negotiates

@@ -1,7 +1,7 @@
 """Per-request wall-time phase attribution (gateway flight recorder).
 
 The engine got its "where do the milliseconds go" answer in the
-decode-step attribution work (``tpu_local_step_sample_every``); this is
+step timeline (``observability/timeline.py``); this is
 the GATEWAY-side twin. A :class:`PhaseClock` rides each HTTP request in
 a contextvar: the flight-recorder middleware opens it, and every layer
 that owns a distinguishable phase — auth resolution, the plugin hook

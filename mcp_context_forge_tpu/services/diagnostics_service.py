@@ -85,13 +85,12 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
         "pipeline_drains": stats.pipeline_drains,
         "dispatch_gap_ms_total": round(stats.dispatch_gap_ms_total, 3),
         "device_idle_fraction": round(engine.device_idle_fraction(), 4),
-        # decode-step attribution + live roofline + compile tracking
+        # step attribution + live roofline + compile tracking
         # (docs/observability.md "Step attribution, live roofline, and
-        # SLOs"): phase rows ride each sampled step in "steps" below
-        "phase_sampling": {
-            "every": engine.config.step_sample_every,
-            "samples": getattr(stats, "phase_samples", 0),
-        },
+        # SLOs"): a phase row rides every host-fed step in "steps" below;
+        # a stall is a host-fed dispatch that held the drained device past
+        # timeline.STALL_S (each also logged with the part that held it)
+        "dispatch_stalls": getattr(stats, "dispatch_stalls", 0),
         "roofline": (engine.roofline_snapshot()
                      if hasattr(engine, "roofline_snapshot") else None),
         "xla_compiles": (engine.compile_stats()

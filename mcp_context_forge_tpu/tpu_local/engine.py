@@ -41,7 +41,8 @@ import numpy as np
 
 from ..observability.faults import fault_point
 from ..observability.logging import trace_extra
-from ..observability.timeline import StepCounts, StepTimeline
+from ..observability.timeline import (STALL_S, StepCounts, StepTimeline,
+                                      gc_watch)
 from .compile_events import (CompileTracker, install_listener,
                              restore_thread, track_thread)
 from .kv import PageAllocator
@@ -204,13 +205,6 @@ class EngineConfig:
     # step-introspection ring: per-dispatch summaries (kind, batch shape,
     # duration, tokens) kept for the diagnostics endpoint / admin UI
     step_log_size: int = 256
-    # decode-step phase attribution: every Nth decode dispatch runs
-    # serially with a timed block_until_ready window so its wall splits
-    # into host-dispatch / table-sync / device-compute / read-back /
-    # emission phases (step ring + mcpforge_llm_step_phase_seconds +
-    # llm.decode span events). 0 disables — the default, so steady-state
-    # traffic is unperturbed and token streams stay byte-identical.
-    step_sample_every: int = 0
     # capture XLA cost_analysis() (FLOPs, bytes accessed) per compiled
     # executable at warmup into the engine's CostRegistry — what feeds
     # the live mcpforge_llm_mfu / mcpforge_llm_hbm_roofline_frac gauges.
@@ -291,8 +285,6 @@ class EngineConfig:
             auto_restart=getattr(settings, "tpu_local_auto_restart", False),
             auto_restart_max=getattr(settings, "tpu_local_auto_restart_max", 3),
             step_log_size=getattr(settings, "tpu_local_step_log_size", 256),
-            step_sample_every=getattr(
-                settings, "tpu_local_step_sample_every", 0),
             cost_analysis=getattr(settings, "tpu_local_cost_analysis", True),
             peak_tflops_per_chip=getattr(
                 settings, "tpu_local_peak_tflops_per_chip",
@@ -329,11 +321,13 @@ class GenRequest:
     # the same instant on the step timeline's clock (perf_counter): the
     # twin of ``created``, so pool shadows inherit both and a failover
     # continuation's queue wait and TTFT still span the failed attempt.
-    # The engine stamps the other three: slot won, first token, retired.
+    # The engine stamps the others: slot won, first token, that token
+    # handed to ``stream`` on the loop's thread, retired.
     # queue_ms / prefill_ms and the llm.* span durations derive from these
     t_submit: float = field(default_factory=time.perf_counter)
     t_admit: float = 0.0
     t_first: float = 0.0
+    t_deliver: float = 0.0
     t_done: float = 0.0
     # filled by the engine
     slot: int = -1
@@ -395,7 +389,9 @@ class EngineStats:
         self.overlap_steps = 0        # decode dispatches fed from device tokens
         self.pipeline_drains = 0      # overlap barriers that forced a drain
         self.dispatch_gap_ms_total = 0.0  # host-side stall between dispatches
-        self.phase_samples = 0        # decode steps with phase attribution
+        # host-fed dispatches whose build.t0 -> dispatch.t1 passed
+        # timeline.STALL_S (each also logged, with the part that held it)
+        self.dispatch_stalls = 0
         # counted on the device by families whose step programs do
         # (models/deepseek.py), read back with each step's tokens
         self.moe_tokens = 0           # tokens through expert layers
@@ -548,6 +544,8 @@ class TPUEngine:
         # one record per device dispatch, request stamps — all on the
         # profiler's clock (observability/timeline.py)
         self.timeline = StepTimeline(config.replica_id)
+        if metrics is not None:
+            metrics.watch_gc(gc_watch)      # mcpforge_gc_pause_seconds
         if config.decode_block < 1:
             raise ValueError(
                 f"decode_block must be >= 1, got {config.decode_block}")
@@ -731,13 +729,9 @@ class TPUEngine:
         self._tpd_ewma: float | None = None  # lint: thread[dispatch]
         # last publish of O(window) signals (idle fraction): bounded tick
         self._signals_slow_ts = 0.0  # lint: thread[dispatch]
-        # decode-step attribution + live roofline state: the dispatch
-        # counter drives the sampling cadence, phase events feed llm.decode
-        # span events, the roofline window backs roofline_snapshot(), and
+        self._phase_observers: dict[str, Any] = {}  # lint: thread[dispatch]
+        # live roofline state: the window backs roofline_snapshot(), and
         # the cost registry holds warmup-captured XLA cost_analysis()
-        self._dispatch_count = 0  # lint: thread[dispatch]
-        self._phase_events: deque[tuple[float, dict[str, float]]] = \
-            deque(maxlen=64)  # lint: thread[dispatch]
         self._roofline_window: deque[tuple[float, float, float]] = \
             deque(maxlen=256)  # lint: thread[dispatch]
         self.cost_registry = CostRegistry()
@@ -2511,10 +2505,14 @@ class TPUEngine:
         any_hist = any(r.hist > 0 for r in admitted)
         kind = "prefill_hist" if any_hist else "prefill"
         seq = tl.next_seq()
+        parts: dict[str, Any] = {}
         with tl.span("prefill.build", seq, kind) as build:
-            tokens, positions, last_idx, slot_ids, sampling = self._pack_rows(
-                [(r, r.hist, self._prefill_end(r)) for r in admitted], bucket)
-            self._rng, key = jax.random.split(self._rng)
+            with tl.span("prefill.build.rows", seq, kind) as parts["rows"]:
+                arrays, per_row = self._pack_rows(
+                    [(r, r.hist, self._prefill_end(r)) for r in admitted],
+                    bucket)
+            sampling, key = self._sample_and_split("prefill", seq, kind,
+                                                   per_row, parts)
             # long buckets route through the sequence-parallel attention
             # path (shape-deterministic: SP-ness is a property of the
             # bucket; SP groups never carry history — _assign_bucket
@@ -2529,10 +2527,8 @@ class TPUEngine:
                     max(len(r.prompt_ids) for r in admitted)))
             else:
                 prefill_fn = self._prefill_sample
-        with tl.span("prefill.dispatch", seq, kind) as dispatch:
-            first, self.kv = prefill_fn(
-                self.params, self.kv, tokens, positions,
-                slot_ids, last_idx, sampling, key)
+        first, dispatch = self._launch_prefill(prefill_fn, seq, kind, build,
+                                               arrays, sampling, key, parts)
         with tl.span("prefill.sync", seq, kind) as sync:
             first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] first-token fetch: prefill result feeds host-side admission
         counts = self._step_counts(aux_host)
@@ -2540,14 +2536,15 @@ class TPUEngine:
         self.stats.prefill_ms_total += elapsed_ms
         self.stats.prefill_batches += 1
         self.stats.prefill_requests += len(admitted)
-        width = int(tokens.shape[0])  # the dispatched pad
+        width = int(arrays[0].shape[0])  # the dispatched pad
         self._count_expert_path(width * bucket)
         tl.step(seq, kind, width, len(admitted), bucket, dispatch.t0, sync.t1,
                 counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
                           width=width, dur_ms=elapsed_ms,
                           tokens=0 if self._block else len(admitted),
-                          bucket=bucket)
+                          bucket=bucket,
+                          phases=self._phase_row(parts, build, sync))
         with tl.span("prefill.emit", seq, kind):
             for i, request in enumerate(admitted):
                 request.prefill_ms = elapsed_ms
@@ -2563,10 +2560,11 @@ class TPUEngine:
 
     def _pack_rows(self, rows: list[tuple[GenRequest, int, int]], S: int):
         """Pack [(request, start, end)] prompt spans into padded [B, S]
-        device arrays + per-row sampling params. B pads to the next power
-        of two so XLA compiles at most log2(prefill_max_batch)+1 shapes
-        per width; padding rows have positions -1 (no KV write — the same
-        masking decode uses for inactive slots) and their samples are
+        host arrays ``(tokens, positions, last_idx, slot_ids)`` + the rows'
+        sampling parameters ``(temperature, top_k, top_p)``. B pads to the
+        next power of two so XLA compiles at most log2(prefill_max_batch)+1
+        shapes per width; padding rows have positions -1 (no KV write — the
+        same masking decode uses for inactive slots) and their samples are
         discarded. Shared by dense/suffix prefill and chunk rounds."""
         B = 1
         while B < len(rows):
@@ -2587,9 +2585,27 @@ class TPUEngine:
             temperature[i] = request.temperature
             top_k[i] = request.top_k
             top_p[i] = request.top_p
-        sampling = self._sampling_params(temperature, top_k, top_p)
-        return (jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(last_idx), jnp.asarray(slot_ids), sampling)
+        return ((tokens, positions, last_idx, slot_ids),
+                (temperature, top_k, top_p))
+
+    def _launch_prefill(self, prefill_fn, seq: int, kind: str, build, arrays,
+                        sampling: SamplingParams, key, parts: dict[str, Any]):
+        """The ``prefill.dispatch`` span of a prefill or a chunk round: the
+        packed rows onto the device, then the jitted call alone. Returns the
+        program's result (still on the device) and the span."""
+        tl = self.timeline
+        with tl.span("prefill.dispatch", seq, kind) as dispatch:
+            with tl.span("prefill.dispatch.upload", seq, kind) \
+                    as parts["upload"]:
+                tokens, positions, last_idx, slot_ids = map(jnp.asarray,
+                                                            arrays)
+            with tl.span("prefill.dispatch.launch", seq, kind) \
+                    as parts["launch"]:
+                first, self.kv = prefill_fn(
+                    self.params, self.kv, tokens, positions,
+                    slot_ids, last_idx, sampling, key)
+        self._host_fed(seq, kind, build, dispatch, parts)
+        return first, dispatch
 
     def _chunk_round(self) -> None:
         """Advance every mid-prefill long prompt by ONE chunk, batched.
@@ -2613,30 +2629,30 @@ class TPUEngine:
                   if max_remaining <= b), max(config.prefill_buckets))
         tl = self.timeline
         seq = tl.next_seq()
+        parts: dict[str, Any] = {}
         with tl.span("prefill.build", seq, "chunk") as build:
-            rows: list[tuple[GenRequest, int, int]] = []
-            max_end = 1
-            for request in batch:
-                start = request.chunk_pos
-                end = min(start + S, self._prefill_end(request))
-                rows.append((request, start, end))
-                request.chunk_pos = end
-                max_end = max(max_end, end)
-            tokens, positions, last_idx, slot_ids, sampling = \
-                self._pack_rows(rows, S)
-            self._rng, key = jax.random.split(self._rng)
+            with tl.span("prefill.build.rows", seq, "chunk") as parts["rows"]:
+                rows: list[tuple[GenRequest, int, int]] = []
+                max_end = 1
+                for request in batch:
+                    start = request.chunk_pos
+                    end = min(start + S, self._prefill_end(request))
+                    rows.append((request, start, end))
+                    request.chunk_pos = end
+                    max_end = max(max_end, end)
+                arrays, per_row = self._pack_rows(rows, S)
+            sampling, key = self._sample_and_split("prefill", seq, "chunk",
+                                                   per_row, parts)
             hist_fn = self._hist_fn(self._hist_ctx_for(max_end))
-        with tl.span("prefill.dispatch", seq, "chunk") as dispatch:
-            first, self.kv = hist_fn(
-                self.params, self.kv, tokens, positions,
-                slot_ids, last_idx, sampling, key)
+        first, dispatch = self._launch_prefill(hist_fn, seq, "chunk", build,
+                                               arrays, sampling, key, parts)
         with tl.span("prefill.sync", seq, "chunk") as sync:
             first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] chunk-round boundary: host decides next chunk from these tokens
         counts = self._step_counts(aux_host)
         elapsed_ms = (sync.t1 - build.t0) * 1000
         self.stats.prefill_batches += 1
         self.stats.prefill_ms_total += elapsed_ms
-        width = int(tokens.shape[0])
+        width = int(arrays[0].shape[0])
         self._count_expert_path(width * S)
         tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1,
                 counts)
@@ -2645,7 +2661,7 @@ class TPUEngine:
             dur_ms=elapsed_ms,
             tokens=0 if self._block else sum(
                 1 for r in batch if r.chunk_pos >= len(r.prompt_ids)),
-            bucket=S)
+            bucket=S, phases=self._phase_row(parts, build, sync))
         with tl.span("prefill.emit", seq, "chunk"):
             for i, request in enumerate(batch):
                 request.prefill_ms += elapsed_ms
@@ -2707,19 +2723,28 @@ class TPUEngine:
         tl = self.timeline
         seq = tl.next_seq()
         active = list(self._running.items())
-        with tl.span("decode.build", seq, "spec"):
-            tokens, positions, sampling, widths, chunks = \
-                self._spec_rows(active, B, K)
-            self._rng, key = jax.random.split(self._rng)
+        parts: dict[str, Any] = {}
+        with tl.span("decode.build", seq, "spec") as build:
+            with tl.span("decode.build.rows", seq, "spec") as parts["rows"]:
+                tokens, positions, per_row, widths, chunks = \
+                    self._spec_rows(active, B, K)
+            sampling, key = self._sample_and_split("decode", seq, "spec",
+                                                   per_row, parts)
             max_pos = int(positions.max()) + 1 if active else K
             spec_ctx_pages = self._ctx_bucket_for(max_pos)
-        with tl.span("decode.table_sync", seq, "spec"):
+        with tl.span("decode.table_sync", seq, "spec") as parts["table_sync"]:
             self._sync_tables()
         with tl.span("decode.dispatch", seq, "spec") as dispatch:
-            block, self.kv = self._verify_fn(spec_ctx_pages)(
-                self.params, self.kv, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.arange(B, dtype=jnp.int32),
-                sampling, key)
+            verify_fn = self._verify_fn(spec_ctx_pages)
+            with tl.span("decode.dispatch.upload", seq, "spec") \
+                    as parts["upload"]:
+                args = (jnp.asarray(tokens), jnp.asarray(positions),
+                        jnp.arange(B, dtype=jnp.int32))
+            with tl.span("decode.dispatch.launch", seq, "spec") \
+                    as parts["launch"]:
+                block, self.kv = verify_fn(self.params, self.kv, *args,
+                                           sampling, key)
+        self._host_fed(seq, "spec", build, dispatch, parts)
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 1
         self.stats.spec_steps += 1
@@ -2762,13 +2787,15 @@ class TPUEngine:
         self._record_step("spec_decode", seq=seq, batch=len(active), width=B,
                           dur_ms=spec_elapsed_ms, tokens=spec_emitted,
                           ctx_pages=spec_ctx_pages, mfu=mfu,
-                          hbm_frac=hbm_frac)
+                          hbm_frac=hbm_frac,
+                          phases=self._phase_row(parts, build, readback))
 
     def _spec_rows(self, active: list[tuple[int, GenRequest]], B: int,
                    K: int):
         """Pack the verify step's [B, K] rows: each active slot's last
         token plus its drafts, cut to the pages the pool grants. Returns
-        (tokens, positions, sampling, usable width and chunk by slot)."""
+        (tokens, positions, the rows' sampling parameters, usable width and
+        chunk by slot)."""
         tokens = np.zeros((B, K), dtype=np.int32)
         positions = np.full((B, K), -1, dtype=np.int32)
         temperature = np.zeros((B,), dtype=np.float32)
@@ -2802,8 +2829,7 @@ class TPUEngine:
             temperature[slot] = request.temperature
             top_k[slot] = request.top_k
             top_p[slot] = request.top_p
-        sampling = self._sampling_params(temperature, top_k, top_p)
-        return tokens, positions, sampling, widths, chunks
+        return tokens, positions, (temperature, top_k, top_p), widths, chunks
 
     # ------------------------------------------------------------ decode step
 
@@ -2826,16 +2852,6 @@ class TPUEngine:
         inside a decode_block."""
         config = self.config
         k = self._k
-        if self._phase_sample_due():
-            # sampled steps run SERIALLY so the timed block_until_ready
-            # window attributes this one step alone (a device-fed step's
-            # wall overlaps its neighbor and cannot be split into
-            # phases). Drains are the same barrier admission uses, so
-            # token streams stay byte-identical to the unsampled run.
-            self._drain_pipeline()
-            if self._running:
-                self._decode_step_all()
-            return
         feed = self._inflight
         self._inflight = None
         if feed is not None:
@@ -3035,50 +3051,52 @@ class TPUEngine:
         tl = self.timeline
         seq = tl.next_seq()
         kind = "decode_fb" if feed is not None else "decode"
-        # phase attribution (opt-in sampling): this dispatch runs serial
-        # (the overlapped caller drained first) and its phase row is read
-        # off the same spans every dispatch leaves
-        sampled = self._phase_sample_due()
-        self._dispatch_count += 1
         first: dict[int, int] = {}
+        parts: dict[str, Any] = {}
         with tl.span("decode.build", seq, kind) as build:
-            if self._block:
-                (tokens, positions, masked, sampling, budgets, first,
-                 truncated, reqs) = self._block_rows(B)
-                reach = int(positions.max()) + 1
-            else:
-                (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
-                 budgets, truncated, reqs) = self._decode_rows(B, feed, k)
-                # the longest row this block can reach (seq_lens counts the
-                # incoming token; k-1 more may be written)
-                reach = int(seq_lens.max()) + k
-            self._rng, key = jax.random.split(self._rng)
+            with tl.span("decode.build.rows", seq, kind) as parts["rows"]:
+                if self._block:
+                    (tokens, positions, masked, per_row, budgets, first,
+                     truncated, reqs) = self._block_rows(B)
+                    reach = int(positions.max()) + 1
+                else:
+                    (tokens, positions, seq_lens, budget_arr, stop_tbl,
+                     per_row, budgets, truncated, reqs) = \
+                        self._decode_rows(B, feed, k)
+                    # the longest row this block can reach (seq_lens counts
+                    # the incoming token; k-1 more may be written)
+                    reach = int(seq_lens.max()) + k
+            sampling, key = self._sample_and_split(
+                "decode", seq, kind, per_row, parts,
+                steps=1 if self._block else k)
             ctx_pages = self._ctx_bucket_for(reach)   # context-width bucket
-        with tl.span("decode.table_sync", seq, kind) as table_sync:
+        with tl.span("decode.table_sync", seq, kind) as parts["table_sync"]:
             self._sync_tables()
         with tl.span("decode.dispatch", seq, kind) as dispatch:
             if self._block:
-                (block_tokens, block_valid, block_done, *block_aux), self.kv = \
-                    self._block_fn(ctx_pages, B)(
-                        self.params, self.kv, jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(masked),
-                        jnp.arange(B, dtype=jnp.int32), sampling, key)
+                step_fn = self._block_fn(ctx_pages, B)
             elif feed is None:
-                (block_tokens, block_valid, block_done, *block_aux), self.kv = \
-                    self._decode_fn(ctx_pages, B)(
-                        self.params, self.kv, jnp.asarray(tokens),
-                        jnp.asarray(positions),
-                        jnp.arange(B, dtype=jnp.int32),
-                        jnp.asarray(seq_lens), jnp.asarray(budget_arr),
-                        jnp.asarray(stop_tbl), sampling, key)
+                step_fn = self._decode_fn(ctx_pages, B)
             else:
+                step_fn = self._decode_fb_fn(ctx_pages, B)
+            with tl.span("decode.dispatch.upload", seq, kind) \
+                    as parts["upload"]:
+                slot_ids = jnp.arange(B, dtype=jnp.int32)
+                if self._block:
+                    args = (jnp.asarray(tokens), jnp.asarray(positions),
+                            jnp.asarray(masked), slot_ids)
+                else:
+                    args = (feed["block"] if feed is not None
+                            else jnp.asarray(tokens),
+                            jnp.asarray(positions), slot_ids,
+                            jnp.asarray(seq_lens), jnp.asarray(budget_arr),
+                            jnp.asarray(stop_tbl))
+            with tl.span("decode.dispatch.launch", seq, kind) \
+                    as parts["launch"]:
                 (block_tokens, block_valid, block_done, *block_aux), self.kv = \
-                    self._decode_fb_fn(ctx_pages, B)(
-                        self.params, self.kv, feed["block"],
-                        jnp.asarray(positions),
-                        jnp.arange(B, dtype=jnp.int32),
-                        jnp.asarray(seq_lens), jnp.asarray(budget_arr),
-                        jnp.asarray(stop_tbl), sampling, key)
+                    step_fn(self.params, self.kv, *args, sampling, key)
+        if feed is None:
+            self._host_fed(seq, kind, build, dispatch, parts)
         # dispatch-gap telemetry: host time since the last step retired,
         # with nothing in flight. A device-fed dispatch by construction
         # overlaps the still-running previous step, so its gap is zero.
@@ -3091,18 +3109,6 @@ class TPUEngine:
         if self.metrics is not None:
             self.metrics.llm_dispatch_gap.labels(
                 replica=self.config.replica_id).observe(gap_s)
-        phases: dict[str, float] | None = None
-        if sampled:
-            # the one intentional sync sampling buys: bounds this step's
-            # device-compute phase exactly, every Nth step only
-            with tl.span("decode.device_wait", seq, kind) as device_wait:
-                block_tokens.block_until_ready()  # lint: allow[host-sync-in-hot-path] opt-in phase-attribution window (config.step_sample_every): every Nth step pays one timed sync; steady-state steps stay overlapped
-            phases = {
-                "host_dispatch_ms": max(
-                    0.0, (dispatch.t1 - build.t0) * 1000 - table_sync.ms),
-                "table_sync_ms": table_sync.ms,
-                "device_compute_ms": device_wait.ms,
-            }
         try:
             # D2H overlaps device compute (tokens + the super-step's
             # valid/done masks all retire in one readback)
@@ -3121,12 +3127,15 @@ class TPUEngine:
                 "truncated": truncated, "B": B, "k": k,
                 "ctx_pages": ctx_pages, "batch": len(reqs), "seq": seq,
                 "kind": kind, "t_dispatched": dispatch.t0, "gap_s": gap_s,
-                "t_build": build.t0, "phases": phases}
+                # the named host parts of a HOST-FED dispatch: its phase row
+                # at retire (a device-fed step's host work overlaps its
+                # predecessor on the device and holds nothing up)
+                "build": build, "parts": parts if feed is None else None}
 
     def _decode_rows(self, B: int, feed: dict[str, Any] | None, k: int):
         """Pack one decode dispatch's [B] rows from the running set and
-        pre-grant its pages. Returns the host arrays, the sampling params
-        and the per-slot bookkeeping the retire needs."""
+        pre-grant its pages. Returns the host arrays, the rows' sampling
+        parameters and the per-slot bookkeeping the retire needs."""
         tokens = np.zeros((B,), dtype=np.int32)
         positions = np.zeros((B,), dtype=np.int32)
         seq_lens = np.zeros((B,), dtype=np.int32)
@@ -3179,9 +3188,8 @@ class TPUEngine:
             stops = (self.tokenizer.eos_id,) + tuple(
                 request.stop_ids)[:self._STOP_TBL_WIDTH - 1]
             stop_tbl[slot, :len(stops)] = stops
-        sampling = self._sampling_params(temperature, top_k, top_p, steps=k)
-        return (tokens, positions, seq_lens, budget_arr, stop_tbl, sampling,
-                budgets, truncated, reqs)
+        return (tokens, positions, seq_lens, budget_arr, stop_tbl,
+                (temperature, top_k, top_p), budgets, truncated, reqs)
 
     def _block_rows(self, B: int):
         """Pack one block step's [B, Bl] rows from the running set and grant
@@ -3191,9 +3199,9 @@ class TPUEngine:
         hold the mask token and are flagged masked. All ``Bl`` positions are
         computed and written whatever ``max_tokens`` leaves to emit, so the
         block's whole page must be granted or the request truncates. Returns
-        the host arrays, the sampling params, and by slot the tokens to emit
-        (``budgets``), where they start in the block (``first``), the rows
-        the pool refused (``truncated``) and the requests."""
+        the host arrays, the rows' sampling parameters, and by slot the tokens
+        to emit (``budgets``), where they start in the block (``first``), the
+        rows the pool refused (``truncated``) and the requests."""
         Bl, cfg = self._block, self.model_config
         tokens = np.full((B, Bl), cfg.mask_token_id, dtype=np.int32)
         positions = np.full((B, Bl), -1, dtype=np.int32)
@@ -3226,9 +3234,8 @@ class TPUEngine:
             top_k[slot] = request.top_k
             top_p[slot] = request.top_p
             budgets[slot], first[slot] = max(0, want), known
-        sampling = self._sampling_params(temperature, top_k, top_p)
-        return (tokens, positions, masked, sampling, budgets, first,
-                truncated, reqs)
+        return (tokens, positions, masked, (temperature, top_k, top_p),
+                budgets, first, truncated, reqs)
 
     def _decode_retire(self, inflight: dict[str, Any]) -> None:  # lint: hot-path
         """Fetch and emit one dispatched decode SUPER-STEP: the [k, B]
@@ -3258,7 +3265,7 @@ class TPUEngine:
         self.stats.decode_ms_total += step_wall_ms
         first = inflight["first"]       # by slot; empty for token steps
         decode_emitted = 0
-        with tl.span("decode.emit", seq, kind) as emit:
+        with tl.span("decode.emit", seq, kind):
             for slot, request in inflight["reqs"].items():
                 if self._running.get(slot) is not request:
                     continue  # finished at an earlier retire: lookahead discards
@@ -3300,15 +3307,11 @@ class TPUEngine:
                     StepCounts(0.0, 0.0, 0.0, denoise_passes=float(passes),
                                block_tokens=float(decode_emitted),
                                filled_by_threshold=float(by_threshold)))
-        phases = inflight.get("phases")
-        if phases is not None:
-            # a phase row exists only when the SAMPLED dispatch reached
-            # retire intact (crash/drop paths discard the inflight record,
-            # so partial rows never surface)
-            phases["readback_ms"] = readback.ms
-            phases["emit_ms"] = emit.ms
-            phases["total_ms"] = (emit.t1 - inflight["t_build"]) * 1000
-            self._observe_phases(phases)
+        # a phase row exists only when the host-fed dispatch reached retire
+        # intact (crash/drop paths discard the inflight record, so partial
+        # rows never surface)
+        phases = self._phase_row(inflight["parts"], inflight["build"],
+                                 readback)
         mfu, hbm_frac = self._observe_roofline(
             kind, inflight["B"], inflight["ctx_pages"], step_wall_ms,
             k=inflight["k"])
@@ -3345,28 +3348,61 @@ class TPUEngine:
 
     # --------------------------------------------------------------- telemetry
 
-    def _phase_sample_due(self) -> bool:
-        """True when the NEXT decode dispatch should take the timed
-        phase-attribution window (every Nth; 0 disables). Pure predicate
-        on the dispatch counter so the overlapped wrapper and the
-        dispatch itself agree within one step."""
-        n = self.config.step_sample_every
-        return n > 0 and self._dispatch_count % n == 0
+    def _sample_and_split(self, family: str, seq: int, kind: str, per_row,
+                          parts: dict[str, Any], steps: int = 1):
+        """The two named tails of every build: the rows' sampling parameters
+        onto the device (three uploads; the tier counted), then the key
+        split (two tiny device programs). ``family`` is ``prefill`` or
+        ``decode``; returns ``(sampling, key)``."""
+        tl = self.timeline
+        with tl.span(family + ".build.sampling", seq, kind) \
+                as parts["sampling"]:
+            sampling = self._sampling_params(*per_row, steps=steps)
+        with tl.span(family + ".build.rng", seq, kind) as parts["rng"]:
+            self._rng, key = jax.random.split(self._rng)
+        return sampling, key
 
-    def _observe_phases(self, phases: dict[str, float]) -> None:
-        """Publish one completed sampled-step phase row: stats counter,
-        the per-phase histograms, and the event buffer llm.decode spans
-        attach from. Runs at retire on the dispatch thread."""
-        self.stats.phase_samples += 1
-        self._phase_events.append((time.time(), dict(phases)))
+    def _host_fed(self, seq: int, kind: str, build, dispatch,
+                  parts: dict[str, Any]) -> None:
+        """After a HOST-FED dispatch is launched: the device sat drained
+        from ``build.t0`` to ``dispatch.t1``, usually ~2 ms. Past
+        ``STALL_S`` that is a stall: counted, and logged once with the part
+        that held most of it and what paused the process meanwhile."""
+        held_s = dispatch.t1 - build.t0
+        if held_s <= STALL_S:
+            return
+        self.stats.dispatch_stalls += 1
+        name, span = max(parts.items(), key=lambda kv: kv[1].t1 - kv[1].t0)
+        pauses = [
+            f"{p.cause}{p.detail} {(p.t1 - p.t0) * 1e3:.1f} ms on {p.thread}"
+            for p in self.timeline.pauses_between(build.t0, dispatch.t1)]
+        logger.warning(
+            "tpu_local dispatch stall: step %d (%s) held the drained device "
+            "%.1f ms from build to launch; %s took %.1f ms wall, %.1f ms on "
+            "the CPU; pauses in it: %s", seq, kind, held_s * 1e3, name,
+            span.ms, span.cpu * 1e3, ", ".join(pauses) or "none")
+
+    def _phase_row(self, parts: dict[str, Any] | None, build,
+                   readback) -> dict[str, float] | None:
+        """The phase row of one host-fed dispatch, read off the spans it
+        left (``rows``, ``sampling``, ``rng``, ``table_sync`` where the
+        dispatch syncs one, ``upload``, ``launch``, and ``readback``: the
+        wait for its result), ms, with ``total_ms`` = ``build.t0 ->
+        readback.t1``; also observed into the per-phase histograms. None for
+        a device-fed dispatch. Runs at retire on the dispatch thread."""
+        if parts is None:
+            return None
+        phases = {name + "_ms": span.ms for name, span in parts.items()}
+        phases["readback_ms"] = readback.ms
         if self.metrics is not None:
-            rid = self.config.replica_id
+            observers = self._phase_observers   # a histogram child a phase
             for key, dur_ms in phases.items():
-                if key == "total_ms":
-                    continue
-                self.metrics.llm_step_phase.labels(
-                    replica=rid, phase=key[:-3]).observe(
-                    max(0.0, dur_ms / 1e3))
+                if key not in observers:
+                    observers[key] = self.metrics.llm_step_phase.labels(
+                        replica=self.config.replica_id, phase=key[:-3])
+                observers[key].observe(dur_ms / 1e3)
+        phases["total_ms"] = (readback.t1 - build.t0) * 1e3
+        return phases
 
     def _observe_roofline(self, kind: str, width: int, ctx_pages: int,
                           dur_ms: float, k: int | None = None
@@ -3538,8 +3574,8 @@ class TPUEngine:
             # host-side stall before this dispatch (decode only; 0 when the
             # overlapped pipeline kept the device fed)
             "gap_ms": round(gap_ms, 3) if gap_ms is not None else None,
-            # sampled phase attribution (None unless this step took the
-            # step_sample_every window) and live cost-model roofline
+            # the host phases of a host-fed dispatch, off its timeline
+            # spans (None for a device-fed one), and live cost-model roofline
             "phases": ({k: round(v, 3) for k, v in phases.items()}
                        if phases is not None else None),
             "mfu": round(mfu, 12) if mfu is not None else None,
@@ -3760,13 +3796,7 @@ class TPUEngine:
                 self.allocator.slot_pages(request.slot)
                 * max(0.0, request.t_done - request.t_admit)))
         reason = request.finish_reason or "stop"
-        # sampled phase rows that landed during this request's decode
-        # phase ride along as span events — the trace-side view of the
-        # step-attribution ring (batch-wide, so shared across the
-        # requests decoding concurrently)
-        phase_events = [(ts, "decode.step.phases", attrs)
-                        for ts, attrs in list(self._phase_events)
-                        if ts >= decode_start][-8:]
+        phase_events = self._decode_phase_events(request, decode_start)
         self._span("llm.decode", request, decode_start,
                    decode_start + decode_s,
                    status="OK" if reason in ("stop", "length") else "ERROR",
@@ -3774,6 +3804,23 @@ class TPUEngine:
                    **{"gen_ai.usage.completion_tokens": n,
                       "llm.finish_reason": reason,
                       "llm.kv_pages": self.allocator.slot_pages(request.slot)})
+
+    def _decode_phase_events(self, request: GenRequest, since_ts: float
+                             ) -> list[tuple[float, str, dict[str, Any]]]:
+        """The phase rows of the last host-fed decode dispatches since
+        ``since_ts`` (at most eight), as events for the request's
+        ``llm.decode`` span: the trace-side view of the step ring
+        (batch-wide, so shared by the requests decoding together)."""
+        events: list[tuple[float, str, dict[str, Any]]] = []
+        if self.tracer is None or request.trace_ctx is None:
+            return events
+        for row in reversed(self.step_log):
+            if row["ts"] < since_ts or len(events) == 8:
+                break
+            if row["phases"] and row["kind"] in ("decode", "spec_decode"):
+                events.append((row["ts"], "decode.step.phases", row["phases"]))
+        events.reverse()
+        return events
 
     # ---------------------------------------------------------------- plumbing
 
@@ -3877,9 +3924,15 @@ class TPUEngine:
             return
         batch, self._emit_buf = self._emit_buf, []
         loop = self._loop
+        stamp = self.timeline.stamp
 
         def _put() -> None:
             for request, tokens, done in batch:
+                if tokens and request.t_deliver == 0.0:
+                    # the first token's way out: t_first -> here is the
+                    # wait in the buffer and the hop to the loop
+                    request.t_deliver = stamp("deliver", request.request_id,
+                                              request.slot)
                 for token in tokens:
                     request.stream.put_nowait(token)
                 if done:

@@ -4,12 +4,14 @@ roofline, and SLOs").
 
 The subsystem's contract, in falsifiable form:
 
-- with ``step_sample_every=N`` every Nth decode step carries a COMPLETE
-  phase row (host_dispatch/table_sync/device_compute/readback/emit) whose
-  components sum to ~ the step's wall, under the overlap pipeline;
-- sampling preserves exact greedy token parity (the sampled step rides
-  the same drain barrier admission uses), and the default (0) emits no
-  rows and takes no timed syncs;
+- every HOST-FED dispatch (a prefill, a chunk round, a decode step that
+  found the device drained) carries a COMPLETE phase row read off its
+  timeline spans (rows/sampling/rng/table_sync/upload/launch/readback)
+  whose components sum to ~ ``build.t0 -> readback.t1``; a device-fed
+  decode step carries none;
+- the rows cost no sync and no drain: the overlapped and the serial engine
+  emit the same greedy tokens, and no dispatch waits for the device for
+  attribution's sake;
 - crash- and EOS-mid-pipeline paths never surface partial/garbage rows;
 - warmup populates the XLA cost registry and decode retires feed the
   live mcpforge_llm_mfu / mcpforge_llm_hbm_roofline_frac gauges;
@@ -27,8 +29,10 @@ from mcp_context_forge_tpu.observability.metrics import PrometheusRegistry
 from mcp_context_forge_tpu.tpu_local.engine import (EngineConfig, GenRequest,
                                                     TPUEngine)
 
-PHASE_KEYS = {"host_dispatch_ms", "table_sync_ms", "device_compute_ms",
-              "readback_ms", "emit_ms", "total_ms"}
+PHASE_KEYS = {"rows_ms", "sampling_ms", "rng_ms", "table_sync_ms",
+              "upload_ms", "launch_ms", "readback_ms", "total_ms"}
+# a prefill's tables were synced by its admission: no such phase
+PREFILL_PHASE_KEYS = PHASE_KEYS - {"table_sync_ms"}
 
 
 def _config(**overrides):
@@ -94,82 +98,119 @@ def _phase_rows(engine):
 
 def _assert_row_complete(row):
     phases = row["phases"]
-    assert set(phases) == PHASE_KEYS, phases
+    wanted = PHASE_KEYS if "decode" in row["kind"] else PREFILL_PHASE_KEYS
+    assert set(phases) == wanted, (row["kind"], phases)
     for key, value in phases.items():
         assert isinstance(value, float) and value >= 0.0, (key, value)
+    parts = sum(v for k, v in phases.items() if k != "total_ms")
+    # the parts are nested in the envelope: never more than it, but for the
+    # rounding of eight numbers
+    assert parts <= phases["total_ms"] + 0.01
 
 
 # ----------------------------------------------------------- phase sampling
 
-def test_sampled_phase_rows_complete_and_sum_to_wall():
-    """Every Nth decode step carries a full phase row; the components sum
-    to ~ the step's dispatch-to-retire wall (the untimed residue is a few
-    lines of python between the timed windows)."""
-    engine = TPUEngine(_config(step_sample_every=2))
-    outs = _gen_all(engine, [engine.tokenizer.encode("attribute my steps")],
-                    max_tokens=12)
-    assert outs[0]
-    rows = _phase_rows(engine)
-    assert rows, "sampling enabled but no phase rows surfaced"
-    assert engine.stats.phase_samples == len(rows)
+@pytest.mark.parametrize("overlap", [False, True])
+def test_phase_rows_complete_on_every_host_fed_step_and_sum_to_wall(overlap):
+    """Every host-fed dispatch carries a full phase row whose parts sum to
+    ~ its ``build.t0 -> readback.t1`` on the timeline; a device-fed decode
+    step (its host work overlapped the step before it) carries none. Under
+    overlap a host-fed step retires after the NEXT dispatch was built and
+    launched: that dispatch's spans are the rest of the envelope."""
+    engine = TPUEngine(_config(prefix_cache=False, decode_overlap=overlap))
+    for _ in range(3):      # the last run's steps find every program compiled
+        outs = _gen_all(engine,
+                        [engine.tokenizer.encode("attribute my steps")],
+                        max_tokens=12)
+        assert outs[0]
+    ring = engine.timeline.snapshot()
+    kind_of = {s.seq: s.kind for s in ring["step"]}
+    span = {(s.step, s.name): s for s in ring["span"]}
+    rows = engine.recent_steps()[-13:]      # a prefill and twelve steps
+    kinds = {kind_of[r["seq"]] for r in rows}
+    assert kinds == ({"prefill", "decode", "decode_fb"} if overlap
+                     else {"prefill", "decode"})
     for row in rows:
-        assert row["kind"] == "decode"
+        seq, kind = row["seq"], kind_of[row["seq"]]
+        if kind == "decode_fb":
+            assert row["phases"] is None
+            continue
         _assert_row_complete(row)
         phases = row["phases"]
-        total = phases["total_ms"]
+        family, wait = (("decode", "readback") if kind == "decode"
+                        else ("prefill", "sync"))
+        build, readback = span[seq, f"{family}.build"], span[seq, f"{family}.{wait}"]
+        launch = span[seq, f"{family}.dispatch.launch"]
+        assert phases["total_ms"] == pytest.approx(
+            (readback.t1 - build.t0) * 1e3, abs=2e-3)
+        assert phases["launch_ms"] == pytest.approx(
+            (launch.t1 - launch.t0) * 1e3, abs=2e-3)
+        between = sum(
+            s.t1 - s.t0 for s in ring["span"]
+            if s.step == seq + 1 and s.name in (
+                "decode.build", "decode.table_sync", "decode.dispatch")
+            and launch.t1 <= s.t0 and s.t1 <= readback.t0) * 1e3
+        assert (between > 0) == (overlap and kind == "decode")
         parts = sum(v for k, v in phases.items() if k != "total_ms")
-        # components never exceed the envelope (timed windows are nested
-        # in it) and cover most of it; the slack bound is loose because
-        # CI wall clocks jitter at the sub-ms scale these phases live at
-        assert parts <= total + 0.5
-        assert total - parts <= max(5.0, 0.5 * total)
-        # sampled steps ran serially: their ring row is also the step the
-        # roofline observed (duration_ms covers the same dispatch)
-        assert row["duration_ms"] >= 0.0
+        # what is left: bucket lookups, the spans' own bookkeeping, counters
+        assert phases["total_ms"] - parts - between <= max(
+            2.0, 0.25 * phases["total_ms"])
+    assert len([r for r in rows if r["phases"]]) == sum(
+        1 for r in rows if kind_of[r["seq"]] != "decode_fb")
 
 
-def test_sampling_preserves_greedy_parity():
+def test_phase_rows_preserve_greedy_parity():
     """The acceptance gate: seeded engines, identical preloaded prompts —
-    enabling phase sampling must not change one emitted token (the
-    sampled step reuses the admission drain barrier)."""
+    the overlapped engine (few host-fed steps, so few rows) and the serial
+    one (every step host-fed, a row on each) emit the same tokens: a row is
+    read off spans and costs no drain and no sync."""
     texts = ["alpha bravo", "charlie", "delta echo foxtrot golf",
              "hotel india juliet"]
-    outs = {}
-    for every in (0, 3):
-        engine = TPUEngine(_config(step_sample_every=every))
+    outs, rows = {}, {}
+    for overlap in (True, False):
+        engine = TPUEngine(_config(decode_overlap=overlap))
         engine._rng = jax.random.PRNGKey(1234)
         prompts = [engine.tokenizer.encode(t) for t in texts]
-        outs[every] = _gen_preloaded(engine, prompts, max_tokens=12)
-        if every:
-            assert engine.stats.phase_samples > 0
-        else:
-            assert engine.stats.phase_samples == 0
-    assert outs[0] == outs[3]
+        outs[overlap] = _gen_preloaded(engine, prompts, max_tokens=12)
+        decode = [r for r in engine.recent_steps() if r["kind"] == "decode"]
+        rows[overlap] = sum(1 for r in decode if r["phases"]), len(decode)
+    assert outs[True] == outs[False]
+    assert rows[False][0] == rows[False][1] > 0     # serial: every step
+    assert 0 < rows[True][0] < rows[True][1]        # overlapped: the fed none
 
 
-def test_sampling_off_is_silent():
-    """Default config: no phase rows in the ring, no phase histogram
-    samples, no sampled-step counter movement."""
+def test_no_dispatch_ever_syncs_for_attribution():
+    """The forced-sync sampler is gone: no setting, no counter, no
+    ``decode.device_wait`` span on any ring, and ``_decode_dispatch`` waits
+    for the device nowhere — yet every host-fed step has its row and the
+    per-phase histograms fill, on the default configuration."""
+    import inspect
+
     metrics = PrometheusRegistry()
     engine = TPUEngine(_config(), metrics=metrics)
     _gen_all(engine, [engine.tokenizer.encode("quiet steady state")],
              max_tokens=8)
-    assert not _phase_rows(engine)
-    assert engine.stats.phase_samples == 0
+    assert not hasattr(engine.config, "step_sample_every")
+    assert not hasattr(engine.stats, "phase_samples")
+    assert not [s for s in engine.timeline.snapshot()["span"]
+                if s.name == "decode.device_wait"]
+    source = inspect.getsource(TPUEngine._decode_dispatch)
+    assert "block_until_ready" not in source and "device_get" not in source
+    assert _phase_rows(engine)
     text = metrics.render()[0].decode()
-    assert "mcpforge_llm_step_phase_seconds_count" not in text or all(
-        line.endswith(" 0.0")
-        for line in text.splitlines()
-        if line.startswith("mcpforge_llm_step_phase_seconds_count"))
+    counts = [float(line.split()[-1]) for line in text.splitlines()
+              if line.startswith("mcpforge_llm_step_phase_seconds_count")]
+    assert counts and all(c >= 1 for c in counts)
+    assert "device_compute" not in text
 
 
 def test_phase_histograms_and_span_events_emitted():
-    """Sampled rows feed mcpforge_llm_step_phase_seconds{phase=...} and
-    ride llm.decode spans as decode.step.phases events."""
+    """Host-fed rows feed mcpforge_llm_step_phase_seconds{phase=...} and
+    the decode ones ride llm.decode spans as decode.step.phases events."""
     from mcp_context_forge_tpu.observability.tracing import Tracer
     tracer = Tracer(exporter="memory")
     metrics = PrometheusRegistry()
-    engine = TPUEngine(_config(step_sample_every=2), tracer=tracer,
+    engine = TPUEngine(_config(decode_overlap=False), tracer=tracer,
                        metrics=metrics)
 
     async def main():
@@ -185,8 +226,8 @@ def test_phase_histograms_and_span_events_emitted():
 
     _run(engine, main())
     text = metrics.render()[0].decode()
-    for phase in ("host_dispatch", "table_sync", "device_compute",
-                  "readback", "emit"):
+    for phase in ("rows", "sampling", "rng", "table_sync", "upload",
+                  "launch", "readback"):
         line = (f'mcpforge_llm_step_phase_seconds_count'
                 f'{{phase="{phase}",replica="0"}}')
         counts = [float(ln.split()[-1]) for ln in text.splitlines()
@@ -196,16 +237,17 @@ def test_phase_histograms_and_span_events_emitted():
     assert decode_spans
     events = [ev for span in decode_spans for ev in span.events
               if ev[1] == "decode.step.phases"]
-    assert events, "no decode.step.phases span events"
+    assert 1 <= len(events) <= 8, "no decode.step.phases span events"
+    assert [ev[0] for ev in events] == sorted(ev[0] for ev in events)
     for _ts, _name, attrs in events:
         assert set(attrs) == PHASE_KEYS
 
 
 def test_crash_mid_pipeline_emits_no_garbage_rows():
-    """A device fault while a sampled window is possible must never leave
+    """A device fault between a dispatch and its retire must never leave
     a partial phase row behind: the inflight record dies with the step,
     and every row that DID surface is complete."""
-    engine = TPUEngine(_config(step_sample_every=2))
+    engine = TPUEngine(_config())
     real = engine._decode_fn
     calls = {"n": 0}
 
@@ -214,7 +256,7 @@ def test_crash_mid_pipeline_emits_no_garbage_rows():
 
         def wrapper(*args, **kwargs):
             calls["n"] += 1
-            if calls["n"] >= 3:
+            if calls["n"] >= 2:
                 raise RuntimeError("injected device fault")
             return fn(*args, **kwargs)
         return wrapper
@@ -222,18 +264,24 @@ def test_crash_mid_pipeline_emits_no_garbage_rows():
     engine._decode_fn = exploding
 
     async def main():
-        request = GenRequest(
+        # a second request arrives mid-decode: its admission drains the
+        # pipeline, and the host-fed dispatch that follows is the fault
+        first = GenRequest(
             request_id="crash",
-            prompt_ids=engine.tokenizer.encode("crash mid sampled window"),
+            prompt_ids=engine.tokenizer.encode("crash mid pipeline"),
             max_tokens=64)
-        await engine.submit(request)
-        tokens = []
-        while True:
-            token = await asyncio.wait_for(request.stream.get(), timeout=60)
-            if token is None:
-                break
-            tokens.append(token)
-        return request
+        late = GenRequest(
+            request_id="crash-late",
+            prompt_ids=engine.tokenizer.encode("the barrier"), max_tokens=8)
+        await engine.submit(first)
+        for _ in range(3):
+            await asyncio.wait_for(first.stream.get(), timeout=60)
+        await engine.submit(late)
+        for request in (first, late):
+            while await asyncio.wait_for(request.stream.get(),
+                                         timeout=60) is not None:
+                pass
+        return first
 
     async def wrapper():
         await engine.start()
@@ -244,23 +292,31 @@ def test_crash_mid_pipeline_emits_no_garbage_rows():
             engine._started = False
 
     request = asyncio.run(wrapper())
-    assert calls["n"] >= 3
+    assert calls["n"] >= 2
     assert request.finish_reason == "error"
-    for row in _phase_rows(engine):
+    rows = _phase_rows(engine)
+    assert rows
+    for row in rows:
         _assert_row_complete(row)
-    assert engine.stats.phase_samples == len(_phase_rows(engine))
+    # the dispatch that died left its spans on the timeline and no row
+    ring = engine.timeline.snapshot()
+    launched = {s.step for s in ring["span"]
+                if s.name == "decode.dispatch.upload"}
+    assert launched - {r["seq"] for r in engine.recent_steps()}
 
 
 def test_eos_mid_pipeline_rows_stay_complete():
     """Mixed-length concurrent requests (EOS/max_tokens staggered across
     the pipeline) exercise the drain-at-EOS barriers; every surfaced
     phase row must still be complete and the streams must terminate."""
-    engine = TPUEngine(_config(step_sample_every=2, decode_block=2))
+    engine = TPUEngine(_config(decode_block=2))
     prompts = [engine.tokenizer.encode(t)
                for t in ("one", "two words here", "three is a longer one")]
     outs = _gen_all(engine, prompts, max_tokens=7)
     assert all(outs)
-    for row in _phase_rows(engine):
+    rows = _phase_rows(engine)
+    assert [r for r in rows if r["kind"] == "decode"]
+    for row in rows:
         _assert_row_complete(row)
 
 
@@ -274,7 +330,7 @@ def warmed_engine():
     mid-serving — a flaky serving-stage compile that would break the
     zero-serving-compiles invariant this fixture exists to pin."""
     metrics = PrometheusRegistry()
-    config = _config(warmup=True, warmup_mode="full", step_sample_every=4)
+    config = _config(warmup=True, warmup_mode="full")
     engine = TPUEngine(config, metrics=metrics)
     outs = _gen_all(engine, [engine.tokenizer.encode("warmed traffic"),
                              engine.tokenizer.encode("second stream")],

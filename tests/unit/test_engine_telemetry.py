@@ -249,12 +249,11 @@ async def test_gateway_http_span_is_ancestor_of_llm_request():
 async def test_gateway_slo_and_step_attribution_surfaces():
     """GET /admin/slo serves objective verdicts over the engine's real
     histograms, and /admin/engine/steps carries the step-attribution /
-    roofline / compile-tracking blocks (with phase rows when sampling is
-    enabled via MCPFORGE_TPU_LOCAL_STEP_SAMPLE_EVERY)."""
+    roofline / compile-tracking blocks (with a phase row on every host-fed
+    step, read off the timeline's spans: no setting turns it on)."""
     import aiohttp
     auth = aiohttp.BasicAuth("admin", "changeme")
     gateway = await _make_llm_gateway(
-        MCPFORGE_TPU_LOCAL_STEP_SAMPLE_EVERY="2",
         MCPFORGE_SLO_TPOT_P95_MS="60000",  # CPU decode must not flake it
         MCPFORGE_SLO_TTFT_P95_MS="60000",
         MCPFORGE_SLO_QUEUE_WAIT_P95_MS="60000",
@@ -286,24 +285,38 @@ async def test_gateway_slo_and_step_attribution_surfaces():
         assert ttft["cumulative_p_ms"] is not None
 
         # step introspection: attribution + roofline + compile blocks,
-        # and sampled decode rows carry complete phase dicts
+        # and host-fed rows carry complete phase dicts
         resp = await gateway.get("/admin/engine/steps?limit=32", auth=auth)
         assert resp.status == 200
         intro = await resp.json()
-        assert intro["phase_sampling"]["every"] == 2
-        assert intro["phase_sampling"]["samples"] >= 1
+        assert "phase_sampling" not in intro
+        assert intro["dispatch_stalls"] >= 0
         assert "cost_entries" in intro["roofline"]
         assert intro["xla_compiles"]["serving"]["count"] >= 0
         phase_rows = [s for s in intro["steps"] if s.get("phases")]
-        assert phase_rows, "sampling enabled but no phase rows served"
+        assert phase_rows, "no host-fed step served its phase row"
         for row in phase_rows:
-            assert {"host_dispatch_ms", "table_sync_ms", "device_compute_ms",
-                    "readback_ms", "emit_ms", "total_ms"} == set(row["phases"])
+            assert {"rows_ms", "sampling_ms", "rng_ms", "upload_ms",
+                    "launch_ms", "readback_ms", "total_ms"} \
+                == set(row["phases"]) - {"table_sync_ms"}
+        assert any("table_sync_ms" in row["phases"] for row in phase_rows
+                   if row["kind"] == "decode")
 
-        # sampled phase histograms reached the exposition
+        # operator's view of stalls and of the collector's pauses
+        resp = await gateway.get("/admin/engine/stats", auth=auth)
+        stats = await resp.json()
+        assert stats["dispatch_stalls"] == intro["dispatch_stalls"]
+        assert set(stats["gc"]) == {"gen0", "gen1", "gen2"}
+        assert stats["gc"]["gen0"]["collections"] >= 1
+        assert set(stats["gc"]["gen2"]) == {"collections", "total_ms",
+                                            "longest_ms"}
+
+        # phase and pause histograms reached the exposition
         resp = await gateway.get("/metrics/prometheus", auth=auth)
         text = await resp.text()
         assert 'mcpforge_llm_step_phase_seconds_count' in text
+        assert 'mcpforge_gc_pause_seconds_bucket{generation="0",le="0.001"}' in text
+        assert 'mcpforge_gc_pause_seconds_count{generation="2"}' in text
         assert 'mcpforge_llm_xla_compiles_total' in text
     finally:
         await gateway.close()

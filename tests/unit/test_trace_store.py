@@ -401,7 +401,7 @@ def test_waterfall_tree_invariants_and_engine_join():
     engine_rows = [
         {"ts": T0 + 0.05, "duration_ms": 10.0, "seq": 1, "kind": "decode",
          "batch": 2, "tokens": 16, "superstep": 8, "frozen": 0,
-         "gap_ms": 0.0, "phases": {"device_compute": 8.0}, "mfu": 0.1,
+         "gap_ms": 0.0, "phases": {"launch_ms": 8.0}, "mfu": 0.1,
          "hbm_frac": 0.2},
         {"ts": T0 + 5.0, "duration_ms": 10.0, "seq": 2, "kind": "decode",
          "batch": 2, "tokens": 16, "superstep": 8, "frozen": 0,
