@@ -428,7 +428,7 @@ class Settings(BaseModel):
     tpu_local_max_seq_len: int = 2048
     tpu_local_page_size: int = 128
     tpu_local_num_pages: int = 512
-    tpu_local_prefill_buckets: tuple[int, ...] = (128, 512, 2048)
+    tpu_local_prefill_buckets: tuple[int, ...] = (128, 512, 2048)  # padded dense prefill lengths, at every width; each also gets a width-1 program at half its length for lone short prompts
     tpu_local_prefill_max_batch: int = 4  # admissions fused into one prefill
     tpu_local_mesh_shape: str = ""  # 'DxM' (e.g. 1x8 on v5e-8); '' = auto (1 x all devices)
     tpu_local_sp_impl: Literal["none", "ring", "ulysses"] = "none"

@@ -809,6 +809,17 @@ document.getElementById("f").onsubmit = async (e) => {
             "superstep": engine.config.fused_steps,
             "prefill_batches": stats.prefill_batches,
             "prefill_requests": stats.prefill_requests,
+            # dense prefills: the prompt tokens they carried, the positions
+            # they dispatched (padded rows x length: 1 - tokens / positions
+            # is the padding share) and those that took the half-length
+            # program of their bucket (half_lengths: bucket -> its half)
+            "dense_prefill": {
+                "tokens": stats.dense_prefill_tokens,
+                "positions": stats.dense_prefill_positions,
+                "half_batches": stats.half_prefill_batches,
+                "half_lengths": {str(b): h
+                                 for b, h in engine.half_lengths.items()},
+            },
             "queue_depth": stats.queue_depth,
             "kv_pages_in_use": alloc.pages_in_use,
             "kv_pages_free": alloc.free_pages,

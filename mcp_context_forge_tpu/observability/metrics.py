@@ -207,6 +207,20 @@ class PrometheusRegistry:
             "Block positions filled because their confidence passed the threshold",
             ["replica"], registry=self.registry,
         )
+        # dense prefill dispatches (no cached history, no chunk round): the
+        # positions they ran, split into the prompts' own and the padding up
+        # to the dispatched rows x length, and those that took a bucket's
+        # half-length program (a lone prompt that fits half its bucket)
+        self.llm_dense_prefill_positions = Counter(
+            "mcpforge_llm_dense_prefill_positions_total",
+            "Positions dense prefill dispatches ran (kind: prompt|padding)",
+            ["replica", "kind"], registry=self.registry,
+        )
+        self.llm_half_prefill_batches = Counter(
+            "mcpforge_llm_half_prefill_batches_total",
+            "Dense prefill dispatches through a bucket's half-length program",
+            ["replica"], registry=self.registry,
+        )
         self.llm_kv_alloc_failures = Counter(
             "mcpforge_llm_kv_alloc_failures_total",
             "Admissions deferred or requests truncated for lack of KV pages",

@@ -19,9 +19,10 @@ and nowhere else. Four kinds of event share one bounded ring:
   ``<p>.dispatch`` holds ``.upload`` / ``.launch`` (``<p>`` is ``prefill``
   or ``decode``), with the parent's ``step`` and ``kind``;
 - **steps** — one ``("step", seq, kind, width, rows, shape, t_dispatched,
-  t_retired, replica, counts)`` per device dispatch (``shape`` is the prefill
-  bucket or the decode context pages; ``counts`` is None, or for a model
-  family whose step programs count on the device (``models/deepseek.py``)
+  t_retired, replica, counts)`` per device dispatch (``shape`` is the length a
+  prefill was padded to or the decode context pages; ``counts`` is None, or
+  for a model family whose step programs count on the device
+  (``models/deepseek.py``)
   the step's ``StepCounts``: mean selected / context share of its rows,
   tokens through expert layers, token-expert pairs on held experts, and for
   a family with per-sequence state (``models/olmo_hybrid.py``) the live state
@@ -111,7 +112,7 @@ class StepEvent(NamedTuple):
     kind: str
     width: int
     rows: int
-    shape: int | None       # prefill bucket, or decode context pages
+    shape: int | None       # prefill length dispatched, or decode context pages
     t_dispatched: float
     t_retired: float
     replica: str

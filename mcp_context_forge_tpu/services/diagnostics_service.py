@@ -77,6 +77,11 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
         "decode_dispatches": stats.decode_dispatches,
         "superstep": engine.config.fused_steps,
         "prefill_batches": stats.prefill_batches,
+        # dense prefills: prompt tokens carried, positions dispatched, and
+        # dispatches through a bucket's half-length program
+        "dense_prefill_tokens": stats.dense_prefill_tokens,
+        "dense_prefill_positions": stats.dense_prefill_positions,
+        "half_prefill_batches": stats.half_prefill_batches,
         "chunking": stats.chunking,
         # overlapped-pipeline health (docs/perf_decode.md): device-fed
         # dispatches, barrier-forced drains, and the host-stall total the

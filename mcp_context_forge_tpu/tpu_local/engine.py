@@ -26,6 +26,7 @@ from __future__ import annotations
 import os
 import asyncio
 import logging
+import math
 import queue
 import threading
 import time
@@ -74,7 +75,7 @@ class EngineConfig:
     max_seq_len: int = 2048
     page_size: int = 128
     num_pages: int = 512
-    prefill_buckets: tuple[int, ...] = (128, 512, 2048)
+    prefill_buckets: tuple[int, ...] = (128, 512, 2048)  # padded dense prefill lengths, at every width; each also gets a width-1 program at half its length for lone short prompts
     prefill_max_batch: int = 4      # admissions fused into one prefill call
     mesh_shape: str = ""
     dtype: str = "bfloat16"
@@ -344,6 +345,9 @@ class GenRequest:
     bucket: int = -1
     chunked: bool = False
     chunk_pos: int = 0   # tokens prefilled so far (chunk-round scheduler)
+    # the length its prefill dispatch was padded to: its bucket, or half of
+    # it where it went alone through the half-length program (0: not yet)
+    prefill_len: int = 0
     # billing identity (observability/tenant.py resolution order:
     # team → API key → user; "" = unattributed internal work). Rides
     # into the engine so retire-time accounting lands in the tenant
@@ -379,6 +383,13 @@ class EngineStats:
         #                               decode_steps / decode_dispatches ≈ K
         self.prefill_batches = 0
         self.prefill_requests = 0
+        # what dense prefills (no history, no chunk round) carried and what
+        # they ran: prompt tokens, positions dispatched (padded rows x padded
+        # length; 1 - tokens / positions is the padding share), and the
+        # dispatches that took a bucket's half-length program
+        self.dense_prefill_tokens = 0
+        self.dense_prefill_positions = 0
+        self.half_prefill_batches = 0
         self.queue_depth = 0
         self.spec_steps = 0      # speculative verify dispatches
         self.spec_tokens = 0     # extra tokens emitted beyond 1/step
@@ -824,6 +835,7 @@ class TPUEngine:
             _named(jax.jit(partial(self._prefill_and_sample, sp=True),
                            donate_argnames=("kv",)), "_prefill_and_sample")
             if config.sp_impl != "none" else None)
+        self.half_lengths = self._find_half_lengths()
         # decode compiles per (batch-width, context-width) bucket pair:
         # attention reads only the table columns the longest active row
         # needs — the full-width gather wastes ~max_context/actual_context
@@ -1166,6 +1178,48 @@ class TPUEngine:
             request.slot = target
             self._running[target] = request
 
+    def _find_half_lengths(self) -> dict[int, int]:
+        """Dense prefill bucket -> the length of its half program: the same
+        jitted function at ``[1, bucket / 2]``, which a lone short prompt
+        takes in place of ``[1, bucket]`` (``_lone_length``). A bucket has
+        one where its half is a whole number of KV pages and of the unit the
+        family's prefill kernels need on this mesh (``prefill_unit``), where
+        a step of half the tokens keeps the bucket's expert formulation
+        (``expert_path``: one that falls from the chosen experts' row-blocks
+        to the scan over every expert computes as many expert rows as the
+        bucket's step, and is no shorter), where some prompt of the bucket
+        fits it (it lies above the next bucket down), and where the bucket
+        is not a sequence-parallel one."""
+        config = self.config
+        unit = math.lcm(config.page_size, self._family.prefill_unit(
+            self.mesh, self.model_config))
+
+        def experts(tokens: int) -> str | None:
+            return self._family.expert_path(self.model_config, self.mesh,
+                                            tokens, self._kv_dtype)
+
+        buckets = sorted(config.prefill_buckets)
+        halves = {}
+        for below, bucket in zip([0] + buckets, buckets):
+            sp = (self._prefill_sample_sp is not None
+                  and bucket > config.sp_threshold)
+            half = bucket // 2
+            if not (bucket % 2 or half % unit or half <= below or sp
+                    or experts(half) != experts(bucket)):
+                halves[bucket] = half
+        return halves
+
+    def _lone_length(self, request: GenRequest) -> int | None:
+        """The half program's length where ``request`` (its bucket assigned)
+        takes it: no cached history, not chunked, and what its prefill runs
+        fits half its bucket. Such a request is dispatched alone
+        (``_admit_slots``); None for every other."""
+        half = self.half_lengths.get(request.bucket)
+        if (half is None or request.hist or request.chunked
+                or self._prefill_end(request) > half):
+            return None
+        return half
+
     def _hist_ctx_buckets(self) -> list[int]:
         """Context-width buckets for the history/chunk prefill: one per
         prefill bucket (covers hist≈0 hits) plus the full table width —
@@ -1220,6 +1274,8 @@ class TPUEngine:
           at the smallest + largest context bucket — boots in minutes on
           a cold chip; a cache miss mid-traffic costs one compile (which
           the persistent cache then keeps).
+        Either mode also compiles each dense bucket's half-length program
+        (``half_lengths``), one shape a bucket that has one.
         """
         token = track_thread(self.compile_tracker, "warmup")
         try:
@@ -1333,6 +1389,26 @@ class TPUEngine:
                         jax.block_until_ready(first)
                         shapes += 1
                     B *= 2
+                half = self.half_lengths.get(bucket)
+                if half is not None:
+                    # the bucket's half program (_find_half_lengths): the
+                    # dense function alone, at width 1 alone. Its rows come
+                    # off the host as a dispatch's do, so that it is the one
+                    # compile this adds (a jnp.full is a small one a shape)
+                    args = (self.params, self.kv,
+                            jnp.asarray(np.full((1, half),
+                                                self.tokenizer.pad_id,
+                                                np.int32)),
+                            jnp.asarray(np.full((1, half), -1, np.int32)),
+                            jnp.zeros((1,), jnp.int32),
+                            jnp.zeros((1,), jnp.int32),
+                            settle, jax.random.PRNGKey(0))
+                    if capture:
+                        self.cost_registry.capture(
+                            "prefill", 1, half, self._prefill_sample, *args)
+                    first, self.kv = self._prefill_sample(*args)
+                    jax.block_until_ready(first)
+                    shapes += 1
             B = self.config.max_batch
             samp = SamplingParams(jnp.zeros((B,), jnp.float32),
                                   jnp.zeros((B,), jnp.int32),
@@ -2374,9 +2450,15 @@ class TPUEngine:
         # O(S * max_context) regardless of hist — don't drag dense rows of
         # the same bucket through it (they'd pay for a hit they didn't get)
         with_hist = head.hist > 0
+        # a prompt that fits its bucket's half program goes through it
+        # ALONE: a short head takes nobody along, and a long head passes
+        # over the short ones behind it (they lead a later admission, in
+        # their order) — a wider batch is no cheaper a token, and a short
+        # prompt's first token should not wait for a long one's positions
+        alone = self._lone_length(head) is not None
         group: list[GenRequest] = []
         skipped: list[GenRequest] = []
-        limit = min(len(free_slots), config.prefill_max_batch)
+        limit = 1 if alone else min(len(free_slots), config.prefill_max_batch)
         if head.chunked:
             limit = min(limit,
                         config.prefill_max_batch - len(self._chunking))
@@ -2389,7 +2471,8 @@ class TPUEngine:
             else:
                 ok = (self._assign_bucket(request) == bucket
                       and (request.hist > 0) == with_hist
-                      and not request.chunked)
+                      and not request.chunked
+                      and (self._lone_length(request) is not None) == alone)
             if ok:
                 group.append(request)
             else:
@@ -2504,13 +2587,17 @@ class TPUEngine:
         tl = self.timeline
         any_hist = any(r.hist > 0 for r in admitted)
         kind = "prefill_hist" if any_hist else "prefill"
+        # the length the rows are padded to: the bucket, or for a prompt
+        # admitted alone because it fits it, the bucket's half program
+        half = self._lone_length(admitted[0]) if len(admitted) == 1 else None
+        length = half or bucket
         seq = tl.next_seq()
         parts: dict[str, Any] = {}
         with tl.span("prefill.build", seq, kind) as build:
             with tl.span("prefill.build.rows", seq, kind) as parts["rows"]:
                 arrays, per_row = self._pack_rows(
                     [(r, r.hist, self._prefill_end(r)) for r in admitted],
-                    bucket)
+                    length)
             sampling, key = self._sample_and_split("prefill", seq, kind,
                                                    per_row, parts)
             # long buckets route through the sequence-parallel attention
@@ -2537,17 +2624,32 @@ class TPUEngine:
         self.stats.prefill_batches += 1
         self.stats.prefill_requests += len(admitted)
         width = int(arrays[0].shape[0])  # the dispatched pad
-        self._count_expert_path(width * bucket)
-        tl.step(seq, kind, width, len(admitted), bucket, dispatch.t0, sync.t1,
+        if not any_hist:
+            real = sum(self._prefill_end(r) for r in admitted)
+            self.stats.dense_prefill_tokens += real
+            self.stats.dense_prefill_positions += width * length
+            self.stats.half_prefill_batches += int(half is not None)
+            if self.metrics is not None:
+                rid = self.config.replica_id
+                self.metrics.llm_dense_prefill_positions.labels(
+                    replica=rid, kind="prompt").inc(real)
+                self.metrics.llm_dense_prefill_positions.labels(
+                    replica=rid, kind="padding").inc(width * length - real)
+                if half is not None:
+                    self.metrics.llm_half_prefill_batches.labels(
+                        replica=rid).inc()
+        self._count_expert_path(width * length)
+        tl.step(seq, kind, width, len(admitted), length, dispatch.t0, sync.t1,
                 counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
                           width=width, dur_ms=elapsed_ms,
                           tokens=0 if self._block else len(admitted),
-                          bucket=bucket,
+                          bucket=length,
                           phases=self._phase_row(parts, build, sync))
         with tl.span("prefill.emit", seq, kind):
             for i, request in enumerate(admitted):
                 request.prefill_ms = elapsed_ms
+                request.prefill_len = length
                 # the prompt's pages are written: register the full ones
                 # so later prompts sharing the prefix skip their KV —
                 # BEFORE emitting, as a first token that finishes the
@@ -2665,6 +2767,7 @@ class TPUEngine:
         with tl.span("prefill.emit", seq, "chunk"):
             for i, request in enumerate(batch):
                 request.prefill_ms += elapsed_ms
+                request.prefill_len = S
                 if request.chunk_pos < self._prefill_end(request):
                     continue  # more chunks to go; sample discarded
                 del self._chunking[request.slot]
@@ -3559,7 +3662,7 @@ class TPUEngine:
             "kind": kind,                       # prefill|chunk_prefill|decode|spec_decode
             "batch": batch,                     # rows carrying real work
             "width": width,                     # padded dispatch width
-            "bucket": bucket,                   # prefill token bucket (S)
+            "bucket": bucket,                   # length a prefill was padded to (S)
             "ctx_pages": ctx_pages,             # decode context-width bucket
             "duration_ms": round(dur_ms, 3),
             "tokens": tokens,                   # tokens emitted by this step
@@ -3877,6 +3980,9 @@ class TPUEngine:
                                   len(request.prompt_ids),
                               "llm.prefill_ms": round(request.prefill_ms, 2),
                               "llm.bucket": request.bucket,
+                              # what its (last) prefill dispatch was padded
+                              # to: under the bucket, the half program
+                              "llm.prefill_len": request.prefill_len,
                               "llm.cached_prefix_tokens": request.hist,
                               "llm.chunked": request.chunked,
                               "llm.kv_pages": self.allocator.slot_pages(

@@ -10,7 +10,8 @@ the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
 ``kv_logical`` / ``kv_page_bytes``, the kernel choices ``prefill_impl`` /
-``paged_impl`` / ``expert_path``, ``refusals`` (engine settings the
+``paged_impl`` / ``expert_path``, ``prefill_unit`` (the tokens a dense
+prefill's length is a whole number of), ``refusals`` (engine settings the
 family cannot serve yet), and ``STEP_KIND``: what one decode dispatch of the
 family is. ``"token"``: ``decode_step`` yields one token a row (a super-step
 scans it K times). ``"block"``: the family gives ``block_step`` in its place,

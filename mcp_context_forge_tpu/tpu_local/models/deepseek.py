@@ -244,6 +244,14 @@ def prefill_impl(impl: str, mesh, seq: int, config: DeepseekConfig,
     return "pallas" if on_tpu(mesh) else "gather"
 
 
+def prefill_unit(mesh, config: DeepseekConfig) -> int:
+    """Tokens a dense prefill's length must be a whole number of: on a TPU
+    the query tiles of the selector's and the latent attention's kernels."""
+    if not on_tpu(mesh):
+        return 1
+    return math.lcm(mla._INDEX_QUERY_TILE, mla._ATTN_QUERY_TILE)
+
+
 def paged_impl(mesh, config: DeepseekConfig, kv: LatentKVState) -> str:
     return "pallas" if on_tpu(mesh) else "gather"
 

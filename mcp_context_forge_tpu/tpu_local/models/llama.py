@@ -28,8 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from .configs import LlamaConfig
-from ..ops.attention import (causal_attention, select_paged_attention,
-                             select_prefill_attention)
+from ..ops.attention import (FLASH_BLOCK, causal_attention, on_tpu,
+                             select_paged_attention, select_prefill_attention)
 from ..kv.paged_cache import (PagedKVState, write_prefill_kv, write_decode_kv,  # noqa: F401 (family names)
                               gather_kv, init_kv_state, kv_logical,
                               kv_page_bytes)
@@ -89,6 +89,13 @@ def prefill_impl(impl: str, mesh, seq: int, config: LlamaConfig,
                  itemsize: int = 2) -> str:
     return select_prefill_attention(impl, mesh, seq, config.head_dim,
                                     config.n_kv_heads, itemsize)
+
+
+def prefill_unit(mesh, config: LlamaConfig) -> int:
+    """Tokens a dense prefill's length must be a whole number of to run the
+    kernels its mesh gives it: the flash kernel's block on a TPU (any other
+    length falls to the S x S reference there), any length off one."""
+    return FLASH_BLOCK if on_tpu(mesh) else 1
 
 
 def paged_impl(mesh, config: LlamaConfig, kv: PagedKVState) -> str:
@@ -348,7 +355,6 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
     if expert_path(config, mesh, tokens, x.dtype) == "grouped":
         # the kernel interprets off-TPU (the caller's mesh says which) so
         # the code path exists everywhere
-        from ..ops.attention import on_tpu
         from ..ops.grouped_moe import moe_ffn_grouped
         use_pallas = config.moe_impl == "grouped_pallas"
         return moe_ffn_grouped(

@@ -37,11 +37,13 @@ and selector counts, the rows, then the live state rows and the real
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from . import llama
 from .configs import OlmoHybridConfig
 from .llama import (_dense, _ffn, _history_attention, _history_tile,
                     _paged_decode_attention, lm_logits, rms_norm)
@@ -194,6 +196,14 @@ def prefill_impl(impl: str, mesh, seq: int, config: OlmoHybridConfig,
                  itemsize: int = 2) -> str:
     return select_prefill_attention(impl, mesh, seq, config.head_dim,
                                     config.n_kv_heads, itemsize)
+
+
+def prefill_unit(mesh, config: OlmoHybridConfig) -> int:
+    """The trunk's (its full-attention layers run the trunk's attention), and
+    on a TPU also whole token tiles of the gated delta-rule kernel (the
+    ``jax.numpy`` twin pads its own chunks)."""
+    unit = llama.prefill_unit(mesh, config)
+    return math.lcm(unit, gated_delta._TOKEN_TILE) if on_tpu(mesh) else unit
 
 
 def paged_impl(mesh, config: OlmoHybridConfig, kv: HybridKVState) -> str:

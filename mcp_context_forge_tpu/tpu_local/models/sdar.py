@@ -45,11 +45,13 @@ between equal confidences to ``torch.topk``: ties go to the lower position.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from . import llama
 from .configs import SdarConfig
 from .llama import (_dense, _ffn_block, _history_attention, _history_tile,  # noqa: F401 (expert_path: a family name, the trunk's rule)
                     apply_rope, expert_path, lm_logits, rms_norm)
@@ -147,6 +149,12 @@ def prefill_impl(impl: str, mesh, seq: int, config: SdarConfig,
                  itemsize: int = 2) -> str:
     return select_prefill_attention(impl, mesh, seq, config.head_dim,
                                     config.n_kv_heads, itemsize)
+
+
+def prefill_unit(mesh, config: SdarConfig) -> int:
+    """Whole blocks (a prefill ends on one, and the block-causal mask cuts
+    none) that are whole units of the trunk's, whose attention it runs."""
+    return math.lcm(config.block_length, llama.prefill_unit(mesh, config))
 
 
 def paged_impl(mesh, config: SdarConfig, kv: PagedKVState) -> str:

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
+FLASH_BLOCK = 128          # q and k tokens a grid step of the flash kernel holds
 
 
 # ------------------------------------------------------------------- reference
@@ -119,8 +120,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, *, block_q: int,
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret",
                                              "mask_block"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
-                           valid: jax.Array, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = False,
+                           valid: jax.Array, block_q: int = FLASH_BLOCK,
+                           block_k: int = FLASH_BLOCK, interpret: bool = False,
                            mask_block: int = 1) -> jax.Array:
     """q: [B,S,H,hd]; k/v: [B,S,KV,hd] (GQA: the index map hands each q
     head its kv head, so the repeated heads never materialize);
@@ -203,7 +204,7 @@ def select_prefill_attention(impl: str, mesh, seq: int, head_dim: int,
     if not on_tpu(mesh):
         return "reference"
     why = ""
-    if seq % 128 or head_dim % 128:
+    if seq % FLASH_BLOCK or head_dim % 128:
         why = f"S={seq} and head_dim={head_dim} must be multiples of 128"
     elif 4 * seq * head_dim * itemsize > _FLASH_KV_VMEM_BYTES:
         why = f"K/V of S={seq} exceed the kernel's VMEM budget"

@@ -121,8 +121,8 @@ def _mla_attention_case(chunk: int | None, table: int = DS_TABLE):
                 ((B, chunk // mla._ATTN_QUERY_TILE), jnp.int32)]
 
 
-def _index_case():
-    B, S = 2, 1024
+def _index_case(S: int = 1024):
+    B = 2
     return (partial(mla.sparse_index_scores_pallas, layer=3),
             [((B, S, DS_IDX_HEADS, DS_IDX_DIM), jnp.bfloat16),
              ((B, S, DS_IDX_HEADS), jnp.float32),
@@ -175,12 +175,12 @@ def _hybrid_paged_case(chunk: int | None, batch: int, table: int):
 SDAR_KV, SDAR_G, SDAR_E, SDAR_D, SDAR_F = 4, 8, 128, 2048, 768
 
 
-def _sdar_flash_case(batch: int):
+def _sdar_flash_case(batch: int, seq: int = 512):
     """A prefill's flash kernel under the block-causal mask (blocks of 4)."""
-    q = ((batch, 512, SDAR_KV * SDAR_G, HD), jnp.bfloat16)
-    kv = ((batch, 512, SDAR_KV, HD), jnp.bfloat16)
+    q = ((batch, seq, SDAR_KV * SDAR_G, HD), jnp.bfloat16)
+    kv = ((batch, seq, SDAR_KV, HD), jnp.bfloat16)
     return (partial(attention.flash_attention_pallas, mask_block=4),
-            [q, kv, kv, ((batch, 512), jnp.bool_)])
+            [q, kv, kv, ((batch, seq), jnp.bool_)])
 
 
 def _sdar_paged_case(chunk: int, batch: int, table: int):
@@ -276,6 +276,17 @@ KERNEL_CASES = {
     "paged_chunk_bf16_sdar_tile256x8": lambda: _sdar_paged_case(256, 4, 8),
     "grouped_moe_int8_sdar_4x512": _sdar_moe_case,
     "grouped_moe_int8_sdar_block_step_b16": lambda: _sdar_moe_case(128, 16),
+    # the half-length prefill programs of the cells (a lone prompt that fits
+    # half its bucket, PR 40): the flash kernel at 256 (the GQA trunk, the
+    # hybrid's attending layers, the block mask), the delta-rule chunk kernel
+    # over one row of 256, a 256-token step's experts at the 16-row block the
+    # rule gives it, and the latent family's two prefill kernels at 512
+    "flash_prefill_half_s256": lambda: _flash_case(256),
+    "flash_prefill_block_mask_half_1x256": lambda: _sdar_flash_case(1, 256),
+    "gated_delta_chunk_half_1x256": lambda: _gated_delta_case(1, 256),
+    "grouped_moe_int8_sdar_half_1x256_b16": lambda: _sdar_moe_case(256, 16),
+    "mla_attention_half_chunk_2x512x8": lambda: _mla_attention_case(512, 8),
+    "sparse_index_half_chunk_2x512x128": lambda: _index_case(512),
 }
 
 
@@ -289,6 +300,45 @@ def test_kernel_compiles_for_v5e(v5e, case):
     assert not jax.config.jax_enable_compilation_cache
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_which_buckets_have_a_half_on_a_v5e_mesh(v5e):
+    """On a TPU mesh a bucket's half-length program has to be whole units of
+    the family's prefill kernels and keep the bucket's expert formulation
+    (``engine._find_half_lengths``): what that gives the cells' buckets."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from mcp_context_forge_tpu.tpu_local.engine import EngineConfig, TPUEngine
+    from mcp_context_forge_tpu.tpu_local.models import MODEL_CONFIGS, family_of
+
+    mesh = Mesh(np.array(list(v5e.device_set)).reshape(1, 1), ("data", "model"))
+    sdar_cell = dataclasses.replace(MODEL_CONFIGS["sdar-test"], n_experts=128,
+                                    moe_top_k=8, moe_block=32)
+    want = {   # config -> (unit, halves of buckets 128, 512, 1024)
+        "mistral-7b": (128, {512: 256, 1024: 512}),
+        # 256 tokens x top-2 fall under the row-block rule: the scan
+        "mixtral-8x7b": (128, {1024: 512}),
+        "olmo-hybrid-test": (128, {512: 256, 1024: 512}),
+        "deepseek-test": (256, {512: 256, 1024: 512}),
+        sdar_cell: (128, {512: 256, 1024: 512}),
+    }
+    for model, (unit, halves) in want.items():
+        config = MODEL_CONFIGS[model] if isinstance(model, str) else model
+        engine = TPUEngine.__new__(TPUEngine)
+        engine.config = EngineConfig(page_size=128)
+        engine.mesh, engine.model_config = mesh, config
+        engine._family, engine._kv_dtype = family_of(config), jnp.bfloat16
+        engine._prefill_sample_sp = None
+        assert engine._family.prefill_unit(mesh, config) == unit, config.name
+        found = {}
+        for bucket in (128, 512, 1024):   # one bucket an engine, as the cells
+            engine.config = EngineConfig(page_size=128,
+                                         prefill_buckets=(bucket,))
+            found.update(engine._find_half_lengths())
+        assert found == halves, config.name
 
 
 def test_block_step_and_masked_prefill_compile_for_v5e(v5e):

@@ -91,7 +91,13 @@ def test_superstep_greedy_parity_and_sync_drop():
                     "hotel india juliet"]
     outs, dispatches = {}, {}
     for k in (1, 2, 8):
-        engine = TPUEngine(_config(superstep=k))
+        # buckets (16, 32): the upper one has no half-length program (its
+        # half is the bucket below), so the two longer prompts are admitted
+        # in ONE dispatch and every row starts decoding within two
+        # admissions; a lone short prompt's own dispatch (the file's (16,
+        # 64)) would stagger the rows and the count of dispatches with them
+        engine = TPUEngine(_config(superstep=k, prefill_buckets=(16, 32)))
+        assert engine.half_lengths == {}
         engine._rng = jax.random.PRNGKey(1234)
         prompts = [engine.tokenizer.encode(t) for t in prompts_text]
         outs[k] = _gen_preloaded(engine, prompts, max_tokens=13)
