@@ -856,6 +856,9 @@ document.getElementById("f").onsubmit = async (e) => {
                 "enabled": engine.config.spec_decode,
                 "steps": stats.spec_steps,
                 "extra_tokens": stats.spec_tokens,
+                # counted on the device by a family that drafts there
+                "drafted_rows": stats.spec_drafted,
+                "accepted_drafts": stats.spec_accepted,
             },
             # routed experts: tokens through expert layers and pairs on
             # held experts (counted on the device by families that do),

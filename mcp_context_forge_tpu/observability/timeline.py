@@ -105,6 +105,13 @@ class StepCounts(NamedTuple):
     denoise_passes: float = 0.0
     block_tokens: float = 0.0
     filled_by_threshold: float = 0.0
+    # a verify step of a family that drafts on the device: rows a draft could
+    # have served (greedy, more than one token to go), rows that carried one,
+    # drafts the step's own samples bore out, and the tokens the step emitted
+    draft_wanted: float = 0.0
+    draft_rows: float = 0.0
+    drafts_accepted: float = 0.0
+    spec_tokens: float = 0.0
 
 
 class StepEvent(NamedTuple):

@@ -13,8 +13,8 @@ Page 0 is reserved as the trash page: masked/padding writes land there.
 What a page holds a token is the model family's to declare (``kv_pools``):
 the GQA trunk keeps ``k`` and ``v`` of ``[KV, hd]`` (``PagedKVState``, below,
 with every rule of this docstring); the latent family keeps ``latent``
-(``c || k_r``) and the selector's ``index_key``, one vector each
-(``LatentKVState``). Both ride the ONE block table and the ONE
+(``c || k_r``) and, where the model has a selector, its ``index_key``, one
+vector each (``LatentKVState``). Both ride the ONE block table and the ONE
 ``PageAllocator``, which deals in page ids and knows nothing of the pools.
 
 A pool also declares WHICH layers hold it and whether it grows a token or is
@@ -112,8 +112,12 @@ AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig
 def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
     """The pools a family's cache holds, in the order of its state's fields."""
     if isinstance(config, DeepseekConfig):
-        return (PoolSpec("latent", (config.latent_dim,)),
-                PoolSpec("index_key", (config.index_head_dim,)))
+        latent = PoolSpec("latent", (config.latent_dim,),
+                          config.n_cache_layers)
+        if not config.has_selector:
+            return (latent,)
+        return (latent, PoolSpec("index_key", (config.index_head_dim,),
+                                 config.n_cache_layers))
     heads = (config.n_kv_heads, config.head_dim)
     if isinstance(config, OlmoHybridConfig):
         heads = (config.kv_pool_heads, config.head_dim)
@@ -145,12 +149,13 @@ def kv_state_bytes(config: AnyConfig, rows: int,
 
 
 class LatentKVState(NamedTuple):
-    """Device state of the latent family: one attention vector and one
-    selector key a token a layer, shared by all heads (nothing to shard over
-    ``model``; full precision only)."""
+    """Device state of the latent family: one attention vector and, where the
+    model has a selector, one selector key a token a layer, shared by all
+    heads (nothing to shard over ``model``; full precision only). L counts
+    the model's layers and its multi-token-prediction block's."""
 
     latent_pages: jax.Array   # [L, num_pages, page_size, kv_lora_rank + rope]
-    index_pages: jax.Array    # [L, num_pages, page_size, index_head_dim]
+    index_pages: jax.Array | None   # [L, num_pages, page_size, index_head_dim]
     block_tables: jax.Array   # [slots, max_pages_per_slot] int32
 
     @property
@@ -223,9 +228,10 @@ def kv_logical(quant: str = "", config: AnyConfig | None = None
                              block_tables="replicated", state="state_pool",
                              conv_tail="state_pool", state_rows="replicated")
     if _latent_only(config, quant):
-        return LatentKVState(latent_pages="latent_pages",
-                             index_pages="latent_pages",
-                             block_tables="replicated")
+        return LatentKVState(
+            latent_pages="latent_pages",
+            index_pages="latent_pages" if config.has_selector else None,
+            block_tables="replicated")
     scales = "kv_scales" if quant == "int8" else None
     return PagedKVState(k_pages="kv_pages", v_pages="kv_pages",
                         block_tables="replicated",
@@ -248,10 +254,11 @@ def init_kv_state(config: AnyConfig, num_pages: int, page_size: int,
         return HybridKVState(k, v, tables, state, conv_tail,
                              jnp.zeros((max_slots,), dtype=jnp.int32))
     if _latent_only(config, quant):
-        latent, index_key = (
-            jnp.zeros((config.n_layers, num_pages, page_size, *pool.shape),
+        latent, *index_key = (
+            jnp.zeros((pool.layers, num_pages, page_size, *pool.shape),
                       dtype=dtype) for pool in kv_pools(config))
-        return LatentKVState(latent, index_key, tables)
+        return LatentKVState(latent, index_key[0] if index_key else None,
+                             tables)
     shape = (config.n_layers, num_pages, page_size, config.n_kv_heads,
              config.head_dim)
     if quant == "int8":
@@ -468,20 +475,23 @@ def _token_pages(kv, slot_ids: jax.Array, positions: jax.Array,
 
 
 def write_latent_kv(kv: LatentKVState, layer: int, latent: jax.Array,
-                    index_key: jax.Array, slot_ids: jax.Array,
+                    index_key: jax.Array | None, slot_ids: jax.Array,
                     positions: jax.Array,
                     valid: jax.Array | None = None) -> LatentKVState:
     """Scatter tokens' latent vectors and selector keys into their pages: a
     [B, S] block (prefill, chunk rounds; ``valid`` [B, S]) or one token a slot
     (decode; positions and ``valid`` [B], False rows MUST be masked for the
     reason ``write_decode_kv`` gives). latent: [..., latent_dim]; index_key:
-    [..., index_head_dim]."""
+    [..., index_head_dim], None for a model without a selector (no pool)."""
     pages, offset = _token_pages(kv, slot_ids, positions, valid)
     pages, offset = pages.reshape(-1), offset.reshape(-1)
     flat = lambda a, pool: a.reshape(-1, a.shape[-1]).astype(pool.dtype)
-    return kv._replace(
+    kv = kv._replace(
         latent_pages=kv.latent_pages.at[layer, pages, offset].set(
-            flat(latent, kv.latent_pages), mode="drop"),
+            flat(latent, kv.latent_pages), mode="drop"))
+    if index_key is None:
+        return kv
+    return kv._replace(
         index_pages=kv.index_pages.at[layer, pages, offset].set(
             flat(index_key, kv.index_pages), mode="drop"))
 

@@ -2,8 +2,8 @@
 moderation classifier), pure-pytree params for pjit.
 
 A decoder family is one module (``llama``: GQA + RoPE + SwiGLU/Mixtral
-experts; ``deepseek``: latent attention, sparse selector, shared + routed
-experts; ``olmo_hybrid``: gated delta-rule linear-attention layers between
+experts; ``deepseek``: latent attention, shared + routed experts, and by the
+model configuration a sparse selector and a multi-token-prediction block; ``olmo_hybrid``: gated delta-rule linear-attention layers between
 full-attention layers; ``sdar``: the GQA trunk with QK-norm and many small
 experts under a block-causal mask, generating by diffusion over blocks) with
 the same set of names: ``init_keys``, ``init_layer``,
@@ -26,8 +26,22 @@ engine compiles one weight-init program a kind) that names the layer's FFN
 (``deepseek``: dense | experts) or its MIXER (``olmo_hybrid``:
 linear_attention | full_attention); cache pools that only some layers hold,
 and pools of a fixed size a sequence beside the per-token ones
-(``kv/paged_cache.py: kv_pools``); and, with ``STEP_AUX``, a float32 vector of
-counts its step programs return beside the tokens (``engine._step_counts``)."""
+(``kv/paged_cache.py: kv_pools``); with ``STEP_AUX``, a float32 vector of
+counts its step programs return beside the tokens (``engine._step_counts``);
+and ``drafts_on_device(config) -> bool``: WHERE A SPECULATIVE DRAFT COMES FROM.
+A family without the name, or one that answers False, gets the engine's
+prompt-lookup drafts (``engine._draft_tokens``) and the plain verify step. A
+family that answers True drafts itself: its ``prefill`` /
+``prefill_with_history`` take ``hidden=True`` and return the last layer's
+hidden states beside the logits, and ``draft_step(params, config, hidden,
+next_tokens, positions, kv, slot_ids, aux, ...)`` turns them and the tokens
+that FOLLOW each position into draft logits for the token after, writing its
+own cache entries. The engine then runs it beside every prefill and chunk
+round (the first decode dispatch has a draft) and makes every decode dispatch
+under ``spec_decode`` a verify step that also drafts
+(``engine._decode_and_sample_draft``); the host keeps the draft with the
+request. The acceptance rule and the dead-by-position rule for rejected
+drafts are the engine's, the same for both answers (docs/adr/008)."""
 
 from importlib import import_module
 from types import ModuleType

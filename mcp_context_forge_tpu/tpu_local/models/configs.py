@@ -78,12 +78,21 @@ class LlamaConfig:
 
 @dataclass(frozen=True)
 class DeepseekConfig:
-    """Geometry for the latent-attention + learned-sparse-selector + shared/
-    routed-expert family (DeepSeek-V3.2 ``config.json`` keys in brackets).
+    """Geometry for the latent-attention + shared/routed-expert family, with
+    two optional parts: a learned sparse selector and a multi-token-prediction
+    block (DeepSeek-V3 / V3.2 ``config.json`` keys in brackets).
 
     Attention goes through low-rank latents: the cache holds one
     ``kv_lora_rank + qk_rope_head_dim`` vector a token a layer, shared by all
-    heads, plus the selector's ``index_head_dim`` key. The first
+    heads. With ``index_topk`` > 0 a selector keeps that many positions a
+    query (and the cache a second pool, the selector's ``index_head_dim``
+    key); with 0 there are no selector weights and no second pool, and a query
+    attends to everything it may see. ``n_mtp_blocks`` [num_nextn_predict_layers]
+    is 0 or 1: one more decoder layer of the expert kind (with its own latent
+    entries, cache layer ``n_layers``) that drafts the token after next from
+    the last layer's hidden state (``models/deepseek.py: draft_step``). Rotary
+    frequencies are YaRN's where ``max_seq_len`` passes ``rope_original_max``
+    and plain otherwise (a model without rope scaling sets the two equal). The first
     ``n_dense_layers`` [first_k_dense_replace] layers carry a dense SwiGLU of
     ``ffn_hidden`` [intermediate_size]; the rest route over ``n_routed_experts``
     (sigmoid scores, bias-corrected group-limited top-k) beside
@@ -106,13 +115,14 @@ class DeepseekConfig:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
-    index_n_heads: int
-    index_head_dim: int
-    index_topk: int
     ffn_hidden: int
     moe_ffn_hidden: int
     n_routed_experts: int
     experts_held: tuple[int, int]
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    n_mtp_blocks: int = 0
     n_dense_layers: int = 1
     n_shared_experts: int = 1
     moe_top_k: int = 8
@@ -138,6 +148,15 @@ class DeepseekConfig:
     def latent_dim(self) -> int:
         """What the cache holds a token a layer for attention: c || k_r."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def has_selector(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Layers that hold latent entries: the model's and the block's."""
+        return self.n_layers + self.n_mtp_blocks
 
     def ffn_kind(self, layer: int) -> str:
         return "dense" if layer < self.n_dense_layers else "experts"
@@ -325,6 +344,17 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
         n_routed_experts=16, experts_held=(0, 4), n_dense_layers=1,
         n_shared_experts=1, moe_top_k=4, n_group=4, topk_group=2,
         rope_factor=4.0, rope_original_max=64, max_seq_len=512,
+        moe_impl="grouped", moe_block=8),
+    # the latent family WITHOUT a selector and WITH the multi-token-prediction
+    # block (spec_decode drafts on the device): one router group, plain
+    # rotary frequencies (no scaling: rope_original_max = max_seq_len)
+    "deepseek-mtp-test": DeepseekConfig(
+        name="deepseek-mtp-test", vocab_size=512, dim=64, n_layers=3,
+        n_heads=4, q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=16, ffn_hidden=128, moe_ffn_hidden=32,
+        n_routed_experts=16, experts_held=(0, 4), n_mtp_blocks=1,
+        n_dense_layers=1, n_shared_experts=1, moe_top_k=4, n_group=1,
+        topk_group=1, rope_original_max=512, max_seq_len=512,
         moe_impl="grouped", moe_block=8),
     # the hybrid family at CI scale: two periods of (3 gated delta-rule layers,
     # 1 full-attention layer); 4 linear heads of a 16 x 32 state

@@ -1,5 +1,8 @@
-"""Latent-attention decoder with a learned sparse selector and shared +
-routed experts (the DeepSeek-V3.2 layer), functional like ``models/llama.py``.
+"""Latent-attention decoder with shared + routed experts, functional like
+``models/llama.py``, with two parts that a model configuration has or lacks:
+a learned sparse selector (the DeepSeek-V3.2 layer: ``index_topk`` > 0) and a
+multi-token-prediction block that drafts for the engine's verify step
+(``n_mtp_blocks`` 1). One module, the latent path written once.
 
 Per layer, pre-norm residual: ``x += MLA(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``.
 
@@ -10,7 +13,9 @@ Per layer, pre-norm residual: ``x += MLA(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``
   Every step runs the ABSORBED form: ``q~ = q_nope W_uk^T`` so a score is one
   dot product with the cached vector, and ``W_uv`` applies after the weighted
   sum of latents. ``scale = (d_nope + d_rope)^-0.5 * mscale^2`` (YaRN).
-- **Selector.** ``q^I = c_q W^I_qb`` (heads x dims), ``k^I = LayerNorm(x
+- **Selector** (where the model has one; without it a query attends to every
+  position it may see, visibility is the only bias, and the cache holds no
+  second pool). ``q^I = c_q W^I_qb`` (heads x dims), ``k^I = LayerNorm(x
   W^I_k)``, RoPE on the leading rotary dims of both, ``w = x W^I_w * Hi^-0.5``;
   ``I[t, s] = d^-0.5 * sum_j w[t, j] relu(q^I_j[t] . k^I[s])``; a query attends
   to the ``min(index_topk, t + 1)`` positions of largest ``I[t, .]`` —
@@ -27,13 +32,30 @@ Per layer, pre-norm residual: ``x += MLA(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``
   (``x[i]`` with ``x[i + d/2]``), as ``models/llama.apply_rope``. With random
   weights the layout is a permutation of weight columns.
 
-Not loaded: the multi-token-prediction block. Not done: FP8 and the Hadamard
-rotation of the published selector (orthogonal, cancels in the product).
+- **Multi-token-prediction block** (``params["mtp"]``; DeepSeek-V3 report
+  section 2.2). With ``h_i`` the last layer's output at position ``i`` BEFORE
+  ``final_norm`` and ``t_{i+1}`` the token that follows: ``x_i =
+  [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)] W_eh``, ``y_i = Layer(x_i)`` (one
+  whole decoder layer of the expert kind: latent attention over the block's
+  OWN entries 0..i at rotary position ``i``, cache layer ``n_layers``),
+  ``draft_i = Head(RMSNorm_s(y_i))`` predicts ``t_{i+2}``; embedding and head
+  are the model's. :func:`draft_step` runs it over a ``[B, S]`` block beside
+  the main pass (prefill, chunk rounds, every verify step), so its cache is
+  always built. The engine asks :func:`drafts_on_device` and then drives a
+  decode dispatch as a verify step of ``1 + n_mtp_blocks`` positions a row
+  that also drafts (``engine._decode_and_sample_draft``). A rejected draft's
+  latent entries (main layers and the block's) are dead by position and
+  overwritten by the next step.
+
+Not done: FP8 and the Hadamard rotation of the published selector
+(orthogonal, cancels in the product); a verify-width path through the
+selector.
 
 Every step function also returns a small float32 vector of counts
 (``STEP_AUX``): tokens through expert layers, token-expert pairs on held
-experts, and the rows' summed selected / context share with the row count;
-the engine reads it back with the sampled tokens.
+experts, and the rows' summed selected / context share with the row count
+(:func:`draft_step` adds the block's tokens and pairs); the engine reads it
+back with the sampled tokens.
 """
 
 from __future__ import annotations
@@ -56,6 +78,9 @@ from ..quantize import embed_rows, qmm
 STEP_AUX = True
 STEP_KIND = "token"  # a decode step yields one token a row (models/__init__.py)
 NEG_INF = mla.NEG_INF
+# query positions a row up to which a step is a verify step (each position's
+# heads a group of the latent kernel's rows); wider steps are chunks of queries
+_VERIFY_POSITIONS = 8
 
 
 # ----------------------------------------------------------------- rotary
@@ -136,15 +161,18 @@ def init_layer(config: DeepseekConfig, key: jax.Array,
                                H * (c.qk_nope_head_dim + c.v_head_dim)),
                         c.kv_lora_rank, dtype),
         "wo": _dense(k[4], (H * c.v_head_dim, D), H * c.v_head_dim, dtype),
-        "idx_wq_b": _dense(k[5], (c.q_lora_rank,
-                                  c.index_n_heads * c.index_head_dim),
-                           c.q_lora_rank, dtype),
-        "idx_wk": _dense(k[6], (D, c.index_head_dim), D, dtype),
-        "idx_k_norm": ones(c.index_head_dim),
-        "idx_k_bias": jnp.zeros((c.index_head_dim,), dtype=jnp.float32),
-        "idx_w": _dense(k[7], (D, c.index_n_heads), D, dtype),
         "ffn_norm": ones(D),
     }
+    if c.has_selector:
+        layer.update({
+            "idx_wq_b": _dense(k[5], (c.q_lora_rank,
+                                      c.index_n_heads * c.index_head_dim),
+                               c.q_lora_rank, dtype),
+            "idx_wk": _dense(k[6], (D, c.index_head_dim), D, dtype),
+            "idx_k_norm": ones(c.index_head_dim),
+            "idx_k_bias": jnp.zeros((c.index_head_dim,), dtype=jnp.float32),
+            "idx_w": _dense(k[7], (D, c.index_n_heads), D, dtype),
+        })
     if kind == "dense":
         F = c.ffn_hidden
         layer.update({"w1": _dense(k[8], (D, F), D, dtype),
@@ -172,13 +200,25 @@ def init_layer(config: DeepseekConfig, key: jax.Array,
 def init_trunk(config: DeepseekConfig, embed_key: jax.Array,
                head_key: jax.Array,
                dtype: jnp.dtype = jnp.bfloat16) -> dict[str, Any]:
-    return {
-        "embed": _dense(embed_key, (config.vocab_size, config.dim),
-                        config.dim, dtype),
-        "final_norm": jnp.ones((config.dim,), dtype=jnp.float32),
-        "lm_head": _dense(head_key, (config.dim, config.vocab_size),
-                          config.dim, dtype),
+    """Everything outside the layers: embedding, final norm, head and, where
+    the model has one, the multi-token-prediction block (its keys folded from
+    the head's)."""
+    D = config.dim
+    trunk = {
+        "embed": _dense(embed_key, (config.vocab_size, D), D, dtype),
+        "final_norm": jnp.ones((D,), dtype=jnp.float32),
+        "lm_head": _dense(head_key, (D, config.vocab_size), D, dtype),
     }
+    if config.n_mtp_blocks:
+        ones = jnp.ones((D,), dtype=jnp.float32)
+        trunk["mtp"] = {
+            "enorm": ones, "hnorm": ones, "norm": ones,
+            "eh_proj": _dense(jax.random.fold_in(head_key, 1), (2 * D, D),
+                              2 * D, dtype),
+            "layer": init_layer(config, jax.random.fold_in(head_key, 2),
+                                dtype, kind="experts"),
+        }
+    return trunk
 
 
 def init_keys(config: DeepseekConfig, key: jax.Array) -> jax.Array:
@@ -201,18 +241,23 @@ def params_logical(config: DeepseekConfig) -> dict[str, Any]:
     replicates: the family runs on a ``model`` axis of one device only
     (:func:`refusals`)."""
     attn = ("attn_norm", "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm",
-            "wkv_b", "wo", "idx_wq_b", "idx_wk", "idx_k_norm", "idx_k_bias",
-            "idx_w", "ffn_norm")
+            "wkv_b", "wo", "ffn_norm")
+    if config.has_selector:
+        attn += ("idx_wq_b", "idx_wk", "idx_k_norm", "idx_k_bias", "idx_w")
     ffn = {"dense": ("w1", "w3", "w2"),
            "experts": ("router", "router_bias", "w1", "w3", "w2",
                        "shared_w1", "shared_w3", "shared_w2")}
-    return {
+    layer = lambda kind: {name: "replicated" for name in attn + ffn[kind]}
+    logical = {
         "embed": "replicated", "final_norm": "replicated",
         "lm_head": "replicated",
-        "layers": [{name: "replicated"
-                    for name in attn + ffn[config.ffn_kind(i)]}
-                   for i in range(config.n_layers)],
+        "layers": [layer(config.ffn_kind(i)) for i in range(config.n_layers)],
     }
+    if config.n_mtp_blocks:
+        logical["mtp"] = {"enorm": "replicated", "hnorm": "replicated",
+                          "norm": "replicated", "eh_proj": "replicated",
+                          "layer": layer("experts")}
+    return logical
 
 
 def param_count(config: DeepseekConfig) -> int:
@@ -223,16 +268,18 @@ def param_count(config: DeepseekConfig) -> int:
             + c.q_lora_rank * H * (c.qk_nope_head_dim + c.qk_rope_head_dim)
             + D * c.latent_dim + c.kv_lora_rank
             + c.kv_lora_rank * H * (c.qk_nope_head_dim + c.v_head_dim)
-            + H * c.v_head_dim * D
-            + c.q_lora_rank * c.index_n_heads * c.index_head_dim
-            + D * c.index_head_dim + 2 * c.index_head_dim
-            + D * c.index_n_heads + 2 * D)
+            + H * c.v_head_dim * D + 2 * D)
+    if c.has_selector:
+        attn += (c.q_lora_rank * c.index_n_heads * c.index_head_dim
+                 + D * c.index_head_dim + 2 * c.index_head_dim
+                 + D * c.index_n_heads)
     dense = 3 * D * c.ffn_hidden
     experts = (D * c.n_routed_experts + c.n_routed_experts
                + (c.n_held + c.n_shared_experts) * 3 * D * c.moe_ffn_hidden)
     n_dense = min(c.n_dense_layers, c.n_layers)
+    block = c.n_mtp_blocks * (3 * D + 2 * D * D + attn + experts)
     return (2 * c.vocab_size * D + D + c.n_layers * attn
-            + n_dense * dense + (c.n_layers - n_dense) * experts)
+            + n_dense * dense + (c.n_layers - n_dense) * experts + block)
 
 
 # ------------------------------------------------ what the engine looks up
@@ -252,6 +299,12 @@ def prefill_unit(mesh, config: DeepseekConfig) -> int:
     return math.lcm(mla._INDEX_QUERY_TILE, mla._ATTN_QUERY_TILE)
 
 
+def drafts_on_device(config: DeepseekConfig) -> bool:
+    """Whether the model drafts for the verify step itself
+    (``models/__init__.py``): it does where it has the block."""
+    return config.n_mtp_blocks > 0
+
+
 def paged_impl(mesh, config: DeepseekConfig, kv: LatentKVState) -> str:
     return "pallas" if on_tpu(mesh) else "gather"
 
@@ -267,9 +320,18 @@ def refusals(config: DeepseekConfig, engine_config, mesh,
                    "have no sharding over it yet (tensor/expert parallelism "
                    "over a mesh)")
     if engine_config.spec_decode:
-        why.append("spec_decode: the verify step has no selector/latent "
-                   "path, and the multi-token-prediction draft block is not "
-                   "loaded")
+        if config.has_selector:
+            why.append("spec_decode: the selector has no verify-width path "
+                       "(its index kernel scores one position or whole query "
+                       "tiles a row)")
+        if not config.n_mtp_blocks:
+            why.append("spec_decode: the model has no multi-token-prediction "
+                       "block to draft with, and prompt-lookup drafts are "
+                       "the GQA trunk's")
+        elif engine_config.spec_k != 1 + config.n_mtp_blocks:
+            why.append(f"spec_k={engine_config.spec_k}: a verify step is the "
+                       f"last token and one draft a block, "
+                       f"{1 + config.n_mtp_blocks} positions a row")
     if engine_config.sp_impl != "none":
         why.append(f"sp_impl={engine_config.sp_impl!r}: no sequence-parallel "
                    "prefill for latent attention")
@@ -360,7 +422,8 @@ def _project(layer: dict[str, Any], config: DeepseekConfig, h: jax.Array,
     at rope positions [B, S]: absorbed queries [B, S, H, latent_dim] (scale
     folded in), the token's cache vectors latent [B, S, latent_dim] and
     index key [B, S, Di], selector queries [B, S, Hi, Di] and head weights
-    [B, S, Hi] (float32, both scales folded in)."""
+    [B, S, Hi] (float32, both scales folded in); the selector's three are
+    None for a model without one."""
     c = config
     B, S, _ = h.shape
     H, dn, dr, dc = c.n_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, \
@@ -378,6 +441,8 @@ def _project(layer: dict[str, Any], config: DeepseekConfig, h: jax.Array,
     q_abs = jnp.concatenate(
         [jnp.einsum("bshd,chd->bshc", q[..., :dn], w_uk), q_rope], axis=-1)
     q_abs = (q_abs.astype(jnp.float32) * scale).astype(h.dtype)
+    if not c.has_selector:
+        return q_abs, latent, None, None, None
 
     Hi, Di = c.index_n_heads, c.index_head_dim
     q_idx = qmm(c_q, layer["idx_wq_b"]).reshape(B, S, Hi, Di)
@@ -407,38 +472,59 @@ def _select_bias(scores: jax.Array, k: int, use_pallas: bool) -> jax.Array:
     return jnp.where(chosen, 0.0, NEG_INF).astype(jnp.float32)
 
 
+def _visible_bias(attn_pos: jax.Array, width: int) -> jax.Array:
+    """A model without a selector: [B, S] last visible positions -> additive
+    bias [B, S, width], 0 on cache positions at or before it."""
+    cache_pos = jnp.arange(width, dtype=jnp.int32)
+    return jnp.where(cache_pos <= attn_pos[..., None], 0.0,
+                     NEG_INF).astype(jnp.float32)
+
+
 def _attention(layer_idx: int, config: DeepseekConfig, q_abs, q_idx, w_idx,
                kv: LatentKVState, tables: jax.Array, attn_pos: jax.Array,
-               use_pallas: bool, dense: bool = False
+               use_pallas: bool, dense: bool = False,
+               visible: jax.Array | None = None
                ) -> tuple[jax.Array, jax.Array]:
-    """Selected-set attention of [B, S] queries over the row's pages (the
-    step's own tokens already written). attn_pos [B, S]: the last cache
+    """Attention of [B, S] queries over the row's pages (the step's own
+    tokens already written): over the selected set, or under ``visible``
+    [B, S, C] for a model without a selector. attn_pos [B, S]: the last cache
     position each query sees, -1 for none. -> (latent-space output [B, S, H,
-    kv_lora_rank], selection bias [B, S, C]). ``dense`` (tests only) skips
-    the selector: every visible token is attended."""
+    kv_lora_rank], the bias attended under [B, S, C]). ``dense`` (tests only)
+    skips the selection: every visible token is attended."""
     c = config
     B, S, H, _ = q_abs.shape
-    decode = S == 1
-    if use_pallas and not decode:
-        scores = mla.sparse_index_scores_pallas(
-            q_idx, w_idx, kv.index_pages, tables, attn_pos, layer=layer_idx)
+    if visible is not None:
+        bias = visible
     else:
-        scores = mla.index_scores_reference(
-            q_idx, w_idx, gather_pool(kv.index_pages, layer_idx, tables),
-            attn_pos)
-    if dense:
-        bias = jnp.where(scores > 0.5 * NEG_INF, 0.0, NEG_INF)
-    else:
-        bias = _select_bias(scores, c.index_topk, use_pallas)
+        if use_pallas and S > 1:
+            scores = mla.sparse_index_scores_pallas(
+                q_idx, w_idx, kv.index_pages, tables, attn_pos,
+                layer=layer_idx)
+        else:
+            scores = mla.index_scores_reference(
+                q_idx, w_idx, gather_pool(kv.index_pages, layer_idx, tables),
+                attn_pos)
+        if dense:
+            bias = jnp.where(scores > 0.5 * NEG_INF, 0.0, NEG_INF)
+        else:
+            bias = _select_bias(scores, c.index_topk, use_pallas)
     if not use_pallas:
         out = mla.mla_attention_reference(
             q_abs.transpose(0, 2, 1, 3), bias,
             gather_pool(kv.latent_pages, layer_idx, tables), c.kv_lora_rank)
         return out.transpose(0, 2, 1, 3), bias
-    if decode:      # one query's heads are the block's rows, one bias row
+    if S == 1:      # one query's heads are the block's rows, one bias row
         out = mla.mla_paged_attention_pallas(
             q_abs, bias, kv.latent_pages, tables, attn_pos, layer=layer_idx,
             value_dim=c.kv_lora_rank)                    # [B, 1, H, dc]
+        return out, bias
+    if S <= _VERIFY_POSITIONS:
+        # a verify step: each position's heads are a group of rows under the
+        # position's own bias row
+        out = mla.mla_paged_attention_pallas(
+            q_abs, bias, kv.latent_pages, tables,
+            jnp.max(attn_pos, axis=1, keepdims=True), layer=layer_idx,
+            value_dim=c.kv_lora_rank, group_bias=True)   # [B, S, H, dc]
         return out, bias
     tile = min(S, mla._ATTN_QUERY_TILE)
     max_pos = jnp.max(attn_pos.reshape(B, S // tile, tile), axis=2)
@@ -446,6 +532,41 @@ def _attention(layer_idx: int, config: DeepseekConfig, q_abs, q_idx, w_idx,
         q_abs.transpose(0, 2, 1, 3), bias, kv.latent_pages, tables, max_pos,
         layer=layer_idx, value_dim=c.kv_lora_rank)       # [B, H, S, dc]
     return out.transpose(0, 2, 1, 3), bias
+
+
+def _layer(cache_idx: int, layer: dict[str, Any], config: DeepseekConfig,
+           x: jax.Array, rope_pos: jax.Array, attn_pos: jax.Array,
+           live: jax.Array, write_valid: jax.Array, kv: LatentKVState,
+           slot_ids: jax.Array, tables: jax.Array, visible: jax.Array | None,
+           use_pallas: bool, mesh, dense_attention: bool = False):
+    """One decoder layer over x [B, S, D], its cache vectors written to cache
+    layer ``cache_idx`` first. ``live`` [B, S]: ``attn_pos >= 0``;
+    ``visible``: the bias of a model without a selector (the same in every
+    layer). -> (x, kv, the bias it attended
+    under, pairs on held experts: None for a dense layer)."""
+    c = config
+    h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+    q_abs, latent, k_idx, q_idx, w_idx = _project(layer, c, h, rope_pos)
+    kv = write_latent_kv(kv, cache_idx, latent, k_idx, slot_ids, rope_pos,
+                         write_valid)
+    out, bias = _attention(cache_idx, c, q_abs, q_idx, w_idx, kv, tables,
+                           attn_pos, use_pallas, dense_attention, visible)
+    w_uv = layer["wkv_b"].reshape(
+        c.kv_lora_rank, c.n_heads,
+        c.qk_nope_head_dim + c.v_head_dim)[..., c.qk_nope_head_dim:]
+    heads = jnp.einsum("bshc,chd->bshd", out.astype(x.dtype), w_uv)
+    x = x + qmm(heads.reshape(*heads.shape[:2], -1), layer["wo"])
+    h = rms_norm(x, layer["ffn_norm"], c.norm_eps)
+    if "router" in layer:
+        y, pairs = _expert_ffn(layer, c, h, live, mesh)
+        return x + y, kv, bias, pairs
+    return x + _ffn(layer, h), kv, bias, None
+
+
+def _row_tables(kv: LatentKVState, slot_ids: jax.Array,
+                ctx_pages: int | None) -> jax.Array:
+    tables = kv.block_tables[slot_ids]
+    return tables if ctx_pages is None else tables[:, :ctx_pages]
 
 
 def _trunk(params: dict[str, Any], config: DeepseekConfig, tokens: jax.Array,
@@ -456,40 +577,70 @@ def _trunk(params: dict[str, Any], config: DeepseekConfig, tokens: jax.Array,
     """Every layer over a [B, S] block of tokens. rope_pos: positions the
     rotary and the cache write use; attn_pos: the last cache position each
     token attends to (-1: a padding or idle row); write_valid: tokens whose
-    cache vectors are kept. -> (final-normed hidden [B, S, D], kv, aux)."""
+    cache vectors are kept. -> (the last layer's hidden [B, S, D] BEFORE the
+    final norm, kv, aux)."""
     c = config
     x = embed_rows(params["embed"], tokens)
-    tables = kv.block_tables[slot_ids]
-    if ctx_pages is not None:
-        tables = tables[:, :ctx_pages]
+    tables = _row_tables(kv, slot_ids, ctx_pages)
     live = attn_pos >= 0
     rows = jnp.sum(live.astype(jnp.float32))
     pairs = jnp.zeros((), jnp.float32)
-    bias = None
+    visible = None if c.has_selector else _visible_bias(
+        attn_pos, tables.shape[1] * kv.page_size)
+    bias = visible
     for idx, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
-        q_abs, latent, k_idx, q_idx, w_idx = _project(layer, c, h, rope_pos)
-        kv = write_latent_kv(kv, idx, latent, k_idx, slot_ids, rope_pos,
-                             write_valid)
-        out, bias = _attention(idx, c, q_abs, q_idx, w_idx, kv, tables,
-                               attn_pos, use_pallas, dense_attention)
-        w_uv = layer["wkv_b"].reshape(
-            c.kv_lora_rank, c.n_heads,
-            c.qk_nope_head_dim + c.v_head_dim)[..., c.qk_nope_head_dim:]
-        heads = jnp.einsum("bshc,chd->bshd", out.astype(x.dtype), w_uv)
-        x = x + qmm(heads.reshape(*heads.shape[:2], -1), layer["wo"])
-        h = rms_norm(x, layer["ffn_norm"], c.norm_eps)
-        if "router" in layer:
-            y, layer_pairs = _expert_ffn(layer, c, h, live, mesh)
-            x, pairs = x + y, pairs + layer_pairs
-        else:
-            x = x + _ffn(layer, h)
+        x, kv, bias, layer_pairs = _layer(
+            idx, layer, c, x, rope_pos, attn_pos, live, write_valid, kv,
+            slot_ids, tables, visible, use_pallas, mesh, dense_attention)
+        if layer_pairs is not None:
+            pairs = pairs + layer_pairs
     # the rows' selected / context share, from the last layer's selection
+    # (1 a live row where nothing selects)
     selected = jnp.sum((bias > 0.5 * NEG_INF).astype(jnp.float32), axis=-1)
     share = jnp.where(live, selected / jnp.maximum(attn_pos + 1, 1), 0.0)
     n_expert_layers = sum("router" in layer for layer in params["layers"])
     aux = jnp.stack([rows * n_expert_layers, pairs, jnp.sum(share), rows])
-    return rms_norm(x, params["final_norm"], c.norm_eps), kv, aux
+    return x, kv, aux
+
+
+def draft_step(params: dict[str, Any], config: DeepseekConfig,
+               hidden: jax.Array, next_tokens: jax.Array,
+               positions: jax.Array, kv: LatentKVState, slot_ids: jax.Array,
+               aux: jax.Array, ctx_pages: int | None = None,
+               pick: jax.Array | None = None, paged_impl: str = "gather",
+               mesh=None) -> tuple[jax.Array, LatentKVState, jax.Array]:
+    """The multi-token-prediction block over a [B, S] block beside the main
+    pass that gave ``hidden`` [B, S, D] (its last layer's output BEFORE the
+    final norm, ``hidden=True``): ``next_tokens`` [B, S] the token that
+    follows each position, ``positions`` absolute (-1 = padding) as the main
+    pass had them, ``ctx_pages`` its context width (None: the pages the block
+    itself spans, beside a dense prefill). Writes the block's own latent
+    entries at those positions and returns (draft logits [B, V] of row
+    ``pick`` [B] of each sequence, or [B, S, V] of all, each predicting the
+    token after ``next_tokens``; kv; ``aux`` with the block's expert tokens
+    and pairs added)."""
+    c, block = config, params["mtp"]
+    valid = positions >= 0
+    if ctx_pages is None:
+        ctx_pages = _prefill_pages(hidden.shape[1], kv)
+    x = jnp.concatenate(
+        [rms_norm(embed_rows(params["embed"], next_tokens), block["enorm"],
+                  c.norm_eps),
+         rms_norm(hidden, block["hnorm"], c.norm_eps)], axis=-1)
+    x = qmm(x, block["eh_proj"])
+    tables = _row_tables(kv, slot_ids, ctx_pages)
+    visible = None if c.has_selector else _visible_bias(
+        positions, tables.shape[1] * kv.page_size)
+    y, kv, _, pairs = _layer(
+        c.n_layers, block["layer"], c, x, jnp.maximum(positions, 0),
+        positions, valid, valid, kv, slot_ids, tables, visible,
+        paged_impl == "pallas", mesh)
+    if pick is not None:
+        y = y[jnp.arange(y.shape[0]), pick]
+    rows = jnp.sum(valid.astype(jnp.float32))
+    aux = aux.at[:2].add(jnp.stack([rows, pairs]))
+    return (lm_logits(params, rms_norm(y, block["norm"], c.norm_eps)), kv,
+            aux)
 
 
 def prefill_with_history(params: dict[str, Any], config: DeepseekConfig,
@@ -498,34 +649,42 @@ def prefill_with_history(params: dict[str, Any], config: DeepseekConfig,
                          ctx_pages: int | None = None,
                          last_idx: jax.Array | None = None,
                          paged_impl: str = "gather", mesh=None,
-                         dense_attention: bool = False
+                         dense_attention: bool = False, hidden: bool = False
                          ) -> tuple[jax.Array, LatentKVState, jax.Array]:
     """A [B, S] block of prompt tokens at ABSOLUTE positions (-1 = padding)
     over whatever the rows' pages already hold: dense prefill (history 0),
-    prefix-cache suffixes and chunk rounds alike. Arguments as
-    ``models.llama.prefill_with_history``. -> (logits, kv, aux)."""
+    prefix-cache suffixes, chunk rounds and verify steps alike. Arguments as
+    ``models.llama.prefill_with_history``. -> (logits, kv, aux), and with
+    ``hidden`` the last layer's output [B, S, D] before the final norm beside
+    them (what :func:`draft_step` takes)."""
     valid = positions >= 0
-    x, kv, aux = _trunk(params, config, tokens, jnp.maximum(positions, 0),
+    h, kv, aux = _trunk(params, config, tokens, jnp.maximum(positions, 0),
                         positions, valid, kv, slot_ids, ctx_pages,
                         paged_impl == "pallas", mesh, dense_attention)
+    x = rms_norm(h, params["final_norm"], config.norm_eps)
     if last_idx is not None:
         x = x[jnp.arange(x.shape[0]), last_idx]
-    return lm_logits(params, x), kv, aux
+    out = (lm_logits(params, x), kv, aux)
+    return (*out, h) if hidden else out
 
 
 def prefill(params: dict[str, Any], config: DeepseekConfig, tokens: jax.Array,
             positions: jax.Array, kv: LatentKVState, slot_ids: jax.Array,
             attn_impl: str = "gather", mesh=None,
-            last_idx: jax.Array | None = None
+            last_idx: jax.Array | None = None, hidden: bool = False
             ) -> tuple[jax.Array, LatentKVState, jax.Array]:
     """A prompt inside one bucket: the history path with no history, reading
     back the pages it just wrote (the cache IS the attention's operand in the
     absorbed form), over as many pages as the bucket spans."""
-    pages = -(-tokens.shape[1] // kv.page_size)
     return prefill_with_history(
         params, config, tokens, positions, kv, slot_ids,
-        ctx_pages=min(pages, kv.block_tables.shape[1]), last_idx=last_idx,
-        paged_impl=attn_impl, mesh=mesh)
+        ctx_pages=_prefill_pages(tokens.shape[1], kv), last_idx=last_idx,
+        paged_impl=attn_impl, mesh=mesh, hidden=hidden)
+
+
+def _prefill_pages(length: int, kv: LatentKVState) -> int:
+    """Pages a dense prefill of ``length`` positions attends over."""
+    return min(-(-length // kv.page_size), kv.block_tables.shape[1])
 
 
 def decode_step(params: dict[str, Any], config: DeepseekConfig,
@@ -542,4 +701,5 @@ def decode_step(params: dict[str, Any], config: DeepseekConfig,
     x, kv, aux = _trunk(params, config, tokens[:, None], positions[:, None],
                         (seq_lens - 1)[:, None], valid[:, None], kv, slot_ids,
                         ctx_pages, paged_impl == "pallas", mesh)
+    x = rms_norm(x, params["final_norm"], config.norm_eps)
     return lm_logits(params, x[:, 0]), kv, aux

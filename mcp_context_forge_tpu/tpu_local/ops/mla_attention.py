@@ -50,8 +50,10 @@ second per-token key for the selector. Three kernels serve a step:
     block-table width (any width is served; a prime one a page a step).
 
 One body serves chunk rounds (a block is some heads x a tile of queries, the
-bias a row a query) and decode (a block is one query's heads, the bias one row
-for all of them).
+bias a row a query), decode (a block is one query's heads, the bias one row
+for all of them) and verify steps (``group_bias``: a block is the heads of
+each of a row's K query positions, K groups of head rows, the bias one row a
+POSITION: each draft position has its own visibility, its heads share it).
 """
 
 from __future__ import annotations
@@ -259,9 +261,10 @@ def _block_entries(block_tables, max_pos, n: int, page_size: int):
 
 
 def _mla_kernel(entries_ref, max_pos_ref, q_ref, bias_ref, *rest,
-                page_size: int, value_dim: int):
+                page_size: int, value_dim: int, group_bias: bool):
     """Refs: q/o [G, R, Dk] / [G, R, value_dim]; bias [Rb, N * page] f32 (Rb
-    in (1, R)); N kv refs [1, 1, page, Dk], the block's pages in table order;
+    in (1, R), a row a query row, or with ``group_bias`` [G, N * page], a row
+    a group); N kv refs [1, 1, page, Dk], the block's pages in table order;
     scratch acc [G, R, value_dim], m/l [G, R, 1] f32."""
     *kv_refs, o_ref, acc_ref, m_ref, l_ref = rest
     b, r, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
@@ -282,7 +285,8 @@ def _mla_kernel(entries_ref, max_pos_ref, q_ref, bias_ref, *rest,
         scores = jax.lax.dot_general(                  # [G * R, N * page]
             q_ref[...].reshape(n_groups * n_rows, -1), kv,
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        scores = scores.reshape(n_groups, n_rows, block) + bias_ref[...][None]
+        scores = scores.reshape(n_groups, n_rows, block) + (
+            bias_ref[...][:, None, :] if group_bias else bias_ref[...][None])
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
@@ -306,15 +310,19 @@ def _mla_kernel(entries_ref, max_pos_ref, q_ref, bias_ref, *rest,
 
 
 @functools.partial(jax.jit, static_argnames=("layer", "value_dim",
-                                             "interpret"))
+                                             "interpret", "group_bias"))
 def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
                                latent_pages: jax.Array,
                                block_tables: jax.Array, max_pos: jax.Array,
                                layer: int = 0, value_dim: int = 512,
-                               interpret: bool = False) -> jax.Array:
+                               interpret: bool = False,
+                               group_bias: bool = False) -> jax.Array:
     """q: [B, G, R, Dk] absorbed queries, scale folded in; bias: [B, Rb, P *
     page] float32, 0 where a row attends and NEG_INF elsewhere (so NEG_INF
-    past the row's last position), Rb = R (a row each) or 1 (one row for all);
+    past the row's last position), Rb = R (a row each) or 1 (one row for all),
+    or with ``group_bias`` [B, G, P * page]: one row a GROUP, shared by the
+    group's R rows (a verify step: G query positions of R heads; G must fit
+    one block, R one tile);
     latent_pages: [L, N, page, Dk]; block_tables: [B, P]; max_pos: [B, R //
     row tile] int32, the last position any row of the tile attends to (blocks
     past it are skipped, pages past it inside its block not fetched)
@@ -329,6 +337,12 @@ def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
                          f"[{groups}, {rows}]")
     shared = bias.shape[1] == 1 and R > 1
     bias_rows = 1 if shared else rows
+    if group_bias:
+        if G != groups or R != rows or bias.shape[1] != G:
+            raise ValueError(f"a bias row a group needs [{G}, {R}] queries in "
+                             f"one block and {G} bias rows, got "
+                             f"{bias.shape[1]}")
+        shared, bias_rows = True, G
     n = _kv_block_pages(n_pages, rows, page_size)
     n_tiles = R // rows
 
@@ -338,7 +352,7 @@ def mla_paged_attention_pallas(q: jax.Array, bias: jax.Array,
 
     return pl.pallas_call(
         functools.partial(_mla_kernel, page_size=page_size,
-                          value_dim=value_dim),
+                          value_dim=value_dim, group_bias=group_bias),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, G // groups, n_tiles, n_pages // n),

@@ -121,6 +121,19 @@ def _mla_attention_case(chunk: int | None, table: int = DS_TABLE):
                 ((B, chunk // mla._ATTN_QUERY_TILE), jnp.int32)]
 
 
+def _mla_verify_case(table: int):
+    """joyai-llm-flash-d5-ep4.reason-closed: a verify step of 32 rows x 2
+    query positions x 32 heads over a context bucket of ``table`` pages, a
+    bias row a POSITION (``group_bias``), six layers of 2304 latent pages."""
+    B, K, H = 32, 2, 32
+    fn = partial(mla.mla_paged_attention_pallas, layer=5, value_dim=DS_VALUE,
+                 group_bias=True)
+    return fn, [((B, K, H, DS_LATENT), jnp.bfloat16),
+                ((B, K, table * PAGE), jnp.float32),
+                ((6, 2304, PAGE, DS_LATENT), jnp.bfloat16),
+                ((B, table), jnp.int32), ((B, 1), jnp.int32)]
+
+
 def _index_case(S: int = 1024):
     B = 2
     return (partial(mla.sparse_index_scores_pallas, layer=3),
@@ -286,6 +299,10 @@ KERNEL_CASES = {
     "gated_delta_chunk_half_1x256": lambda: _gated_delta_case(1, 256),
     "grouped_moe_int8_sdar_half_1x256_b16": lambda: _sdar_moe_case(256, 16),
     "mla_attention_half_chunk_2x512x8": lambda: _mla_attention_case(512, 8),
+    # the latent kernel at verify width (PR 41): the narrowest and the widest
+    # context bucket of the cell
+    "mla_attention_verify_32x2x4": lambda: _mla_verify_case(4),
+    "mla_attention_verify_32x2x64": lambda: _mla_verify_case(64),
     "sparse_index_half_chunk_2x512x128": lambda: _index_case(512),
 }
 
