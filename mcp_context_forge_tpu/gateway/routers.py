@@ -825,6 +825,10 @@ document.getElementById("f").onsubmit = async (e) => {
             "kv_pages_free": alloc.free_pages,
             "kv_quant": engine.config.kv_quant or "off",
             "kv_bytes_in_use": engine.kv_bytes_in_use(),
+            # the whole pool: by the elements its family declares a token,
+            # and as the arrays are stored (a vector padded to whole lanes)
+            "kv_bytes_capacity": engine.kv_bytes_capacity(),
+            "kv_bytes_resident": engine.kv_bytes_resident(),
             # per-sequence recurrent state beside the pages (0 for a family
             # whose cache grows a token only)
             "state_rows_in_use": alloc.rows_in_use,

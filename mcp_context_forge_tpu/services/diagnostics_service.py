@@ -110,6 +110,7 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
             "quant": engine.config.kv_quant or "off",
             "bytes_in_use": engine.kv_bytes_in_use(),
             "bytes_capacity": engine.kv_bytes_capacity(),
+            "bytes_resident": engine.kv_bytes_resident(),
         },
         "steps": engine.recent_steps(limit),
     }

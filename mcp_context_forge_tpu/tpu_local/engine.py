@@ -46,7 +46,7 @@ from ..observability.timeline import (STALL_S, StepCounts, StepTimeline,
                                       gc_watch)
 from .compile_events import (CompileTracker, install_listener,
                              restore_thread, track_thread)
-from .kv import PageAllocator
+from .kv import PageAllocator, kv_resident_bytes
 from .models import MODEL_CONFIGS, LlamaConfig
 from .models import family_of
 from .parallel import make_mesh, param_specs
@@ -1079,6 +1079,9 @@ class TPUEngine:
                 dtype=self._kv_dtype, quant=config.kv_quant),
                 out_shardings=kv_shardings)
             self.kv = kv_init()
+        logger.info("tpu_local: kv pool of %d pages: %d bytes declared, %d "
+                    "resident", self.num_kv_pages, self.kv_bytes_capacity(),
+                    self.kv_bytes_resident())
         if self._tier_client is not None:
             # a rebuilt pool (crash restart, reload) invalidates every
             # resident page — stale HBM locations in the pool index would
@@ -4225,8 +4228,15 @@ class TPUEngine:
         return out
 
     def kv_bytes_capacity(self) -> int:
-        """HBM bytes the whole KV pool occupies (fixed at construction)."""
+        """HBM bytes of the whole KV pool by the elements its family DECLARES
+        a token (fixed at construction; ``kv_page_bytes``)."""
         return self.num_kv_pages * self._kv_page_bytes
+
+    def kv_bytes_resident(self) -> int:
+        """HBM bytes the pool's arrays hold as STORED: more than the declared
+        figure where a vector is padded to whole lanes (the latent family's
+        576 -> 640, 11 %)."""
+        return kv_resident_bytes(self.kv)
 
     def state_bytes_in_use(self) -> int:
         """HBM bytes of the per-sequence pools the live rows occupy."""

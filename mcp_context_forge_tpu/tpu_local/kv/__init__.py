@@ -8,6 +8,9 @@ from .paged_cache import (
     kv_state_bytes,
     PoolSpec,
     kv_pools,
+    stored_width,
+    lane_padded,
+    kv_resident_bytes,
     write_latent_kv,
     gather_pool,
     PageAllocator,
@@ -24,7 +27,8 @@ from .prefix_index import PrefixIndex, chain_hash, chain_hashes
 from .tiers import SpilledPage, TierClient, TieredPageStore
 
 __all__ = ["PagedKVState", "LatentKVState", "HybridKVState", "PoolSpec",
-           "kv_pools", "state_rows_for", "kv_state_bytes",
+           "kv_pools", "stored_width", "lane_padded", "kv_resident_bytes",
+           "state_rows_for", "kv_state_bytes",
            "write_latent_kv", "gather_pool", "PageAllocator", "PrefixEvictionPolicy",
            "init_kv_state", "kv_page_bytes",
            "num_pages_for_budget", "write_prefill_kv", "write_decode_kv",

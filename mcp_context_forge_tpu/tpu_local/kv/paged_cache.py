@@ -17,6 +17,16 @@ with every rule of this docstring); the latent family keeps ``latent``
 vector each (``LatentKVState``). Both ride the ONE block table and the ONE
 ``PageAllocator``, which deals in page ids and knows nothing of the pools.
 
+A family DECLARES what a token's vector holds; the latent family's pools
+STORE it padded with zeros to whole 128-lane tiles (``stored_width``: 576 ->
+640, 128 -> 128), because that is what the chip's tiled layout occupies
+anyway, and with the padding explicit the pool's parameter layout, the
+scatter that writes it and the kernel that reads it agree: at 576 every
+step program relayouted the WHOLE pool on its way in and again on its way
+out. A new pool gets this by declaring its vector, not by asking: its writer
+pads with ``lane_padded`` and its queries carry the same zero tail, so every
+dot product is the same sum plus zeros.
+
 A pool also declares WHICH layers hold it and whether it grows a token or is
 a fixed size a sequence (``PoolSpec.layers`` / ``.per``). The hybrid family
 (``HybridKVState``) keeps ``k`` and ``v`` pages in its full-attention layers
@@ -107,6 +117,20 @@ class PoolSpec(NamedTuple):
 
 
 AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig
+LANES = 128     # the minor dimension of the chip's tiles
+
+
+def stored_width(width: int) -> int:
+    """Elements a per-token vector of ``width`` declared elements is STORED
+    in: whole lane tiles (module docstring)."""
+    return -(-width // LANES) * LANES
+
+
+def lane_padded(x: jax.Array) -> jax.Array:
+    """x [..., d] -> [..., stored_width(d)]: the zero tail appended (``x``
+    itself where d is whole tiles)."""
+    tail = stored_width(x.shape[-1]) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, tail)]) if tail else x
 
 
 def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
@@ -152,10 +176,12 @@ class LatentKVState(NamedTuple):
     """Device state of the latent family: one attention vector and, where the
     model has a selector, one selector key a token a layer, shared by all
     heads (nothing to shard over ``model``; full precision only). L counts
-    the model's layers and its multi-token-prediction block's."""
+    the model's layers and its multi-token-prediction block's. The minor
+    dimension is the STORED width of the declared vector (``stored_width``):
+    zeros past ``kv_lora_rank + rope`` / ``index_head_dim``."""
 
-    latent_pages: jax.Array   # [L, num_pages, page_size, kv_lora_rank + rope]
-    index_pages: jax.Array | None   # [L, num_pages, page_size, index_head_dim]
+    latent_pages: jax.Array   # [L, num_pages, page_size, stored latent_dim]
+    index_pages: jax.Array | None   # [L, num_pages, page_size, stored index dim]
     block_tables: jax.Array   # [slots, max_pages_per_slot] int32
 
     @property
@@ -255,8 +281,9 @@ def init_kv_state(config: AnyConfig, num_pages: int, page_size: int,
                              jnp.zeros((max_slots,), dtype=jnp.int32))
     if _latent_only(config, quant):
         latent, *index_key = (
-            jnp.zeros((pool.layers, num_pages, page_size, *pool.shape),
-                      dtype=dtype) for pool in kv_pools(config))
+            jnp.zeros((pool.layers, num_pages, page_size,
+                       stored_width(*pool.shape)), dtype=dtype)
+            for pool in kv_pools(config))
         return LatentKVState(latent, index_key[0] if index_key else None,
                              tables)
     shape = (config.n_layers, num_pages, page_size, config.n_kv_heads,
@@ -281,7 +308,11 @@ def kv_page_bytes(config: AnyConfig, page_size: int,
                   dtype: jnp.dtype = jnp.bfloat16, quant: str = "") -> int:
     """HBM bytes ONE page (every per-token pool the family declares, each
     over the layers that hold it) costs under a storage mode — the unit
-    _init_kv's byte-denominated budget divides by."""
+    _init_kv's byte-denominated budget divides by. It counts DECLARED
+    elements. The chip's tiled layout pads a pool's minor dimension to whole
+    lanes whether the stored shape says so (the latent family's:
+    ``stored_width``) or not, so what a pool occupies is ``kv_resident_bytes``
+    of the built state, 11 % more for a 576-wide vector."""
     _full_precision_only(config, quant)
     _latent_only(config, quant)
     elems = page_size * sum(
@@ -292,6 +323,13 @@ def kv_page_bytes(config: AnyConfig, page_size: int,
                        * jnp.dtype(dtype).itemsize)
         return elems + scale_bytes  # int8 values + per-(page, head) scales
     return elems * jnp.dtype(dtype).itemsize
+
+
+def kv_resident_bytes(kv) -> int:
+    """HBM bytes the per-token arrays of a built state hold as STORED (the
+    arrays' own sizes: pages and their scales, whatever the family)."""
+    return sum(a.size * a.dtype.itemsize for name, a in kv._asdict().items()
+               if a is not None and name.endswith(("_pages", "_scales")))
 
 
 def num_pages_for_budget(config: AnyConfig, page_size: int,
@@ -481,8 +519,9 @@ def write_latent_kv(kv: LatentKVState, layer: int, latent: jax.Array,
     """Scatter tokens' latent vectors and selector keys into their pages: a
     [B, S] block (prefill, chunk rounds; ``valid`` [B, S]) or one token a slot
     (decode; positions and ``valid`` [B], False rows MUST be masked for the
-    reason ``write_decode_kv`` gives). latent: [..., latent_dim]; index_key:
-    [..., index_head_dim], None for a model without a selector (no pool)."""
+    reason ``write_decode_kv`` gives). latent, index_key: [..., the pool's
+    stored width] (``lane_padded``); index_key None for a model without a
+    selector (no pool)."""
     pages, offset = _token_pages(kv, slot_ids, positions, valid)
     pages, offset = pages.reshape(-1), offset.reshape(-1)
     flat = lambda a, pool: a.reshape(-1, a.shape[-1]).astype(pool.dtype)

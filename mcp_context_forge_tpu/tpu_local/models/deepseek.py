@@ -9,7 +9,8 @@ Per layer, pre-norm residual: ``x += MLA(RMSNorm(x))``, ``x += FFN(RMSNorm(x))``
 - **Latent attention (MLA).** ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` is
   ``(q_nope, q_rope)`` a head; ``(c_raw, k_raw) = x W_kva``, ``c =
   RMSNorm(c_raw)``, ``k_rope = RoPE(k_raw)`` ONE for all heads. The cache holds
-  ``c || k_rope`` a token a layer (``kv/paged_cache.py: LatentKVState``).
+  ``c || k_rope`` a token a layer, stored padded with zeros to whole lanes
+  (``kv/paged_cache.py: LatentKVState``, ``stored_width``).
   Every step runs the ABSORBED form: ``q~ = q_nope W_uk^T`` so a score is one
   dot product with the cached vector, and ``W_uv`` applies after the weighted
   sum of latents. ``scale = (d_nope + d_rope)^-0.5 * mscale^2`` (YaRN).
@@ -70,7 +71,8 @@ import numpy as np
 from .configs import DeepseekConfig
 from .llama import _dense, _ffn, lm_logits, rms_norm
 from ..kv.paged_cache import (LatentKVState, gather_pool, init_kv_state,  # noqa: F401 (family names)
-                              kv_logical, kv_page_bytes, write_latent_kv)
+                              kv_logical, kv_page_bytes, lane_padded,
+                              write_latent_kv)
 from ..ops import mla_attention as mla
 from ..ops.attention import on_tpu
 from ..quantize import embed_rows, qmm
@@ -419,11 +421,13 @@ def _expert_ffn(layer: dict[str, Any], config: DeepseekConfig, x: jax.Array,
 def _project(layer: dict[str, Any], config: DeepseekConfig, h: jax.Array,
              positions: jax.Array):
     """The attention block's projections of normed hidden states h [B, S, D]
-    at rope positions [B, S]: absorbed queries [B, S, H, latent_dim] (scale
-    folded in), the token's cache vectors latent [B, S, latent_dim] and
-    index key [B, S, Di], selector queries [B, S, Hi, Di] and head weights
-    [B, S, Hi] (float32, both scales folded in); the selector's three are
-    None for a model without one."""
+    at rope positions [B, S]: absorbed queries [B, S, H, Dk] (scale folded
+    in), the token's cache vectors latent [B, S, Dk] and index key [B, S,
+    Di], selector queries [B, S, Hi, Di] and head weights [B, S, Hi]
+    (float32, both scales folded in); the selector's three are None for a
+    model without one. Dk and Di are the widths the cache STORES
+    (``lane_padded`` of ``latent_dim`` and ``index_head_dim``): the same zero
+    tail on keys and on queries, so a score is the same sum plus zeros."""
     c = config
     B, S, _ = h.shape
     H, dn, dr, dc = c.n_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, \
@@ -433,25 +437,25 @@ def _project(layer: dict[str, Any], config: DeepseekConfig, h: jax.Array,
     q = qmm(c_q, layer["wq_b"]).reshape(B, S, H, dn + dr)
     q_rope = _rope(q[..., dn:], positions, inv_freq)
     kv_a = qmm(h, layer["wkv_a"])
-    latent = jnp.concatenate(
+    latent = lane_padded(jnp.concatenate(
         [rms_norm(kv_a[..., :dc], layer["kv_norm"], c.norm_eps),
-         _rope(kv_a[..., dc:], positions, inv_freq)], axis=-1)
+         _rope(kv_a[..., dc:], positions, inv_freq)], axis=-1))
     w_uk = layer["wkv_b"].reshape(dc, H, dn + c.v_head_dim)[..., :dn]
     scale = softmax_scale(c)
     q_abs = jnp.concatenate(
         [jnp.einsum("bshd,chd->bshc", q[..., :dn], w_uk), q_rope], axis=-1)
-    q_abs = (q_abs.astype(jnp.float32) * scale).astype(h.dtype)
+    q_abs = lane_padded((q_abs.astype(jnp.float32) * scale).astype(h.dtype))
     if not c.has_selector:
         return q_abs, latent, None, None, None
 
     Hi, Di = c.index_n_heads, c.index_head_dim
     q_idx = qmm(c_q, layer["idx_wq_b"]).reshape(B, S, Hi, Di)
-    q_idx = jnp.concatenate([_rope(q_idx[..., :dr], positions, inv_freq),
-                             q_idx[..., dr:]], axis=-1)
+    q_idx = lane_padded(jnp.concatenate(
+        [_rope(q_idx[..., :dr], positions, inv_freq), q_idx[..., dr:]], axis=-1))
     k_idx = _layer_norm(qmm(h, layer["idx_wk"]), layer["idx_k_norm"],
                         layer["idx_k_bias"], c.norm_eps)
-    k_idx = jnp.concatenate([_rope(k_idx[..., :dr], positions, inv_freq),
-                             k_idx[..., dr:]], axis=-1)
+    k_idx = lane_padded(jnp.concatenate(
+        [_rope(k_idx[..., :dr], positions, inv_freq), k_idx[..., dr:]], axis=-1))
     w_idx = (qmm(h, layer["idx_w"]).astype(jnp.float32)
              * (Hi ** -0.5 * Di ** -0.5))
     return q_abs, latent, k_idx, q_idx, w_idx

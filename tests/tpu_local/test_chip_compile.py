@@ -15,6 +15,7 @@ and the check that the script refuses to run without a TPU.
 import asyncio
 import json
 import os
+import re
 import subprocess
 import sys
 from functools import partial
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from mcp_context_forge_tpu.tpu_local.kv import (LatentKVState, stored_width,
+                                                write_latent_kv)
 from mcp_context_forge_tpu.tpu_local.ops import attention, gated_delta, grouped_moe
 from mcp_context_forge_tpu.tpu_local.ops import mla_attention as mla
 from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
@@ -98,9 +101,11 @@ def _moe_case(quantized: bool, n_blocks: int = 2048 * 2 // BLOCK + E,
 
 
 # deepseek-v3.2-d5-ep16.longctx-closed: 5 layers, 1152 pages of 128, latent
-# 512 + 64, selector 64 heads x 128, top-2048; chunk rounds of 2 x 1024 and
-# decode steps of 8 rows over the full 128-page table; 16 held experts
-DS_LAYERS, DS_PAGES, DS_TABLE, DS_LATENT, DS_VALUE = 5, 1152, 128, 576, 512
+# 512 + 64 stored in 640 lanes (kv.stored_width), selector 64 heads x 128,
+# top-2048; chunk rounds of 2 x 1024 and decode steps of 8 rows over the full
+# 128-page table; 16 held experts
+DS_LAYERS, DS_PAGES, DS_TABLE, DS_LATENT, DS_VALUE = 5, 1152, 128, 640, 512
+DS_DECLARED = 576
 DS_HEADS, DS_IDX_HEADS, DS_IDX_DIM, DS_TOPK = 128, 64, 128, 2048
 
 
@@ -124,7 +129,8 @@ def _mla_attention_case(chunk: int | None, table: int = DS_TABLE):
 def _mla_verify_case(table: int):
     """joyai-llm-flash-d5-ep4.reason-closed: a verify step of 32 rows x 2
     query positions x 32 heads over a context bucket of ``table`` pages, a
-    bias row a POSITION (``group_bias``), six layers of 2304 latent pages."""
+    bias row a POSITION (``group_bias``), six layers of 2304 latent pages
+    (stored width)."""
     B, K, H = 32, 2, 32
     fn = partial(mla.mla_paged_attention_pallas, layer=5, value_dim=DS_VALUE,
                  group_bias=True)
@@ -317,6 +323,84 @@ def test_kernel_compiles_for_v5e(v5e, case):
     assert not jax.config.jax_enable_compilation_cache
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _latent_step(kv, q, latent, index_key, bias, slots, positions, max_pos,
+                 *, group_bias: bool):
+    """What every latent step program does to the pool, layer after layer:
+    the tokens' vectors scattered into their pages (``write_latent_kv``), then
+    the latent kernel over the pool; the pool is donated and returned."""
+    tables = kv.block_tables[slots]
+    total = jnp.zeros((), jnp.float32)
+    for layer in range(kv.latent_pages.shape[0]):
+        kv = write_latent_kv(kv, layer, latent, index_key, slots, positions)
+        out = mla.mla_paged_attention_pallas(
+            q, bias, kv.latent_pages, tables, max_pos, layer=layer,
+            value_dim=DS_VALUE, group_bias=group_bias)
+        total = total + jnp.sum(out.astype(jnp.float32))
+        q = q + total.astype(q.dtype)       # the next layer waits for this one
+    return kv, total
+
+
+def _latent_step_case(cell: str, step: str, width: int, spec):
+    """(pool shape, arguments of ``_latent_step`` as ``spec(shape, dtype)``,
+    group_bias) at a cell's shapes: ``deepseek`` decode steps of 8 rows and
+    chunk rounds of 2 x 1024 over [5, 1152, 128, width] with the index pool
+    beside it, 128 heads, the 128-page table; ``joyai`` verify steps of 32
+    rows x 2 positions x 32 heads and chunk rounds of 2 x 1024 over
+    [6, 2304, 128, width], the 64-page table."""
+    layers, pages, heads, table, slots, idx = {
+        "deepseek": (DS_LAYERS, DS_PAGES, DS_HEADS, DS_TABLE, 8, DS_IDX_DIM),
+        "joyai": (6, 2304, 32, 64, 32, None)}[cell]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    B, S = {"decode": (8, 1), "verify": (32, 2), "chunk": (2, 1024)}[step]
+    tokens = (B,) if step == "decode" else (B, S)
+    # decode and verify: a position's heads are the block's rows; a chunk
+    # round: some heads x a tile of queries
+    q = (B, heads, S, width) if step == "chunk" else (B, S, heads, width)
+    tiles = S // mla._ATTN_QUERY_TILE if step == "chunk" else 1
+    pool = (layers, pages, PAGE, width)
+    kv = LatentKVState(
+        spec(pool, bf16), spec((layers, pages, PAGE, idx), bf16) if idx else None,
+        spec((slots, table), i32))
+    args = [kv, spec(q, bf16), spec((*tokens, width), bf16),
+            spec((*tokens, idx), bf16) if idx else None,
+            spec((B, S, table * PAGE), jnp.float32), spec((B,), i32),
+            spec(tokens, i32), spec((B, tiles), i32)]
+    return pool, args, step == "verify"
+
+
+LATENT_STEP_CASES = [("deepseek", "decode", DS_LATENT),
+                     ("deepseek", "chunk", DS_LATENT),
+                     ("joyai", "verify", DS_LATENT),
+                     ("joyai", "chunk", DS_LATENT),
+                     # the fault this guards against: the DECLARED width
+                     ("joyai", "verify", DS_DECLARED)]
+
+
+@pytest.mark.parametrize("cell, step, width", LATENT_STEP_CASES)
+def test_latent_step_relayouts_no_pool(v5e, cell, step, width):
+    """The latent pool is stored in the layout its writer and its kernel use:
+    at the stored width the pool's entry layout, in and out, is major-to-minor
+    and the compiled step holds no ``copy`` of the pool's shape. At the
+    declared 576 (4.5 lane tiles) the compiler's own parameter layout puts a
+    page's tokens in the lanes, and the program copies the WHOLE pool into the
+    kernel's layout and back, every step."""
+    assert stored_width(DS_DECLARED) == DS_LATENT
+    pool, args, group_bias = _latent_step_case(
+        cell, step, width, partial(jax.ShapeDtypeStruct, sharding=v5e))
+    compiled = jax.jit(partial(_latent_step, group_bias=group_bias),
+                       donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    shape = "bf16\\[" + ",".join(map(str, pool)) + "\\]"
+    copies = re.findall(rf"= {shape}\{{[^}}]*\}} copy\(", text)
+    layouts = [f.latent_pages.layout.major_to_minor for f in (
+        compiled.input_formats[0][0], compiled.output_formats[0])]
+    assert text.count("tpu_custom_call") >= pool[0]
+    if width == DS_LATENT:
+        assert not copies and layouts == [(0, 1, 2, 3)] * 2
+    else:
+        assert len(copies) == 2 and layouts == [(0, 1, 3, 2)] * 2
 
 
 def test_which_buckets_have_a_half_on_a_v5e_mesh(v5e):

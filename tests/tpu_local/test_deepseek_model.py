@@ -2,8 +2,9 @@
 (``models/deepseek.py``) at a CPU size: held to the plain reference
 (``benchmark/reference/deepseek_v32_plain.py``: non-absorbed attention, full
 ``[T, S]`` index matrix, a full sort, a loop over experts) in float32, through
-dense prefill, chunked history prefill and decode over the latent cache; the
-kernels (interpreted) against their jnp references; the expert shares adding up
+dense prefill, chunked history prefill and decode over the latent cache, by
+the gather path and by the kernels (interpreted), with the pools' zero tails
+kept; the kernels against their jnp references; the expert shares adding up
 to the uncut layer; the family seam leaving the GQA trunk's programs alone; and
 what the family refuses."""
 
@@ -11,6 +12,7 @@ import asyncio
 import dataclasses
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -77,23 +79,50 @@ def chunked(params, tokens, chunk, cfg=CFG, **kw):
     return np.concatenate(rows), kv
 
 
+def interpreted_kernels(monkeypatch):
+    """``paged_impl="pallas"`` off the chip: the family's three kernels run
+    interpreted."""
+    for name in ("mla_paged_attention_pallas", "sparse_index_scores_pallas",
+                 "sparse_select_pallas"):
+        monkeypatch.setattr(mla, name, partial(getattr(mla, name), interpret=True))
+
+
+def assert_zero_tails(kv, cfg, written: int):
+    """The pools hold ``written`` tokens' declared vectors a layer and zeros
+    in the lanes past them (``kv.stored_width``), trash page apart."""
+    for pages, width in ((kv.latent_pages, cfg.latent_dim),
+                         (kv.index_pages, cfg.index_head_dim)):
+        if pages is None:
+            continue
+        pages = np.asarray(pages)[:, 1:]
+        assert pages.shape[-1] == kv_mod.stored_width(width) > width
+        assert not pages[..., width:].any()
+        tokens_held = pages[..., :width].any(axis=-1).sum(axis=(1, 2))
+        assert (tokens_held == written).all(), tokens_held
+
+
+@pytest.mark.parametrize("impl", ["gather", "pallas"])
 @pytest.mark.parametrize("path", ["prefill", "chunked_history", "decode"])
-def test_program_equals_the_plain_reference_in_float32(params, tokens, reference, path):
+def test_program_equals_the_plain_reference_in_float32(params, tokens, reference,
+                                                       path, impl, monkeypatch):
     want = np.asarray(reference["logits"])
+    if impl == "pallas":
+        interpreted_kernels(monkeypatch)
     with jax.default_matmul_precision("highest"):
         if path == "prefill":
             tok, pos = block(tokens, 0, T, 128)
-            logits, _, _ = deepseek.prefill(params, CFG, tok, pos, fresh_cache(), SLOT)
+            logits, kv, _ = deepseek.prefill(params, CFG, tok, pos, fresh_cache(),
+                                             SLOT, attn_impl=impl)
             got = np.asarray(logits)[0, :T]
         else:
-            got, kv = chunked(params, tokens, 32)
+            got, kv = chunked(params, tokens, 32, paged_impl=impl)
             if path == "decode":
                 rows = []
                 for j in range(4):
                     logits, kv, _ = deepseek.decode_step(
                         params, CFG, jnp.asarray(tokens[T + j:T + j + 1]),
                         jnp.asarray([T + j]), kv, SLOT, jnp.asarray([T + j + 1]),
-                        ctx_pages=TABLE)
+                        ctx_pages=TABLE, paged_impl=impl)
                     rows.append(np.asarray(logits)[0])
                 got, want = np.stack(rows), want[T:T + 4]
             else:
@@ -101,6 +130,7 @@ def test_program_equals_the_plain_reference_in_float32(params, tokens, reference
     # the selection is a strict subset here (index_topk 8 of up to 74 tokens)
     assert T > 4 * CFG.index_topk
     np.testing.assert_allclose(got, want[:len(got)], atol=1e-4, rtol=1e-4)
+    assert_zero_tails(kv, CFG, T + 4 if path == "decode" else T)
 
 
 def test_selecting_everything_is_dense_latent_attention(params, tokens):
@@ -366,7 +396,8 @@ def test_latent_kv_block_is_a_divisor_of_any_table_width():
 # ------------------------------------------------------------ cache and seam
 
 def test_the_cache_counts_the_pools_a_family_declares():
-    assert [p.name for p in kv_mod.kv_pools(CFG)] == ["latent", "index_key"]
+    assert [(p.name, p.shape) for p in kv_mod.kv_pools(CFG)] == [
+        ("latent", (CFG.latent_dim,)), ("index_key", (CFG.index_head_dim,))]
     assert kv_mod.kv_page_bytes(CFG, PAGE, jnp.float32) == (
         CFG.n_layers * PAGE * (CFG.latent_dim + CFG.index_head_dim) * 4)
     mistral = MODEL_CONFIGS["mistral-7b"]
@@ -381,6 +412,48 @@ def test_the_cache_counts_the_pools_a_family_declares():
         kv_mod.kv_page_bytes(CFG, PAGE, quant="int8")
     assert deepseek.param_count(CFG) == sum(
         a.size for a in jax.tree.leaves(deepseek.init_params(CFG, jax.random.PRNGKey(0))))
+
+
+CELL = dataclasses.replace(      # deepseek-v3.2-d5-ep16's cache widths
+    CFG, name="cell-widths", n_layers=5, kv_lora_rank=512, qk_rope_head_dim=64,
+    index_head_dim=128)
+
+
+@pytest.mark.parametrize("config, declared, stored", [
+    # the latent family: declared 576 (1152 B a token a layer in bfloat16),
+    # stored 640; the selector's 128 comes out of the same rule unchanged
+    (CELL, 5 * (576 + 128) * 2,
+     {"latent_pages": (5, 6, 128, 640), "index_pages": (5, 6, 128, 128)}),
+    (CFG, 3 * (48 + 16) * 2,
+     {"latent_pages": (3, 6, 128, 128), "index_pages": (3, 6, 128, 128)}),
+    (MODEL_CONFIGS["deepseek-mtp-test"], 4 * 48 * 2,
+     {"latent_pages": (4, 6, 128, 128)}),
+    # the three other families' pools have the shapes they had
+    (MODEL_CONFIGS["mistral-7b"], 2 * 32 * 8 * 128 * 2,
+     {"k_pages": (32, 6, 128, 8, 128), "v_pages": (32, 6, 128, 8, 128)}),
+    (MODEL_CONFIGS["llama3-test"], None, None),
+    (MODEL_CONFIGS["olmo-hybrid-test"], None, None),
+    (MODEL_CONFIGS["sdar-test"], None, None),
+], ids=lambda v: getattr(v, "name", None))
+def test_what_a_pool_declares_and_what_it_stores(config, declared, stored):
+    """``kv_pools`` / ``kv_page_bytes`` count the vector a family declares;
+    ``init_kv_state`` stores the latent family's padded to whole lanes and
+    every other pool as declared (None: the declared shapes themselves)."""
+    pools = [p for p in kv_mod.kv_pools(config) if p.per == "token"]
+    state = jax.eval_shape(lambda: family_of(config).init_kv_state(
+        config, 6, 128, 2, 4, dtype=jnp.bfloat16))
+    if stored is None:
+        layers = lambda p: p.layers or config.n_layers
+        stored = {f"{p.name}_pages": (layers(p), 6, 128, *p.shape) for p in pools}
+        declared = sum(layers(p) * int(np.prod(p.shape)) for p in pools) * 2
+    assert kv_mod.kv_page_bytes(config, 1, jnp.bfloat16) == declared
+    got = {name: a.shape for name, a in state._asdict().items()
+           if a is not None and name.endswith("_pages")}
+    assert got == stored
+    resident = sum(2 * int(np.prod(shape)) for shape in stored.values())
+    assert kv_mod.kv_resident_bytes(state) == resident >= 6 * 128 * declared
+    assert [kv_mod.stored_width(d) for d in (1, 48, 128, 576, 640, 641)] == [
+        128, 128, 128, 640, 640, 768]
 
 
 def _engine(model, devices=1, **kw):
@@ -479,6 +552,15 @@ def test_the_engine_serves_the_family_and_reads_its_counts_back():
 
     first, again = asyncio.run(run())
     assert again == first[0] and all(len(t) == 6 for t in first)
+    # the repeated prompt took its full pages from the prefix cache, and what
+    # every step wrote (chunks, suffix, decode) left the pools' tails zero
+    assert engine.allocator.prefix_hits > 0
+    for pages, width in ((engine.kv.latent_pages, CFG.latent_dim),
+                         (engine.kv.index_pages, CFG.index_head_dim)):
+        pages = np.asarray(pages)
+        assert pages[..., :width].any() and not pages[..., width:].any()
+    assert engine.kv_bytes_capacity() == 96 * PAGE * 3 * (48 + 16) * 4
+    assert engine.kv_bytes_resident() == 96 * PAGE * 3 * (128 + 128) * 4
     stats = engine.stats
     assert stats.prompt_tokens == 101 + 20 + 75 + 101 and stats.completion_tokens == 24
     # 2 expert layers: every prefilled prompt token and every decode input token

@@ -13,6 +13,7 @@ import asyncio
 import dataclasses
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,8 @@ def fresh_cache():
     return kv._replace(block_tables=jnp.arange(1, 1 + TABLE, dtype=jnp.int32)[None])
 
 
-def both(params, kv, start, toks, follows, width, hidden_of=lambda h: h):
+def both(params, kv, start, toks, follows, width, hidden_of=lambda h: h,
+         impl="gather"):
     """The model's pass and the block's over one [1, width] block from
     ``start``: (main logits, draft logits) of its real rows, and the cache."""
     n = len(toks)
@@ -75,14 +77,15 @@ def both(params, kv, start, toks, follows, width, hidden_of=lambda h: h):
     tok[0, :n], nxt[0, :n], pos[0, :n] = toks, follows, np.arange(start, start + n)
     logits, kv, aux, hidden = deepseek.prefill_with_history(
         params, CFG, jnp.asarray(tok), jnp.asarray(pos), kv, SLOT,
-        ctx_pages=TABLE, hidden=True)
+        ctx_pages=TABLE, hidden=True, paged_impl=impl)
     drafts, kv, aux = deepseek.draft_step(
         params, CFG, hidden_of(hidden), jnp.asarray(nxt), jnp.asarray(pos), kv,
-        SLOT, aux, ctx_pages=TABLE)
+        SLOT, aux, ctx_pages=TABLE, paged_impl=impl)
     return np.asarray(logits)[0, :n], np.asarray(drafts)[0, :n], kv, aux
 
 
-def served(params, tokens, overwrite=True, hidden_of=lambda h: h):
+def served(params, tokens, overwrite=True, hidden_of=lambda h: h,
+           impl="gather"):
     """The prompt in chunks with the block's pass beside each, then verify
     steps of the true token and a WRONG draft (whose entries the next step
     overwrites): main and draft logits of positions 0 .. T + STEPS - 1."""
@@ -90,12 +93,12 @@ def served(params, tokens, overwrite=True, hidden_of=lambda h: h):
     for start in range(0, T, CHUNK):
         end = min(start + CHUNK, T)
         m, d, kv, _ = both(params, kv, start, tokens[start:end],
-                           tokens[start + 1:end + 1], CHUNK, hidden_of)
+                           tokens[start + 1:end + 1], CHUNK, hidden_of, impl)
         main.append(m), draft.append(d)
     for p in range(T, T + STEPS):
         wrong = (int(tokens[p + 1]) + 1) % CFG.vocab_size
         m, d, kv, aux = both(params, kv, p, [tokens[p], wrong],
-                             [tokens[p + 1], tokens[p + 1]], 2, hidden_of)
+                             [tokens[p + 1], tokens[p + 1]], 2, hidden_of, impl)
         main.append(m[:1]), draft.append(d[:1])
         if not overwrite:       # the fault: the rejected position keeps its entries
             break
@@ -104,13 +107,26 @@ def served(params, tokens, overwrite=True, hidden_of=lambda h: h):
 
 # ------------------------------------------------------ against the reference
 
-def test_model_and_block_equal_the_plain_reference_in_float32(params, tokens,
-                                                              reference):
+@pytest.mark.parametrize("impl", ["gather", "pallas"])
+def test_model_and_block_equal_the_plain_reference_in_float32(
+        params, tokens, reference, impl, monkeypatch):
     """(a) chunked prefill, then the verify-width path through the cache with
     a rejected draft before every step: the model's logits at every position
-    and the block's (row i guesses token i + 2) are the reference's."""
+    and the block's (row i guesses token i + 2) are the reference's, by the
+    gather path and by the latent kernel (interpreted); and the pool, stored
+    wider than its vector, keeps zeros in the lanes past it."""
+    if impl == "pallas":
+        monkeypatch.setattr(mla, "mla_paged_attention_pallas", partial(
+            mla.mla_paged_attention_pallas, interpret=True))
     with jax.default_matmul_precision("highest"):
-        main, draft, _kv, aux = served(params, tokens)
+        main, draft, kv, aux = served(params, tokens, impl=impl)
+    pool = np.asarray(kv.latent_pages)
+    assert pool.shape[-1] == kv_mod.stored_width(CFG.latent_dim) > CFG.latent_dim
+    assert not pool[..., CFG.latent_dim:].any()
+    # every layer and the block hold positions 0 .. T + STEPS: the last
+    # rejected draft's entry is still there, dead by position
+    held = pool[:, 1:, :, :CFG.latent_dim].any(axis=-1).sum(axis=(1, 2))
+    assert (held == T + STEPS + 1).all(), held
     np.testing.assert_allclose(main, np.asarray(reference["logits"])[:T + STEPS],
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(draft,
@@ -308,7 +324,9 @@ def test_the_selector_less_cache_is_one_pool_of_1152_bytes_a_token_a_layer():
     state = jax.eval_shape(lambda: deepseek.init_kv_state(
         JOYAI, 2304, 128, 32, 64, dtype=jnp.bfloat16))
     assert state.index_pages is None
-    assert state.latent_pages.shape == (6, 2304, 128, 576)
+    # stored padded to whole lanes: 2.04 GB declared, 2.26 GB resident
+    assert state.latent_pages.shape == (6, 2304, 128, 640)
+    assert kv_mod.kv_resident_bytes(state) == 2304 * 128 * 6 * 1280
     assert kv_mod.kv_logical("", JOYAI).index_pages is None
     # rope without scaling: plain frequencies, no YaRN factor on the scale
     assert deepseek.softmax_scale(JOYAI) == 192 ** -0.5
