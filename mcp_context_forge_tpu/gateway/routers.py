@@ -834,6 +834,18 @@ document.getElementById("f").onsubmit = async (e) => {
             "state_rows_in_use": alloc.rows_in_use,
             "state_rows_total": stats.state_rows_total,
             "state_bytes_in_use": engine.state_bytes_in_use(),
+            # a model whose window layers keep a ring a sequence beside full
+            # layers that page (zeros but the first for any other): the
+            # paged pool, every row's rings, rows that hold a sequence, and
+            # what the step programs counted of context seen and kept
+            "kv": {
+                "full_pool_bytes": engine.kv_bytes_capacity(),
+                "window_pool_bytes": engine.window_pool_bytes(),
+                "window_rows_in_use": (alloc.rows_in_use
+                                       if engine.window_pool_bytes() else 0),
+                "context_keys": stats.context_keys,
+                "window_keys": stats.window_keys,
+            },
             "prefill_ms_total": round(stats.prefill_ms_total, 1),
             "decode_ms_total": round(stats.decode_ms_total, 1),
             "engine_restarts": stats.engine_restarts,

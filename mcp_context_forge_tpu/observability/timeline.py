@@ -112,6 +112,10 @@ class StepCounts(NamedTuple):
     draft_rows: float = 0.0
     drafts_accepted: float = 0.0
     spec_tokens: float = 0.0
+    # a step of a model with window layers: the live rows' summed context,
+    # and what of it a window layer sees (min(context, window) a row)
+    context_keys: float = 0.0
+    window_keys: float = 0.0
 
 
 class StepEvent(NamedTuple):

@@ -431,6 +431,11 @@ class EngineStats:
         self.state_rows_in_use = 0
         self.state_rows_total = 0
         self.state_scanned_tokens = 0
+        # a family with window layers (counted on the device, decode steps
+        # and chunk rounds): the live rows' summed context, and what of it
+        # their window layers see (min(context, window) a row)
+        self.context_keys = 0
+        self.window_keys = 0
         # a family whose decode dispatch is a BLOCK step (models/sdar.py):
         # dispatches, the forward passes they made that sampled (the commit
         # pass of each dispatch is not one of them), the tokens they emitted,
@@ -1040,7 +1045,8 @@ class TPUEngine:
         allocator are sized by the converted, dtype-aware page count."""
         config = self.config
         max_pages_per_slot = config.max_seq_len // config.page_size
-        from .kv import kv_state_bytes, num_pages_for_budget, state_rows_for
+        from .kv import (kv_pools, kv_state_bytes, num_pages_for_budget,
+                         state_rows_for)
         from .parallel.sharding import (kv_pages_sharding, kv_scales_sharding,
                                         logical_to_sharding)
         # bytes one page costs under the ACTIVE storage mode (gauge unit)
@@ -1095,6 +1101,11 @@ class TPUEngine:
         self._state_row_bytes = kv_state_bytes(self.model_config, 1,
                                                self._kv_dtype)
         self.stats.state_rows_total = max(0, state_rows - 1)
+        # window layers that keep a ring a sequence (pools named window_*)
+        # beside full layers that page: how many of each, for the spans
+        pools = {pool.name: pool for pool in kv_pools(self.model_config)}
+        self._window_layers = getattr(pools.get("window_k"), "layers", 0)
+        self._full_layers = pools["k"].layers if self._window_layers else 0
         self.allocator = PageAllocator(self.num_kv_pages, config.page_size,
                                        config.max_batch, max_pages_per_slot,
                                        tiers=self._tier_client,
@@ -3770,7 +3781,9 @@ class TPUEngine:
         GQA family), or one vector ``[moe_tokens, moe_local_pairs, summed
         selected / context share, rows]`` (``STEP_AUX``), which a family
         with per-sequence state extends by ``[live state rows, real tokens
-        scanned]``; a verify step that drafts on the device returns a second
+        scanned]`` and one with window layers by ``[the live rows' summed
+        context, their summed min(context, window)]`` after those; a verify
+        step that drafts on the device returns a second
         vector after it, ``[rows that carried a draft, drafts accepted]``.
         The counts go to ``EngineStats`` and onto the step's timeline
         record."""
@@ -3779,15 +3792,19 @@ class TPUEngine:
         moe_tokens, pairs, share_sum, rows, *state = (float(v) for v in aux[0])
         self.stats.moe_tokens += int(moe_tokens)
         self.stats.moe_local_pairs += int(pairs)
-        live, scanned = state or (0.0, 0.0)
+        live, scanned, context_keys, window_keys = (*state, 0.0, 0.0, 0.0,
+                                                    0.0)[:4]
         self.stats.state_scanned_tokens += int(scanned)
+        self.stats.context_keys += int(context_keys)
+        self.stats.window_keys += int(window_keys)
         drafted, accepted = ((float(v) for v in aux[1]) if len(aux) > 1
                              else (0.0, 0.0))
         self.stats.spec_drafted += int(drafted)
         self.stats.spec_accepted += int(accepted)
         return StepCounts(share_sum / rows if rows else 0.0, moe_tokens, pairs,
                           live, scanned, draft_rows=drafted,
-                          drafts_accepted=accepted)
+                          drafts_accepted=accepted, context_keys=context_keys,
+                          window_keys=window_keys)
 
     def _record_step(self, kind: str, *, seq: int, batch: int, width: int,
                      dur_ms: float, tokens: int, bucket: int | None = None,
@@ -4056,7 +4073,8 @@ class TPUEngine:
                    events=phase_events or None,
                    **{"gen_ai.usage.completion_tokens": n,
                       "llm.finish_reason": reason,
-                      "llm.kv_pages": self.allocator.slot_pages(request.slot)})
+                      "llm.kv_pages": self.allocator.slot_pages(request.slot),
+                      **self._window_attrs(len(request.prompt_ids) + n)})
 
     def _decode_phase_events(self, request: GenRequest, since_ts: float
                              ) -> list[tuple[float, str, dict[str, Any]]]:
@@ -4136,7 +4154,8 @@ class TPUEngine:
                               "llm.cached_prefix_tokens": request.hist,
                               "llm.chunked": request.chunked,
                               "llm.kv_pages": self.allocator.slot_pages(
-                                  request.slot)})
+                                  request.slot),
+                              **self._window_attrs(len(request.prompt_ids))})
         done = (token == self.tokenizer.eos_id or token in request.stop_ids
                 or len(request.generated) >= request.max_tokens)
         if done and request.finish_reason is None:
@@ -4241,3 +4260,21 @@ class TPUEngine:
     def state_bytes_in_use(self) -> int:
         """HBM bytes of the per-sequence pools the live rows occupy."""
         return self.allocator.rows_in_use * self._state_row_bytes
+
+    def window_pool_bytes(self) -> int:
+        """HBM bytes of the window layers' rings, every row's (0 for a family
+        without window layers)."""
+        if not self._window_layers:
+            return 0
+        return self.allocator.state_rows * self._state_row_bytes
+
+    def _window_attrs(self, context: int) -> dict[str, int]:
+        """Span attributes of a request at ``context`` tokens in a model with
+        window layers (none for any other): the layers of each kind and the
+        keys a window layer sees of that context."""
+        if not self._window_layers:
+            return {}
+        return {"llm.window_layers": self._window_layers,
+                "llm.full_layers": self._full_layers,
+                "llm.window_tokens": min(
+                    context, self.model_config.sliding_window)}

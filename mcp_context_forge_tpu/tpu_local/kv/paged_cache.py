@@ -40,6 +40,25 @@ page), the row follows its request through compaction by id
 copies a state. ``kv_page_bytes`` counts the per-token pools by their own
 layer counts; ``kv_state_bytes`` the per-sequence ones.
 
+The same tuple serves a family whose layers are WINDOW or FULL attention
+(``models/afmoe.py``). A full layer holds every token of its sequence in
+``k``/``v`` pages under the block table, as above. A window layer's query sees
+its ``W`` newest keys and nothing older, so its K and V are per-sequence pools
+too: a RING of ``ring_pages = ring_tokens / page`` pages a state row (the
+tuple's two per-sequence fields, stored ``[L_window, rows * ring_pages + 1,
+page, KV, hd]`` so that the writers and the paged kernel read them as pages;
+page 0 the trash page). Position ``p`` lives in the row's ring page ``(p //
+page) mod ring_pages``. The ring's block table is never uploaded: the step
+program computes it from ``state_rows`` (``ring_tables``), and ``ring_view``
+hands the writers and the kernels a ``PagedKVState`` over the rings. Positions
+stay what they are and every mask stays a comparison of positions: an entry
+older than the window that its page still holds, an entry of an earlier lap of
+the ring or of the row's last tenant is dead by position (docs/adr/019). With
+``ring_tokens >= W +`` the widest step that writes before it attends ``+`` a
+page, no step overwrites a key one of its own queries still sees. The
+``PageAllocator`` deals page ids for the full layers alone and learns nothing:
+no pool has a retire policy and no second free list exists.
+
 The layout is token-major on purpose: ``(KV, hd)`` are the two minor dims,
 so one token's kv heads are one contiguous tile and a token write (decode,
 prefill scatter) is one whole-tile update. Head-major pages
@@ -72,7 +91,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..models.configs import DeepseekConfig, LlamaConfig, OlmoHybridConfig
+from ..models.configs import (AfmoeConfig, DeepseekConfig, LlamaConfig,
+                              OlmoHybridConfig)
 from ..quantize import KV_SCALE_EPS, kv_dequantize, kv_int8_scale, kv_quantize
 
 
@@ -116,7 +136,7 @@ class PoolSpec(NamedTuple):
     dtype: Any = None
 
 
-AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig
+AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig | AfmoeConfig
 LANES = 128     # the minor dimension of the chip's tiles
 
 
@@ -153,6 +173,12 @@ def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
                          linear, "sequence", jnp.float32),
                 PoolSpec("conv_tail", (config.conv_kernel - 1, config.conv_dim),
                          linear, "sequence"))
+    if isinstance(config, AfmoeConfig):
+        full = len(config.layers_of("full"))
+        ring = (config.ring_tokens, *heads)
+        return (PoolSpec("k", heads, full), PoolSpec("v", heads, full),
+                PoolSpec("window_k", ring, config.n_layers - full, "sequence"),
+                PoolSpec("window_v", ring, config.n_layers - full, "sequence"))
     return (PoolSpec("k", heads), PoolSpec("v", heads))
 
 
@@ -198,18 +224,21 @@ class LatentKVState(NamedTuple):
 
 
 class HybridKVState(NamedTuple):
-    """Device state of the hybrid family: K/V pages of the full-attention
-    layers (indexed by the layer's ordinal AMONG them) under the block table,
-    and the linear-attention layers' per-sequence pools under ``state_rows``
-    (slot -> row id, 0 = none: the trash row). Full precision only; the
-    ``*_scales`` fields are the GQA trunk's attention functions' (always
-    None here)."""
+    """Device state of a family in which only SOME layers page their K/V: K/V
+    pages of the full-attention layers (indexed by the layer's ordinal AMONG
+    them) under the block table, and the other layers' two per-sequence pools
+    under ``state_rows`` (slot -> row id, 0 = none: the trash row). The hybrid
+    family's linear-attention layers keep their recurrent ``state`` and
+    ``conv_tail`` there; a window / full family's window layers keep their K
+    ring in the first field and their V ring in the second, read through
+    :func:`ring_view`. Full precision only; the ``*_scales`` fields are the
+    GQA trunk's attention functions' (always None here)."""
 
     k_pages: jax.Array       # [L_full, num_pages, page_size, KV, hd]
     v_pages: jax.Array
     block_tables: jax.Array  # [slots, max_pages_per_slot] int32
-    state: jax.Array         # [L_lin, rows, d_k, H * d_v] float32
-    conv_tail: jax.Array     # [L_lin, rows, taps, channels]
+    state: jax.Array         # [L_lin, rows, d_k, H * d_v] float32; or the K ring
+    conv_tail: jax.Array     # [L_lin, rows, taps, channels]; or the V ring
     state_rows: jax.Array    # [slots] int32
     k_scales: None = None
     v_scales: None = None
@@ -228,13 +257,28 @@ class HybridKVState(NamedTuple):
 
 
 def _full_precision_only(config, quant: str) -> bool:
-    """True for the hybrid family (which then refuses ``quant``)."""
-    hybrid = isinstance(config, OlmoHybridConfig)
+    """True for the families of :class:`HybridKVState` (which then refuse
+    ``quant``)."""
+    hybrid = isinstance(config, (OlmoHybridConfig, AfmoeConfig))
     if hybrid and quant:
         raise NotImplementedError(
             f"kv_quant={quant!r}: the hybrid family's pools are full "
             f"precision only")
     return hybrid
+
+
+def _stored_shape(pool: PoolSpec, num_pages: int, page_size: int,
+                  rows: int) -> tuple[int, ...]:
+    """The array a pool of :class:`HybridKVState` is stored in. A window ring
+    (a per-sequence pool of ``[ring_tokens, KV, hd]``) is stored as pages, a
+    trash page and ``ring_tokens / page_size`` a row."""
+    if pool.per == "token":
+        return (pool.layers, num_pages, page_size, *pool.shape)
+    if pool.name.startswith("window_"):
+        tokens, *heads = pool.shape
+        return (pool.layers, rows * (tokens // page_size) + 1, page_size,
+                *heads)
+    return (pool.layers, rows, *pool.shape)
 
 
 def _latent_only(config, quant: str) -> bool:
@@ -273,9 +317,8 @@ def init_kv_state(config: AnyConfig, num_pages: int, page_size: int,
     if _full_precision_only(config, quant):
         rows = state_rows_for(config, max_slots)
         k, v, state, conv_tail = (
-            jnp.zeros((pool.layers, *((num_pages, page_size)
-                                      if pool.per == "token" else (rows,)),
-                       *pool.shape), dtype=pool.dtype or dtype)
+            jnp.zeros(_stored_shape(pool, num_pages, page_size, rows),
+                      dtype=pool.dtype or dtype)
             for pool in kv_pools(config))
         return HybridKVState(k, v, tables, state, conv_tail,
                              jnp.zeros((max_slots,), dtype=jnp.int32))
@@ -493,6 +536,32 @@ def gather_kv(kv: PagedKVState, layer: int, slot_ids: jax.Array,
         v = kv_dequantize(v, vs, dt)
     B, P, page, KV, hd = k.shape
     return k.reshape(B, P * page, KV, hd), v.reshape(B, P * page, KV, hd)
+
+
+def ring_tables(ring_pages: int, rows: jax.Array, first_page: jax.Array,
+                n_pages: int) -> jax.Array:
+    """The ring pages that hold logical pages ``first_page .. first_page +
+    n_pages - 1`` of state rows ``rows``: rows, first_page [B] -> [B, n_pages]
+    int32 (``1 + row * ring_pages + n mod ring_pages``; module docstring)."""
+    logical = first_page[:, None] + jnp.arange(n_pages, dtype=jnp.int32)[None]
+    return 1 + rows[:, None] * ring_pages + logical % ring_pages
+
+
+def ring_view(kv: HybridKVState, ring_pages: int) -> PagedKVState:
+    """The window layers' rings as the trunk's writers and kernels take a
+    cache: K and V pages indexed by the layer's ordinal among the window
+    layers, and a block table ``[slots, max_pages_per_slot]`` that sends
+    every logical page of a slot to its ring page. Write through it with
+    ``write_prefill_kv`` / ``write_decode_kv`` and put the pages back with
+    :func:`with_rings`."""
+    slots, width = kv.block_tables.shape
+    tables = ring_tables(ring_pages, kv.state_rows,
+                         jnp.zeros((slots,), jnp.int32), width)
+    return PagedKVState(kv.state, kv.conv_tail, tables)
+
+
+def with_rings(kv: HybridKVState, view: PagedKVState) -> HybridKVState:
+    return kv._replace(state=view.k_pages, conv_tail=view.v_pages)
 
 
 def _token_pages(kv, slot_ids: jax.Array, positions: jax.Array,

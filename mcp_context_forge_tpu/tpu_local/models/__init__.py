@@ -5,7 +5,10 @@ A decoder family is one module (``llama``: GQA + RoPE + SwiGLU/Mixtral
 experts; ``deepseek``: latent attention, shared + routed experts, and by the
 model configuration a sparse selector and a multi-token-prediction block; ``olmo_hybrid``: gated delta-rule linear-attention layers between
 full-attention layers; ``sdar``: the GQA trunk with QK-norm and many small
-experts under a block-causal mask, generating by diffusion over blocks) with
+experts under a block-causal mask, generating by diffusion over blocks;
+``afmoe``: window layers that keep a ring of their newest keys beside full
+layers that keep every page, a QK-normed gated attention, and dense or
+sigmoid-routed expert FFNs with a shared expert) with
 the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
@@ -23,8 +26,9 @@ first block. The engine finds the module from the model config's CLASS
 
 What a family may declare: a KIND a layer (``layer_kind(config, i)``; the
 engine compiles one weight-init program a kind) that names the layer's FFN
-(``deepseek``: dense | experts) or its MIXER (``olmo_hybrid``:
-linear_attention | full_attention); cache pools that only some layers hold,
+(``deepseek``: dense | experts), its MIXER (``olmo_hybrid``:
+linear_attention | full_attention) or BOTH (``afmoe``: window | full, dense |
+experts, as ``window.experts``); cache pools that only some layers hold,
 and pools of a fixed size a sequence beside the per-token ones
 (``kv/paged_cache.py: kv_pools``); with ``STEP_AUX``, a float32 vector of
 counts its step programs return beside the tokens (``engine._step_counts``);
@@ -46,12 +50,13 @@ drafts are the engine's, the same for both answers (docs/adr/008)."""
 from importlib import import_module
 from types import ModuleType
 
-from .configs import (DeepseekConfig, EncoderConfig, LlamaConfig,
+from .configs import (AfmoeConfig, DeepseekConfig, EncoderConfig, LlamaConfig,
                       OlmoHybridConfig, SdarConfig, ENCODER_CONFIGS,
                       MODEL_CONFIGS)
 
 _FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek",
-                   OlmoHybridConfig: "olmo_hybrid", SdarConfig: "sdar"}
+                   OlmoHybridConfig: "olmo_hybrid", SdarConfig: "sdar",
+                   AfmoeConfig: "afmoe"}
 
 
 def family_of(model_config) -> ModuleType:
@@ -63,5 +68,5 @@ def family_of(model_config) -> ModuleType:
 
 
 __all__ = ["LlamaConfig", "DeepseekConfig", "OlmoHybridConfig",
-           "SdarConfig", "EncoderConfig", "MODEL_CONFIGS",
+           "SdarConfig", "AfmoeConfig", "EncoderConfig", "MODEL_CONFIGS",
            "ENCODER_CONFIGS", "family_of"]

@@ -9,9 +9,9 @@ from dataclasses import dataclass
 class LlamaConfig:
     """Geometry for the GQA+RoPE+SwiGLU decoder family.
 
-    One trunk covers Llama-3, Mistral (v0.3+, no sliding window), Qwen2
-    and Gemma. Family knobs: ``attn_bias`` (Qwen2 q/k/v projection
-    biases), ``tie_embeddings`` (Qwen2-0.5B, Llama-3.2-1B, Gemma — no
+    One trunk covers Llama-3, Mistral (v0.3+, which has no sliding window;
+    window layers are :class:`AfmoeConfig`'s), Qwen2 and Gemma. Family
+    knobs: ``attn_bias`` (Qwen2 q/k/v projection biases), ``tie_embeddings`` (Qwen2-0.5B, Llama-3.2-1B, Gemma — no
     ``lm_head.weight`` in the HF checkpoint), ``head_dim_override``
     (Gemma decouples head_dim from dim//n_heads: 2B uses 256-wide heads
     on a 2048 model dim), ``hidden_act`` (Gemma gates with tanh-approx
@@ -260,8 +260,80 @@ class SdarConfig:
     moe_block: int = 128
 
 
+@dataclass(frozen=True)
+class AfmoeConfig:
+    """Geometry of the family whose layers differ in BOTH parts (``afmoe``
+    ``config.json`` keys in brackets). The mixer [layer_types]: layer ``i`` is
+    a WINDOW layer (rotary, a query sees the ``sliding_window`` newest keys,
+    its own included) unless ``i % global_attn_every == global_attn_every -
+    1``, when it is a FULL layer (no rotation, every earlier key). The FFN:
+    the leading ``n_dense_layers`` [num_dense_layers] are dense SwiGLU of
+    ``ffn_hidden`` [intermediate_size], the rest route over ``n_experts`` of
+    ``moe_ffn_hidden`` [moe_intermediate_size] (sigmoid scores plus a
+    correction bias choose ``moe_top_k``; the chosen scores, normalised and
+    times ``routed_scaling_factor`` [route_scale], weigh them) beside one
+    shared expert of ``n_shared_experts * moe_ffn_hidden``. Both kinds: GQA
+    with a per-head RMSNorm on q and k, an output gate ``sigmoid(x W_g)`` on
+    the attention's heads, four norms a layer (``x + norm(f(norm(x)))``), the
+    embedding times ``sqrt(dim)`` [mup_enabled], an untied head.
+
+    ``ring_slack``: tokens a sequence's ring of window K/V holds beyond the
+    window: the widest step that writes before it attends (a chunk round)
+    and one page, so that a step's queries find every key of their windows
+    (``kv/paged_cache.py``). The family refuses an engine whose largest
+    prefill bucket and page do not fit it. ``n_group`` / ``topk_group`` are 1
+    (group limiting is the identity); ``moe_impl`` / ``moe_block`` as in
+    :class:`LlamaConfig`."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    ffn_hidden: int
+    moe_ffn_hidden: int
+    n_experts: int
+    moe_top_k: int
+    sliding_window: int
+    global_attn_every: int = 4
+    n_dense_layers: int = 2
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.826
+    n_group: int = 1
+    topk_group: int = 1
+    ring_slack: int = 1152
+    rope_theta: float = 10_000.0
+    norm_eps: float = 1e-5
+    max_seq_len: int = 131_072
+    hidden_act: str = "silu"
+    moe_impl: str = "grouped_pallas"
+    moe_block: int = 128
+
+    def mixer_kind(self, layer: int) -> str:
+        full = layer % self.global_attn_every == self.global_attn_every - 1
+        return "full" if full else "window"
+
+    def ffn_kind(self, layer: int) -> str:
+        return "dense" if layer < self.n_dense_layers else "experts"
+
+    def layers_of(self, mixer: str) -> tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.mixer_kind(i) == mixer)
+
+    @property
+    def ring_tokens(self) -> int:
+        """Tokens of K and of V a window layer keeps a sequence."""
+        return self.sliding_window + self.ring_slack
+
+    @property
+    def embed_multiplier(self) -> float:
+        return float(self.dim) ** 0.5
+
+
 MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
-                    | SdarConfig] = {
+                    | SdarConfig | AfmoeConfig] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -371,6 +443,16 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
         n_kv_heads=2, head_dim=16, ffn_hidden=32, n_experts=8, moe_top_k=2,
         mask_token_id=511, max_seq_len=512, moe_impl="grouped",
         moe_block=8),
+    # the window / full family at CI scale: two periods of (3 window layers,
+    # 1 full layer), 1 dense layer then expert layers of 8 experts top-2 and a
+    # shared one; a window of 4 pages of 8 and a ring of 7 (slack: a chunk of
+    # 16 and a page), so that a test's ring wraps; the grouped experts through
+    # XLA as deepseek-test
+    "afmoe-test": AfmoeConfig(
+        name="afmoe-test", vocab_size=512, dim=64, n_layers=8, n_heads=4,
+        n_kv_heads=2, head_dim=16, ffn_hidden=128, moe_ffn_hidden=32,
+        n_experts=8, moe_top_k=2, sliding_window=32, n_dense_layers=1,
+        ring_slack=24, max_seq_len=512, moe_impl="grouped", moe_block=8),
 }
 
 

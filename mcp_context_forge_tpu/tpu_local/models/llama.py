@@ -322,6 +322,40 @@ def expert_path(config: LlamaConfig, mesh, tokens: int,
     return "grouped"
 
 
+def routed_experts(stacks: dict[str, Any], config, flat: jax.Array,
+                   ids: jax.Array, weights: jax.Array, mesh=None,
+                   valid: jax.Array | None = None) -> jax.Array:
+    """The routed experts' weighted sum for routing CHOICES, however a router
+    made them: flat [T, D], ids [T, k] int32 into the stacks ``w1``/``w3``
+    [E, D, F] and ``w2`` [E, F, D], weights [T, k] float32 (final: whatever
+    normalising and scaling the router does is done) -> [T, D]. The softmax
+    router of :func:`_ffn_block` and a family's own router (sigmoid scores, a
+    correction bias) feed the same two formulations through it, picked by
+    :func:`expert_path` from the step's shape and run at
+    :func:`expert_block`'s row-block. ``valid`` (T entries): False marks
+    padding and idle rows; the grouped path gives their pairs no row and zero
+    output, the scan computes them like any token, and nothing reads either."""
+    T, E = flat.shape[0], config.n_experts
+    if expert_path(config, mesh, T, flat.dtype) == "grouped":
+        # the kernel interprets off-TPU (the caller's mesh says which) so
+        # the code path exists everywhere
+        from ..ops.grouped_moe import experts_grouped, plan_sorted_blocks
+        if valid is not None:
+            ids = jnp.where(valid.reshape(-1, 1), ids, E)
+        block = expert_block(config, T, flat.dtype)
+        use_pallas = config.moe_impl == "grouped_pallas"
+        return experts_grouped(
+            stacks, flat, plan_sorted_blocks(ids, weights, E, block),
+            act=config.hidden_act, impl="pallas" if use_pallas else "xla",
+            block=block, interpret=use_pallas and not on_tpu(mesh),
+            gather_back=True)
+    from ..parallel.moe import expert_scan
+    gates = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32)
+                    * weights[:, :, None], axis=1)               # [T, E]
+    return expert_scan(stacks, flat, gates.astype(flat.dtype),
+                       config.hidden_act)
+
+
 def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
                x: jax.Array, mesh=None,
                valid: jax.Array | None = None) -> jax.Array:
@@ -353,15 +387,18 @@ def _ffn_block(layer: dict[str, Any], config: LlamaConfig,
     moe_params = {k: layer[k] for k in ("router", "w1", "w3", "w2")}
     tokens = x.shape[0] * x.shape[1]
     if expert_path(config, mesh, tokens, x.dtype) == "grouped":
-        # the kernel interprets off-TPU (the caller's mesh says which) so
-        # the code path exists everywhere
-        from ..ops.grouped_moe import moe_ffn_grouped
-        use_pallas = config.moe_impl == "grouped_pallas"
-        return moe_ffn_grouped(
-            moe_params, x, moe_cfg, act=config.hidden_act,
-            impl="pallas" if use_pallas else "xla",
-            block=expert_block(config, tokens, x.dtype),
-            interpret=use_pallas and not on_tpu(mesh), valid=valid)
+        # the softmax router's choices into the formulation every router
+        # feeds (:func:`routed_experts`)
+        from ..ops.grouped_moe import top_k_gates
+        from ..parallel.moe import router_probs
+        flat = x.reshape(-1, x.shape[-1])
+        ids, gates = top_k_gates(router_probs(layer["router"], flat),
+                                 config.moe_top_k)
+        return routed_experts(moe_params, config, flat, ids, gates, mesh,
+                              valid).reshape(x.shape)
+    # the scan's gates straight from the probabilities (the program this
+    # family's decode steps have always been); routed_experts' scan branch
+    # builds the same [T, E] from ids and weights for any other router
     return moe_ffn_dense_mask(moe_params, x, moe_cfg, act=config.hidden_act)
 
 
@@ -491,12 +528,14 @@ def _history_tile(S: int, G: int) -> int:
 
 def _history_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
                        positions: jax.Array, valid: jax.Array,
-                       config: LlamaConfig) -> jax.Array:
+                       config: LlamaConfig,
+                       window: int | None = None) -> jax.Array:
     """Chunk queries over the full gathered context (history + chunk).
 
     q: [B,S,H,hd]; keys/values: [B,C,KV,hd]; positions/valid: [B,S].
     Causality rides absolute position: cache index c (its position in the
-    slot's context) attends iff c <= q_position. -> [B,S,H,hd]."""
+    slot's context) attends iff c <= q_position, and under a ``window``
+    (static) iff also q_position - c < window. -> [B,S,H,hd]."""
     B, S, H, hd = q.shape
     C = keys.shape[1]
     G = H // config.n_kv_heads
@@ -505,6 +544,8 @@ def _history_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
     scores = jnp.einsum("bskgh,bckh->bkgsc", qg, kf) / math.sqrt(hd)
     cache_pos = jnp.arange(C)[None, None, :]                 # [1,1,C]
     ok = (cache_pos <= positions[:, :, None]) & valid[:, :, None]  # [B,S,C]
+    if window is not None:
+        ok &= cache_pos > positions[:, :, None] - window
     scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgsc,bckh->bskgh", probs, values.astype(jnp.float32))
@@ -561,8 +602,11 @@ def decode_step(params: dict[str, Any], config: LlamaConfig, tokens: jax.Array,
 
 
 def _paged_decode_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
-                            seq_lens: jax.Array, config: LlamaConfig) -> jax.Array:
-    """q: [B,H,hd]; keys/values: [B,C,KV,hd]; seq_lens: [B] -> [B,1,H,hd]."""
+                            seq_lens: jax.Array, config: LlamaConfig,
+                            window: int | None = None) -> jax.Array:
+    """q: [B,H,hd]; keys/values: [B,C,KV,hd]; seq_lens: [B] -> [B,1,H,hd].
+    Under a ``window`` (static) the query, at position seq_len - 1, sees its
+    ``window`` newest keys alone."""
     B, H, hd = q.shape
     C = keys.shape[1]
     group = H // config.n_kv_heads
@@ -571,6 +615,8 @@ def _paged_decode_attention(q: jax.Array, keys: jax.Array, values: jax.Array,
     vf = values.astype(jnp.float32)
     scores = jnp.einsum("bkgh,bckh->bkgc", qg, kf) / math.sqrt(hd)
     valid = jnp.arange(C)[None, :] < seq_lens[:, None]        # [B,C]
+    if window is not None:
+        valid &= jnp.arange(C)[None, :] >= seq_lens[:, None] - window
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgc,bckh->bkgh", probs, vf)

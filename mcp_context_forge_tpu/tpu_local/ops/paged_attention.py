@@ -58,9 +58,18 @@ the f32 scores and the f32 probabilities of the page's columns (one scale
 per (page, head), so it factors out of both matmuls) — the HBM side of
 attention moves 1 byte/element.
 
+A WINDOW (both entry points' static ``window``; absent: the body and the
+program above, unchanged) bounds what a query sees from below as well: key
+``c`` is visible iff ``q_pos - window < c <= q_pos``. The mask gains that
+comparison, and a KV block wholly behind ``lowest query position - window``
+of its row block is as dead as one past the highest: it is not processed and
+its slots fetch nothing (``_block_entries``: the first live page a row block
+from the scalar-prefetched positions). A decode step at context 16k with a
+window of 2048 touches 17 pages a row, not 125.
+
 Grid: (batch, query-row block, KV block). Scalar prefetch: the pool page of
-every slot of every block per (batch, row block), and the highest query
-position per (batch, row block).
+every slot of every block per (batch, row block), the highest query position
+per (batch, row block) and, under a window, the lowest.
 """
 
 from __future__ import annotations
@@ -138,7 +147,14 @@ def _kv_block_pages(n_pages: int, rows: int, page_size: int,
     return max(n for n in range(1, want + 1) if n_pages % n == 0)
 
 
-def _block_entries(block_tables, max_pos, n: int, page_size: int):
+def _first_visible(min_pos, window: int):
+    """The lowest key position a row block's queries see: its lowest query
+    position's window (0 until a window has filled)."""
+    return jnp.maximum(min_pos - (window - 1), 0)
+
+
+def _block_entries(block_tables, max_pos, n: int, page_size: int,
+                   min_pos=None, window: int | None = None):
     """The pool page every slot of every KV block fetches: block_tables
     [B, P], max_pos [B, row blocks] -> [B * row blocks, P] int32. Entry
     ``j * n + i`` (slot ``i`` of block ``j``) is the row's table entry while
@@ -146,11 +162,17 @@ def _block_entries(block_tables, max_pos, n: int, page_size: int):
     inside a live block, a dead block, an idle row) keeps the entry the slot
     last fetched, in the order the grid walks, so it fetches nothing. Worked
     out here and not in the index maps: a map is traced and lowered once a
-    pool operand a layer, and cannot see what its slot held a step before."""
+    pool operand a layer, and cannot see what its slot held a step before.
+    Under a ``window`` the pages behind the first one the row block's lowest
+    query position (``min_pos``) still sees are not reached either."""
     B, P = block_tables.shape
     row_blocks = max_pos.shape[1]
     live = (jnp.arange(P, dtype=jnp.int32)
-            <= (max_pos // page_size)[..., None]).reshape(-1, n)
+            <= (max_pos // page_size)[..., None])
+    if window is not None:
+        live &= (jnp.arange(P, dtype=jnp.int32) >= (
+            _first_visible(min_pos, window) // page_size)[..., None])
+    live = live.reshape(-1, n)
     entries = jnp.broadcast_to(
         block_tables[:, None, :], (B, row_blocks, P)).reshape(-1, n)
     step = jnp.arange(live.shape[0], dtype=jnp.int32)[:, None]
@@ -158,12 +180,17 @@ def _block_entries(block_tables, max_pos, n: int, page_size: int):
     return jnp.take_along_axis(entries, fetched, axis=0).reshape(-1, P)
 
 
-def _kernel(entries_ref, max_pos_ref, pos_ref, q_ref, *rest,
-            page_size: int, n: int, quantized: bool):
-    """Refs: pos [R, 1] int32 (absolute position of each query row, -1 =
-    padding); q/o [KV, R, hd]; N k then N v refs [1, 1, page, KV, hd], the
-    block's pages in table order; N k then N v scale refs [_SCALE_ROWS, KV];
+def _kernel(entries_ref, max_pos_ref, *rest,
+            page_size: int, n: int, quantized: bool,
+            window: int | None = None):
+    """Refs (under a ``window`` the lowest query positions ``min_pos`` come
+    third, as scalar prefetch): pos [R, 1] int32 (absolute position of each
+    query row, -1 = padding); q/o [KV, R, hd]; N k then N v refs [1, 1, page,
+    KV, hd], the block's pages in table order; N k then N v scale refs [_SCALE_ROWS, KV];
     scratch acc [KV, R, hd], m/l [KV, R, 1] f32."""
+    if window is not None:
+        min_pos_ref, *rest = rest
+    pos_ref, q_ref, *rest = rest
     k_refs, v_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
     if quantized:
         k_scale_refs, v_scale_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
@@ -178,12 +205,19 @@ def _kernel(entries_ref, max_pos_ref, pos_ref, q_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # the block holds live context iff some query position reaches it
-    @pl.when(max_pos_ref[b, r] >= j * block)
+    # the block holds live context iff some query position reaches it (and,
+    # under a window, the lowest one's window still does)
+    reached = max_pos_ref[b, r] >= j * block
+    if window is not None:
+        reached &= (j + 1) * block > _first_visible(min_pos_ref[b, r], window)
+
+    @pl.when(reached)
     def _process():
         col = j * block + jax.lax.broadcasted_iota(
             jnp.int32, (n_rows, block), 1)
         live = col <= pos_ref[...]                    # causal, on position
+        if window is not None:
+            live &= col > pos_ref[...] - window
         if quantized:
             slot_of = jax.lax.broadcasted_iota(
                 jnp.int32, (1, block), 1) // page_size
@@ -264,7 +298,7 @@ def _kernel(entries_ref, max_pos_ref, pos_ref, q_ref, *rest,
 
 
 def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
-                     k_scales, v_scales, interpret):
+                     k_scales, v_scales, interpret, window=None):
     """q: [B, KV, R, hd] (R query rows per kv head); row_pos: [B, R] int32
     absolute position of each row (-1 = padding) -> [B, KV, R, hd]."""
     B, KV, R, hd = q.shape
@@ -277,7 +311,7 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
     n_blocks = R // rows
     n = _kv_block_pages(n_pages, rows, page_size, KV)
 
-    def slot_entry(slot, b, r, j, entries, max_pos):
+    def slot_entry(slot, b, r, j, entries, *_):
         return entries[b * n_blocks + r, j * n + slot]
 
     def page_map(slot):
@@ -286,14 +320,14 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
     def scale_map(slot):
         return lambda *at: (layer, slot_entry(slot, *at) // _SCALE_ROWS, 0)
 
-    def row_map(b, r, j, entries, max_pos):
+    def row_map(b, r, j, *_):
         return (b, 0, r, 0)
 
     # not squeezed: a ref with squeezed dims cannot be bitcast
     page_specs = [pl.BlockSpec((1, 1, page_size, KV, hd), page_map(slot))
                   for slot in range(n)]
     in_specs = [
-        pl.BlockSpec((None, rows, 1), lambda b, r, j, t, m: (b, r, 0)),
+        pl.BlockSpec((None, rows, 1), lambda b, r, j, *_: (b, r, 0)),
         pl.BlockSpec((None, KV, rows, hd), row_map),
         *page_specs, *page_specs,
     ]
@@ -303,11 +337,18 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
                          for slot in range(n)]
         inputs += [*[k_scales] * n, *[v_scales] * n]
     max_pos = jnp.max(row_pos.reshape(B, n_blocks, rows), axis=2)
+    bounds, kernel = (max_pos,), functools.partial(
+        _kernel, page_size=page_size, n=n, quantized=quantized)
+    if window is not None:
+        # the lowest REAL query position a row block (padding is -1; a block
+        # of padding alone is dead by max_pos)
+        real = jnp.where(row_pos >= 0, row_pos, jnp.iinfo(jnp.int32).max)
+        bounds += (jnp.min(real.reshape(B, n_blocks, rows), axis=2),)
+        kernel = functools.partial(kernel, window=window)
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, n=n,
-                          quantized=quantized),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=1 + len(bounds),
             grid=(B, n_blocks, n_pages // n),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, KV, rows, hd), row_map),
@@ -320,7 +361,8 @@ def _paged_attention(q, row_pos, k_pages, v_pages, block_tables, layer,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
         name="paged_attention",
         interpret=interpret,
-    )(_block_entries(block_tables, max_pos, n, page_size), max_pos, *inputs)
+    )(_block_entries(block_tables, max_pos, n, page_size, *bounds[1:],
+                     window=window), *bounds, *inputs)
 
 
 _POOL_SPEC = P(None, None, None, "model", None)   # kv_pages, per shard
@@ -334,11 +376,13 @@ def _shard_specs(q_spec: P, quantized: bool) -> tuple:
     return (q_spec, _POOL_SPEC, _POOL_SPEC, P(), P(), scales, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh"))
+@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh",
+                                             "window"))
 def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
                                  q_positions, layer: int = 0,
                                  interpret: bool = False,
-                                 k_scales=None, v_scales=None, mesh=None):
+                                 k_scales=None, v_scales=None, mesh=None,
+                                 window: int | None = None):
     """Chunk (multi-query) attention: S queries per sequence walk the page
     list; causality rides the absolute query positions (cache position c
     attends iff c <= q_pos). Serves the prefix-cache suffix prefill, chunked
@@ -348,8 +392,9 @@ def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
     block_tables: [B, P] int32; q_positions: [B, S] int32 absolute
     positions (-1 = padding); k_scales/v_scales: [L, num_pages, KV] dequant
     scales for int8 pages (None = full-precision pages); ``mesh``: the
-    engine's mesh when the pool is sharded over its ``model`` axis
-    -> [B, S, KV, G, hd]."""
+    engine's mesh when the pool is sharded over its ``model`` axis;
+    ``window``: a query also sees no key at or below ``q_pos - window``
+    (module docstring) -> [B, S, KV, G, hd]."""
     def per_shard(q, k_pages, v_pages, block_tables, q_positions,
                   k_scales, v_scales):
         B, S, KV, G, hd = q.shape
@@ -357,7 +402,7 @@ def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
         rows = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, S * G, hd)
         out = _paged_attention(rows, jnp.repeat(q_positions, G, axis=1),
                                k_pages, v_pages, block_tables, layer,
-                               k_scales, v_scales, interpret)
+                               k_scales, v_scales, interpret, window)
         return out.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4)
 
     q_spec = P(None, None, "model", None, None)
@@ -366,23 +411,25 @@ def paged_chunk_attention_pallas(q, k_pages, v_pages, block_tables,
             q, k_pages, v_pages, block_tables, q_positions, k_scales, v_scales)
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh"))
+@functools.partial(jax.jit, static_argnames=("layer", "interpret", "mesh",
+                                             "window"))
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
                                   layer: int = 0, interpret: bool = False,
-                                  k_scales=None, v_scales=None, mesh=None):
+                                  k_scales=None, v_scales=None, mesh=None,
+                                  window: int | None = None):
     """One query token per sequence, attending its first ``seq_len`` cache
     positions (0 = inactive row, output zeros).
 
     q: [B, KV, G, hd]; k_pages/v_pages: [L, num_pages, page, KV, hd];
     block_tables: [B, P] int32; seq_lens: [B] int32; k_scales/v_scales:
-    [L, num_pages, KV] (None = full precision); ``mesh`` as in
-    :func:`paged_chunk_attention_pallas` -> [B, KV, G, hd]."""
+    [L, num_pages, KV] (None = full precision); ``mesh`` and ``window`` as
+    in :func:`paged_chunk_attention_pallas` -> [B, KV, G, hd]."""
     def per_shard(q, k_pages, v_pages, block_tables, seq_lens,
                   k_scales, v_scales):
         row_pos = jnp.broadcast_to(seq_lens[:, None] - 1,
                                    (q.shape[0], q.shape[2]))
         return _paged_attention(q, row_pos, k_pages, v_pages, block_tables,
-                                layer, k_scales, v_scales, interpret)
+                                layer, k_scales, v_scales, interpret, window)
 
     q_spec = P(None, "model", None, None)
     return on_model_axis(

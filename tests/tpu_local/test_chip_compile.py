@@ -211,6 +211,25 @@ def _sdar_paged_case(chunk: int, batch: int, table: int):
              ((batch, table), jnp.int32), ((batch, chunk), jnp.int32)])
 
 
+def _window_paged_case(chunk: int | None, batch: int):
+    """trinity-mini-d8.mixedctx-closed: the paged kernel under a window of
+    2048 over the six window layers' rings (33 rows x 25 ring pages and the
+    trash page, 4 kv heads, 8 query heads a kv head), the ring table the
+    step program hands it (32 columns: the narrowest context bucket that
+    holds a ring): a decode step of 32 rows, a chunk round's tile of 256
+    queries x 2 rows."""
+    pool = ((6, 33 * 25 + 1, PAGE, 4, HD), jnp.bfloat16)
+    tables = ((batch, 32), jnp.int32)
+    if chunk is None:
+        return (partial(paged.paged_decode_attention_pallas, layer=5,
+                        window=2048),
+                [((batch, 4, 8, HD), jnp.bfloat16), pool, pool, tables,
+                 ((batch,), jnp.int32)])
+    return (partial(paged.paged_chunk_attention_pallas, layer=5, window=2048),
+            [((batch, chunk, 4, 8, HD), jnp.bfloat16), pool, pool, tables,
+             ((batch, chunk), jnp.int32)])
+
+
 def _sdar_moe_case(tokens: int = 2048, block: int = BLOCK):
     """The row-block kernel over 128 int8 experts: a [4, 512] prefill's plan,
     2048 x 8 pairs in blocks of 128 rows, one spare block an expert; or a
@@ -310,6 +329,10 @@ KERNEL_CASES = {
     "mla_attention_verify_32x2x4": lambda: _mla_verify_case(4),
     "mla_attention_verify_32x2x64": lambda: _mla_verify_case(64),
     "sparse_index_half_chunk_2x512x128": lambda: _index_case(512),
+    # the paged kernel with a lower bound on what a query sees (PR 43): the
+    # window layers' calls of the cell, decode width and a chunk round's tile
+    "paged_decode_bf16_window_32x32": lambda: _window_paged_case(None, 32),
+    "paged_chunk_bf16_window_tile256x32": lambda: _window_paged_case(256, 2),
 }
 
 

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from mcp_context_forge_tpu.tpu_local.ops.grouped_moe import (
-    _expert_blocks_pallas, _expert_blocks_xla, grouped_flops, moe_ffn_grouped,
+    _expert_blocks_pallas, _expert_blocks_xla, experts_grouped, grouped_flops,
+    moe_ffn_grouped,
     plan_sorted_blocks, top_k_gates)
 from mcp_context_forge_tpu.tpu_local.parallel.moe import (
     MoEConfig, init_moe_params, moe_ffn_dense_mask, router_probs)
@@ -355,8 +356,9 @@ def test_decode_shapes_fall_back_to_dense():
 
     from mcp_context_forge_tpu.tpu_local.models.llama import _ffn_block
     layer = dict(params)
+    # the grouped formulation every router feeds (``llama.routed_experts``)
     grouped_fn = ("mcp_context_forge_tpu.tpu_local.ops.grouped_moe."
-                  "moe_ffn_grouped")
+                  "experts_grouped")
     with mock.patch(grouped_fn) as spy:
         out = _ffn_block(layer, _Cfg(), x)
         # as wide as a Mixtral decode batch gets, and a verify step's width
@@ -368,7 +370,7 @@ def test_decode_shapes_fall_back_to_dense():
         rtol=2e-5, atol=2e-6)
     # a prefill-shaped call with the same config DOES take the grouped path
     big = _x((4, 32), seed=12)  # T=128, k=2 -> 256 >= E*block=128
-    with mock.patch(grouped_fn, wraps=moe_ffn_grouped) as spy:
+    with mock.patch(grouped_fn, wraps=experts_grouped) as spy:
         grouped = _ffn_block(layer, _Cfg(), big)
         assert spy.call_args.kwargs["block"] == 16
     np.testing.assert_allclose(
@@ -385,7 +387,7 @@ def test_decode_shapes_fall_back_to_dense():
 
     _, step_params, _, step_x, live = _padding_case("block_step_b8", "full")
     valid = jnp.arange(4)[None, :] < jnp.asarray(live)[:, None]
-    with mock.patch(grouped_fn, wraps=moe_ffn_grouped) as spy:
+    with mock.patch(grouped_fn, wraps=experts_grouped) as spy:
         narrow = _ffn_block(dict(step_params), _Step(), step_x, valid=valid)
         assert spy.call_args.kwargs["block"] == 8
     m = np.asarray(valid)
