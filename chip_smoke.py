@@ -822,20 +822,11 @@ def _release(engine) -> None:
 
 def _decode_example_args(engine):
     """Arguments of the shapes (and shardings) the engine's decode step
-    was compiled for, to look its executable up again."""
-    import jax
-    import jax.numpy as jnp
-
-    from mcp_context_forge_tpu.tpu_local.sampling import SamplingParams
-
-    B = engine.config.max_batch
-    zeros = jnp.zeros((B,), jnp.int32)
-    sampling = SamplingParams(jnp.zeros((B,), jnp.float32), zeros,
-                              jnp.ones((B,), jnp.float32))
-    return (engine.params, engine.kv, zeros, zeros,
-            jnp.arange(B, dtype=jnp.int32), zeros, zeros,
-            jnp.full((B, engine._STOP_TBL_WIDTH), -1, jnp.int32), sampling,
-            jax.random.PRNGKey(0))
+    was compiled for, to look its executable up again: params, kv, one packed
+    call of idle rows and the base key."""
+    return (engine.params, engine.kv,
+            engine._idle_call(engine._decode_call, engine.config.max_batch),
+            engine._rng)
 
 
 def _all_gather_shapes(hlo_text: str) -> list[str]:

@@ -809,6 +809,9 @@ document.getElementById("f").onsubmit = async (e) => {
             "superstep": engine.config.fused_steps,
             "prefill_batches": stats.prefill_batches,
             "prefill_requests": stats.prefill_requests,
+            # host-to-device transfers made for dispatches: over
+            # decode_dispatches + prefill_batches, ~1 a dispatch
+            "host_uploads": stats.host_uploads,
             # dense prefills: the prompt tokens they carried, the positions
             # they dispatched (padded rows x length: 1 - tokens / positions
             # is the padding share) and those that took the half-length

@@ -77,6 +77,9 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
         "decode_dispatches": stats.decode_dispatches,
         "superstep": engine.config.fused_steps,
         "prefill_batches": stats.prefill_batches,
+        # host-to-device transfers made for dispatches (one packed call
+        # each, a table sync where rows were dirty): per step in "steps"
+        "host_uploads": stats.host_uploads,
         # dense prefills: prompt tokens carried, positions dispatched, and
         # dispatches through a bucket's half-length program
         "dense_prefill_tokens": stats.dense_prefill_tokens,

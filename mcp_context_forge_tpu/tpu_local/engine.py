@@ -11,10 +11,11 @@ jax.device_get"). XLA's static-shape discipline is respected everywhere:
   amortize the forward pass instead of serializing behind each other;
 - decode compiles once for the full [max_batch] slot array — inactive slots
   ride along masked (position 0 into the trash page);
-- sampling params are per-slot device arrays, so mixed greedy/temperature
-  requests share one compiled step, and the FIRST token is sampled on
-  device with the same kernel + engine PRNG as every later token (one
-  sampler, one RNG stream).
+- sampling params are per-row columns of the one packed call a dispatch
+  uploads (call_layout.py), so mixed greedy/temperature requests share one
+  compiled step, and the FIRST token is sampled on device with the same
+  kernel + engine PRNG as every later token (one sampler; each step's key
+  folded inside its program from one base key and the dispatch's number).
 
 The engine is a single-owner of its mesh/slice: gateway workers reach it
 in-process (single worker) or over the /v1 HTTP surface (multi-worker),
@@ -44,6 +45,7 @@ from ..observability.faults import fault_point
 from ..observability.logging import trace_extra
 from ..observability.timeline import (STALL_S, StepCounts, StepTimeline,
                                       gc_watch)
+from .call_layout import CALL_TAIL, CallLayout, Field
 from .compile_events import (CompileTracker, install_listener,
                              restore_thread, track_thread)
 from .kv import PageAllocator, kv_resident_bytes
@@ -388,6 +390,9 @@ class EngineStats:
         #                               decode_steps / decode_dispatches ≈ K
         self.prefill_batches = 0
         self.prefill_requests = 0
+        # host-to-device transfers made for dispatches: the one packed call
+        # of each (call_layout.py), and a table sync where rows were dirty
+        self.host_uploads = 0
         # what dense prefills (no history, no chunk round) carried and what
         # they ran: prompt tokens, positions dispatched (padded rows x padded
         # length; 1 - tokens / positions is the padding share), and the
@@ -858,13 +863,47 @@ class TPUEngine:
             self._kv_dtype = dtype
             self._init_kv()
 
-        self._rng = jax.random.PRNGKey(int(time.time()) & 0x7FFFFFFF)
+        # what a dispatch hands its step program beside params and kv: ONE
+        # packed call (call_layout.py), replicated over the mesh as the
+        # block table is, and the base key, which stays on the device; the
+        # program folds the dispatch's number (a column of the call) into
+        # it, so no two dispatches share a key and none costs a key-split
+        # program
+        self._call_sharding = jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec())
+        self._rng = jax.device_put(
+            jax.random.PRNGKey(int(time.time()) & 0x7FFFFFFF),
+            self._call_sharding)
+        self._dispatch_no = 0  # lint: thread[dispatch]
+        self._uploads_claimed = 0  # lint: thread[dispatch]
+        i32 = np.int32
+        decode_rows = (Field("positions", 0, i32), Field("seq_lens", 0, i32),
+                       Field("budgets", 0, i32),
+                       Field("stop_tbl", self._STOP_TBL_WIDTH, i32, -1))
+        self._decode_call = CallLayout(Field("tokens", 0, i32), *decode_rows,
+                                       *CALL_TAIL)
+        # a device-fed step takes its tokens from the step in flight
+        self._decode_fb_call = CallLayout(*decode_rows, *CALL_TAIL)
+        self._block_call = CallLayout(
+            Field("tokens", self._block, i32, self.model_config.mask_token_id),
+            Field("positions", self._block, i32, -1),
+            Field("masked", self._block, np.bool_),
+            *CALL_TAIL) if self._block else None
+        self._verify_call = CallLayout(
+            Field("tokens", config.spec_k, i32),
+            Field("positions", config.spec_k, i32, -1),
+            *CALL_TAIL) if config.spec_decode else None
+        self._prefill_calls: dict[int, CallLayout] = {}
 
         # compiled steps
-        self._prefill_sample = jax.jit(self._prefill_and_sample,
-                                       donate_argnames=("kv",))
+        self._prefill_sample = _named(
+            jax.jit(self._of_call(self._prefill_and_sample,
+                                  self._prefill_call_of_width),
+                    donate_argnames=("kv",)), "_prefill_and_sample")
         self._prefill_sample_sp = (
-            _named(jax.jit(partial(self._prefill_and_sample, sp=True),
+            _named(jax.jit(partial(self._of_call(self._prefill_and_sample,
+                                                 self._prefill_call_of_width),
+                                   sp=True),
                            donate_argnames=("kv",)), "_prefill_and_sample")
             if config.sp_impl != "none" else None)
         self.half_lengths = self._find_half_lengths()
@@ -1160,7 +1199,8 @@ class TPUEngine:
         key = (k, batch or self.config.max_batch, ctx_pages)
         fn = self._decode_fns.get(key)
         if fn is None:
-            fn = _named(jax.jit(partial(self._decode_and_sample,
+            fn = _named(jax.jit(partial(self._of_call(self._decode_and_sample,
+                                                      self._decode_call),
                                         ctx_pages=ctx_pages, k=k),
                                 donate_argnames=("kv",)),
                         "_decode_and_sample")
@@ -1173,9 +1213,10 @@ class TPUEngine:
         key = (k, batch or self.config.max_batch, ctx_pages)
         fn = self._decode_fb_fns.get(key)
         if fn is None:
-            fn = _named(jax.jit(partial(self._decode_and_sample_fb,
-                                        ctx_pages=ctx_pages, k=k),
-                                donate_argnames=("kv",)),
+            fn = _named(jax.jit(partial(
+                self._of_call(self._decode_and_sample_fb,
+                              self._decode_fb_call),
+                ctx_pages=ctx_pages, k=k), donate_argnames=("kv",)),
                         "_decode_and_sample_fb")
             self._decode_fb_fns[key] = fn
         return fn
@@ -1184,9 +1225,10 @@ class TPUEngine:
         key = (batch or self.config.max_batch, ctx_pages)
         fn = self._block_fns.get(key)
         if fn is None:
-            fn = _named(jax.jit(partial(self._decode_and_sample_block,
-                                        ctx_pages=ctx_pages),
-                                donate_argnames=("kv",)),
+            fn = _named(jax.jit(partial(
+                self._of_call(self._decode_and_sample_block,
+                              self._block_call),
+                ctx_pages=ctx_pages), donate_argnames=("kv",)),
                         "_decode_and_sample_block")
             self._block_fns[key] = fn
         return fn
@@ -1290,9 +1332,10 @@ class TPUEngine:
     def _hist_fn(self, ctx_pages: int):
         fn = self._prefill_hist_fns.get(ctx_pages)
         if fn is None:
-            fn = _named(jax.jit(partial(self._prefill_hist_and_sample,
-                                        ctx_pages=ctx_pages),
-                                donate_argnames=("kv",)),
+            fn = _named(jax.jit(partial(
+                self._of_call(self._prefill_hist_and_sample,
+                              self._prefill_call_of_width),
+                ctx_pages=ctx_pages), donate_argnames=("kv",)),
                         "_prefill_hist_and_sample")
             self._prefill_hist_fns[ctx_pages] = fn
         return fn
@@ -1347,29 +1390,10 @@ class TPUEngine:
             # against the PRE-transition kv would bake the init placement
             # into the first shape and recompile it at first traffic hit
             b0 = min(self.config.prefill_buckets)
-            settle = SamplingParams(jnp.zeros((1,), jnp.float32),
-                                    jnp.zeros((1,), jnp.int32),
-                                    jnp.ones((1,), jnp.float32))
             first, self.kv = self._prefill_sample(
                 self.params, self.kv,
-                jnp.full((1, b0), self.tokenizer.pad_id, jnp.int32),
-                jnp.full((1, b0), -1, jnp.int32),
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                settle, jax.random.PRNGKey(0), *self._no_follow(1))
+                self._idle_call(self._prefill_call(b0), 1), self._rng)
             jax.block_until_ready(first)
-            # utility-kernel warmup: the dispatch thread's first
-            # jax.random.split UNPACK (a slice program) and _sync_tables'
-            # sharded block-table device_put would otherwise be tiny
-            # serving-stage compiles, polluting the zero-mid-traffic-
-            # compile invariant the compile tracker guards. After the
-            # settle call so the table sharding is the canonical one.
-            _k1, _k2 = jax.random.split(self._rng)
-            del _k1, _k2
-            jax.device_put(self.allocator.tables(),
-                           self.kv.block_tables.sharding)
-            if self.allocator.state_rows:
-                jax.device_put(self.allocator.state_row_table(),
-                               self.kv.state_rows.sharding)
             if self._tier_read_fn is not None:
                 # spill/restore executables: compile both directions now
                 # (against the trash page — contents are zeros either
@@ -1407,18 +1431,9 @@ class TPUEngine:
                         fns = [self._prefill_sample]
                         if hist_reachable:
                             fns.extend(self._hist_fn(cp) for cp in hist_ctx)
-                    samp = SamplingParams(jnp.zeros((B,), jnp.float32),
-                                          jnp.zeros((B,), jnp.int32),
-                                          jnp.ones((B,), jnp.float32))
                     for fn in fns:
-                        args = (self.params, self.kv,
-                                jnp.full((B, bucket), self.tokenizer.pad_id,
-                                         jnp.int32),
-                                jnp.full((B, bucket), -1, jnp.int32),
-                                jnp.zeros((B,), jnp.int32),
-                                jnp.zeros((B,), jnp.int32),
-                                samp, jax.random.PRNGKey(0),
-                                *self._no_follow(B))
+                        args = (self.params, self.kv, self._idle_call(
+                            self._prefill_call(bucket), B), self._rng)
                         # cost entries: the dense prefill, and the history
                         # prefill at its narrowest context bucket
                         kind = ("prefill" if fn is self._prefill_sample
@@ -1434,18 +1449,9 @@ class TPUEngine:
                 half = self.half_lengths.get(bucket)
                 if half is not None:
                     # the bucket's half program (_find_half_lengths): the
-                    # dense function alone, at width 1 alone. Its rows come
-                    # off the host as a dispatch's do, so that it is the one
-                    # compile this adds (a jnp.full is a small one a shape)
-                    args = (self.params, self.kv,
-                            jnp.asarray(np.full((1, half),
-                                                self.tokenizer.pad_id,
-                                                np.int32)),
-                            jnp.asarray(np.full((1, half), -1, np.int32)),
-                            jnp.zeros((1,), jnp.int32),
-                            jnp.zeros((1,), jnp.int32),
-                            settle, jax.random.PRNGKey(0),
-                            *self._no_follow(1))
+                    # dense function alone, at width 1 alone
+                    args = (self.params, self.kv, self._idle_call(
+                        self._prefill_call(half), 1), self._rng)
                     if capture:
                         self.cost_registry.capture(
                             "prefill", 1, half, self._prefill_sample, *args)
@@ -1453,16 +1459,10 @@ class TPUEngine:
                     jax.block_until_ready(first)
                     shapes += 1
             B = self.config.max_batch
-            samp = SamplingParams(jnp.zeros((B,), jnp.float32),
-                                  jnp.zeros((B,), jnp.int32),
-                                  jnp.ones((B,), jnp.float32))
             if self._verify_fns is not None:
                 for ctx_pages in self._ctx_buckets():
                     args = (self.params, self.kv,
-                            jnp.zeros((B, self.config.spec_k), jnp.int32),
-                            jnp.full((B, self.config.spec_k), -1, jnp.int32),
-                            jnp.arange(B, dtype=jnp.int32), samp,
-                            jax.random.PRNGKey(0))
+                            self._idle_call(self._verify_call, B), self._rng)
                     if capture:
                         self.cost_registry.capture(
                             "spec_verify", B, ctx_pages,
@@ -1473,8 +1473,9 @@ class TPUEngine:
             # plain decode is always live: spec engines fall back to it on
             # steps where no greedy row would draft (width-K verify would be
             # pure compute waste — round-2 ADVICE low). One compile per
-            # (batch-width, context-width) bucket pair.
-            # seq_lens=0: every slot is "inactive", writes masked to trash
+            # (batch-width, context-width) bucket pair. An idle call's
+            # seq_lens are 0: every slot is "inactive", writes masked to
+            # trash, budgets zero, the stop table empty
             widths = (self._batch_buckets() if self.config.batch_buckets
                       else [self.config.max_batch])
             # the K ladder multiplies the grid: every (width, ctx, K rung)
@@ -1488,15 +1489,6 @@ class TPUEngine:
                 shapes += self._warmup_block_steps(widths)
                 widths = []
             for batch in widths:
-                bsamp = SamplingParams(jnp.zeros((batch,), jnp.float32),
-                                       jnp.zeros((batch,), jnp.int32),
-                                       jnp.ones((batch,), jnp.float32))
-                # super-step freeze inputs (values are irrelevant to the
-                # compile — jit keys on shape/dtype): zero budgets, empty
-                # stop table
-                wbudget = jnp.zeros((batch,), jnp.int32)
-                wstops = jnp.full((batch, self._STOP_TBL_WIDTH), -1,
-                                  jnp.int32)
                 for ctx_pages in self._ctx_buckets():
                     for k_rung in k_rungs:
                         # cost entries for non-default rungs carry the
@@ -1505,12 +1497,8 @@ class TPUEngine:
                         # cost); the static rung keeps the bare kind the
                         # existing roofline consumers look up
                         suffix = "" if k_rung == self._k else f"@k{k_rung}"
-                        args = (self.params, self.kv,
-                                jnp.zeros((batch,), jnp.int32),
-                                jnp.zeros((batch,), jnp.int32),
-                                jnp.arange(batch, dtype=jnp.int32),
-                                jnp.zeros((batch,), jnp.int32), wbudget,
-                                wstops, bsamp, jax.random.PRNGKey(0))
+                        args = (self.params, self.kv, self._idle_call(
+                            self._decode_call, batch), self._rng)
                         if capture:
                             self.cost_registry.capture(
                                 "decode" + suffix, batch, ctx_pages,
@@ -1530,12 +1518,9 @@ class TPUEngine:
                             # output, and the pjit cache keys on that
                             # committed sharding (a fresh jnp.zeros here
                             # would warm a cache entry traffic never hits)
-                            fb_args = (self.params, self.kv, block,
-                                       jnp.zeros((batch,), jnp.int32),
-                                       jnp.arange(batch, dtype=jnp.int32),
-                                       jnp.zeros((batch,), jnp.int32),
-                                       wbudget, wstops, bsamp,
-                                       jax.random.PRNGKey(0))
+                            fb_args = (self.params, self.kv, self._idle_call(
+                                self._decode_fb_call, batch), self._rng,
+                                block)
                             if capture:
                                 self.cost_registry.capture(
                                     "decode_fb" + suffix, batch, ctx_pages,
@@ -1559,29 +1544,15 @@ class TPUEngine:
         logger.info("tpu_local warmup: %d shapes compiled in %.1fs",
                     shapes, time.monotonic() - started)
 
-    def _no_follow(self, batch: int) -> tuple:
-        """What a warm-up call of a prefill program passes after the key:
-        for a family that drafts on the device the ``follow`` row of
-        :meth:`_draft_beside` (no row's prompt goes on), else nothing."""
-        return ((jnp.full((batch,), -1, jnp.int32),) if self._drafts else ())
-
     def _warmup_block_steps(self, widths: list[int]) -> int:
         """Compile the block step for every (width, context bucket): rows
         with positions -1 are idle, write the trash page and mask nothing,
         so the loop makes no pass and the commit pass runs once."""
-        Bl = self._block
         for batch in widths:
-            samp = SamplingParams(jnp.zeros((batch,), jnp.float32),
-                                  jnp.zeros((batch,), jnp.int32),
-                                  jnp.ones((batch,), jnp.float32))
             for ctx_pages in self._ctx_buckets():
                 (block, *_), self.kv = self._block_fn(ctx_pages, batch)(
                     self.params, self.kv,
-                    jnp.zeros((batch, Bl), jnp.int32),
-                    jnp.full((batch, Bl), -1, jnp.int32),
-                    jnp.zeros((batch, Bl), bool),
-                    jnp.arange(batch, dtype=jnp.int32), samp,
-                    jax.random.PRNGKey(0))
+                    self._idle_call(self._block_call, batch), self._rng)
                 block.block_until_ready()
             self._warmed_widths.add(batch)
         return len(widths) * len(self._ctx_buckets())
@@ -1595,6 +1566,58 @@ class TPUEngine:
         impl = self._family.paged_impl(self.mesh, self.model_config, kv)
         self.attn_traced[step] = impl
         return impl
+
+    def _of_call(self, step, layout):
+        """``step`` (a step function below) as the program of one packed
+        call: ``(params, kv, packed, base_key, *fed, **static)``. The program
+        slices ``packed`` apart by ``layout`` (a :class:`CallLayout`, or a
+        function of the call's width that gives one), whose fields carry the
+        step function's own parameter names; ``slot_ids`` is the rows' own
+        numbers where the call has none (a decode width's rows ARE its
+        slots); the step's key is the base key with the call's dispatch
+        number folded in. ``fed`` goes on before them as it came (the block
+        of the step in flight)."""
+        layout_of = layout if callable(layout) else (lambda _width: layout)
+
+        def program(params, kv, packed, base_key, *fed, **static):
+            fields = layout_of(packed.shape[1]).unpack(packed)
+            key = jax.random.fold_in(base_key, fields.pop("counter")[0])
+            sampling = SamplingParams(
+                *(fields.pop(name) for name in SamplingParams._fields))
+            fields.setdefault("slot_ids", jnp.arange(packed.shape[0],
+                                                     dtype=jnp.int32))
+            return step(params, kv, *fed, sampling=sampling, key=key,
+                        **fields, **static)
+        # a capture names a jitted call after the function: the step's own
+        program.__name__ = program.__qualname__ = step.__name__
+        return program
+
+    def _prefill_call(self, length: int) -> CallLayout:
+        """The call of a prefill program (dense, history, chunk round) over
+        rows padded to ``length``; under a family that drafts on the device
+        with ``follow`` (:meth:`_draft_beside`)."""
+        layout = self._prefill_calls.get(length)
+        if layout is None:
+            i32 = np.int32
+            follow = (Field("follow", 0, i32, -1),) if self._drafts else ()
+            layout = self._prefill_calls[length] = CallLayout(
+                Field("tokens", length, i32, self.tokenizer.pad_id),
+                Field("positions", length, i32, -1),
+                Field("last_idx", 0, i32), Field("slot_ids", 0, i32),
+                *follow, *CALL_TAIL)
+        return layout
+
+    def _prefill_call_of_width(self, width: int) -> CallLayout:
+        """... found again inside the program, from the call's width: two
+        fields of ``length`` columns and one column each of the rest."""
+        return self._prefill_call(
+            (width - 2 - bool(self._drafts) - len(CALL_TAIL)) // 2)
+
+    def _idle_call(self, layout: CallLayout, rows: int):
+        """A call of ``rows`` idle rows on the device, placed as a dispatch
+        places its own: what warm-up runs a program on (positions -1 or
+        lengths 0: every write lands on the trash page)."""
+        return jax.device_put(layout.host(rows)[0], self._call_sharding)
 
     def _prefill_and_sample(self, params, kv, tokens, positions, slot_ids,
                             last_idx, sampling: SamplingParams, key,
@@ -1678,8 +1701,9 @@ class TPUEngine:
             step, name = ((self._decode_and_sample_draft,
                            "_decode_and_sample_draft") if self._drafts
                           else (self._verify_and_sample, "_verify_and_sample"))
-            fn = _named(jax.jit(partial(step, ctx_pages=ctx_pages),
-                                donate_argnames=("kv",)), name)
+            fn = _named(jax.jit(partial(
+                self._of_call(step, self._verify_call), ctx_pages=ctx_pages),
+                donate_argnames=("kv",)), name)
             self._verify_fns[ctx_pages] = fn
         return fn
 
@@ -2705,11 +2729,10 @@ class TPUEngine:
         parts: dict[str, Any] = {}
         with tl.span("prefill.build", seq, kind) as build:
             with tl.span("prefill.build.rows", seq, kind) as parts["rows"]:
-                arrays, per_row = self._pack_rows(
+                call, fields, per_row = self._pack_rows(
                     [(r, r.hist, self._prefill_end(r)) for r in admitted],
                     length)
-            sampling, key = self._sample_and_split("prefill", seq, kind,
-                                                   per_row, parts)
+            self._seal_call("prefill", seq, kind, fields, per_row, parts)
             # long buckets route through the sequence-parallel attention
             # path (shape-deterministic: SP-ness is a property of the
             # bucket; SP groups never carry history — _assign_bucket
@@ -2725,7 +2748,7 @@ class TPUEngine:
             else:
                 prefill_fn = self._prefill_sample
         first, dispatch = self._launch_prefill(prefill_fn, seq, kind, build,
-                                               arrays, sampling, key, parts)
+                                               call, parts)
         with tl.span("prefill.sync", seq, kind) as sync:
             first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] first-token fetch: prefill result feeds host-side admission
         drafts_host = aux_host.pop() if self._drafts else None
@@ -2734,7 +2757,7 @@ class TPUEngine:
         self.stats.prefill_ms_total += elapsed_ms
         self.stats.prefill_batches += 1
         self.stats.prefill_requests += len(admitted)
-        width = int(arrays[0].shape[0])  # the dispatched pad
+        width = call.shape[0]  # the dispatched pad
         if not any_hist:
             real = sum(self._prefill_end(r) for r in admitted)
             self.stats.dense_prefill_tokens += real
@@ -2755,7 +2778,7 @@ class TPUEngine:
         self._record_step("prefill", seq=seq, batch=len(admitted),
                           width=width, dur_ms=elapsed_ms,
                           tokens=0 if self._block else len(admitted),
-                          bucket=length,
+                          bucket=length, host_uploads=self._claim_uploads(),
                           phases=self._phase_row(parts, build, sync))
         with tl.span("prefill.emit", seq, kind):
             for i, request in enumerate(admitted):
@@ -2773,58 +2796,52 @@ class TPUEngine:
                     self._keep_draft(request, drafts_host, i)
 
     def _pack_rows(self, rows: list[tuple[GenRequest, int, int]], S: int):
-        """Pack [(request, start, end)] prompt spans into padded [B, S]
-        host arrays ``(tokens, positions, last_idx, slot_ids)`` + the rows'
-        sampling parameters ``(temperature, top_k, top_p)``. B pads to the
-        next power of two so XLA compiles at most log2(prefill_max_batch)+1
-        shapes per width; padding rows have positions -1 (no KV write — the
-        same masking decode uses for inactive slots) and their samples are
+        """Pack [(request, start, end)] prompt spans into the call of a
+        prefill program padded to ``S`` (:meth:`_prefill_call`: ``tokens``,
+        ``positions`` [B, S], ``last_idx``, ``slot_ids`` [B]). Returns the
+        call's buffer, its fields (views of it) and the rows' sampling
+        parameters ``(temperature, top_k, top_p)``. B pads to the next power
+        of two so XLA compiles at most log2(prefill_max_batch)+1 shapes per
+        width; padding rows stay idle (positions -1: no KV write — the same
+        masking decode uses for inactive slots) and their samples are
         discarded. Shared by dense/suffix prefill and chunk rounds. Under a
-        family that drafts on the device a fifth array ``follow`` [B] says
-        what follows each row's span: the prompt's next token where it goes
-        on in a later chunk, -1 where it ends here (:meth:`_draft_beside`)."""
+        family that drafts on the device the field ``follow`` [B] says what
+        follows each row's span: the prompt's next token where it goes on in
+        a later chunk, -1 where it ends here (:meth:`_draft_beside`)."""
         B = 1
         while B < len(rows):
             B *= 2
-        tokens = np.full((B, S), self.tokenizer.pad_id, dtype=np.int32)
-        positions = np.full((B, S), -1, dtype=np.int32)
-        last_idx = np.zeros((B,), dtype=np.int32)
-        slot_ids = np.zeros((B,), dtype=np.int32)
+        call, fields = self._prefill_call(S).host(B)
         temperature = np.zeros((B,), dtype=np.float32)
         top_k = np.zeros((B,), dtype=np.int32)
         top_p = np.ones((B,), dtype=np.float32)
-        follow = np.full((B,), -1, dtype=np.int32)
         for i, (request, start, end) in enumerate(rows):
             n = end - start
-            tokens[i, :n] = request.prompt_ids[start:end]
-            positions[i, :n] = np.arange(start, end)
-            last_idx[i] = max(n - 1, 0)
-            slot_ids[i] = request.slot
+            fields["tokens"][i, :n] = request.prompt_ids[start:end]
+            fields["positions"][i, :n] = np.arange(start, end)
+            fields["last_idx"][i] = max(n - 1, 0)
+            fields["slot_ids"][i] = request.slot
             temperature[i] = request.temperature
             top_k[i] = request.top_k
             top_p[i] = request.top_p
-            if end < len(request.prompt_ids):
-                follow[i] = request.prompt_ids[end]
-        arrays = (tokens, positions, last_idx, slot_ids)
-        return (arrays + ((follow,) if self._drafts else ()),
-                (temperature, top_k, top_p))
+            if self._drafts and end < len(request.prompt_ids):
+                fields["follow"][i] = request.prompt_ids[end]
+        return call, fields, (temperature, top_k, top_p)
 
-    def _launch_prefill(self, prefill_fn, seq: int, kind: str, build, arrays,
-                        sampling: SamplingParams, key, parts: dict[str, Any]):
+    def _launch_prefill(self, prefill_fn, seq: int, kind: str, build,
+                        call: np.ndarray, parts: dict[str, Any]):
         """The ``prefill.dispatch`` span of a prefill or a chunk round: the
-        packed rows onto the device, then the jitted call alone. Returns the
+        packed call onto the device, then the jitted call alone. Returns the
         program's result (still on the device) and the span."""
         tl = self.timeline
         with tl.span("prefill.dispatch", seq, kind) as dispatch:
             with tl.span("prefill.dispatch.upload", seq, kind) \
                     as parts["upload"]:
-                tokens, positions, last_idx, slot_ids, *follow = map(
-                    jnp.asarray, arrays)
+                packed = self._upload(call)
             with tl.span("prefill.dispatch.launch", seq, kind) \
                     as parts["launch"]:
-                first, self.kv = prefill_fn(
-                    self.params, self.kv, tokens, positions,
-                    slot_ids, last_idx, sampling, key, *follow)
+                first, self.kv = prefill_fn(self.params, self.kv, packed,
+                                            self._rng)
         self._host_fed(seq, kind, build, dispatch, parts)
         return first, dispatch
 
@@ -2861,12 +2878,11 @@ class TPUEngine:
                     rows.append((request, start, end))
                     request.chunk_pos = end
                     max_end = max(max_end, end)
-                arrays, per_row = self._pack_rows(rows, S)
-            sampling, key = self._sample_and_split("prefill", seq, "chunk",
-                                                   per_row, parts)
+                call, fields, per_row = self._pack_rows(rows, S)
+            self._seal_call("prefill", seq, "chunk", fields, per_row, parts)
             hist_fn = self._hist_fn(self._hist_ctx_for(max_end))
         first, dispatch = self._launch_prefill(hist_fn, seq, "chunk", build,
-                                               arrays, sampling, key, parts)
+                                               call, parts)
         with tl.span("prefill.sync", seq, "chunk") as sync:
             first_host, *aux_host = _apart(jax.device_get(first))  # lint: allow[host-sync-in-hot-path] chunk-round boundary: host decides next chunk from these tokens
         drafts_host = aux_host.pop() if self._drafts else None
@@ -2874,7 +2890,7 @@ class TPUEngine:
         elapsed_ms = (sync.t1 - build.t0) * 1000
         self.stats.prefill_batches += 1
         self.stats.prefill_ms_total += elapsed_ms
-        width = int(arrays[0].shape[0])
+        width = call.shape[0]
         self._count_expert_path(width * S)
         tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1,
                 counts)
@@ -2883,7 +2899,8 @@ class TPUEngine:
             dur_ms=elapsed_ms,
             tokens=0 if self._block else sum(
                 1 for r in batch if r.chunk_pos >= len(r.prompt_ids)),
-            bucket=S, phases=self._phase_row(parts, build, sync))
+            bucket=S, host_uploads=self._claim_uploads(),
+            phases=self._phase_row(parts, build, sync))
         with tl.span("prefill.emit", seq, "chunk"):
             for i, request in enumerate(batch):
                 request.prefill_ms += elapsed_ms
@@ -2966,11 +2983,10 @@ class TPUEngine:
         parts: dict[str, Any] = {}
         with tl.span("decode.build", seq, "spec") as build:
             with tl.span("decode.build.rows", seq, "spec") as parts["rows"]:
-                tokens, positions, per_row, widths, chunks = \
+                call, fields, per_row, widths, chunks = \
                     self._spec_rows(active, B, K)
-            sampling, key = self._sample_and_split("decode", seq, "spec",
-                                                   per_row, parts)
-            max_pos = int(positions.max()) + 1 if active else K
+            self._seal_call("decode", seq, "spec", fields, per_row, parts)
+            max_pos = int(fields["positions"].max()) + 1 if active else K
             spec_ctx_pages = self._ctx_bucket_for(max_pos)
         with tl.span("decode.table_sync", seq, "spec") as parts["table_sync"]:
             self._sync_tables()
@@ -2978,12 +2994,11 @@ class TPUEngine:
             verify_fn = self._verify_fn(spec_ctx_pages)
             with tl.span("decode.dispatch.upload", seq, "spec") \
                     as parts["upload"]:
-                args = (jnp.asarray(tokens), jnp.asarray(positions),
-                        jnp.arange(B, dtype=jnp.int32))
+                packed = self._upload(call)
             with tl.span("decode.dispatch.launch", seq, "spec") \
                     as parts["launch"]:
-                block, self.kv = verify_fn(self.params, self.kv, *args,
-                                           sampling, key)
+                block, self.kv = verify_fn(self.params, self.kv, packed,
+                                           self._rng)
         self._host_fed(seq, "spec", build, dispatch, parts)
         self.stats.decode_steps += 1
         self.stats.decode_dispatches += 1
@@ -3036,16 +3051,17 @@ class TPUEngine:
                           dur_ms=spec_elapsed_ms, tokens=spec_emitted,
                           ctx_pages=spec_ctx_pages, mfu=mfu,
                           hbm_frac=hbm_frac,
+                          host_uploads=self._claim_uploads(),
                           phases=self._phase_row(parts, build, readback))
 
     def _spec_rows(self, active: list[tuple[int, GenRequest]], B: int,
                    K: int):
         """Pack the verify step's [B, K] rows: each active slot's last
         token plus its drafts, cut to the pages the pool grants. Returns
-        (tokens, positions, the rows' sampling parameters, usable width and
-        chunk by slot)."""
-        tokens = np.zeros((B, K), dtype=np.int32)
-        positions = np.full((B, K), -1, dtype=np.int32)
+        (the call's buffer, its fields ``tokens`` and ``positions``, the
+        rows' sampling parameters, usable width and chunk by slot)."""
+        call, fields = self._verify_call.host(B)
+        tokens, positions = fields["tokens"], fields["positions"]
         temperature = np.zeros((B,), dtype=np.float32)
         top_k = np.zeros((B,), dtype=np.int32)
         top_p = np.ones((B,), dtype=np.float32)
@@ -3080,7 +3096,7 @@ class TPUEngine:
             temperature[slot] = request.temperature
             top_k[slot] = request.top_k
             top_p[slot] = request.top_p
-        return tokens, positions, (temperature, top_k, top_p), widths, chunks
+        return call, fields, (temperature, top_k, top_p), widths, chunks
 
     # ------------------------------------------------------------ decode step
 
@@ -3307,19 +3323,17 @@ class TPUEngine:
         with tl.span("decode.build", seq, kind) as build:
             with tl.span("decode.build.rows", seq, kind) as parts["rows"]:
                 if self._block:
-                    (tokens, positions, masked, per_row, budgets, first,
-                     truncated, reqs) = self._block_rows(B)
-                    reach = int(positions.max()) + 1
+                    (call, fields, per_row, budgets, first, truncated,
+                     reqs) = self._block_rows(B)
+                    reach = int(fields["positions"].max()) + 1
                 else:
-                    (tokens, positions, seq_lens, budget_arr, stop_tbl,
-                     per_row, budgets, truncated, reqs) = \
+                    call, fields, per_row, budgets, truncated, reqs = \
                         self._decode_rows(B, feed, k)
                     # the longest row this block can reach (seq_lens counts
                     # the incoming token; k-1 more may be written)
-                    reach = int(seq_lens.max()) + k
-            sampling, key = self._sample_and_split(
-                "decode", seq, kind, per_row, parts,
-                steps=1 if self._block else k)
+                    reach = int(fields["seq_lens"].max()) + k
+            self._seal_call("decode", seq, kind, fields, per_row, parts,
+                            steps=1 if self._block else k)
             ctx_pages = self._ctx_bucket_for(reach)   # context-width bucket
         with tl.span("decode.table_sync", seq, kind) as parts["table_sync"]:
             self._sync_tables()
@@ -3332,20 +3346,13 @@ class TPUEngine:
                 step_fn = self._decode_fb_fn(ctx_pages, B)
             with tl.span("decode.dispatch.upload", seq, kind) \
                     as parts["upload"]:
-                slot_ids = jnp.arange(B, dtype=jnp.int32)
-                if self._block:
-                    args = (jnp.asarray(tokens), jnp.asarray(positions),
-                            jnp.asarray(masked), slot_ids)
-                else:
-                    args = (feed["block"] if feed is not None
-                            else jnp.asarray(tokens),
-                            jnp.asarray(positions), slot_ids,
-                            jnp.asarray(seq_lens), jnp.asarray(budget_arr),
-                            jnp.asarray(stop_tbl))
+                packed = self._upload(call)
+            # a device-fed step's tokens: the block of the step in flight
+            fed = () if feed is None else (feed["block"],)
             with tl.span("decode.dispatch.launch", seq, kind) \
                     as parts["launch"]:
                 (block_tokens, block_valid, block_done, *block_aux), self.kv = \
-                    step_fn(self.params, self.kv, *args, sampling, key)
+                    step_fn(self.params, self.kv, packed, self._rng, *fed)
         if feed is None:
             self._host_fed(seq, kind, build, dispatch, parts)
         # dispatch-gap telemetry: host time since the last step retired,
@@ -3378,18 +3385,21 @@ class TPUEngine:
                 "truncated": truncated, "B": B, "k": k,
                 "ctx_pages": ctx_pages, "batch": len(reqs), "seq": seq,
                 "kind": kind, "t_dispatched": dispatch.t0, "gap_s": gap_s,
+                "uploads": self._claim_uploads(),
                 # the named host parts of a HOST-FED dispatch: its phase row
                 # at retire (a device-fed step's host work overlaps its
                 # predecessor on the device and holds nothing up)
                 "build": build, "parts": parts if feed is None else None}
 
     def _decode_rows(self, B: int, feed: dict[str, Any] | None, k: int):
-        """Pack one decode dispatch's [B] rows from the running set and
-        pre-grant its pages. Returns the host arrays, the rows' sampling
-        parameters and the per-slot bookkeeping the retire needs."""
-        tokens = np.zeros((B,), dtype=np.int32)
-        positions = np.zeros((B,), dtype=np.int32)
-        seq_lens = np.zeros((B,), dtype=np.int32)
+        """Pack one decode dispatch's [B] rows from the running set into its
+        call (``_decode_call``; ``_decode_fb_call``, without ``tokens``,
+        where ``feed`` supplies them on the device) and pre-grant its pages.
+        Returns the call's buffer, its fields, the rows' sampling parameters
+        and the per-slot bookkeeping the retire needs."""
+        call, fields = (self._decode_call if feed is None
+                        else self._decode_fb_call).host(B)
+        positions, seq_lens = fields["positions"], fields["seq_lens"]
         temperature = np.zeros((B,), dtype=np.float32)
         top_k = np.zeros((B,), dtype=np.int32)
         top_p = np.ones((B,), dtype=np.float32)
@@ -3397,8 +3407,7 @@ class TPUEngine:
         # remainder ∧ granted pages) and the EOS/stop-id table — what
         # lets a finished row freeze INSIDE the super-step without a
         # host round-trip
-        budget_arr = np.zeros((B,), dtype=np.int32)
-        stop_tbl = np.full((B, self._STOP_TBL_WIDTH), -1, dtype=np.int32)
+        budget_arr, stop_tbl = fields["budgets"], fields["stop_tbl"]
         # per-slot budget within this block: page capacity and max_tokens cap
         # how many of the k decoded tokens are usable
         budgets: dict[int, int] = {}
@@ -3412,7 +3421,7 @@ class TPUEngine:
             # this step, after which the slot's context length is n_ctx.
             n_ctx = len(request.prompt_ids) + len(request.generated) + pending
             if feed is None:
-                tokens[slot] = request.generated[-1]
+                fields["tokens"][slot] = request.generated[-1]
             positions[slot] = n_ctx - 1
             seq_lens[slot] = n_ctx
             temperature[slot] = request.temperature
@@ -3439,8 +3448,8 @@ class TPUEngine:
             stops = (self.tokenizer.eos_id,) + tuple(
                 request.stop_ids)[:self._STOP_TBL_WIDTH - 1]
             stop_tbl[slot, :len(stops)] = stops
-        return (tokens, positions, seq_lens, budget_arr, stop_tbl,
-                (temperature, top_k, top_p), budgets, truncated, reqs)
+        return (call, fields, (temperature, top_k, top_p), budgets, truncated,
+                reqs)
 
     def _block_rows(self, B: int):
         """Pack one block step's [B, Bl] rows from the running set and grant
@@ -3450,13 +3459,15 @@ class TPUEngine:
         hold the mask token and are flagged masked. All ``Bl`` positions are
         computed and written whatever ``max_tokens`` leaves to emit, so the
         block's whole page must be granted or the request truncates. Returns
-        the host arrays, the rows' sampling parameters, and by slot the tokens
-        to emit (``budgets``), where they start in the block (``first``), the
-        rows the pool refused (``truncated``) and the requests."""
-        Bl, cfg = self._block, self.model_config
-        tokens = np.full((B, Bl), cfg.mask_token_id, dtype=np.int32)
-        positions = np.full((B, Bl), -1, dtype=np.int32)
-        masked = np.zeros((B, Bl), dtype=bool)
+        the call's buffer and its fields (``_block_call``: an idle position
+        holds the mask token), the rows' sampling parameters, and by slot the
+        tokens to emit (``budgets``), where they start in the block
+        (``first``), the rows the pool refused (``truncated``) and the
+        requests."""
+        Bl = self._block
+        call, fields = self._block_call.host(B)
+        tokens, positions, masked = (fields["tokens"], fields["positions"],
+                                     fields["masked"])
         temperature = np.zeros((B,), dtype=np.float32)
         top_k = np.zeros((B,), dtype=np.int32)
         top_p = np.ones((B,), dtype=np.float32)
@@ -3485,8 +3496,8 @@ class TPUEngine:
             top_k[slot] = request.top_k
             top_p[slot] = request.top_p
             budgets[slot], first[slot] = max(0, want), known
-        return (tokens, positions, masked, (temperature, top_k, top_p),
-                budgets, first, truncated, reqs)
+        return (call, fields, (temperature, top_k, top_p), budgets, first,
+                truncated, reqs)
 
     def _decode_retire(self, inflight: dict[str, Any]) -> None:  # lint: hot-path
         """Fetch and emit one dispatched decode SUPER-STEP: the [k, B]
@@ -3580,6 +3591,7 @@ class TPUEngine:
                           ctx_pages=inflight["ctx_pages"],
                           gap_ms=inflight["gap_s"] * 1000,
                           phases=phases, mfu=mfu, hbm_frac=hbm_frac,
+                          host_uploads=inflight["uploads"],
                           superstep=inflight["k"],
                           frozen=int(done_host.sum()),
                           wall_ms=step_wall_ms)
@@ -3605,19 +3617,43 @@ class TPUEngine:
 
     # --------------------------------------------------------------- telemetry
 
-    def _sample_and_split(self, family: str, seq: int, kind: str, per_row,
-                          parts: dict[str, Any], steps: int = 1):
-        """The two named tails of every build: the rows' sampling parameters
-        onto the device (three uploads; the tier counted), then the key
-        split (two tiny device programs). ``family`` is ``prefill`` or
-        ``decode``; returns ``(sampling, key)``."""
+    def _seal_call(self, family: str, seq: int, kind: str,
+                   fields: dict[str, np.ndarray], per_row,
+                   parts: dict[str, Any], steps: int = 1) -> None:
+        """The two named tails of every build, both on the host: the rows'
+        sampling parameters into the call (the tier counted), then the
+        dispatch's number, from which the step program folds its key.
+        ``family`` is ``prefill`` or ``decode``."""
         tl = self.timeline
         with tl.span(family + ".build.sampling", seq, kind) \
                 as parts["sampling"]:
-            sampling = self._sampling_params(*per_row, steps=steps)
+            self._sampling_params(fields, *per_row, steps=steps)
         with tl.span(family + ".build.rng", seq, kind) as parts["rng"]:
-            self._rng, key = jax.random.split(self._rng)
-        return sampling, key
+            fields["counter"][:] = self._next_dispatch()
+
+    def _next_dispatch(self) -> int:
+        """The number the next dispatch's key is folded from: 1, 2, ... in
+        dispatch order. It rides as an int32, so past 2**31 - 1 the base key
+        itself moves on (once in years of serving) and the count restarts:
+        no two dispatches of an engine share a key."""
+        self._dispatch_no += 1
+        if self._dispatch_no > 0x7FFFFFFF:
+            self._rng = jax.random.fold_in(self._rng, 0)
+            self._dispatch_no = 1
+        return self._dispatch_no
+
+    def _claim_uploads(self) -> int:
+        """For a dispatch's step record: the transfers made since the last
+        dispatch claimed its own, so its call and the table syncs that led
+        to it (an admission's, its own)."""
+        made = self.stats.host_uploads - self._uploads_claimed
+        self._uploads_claimed = self.stats.host_uploads
+        return made
+
+    def _upload(self, call: np.ndarray):
+        """A dispatch's ONE host-to-device transfer: its packed call."""
+        self.stats.host_uploads += 1
+        return jax.device_put(call, self._call_sharding)
 
     def _host_fed(self, seq: int, kind: str, build, dispatch,
                   parts: dict[str, Any]) -> None:
@@ -3761,9 +3797,10 @@ class TPUEngine:
         elif path == "scan":
             self.stats.moe_scan_steps += steps
 
-    def _sampling_params(self, temperature: np.ndarray, top_k: np.ndarray,
-                         top_p: np.ndarray, steps: int = 1) -> SamplingParams:
-        """A dispatch's per-row parameters on the device, its ``steps``
+    def _sampling_params(self, fields: dict[str, np.ndarray],
+                         temperature: np.ndarray, top_k: np.ndarray,
+                         top_p: np.ndarray, steps: int = 1) -> None:
+        """A dispatch's per-row parameters into its call, its ``steps``
         sampled steps counted by the tier the step program will take."""
         rows = SamplingParams(temperature, top_k, top_p)
         samples, filters = rows.tiers()
@@ -3773,7 +3810,8 @@ class TPUEngine:
             self.stats.sample_plain_steps += steps
         else:
             self.stats.sample_argmax_steps += steps
-        return SamplingParams(*map(jnp.asarray, rows))
+        for name, values in zip(SamplingParams._fields, rows):
+            fields[name][:] = values
 
     def _step_counts(self, aux: list) -> StepCounts | None:
         """What a step program counted on the device, from what it returned
@@ -3815,7 +3853,8 @@ class TPUEngine:
                      hbm_frac: float | None = None,
                      superstep: int | None = None,
                      frozen: int | None = None,
-                     wall_ms: float | None = None) -> None:
+                     wall_ms: float | None = None,
+                     host_uploads: int = 0) -> None:
         """One ring-buffer entry + gauge refresh per device dispatch.
         Runs on the dispatch thread; deque.append and prometheus_client
         ops are both thread-safe, and the asyncio side only ever copies
@@ -3848,6 +3887,10 @@ class TPUEngine:
             # spans (None for a device-fed one), and live cost-model roofline
             "phases": ({k: round(v, 3) for k, v in phases.items()}
                        if phases is not None else None),
+            # host-to-device transfers made for this dispatch: its packed
+            # call, and a table sync where rows were dirty (a count, so
+            # beside the phases, whose keys are milliseconds)
+            "host_uploads": host_uploads,
             "mfu": round(mfu, 12) if mfu is not None else None,
             "hbm_frac": round(hbm_frac, 12) if hbm_frac is not None else None,
         })
@@ -4104,14 +4147,16 @@ class TPUEngine:
             # upload under the table's existing (replicated NamedSharding)
             # placement: the pjit cache keys on input shardings, so a bare
             # jnp.array here — single-device, uncommitted — would recompile
-            # every warmup-built executable at its first traffic hit
+            # every warmup-built executable at its first traffic hit. The
+            # host table goes up as it is: one transfer, no program
             fresh = {"block_tables": jax.device_put(
-                self.allocator.tables(), self.kv.block_tables.sharding)}
+                self.allocator.tables_host(), self.kv.block_tables.sharding)}
             if self.allocator.state_rows:
                 # a slot's state row rides beside its block-table row
                 fresh["state_rows"] = jax.device_put(
                     self.allocator.state_row_table(),
                     self.kv.state_rows.sharding)
+            self.stats.host_uploads += len(fresh)
             self.kv = self.kv._replace(**fresh)
 
     def _emit(self, request: GenRequest, token: int) -> None:

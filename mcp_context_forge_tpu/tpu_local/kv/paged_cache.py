@@ -1122,10 +1122,10 @@ class PageAllocator:
         for page in reversed(pages):
             self._release_page(page)
 
-    def tables(self) -> "jnp.ndarray":
-        """The device block table. Only dirty rows are rebuilt in the
-        cached host table; the returned array is a fresh copy (jnp.array
-        copies), so later in-place row updates can never alias a device
+    def tables_host(self) -> "np.ndarray":
+        """The block table to upload, on the host. Only dirty rows are
+        rebuilt in the cached host table; the returned array is a fresh
+        copy, so later in-place row updates can never alias a device
         buffer. Reading clears the dirty set — callers that gate on
         ``dirty`` skip the upload entirely when nothing changed."""
         for slot in self._dirty:
@@ -1135,4 +1135,10 @@ class PageAllocator:
             if pages:
                 row[:len(pages)] = pages
         self._dirty.clear()
-        return jnp.array(self._table)
+        return self._table.copy()
+
+    def tables(self) -> "jnp.ndarray":
+        """:meth:`tables_host` as a device array (the default device's): for
+        callers that hand it straight to a model function. The engine
+        uploads the host table itself, in one transfer and no program."""
+        return jnp.array(self.tables_host())

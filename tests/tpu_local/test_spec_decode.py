@@ -139,7 +139,9 @@ def test_accept_loop_emits_confirmed_drafts_deterministically():
 
     captured = {}
 
-    def fake_verify(params, kv, tokens, positions, slot_ids, sampling, key):
+    def fake_verify(params, kv, packed, base_key):
+        # the verify program's one packed call, sliced by its layout
+        tokens = engine._verify_call.unpack(packed)["tokens"]
         captured["tokens"] = np.asarray(tokens)
         return jnp.asarray(np.asarray(tokens) + 1), kv
 
