@@ -1,5 +1,11 @@
-"""Admission queue: request.queue_ms p95 in the cells whose TTFT tail is too
-noisy to stand end to end; the same reading as ``queue.wait_ms_p95``."""
-from benchmark.harness.layers import load_reader
+"""Admission queue: the engine's own request.queue_ms (submit -> slot won), p95.
+It moves ``ttft_p50_ms``: no cell's TTFT tail stands end to end (PERF.md,
+section 2), so the name says which reading this is not."""
+from benchmark.harness.stats import percentile
 
-read = load_reader("queue.wait_ms_p95")
+
+def read(ctx):
+    by_index = {r.index for r in ctx.records}
+    waits = [req.queue_ms for i, (_t, req) in ctx.submits.items()
+             if i in by_index and req.queue_observed]
+    return percentile(waits, 95) if waits else None

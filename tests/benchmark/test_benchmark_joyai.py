@@ -1,4 +1,6 @@
-"""What PR 41 added to the benchmark for ``joyai-llm-flash-d5-ep4``: the
+"""What PR 41 added to the benchmark for ``joyai-llm-flash-d5-ep4`` (its cell
+an open loop at a fixed rate since PR 47, ``reason-open`` in place of
+``reason-closed``): the
 manifest's new entries (held by name, never by position), the configuration
 file against the catalog's published keys, the bytes the issue reckoned, the
 mix and the cell letter for letter, the family file's contract, the verify
@@ -18,7 +20,7 @@ from benchmark.harness import (correct, kernel_cost, layers, manifest,
 from mcp_context_forge_tpu.observability.timeline import StepCounts, StepTimeline
 
 T0, NS0 = 100.0, 5e9
-CELL, CONFIG = "joyai-llm-flash-d5-ep4.reason-closed", "joyai-llm-flash-d5-ep4"
+CELL, CONFIG = "joyai-llm-flash-d5-ep4.reason-open", "joyai-llm-flash-d5-ep4"
 NEW_READERS = ("spec.tokens_per_step", "spec.verify_share",
                "mla_verify_attention_roofline")
 APPENDED_TO = ("decode.device_ms_per_step", "decode.host_gap_ms_mean",
@@ -77,7 +79,7 @@ def model(config):
 # ------------------------------------------------------------- the manifest
 
 def test_the_cell_and_what_it_reports(doc, cell):
-    assert (cell.config, cell.traffic, cell.chips) == (CONFIG, "reason-closed", 1)
+    assert (cell.config, cell.traffic, cell.chips) == (CONFIG, "reason-open", 1)
     assert [m["name"] for m in cell.end_to_end] == ["ttft_p50_ms", "tpot_p95_ms",
                                                     "setup_s"]
     names = {m["name"] for m in cell.per_layer}
@@ -99,15 +101,20 @@ def test_the_cell_and_what_it_reports(doc, cell):
     assert by_name["mla_verify_attention_roofline"]["unit"] == "%"
     assert by_name["mla_verify_attention_roofline"]["source"] == "device_trace"
     assert by_name["spec.verify_share"]["source"] == "program_counter"
+    # by name, once: later cells follow it on these lists; the closed loop it
+    # took the place of is on none
     for metric in doc["end_to_end"] + doc["per_layer"]:
         if CELL in metric.get("workloads", ()):
-            assert metric["workloads"][-1] == CELL        # appended, nothing moved
+            assert metric["workloads"].count(CELL) == 1
+        assert CELL.replace("-open", "-closed") not in metric.get("workloads", ())
     entry = next(c for c in doc["configs"] if c["name"] == CONFIG)
     assert entry["source"] == SOURCE and entry["reduced"] == list(CUT)
     assert len(doc["workloads"]) >= 7 and all(w["chips"] == 1 for w in doc["workloads"])
     why = next(w["why"] for w in doc["workloads"] if w["name"] == CELL)
-    for words in ("32320", "1/5 of a step", "1/40 deployed", "4 x its share"):
-        assert words in why
+    rate = manifest.read_json(cell.cell_file)["rate_rps"]
+    for words in ("open loop", f"{rate} req/s", "0.8 x", "2048-7000", "512-1024",
+                  "verif", "GB"):
+        assert words in why, words
 
 
 @pytest.mark.parametrize("key", sorted(PUBLISHED))
@@ -196,16 +203,11 @@ def test_weights_and_pool_are_the_bytes_the_issue_reckoned(config, model):
 def test_the_mix_and_the_cell_are_the_issues(cell):
     mix = manifest.read_json(cell.traffic_file)
     what = mix.pop("what")
-    assert "reasoning" in what and "ReAct" in what
-    assert mix == {
-        "kind": "closed_loop",
-        "prompt_tokens": {"dist": "log_uniform", "low": 2048, "high": 7000},
-        "max_tokens": {"dist": "uniform", "low": 512, "high": 1024},
-        "temperature": 0.0, "shared_prefix_tokens": 0, "cycle": 64,
-        "drain_seconds": 40, "schedule_seed": 23, "trace_seconds": 5.0,
-        "engine": {"max_seq_len": 8192, "prefill_buckets": [1024],
-                   "prefill_max_batch": 2, "max_batch": 32}}
-    assert manifest.read_json(cell.cell_file) == {"clients": 32}
+    assert "reasoning" in what and "A2A" in what and "do not wait" in what
+    # the mix letter for letter: test_benchmark_open_cells.py
+    assert mix["kind"] == "open_loop"
+    params = manifest.read_json(cell.cell_file)
+    assert set(params) == {"rate_rps"} and 1.0 <= params["rate_rps"] <= 2.5
     # the longest prompt and output fit a row, every row's pages fit the pool
     assert 7000 + 1024 <= mix["engine"]["max_seq_len"]
 
@@ -382,7 +384,7 @@ TINY = {   # deepseek-mtp-test's geometry, as a config.json
                "mesh_shape": "8x1", "embedding_model": "encoder-tiny"},
     "logits_tolerance": {"atol": 2e-3, "rtol": 2e-3}, "check_seed": 5,
 }
-MIX = {"kind": "closed_loop", "schedule_seed": 1, "cycle": 8,
+MIX = {"kind": "open_loop", "arrivals": "poisson", "schedule_seed": 1,
        "prompt_tokens": {"dist": "log_uniform", "low": 40, "high": 100},
        "max_tokens": {"dist": "uniform", "low": 6, "high": 12},
        "temperature": 0.0, "shared_prefix_tokens": 0,
@@ -407,7 +409,7 @@ def test_rehearsal_of_the_cell_traced(cell, capsys, tmp_path, monkeypatch):
     tiny_cell = manifest.Cell(**{**cell.__dict__, "config": "bench-tiny-joyai"})
     saved = dict(os.environ)
     try:
-        result = asyncio.run(run.measure(tiny_cell, TINY, MIX, {"clients": 3},
+        result = asyncio.run(run.measure(tiny_cell, TINY, MIX, {"rate_rps": 3.0},
                                          seed=3_000_000_019, seconds=2.0, trace=True))
     finally:
         os.environ.clear()

@@ -38,6 +38,18 @@ def change(doc, where, index, **values):
     return doc
 
 
+def quota(doc):
+    """Four-chip cells the contract allows: a quarter of the cells, rounded
+    down, and one always."""
+    return max(1, len(doc["workloads"]) // 4)
+
+
+def four_chip_cells(doc, count):
+    for index in range(count):
+        change(doc, "workloads", index, chips=4)
+    return doc
+
+
 @pytest.mark.parametrize("mutate,why", [
     (lambda d: change(d, "workloads", 0, name="mistral 7b/chat"), "not a name"),
     (lambda d: change(d, "workloads", 0, name="x" * 65), "not a name"),
@@ -47,8 +59,7 @@ def change(doc, where, index, **values):
     (lambda d: change(d, "end_to_end", 0, source="program_counter"), "host_clock"),
     (lambda d: change(d, "end_to_end", 0, why="because"), "unknown keys"),
     (lambda d: change(d, "workloads", 1, chips=2), "chips"),
-    (lambda d: change(d, "workloads", 1, chips=4) and change(d, "workloads", 2, chips=4),
-     "quarter"),
+    (lambda d: four_chip_cells(d, quota(d) + 1), "quarter"),
     (lambda d: change(d, "workloads", 1, config="mistral-7b"), "twice"),
     (lambda d: change(d, "workloads", 0, why="two\nlines"), "one line"),
     (lambda d: change(d, "workloads", 0, config="nope"), "names no config"),
@@ -62,6 +73,10 @@ def change(doc, where, index, **values):
 def test_loader_refuses(doc, mutate, why):
     with pytest.raises(manifest.ManifestError, match=why):
         manifest.validate(mutate(doc))
+
+
+def test_the_quota_itself_is_allowed(doc):
+    manifest.validate(four_chip_cells(doc, quota(doc)))
 
 
 def test_unknown_cell_and_reader(doc):

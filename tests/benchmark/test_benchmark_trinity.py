@@ -1,4 +1,6 @@
-"""What PR 43 added to the benchmark for ``trinity-mini-d8``: the manifest's new
+"""What PR 43 added to the benchmark for ``trinity-mini-d8`` (its cell an open
+loop at a fixed rate since PR 47, ``mixedctx-open`` in place of
+``mixedctx-closed``): the manifest's new
 entries (held by name, never by position), the configuration file against the
 catalog's published keys, the bytes the issue reckoned from those keys, the mix
 and the cell letter for letter, the family file's contract, the window cost at
@@ -19,7 +21,7 @@ from benchmark.harness import (correct, kernel_cost, layers, manifest, stats,
 from mcp_context_forge_tpu.observability.timeline import StepCounts, StepTimeline
 
 T0, NS0 = 100.0, 5e9
-CELL, CONFIG = "trinity-mini-d8.mixedctx-closed", "trinity-mini-d8"
+CELL, CONFIG = "trinity-mini-d8.mixedctx-open", "trinity-mini-d8"
 NEW_READERS = ("window_paged_attention_roofline", "window_chunk_attention_roofline",
                "window.visible_share_mean", "paged_attention.device_share")
 APPENDED_TO = ("decode.device_ms_per_step", "decode.host_gap_ms_mean",
@@ -79,7 +81,7 @@ def model(config):
 # ------------------------------------------------------------ the manifest
 
 def test_the_cell_and_what_it_reports(doc, cell):
-    assert (cell.config, cell.traffic, cell.chips) == (CONFIG, "mixedctx-closed", 1)
+    assert (cell.config, cell.traffic, cell.chips) == (CONFIG, "mixedctx-open", 1)
     assert [m["name"] for m in cell.end_to_end] == ["ttft_p50_ms", "tpot_p95_ms",
                                                     "setup_s"]
     names = {m["name"] for m in cell.per_layer}
@@ -111,14 +113,16 @@ def test_the_cell_and_what_it_reports(doc, cell):
     for metric in doc["end_to_end"] + doc["per_layer"]:
         if CELL in metric.get("workloads", ()):
             assert metric["workloads"].count(CELL) == 1   # by name: later cells follow it
+        assert CELL.replace("-open", "-closed") not in metric.get("workloads", ())
     entry = next(c for c in doc["configs"] if c["name"] == CONFIG)
     assert entry["source"] == SOURCE and entry["reduced"] == REDUCED
     assert entry["file"] == "benchmark/configs/trinity-mini-d8.json"
     assert len(doc["workloads"]) >= 8
     why = next(w["why"] for w in doc["workloads"] if w["name"] == CELL)
-    for words in ("closed loop of 32", "1024-16000", "256-512", "window",
-                  "8 layers"):
-        assert words in why
+    rate = manifest.read_json(cell.cell_file)["rate_rps"]
+    for words in ("open loop", f"{rate} req/s", "0.7 x", "1024-16000", "256-512",
+                  "window", "GB"):
+        assert words in why, words
 
 
 @pytest.mark.parametrize("key", sorted(PUBLISHED))
@@ -190,7 +194,7 @@ def test_weights_and_cache_are_the_bytes_the_issue_reckoned(config, model):
     assert one == 262_144
     mix = manifest.read_json(os.path.join(os.path.dirname(os.path.dirname(
         manifest.cell(manifest.load(), CELL).config_file)), "traffic",
-        "mixedctx-closed.json"))
+        "mixedctx-open.json"))
     rows = state_rows_for(model, mix["engine"]["max_batch"])
     assert rows == 33
     assert kv_page_bytes(model, page) == 2 * one             # 2 full layers
@@ -207,26 +211,25 @@ def test_weights_and_cache_are_the_bytes_the_issue_reckoned(config, model):
     assert (matrices + full + window) / 16e9 < 0.66
 
 
-def test_the_mix_and_the_cell_are_the_issues(cell):
+def test_the_mix_and_the_cell_are_the_issues(doc, cell):
     mix = manifest.read_json(cell.traffic_file)
     what = mix.pop("what")
     assert "summarizer" in what and "moderation" in what and "/v1" in what
-    assert mix == {
-        "kind": "closed_loop",
-        "prompt_tokens": {"dist": "log_uniform", "low": 1024, "high": 16000},
-        "max_tokens": {"dist": "uniform", "low": 256, "high": 512},
-        "temperature": 0.0, "shared_prefix_tokens": 0, "cycle": 64,
-        "drain_seconds": 40, "schedule_seed": 23, "trace_seconds": 5.0,
-        "engine": {"max_seq_len": 16896, "prefill_buckets": [1024],
-                   "prefill_max_batch": 2, "max_batch": 32}}
-    assert manifest.read_json(cell.cell_file) == {"clients": 32}
+    assert "do not wait" in what
+    # the mix letter for letter: test_benchmark_open_cells.py
+    assert mix["kind"] == "open_loop"
+    params = manifest.read_json(cell.cell_file)
+    assert set(params) == {"rate_rps"} and 1.0 <= params["rate_rps"] <= 2.8
     assert 16000 + 512 <= mix["engine"]["max_seq_len"]
-    # a quarter of the prompts inside the window, three quarters 1-8 x past it
+    # a quarter of the window's prompts inside the model's window of 2048,
+    # three quarters 1-8 x past it (the grid of the requests a run offers)
     from benchmark.harness.draw import grid
-    lengths = grid(mix["prompt_tokens"], mix["cycle"])
-    inside = sum(n <= 2048 for n in lengths)
-    assert inside == 16 and min(lengths) >= 1024 and max(lengths) <= 16000
-    assert sum(n > 4096 for n in lengths) >= 31
+    offered = round(params["rate_rps"] * doc["run_seconds"])
+    lengths = grid(mix["prompt_tokens"], offered)
+    inside = sum(length <= 2048 for length in lengths)
+    assert abs(inside - offered / 4) <= 1
+    assert min(lengths) >= 1024 and max(lengths) <= 16000
+    assert sum(length > 4096 for length in lengths) >= 0.48 * offered
 
 
 def test_family_file_keeps_the_contract(config, monkeypatch):
@@ -435,7 +438,7 @@ TINY = {   # afmoe-test's geometry, as a config.json
                "mesh_shape": "8x1", "embedding_model": "encoder-tiny"},
     "logits_tolerance": {"atol": 2e-3, "rtol": 2e-3}, "check_seed": 5,
 }
-MIX = {"kind": "closed_loop", "schedule_seed": 1, "cycle": 8,
+MIX = {"kind": "open_loop", "arrivals": "poisson", "schedule_seed": 1,
        "prompt_tokens": {"dist": "log_uniform", "low": 40, "high": 200},
        "max_tokens": {"dist": "uniform", "low": 6, "high": 12},
        "temperature": 0.0, "shared_prefix_tokens": 0,
@@ -459,7 +462,7 @@ def test_rehearsal_of_the_cell_traced(cell, capsys, tmp_path, monkeypatch):
     tiny_cell = manifest.Cell(**{**cell.__dict__, "config": "bench-tiny-afmoe"})
     saved = dict(os.environ)
     try:
-        result = asyncio.run(run.measure(tiny_cell, TINY, MIX, {"clients": 3},
+        result = asyncio.run(run.measure(tiny_cell, TINY, MIX, {"rate_rps": 3.0},
                                          seed=3_000_000_019, seconds=2.0, trace=True))
     finally:
         os.environ.clear()
