@@ -49,6 +49,7 @@ live rows' summed context and summed ``min(context, W)``.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
@@ -57,8 +58,8 @@ import jax.numpy as jnp
 from . import llama
 from .configs import AfmoeConfig
 from .deepseek import route
-from .llama import (_dense, _ffn, _history_attention, _history_tile,  # noqa: F401 (expert_path: a family name, the trunk's rule)
-                    _paged_decode_attention, apply_rope, expert_path,
+from .llama import (_dense, _ffn, _history_attention, _history_tile,
+                    _paged_decode_attention, apply_rope, expert_block,
                     lm_logits, rms_norm, routed_experts)
 from ..kv.paged_cache import (HybridKVState, init_kv_state,  # noqa: F401 (family names)
                               kv_logical, kv_page_bytes, ring_tables,
@@ -70,6 +71,8 @@ from ..quantize import embed_rows, qmm
 
 STEP_AUX = True
 STEP_KIND = "token"  # a decode step yields one token a row (models/__init__.py)
+SCAN_PASS = 2   # a pass of the expert scan over one expert's weights, in
+#                 passes of the row-block kernel (``expert_path``)
 ROUTER_BIAS_SCALE = 0.1   # the correction bias is drawn N(0, 0.1): a trained
 #                           value is not zero, and zeros would leave it untested
 
@@ -199,6 +202,62 @@ def paged_impl(mesh, config: AfmoeConfig, kv: HybridKVState) -> str:
                                   config.n_kv_heads, False)
 
 
+def expert_path(config: AfmoeConfig, mesh, tokens: int,
+                dtype: Any = jnp.bfloat16) -> str:
+    """Which formulation the routed experts of a step of ``tokens`` tokens
+    trace: ``"grouped"`` (ops/grouped_moe.py at the row-block the trunk's
+    ``expert_block`` gives the step) or ``"scan"`` (parallel/moe.py). The
+    family's OWN rule, handed to ``llama.routed_experts`` and counted by the
+    engine; a pure function of the step's shape (T, k, E, the row-block, the
+    activations' dtype through it), the configuration's ``moe_impl`` and the
+    mesh.
+
+    Where the kernel runs, and a wide step (T·k >= E·moe_block: the chunk
+    rounds), are the trunk's (``llama.expert_path``): one device on the
+    ``model`` axis, a caller that names its mesh, ``moe_impl`` grouped*.
+
+    A NARROWER step is weighed in WEIGHT PASSES, not in rows as the trunk
+    weighs it. Over many small experts rows are not what a decode-width step
+    costs: a pass over an expert's weights takes the same time at 1 row and at
+    32 (int8 stacks go through the MXU no faster than they leave HBM).
+    - The scan cannot avoid E passes, whatever T and whatever the router
+      chose, and in each the weight read and the matmuls serialise: 14.1 us an
+      iteration over Trinity-Mini's 6.3 MB experts, 1.81 ms a layer at 1 row
+      as at 32 (alone on a v5e: PERF.md section 6, PR 48), 896 iterations and
+      13.1 of a decode step's 16.3 ms in the cell (ledger, PR 47).
+    - The plan makes one pass a LIVE row-block, the next block's tiles fetched
+      under this one's matmuls: 7.5 us, the expert's bytes at the HBM's rate,
+      and 0.17 ms a layer for the sort, the gathers and the dead grid steps
+      (0.77 ms a layer with 32 rows live, 0.47 with 8, 0.22 with one: the
+      same run), so a scan pass is counted as ``SCAN_PASS`` = 2 of them. Live
+      blocks are never more than the pairs T·k (a block holds a pair) nor
+      than E + T·k // b (every expert's spare block and the full ones); an
+      expert no live row chose gets none, and idle rows have no pair.
+    So a narrow step is grouped when min(T·k, E + T·k // b) <= 2·E. At 32
+    rows of 128 x top-8 (b = 16) that is 144 against 256, and what a step
+    really makes is far under the bound. At ONE row (the logits check's
+    decode) it is 8 passes against the scan's 128. With the row-block
+    ``expert_block`` gives today the bound holds for EVERY narrow step (b
+    holds an expert's mean share of the pairs, so T·k // b <= E): on one
+    device all of this family's steps are grouped, and the inequality is what
+    sends a step back to the scan should the row-block stop following the
+    share.
+
+    The trunk's rule stays the trunk's: its values at the block family's
+    widths are pinned (tests/benchmark), and no function of the shape tells 32
+    tokens of 128 x top-8 from 64. PERF.md section 7 says what removes this
+    second rule."""
+    whole = mesh is not None and mesh.shape.get("model", 1) == 1
+    if (not config.moe_impl.startswith("grouped")
+            or (config.moe_impl == "grouped_pallas" and not whole)):
+        return "scan"
+    pairs, experts = tokens * config.moe_top_k, config.n_experts
+    if pairs >= experts * config.moe_block:
+        return "grouped"
+    passes = min(pairs, experts + pairs // expert_block(config, tokens, dtype))
+    return "grouped" if passes <= SCAN_PASS * experts else "scan"
+
+
 def refusals(config: AfmoeConfig, engine_config, mesh,
              tiers: bool) -> list[str]:
     """Engine settings this family cannot serve yet, each with its reason.
@@ -261,14 +320,20 @@ def _qkv(layer: dict[str, Any], config: AfmoeConfig, x: jax.Array,
     return q, k, v
 
 
+@partial(jax.jit, static_argnames=("config", "mesh"))
 def _expert_ffn(layer: dict[str, Any], config: AfmoeConfig, x: jax.Array,
                 valid: jax.Array, mesh) -> jax.Array:
     """Routed experts (the sigmoid router's choices into the trunk's two
-    formulations) + the shared expert. x [B, S, D]; valid [B, S]."""
+    formulations, by the family's rule) + the shared expert. x [B, S, D];
+    valid [B, S]: a row or token without it gets no row of the plan.
+    Jitted, so a step program traces and lowers it once a shape and not once
+    a layer: inlined seven times a decode program, the plan's sort, scatters
+    and gathers cost the cell 16 s of every warm build (94.2 against 78.2 s:
+    PERF.md section 6, PR 48)."""
     flat = x.reshape(-1, x.shape[-1])
     ids, weights, _ = route(layer, config, flat)
     routed = routed_experts({k: layer[k] for k in ("w1", "w3", "w2")}, config,
-                            flat, ids, weights, mesh, valid)
+                            flat, ids, weights, mesh, valid, rule=expert_path)
     shared = _ffn({"w1": layer["shared_w1"], "w3": layer["shared_w3"],
                    "w2": layer["shared_w2"]}, flat, config.hidden_act)
     return (routed + shared).reshape(x.shape)

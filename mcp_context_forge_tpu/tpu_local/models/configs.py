@@ -283,7 +283,8 @@ class AfmoeConfig:
     (``kv/paged_cache.py``). The family refuses an engine whose largest
     prefill bucket and page do not fit it. ``n_group`` / ``topk_group`` are 1
     (group limiting is the identity); ``moe_impl`` / ``moe_block`` as in
-    :class:`LlamaConfig`."""
+    :class:`LlamaConfig`, but which formulation a narrow step takes is the
+    family's own rule (``models/afmoe.py: expert_path``)."""
 
     name: str
     vocab_size: int

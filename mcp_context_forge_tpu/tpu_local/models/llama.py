@@ -324,19 +324,22 @@ def expert_path(config: LlamaConfig, mesh, tokens: int,
 
 def routed_experts(stacks: dict[str, Any], config, flat: jax.Array,
                    ids: jax.Array, weights: jax.Array, mesh=None,
-                   valid: jax.Array | None = None) -> jax.Array:
+                   valid: jax.Array | None = None,
+                   rule=expert_path) -> jax.Array:
     """The routed experts' weighted sum for routing CHOICES, however a router
     made them: flat [T, D], ids [T, k] int32 into the stacks ``w1``/``w3``
     [E, D, F] and ``w2`` [E, F, D], weights [T, k] float32 (final: whatever
     normalising and scaling the router does is done) -> [T, D]. The softmax
     router of :func:`_ffn_block` and a family's own router (sigmoid scores, a
     correction bias) feed the same two formulations through it, picked by
-    :func:`expert_path` from the step's shape and run at
-    :func:`expert_block`'s row-block. ``valid`` (T entries): False marks
-    padding and idle rows; the grouped path gives their pairs no row and zero
-    output, the scan computes them like any token, and nothing reads either."""
+    ``rule`` from the step's shape (the trunk's :func:`expert_path`, or the
+    calling family's own: the one the engine counts that family's steps by)
+    and run at :func:`expert_block`'s row-block. ``valid`` (T entries): False
+    marks padding and idle rows; the grouped path gives their pairs no row and
+    zero output, the scan computes them like any token, and nothing reads
+    either."""
     T, E = flat.shape[0], config.n_experts
-    if expert_path(config, mesh, T, flat.dtype) == "grouped":
+    if rule(config, mesh, T, flat.dtype) == "grouped":
         # the kernel interprets off-TPU (the caller's mesh says which) so
         # the code path exists everywhere
         from ..ops.grouped_moe import experts_grouped, plan_sorted_blocks

@@ -45,7 +45,9 @@ trick of MegaBlocks-style grouped GEMMs:
 Who takes which path, and at which row-block, is the model family's to say
 from what it can see (`models/llama.py: expert_path` / `expert_block`,
 `models/deepseek.py: expert_path`): the step's rows ``T·k + E·Bt`` against
-the scan's ``E·T``, the mesh, the activations' dtype. ``Bt`` follows the
+the scan's ``E·T``, the mesh, the activations' dtype; the window / full
+family (`models/afmoe.py: expert_path`) counts passes over an expert's
+weights in place of rows. ``Bt`` follows the
 step: ``moe_block`` for a wide one, the power of two that holds an expert's
 share of the pairs for a narrow one, down to the activations' sublane tile.
 

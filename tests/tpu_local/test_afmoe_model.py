@@ -421,6 +421,104 @@ def test_the_softmax_router_still_goes_through_the_same_cut():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# the benchmark cell's expert shape (trinity-mini-d8: 128 x top-8, the kernel,
+# moe_block 128); the widths do not enter the rule
+CELL = dataclasses.replace(CFG, n_experts=128, moe_top_k=8, moe_block=128,
+                           moe_impl="grouped_pallas")
+
+
+def _mesh(model: int = 1):
+    return jax.sharding.Mesh(np.array(jax.devices()[:model]).reshape(1, model),
+                             ("data", "model"))
+
+
+@pytest.mark.parametrize("tokens, block", [
+    (1, 16),      # the logits check's one-row decode: 8 passes against 128
+    (8, 16),
+    (16, 16),
+    (32, 16),     # the cell's decode step: at most 144 passes against 2 x 128
+    (64, 16),
+    (512, 32),    # the half-length dense prefill
+    (1024, 64),   # a [1, 1024] chunk round
+    (2048, 128),  # a [2, 1024] chunk round: wide, the trunk's own answer
+])
+def test_family_rule_table(tokens, block):
+    """``afmoe.expert_path`` over the cell's shape: every step is grouped
+    where one device holds the stacks, at the row-block the trunk's
+    ``expert_block`` gives it; a ``model`` axis of two, a caller that names
+    no mesh and ``moe_impl="dense"`` keep the scan. The trunk's rule scans
+    under 86 tokens of this shape (a quarter of the scan's rows), and is the
+    family's answer from there up."""
+    assert afmoe.expert_block is llama.expert_block
+    assert afmoe.expert_block(CELL, tokens) == block
+    assert afmoe.expert_path(CELL, _mesh(), tokens) == "grouped"
+    assert afmoe.expert_path(CELL, _mesh(), tokens, jnp.float32) == "grouped"
+    assert afmoe.expert_path(CELL, _mesh(2), tokens) == "scan"
+    assert afmoe.expert_path(CELL, None, tokens) == "scan"
+    assert afmoe.expert_path(dataclasses.replace(CELL, moe_impl="dense"),
+                             _mesh(), tokens) == "scan"
+    assert llama.expert_path(CELL, _mesh(), tokens) == (
+        "grouped" if tokens >= 86 else "scan")
+    # the plan through XLA runs on any mesh that is named
+    xla = dataclasses.replace(CELL, moe_impl="grouped")
+    assert afmoe.expert_path(xla, _mesh(2), tokens) == "grouped"
+
+
+def test_decode_step_of_32_rows_grouped_matches_the_scan(params):
+    """``decode_step`` at [32, 1] with 11 idle rows, contexts inside and past
+    the window over random pools: the family's rule traces the row-block plan
+    (the idle rows get no row of it), ``moe_impl="dense"`` the scan; the live
+    rows' logits agree and the pools are written alike."""
+    rows, table = 32, 16
+    live = np.arange(rows) % 3 != 1                     # 21 live, 11 idle
+    assert live.sum() == 21
+    rng = np.random.default_rng(11)
+    lens = np.where(live, rng.integers(1, table * PAGE, rows), 0).astype(np.int32)
+    assert (lens[live] > W).any() and (lens[live] <= W).any()
+    kv = init_kv_state(CFG, 1 + rows * table, PAGE, rows, table,
+                       dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    kv = kv._replace(
+        **{name: jax.random.normal(key, getattr(kv, name).shape, jnp.float32)
+           for name, key in zip(("k_pages", "v_pages", "state", "conv_tail"),
+                                keys)},
+        block_tables=jnp.asarray(
+            1 + np.arange(rows * table, dtype=np.int32).reshape(rows, table)),
+        state_rows=jnp.arange(1, rows + 1, dtype=jnp.int32))
+    # the test configuration with the row-block limit that makes 32 rows a
+    # NARROW step (64 pairs under 8 x 16), as the cell's 32 rows are: the
+    # trunk's rule scans it, the family's groups it at blocks of 8
+    narrow, mesh = dataclasses.replace(CFG, moe_block=16), _mesh()
+    assert afmoe.expert_path(narrow, mesh, rows, jnp.float32) == "grouped"
+    assert llama.expert_path(narrow, mesh, rows, jnp.float32) == "scan"
+    assert llama.expert_block(narrow, rows, jnp.float32) == 8
+    call = dict(tokens=jnp.asarray(rng.integers(32, 127, rows), jnp.int32),
+                positions=jnp.asarray(np.maximum(lens - 1, 0)), kv=kv,
+                slot_ids=jnp.arange(rows), seq_lens=jnp.asarray(lens),
+                write_mask=jnp.asarray(live))
+    outs = {}
+    for impl in ("grouped", "dense"):
+        config = dataclasses.replace(narrow, moe_impl=impl)
+        step = jax.jit(partial(afmoe.decode_step, config=config, mesh=mesh,
+                               ctx_pages=table))
+        text = str(jax.make_jaxpr(step)(params, **call))
+        # the plan sorts the pairs by expert, the scan sorts nothing
+        assert (" sort[" in text) == (impl == "grouped")
+        outs[impl] = step(params, **call)
+    (got, got_kv, got_aux), (want, want_kv, want_aux) = (outs["grouped"],
+                                                         outs["dense"])
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=TOL, rtol=TOL)
+    for name in ("k_pages", "v_pages", "state", "conv_tail"):
+        # but the trash page, where the idle rows' entries land
+        np.testing.assert_allclose(np.asarray(getattr(got_kv, name))[:, 1:],
+                                   np.asarray(getattr(want_kv, name))[:, 1:],
+                                   atol=TOL, rtol=TOL)
+        assert not np.array_equal(np.asarray(getattr(got_kv, name)),
+                                  np.asarray(getattr(kv, name)))
+    np.testing.assert_array_equal(np.asarray(got_aux), np.asarray(want_aux))
+
+
 # ------------------------------------------------------------------ the engine
 
 def _engine(**over):
@@ -466,7 +564,8 @@ def test_engine_serves_the_family_end_to_end():
     stats = engine.stats
     assert engine.allocator.rows_in_use == 0 and stats.state_rows_total == 4
     assert 0 < stats.window_keys < stats.context_keys
-    assert stats.moe_scan_steps > 0 and stats.moe_grouped_steps > 0
+    # by the family's rule every step on one device is grouped
+    assert stats.moe_scan_steps == 0 < stats.moe_grouped_steps
     # the admission unit counts the full layers' pools, the row the rings'
     assert engine._kv_page_bytes == kv_page_bytes(CFG, PAGE, jnp.float32)
     assert engine._state_row_bytes == kv_state_bytes(CFG, 1, jnp.float32)
@@ -474,6 +573,34 @@ def test_engine_serves_the_family_end_to_end():
     assert engine._window_attrs(100) == {
         "llm.window_layers": 6, "llm.full_layers": 2, "llm.window_tokens": W}
     assert engine._window_attrs(20)["llm.window_tokens"] == 20
+
+
+def test_decode_dispatches_count_as_grouped_and_give_the_scans_tokens():
+    """A few greedy requests on one device: every decode dispatch counts as a
+    grouped step and none as a scan (``engine._count_expert_path`` by the
+    family's rule, the rule the step programs trace by); the tokens are those
+    of the same engine on the scan (``moe_impl="dense"``)."""
+    prompts = [prompt_of(n, 80 + n) for n in (70, 12, 33)]
+
+    async def run(**over):
+        engine = _engine(**over)
+        await engine.start()
+        try:
+            tokens = await asyncio.gather(*[_generate(engine, p, 8)
+                                            for p in prompts])
+            return engine.stats, tokens
+        finally:
+            await engine.stop()
+
+    stats, tokens = asyncio.run(run())
+    scan_stats, scan_tokens = asyncio.run(run(moe_impl="dense"))
+    assert tokens == scan_tokens and all(len(t) == 8 for t in tokens)
+    assert stats.decode_steps >= 7
+    # dense prefills and chunk rounds are prefill batches: grouped before too
+    assert (stats.moe_grouped_steps, stats.moe_scan_steps) == (
+        stats.decode_steps + stats.prefill_batches, 0)
+    assert scan_stats.moe_grouped_steps == 0
+    assert scan_stats.moe_scan_steps >= scan_stats.decode_steps >= 7
 
 
 def test_an_engine_of_another_family_has_no_window_attributes():

@@ -13,6 +13,7 @@ and the check that the script refuses to run without a TPU.
 """
 
 import asyncio
+import dataclasses
 import json
 import os
 import re
@@ -212,7 +213,7 @@ def _sdar_paged_case(chunk: int, batch: int, table: int):
 
 
 def _window_paged_case(chunk: int | None, batch: int):
-    """trinity-mini-d8.mixedctx-closed: the paged kernel under a window of
+    """trinity-mini-d8.mixedctx-open: the paged kernel under a window of
     2048 over the six window layers' rings (33 rows x 25 ring pages and the
     trash page, 4 kv heads, 8 query heads a kv head), the ring table the
     step program hands it (32 columns: the narrowest context bucket that
@@ -430,8 +431,6 @@ def test_which_buckets_have_a_half_on_a_v5e_mesh(v5e):
     """On a TPU mesh a bucket's half-length program has to be whole units of
     the family's prefill kernels and keep the bucket's expert formulation
     (``engine._find_half_lengths``): what that gives the cells' buckets."""
-    import dataclasses
-
     import numpy as np
     from jax.sharding import Mesh
 
@@ -521,6 +520,62 @@ def test_block_step_and_masked_prefill_compile_for_v5e(v5e):
     assert sdar.expert_path(cfg, mesh, 4 * 512) == "grouped"
     assert sdar.expert_path(cfg, mesh, B * Bl) == "grouped"
     assert (expert_block(cfg, 4 * 512), expert_block(cfg, B * Bl)) == (128, 16)
+
+
+def test_window_family_decode_step_compiles_grouped_for_v5e(v5e):
+    """trinity-mini-d8.mixedctx-open's decode step, whole, at the cell's
+    widths and four layers deep (a dense layer, two window layers and a full
+    one over 128 int8 experts top-8): 32 rows over a 32-page bucket lower with
+    the row-block kernel in every routed layer, at the 16-row block the step's
+    width gives it, and with no loop that carries the expert stacks (the
+    expert scan's ``while``; the plan's binary search is a loop too, over a
+    vector of 128)."""
+    from mcp_context_forge_tpu.tpu_local.models import afmoe
+    from mcp_context_forge_tpu.tpu_local.models.configs import AfmoeConfig
+    from mcp_context_forge_tpu.tpu_local.parallel.mesh import make_mesh
+    from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
+
+    cfg = AfmoeConfig(name="trinity-d4", vocab_size=200192, dim=2048,
+                      n_layers=4, n_heads=32, n_kv_heads=4, head_dim=HD,
+                      ffn_hidden=6144, moe_ffn_hidden=1024, n_experts=128,
+                      moe_top_k=8, sliding_window=2048, n_dense_layers=1)
+    routed = cfg.n_layers - cfg.n_dense_layers
+    mesh = make_mesh("", devices=[next(iter(v5e.device_set))])
+    like = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    params = like(jax.eval_shape(lambda: quantize_tree(
+        afmoe.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16),
+        afmoe.params_logical(cfg), scale_dtype=jnp.bfloat16)))
+    B = 32
+    kv = like(jax.eval_shape(partial(afmoe.init_kv_state, cfg, 4352, PAGE, B,
+                                     132, dtype=jnp.bfloat16)))
+    assert afmoe.expert_path(cfg, mesh, B) == "grouped"
+    assert afmoe.expert_block(cfg, B) == 16
+
+    def lowered(config):
+        step = jax.jit(
+            lambda p, kv, tok, pos, slots, lens, live: afmoe.decode_step(
+                p, config, tok, pos, kv, slots, lens, ctx_pages=32,
+                write_mask=live, paged_impl="pallas", mesh=mesh)[:2],
+            donate_argnums=(1,))
+        with mesh:
+            return step.lower(
+                params, kv, spec((B,), jnp.int32), spec((B,), jnp.int32),
+                spec((B,), jnp.int32), spec((B,), jnp.int32),
+                spec((B,), jnp.bool_)).compile().as_text()
+
+    def stack_loops(text):
+        return [line for line in text.splitlines()
+                if " while(" in line and "s8[128,2048,1024]" in line]
+
+    text = lowered(cfg)
+    # the paged kernel in every layer, the row-block kernel in the routed ones
+    assert text.count("tpu_custom_call") >= cfg.n_layers + routed
+    assert "grouped_moe_q8" in text and not stack_loops(text)
+    # what the check tells apart: the same step on the scan
+    scan = lowered(dataclasses.replace(cfg, moe_impl="dense"))
+    assert "grouped_moe_q8" not in scan and len(stack_loops(scan)) == routed
 
 
 # ------------------------------------------------- chip_smoke.py, rehearsed

@@ -450,12 +450,14 @@ def test_expert_rule_table(name, tokens, dtype, path, block):
     pure function of the step's shape, the same for the trunk and for the
     family that imports it, and the scan wherever one device does not hold
     the stacks or no mesh is named."""
-    from mcp_context_forge_tpu.tpu_local.models import llama, sdar
+    from mcp_context_forge_tpu.tpu_local.models import afmoe, llama, sdar
 
     config = RULE_CONFIGS[name]()
     assert llama.expert_path(config, ONE_DEVICE, tokens, dtype) == path
     assert llama.expert_block(config, tokens, dtype) == block
     assert sdar.expert_path is llama.expert_path
+    # the window / full family weighs a narrow step by a rule of its own
+    assert afmoe.expert_path is not llama.expert_path
     two = type("Mesh", (), {"shape": {"data": 1, "model": 2}})()
     assert llama.expert_path(config, two, tokens, dtype) == "scan"
     assert llama.expert_path(config, None, tokens, dtype) == "scan"
