@@ -433,12 +433,11 @@ class Settings(BaseModel):
     tpu_local_mesh_shape: str = ""  # 'DxM' (e.g. 1x8 on v5e-8); '' = auto (1 x all devices)
     tpu_local_sp_impl: Literal["none", "ring", "ulysses"] = "none"
     tpu_local_sp_threshold: int = 1024  # prefill BUCKETS > this use SP prefill
-    tpu_local_decode_block: int = 1     # decode steps fused per dispatch
     # K-step decode super-steps (token-loop fusion): one jitted on-device
     # loop runs K decode iterations — fused sampling, in-loop paged-KV
     # append, per-slot budget/EOS masking freezing finished rows — and
-    # the host syncs once per K tokens. Supersedes tpu_local_decode_block
-    # (legacy alias). Raise on host-dispatch-bound TPU decode (8-16);
+    # the host syncs once per K tokens. Raise on host-dispatch-bound TPU
+    # decode (8-16);
     # trade: up to K-1 tokens of lookahead compute waste past EOS, and
     # admissions wait out the in-flight super-step (TTFT vs throughput).
     tpu_local_superstep: int = 1
@@ -518,10 +517,6 @@ class Settings(BaseModel):
     # denotes, the pool holds ~2x the pages (kv/paged_cache.py)
     tpu_local_kv_quant: str = ""
     tpu_local_moe_impl: str = ""  # ""=model default | dense | grouped | grouped_pallas
-    # decode batch-width bucketing (+ slot compaction, shrink hysteresis):
-    # size decode dispatches by active load — enable for latency-sensitive
-    # low-concurrency serving; bursty full loads prefer fixed max_batch
-    tpu_local_batch_buckets: bool = False
     # moderation classify granularity: texts longer than the window are
     # scored over fixed windows (max-pooled) — 'full' strides the whole
     # text (bounded by max_windows; the default covers 1024 tokens, a

@@ -531,13 +531,11 @@ function renderController(snap){
     const k = knobs[rid] || {};
     return `<tr><td>${esc(rid)}</td><td>${cell(k.superstep)}</td>`
       + `<td>${esc(JSON.stringify(k.warmed_k||[]))}</td>`
-      + `<td>${cell(k.width_floor)}</td><td>${cell(k.batch_width)}</td>`
       + `<td>${k.spec_built ? (k.spec_enabled ? "on" : "off") : "-"}</td></tr>`;
   }).join("");
   const knobTable = knobRows
     ? `<br><h3>replica knobs</h3><table><tr><th>replica</th><th>K</th>`
-      + `<th>warmed_k</th><th>width_floor</th><th>batch_width</th>`
-      + `<th>spec</th></tr>${knobRows}</table>`
+      + `<th>warmed_k</th><th>spec</th></tr>${knobRows}</table>`
     : "<br>no engines wired";
   // decision ring, newest first: every row says what the controller
   // saw, what it moved, and what the signals did afterwards

@@ -806,7 +806,7 @@ document.getElementById("f").onsubmit = async (e) => {
             # host syncs: one retire per dispatch; steps/dispatches ≈ the
             # effective superstep K (token-loop fusion, perf_decode.md)
             "decode_dispatches": stats.decode_dispatches,
-            "superstep": engine.config.fused_steps,
+            "superstep": engine.config.superstep,
             "prefill_batches": stats.prefill_batches,
             "prefill_requests": stats.prefill_requests,
             # host-to-device transfers made for dispatches: over

@@ -580,14 +580,14 @@ class PrometheusRegistry:
         self.controller_decisions = Counter(
             "mcpforge_controller_decisions_total",
             "Serving-controller knob decisions, by knob (superstep, "
-            "width_floor, spec, shed_bar) and direction (up, down, on, "
+            "spec, shed_bar) and direction (up, down, on, "
             "off, hold_rejected = the engine refused the staged value)",
             ["knob", "direction"], registry=self.registry,
         )
         self.controller_knob = Gauge(
             "mcpforge_controller_knob",
             "Current serving-knob posture per replica (superstep = "
-            "active K, width_floor = decode width floor, spec = 0/1, "
+            "active K, spec = 0/1, "
             "shed_bar = OverloadShedder shed_at; gateway-scope knobs "
             "use replica '-')",
             ["knob", "replica"], registry=self.registry,

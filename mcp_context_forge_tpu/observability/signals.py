@@ -31,7 +31,7 @@ Signal names are dotted strings, conventionally::
     llm.mfu                  llm.hbm_roofline_frac   llm.tokens_per_dispatch
     llm.ttft_ms              llm.tpot_ms             llm.queue_wait_ms
     llm.saturation           llm.idle_frac           llm.dispatch_gap_ms
-    llm.spec_accept          llm.occupancy           gw.loop_lag_ms
+    llm.spec_accept          gw.loop_lag_ms
     slo.burn_rate            tenant.quota_ratio
 
 The ``replica`` key scopes per-engine signals ("0", "1", ...); gateway-

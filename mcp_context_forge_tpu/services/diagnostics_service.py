@@ -75,7 +75,7 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
         "queue_depth": stats.queue_depth,
         "decode_steps": stats.decode_steps,
         "decode_dispatches": stats.decode_dispatches,
-        "superstep": engine.config.fused_steps,
+        "superstep": engine.config.superstep,
         "prefill_batches": stats.prefill_batches,
         # host-to-device transfers made for dispatches (one packed call
         # each, a table sync where rows were dirty): per step in "steps"

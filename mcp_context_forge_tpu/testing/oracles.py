@@ -515,22 +515,11 @@ def page_allocator_oracle(mod: types.ModuleType) -> None:
     assert alloc.free_pages == 0
     assert alloc.cached_pages <= 1                # chain broken by eviction
 
-    # move_slot: pages follow the new id, old id empties
-    alloc = PA(num_pages=8, page_size=4, max_slots=4, max_pages_per_slot=4)
-    assert alloc.allocate_slot(3, 8)
-    pages = list(alloc._slots[3])
-    alloc.move_slot(3, 0)
-    assert alloc._slots[0] == pages and 3 not in alloc._slots
-    table = alloc.tables()
-    assert int(np.asarray(table)[0, 0]) == pages[0]
-    assert int(np.asarray(table)[3, 0]) == 0
-
 
 def _state_row_spec(mod: types.ModuleType) -> None:
     """State-row contract (families with per-sequence pools): a slot is
-    dealt exactly one row beside its pages, never row 0 (the trash row), the
-    row follows the slot through ``move_slot`` and returns to the free list
-    with the slot. A surviving mutant is two sequences sharing a recurrent
+    dealt exactly one row beside its pages, never row 0 (the trash row), and
+    the row returns to the free list with the slot. A surviving mutant is two sequences sharing a recurrent
     state, or a row leak that runs the pool dry."""
     import numpy as np
 
@@ -555,9 +544,7 @@ def _state_row_spec(mod: types.ModuleType) -> None:
     assert alloc.slot_row(1) == 0 and alloc.rows_in_use == 2
     assert np.asarray(alloc.state_row_table()).tolist() == [rows[0], 0, rows[2]]
     alloc.free_slot(1)                           # freeing twice adds no row
-    alloc.move_slot(2, 1)                        # the row follows by id
-    assert alloc.slot_row(1) == rows[2] and alloc.slot_row(2) == 0
-    assert alloc.allocate_slot(2, 4) and alloc.slot_row(2) == rows[1]
+    assert alloc.allocate_slot(1, 4) and alloc.slot_row(1) == rows[1]
     for slot in range(3):
         alloc.free_slot(slot)
     assert alloc.rows_in_use == 0
@@ -603,11 +590,8 @@ def _dirty_tracking_spec(mod: types.ModuleType) -> None:
     assert dry.slot_pages(0) == 2 and dry.free_pages == 0
     assert dry.dirty
 
-    # move and free both dirty; the freed row reads back as zeros
-    alloc.move_slot(0, 2)
-    assert alloc.dirty
-    assert int(np.asarray(alloc.tables())[2, 0]) > 0
-    alloc.free_slot(2)
+    # a free dirties; the freed row reads back as zeros
+    alloc.free_slot(0)
     assert alloc.dirty
     assert (np.asarray(alloc.tables()) == 0).all()
     assert not alloc.dirty

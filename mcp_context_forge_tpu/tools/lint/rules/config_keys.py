@@ -14,7 +14,9 @@ Checks (both anchored at the field's declaration line):
    never an Attribute) so it cannot satisfy its own check; config.py's
    computed properties (``cors_origins`` parsing ``cors_allowed_origins``)
    and ``getattr(settings, "name", default)`` string literals count as
-   reads. Fields read only through f-string getattr (dynamic key
+   reads, and a ``Settings`` field ``tpu_local_<f>`` (or ``<f>``) beside
+   an ``EngineConfig`` field ``<f>`` is read where that field is
+   (``EngineConfig.from_settings`` fills every field by that rule). Fields read only through f-string getattr (dynamic key
    construction) or kept deliberately (forward-compat) get
    ``# lint: allow[config-key-liveness] <why it stays>``.
 2. **Undocumented field** — the name appears nowhere in the
@@ -62,6 +64,13 @@ class ConfigKeyLivenessRule(Rule):
             # its own check; config.py-internal reads are computed
             # properties (cors_origins etc.), a legitimate consumption
             readers = graph.attr_reads.get(name, set())
+            filled = name.removeprefix("tpu_local_")
+            if not readers and owner == "Settings" \
+                    and filled in graph.engine_fields:
+                # EngineConfig.from_settings fills each field from its
+                # setting by rule, not by name: the setting is read where
+                # the field it fills is
+                readers = graph.attr_reads.get(filled, set())
             if not readers:
                 findings.append(Finding(
                     self.rule_id, site.path, site.lineno,

@@ -9,7 +9,7 @@ to the whole signal plane.
 
 Consumed-name extraction handles the tree's three read idioms: direct
 literals (``bus.get("llm.spec_accept", rid)``), same-class forwarders
-(``self._view("llm.occupancy", rid)`` → ``bus.get(name, ...)``), and
+(``self._view("llm.idle_frac", rid)`` → ``bus.get(name, ...)``), and
 constant-tuple loops (``for name in self._EFFECT_SIGNALS: bus.ewma(name,
 ...)``).
 

@@ -3,9 +3,9 @@
 ROADMAP item 1's second half. The stack measures everything — live
 MFU/roofline, flight-recorder phase vectors, tenant SLO burn, queue-wait
 and TTFT percentiles — but until now every serving knob (superstep K,
-batch-bucket widths, spec decode, shed bars) was frozen config. This
+spec decode, shed bars) was frozen config. This
 module consumes the live :class:`~..observability.signals.SignalBus` and
-retunes four knobs inside hard safety rails:
+retunes three knobs inside hard safety rails:
 
 - **Adaptive superstep K** (per replica): queue-wait p95 past
   ``queue_wait_high_ms`` steps K DOWN one warmed ladder rung (drain
@@ -15,10 +15,6 @@ retunes four knobs inside hard safety rails:
   Moves land ONLY at engine drain barriers on pre-warmed executables
   (:meth:`TPUEngine.request_knobs` rejects unwarmed rungs), so greedy
   parity holds and a knob move can never compile mid-traffic.
-- **Batch-width floor** (per replica): the live occupancy histogram's
-  p95 picks the smallest warmed bucket the engine may shrink to —
-  shrink/re-grow churn (each re-homes the donated KV pool) stops when
-  load says the burst will return.
 - **Spec decode on/off** (per replica): measured acceptance (extra
   tokens per row per verify dispatch) below ``spec_accept_off`` turns
   drafting off; a stale acceptance signal after ``reprobe_after_s``
@@ -256,9 +252,6 @@ class ServingController:
         move = self._decide_superstep(rid, state, now)
         if move is not None:
             out.append(self._actuate(engine, rid, "superstep", move, now))
-        move = self._decide_width_floor(rid, state, now)
-        if move is not None:
-            out.append(self._actuate(engine, rid, "width_floor", move, now))
         move = self._decide_spec(rid, state, now)
         if move is not None:
             out.append(self._actuate(engine, rid, "spec", move, now))
@@ -309,41 +302,6 @@ class ServingController:
                                     qw["p95"] if qw else None,
                                 "threshold": self.idle_frac_high * margin}}
         return None
-
-    def _decide_width_floor(self, rid: str, state: dict[str, Any],
-                            now: float) -> dict[str, Any] | None:
-        widths = sorted(state.get("warmed_widths", []))
-        # a single warmed width means fixed-width serving (batch
-        # bucketing off): there is no floor ladder to manage, and asking
-        # anyway would fill the audit ring with one hold_rejected per
-        # tick (a refusal deliberately does not burn the cooldown)
-        if len(widths) < 2 or not self._cooldown_ok(rid, "width_floor", now):
-            return None
-        occ = self._view("llm.occupancy", rid)
-        if occ is None:
-            return None
-        current = state.get("width_floor", 0)
-        max_width = widths[-1]
-        # the p95 of live occupancy says where bursts keep landing; a
-        # floor below that just buys shrink/re-grow pool re-homes
-        need = occ["p95"] * max_width
-        target = 0
-        if occ["p95"] >= 0.25:
-            for w in widths:
-                if w >= need:
-                    target = w
-                    break
-            else:
-                target = max_width
-        if target == current:
-            return None
-        direction = "up" if target > current else "down"
-        if self._reversal_margin(rid, "width_floor", direction) > 1.0 \
-                and abs(target - current) <= 0:
-            return None
-        return {"direction": direction, "from": current, "to": target,
-                "why": {"llm.occupancy.p95": occ["p95"],
-                        "max_width": max_width}}
 
     def _decide_spec(self, rid: str, state: dict[str, Any],
                      now: float) -> dict[str, Any] | None:
@@ -415,9 +373,6 @@ class ServingController:
             if knob == "superstep":
                 result = engine.request_knobs(superstep=move["to"])
                 accepted = result.get("superstep", False)
-            elif knob == "width_floor":
-                result = engine.request_knobs(width_floor=move["to"])
-                accepted = result.get("width_floor", False)
             elif knob == "spec":
                 result = engine.request_knobs(
                     spec_enabled=bool(move["to"]))
@@ -536,9 +491,6 @@ class ServingController:
                 state = engine.knob_state()
                 self.metrics.controller_knob.labels(
                     knob="superstep", replica=rid).set(state["superstep"])
-                self.metrics.controller_knob.labels(
-                    knob="width_floor", replica=rid).set(
-                    state["width_floor"])
                 self.metrics.controller_knob.labels(
                     knob="spec", replica=rid).set(
                     1.0 if state["spec_enabled"] else 0.0)

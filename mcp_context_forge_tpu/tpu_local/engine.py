@@ -33,7 +33,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields, replace
 from functools import partial
 from typing import Any, AsyncIterator
 
@@ -87,30 +87,24 @@ class EngineConfig:
     # through ring/ulysses attention over the mesh (SURVEY.md §5.7)
     sp_impl: str = "none"      # none|ring|ulysses
     sp_threshold: int = 1024
-    # decode steps fused per device dispatch (lax.scan): amortizes the
-    # host<->device sync to 1/k per token; tokens decoded past EOS inside a
-    # block are discarded (standard multi-step scheduling waste)
-    decode_block: int = 1
     # K-step decode SUPER-STEPS (token-loop fusion, ROADMAP item 1 /
     # SnapStream-style dataflow decoding): one jitted lax.scan runs
     # ``superstep`` decode iterations entirely on device — fused
     # sampling, in-loop paged-KV page append over pre-granted pages, and
     # per-slot budget/EOS/stop masking so finished rows FREEZE on device
     # (no post-EOS KV writes, positions stop advancing) — and the host
-    # syncs once per K tokens instead of once per token. Supersedes
-    # ``decode_block`` (kept as a back-compat alias; setting both to
-    # conflicting values is rejected). Composes with decode_overlap
-    # (depth-2 pipeline at super-step granularity) and int8 KV; mutually
-    # exclusive with spec_decode like decode_block>1.
+    # syncs once per K tokens instead of once per token. Composes with
+    # decode_overlap (depth-2 pipeline at super-step granularity) and
+    # int8 KV; mutually exclusive with spec_decode.
     superstep: int = 1
     # depth-2 overlapped decode pipeline: dispatch step N+1 fed by step
     # N's device-resident sampled tokens while step N's results transfer
     # and emit one step behind, so host bookkeeping (emission, EOS
     # checks, page extension) hides behind device execution instead of
     # serializing with it. Drain barriers (admission, chunk completion,
-    # batch-width changes, stop/crash) keep token streams identical to
-    # the serial path. Ignored when spec_decode is on (the verify step
-    # has its own host feedback loop).
+    # stop/crash) keep token streams identical to the serial path.
+    # Ignored when spec_decode is on (the verify step has its own host
+    # feedback loop).
     decode_overlap: bool = True
     # seconds to wait for jax backend init before failing fast (0 = forever)
     init_timeout_s: float = 120.0
@@ -154,7 +148,7 @@ class EngineConfig:
     # spec_k drafted tokens in ONE step multiplies tokens/step by the
     # accept rate for free bandwidth-wise. Greedy rows only; sampled rows
     # ride the same verify step one token at a time. Mutually exclusive
-    # with decode_block > 1. On TPU the verify runs the Pallas paged
+    # with superstep > 1. On TPU the verify runs the Pallas paged
     # CHUNK kernel (same enabling conditions as decode); the remaining
     # trade is K x the attention/MLP compute per dispatch, so low accept
     # rates (non-repetitive output) can still lose — enable for
@@ -174,30 +168,11 @@ class EngineConfig:
     # denominated in ENGINE-DTYPE pages (a byte budget): at the same HBM
     # bytes an int8 pool holds ~2x the pages, so _init_kv converts.
     kv_quant: str = ""
-    # MoE serving formulation override ("" = model default; see
-    # models/configs.py moe_impl): dense | grouped | grouped_pallas.
-    # moe_block overrides the model's (0 = model default): the kernel's
-    # widest row-block and the T·k >= E·block width from which a step takes
-    # it (models/llama.py: expert_path) — small models need a smaller block
-    # or every dispatch takes the expert scan.
+    # the model config's ``moe_impl`` for this engine ("" = as registered;
+    # models/configs.py): dense | grouped | grouped_pallas. Kept for the
+    # CPU rehearsals of tests/benchmark/, whose ``engine`` blocks set it
+    # (ROADMAP Queue 3 ``unmeasured-options``); every cell leaves it ""
     moe_impl: str = ""
-    moe_block: int = 0
-    # decode batch-width bucketing: size decode arrays by the ACTIVE slot
-    # ceiling (pow-2, with slot compaction + shrink hysteresis) instead of
-    # max_batch. Wins on sparse/steady loads (fewer wasted rows per step);
-    # every width change re-homes the donated KV pool (~a pool copy), so
-    # the width starts at max_batch (identical to fixed width until light
-    # load is SUSTAINED), pins at max while work is queued, and only
-    # shrinks to warmup-compiled widths after batch_shrink_steps
-    # consecutive under-width steps. Off by default; enable for
-    # latency-sensitive low-concurrency serving.
-    batch_buckets: bool = False
-    batch_shrink_steps: int = 64
-    # idle-boundary width reset: after this long fully idle, the next
-    # admission re-sizes from the NEW load instead of inheriting a stale
-    # burst width. High enough that inter-wave dips (ms) never trigger
-    # the shrink+regrow re-home pair the hysteresis exists to avoid.
-    batch_idle_reset_s: float = 2.0
     # device-fault recovery (SURVEY §5.3): a crashed dispatch thread
     # rebuilds the KV pool, re-queues PENDING requests (mid-stream ones
     # fail — silent retry would duplicate emitted tokens) and restarts
@@ -218,7 +193,7 @@ class EngineConfig:
     # per-chip roofline peaks the live gauges divide by (defaults: v5e)
     peak_tflops_per_chip: float = V5E_PEAK_BF16_TFLOPS
     hbm_gbps_per_chip: float = V5E_HBM_GBPS
-    # extra superstep rungs warmed ALONGSIDE fused_steps so the serving
+    # extra superstep rungs warmed ALONGSIDE superstep so the serving
     # controller (tpu_local/controller.py) can retune K at drain
     # barriers onto pre-compiled executables — a knob move can never
     # trigger a mid-traffic XLA compile. () = no extra rungs: the
@@ -226,81 +201,47 @@ class EngineConfig:
     # compile nothing new and behave bit-identically).
     k_ladder: tuple[int, ...] = ()
 
-    @property
-    def fused_steps(self) -> int:
-        """Effective decode iterations fused per device dispatch: the
-        superstep K when set, else the legacy decode_block alias."""
-        return self.superstep if self.superstep > 1 else self.decode_block
-
     def k_rungs(self) -> tuple[int, ...]:
         """Superstep values the warmup decode grid compiles: the static
-        fused_steps plus every configured ladder rung, deduped and
+        superstep plus every configured ladder rung, deduped and
         ascending. Adaptive K only ever moves along this set."""
-        rungs = {self.fused_steps}
+        rungs = {self.superstep}
         rungs.update(int(k) for k in self.k_ladder if int(k) >= 1)
         return tuple(sorted(rungs))
 
     @classmethod
     def from_settings(cls, settings) -> "EngineConfig":
-        return cls(
-            model=settings.tpu_local_model,
-            checkpoint=settings.tpu_local_checkpoint,
-            max_batch=settings.tpu_local_max_batch,
-            max_seq_len=settings.tpu_local_max_seq_len,
-            page_size=settings.tpu_local_page_size,
-            num_pages=settings.tpu_local_num_pages,
-            prefill_buckets=tuple(settings.tpu_local_prefill_buckets),
-            prefill_max_batch=getattr(settings, "tpu_local_prefill_max_batch", 4),
-            mesh_shape=settings.tpu_local_mesh_shape,
-            dtype=settings.tpu_local_dtype,
-            sp_impl=getattr(settings, "tpu_local_sp_impl", "none"),
-            sp_threshold=getattr(settings, "tpu_local_sp_threshold", 1024),
-            decode_block=getattr(settings, "tpu_local_decode_block", 1),
-            superstep=getattr(settings, "tpu_local_superstep", 1),
-            decode_overlap=getattr(settings, "tpu_local_decode_overlap", True),
-            init_timeout_s=getattr(settings, "tpu_local_init_timeout_s", 120.0),
-            warmup=getattr(settings, "tpu_local_warmup", False),
-            warmup_mode=getattr(settings, "tpu_local_warmup_mode", "full"),
-            prefix_cache=getattr(settings, "tpu_local_prefix_cache", True),
-            prefix_tiers=getattr(settings, "tpu_local_prefix_tiers", False),
-            tier_host_bytes=getattr(
-                settings, "tpu_local_tier_host_bytes", 256 * 1024 * 1024),
-            tier_disk_bytes=getattr(
-                settings, "tpu_local_tier_disk_bytes", 1024 * 1024 * 1024),
-            tier_disk_dir=getattr(settings, "tpu_local_tier_disk_dir", ""),
-            tier_spill_quant=getattr(
-                settings, "tpu_local_tier_spill_quant", "int8"),
-            tier_io_retry_max=getattr(settings, "tier_io_retry_max", 2),
-            tier_io_retry_backoff_ms=getattr(
-                settings, "tier_io_retry_backoff_ms", 10.0),
-            tier_object_url=getattr(
-                settings, "tpu_local_tier_object_url", ""),
-            fabric_namespace=getattr(
-                settings, "tpu_local_fabric_namespace", "shared"),
-            spec_decode=getattr(settings, "tpu_local_spec_decode", False),
-            spec_k=getattr(settings, "tpu_local_spec_k", 4),
-            spec_ngram=getattr(settings, "tpu_local_spec_ngram", 2),
-            quant=getattr(settings, "tpu_local_quant", ""),
-            kv_quant=getattr(settings, "tpu_local_kv_quant", ""),
-            moe_impl=getattr(settings, "tpu_local_moe_impl", ""),
-            batch_buckets=getattr(settings, "tpu_local_batch_buckets", False),
-            max_queue=getattr(settings, "tpu_local_max_queue", 1024),
-            auto_restart=getattr(settings, "tpu_local_auto_restart", False),
-            auto_restart_max=getattr(settings, "tpu_local_auto_restart_max", 3),
-            step_log_size=getattr(settings, "tpu_local_step_log_size", 256),
-            cost_analysis=getattr(settings, "tpu_local_cost_analysis", True),
-            peak_tflops_per_chip=getattr(
-                settings, "tpu_local_peak_tflops_per_chip",
-                V5E_PEAK_BF16_TFLOPS),
-            hbm_gbps_per_chip=getattr(
-                settings, "tpu_local_hbm_gbps_per_chip", V5E_HBM_GBPS),
-            # extra K rungs only when the controller is on: off keeps the
-            # warmup grid — and therefore compile count and serving
-            # behavior — bit-identical to a pre-controller build
-            k_ladder=(tuple(getattr(settings, "controller_k_ladder", ()))
-                      if getattr(settings, "controller_enabled", False)
-                      else ()),
-        )
+        """Every field from its ``tpu_local_<field>`` setting, but for the
+        few in ``_SETTING_OF``. A setting that is missing is an
+        ``AttributeError`` here, at start-up: a default lives in ``Settings``
+        and on the field, nowhere else."""
+        values = {}
+        for spec in fields(cls):
+            source = _SETTING_OF.get(spec.name, "tpu_local_" + spec.name)
+            if source is None:
+                continue
+            value = (source(settings) if callable(source)
+                     else getattr(settings, source))
+            values[spec.name] = (tuple(value) if isinstance(spec.default, tuple)
+                                 else value)
+        return cls(**values)
+
+
+# EngineConfig fields that are not read from ``tpu_local_<field>``: another
+# setting's name, a function of the settings, or None for a field that no
+# setting reaches (a pool names its replicas; a test or a bench picks the
+# attention implementation)
+_SETTING_OF: dict[str, Any] = {
+    "replica_id": None,
+    "attn_impl": None,
+    "tier_io_retry_max": "tier_io_retry_max",
+    "tier_io_retry_backoff_ms": "tier_io_retry_backoff_ms",
+    # extra K rungs only when the controller is on: off keeps the warmup
+    # grid — and therefore compile count and serving behavior —
+    # bit-identical to a pre-controller build
+    "k_ladder": lambda settings: (settings.controller_k_ladder
+                                  if settings.controller_enabled else ()),
+}
 
 
 @dataclass
@@ -586,22 +527,12 @@ class TPUEngine:
         self.timeline = StepTimeline(config.replica_id)
         if metrics is not None:
             metrics.watch_gc(gc_watch)      # mcpforge_gc_pause_seconds
-        if config.decode_block < 1:
-            raise ValueError(
-                f"decode_block must be >= 1, got {config.decode_block}")
         if config.superstep < 1:
             raise ValueError(
                 f"superstep must be >= 1, got {config.superstep}")
-        if (config.superstep > 1 and config.decode_block > 1
-                and config.superstep != config.decode_block):
-            raise ValueError(
-                f"superstep={config.superstep} and decode_block="
-                f"{config.decode_block} disagree — set only one "
-                "(decode_block is the legacy alias)")
-        if config.spec_decode and config.fused_steps > 1:
-            raise ValueError("spec_decode and superstep/decode_block>1 are "
-                             "mutually exclusive (both widen the "
-                             "per-dispatch step)")
+        if config.spec_decode and config.superstep > 1:
+            raise ValueError("spec_decode and superstep>1 are mutually "
+                             "exclusive (both widen the per-dispatch step)")
         if config.spec_decode and any(int(k) > 1 for k in config.k_ladder):
             raise ValueError("k_ladder rungs > 1 are mutually exclusive "
                              "with spec_decode (same exclusivity as "
@@ -650,27 +581,12 @@ class TPUEngine:
         # the fused super-step width every decode dispatch scans over
         # (1 = the classic one-token step); resolved once — the compiled
         # grid is keyed on it
-        self._k = config.fused_steps
-        if config.batch_buckets and not config.warmup:
-            # unwarmed engines shrink only to widths already compiled
-            # in-process (shrinking never compiles on the serving path);
-            # warmup compiles the whole grid up front and starts at max
-            logger.info(
-                "batch_buckets=true without warmup: width starts small "
-                "and shrink targets are limited to in-process-compiled "
-                "widths — set MCPFORGE_TPU_LOCAL_WARMUP=true for "
-                "production serving")
+        self._k = config.superstep
         self.compile_cache_dir = apply_compile_cache()
         self.model_config: LlamaConfig = MODEL_CONFIGS[config.model]
-        if config.moe_impl or config.moe_block:
-            import dataclasses
-            overrides: dict[str, Any] = {}
-            if config.moe_impl:
-                overrides["moe_impl"] = config.moe_impl
-            if config.moe_block:
-                overrides["moe_block"] = config.moe_block
-            self.model_config = dataclasses.replace(self.model_config,
-                                                    **overrides)
+        if config.moe_impl:
+            self.model_config = replace(self.model_config,
+                                        moe_impl=config.moe_impl)
         # the model family (models/__init__.py): the module whose step
         # functions, weight tree and cache pools serve this config's class
         self._family = family_of(self.model_config)
@@ -713,27 +629,6 @@ class TPUEngine:
         self._emit_buf: list[list[Any]] = []  # lint: thread[dispatch]
         # dispatch-gap telemetry: (gap_s, step_wall_s) per decode step
         self._gap_window: deque[tuple[float, float]] = deque(maxlen=256)  # lint: thread[dispatch]
-        # decode batch-width hysteresis state (see _decode_step_all).
-        # UNWARMED engines start small (light load is free immediately; a
-        # burst pays ONE grow re-home) and may shrink back to any width
-        # compiled earlier in-process. warmup() flips the posture: width
-        # starts at max (a warmed engine must never be slower than fixed
-        # width — the round-5 config-4 A/B) and shrink targets are the
-        # whole warmed grid. (_batch_width itself is set to the smallest
-        # bucket just below, once _warmed_widths exists.)
-        self._shrink_streak = 0  # lint: thread[dispatch]
-        self._shrink_peak = 0  # lint: thread[dispatch]
-        # widths whose full ctx-bucket decode grid warmup precompiled:
-        # shrinking is an OPTIMIZATION, so the engine never eats a
-        # mid-traffic compile (+ donated-pool re-home) to get smaller —
-        # only warmed widths are shrink targets. Growth is correctness
-        # (arrays must cover the ceiling) and may compile.
-        self._warmed_widths: set[int] = set()  # lint: thread[dispatch]
-        self._batch_width = self._batch_buckets()[0]  # smallest  # lint: thread[dispatch]
-        # when the engine last had active work (idle-boundary reset guard);
-        # starts "now" so the warmed start-at-max posture survives a
-        # burst arriving right after startup
-        self._last_active_ts = time.monotonic()  # lint: thread[dispatch]
         # liveness heartbeat: bumped once per dispatch-loop iteration (the
         # idle wait is bounded at 50 ms, so a healthy engine beats at
         # >=20 Hz even with no traffic). The pool's health monitor reads
@@ -764,10 +659,6 @@ class TPUEngine:
         # decode is always warmed as the fallback path, so flipping this
         # never compiles; engines built without spec_decode ignore it
         self._spec_enabled = True  # lint: thread[dispatch]
-        # controller-requested decode width floor (0 = none): bounds the
-        # batch-bucket shrink path from below when the live occupancy
-        # histogram says the next burst will just re-grow anyway
-        self._width_floor = 0  # lint: thread[dispatch]
         # superstep rungs the warmup grid compiled; adaptive K may only
         # select these (request_knobs rejects anything else)
         self._warmed_k: set[int] = set()  # lint: thread[dispatch]
@@ -907,7 +798,7 @@ class TPUEngine:
                            donate_argnames=("kv",)), "_prefill_and_sample")
             if config.sp_impl != "none" else None)
         self.half_lengths = self._find_half_lengths()
-        # decode compiles per (batch-width, context-width) bucket pair:
+        # decode compiles per (superstep K, context-width bucket) pair:
         # attention reads only the table columns the longest active row
         # needs — the full-width gather wastes ~max_context/actual_context
         # x HBM bandwidth on short conversations, and decode is
@@ -917,9 +808,9 @@ class TPUEngine:
         # same grid as _decode_fns, but the input token comes from the
         # PREVIOUS dispatch's on-device sampled block instead of the host
         self._decode_fb_fns: dict[tuple[int, int], Any] = {}
-        # a block family's decode grid: (batch-width, context-width) ->
-        # its block step (and then neither dict above ever fills)
-        self._block_fns: dict[tuple[int, int], Any] = {}
+        # a block family's decode grid: context-width bucket -> its block
+        # step (and then neither dict above ever fills)
+        self._block_fns: dict[int, Any] = {}
         # the chunk/history prefill is a core primitive (prefix-cache hits
         # AND chunked prefill of prompts longer than the largest bucket);
         # compiled per context-width bucket like decode (a hit with 40
@@ -1170,33 +1061,12 @@ class TPUEngine:
                 return bucket
         return self._ctx_buckets()[-1]
 
-    def _batch_buckets(self) -> list[int]:
-        """Decode batch-width buckets: powers of two from 8 (or max_batch
-        if smaller) up to max_batch. Decode dispatches size their arrays
-        by the ACTIVE slot ceiling, not configured capacity — with slot
-        compaction (below) a half-idle engine stops paying attention and
-        sampling FLOPs for empty slots."""
-        buckets = []
-        width = min(8, self.config.max_batch)
-        while width < self.config.max_batch:
-            buckets.append(width)
-            width *= 2
-        buckets.append(self.config.max_batch)
-        return buckets
-
-    def _batch_bucket_for(self, active_ceiling: int) -> int:
-        for bucket in self._batch_buckets():
-            if bucket >= active_ceiling:
-                return bucket
-        return self.config.max_batch
-
-    def _decode_fn(self, ctx_pages: int, batch: int | None = None,
-                   k: int | None = None):
+    def _decode_fn(self, ctx_pages: int, k: int | None = None):
         # K is part of the executable identity (the scan length is baked
         # into the trace), so the cache keys on it: adaptive K switches
         # between PRE-COMPILED entries and can never compile mid-traffic
         k = self._k if k is None else int(k)
-        key = (k, batch or self.config.max_batch, ctx_pages)
+        key = (k, ctx_pages)
         fn = self._decode_fns.get(key)
         if fn is None:
             fn = _named(jax.jit(partial(self._of_call(self._decode_and_sample,
@@ -1207,10 +1077,9 @@ class TPUEngine:
             self._decode_fns[key] = fn
         return fn
 
-    def _decode_fb_fn(self, ctx_pages: int, batch: int | None = None,
-                      k: int | None = None):
+    def _decode_fb_fn(self, ctx_pages: int, k: int | None = None):
         k = self._k if k is None else int(k)
-        key = (k, batch or self.config.max_batch, ctx_pages)
+        key = (k, ctx_pages)
         fn = self._decode_fb_fns.get(key)
         if fn is None:
             fn = _named(jax.jit(partial(
@@ -1221,45 +1090,16 @@ class TPUEngine:
             self._decode_fb_fns[key] = fn
         return fn
 
-    def _block_fn(self, ctx_pages: int, batch: int | None = None):
-        key = (batch or self.config.max_batch, ctx_pages)
-        fn = self._block_fns.get(key)
+    def _block_fn(self, ctx_pages: int):
+        fn = self._block_fns.get(ctx_pages)
         if fn is None:
             fn = _named(jax.jit(partial(
                 self._of_call(self._decode_and_sample_block,
                               self._block_call),
                 ctx_pages=ctx_pages), donate_argnames=("kv",)),
                         "_decode_and_sample_block")
-            self._block_fns[key] = fn
+            self._block_fns[ctx_pages] = fn
         return fn
-
-    def _compact_slots(self) -> None:
-        """Move the highest-slot requests into the lowest free slots so the
-        active ceiling equals the active COUNT. Only block-table rows and
-        state-row ids move (pages and state rows are slot-agnostic: no state
-        is copied); the device tables refresh on the next _sync_tables. Runs
-        between dispatches on the dispatch thread."""
-        if not self._running:
-            return
-        # dense prefix already (the steady state at ANY constant load):
-        # skip the sort + first frees-scan the old loop paid per decode
-        # step before breaking (constant-factor, not the O(B^2) sparse
-        # path — a checkerboard of finishes still pays up to B/2 moves)
-        occupied = len(self._running) + len(self._chunking)
-        ceiling = max(max(self._running),
-                      max(self._chunking, default=-1)) + 1
-        if ceiling == occupied:
-            return
-        for slot in sorted(self._running, reverse=True):
-            frees = [s for s in range(slot)
-                     if s not in self._running and s not in self._chunking]
-            if not frees:
-                break  # nothing lower is free: already compact
-            target = frees[0]
-            request = self._running.pop(slot)
-            self.allocator.move_slot(slot, target)
-            request.slot = target
-            self._running[target] = request
 
     def _find_half_lengths(self) -> dict[int, int]:
         """Dense prefill bucket -> the length of its half program: the same
@@ -1473,89 +1313,73 @@ class TPUEngine:
             # plain decode is always live: spec engines fall back to it on
             # steps where no greedy row would draft (width-K verify would be
             # pure compute waste — round-2 ADVICE low). One compile per
-            # (batch-width, context-width) bucket pair. An idle call's
+            # (context-width bucket, K rung) pair. An idle call's
             # seq_lens are 0: every slot is "inactive", writes masked to
-            # trash, budgets zero, the stop table empty
-            widths = (self._batch_buckets() if self.config.batch_buckets
-                      else [self.config.max_batch])
-            # the K ladder multiplies the grid: every (width, ctx, K rung)
-            # triple compiles here so the controller's adaptive K only
-            # ever lands on pre-warmed executables. With no ladder
-            # configured this is exactly the static-K grid (one rung).
+            # trash, budgets zero, the stop table empty.
+            # The K ladder multiplies the grid: every (ctx, K rung) pair
+            # compiles here so the controller's adaptive K only ever lands
+            # on pre-warmed executables. With no ladder configured this is
+            # exactly the static-K grid (one rung). A block family has no
+            # one-token decode program: its grid is the block step
             k_rungs = self.config.k_rungs()
+            ctx_buckets = self._ctx_buckets()
             if self._block:
-                # a block family has no one-token decode program: its grid
-                # is the block step, one a (width, context) pair
-                shapes += self._warmup_block_steps(widths)
-                widths = []
-            for batch in widths:
-                for ctx_pages in self._ctx_buckets():
-                    for k_rung in k_rungs:
-                        # cost entries for non-default rungs carry the
-                        # rung in the kind (FLOPs/bytes scale with K, so
-                        # MFU after a K switch must divide by the right
-                        # cost); the static rung keeps the bare kind the
-                        # existing roofline consumers look up
-                        suffix = "" if k_rung == self._k else f"@k{k_rung}"
-                        args = (self.params, self.kv, self._idle_call(
-                            self._decode_call, batch), self._rng)
+                shapes += self._warmup_block_steps()
+                ctx_buckets = []
+            for ctx_pages in ctx_buckets:
+                for k_rung in k_rungs:
+                    # cost entries for non-default rungs carry the
+                    # rung in the kind (FLOPs/bytes scale with K, so
+                    # MFU after a K switch must divide by the right
+                    # cost); the static rung keeps the bare kind the
+                    # existing roofline consumers look up
+                    suffix = "" if k_rung == self._k else f"@k{k_rung}"
+                    args = (self.params, self.kv, self._idle_call(
+                        self._decode_call, B), self._rng)
+                    if capture:
+                        self.cost_registry.capture(
+                            "decode" + suffix, B, ctx_pages,
+                            self._decode_fn(ctx_pages, k_rung), *args)
+                    (block, *_), self.kv = \
+                        self._decode_fn(ctx_pages, k_rung)(*args)
+                    block.block_until_ready()
+                    shapes += 1
+                    if (self.config.decode_overlap
+                            and self._verify_fns is None):
+                        # the pipelined steady state runs the feedback
+                        # variant; warm it alongside so overlap never
+                        # compiles mid-traffic. Feed it the plain
+                        # decode's OUTPUT block — at runtime the feed
+                        # is always the previous step's on-device jit
+                        # output, and the pjit cache keys on that
+                        # committed sharding (a fresh jnp.zeros here
+                        # would warm a cache entry traffic never hits)
+                        fb_args = (self.params, self.kv, self._idle_call(
+                            self._decode_fb_call, B), self._rng, block)
                         if capture:
                             self.cost_registry.capture(
-                                "decode" + suffix, batch, ctx_pages,
-                                self._decode_fn(ctx_pages, batch, k_rung),
-                                *args)
-                        (block, *_), self.kv = \
-                            self._decode_fn(ctx_pages, batch, k_rung)(*args)
+                                "decode_fb" + suffix, B, ctx_pages,
+                                self._decode_fb_fn(ctx_pages, k_rung),
+                                *fb_args)
+                        (block, *_), self.kv = self._decode_fb_fn(
+                            ctx_pages, k_rung)(*fb_args)
                         block.block_until_ready()
                         shapes += 1
-                        if (self.config.decode_overlap
-                                and self._verify_fns is None):
-                            # the pipelined steady state runs the feedback
-                            # variant; warm it alongside so overlap never
-                            # compiles mid-traffic. Feed it the plain
-                            # decode's OUTPUT block — at runtime the feed
-                            # is always the previous step's on-device jit
-                            # output, and the pjit cache keys on that
-                            # committed sharding (a fresh jnp.zeros here
-                            # would warm a cache entry traffic never hits)
-                            fb_args = (self.params, self.kv, self._idle_call(
-                                self._decode_fb_call, batch), self._rng,
-                                block)
-                            if capture:
-                                self.cost_registry.capture(
-                                    "decode_fb" + suffix, batch, ctx_pages,
-                                    self._decode_fb_fn(ctx_pages, batch,
-                                                       k_rung),
-                                    *fb_args)
-                            (block, *_), self.kv = self._decode_fb_fn(
-                                ctx_pages, batch, k_rung)(*fb_args)
-                            block.block_until_ready()
-                            shapes += 1
-                self._warmed_widths.add(batch)
             self._warmed_k.update(k_rungs)
-            if self.config.batch_buckets:
-                # warmed posture: start at max (never slower than fixed
-                # width; the first burst costs zero transitions) — the
-                # warmed grid makes every later shrink compile-free. Any
-                # pre-warmup shrink evidence is stale at the new width.
-                self._batch_width = self.config.max_batch
-                self._shrink_streak = 0
-                self._shrink_peak = 0
         logger.info("tpu_local warmup: %d shapes compiled in %.1fs",
                     shapes, time.monotonic() - started)
 
-    def _warmup_block_steps(self, widths: list[int]) -> int:
-        """Compile the block step for every (width, context bucket): rows
-        with positions -1 are idle, write the trash page and mask nothing,
-        so the loop makes no pass and the commit pass runs once."""
-        for batch in widths:
-            for ctx_pages in self._ctx_buckets():
-                (block, *_), self.kv = self._block_fn(ctx_pages, batch)(
-                    self.params, self.kv,
-                    self._idle_call(self._block_call, batch), self._rng)
-                block.block_until_ready()
-            self._warmed_widths.add(batch)
-        return len(widths) * len(self._ctx_buckets())
+    def _warmup_block_steps(self) -> int:
+        """Compile the block step for every context bucket: rows with
+        positions -1 are idle, write the trash page and mask nothing, so
+        the loop makes no pass and the commit pass runs once."""
+        for ctx_pages in self._ctx_buckets():
+            (block, *_), self.kv = self._block_fn(ctx_pages)(
+                self.params, self.kv,
+                self._idle_call(self._block_call, self.config.max_batch),
+                self._rng)
+            block.block_until_ready()
+        return len(self._ctx_buckets())
 
     # ------------------------------------------------------------- device fns
 
@@ -1760,7 +1584,7 @@ class TPUEngine:
                            sampling: SamplingParams, key,
                            ctx_pages: int | None = None,
                            k: int | None = None):
-        """One decode SUPER-STEP: k = config.fused_steps decode iterations
+        """One decode SUPER-STEP: k = config.superstep decode iterations
         as a single jitted lax.scan — fused sampling, in-loop paged-KV
         append over pre-granted pages, and per-slot budget/EOS/stop
         masking so finished rows FREEZE on device instead of burning a
@@ -1963,11 +1787,11 @@ class TPUEngine:
 
     @property
     def warmed(self) -> bool:
-        """True once warmup compiled at least one decode width. A warmed
+        """True once warmup compiled the decode grid. A warmed
         engine has no first-dispatch compile left, so the pool health
         monitor may read a stale heartbeat as a wedge even before the
         first traffic step retires."""
-        return bool(self._warmed_widths)
+        return bool(self._warmed_k)
 
     def request_cancel(self, request_id: str) -> bool:
         """Thread-safe: ask the dispatch thread to terminate a generation.
@@ -2314,16 +2138,15 @@ class TPUEngine:
         self._pending = kept
 
     def request_knobs(self, *, superstep: int | None = None,
-                      spec_enabled: bool | None = None,
-                      width_floor: int | None = None) -> dict[str, bool]:
+                      spec_enabled: bool | None = None) -> dict[str, bool]:
         """Stage serving-knob changes for the dispatch thread to land at
         its next drain barrier (the controller's actuation surface —
         same handoff pattern as request_cancel). Validation happens HERE,
         against the warmed grid, so a rejected value never reaches the
         loop: adaptive K may only select warmed ladder rungs (zero
-        mid-traffic XLA compiles by construction), toggling spec needs a
-        spec-built engine, and a width floor must be a warmed bucket
-        width. Returns {knob: accepted} so the caller can audit refusals.
+        mid-traffic XLA compiles by construction) and toggling spec needs
+        a spec-built engine. Returns {knob: accepted} so the caller can
+        audit refusals.
         Thread-safe; callable from any thread."""
         accepted: dict[str, bool] = {}
         staged: dict[str, Any] = {}
@@ -2341,13 +2164,6 @@ class TPUEngine:
             accepted["spec_enabled"] = ok
             if ok:
                 staged["spec_enabled"] = bool(spec_enabled)
-        if width_floor is not None:
-            w = int(width_floor)
-            ok = w == 0 or (self.config.batch_buckets
-                            and w in self._warmed_widths)
-            accepted["width_floor"] = ok
-            if ok:
-                staged["width_floor"] = min(w, self.config.max_batch)
         if staged:
             with self._knob_lock:
                 self._pending_knobs.update(staged)
@@ -2360,7 +2176,7 @@ class TPUEngine:
         was dispatched at the OLD K and its retire accounting carries its
         own ``k``; after the drain the switch is a clean barrier and the
         next dispatch picks the pre-warmed executable for the new K.
-        Spec/width-floor moves are pure host-side posture flips."""
+        A spec move is a pure host-side posture flip."""
         with self._knob_lock:
             knobs, self._pending_knobs = self._pending_knobs, {}
         if not knobs:
@@ -2372,8 +2188,6 @@ class TPUEngine:
             self._k = int(new_k)
         if "spec_enabled" in knobs:
             self._spec_enabled = bool(knobs["spec_enabled"])
-        if "width_floor" in knobs:
-            self._width_floor = int(knobs["width_floor"])
 
     def request_chain_export(self, prompt_ids: list[int]) -> "Future[int]":
         """Stage a KV chain export for the dispatch thread (the pool's
@@ -2418,10 +2232,7 @@ class TPUEngine:
             "spec_built": self._verify_fns is not None,
             "spec_enabled": bool(self._verify_fns is not None
                                  and self._spec_enabled),
-            "width_floor": self._width_floor,
-            "batch_width": self._batch_width,
             "warmed_k": sorted(self._warmed_k),
-            "warmed_widths": sorted(self._warmed_widths),
         }
 
     def _wait_for_work(self) -> None:
@@ -2535,9 +2346,6 @@ class TPUEngine:
         self._drain_work()
         if not self._pending:
             return none
-        was_idle = (not self._running and not self._chunking
-                    and (time.monotonic() - self._last_active_ts
-                         >= config.batch_idle_reset_s))
         # priority classes: interactive requests admit before queued
         # background work (summaries must not make a chat turn wait for a
         # free slot — and the sort is stable, so FIFO holds within each
@@ -2680,31 +2488,6 @@ class TPUEngine:
         if not admitted:
             return none
         self._sync_tables()
-        self._last_active_ts = time.monotonic()
-        if was_idle and config.batch_buckets:
-            # idle-boundary width reset: a width inherited from a drained
-            # burst must not tax the next arrival for batch_shrink_steps
-            # decode steps (the config-3 post-burst bad mode: summaries
-            # decoding at width 64 with 8 active). Guards: the engine was
-            # idle past batch_idle_reset_s (millisecond inter-wave dips
-            # keep the warmed start-at-max posture), the ceiling counts
-            # ADMISSIBLE load only (a page-bound backlog must not hold a
-            # too-wide bucket over a handful of decodable slots — same
-            # clamp the decode-path sizing uses), slots were assigned
-            # from index 0 up so the bucket covers every admitted slot,
-            # and the reset never compiles (warmed widths only).
-            active = len(self._running) + len(self._chunking)
-            admissible = max(0, min(
-                len(self._pending),
-                config.max_batch - active,
-                self.allocator.free_pages
-                // self.allocator.avg_slot_pages()))
-            ceiling = min(active + admissible, config.max_batch)
-            desired = self._batch_bucket_for(max(ceiling, 1))
-            if desired < self._batch_width and desired in self._warmed_widths:
-                self._batch_width = desired
-                self._shrink_streak = 0
-                self._shrink_peak = 0
         return admitted, bucket
 
     def _prefill_end(self, request: GenRequest) -> int:
@@ -3104,7 +2887,7 @@ class TPUEngine:
         """Serial decode: one fixed-shape step over every active slot,
         dispatched and retired back-to-back (the pre-overlap behavior;
         also the first step after any pipeline drain)."""
-        inflight = self._decode_dispatch(self._decode_width(), None)
+        inflight = self._decode_dispatch(None)
         self._decode_retire(inflight)
 
     def _decode_step_overlapped(self) -> None:
@@ -3116,8 +2899,7 @@ class TPUEngine:
         step N still ride dispatch N+1 (their KV writes land in pages no
         one can reuse before the next drain barrier) and their lookahead
         tokens are discarded at retire, exactly like tokens past EOS
-        inside a decode_block."""
-        config = self.config
+        inside a super-step."""
         k = self._k
         feed = self._inflight
         self._inflight = None
@@ -3129,22 +2911,14 @@ class TPUEngine:
             # - a PARTIAL budget on a row that will survive its retire
             #   (per-slot page cap granted 0 < b < k): the feedback fn
             #   feeds block row k-1, but the row's true last token is at
-            #   b-1 — only a host-fed dispatch can resume it correctly;
-            # - a batch_buckets compaction/width decision that would move
-            #   slots under it
+            #   b-1 — only a host-fed dispatch can resume it correctly
             stale = any(
                 feed["reqs"].get(slot) is not request
                 or (0 < feed["budgets"].get(slot, 0) < k
                     and len(request.generated) + feed["budgets"][slot]
                     < request.max_tokens)
                 for slot, request in self._running.items())
-            holes = False
-            if config.batch_buckets:
-                ceiling = max(self._running) + 1
-                holes = (ceiling != len(self._running) + len(self._chunking)
-                         or self._batch_bucket_for(ceiling)
-                         != self._batch_width)
-            if stale or holes:
+            if stale:
                 if not self._drain_feed(feed):
                     return
                 feed = None
@@ -3181,15 +2955,7 @@ class TPUEngine:
                 if not self._drain_feed(feed):
                     return
                 feed = None
-        B = self._decode_width(allow_compact=feed is None)
-        if feed is not None and feed["B"] != B:
-            # width changed (batch_buckets growth): the [k, B] feedback
-            # shape no longer matches — drain and restart host-fed
-            if not self._drain_feed(feed):
-                return
-            feed = None
-            B = self._decode_width()
-        nxt = self._decode_dispatch(B, feed)
+        nxt = self._decode_dispatch(feed)
         self._inflight = nxt
         if feed is not None:
             self._decode_retire(feed)
@@ -3212,95 +2978,11 @@ class TPUEngine:
             self._decode_retire(feed)
         return bool(self._running)
 
-    def _decode_width(self, allow_compact: bool = True) -> int:
-        """The decode dispatch width: the power-of-two bucket covering the
-        ACTIVE slot ceiling (slots compacted first) under batch_buckets,
-        else the configured max. ``allow_compact=False`` skips slot
-        compaction — moving rows under an in-flight lookahead would break
-        its slot->column mapping."""
-        config = self.config
-        if self._running or self._chunking:
-            self._last_active_ts = time.monotonic()
-        if config.batch_buckets:
-            # Hysteresis on the width: switching executables makes XLA
-            # re-home the donated KV pool (~a full pool copy), so width
-            # changes must be RARE. Grow immediately (correctness: arrays
-            # must cover the active ceiling); shrink only after the smaller
-            # width has sufficed for a sustained streak (load genuinely
-            # dropped, not an inter-wave dip).
-            # the width target is the ACTIVE ceiling plus the queued load
-            # that could actually admit (anticipatory growth, round-4):
-            # one transiently queued request at 8-active/64-slot light
-            # load targets 16, not 64 — jumping to max on any queued item
-            # cost config-3 a 4.5x regression in the round-5 gateway
-            # bench. At genuine full load the target IS max_batch, so
-            # this matches the fixed-width engine there.
-            incoming = self._work.qsize() + len(self._pending)
-            free_slots = (config.max_batch - len(self._running)
-                          - len(self._chunking))
-            page_capacity = (self.allocator.free_pages
-                             // self.allocator.avg_slot_pages())
-            admissible = max(0, min(incoming, free_slots, page_capacity))
-            if admissible == 0 and allow_compact:
-                # compaction pays exactly when holes will NOT refill at
-                # the next admission: an empty queue, OR a page-bound
-                # backlog (queued work that cannot admit) — without it a
-                # lone high-index slot would hold the ceiling at max for
-                # the backlog's whole duration
-                self._compact_slots()
-            ceiling = min(max(max(self._running) + 1,
-                              len(self._running) + len(self._chunking)
-                              + admissible),
-                          config.max_batch)
-            desired = self._batch_bucket_for(ceiling)
-            if self._width_floor:
-                # controller floor: live occupancy says the next burst
-                # would just re-grow — don't shrink below it (each width
-                # change re-homes the donated KV pool)
-                desired = max(desired, self._batch_bucket_for(
-                    min(self._width_floor, config.max_batch)))
-            if desired >= self._batch_width:
-                # grow immediately (arrays must cover the ceiling)
-                self._batch_width = desired
-                self._shrink_streak = 0
-                self._shrink_peak = 0
-            else:
-                self._shrink_streak += 1
-                # shrink to the PEAK desired width seen over the streak,
-                # not the instantaneous one — a momentary dip must not
-                # trigger an over-shrink followed by an immediate re-grow
-                # (each width change re-homes the donated KV pool)
-                self._shrink_peak = max(self._shrink_peak, desired)
-                if self._shrink_streak >= config.batch_shrink_steps:
-                    # never EAT a compile to get smaller (round-4
-                    # config-4 tail: the drain-phase shrink compiled a
-                    # fresh executable inside the serving path) — shrink
-                    # only to warmup-compiled widths or widths this
-                    # process already compiled (an unwarmed engine that
-                    # grew for a burst may return to its earlier width:
-                    # the executables exist)
-                    target = self._shrink_peak
-                    # "already compiled" means the (width, ctx) PAIR the
-                    # next dispatch would use — a width whose executables
-                    # exist only for shorter contexts would still compile
-                    # mid-traffic
-                    ctx_now = self._ctx_bucket_for(max(
-                        (len(r.prompt_ids) + len(r.generated)
-                         for r in self._running.values()), default=1)
-                        + self._k)
-                    if (target in self._warmed_widths
-                            or (self._k, target, ctx_now)
-                            in self._decode_fns):
-                        self._batch_width = target
-                    self._shrink_streak = 0
-                    self._shrink_peak = 0
-            return self._batch_width
-        return config.max_batch
-
-    def _decode_dispatch(self, B: int, feed: dict[str, Any] | None
+    def _decode_dispatch(self, feed: dict[str, Any] | None
                          ) -> dict[str, Any]:  # lint: hot-path
-        """Build and submit one decode SUPER-STEP dispatch of width ``B``;
-        returns the in-flight record the matching _decode_retire consumes.
+        """Build and submit one decode SUPER-STEP dispatch, ``max_batch``
+        rows wide (a row is a slot); returns the in-flight record the
+        matching _decode_retire consumes.
 
         ``feed`` is the previous, still-in-flight step: its [k, B] sampled
         block (device-resident) supplies this step's input token, and host
@@ -3314,6 +2996,7 @@ class TPUEngine:
         Under a block family (``self._block``) the dispatch is a BLOCK step:
         never device-fed, its rows are blocks (:meth:`_block_rows`) and its
         program the family's block step, which returns the same record."""
+        B = self.config.max_batch
         k = self._k
         tl = self.timeline
         seq = tl.next_seq()
@@ -3339,11 +3022,11 @@ class TPUEngine:
             self._sync_tables()
         with tl.span("decode.dispatch", seq, kind) as dispatch:
             if self._block:
-                step_fn = self._block_fn(ctx_pages, B)
+                step_fn = self._block_fn(ctx_pages)
             elif feed is None:
-                step_fn = self._decode_fn(ctx_pages, B)
+                step_fn = self._decode_fn(ctx_pages)
             else:
-                step_fn = self._decode_fb_fn(ctx_pages, B)
+                step_fn = self._decode_fb_fn(ctx_pages)
             with tl.span("decode.dispatch.upload", seq, kind) \
                     as parts["upload"]:
                 packed = self._upload(call)
@@ -3707,7 +3390,7 @@ class TPUEngine:
         off). ``k`` selects the rung-suffixed cost entry when adaptive K
         moved off the static rung (FLOPs/bytes scale with K)."""
         entry = None
-        if k is not None and k != self.config.fused_steps:
+        if k is not None and k != self.config.superstep:
             entry = self.cost_registry.lookup(f"{kind}@k{k}", width,
                                               ctx_pages)
             if entry is None and kind == "decode_fb":
@@ -3971,9 +3654,6 @@ class TPUEngine:
                         tokens / (wall_ms / 1e3), rid)
         bus.publish("llm.saturation",  # lint: allow[signal-name-conformance] dashboard-only export via the /signals snapshot
                     depth / max(1, self.config.max_queue), rid)
-        bus.publish("llm.occupancy",
-                    (len(self._running) + len(self._chunking))
-                    / max(1, self.config.max_batch), rid)
         now = self.timeline.last_retired or 0.0  # this step's retire stamp
         if now - self._signals_slow_ts >= 0.25:
             self._signals_slow_ts = now
@@ -4227,7 +3907,7 @@ class TPUEngine:
         buffer (merged per request) and hop to the asyncio loop in ONE
         call_soon_threadsafe per flush — one loop wakeup per engine step,
         not one per token (the old per-token wakeups were measurable
-        scheduler pressure at decode_block/spec widths > 1)."""
+        scheduler pressure at superstep/spec widths > 1)."""
         buf = self._emit_buf
         if buf and buf[-1][0] is request and not buf[-1][2]:
             buf[-1][1].extend(tokens)

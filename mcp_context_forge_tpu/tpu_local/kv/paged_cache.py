@@ -35,7 +35,7 @@ only, and in its linear-attention layers a ``"sequence"`` pool: the recurrent
 ``conv_tail`` ``[L_lin, rows, taps, channels]``. A decode slot then owns a
 state ROW as well as pages: the allocator deals row ids with the slot
 (``state_rows = slots + 1``; row 0 is the trash row as page 0 is the trash
-page), the row follows its request through compaction by id
+page), a request reaches its row by id
 (``HybridKVState.state_rows``, uploaded with the block table), and nothing
 copies a state. ``kv_page_bytes`` counts the per-token pools by their own
 layer counts; ``kv_state_bytes`` the per-sequence ones.
@@ -1098,19 +1098,6 @@ class PageAllocator:
             return 0
         capacity = self.grow_slot(slot, n_ctx + k - 1)
         return max(0, min(k, capacity - (n_ctx - 1)))
-
-    def move_slot(self, old: int, new: int) -> None:
-        """Reassign a slot's pages (and its state row) to another (free)
-        slot id — pages and rows are slot-agnostic, so compaction moves only
-        these mappings (the device tables refresh from tables() /
-        state_row_table())."""
-        assert new not in self._slots, f"slot {new} occupied"
-        if old in self._slots:
-            self._slots[new] = self._slots.pop(old)
-            if old in self._row:        # the state row follows by id
-                self._row[new] = self._row.pop(old)
-            self._dirty.add(old)
-            self._dirty.add(new)
 
     def free_slot(self, slot: int) -> None:
         pages = self._slots.pop(slot, [])

@@ -57,8 +57,9 @@ class LlamaConfig:
     # ``moe_impl`` names the family's widest choice: grouped_pallas (the
     # rule above), grouped (the same plan through XLA's gathered-weights
     # einsum, which materializes [NB, D, F]: small models and tests) or
-    # dense (the scan always). The engine's ``moe_impl`` override exists
-    # for an A/B until ROADMAP Queue 3 ``unmeasured-options`` removes it.
+    # dense (the scan always: what the tests hold the rule's other choices
+    # to). The engine's ``moe_impl`` override exists for the CPU rehearsals
+    # under tests/benchmark/ alone (ROADMAP Queue 3 ``unmeasured-options``).
     # moe_block is the kernel's WIDEST row-block and the width (in pairs
     # an expert) from which a step takes it whatever its rows.
     # parallel/moe.py's capacity dispatch stays the EP-training path.

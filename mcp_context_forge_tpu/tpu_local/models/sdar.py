@@ -168,9 +168,8 @@ def refusals(config: SdarConfig, engine_config, mesh,
     engine refuses to build on any of them; nothing falls back."""
     Bl = config.block_length
     why = []
-    if engine_config.fused_steps > 1 or any(
-            k > 1 for k in engine_config.k_rungs()):
-        why.append("superstep / decode_block / k_ladder > 1: a super-step "
+    if any(k > 1 for k in engine_config.k_rungs()):
+        why.append("superstep / k_ladder > 1: a super-step "
                    "scans one-token decode steps, and this family has none "
                    "(a block step already commits block_length tokens a "
                    "dispatch)")

@@ -46,6 +46,30 @@ def pytest_pyfunc_call(pyfuncitem):
     return None
 
 
+@pytest.fixture(scope="module")
+def model_variant():
+    """``model_variant("mixtral-test", moe_block=8)``: a changed copy of a
+    model config, registered in ``MODEL_CONFIGS`` under a name that says the
+    change (as ``benchmark/harness`` registers a cell's) and taken out again
+    when the test module ends. Returns the name, for
+    ``EngineConfig(model=...)``."""
+    import dataclasses
+
+    from mcp_context_forge_tpu.tpu_local.models import MODEL_CONFIGS
+
+    patch = pytest.MonkeyPatch()
+
+    def register(model: str, **changes) -> str:
+        name = model + "+" + ",".join(
+            f"{field}={value}" for field, value in sorted(changes.items()))
+        patch.setitem(MODEL_CONFIGS, name, dataclasses.replace(
+            MODEL_CONFIGS[model], name=name, **changes))
+        return name
+
+    yield register
+    patch.undo()
+
+
 @pytest.fixture()
 def settings():
     from mcp_context_forge_tpu.config import load_settings

@@ -575,7 +575,8 @@ def test_engine_serves_the_family_end_to_end():
     assert engine._window_attrs(20)["llm.window_tokens"] == 20
 
 
-def test_decode_dispatches_count_as_grouped_and_give_the_scans_tokens():
+def test_decode_dispatches_count_as_grouped_and_give_the_scans_tokens(
+        model_variant):
     """A few greedy requests on one device: every decode dispatch counts as a
     grouped step and none as a scan (``engine._count_expert_path`` by the
     family's rule, the rule the step programs trace by); the tokens are those
@@ -593,7 +594,8 @@ def test_decode_dispatches_count_as_grouped_and_give_the_scans_tokens():
             await engine.stop()
 
     stats, tokens = asyncio.run(run())
-    scan_stats, scan_tokens = asyncio.run(run(moe_impl="dense"))
+    scan_stats, scan_tokens = asyncio.run(run(
+        model=model_variant("afmoe-test", moe_impl="dense")))
     assert tokens == scan_tokens and all(len(t) == 8 for t in tokens)
     assert stats.decode_steps >= 7
     # dense prefills and chunk rounds are prefill batches: grouped before too
@@ -654,4 +656,4 @@ def test_superstep_and_overlap_twin_serve_the_family():
 
     plain_tokens = asyncio.run(run(decode_overlap=False))
     assert asyncio.run(run(decode_overlap=True)) == plain_tokens
-    assert asyncio.run(run(decode_block=4, decode_overlap=False)) == plain_tokens
+    assert asyncio.run(run(superstep=4, decode_overlap=False)) == plain_tokens

@@ -9,15 +9,18 @@ boundaries are bit-identical to a full-width engine.
 
 import asyncio
 
+import jax
+import pytest
+
 from mcp_context_forge_tpu.tpu_local.engine import EngineConfig, TPUEngine
 
 
-def _engine(**overrides) -> TPUEngine:
+def _engine(devices: int | None = None, **overrides) -> TPUEngine:
     base = dict(model="llama3-test", max_batch=2, max_seq_len=256,
                 page_size=16, num_pages=64, prefill_buckets=(32,),
                 dtype="float32", attn_impl="reference", prefix_cache=False)
     base.update(overrides)
-    return TPUEngine(EngineConfig(**base))
+    return TPUEngine(EngineConfig(**base), devices=jax.devices()[:devices])
 
 
 def _greedy(engine: TPUEngine, prompt: list[int], max_tokens: int) -> list[int]:
@@ -66,24 +69,43 @@ def test_generation_across_bucket_boundary_matches_full_width():
     assert len(out_bucketed) == 120  # crossed 64 and 128 token boundaries
 
 
-def test_decode_block_respects_bucket_growth():
-    """decode_block > 1 extends positions INSIDE one dispatch: the bucket
+def test_superstep_respects_bucket_growth():
+    """superstep > 1 extends positions INSIDE one dispatch: the bucket
     chosen for the block must already cover seq_len + k, or late
     sub-steps would write/read past the sliced table."""
-    engine = _engine(decode_block=4)
+    engine = _engine(superstep=4)
     prompt = engine.tokenizer.encode("y" * 29)
     out = _greedy(engine, prompt, max_tokens=40)
     assert len(out) == 40
 
-    reference = _engine(decode_block=1)
+    reference = _engine(superstep=1)
     assert out == _greedy(reference, prompt, max_tokens=40)
 
 
-def test_slot_compaction_preserves_generations():
-    """Batch-width bucketing depends on compaction: finish the low-slot
-    request mid-flight, admit another, and verify the surviving high-slot
-    request's stream is unaffected (its pages only changed table rows)."""
-    engine = _engine(max_batch=4, batch_buckets=True)
+@pytest.mark.parametrize("family", [
+    dict(superstep=2, k_ladder=(1, 2)),
+    dict(model="sdar-test", decode_overlap=False),
+], ids=["token", "block"])
+def test_decode_programs_are_keyed_by_k_and_context_only(family):
+    """A decode dispatch is ``max_batch`` rows wide, always: a warm-up
+    leaves one program a (superstep rung, context bucket) pair in the token
+    caches, or one a context bucket in the block cache, each at one shape,
+    and traffic whose occupancy shrinks and regrows (short requests that
+    finish and admit beside a long one) compiles nothing, adds no entry and
+    leaves the long stream what it is alone."""
+    engine = _engine(max_batch=4, warmup=True, devices=1, **family)
+    buckets = set(engine._ctx_buckets())
+    grid = {(k, pages) for k in engine.config.k_rungs() for pages in buckets}
+
+    def programs():
+        return [set(engine._decode_fns), set(engine._decode_fb_fns),
+                set(engine._block_fns)]
+
+    want = [set(), set(), buckets] if engine._block else [grid, grid, set()]
+    assert programs() == want
+    every = [*engine._decode_fns.values(), *engine._decode_fb_fns.values(),
+             *engine._block_fns.values()]
+    assert every and all(fn._cache_size() == 1 for fn in every)
 
     async def run():
         await engine.start()
@@ -92,33 +114,22 @@ def test_slot_compaction_preserves_generations():
             long = engine.tokenizer.encode("b" * 20)
 
             async def consume(prompt, n):
-                out = []
-                async for tok in engine.generate(prompt, max_tokens=n):
-                    out.append(tok)
-                return out
+                return [t async for t in engine.generate(prompt, max_tokens=n)]
 
-            # expected output of the long request, measured solo
-            expected = await consume(long, 60)
-            # now race it against short requests that finish early, forcing
-            # holes + compaction while the long one is mid-stream
-            results = await asyncio.gather(
+            alone = await consume(long, 60)
+            beside = await asyncio.gather(
                 consume(short, 3), consume(short, 3), consume(long, 60),
                 consume(short, 3))
-            assert results[2] == expected
-            return True
+            late = await asyncio.gather(consume(short, 5), consume(short, 9))
+            return alone, beside, late
         finally:
             await engine.stop()
 
-    assert asyncio.run(run())
-
-
-def test_batch_bucket_selection():
-    engine = _engine(max_batch=4, batch_buckets=True)
-    assert engine._batch_buckets() == [4]
-    engine16 = _engine(max_batch=16, batch_buckets=True)
-    assert engine16._batch_buckets() == [8, 16]
-    assert engine16._batch_bucket_for(1) == 8
-    assert engine16._batch_bucket_for(9) == 16
+    alone, beside, late = asyncio.run(run())
+    assert len(alone) == 60 and beside[2] == alone and all(late)
+    assert engine.compile_tracker.serving_compiles() == 0
+    assert programs() == want
+    assert all(fn._cache_size() == 1 for fn in every)
 
 
 def test_chunk_rounds_batch_concurrent_long_prompts():
@@ -238,146 +249,3 @@ def test_oversized_prompt_behind_blocked_chunker_rejects_cleanly():
 # (the spec-decode x chunked-prefill losslessness test lives in
 # test_real_checkpoint.py — random weights never ACCEPT a draft, so only
 # a trained, repetitive model exercises the accepted-draft path)
-
-
-def test_idle_boundary_resets_stale_burst_width():
-    """A width inherited from a drained burst resets at the next idle
-    admission (the config-3 post-burst bad mode: 8 summaries decoding at
-    width 64 until the shrink hysteresis finally fires). The reset only
-    targets WARMED widths and only applies when the engine was idle."""
-    engine = _engine(max_batch=16, batch_buckets=True, num_pages=256)
-    ids = engine.tokenizer.encode("hello")
-    from mcp_context_forge_tpu.tpu_local.engine import GenRequest
-
-    # simulate post-burst state: width pinned at max, engine drained
-    # long enough to cross the idle-reset threshold
-    engine._warmed_widths = set(engine._batch_buckets())
-    engine._batch_width = 16
-    engine._last_active_ts = 0.0
-    engine._pending.append(GenRequest(request_id="i1", prompt_ids=ids,
-                                      max_tokens=4))
-    engine._admit_batch()
-    assert engine._batch_width == 8  # smallest bucket covering the load
-
-    # NOT idle: a second admission while one runs must not reset
-    engine._batch_width = 16
-    engine._last_active_ts = 0.0
-    engine._pending.append(GenRequest(request_id="i2", prompt_ids=ids,
-                                      max_tokens=4))
-    engine._admit_batch()
-    assert engine._batch_width == 16
-
-    # a millisecond inter-wave dip (recent activity) keeps the warmed
-    # start-at-max posture: no shrink+regrow re-home pair per wave
-    engine3 = _engine(max_batch=16, batch_buckets=True, num_pages=256)
-    engine3._warmed_widths = set(engine3._batch_buckets())
-    engine3._batch_width = 16
-    import time as _time
-    engine3._last_active_ts = _time.monotonic()  # active milliseconds ago
-    engine3._pending.append(GenRequest(request_id="i4", prompt_ids=ids,
-                                       max_tokens=4))
-    engine3._admit_batch()
-    assert engine3._batch_width == 16
-
-    # unwarmed target: the reset must never buy a compile
-    engine2 = _engine(max_batch=16, batch_buckets=True, num_pages=256)
-    engine2._warmed_widths = set()
-    engine2._batch_width = 16
-    engine2._last_active_ts = 0.0
-    engine2._pending.append(GenRequest(request_id="i3", prompt_ids=ids,
-                                       max_tokens=4))
-    engine2._admit_batch()
-    assert engine2._batch_width == 16
-
-
-def test_width_grows_to_cover_queued_admissible_load():
-    """Anticipatory growth: the width targets active + ADMISSIBLE queued
-    load — a big backlog grows to max in one hop, while ONE transiently
-    queued request at light load must NOT jump the width to max (that
-    re-pin cost config-3 a 4.5x regression in the round-5 bench)."""
-    engine = _engine(max_batch=16, batch_buckets=True, num_pages=256)
-    ids = engine.tokenizer.encode("hello")
-    from mcp_context_forge_tpu.tpu_local.engine import GenRequest
-
-    # one active + ONE queued: stays at the small bucket
-    engine._pending.append(GenRequest(request_id="a", prompt_ids=ids,
-                                      max_tokens=4))
-    engine._admit_batch()
-    engine._pending.append(GenRequest(request_id="t", prompt_ids=ids,
-                                      max_tokens=4))
-    engine._decode_step_all()
-    assert engine._batch_width == 8
-
-    # a real backlog: ceiling = active + admissible reaches max -> one hop
-    for i in range(20):
-        engine._pending.append(GenRequest(request_id=f"b{i}",
-                                          prompt_ids=ids, max_tokens=4))
-    engine._admit_batch()
-    engine._decode_step_all()
-    assert engine._batch_width == 16
-
-
-
-def test_page_bound_backlog_does_not_pin():
-    """Queued work that CANNOT admit (page pool exhausted) must not hold
-    the width at max: the backlog would otherwise decode full-width over
-    a handful of slots for its whole duration."""
-    engine = _engine(max_batch=16, batch_buckets=True, num_pages=8,
-                     max_seq_len=64)
-    ids = engine.tokenizer.encode("hello world and more text")
-    from mcp_context_forge_tpu.tpu_local.engine import GenRequest
-
-    # fill pages with one long-budget request, then queue more
-    engine._pending.append(GenRequest(request_id="big", prompt_ids=ids,
-                                      max_tokens=48))
-    engine._admit_batch()
-    assert engine._running
-    # exhaust the pool so queued work is page-bound
-    while engine.allocator.free_pages >= engine.allocator.avg_slot_pages():
-        if not engine.allocator.allocate_slot(
-                len(engine._running) + 1, engine.config.page_size):
-            break
-    engine._pending.append(GenRequest(request_id="q", prompt_ids=ids,
-                                      max_tokens=8))
-    engine._batch_width = min(8, engine.config.max_batch)
-    engine._decode_step_all()
-    assert engine._batch_width < engine.config.max_batch
-
-
-def test_shrink_requires_compiled_width_and_sustained_streak():
-    """Shrinking never compiles on the serving path: targets must be
-    warmup-compiled OR already compiled in-process (an unwarmed engine
-    that grew for a burst returns to its earlier width), and only after
-    batch_shrink_steps consecutive under-width steps."""
-    engine = _engine(max_batch=16, batch_buckets=True)
-    ids = engine.tokenizer.encode("hello")
-    from mcp_context_forge_tpu.tpu_local.engine import GenRequest
-
-    assert engine._batch_width == 8  # unwarmed engines start small
-
-    def light_steps(n, prefix):
-        for i in range(n):
-            if not engine._running:
-                engine._pending.append(GenRequest(
-                    request_id=f"{prefix}{i}", prompt_ids=ids, max_tokens=4))
-                engine._admit_batch()
-            engine._decode_step_all()
-
-    # light phase compiles the (8, ctx) executables
-    light_steps(4, "warm")
-    # burst: ceiling = active + admissible reaches max width
-    for i in range(20):
-        engine._pending.append(GenRequest(request_id=f"b{i}",
-                                          prompt_ids=ids, max_tokens=4))
-    engine._admit_batch()
-    engine._decode_step_all()
-    assert engine._batch_width == 16
-    while engine._running or engine._pending:
-        engine._admit_batch()
-        if engine._running:
-            engine._decode_step_all()
-    # drain done; sustained light load shrinks BACK to the in-process-
-    # compiled width 8 (no warmup ran) after the streak
-    engine._shrink_streak = 0
-    light_steps(engine.config.batch_shrink_steps + 4, "lite")
-    assert engine._batch_width == 8

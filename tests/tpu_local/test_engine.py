@@ -1,10 +1,12 @@
 """Engine: continuous batching scheduler over the paged cache (CPU)."""
 
 import asyncio
+import dataclasses
 
 import pytest
 
-from mcp_context_forge_tpu.tpu_local.engine import EngineConfig, GenRequest, TPUEngine
+from mcp_context_forge_tpu.tpu_local.engine import (_SETTING_OF, EngineConfig,
+                                                    GenRequest, TPUEngine)
 
 
 @pytest.fixture(scope="module")
@@ -222,14 +224,14 @@ def test_encoder_batcher_coalesces():
     asyncio.run(main())
 
 
-def test_decode_block_matches_single_step():
-    """Multi-step decode dispatch (decode_block=4) produces the same greedy
+def test_superstep_matches_single_step():
+    """Multi-step decode dispatch (superstep=4) produces the same greedy
     tokens as step-by-step, with ~1/4 the device dispatches."""
     def build(block):
         config = EngineConfig(model="llama3-test", max_batch=2, max_seq_len=128,
                               page_size=16, num_pages=64, prefill_buckets=(16,),
                               dtype="float32", attn_impl="reference",
-                              decode_block=block)
+                              superstep=block)
         return TPUEngine(config)
 
     async def run(engine, n):
@@ -251,11 +253,11 @@ def test_decode_block_matches_single_step():
     assert blocked.allocator.pages_in_use == 0
 
 
-def test_decode_block_respects_max_tokens_and_capacity():
+def test_superstep_respects_max_tokens_and_capacity():
     config = EngineConfig(model="llama3-test", max_batch=2, max_seq_len=32,
                           page_size=16, num_pages=8, prefill_buckets=(16,),
                           dtype="float32", attn_impl="reference",
-                          decode_block=8)
+                          superstep=8)
     engine = TPUEngine(config)
 
     async def main():
@@ -322,6 +324,39 @@ def test_engine_config_carries_init_timeout():
     assert cfg.init_timeout_s == settings.tpu_local_init_timeout_s > 0
 
 
+# where the gateway's default and a hand-built engine's differ, and why
+_DEFAULTS_APART = {
+    "max_batch": "a gateway serves 64 slots; an engine built by a test or a "
+                 "script with no max_batch stays 8 rows small",
+}
+
+
+@pytest.mark.parametrize("spec", [
+    spec for spec in dataclasses.fields(EngineConfig)
+    if _SETTING_OF.get(spec.name, "") is not None], ids=lambda spec: spec.name)
+def test_from_settings_reads_every_field(spec):
+    """Every ``EngineConfig`` field that a setting reaches: a value set on
+    ``Settings`` arrives in the config (a setting that is missing is an
+    ``AttributeError`` here), and the default is the same in both places."""
+    from mcp_context_forge_tpu.config import Settings, load_settings
+
+    name = spec.name
+    source = _SETTING_OF.get(name, "tpu_local_" + name)
+    setting = "controller_k_ladder" if callable(source) else source
+    if name not in _DEFAULTS_APART:
+        assert Settings.model_fields[setting].default == spec.default
+    kind = type(spec.default)
+    value = {bool: lambda d: not d, int: lambda d: d + 1,
+             float: lambda d: d + 1.5, str: lambda d: d + "-changed",
+             tuple: lambda d: d + (7,)}[kind](spec.default)
+    changed = load_settings(env_file=None).model_copy(
+        update={setting: value, "controller_enabled": True})
+    assert getattr(EngineConfig.from_settings(changed), name) == value
+    if name == "k_ladder":      # read only where the controller is on
+        off = changed.model_copy(update={"controller_enabled": False})
+        assert EngineConfig.from_settings(off).k_ladder == ()
+
+
 def test_engine_serves_qwen2_family():
     """End-to-end serving on the Qwen2-style config (attention biases +
     tied embeddings) — the family knobs work through the whole engine."""
@@ -351,7 +386,7 @@ def test_warmup_precompiles_without_corrupting_state():
         kwargs = dict(model="llama3-test", max_batch=2, max_seq_len=128,
                       page_size=16, num_pages=64, prefill_buckets=(16, 32),
                       prefill_max_batch=2, dtype="float32",
-                      attn_impl="reference", decode_block=2)
+                      attn_impl="reference", superstep=2)
         warm = TPUEngine(EngineConfig(**kwargs, warmup=True))
         assert warm.allocator.pages_in_use == 0
         cold = TPUEngine(EngineConfig(**kwargs))

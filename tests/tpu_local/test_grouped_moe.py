@@ -268,24 +268,20 @@ def test_flops_accounting_near_topk_over_e():
     assert big["grouped"] / big["ideal"] < 1.01   # padding term vanishes
 
 
-def _greedy_tokens(moe_impl: str, devices: int = 1) -> tuple[list[int], object]:
-    """Eight greedy tokens of a mixtral-test engine over ``devices`` of the
-    conftest's virtual devices (its mesh's model axis), and its stats.
-    moe_block is shrunk so the CI-scale prefill clears the T·k >= E·block
-    gate (at the default 128 the tiny prompt would take the scan)."""
+def _greedy_tokens(model: str, devices: int = 1) -> tuple[list[int], object]:
+    """Eight greedy tokens of an engine of ``model`` (a ``model_variant`` of
+    mixtral-test) over ``devices`` of the conftest's virtual devices (its
+    mesh's model axis), and its stats."""
     import asyncio
-    import dataclasses
 
     from mcp_context_forge_tpu.tpu_local.engine import (EngineConfig,
                                                         TPUEngine)
 
-    config = EngineConfig(model="mixtral-test", max_batch=2,
+    config = EngineConfig(model=model, max_batch=2,
                           max_seq_len=128, page_size=16, num_pages=32,
                           prefill_buckets=(32,), dtype="float32",
-                          attn_impl="reference", moe_impl=moe_impl)
+                          attn_impl="reference")
     engine = TPUEngine(config, devices=jax.devices()[:devices])
-    engine.model_config = dataclasses.replace(engine.model_config,
-                                              moe_block=8)
 
     async def run():
         await engine.start()
@@ -298,9 +294,16 @@ def _greedy_tokens(moe_impl: str, devices: int = 1) -> tuple[list[int], object]:
     return asyncio.run(run()), engine.stats
 
 
+def _mixtral(model_variant, **changes) -> str:
+    """mixtral-test with moe_block shrunk so the CI-scale prefill clears the
+    T·k >= E·block gate (at the default 128 the tiny prompt would take the
+    scan)."""
+    return model_variant("mixtral-test", moe_block=8, **changes)
+
+
 @pytest.fixture(scope="module")
-def scan_tokens():
-    tokens, stats = _greedy_tokens("dense")
+def scan_tokens(model_variant):
+    tokens, stats = _greedy_tokens(_mixtral(model_variant, moe_impl="dense"))
     assert len(tokens) == 8
     # the scan always: no step took row-blocks
     assert stats.moe_grouped_steps == 0 and stats.moe_scan_steps >= 8
@@ -308,26 +311,28 @@ def scan_tokens():
 
 
 @pytest.mark.parametrize("moe_impl", ["", "grouped", "grouped_pallas"])
-def test_mixtral_trunk_parity_across_impls(scan_tokens, moe_impl):
+def test_mixtral_trunk_parity_across_impls(scan_tokens, moe_impl,
+                                           model_variant):
     """The serving trunk end-to-end: a mixtral-test engine generates the
     SAME greedy tokens on its DEFAULT path ("": the row-block kernel for
     the prefill, interpreted off-TPU, the scan for decode steps), under the
     XLA grouped path and under the scan alone — the MoE formulation is a
     perf choice, never a numerics one. The counters say which steps took
     which: the one prefill grouped, every decode step the scan."""
-    tokens, stats = _greedy_tokens(moe_impl)
+    tokens, stats = _greedy_tokens(_mixtral(
+        model_variant, **({"moe_impl": moe_impl} if moe_impl else {})))
     assert tokens == scan_tokens
     assert stats.moe_grouped_steps == stats.prefill_batches == 1
     assert stats.moe_scan_steps == stats.decode_steps >= 7
 
 
 def test_default_takes_the_scan_on_a_model_axis_wider_than_one_device(
-        scan_tokens):
+        scan_tokens, model_variant):
     """A mesh whose model axis holds two devices (the conftest's virtual
     ones) builds the family with its default — it used to be refused on a
     TPU mesh — and every step takes the scan: the kernel is not wrapped in
     shard_map. Same tokens as on one device."""
-    tokens, stats = _greedy_tokens("", devices=2)
+    tokens, stats = _greedy_tokens(_mixtral(model_variant), devices=2)
     assert tokens == scan_tokens
     assert stats.moe_grouped_steps == 0
     assert stats.moe_scan_steps == stats.prefill_batches + stats.decode_steps

@@ -31,12 +31,22 @@ FAMILIES = {
 }
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _variants(model_variant):
+    """``_engine`` registers a ``moe_block`` of its own through the conftest's
+    helper: the setting is the model config's."""
+    global _variant
+    _variant = model_variant
+
+
 def _engine(model: str = "llama3-test", devices: int | None = 1,
             **over) -> TPUEngine:
     config = dict(model=model, max_batch=4, max_seq_len=128, page_size=PAGE,
                   num_pages=64, prefill_buckets=(BUCKET,), prefill_max_batch=4,
                   dtype="float32", **FAMILIES.get(model, ({}, 0))[0])
     config.update(over)
+    if "moe_block" in config:
+        config["model"] = _variant(model, moe_block=config.pop("moe_block"))
     return TPUEngine(EngineConfig(**config),
                      devices=jax.devices()[:devices] if devices else None)
 

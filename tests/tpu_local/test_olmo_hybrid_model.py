@@ -236,9 +236,7 @@ def test_allocator_deals_a_state_row_with_the_slot():
     assert alloc.allocate_slot(0, 20) and alloc.allocate_slot(2, 20)
     row0, row2 = alloc.slot_row(0), alloc.slot_row(2)
     assert {row0, row2} <= set(range(1, SLOTS + 1)) and row0 != row2
-    alloc.move_slot(2, 1)                      # compaction: the row follows
-    assert alloc.slot_row(1) == row2 and alloc.slot_row(2) == 0
-    assert alloc.state_row_table().tolist() == [row0, row2, 0, 0]
+    assert alloc.state_row_table().tolist() == [row0, 0, row2, 0]
     alloc.free_slot(0)
     assert alloc.rows_in_use == 1
     assert alloc.allocate_slot(3, 20) and alloc.slot_row(3) == row0
@@ -290,22 +288,19 @@ def test_engine_serves_the_family_and_compaction_keeps_the_tokens():
     assert scanned >= 2 * sum(len(p) for p in prompts)
 
 
-def test_compact_slots_moves_the_row_id_not_the_state():
+def test_a_table_sync_uploads_the_row_ids_not_the_state():
     engine = _engine()
     kv = engine.kv
     assert kv.state.shape[:2] == (6, 5) and kv.k_pages.shape[0] == 2
-    from mcp_context_forge_tpu.tpu_local.engine import GenRequest
     for slot in (0, 3):
         assert engine.allocator.allocate_slot(slot, 40)
-    request = GenRequest(request_id="r", prompt_ids=[1, 2, 3], max_tokens=4)
-    request.slot = 3
-    engine._running[3] = request
-    row = engine.allocator.slot_row(3)
-    engine.allocator.free_slot(0)
-    engine._compact_slots()
+    rows = [engine.allocator.slot_row(slot) for slot in (0, 3)]
     engine._sync_tables()
-    assert request.slot == 0 and engine.allocator.slot_row(0) == row
-    assert np.asarray(engine.kv.state_rows).tolist() == [row, 0, 0, 0]
+    assert np.asarray(engine.kv.state_rows).tolist() == [rows[0], 0, 0, rows[1]]
+    engine.allocator.free_slot(0)                 # its row goes to the next
+    assert engine.allocator.allocate_slot(1, 40)
+    engine._sync_tables()
+    assert np.asarray(engine.kv.state_rows).tolist() == [0, rows[0], 0, rows[1]]
     assert engine.kv.state is kv.state            # nothing copied
 
 

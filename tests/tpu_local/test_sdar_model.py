@@ -359,7 +359,8 @@ WIDE = dataclasses.replace(CFG, name="sdar-test-e32", n_experts=32)
 @pytest.mark.parametrize("model,batch,bucket,block_path", [
     ("sdar-test", 4, BUCKET, "scan"), ("sdar-test-e32", 16, 64, "grouped")])
 def test_block_steps_take_the_path_the_rule_gives(model, batch, bucket,
-                                                  block_path, monkeypatch):
+                                                  block_path, monkeypatch,
+                                                  model_variant):
     """A few block dispatches under the family's rule (``expert_path``, a
     function of the step's shape) against the scan always (``moe_impl=
     "dense"``): the same tokens at temperature 0, and the counters say which
@@ -380,9 +381,9 @@ def test_block_steps_take_the_path_the_rule_gives(model, batch, bucket,
     assert sdar.expert_path(config, one, bucket, jnp.float32) == "grouped"
     prompts = [[1] + prompt_of(n - 1, n) for n in (22, 29, 9)]
 
-    async def run(**overrides):
+    async def run(model):
         engine = _engine(model=model, max_batch=batch,
-                         prefill_buckets=(bucket,), **overrides)
+                         prefill_buckets=(bucket,))
         await engine.start()
         try:
             tokens = await asyncio.gather(*[
@@ -391,8 +392,9 @@ def test_block_steps_take_the_path_the_rule_gives(model, batch, bucket,
         finally:
             await engine.stop()
 
-    tokens, stats = asyncio.run(run())
-    scanned, scan_stats = asyncio.run(run(moe_impl="dense"))
+    tokens, stats = asyncio.run(run(model))
+    scanned, scan_stats = asyncio.run(run(
+        model_variant(model, moe_impl="dense")))
     assert tokens == scanned and all(len(t) == 9 for t in tokens)
     assert scan_stats.moe_grouped_steps == 0 < scan_stats.moe_scan_steps
     assert stats.block_steps >= 3
@@ -449,8 +451,7 @@ def test_warmup_compiles_block_steps_and_no_decode_program():
     engine = _engine(max_seq_len=4 * PAGE, num_pages=16)
     engine.warmup()
     assert not engine._decode_fns and not engine._decode_fb_fns
-    assert sorted(engine._block_fns) == [
-        (4, pages) for pages in engine._ctx_buckets()]
+    assert sorted(engine._block_fns) == engine._ctx_buckets()
     assert engine.attn_traced["decode"] == "gather"
 
     async def run():

@@ -107,7 +107,7 @@ def test_spec_decode_respects_max_tokens_and_capacity():
 
 def test_spec_config_validation():
     with pytest.raises(ValueError):
-        _engine(spec_decode=True, decode_block=2)
+        _engine(spec_decode=True, superstep=2)
     with pytest.raises(ValueError):
         _engine(spec_decode=True, spec_k=1)
 

@@ -309,7 +309,7 @@ def test_eos_mid_pipeline_rows_stay_complete():
     """Mixed-length concurrent requests (EOS/max_tokens staggered across
     the pipeline) exercise the drain-at-EOS barriers; every surfaced
     phase row must still be complete and the streams must terminate."""
-    engine = TPUEngine(_config(decode_block=2))
+    engine = TPUEngine(_config(superstep=2))
     prompts = [engine.tokenizer.encode(t)
                for t in ("one", "two words here", "three is a longer one")]
     outs = _gen_all(engine, prompts, max_tokens=7)

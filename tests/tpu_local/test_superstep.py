@@ -325,13 +325,7 @@ def test_superstep_config_wiring_and_validation():
     settings = load_settings(
         env={"MCPFORGE_TPU_LOCAL_SUPERSTEP": "8"}, env_file=None)
     cfg = EngineConfig.from_settings(settings)
-    assert cfg.superstep == 8
-    assert cfg.fused_steps == 8
-    # legacy alias still resolves when superstep is unset
-    assert _config(decode_block=4).fused_steps == 4
-    assert _config(superstep=8, decode_block=1).fused_steps == 8
-    with pytest.raises(ValueError, match="disagree"):
-        TPUEngine(_config(superstep=2, decode_block=4))
+    assert cfg.superstep == 8 and cfg.k_rungs() == (8,)
     with pytest.raises(ValueError, match="superstep must be"):
         TPUEngine(_config(superstep=0))
     with pytest.raises(ValueError, match="mutually"):

@@ -25,16 +25,13 @@ class FakeEngine:
     (or refuses) like the real drain-barrier path."""
 
     def __init__(self, rid="0", superstep=8, warmed_k=(1, 4, 8),
-                 warmed_widths=(4,), spec_built=False, spec_enabled=False):
+                 spec_built=False, spec_enabled=False):
         self.config = types.SimpleNamespace(replica_id=rid)
         self.state = {
             "superstep": superstep,
             "spec_built": spec_built,
             "spec_enabled": spec_enabled,
-            "width_floor": 0,
-            "batch_width": max(warmed_widths),
             "warmed_k": sorted(warmed_k),
-            "warmed_widths": sorted(warmed_widths),
         }
         self.requests = []
         self.accept = True
@@ -234,21 +231,6 @@ def test_engine_refusal_records_hold_rejected_and_skips_cooldown():
 
 
 # ------------------------------------------------------------- other knobs
-
-def test_width_floor_follows_occupancy():
-    engine = FakeEngine(superstep=8, warmed_k=(8,), warmed_widths=(1, 2, 4))
-    t, bus, ctrl = _rig(engine)
-    _publish(bus, "llm.occupancy", 0.8)
-    (row,) = ctrl.tick()
-    assert row["knob"] == "width_floor" and row["direction"] == "up"
-    assert row["to"] == 4               # smallest warmed bucket >= p95 need
-    assert engine.state["width_floor"] == 4
-    # occupancy collapses (full-window flush): the floor drops back out
-    t[0] = 2.0
-    _publish(bus, "llm.occupancy", 0.05, n=64)
-    (row,) = ctrl.tick()
-    assert row["direction"] == "down" and row["to"] == 0
-
 
 def test_spec_disables_on_low_acceptance_and_reprobes():
     engine = FakeEngine(superstep=8, warmed_k=(8,), spec_built=True,

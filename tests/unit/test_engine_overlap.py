@@ -108,13 +108,13 @@ def test_overlap_matches_serial_sampled_single_stream():
     assert outs[True] == outs[False]
 
 
-def test_overlap_with_decode_block_matches_serial():
-    """decode_block>1 composes with the pipeline: [k,B] feedback blocks
+def test_overlap_with_superstep_matches_serial():
+    """superstep>1 composes with the pipeline: [k,B] feedback blocks
     feed the next dispatch; parity must hold and the max_tokens tail must
     not cost extra dispatches (the all-exhausted fast path)."""
     outs, steps = {}, {}
     for overlap in (False, True):
-        engine = TPUEngine(_config(decode_overlap=overlap, decode_block=4))
+        engine = TPUEngine(_config(decode_overlap=overlap, superstep=4))
         engine._rng = jax.random.PRNGKey(5)
         ids = engine.tokenizer.encode("block and overlap")
         outs[overlap] = _gen_all(engine, [ids], max_tokens=13)
@@ -125,7 +125,7 @@ def test_overlap_with_decode_block_matches_serial():
 
 
 def test_partial_budget_row_drains_before_feedback():
-    """A row whose decode_block budget is cut by the per-slot page cap
+    """A row whose super-step budget is cut by the per-slot page cap
     (0 < budget < k) but which SURVIVES its step must not be resumed via
     device feedback — the feedback fn reads block row k-1, its true last
     token is at budget-1. The pipeline must drain and re-feed from host.
@@ -133,7 +133,7 @@ def test_partial_budget_row_drains_before_feedback():
     is granted partially, then truncates, exactly like the serial path."""
     outs = {}
     for overlap in (False, True):
-        engine = TPUEngine(_config(decode_overlap=overlap, decode_block=4,
+        engine = TPUEngine(_config(decode_overlap=overlap, superstep=4,
                                    max_batch=2, max_seq_len=32, num_pages=8,
                                    prefill_buckets=(16,)))
         engine._rng = jax.random.PRNGKey(3)
@@ -265,12 +265,7 @@ def test_allocator_dirty_tracking():
     assert alloc.dirty
     alloc.tables()
 
-    alloc.move_slot(0, 2)
-    assert alloc.dirty
-    moved = jax.device_get(alloc.tables())
-    assert (moved[0] == 0).all() and (moved[2][:3] > 0).all()
-
-    alloc.free_slot(2)
+    alloc.free_slot(0)
     assert alloc.dirty
     cleared = jax.device_get(alloc.tables())
     assert (cleared == 0).all()
@@ -314,9 +309,9 @@ def test_engine_skips_table_upload_when_clean():
 
 def test_one_loop_wakeup_per_step():
     """_post_tokens buffers and _flush_emits posts once per dispatch-loop
-    iteration: a decode_block=4 generation must produce far fewer
+    iteration: a superstep=4 generation must produce far fewer
     call_soon_threadsafe hops than tokens."""
-    engine = TPUEngine(_config(decode_block=4, decode_overlap=False,
+    engine = TPUEngine(_config(superstep=4, decode_overlap=False,
                                max_batch=2))
     counted = {"n": 0}
 

@@ -474,6 +474,33 @@ def test_config_liveness_engine_config_fields_are_policed_too():
     assert "EngineConfig.unused_dial" in result.findings[0].message
 
 
+def test_config_liveness_a_setting_is_read_where_the_field_it_fills_is():
+    """``EngineConfig.from_settings`` walks the fields: ``tpu_local_<f>`` is
+    live exactly when the engine field ``<f>`` is read."""
+    sources = {
+        "pkg/config.py": """
+            class Settings:
+                tpu_local_max_batch: int = 8
+                tpu_local_unused_dial: int = 0
+                tpu_local_no_such_field: int = 0
+        """,
+        "pkg/engine.py": """
+            from dataclasses import dataclass
+
+            @dataclass
+            class EngineConfig:
+                max_batch: int = 8
+                unused_dial: int = 0
+
+            def boot(cfg):
+                return cfg.max_batch
+        """}
+    result = run(ConfigKeyLivenessRule(), sources)
+    assert sorted(f.message.split(" is read")[0] for f in result.findings) == [
+        "EngineConfig.unused_dial", "Settings.tpu_local_no_such_field",
+        "Settings.tpu_local_unused_dial"]
+
+
 def test_config_liveness_docs_clause_uses_injected_docs_text():
     """Undocumented-but-live fields flag only when a docs tree exists;
     in-memory runs (docs_text None) skip the clause entirely."""
