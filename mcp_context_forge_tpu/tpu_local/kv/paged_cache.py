@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.configs import (AfmoeConfig, DeepseekConfig, LlamaConfig,
-                              OlmoHybridConfig)
+                              OlmoHybridConfig, SolarOpen2Config)
 from ..quantize import KV_SCALE_EPS, kv_dequantize, kv_int8_scale, kv_quantize
 
 
@@ -136,7 +136,10 @@ class PoolSpec(NamedTuple):
     dtype: Any = None
 
 
-AnyConfig = LlamaConfig | DeepseekConfig | OlmoHybridConfig | AfmoeConfig
+AnyConfig = (LlamaConfig | DeepseekConfig | OlmoHybridConfig | AfmoeConfig
+             | SolarOpen2Config)
+# families whose linear layers keep a recurrent state and a convolution tail
+_DELTA_RULE = (OlmoHybridConfig, SolarOpen2Config)
 LANES = 128     # the minor dimension of the chip's tiles
 
 
@@ -163,7 +166,7 @@ def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
         return (latent, PoolSpec("index_key", (config.index_head_dim,),
                                  config.n_cache_layers))
     heads = (config.n_kv_heads, config.head_dim)
-    if isinstance(config, OlmoHybridConfig):
+    if isinstance(config, _DELTA_RULE):
         heads = (config.kv_pool_heads, config.head_dim)
         full = len(config.layers_of("full_attention"))
         linear = config.n_layers - full
@@ -259,7 +262,7 @@ class HybridKVState(NamedTuple):
 def _full_precision_only(config, quant: str) -> bool:
     """True for the families of :class:`HybridKVState` (which then refuse
     ``quant``)."""
-    hybrid = isinstance(config, (OlmoHybridConfig, AfmoeConfig))
+    hybrid = isinstance(config, (*_DELTA_RULE, AfmoeConfig))
     if hybrid and quant:
         raise NotImplementedError(
             f"kv_quant={quant!r}: the hybrid family's pools are full "

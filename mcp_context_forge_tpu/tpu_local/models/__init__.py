@@ -8,7 +8,10 @@ full-attention layers; ``sdar``: the GQA trunk with QK-norm and many small
 experts under a block-causal mask, generating by diffusion over blocks;
 ``afmoe``: window layers that keep a ring of their newest keys beside full
 layers that keep every page, a QK-normed gated attention, and dense or
-sigmoid-routed expert FFNs with a shared expert) with
+sigmoid-routed expert FFNs with a shared expert; ``solar_open2``: delta-rule
+layers whose decay is a vector a head (Kimi Delta Attention) beside gated
+un-rotated GQA layers, every layer routing over a HELD RANGE of many small
+experts beside a shared one) with
 the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
@@ -28,10 +31,15 @@ What a family may declare: a KIND a layer (``layer_kind(config, i)``; the
 engine compiles one weight-init program a kind) that names the layer's FFN
 (``deepseek``: dense | experts), its MIXER (``olmo_hybrid``:
 linear_attention | full_attention) or BOTH (``afmoe``: window | full, dense |
-experts, as ``window.experts``); cache pools that only some layers hold,
+experts, as ``window.experts``; ``solar_open2``: ``gqa.experts`` |
+``kda.experts``); cache pools that only some layers hold,
 and pools of a fixed size a sequence beside the per-token ones
 (``kv/paged_cache.py: kv_pools``); with ``STEP_AUX``, a float32 vector of
-counts its step programs return beside the tokens (``engine._step_counts``);
+counts its step programs return beside the tokens (``engine._step_counts``:
+``[tokens through expert layers, pairs on held experts, a summed share, the
+rows]``, then ``[live state rows, real tokens scanned]`` from a family with a
+state a sequence, ``solar_open2`` filling both halves, then a window family's
+two key counts);
 and ``drafts_on_device(config) -> bool``: WHERE A SPECULATIVE DRAFT COMES FROM.
 A family without the name, or one that answers False, gets the engine's
 prompt-lookup drafts (``engine._draft_tokens``) and the plain verify step. A
@@ -51,12 +59,12 @@ from importlib import import_module
 from types import ModuleType
 
 from .configs import (AfmoeConfig, DeepseekConfig, EncoderConfig, LlamaConfig,
-                      OlmoHybridConfig, SdarConfig, ENCODER_CONFIGS,
-                      MODEL_CONFIGS)
+                      OlmoHybridConfig, SdarConfig, SolarOpen2Config,
+                      ENCODER_CONFIGS, MODEL_CONFIGS)
 
 _FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek",
                    OlmoHybridConfig: "olmo_hybrid", SdarConfig: "sdar",
-                   AfmoeConfig: "afmoe"}
+                   AfmoeConfig: "afmoe", SolarOpen2Config: "solar_open2"}
 
 
 def family_of(model_config) -> ModuleType:
@@ -68,5 +76,6 @@ def family_of(model_config) -> ModuleType:
 
 
 __all__ = ["LlamaConfig", "DeepseekConfig", "OlmoHybridConfig",
-           "SdarConfig", "AfmoeConfig", "EncoderConfig", "MODEL_CONFIGS",
+           "SdarConfig", "AfmoeConfig", "SolarOpen2Config", "EncoderConfig",
+           "MODEL_CONFIGS",
            "ENCODER_CONFIGS", "family_of"]

@@ -254,8 +254,15 @@ def expert_path(config: AfmoeConfig, mesh, tokens: int,
     pairs, experts = tokens * config.moe_top_k, config.n_experts
     if pairs >= experts * config.moe_block:
         return "grouped"
-    passes = min(pairs, experts + pairs // expert_block(config, tokens, dtype))
-    return "grouped" if passes <= SCAN_PASS * experts else "scan"
+    # under a share (``n_held`` < ``n_experts``: models/solar_open2.py) only
+    # the HELD experts are passed over, by the scan and by the plan, and the
+    # pairs that land here are the held experts' share of them; the row-block
+    # still follows an expert's share of ALL the pairs (``expert_block``
+    # reads the published count)
+    held = config.n_held
+    here = -(-pairs * held // experts)
+    passes = min(here, held + here // expert_block(config, tokens, dtype))
+    return "grouped" if passes <= SCAN_PASS * held else "scan"
 
 
 def refusals(config: AfmoeConfig, engine_config, mesh,
@@ -318,6 +325,15 @@ def _qkv(layer: dict[str, Any], config: AfmoeConfig, x: jax.Array,
         q = apply_rope(q, positions, c.rope_theta)
         k = apply_rope(k, positions, c.rope_theta)
     return q, k, v
+
+
+def gated_output(layer: dict[str, Any], a: jax.Array,
+                 out: jax.Array) -> jax.Array:
+    """The output gate (arXiv:2505.06708, elementwise): the attention's heads
+    out [B, S, H, hd] times ``sigmoid(a W_g)`` of the layer's normed input a
+    [B, S, D], then ``W_o``. (``models/solar_open2.py`` calls it too.)"""
+    gate = jax.nn.sigmoid(qmm(a, layer["wg"]))
+    return qmm(out.reshape(*out.shape[:2], -1) * gate, layer["wo"])
 
 
 @partial(jax.jit, static_argnames=("config", "mesh"))
@@ -403,9 +419,8 @@ def _trunk(params: dict[str, Any], config: AfmoeConfig, tokens: jax.Array,
             kv = write(kv, ordinal[mixer], k, v)
         out = attend(mixer, ordinal[mixer], q, k, v, kv)
         ordinal[mixer] += 1
-        gate = jax.nn.sigmoid(qmm(a, layer["wg"]))
-        mixed = qmm(out.reshape(*out.shape[:2], -1) * gate, layer["wo"])
-        x = x + rms_norm(mixed, layer["post_attn_norm"], c.norm_eps)
+        x = x + rms_norm(gated_output(layer, a, out), layer["post_attn_norm"],
+                         c.norm_eps)
         m = rms_norm(x, layer["ffn_norm"], c.norm_eps)
         f = (_expert_ffn(layer, c, m, valid, mesh) if "router" in layer
              else _ffn(layer, m, c.hidden_act))

@@ -325,6 +325,12 @@ class AfmoeConfig:
                      if self.mixer_kind(i) == mixer)
 
     @property
+    def n_held(self) -> int:
+        """Routed experts this engine computes: all of them (the name a
+        family with a share gives: :class:`SolarOpen2Config`)."""
+        return self.n_experts
+
+    @property
     def ring_tokens(self) -> int:
         """Tokens of K and of V a window layer keeps a sequence."""
         return self.sliding_window + self.ring_slack
@@ -334,8 +340,87 @@ class AfmoeConfig:
         return float(self.dim) ** 0.5
 
 
+@dataclass(frozen=True)
+class SolarOpen2Config:
+    """Geometry of the hybrid family whose layers differ in their MIXER and
+    all route (``solar_open2`` ``config.json`` keys in brackets). Layer ``i``
+    is a gated NoPE GQA layer if ``i`` is in ``gqa_layers`` [gqa_layers]
+    (``n_heads`` query and ``n_kv_heads`` kv heads of ``head_dim``, no rotary
+    embedding [use_rope false], an elementwise output gate [use_gqa_gate])
+    and a Kimi-Delta-Attention layer otherwise: the gated delta rule with a
+    decay a head AND a key channel, ``linear_n_heads`` [linear_attn_config
+    .num_heads] heads of a ``linear_key_dim`` x ``linear_value_dim`` [both
+    .head_dim] float32 state a SEQUENCE, a causal convolution of
+    ``conv_kernel`` [.short_conv_kernel_size] taps, the decay and the output
+    gate through low-rank pairs of ``gate_rank`` [kda_use_full_proj false],
+    ``beta = 2 sigmoid(.)`` [kda_allow_neg_eigval]. The field names of the
+    linear part are :class:`OlmoHybridConfig`'s: the cache pools, the state
+    rows and the mixer's plumbing are shared (``kv/paged_cache.py``,
+    ``models/olmo_hybrid.py``). EVERY layer's FFN routes [first_k_dense_replace
+    0] over ``n_experts`` [n_routed_experts] of ``moe_ffn_hidden``
+    [moe_intermediate_size] (sigmoid scores plus a correction bias choose
+    ``moe_top_k``; the chosen scores, normalised [norm_topk_prob] and times
+    ``routed_scaling_factor``, weigh them) beside ``n_shared_experts``.
+    ``experts_held`` is the half-open range of routed experts THIS engine
+    computes, as :class:`DeepseekConfig`'s: routing is over all
+    ``n_experts``, a pair outside the range adds nothing here. Pre-norm
+    residual blocks, an untied head."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    moe_ffn_hidden: int
+    n_experts: int
+    experts_held: tuple[int, int]
+    moe_top_k: int
+    linear_n_heads: int
+    linear_key_dim: int
+    linear_value_dim: int
+    gate_rank: int
+    gqa_layers: tuple[int, ...]
+    conv_kernel: int = 4
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    allow_neg_eigval: bool = True   # beta = 2 * sigmoid(b)
+    norm_eps: float = 1e-5
+    max_seq_len: int = 1_048_576
+    hidden_act: str = "silu"
+    moe_impl: str = "grouped_pallas"
+    moe_block: int = 128
+
+    def mixer_kind(self, layer: int) -> str:
+        return ("full_attention" if layer in self.gqa_layers
+                else "linear_attention")
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.mixer_kind(i) == kind)
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def kv_pool_heads(self) -> int:
+        """kv heads a K/V page holds (:class:`OlmoHybridConfig`'s name): the
+        model's, 8 being whole sublane rows already."""
+        return self.n_kv_heads
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q, k and v."""
+        return self.linear_n_heads * (2 * self.linear_key_dim
+                                      + self.linear_value_dim)
+
+
 MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
-                    | SdarConfig | AfmoeConfig] = {
+                    | SdarConfig | AfmoeConfig | SolarOpen2Config] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -455,6 +540,17 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
         n_kv_heads=2, head_dim=16, ffn_hidden=128, moe_ffn_hidden=32,
         n_experts=8, moe_top_k=2, sliding_window=32, n_dense_layers=1,
         ring_slack=24, max_seq_len=512, moe_impl="grouped", moe_block=8),
+    # the KDA / gated-GQA expert family at CI scale: two periods of (1 GQA
+    # layer, 3 channel-decay delta-rule layers of 4 heads of a 16 x 16 state),
+    # every layer routing top-4 over 16 experts of which 4 are held (a range
+    # that does not start at 0) beside a shared one; the grouped experts
+    # through XLA as deepseek-test
+    "solar-open2-test": SolarOpen2Config(
+        name="solar-open2-test", vocab_size=512, dim=64, n_layers=8,
+        n_heads=4, n_kv_heads=2, head_dim=16, moe_ffn_hidden=32,
+        n_experts=16, experts_held=(4, 8), moe_top_k=4, linear_n_heads=4,
+        linear_key_dim=16, linear_value_dim=16, gate_rank=16,
+        gqa_layers=(0, 4), max_seq_len=512, moe_impl="grouped", moe_block=8),
 }
 
 

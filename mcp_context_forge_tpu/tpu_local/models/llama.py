@@ -325,7 +325,8 @@ def expert_path(config: LlamaConfig, mesh, tokens: int,
 def routed_experts(stacks: dict[str, Any], config, flat: jax.Array,
                    ids: jax.Array, weights: jax.Array, mesh=None,
                    valid: jax.Array | None = None,
-                   rule=expert_path) -> jax.Array:
+                   rule=expert_path,
+                   held: tuple[int, int] | None = None) -> jax.Array:
     """The routed experts' weighted sum for routing CHOICES, however a router
     made them: flat [T, D], ids [T, k] int32 into the stacks ``w1``/``w3``
     [E, D, F] and ``w2`` [E, F, D], weights [T, k] float32 (final: whatever
@@ -337,8 +338,17 @@ def routed_experts(stacks: dict[str, Any], config, flat: jax.Array,
     and run at :func:`expert_block`'s row-block. ``valid`` (T entries): False
     marks padding and idle rows; the grouped path gives their pairs no row and
     zero output, the scan computes them like any token, and nothing reads
-    either."""
-    T, E = flat.shape[0], config.n_experts
+    either. ``held`` (lo, hi): the stacks are experts ``[lo, hi)`` of the
+    ``config.n_experts`` the router chose among (one chip's share of an
+    expert-parallel layer). A pair on an expert held elsewhere gets no row of
+    the plan and a zero gate in the scan, and adds nothing here; the
+    row-block still follows an expert's share of ALL the pairs
+    (:func:`expert_block` reads the published count), and the weighted rows
+    are scatter-added to their tokens, since most pairs have none."""
+    T = flat.shape[0]
+    E = config.n_experts if held is None else held[1] - held[0]
+    if held is not None:
+        ids = ids - held[0]                # outside [0, E): held elsewhere
     if rule(config, mesh, T, flat.dtype) == "grouped":
         # the kernel interprets off-TPU (the caller's mesh says which) so
         # the code path exists everywhere
@@ -351,7 +361,7 @@ def routed_experts(stacks: dict[str, Any], config, flat: jax.Array,
             stacks, flat, plan_sorted_blocks(ids, weights, E, block),
             act=config.hidden_act, impl="pallas" if use_pallas else "xla",
             block=block, interpret=use_pallas and not on_tpu(mesh),
-            gather_back=True)
+            gather_back=held is None)
     from ..parallel.moe import expert_scan
     gates = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32)
                     * weights[:, :, None], axis=1)               # [T, E]

@@ -211,7 +211,7 @@ def paged_impl(mesh, config: OlmoHybridConfig, kv: HybridKVState) -> str:
                                   config.kv_pool_heads, False)
 
 
-def delta_impl(mesh, config: OlmoHybridConfig) -> str:
+def delta_impl(mesh, config) -> str:
     """``pallas`` on a TPU mesh whose head geometry the kernel takes, else the
     ``jax.numpy`` twin (``ops/gated_delta.py``)."""
     aligned = gated_delta.head_group(config.linear_n_heads,
@@ -262,17 +262,26 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
-def _linear_mixer(layer: dict[str, Any], config: OlmoHybridConfig,
-                  ordinal: int, x: jax.Array, stream: jax.Array,
-                  valid: jax.Array, rows: jax.Array, counts: jax.Array,
-                  fresh: jax.Array, kv: HybridKVState, impl: str
-                  ) -> tuple[jax.Array, HybridKVState]:
-    """The gated delta-rule mixer of x [B, S, D] (``stream``: the same hidden
-    states in the residual stream's float32, which the gates read) over the
-    rows' stored state. valid [B, S]: real tokens, a prefix of each row; rows
-    [B]: state row ids (0 for a row with none); counts [B]: real tokens a
-    row; fresh [B]: start from zero. ``ordinal``: the layer's index among the
-    linear layers."""
+def state_rows(valid: jax.Array, positions: jax.Array, kv: HybridKVState,
+               slot_ids: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """What a step's delta-rule layers walk, from valid / positions [B, S]:
+    (rows [B]: the slots' state row ids, the trash row 0 for a row without a
+    real token; counts [B]: real tokens a row; fresh [B]: the row's first
+    position is 0, so it starts from a zero state and tail). Shared by every
+    family that keeps a recurrent state a sequence (``models/solar_open2.py``)."""
+    counts = jnp.sum(valid.astype(jnp.int32), axis=1)
+    rows = jnp.where(counts > 0, kv.state_rows[slot_ids], 0)
+    return rows, counts, positions[:, 0] <= 0
+
+
+def conv_qkv(layer: dict[str, Any], config, ordinal: int, x: jax.Array,
+             rows: jax.Array, counts: jax.Array, fresh: jax.Array,
+             kv: HybridKVState):
+    """A delta-rule mixer's q, k, v of x [B, S, D]: the three projections
+    through the causal depthwise convolution (continued from the rows' stored
+    tails, which are replaced by the last REAL inputs) and SiLU, q and k
+    L2-normed a head. -> (q [B, S, H, dk] scaled by dk^-0.5, k, v [B, S, H,
+    dv], kv). ``ordinal``: the layer's index among the linear layers."""
     c = config
     B, S, _ = x.shape
     H, dk, dv = c.linear_n_heads, c.linear_key_dim, c.linear_value_dim
@@ -294,7 +303,42 @@ def _linear_mixer(layer: dict[str, Any], config: OlmoHybridConfig,
     q, k, v = jnp.split(conv, [H * dk, 2 * H * dk], axis=-1)
     q = _l2norm(q.reshape(B, S, H, dk)) * dk ** -0.5
     k = _l2norm(k.reshape(B, S, H, dk))
-    v = v.reshape(B, S, H, dv)
+    return q, k, v.reshape(B, S, H, dv), kv
+
+
+def delta_rule(q, k, v, g, beta, valid: jax.Array, rows: jax.Array,
+               counts: jax.Array, fresh: jax.Array, kv: HybridKVState,
+               ordinal: int, impl: str):
+    """The recurrence over the rows' stored state (``ops/gated_delta.py``: the
+    kernel or its twin by ``impl``), padding tokens made the identity step.
+    g [B, S, H], or [B, S, H, dk] for a decay a key channel; beta, valid as
+    the step's. -> (o [B, S, H, dv] float32, kv)."""
+    on = valid[..., None, None] if g.ndim == 4 else valid[..., None]
+    g = jnp.where(on, g, 0.0)                        # identity step on padding
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    if impl == "pallas":
+        o, state = gated_delta.gated_delta_pallas(
+            q, k, v, g, beta, kv.state, rows, counts, fresh, layer=ordinal)
+    else:
+        o, state = gated_delta.gated_delta_reference(
+            q, k, v, g, beta, kv.state, rows, fresh, layer=ordinal)
+    return o, kv._replace(state=state)
+
+
+def _linear_mixer(layer: dict[str, Any], config: OlmoHybridConfig,
+                  ordinal: int, x: jax.Array, stream: jax.Array,
+                  valid: jax.Array, rows: jax.Array, counts: jax.Array,
+                  fresh: jax.Array, kv: HybridKVState, impl: str
+                  ) -> tuple[jax.Array, HybridKVState]:
+    """The gated delta-rule mixer of x [B, S, D] (``stream``: the same hidden
+    states in the residual stream's float32, which the gates read) over the
+    rows' stored state. valid [B, S]: real tokens, a prefix of each row;
+    rows, counts, fresh: :func:`state_rows`. ``ordinal``: the layer's index
+    among the linear layers."""
+    c = config
+    B, S, _ = x.shape
+    H, dv = c.linear_n_heads, c.linear_value_dim
+    q, k, v, kv = conv_qkv(layer, c, ordinal, x, rows, counts, fresh, kv)
     # the gates in float32 FROM the float32 stream: the decay is exp(-A
     # softplus(a)) with A up to 16 and a = x W_a over an un-normed stream, so
     # the 2^-9 rounding of a bfloat16 x moves a token's decay by several per
@@ -305,15 +349,8 @@ def _linear_mixer(layer: dict[str, Any], config: OlmoHybridConfig,
     b = jnp.dot(stream, layer["wb"].astype(f32), precision=hi)
     g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(a + layer["dt_bias"])
     beta = jax.nn.sigmoid(b) * (2.0 if c.allow_neg_eigval else 1.0)
-    g = jnp.where(valid[..., None], g, 0.0)          # identity step on padding
-    beta = jnp.where(valid[..., None], beta, 0.0)
-    if impl == "pallas":
-        o, state = gated_delta.gated_delta_pallas(
-            q, k, v, g, beta, kv.state, rows, counts, fresh, layer=ordinal)
-    else:
-        o, state = gated_delta.gated_delta_reference(
-            q, k, v, g, beta, kv.state, rows, fresh, layer=ordinal)
-    kv = kv._replace(state=state)
+    o, kv = delta_rule(q, k, v, g, beta, valid, rows, counts, fresh, kv,
+                       ordinal, impl)
     z = qmm(x, layer["wg"]).reshape(B, S, H, dv).astype(jnp.float32)
     gated = rms_norm(o, layer["o_norm"], c.norm_eps) * jax.nn.silu(z)
     return qmm(gated.reshape(B, S, H * dv).astype(x.dtype), layer["wo"]), kv
@@ -331,7 +368,7 @@ def _qkv(layer: dict[str, Any], config: OlmoHybridConfig, x: jax.Array):
             v.reshape(B, S, c.n_kv_heads, c.head_dim))
 
 
-def _pool_heads(config: OlmoHybridConfig, x: jax.Array, axis: int) -> jax.Array:
+def _pool_heads(config, x: jax.Array, axis: int) -> jax.Array:
     """x with its kv-head axis padded by zeros to the heads a page holds
     (``OlmoHybridConfig.kv_pool_heads``)."""
     extra = config.kv_pool_heads - config.n_kv_heads
@@ -342,7 +379,7 @@ def _pool_heads(config: OlmoHybridConfig, x: jax.Array, axis: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
-def _gather_kv(config: OlmoHybridConfig, kv: HybridKVState, ordinal: int,
+def _gather_kv(config, kv: HybridKVState, ordinal: int,
                slot_ids: jax.Array, ctx_pages: int | None):
     """The rows' gathered context without the pool's padding heads."""
     keys, values = gather_kv(kv, ordinal, slot_ids, ctx_pages)
@@ -369,20 +406,21 @@ def _logits(params: dict[str, Any], x: jax.Array,
     return lm_logits(params, x)
 
 
-def _history_attend(config: OlmoHybridConfig, ordinal: int, q: jax.Array,
-                    kv: HybridKVState, slot_ids: jax.Array,
-                    positions: jax.Array, ctx_pages: int | None,
-                    use_pallas: bool, mesh) -> jax.Array:
+def history_attend(config, ordinal: int, q: jax.Array, kv: HybridKVState,
+                   slot_ids: jax.Array, positions: jax.Array,
+                   ctx_pages: int | None, use_pallas: bool, mesh) -> jax.Array:
     """The trunk's chunk attention (``models/llama.prefill_with_history``'s
-    inner loop) of q [B, S, H, hd] over the rows' pages, this step's tokens
-    already written; positions [B, S] absolute, -1 for padding."""
+    inner loop) of q [B, S, H, hd] over the rows' pages of the ``ordinal``-th
+    full-attention layer, this step's tokens already written; positions
+    [B, S] absolute, -1 for padding. (``models/solar_open2.py`` calls it too.)"""
     c = config
     B, S = positions.shape
     G = c.n_heads // c.n_kv_heads
     # the kernel holds every kv head's rows of a tile in VMEM at once: with
     # as many kv heads as query heads a tile is 128 queries (the trunk's
     # _history_tile would ask for 512 at G = 1)
-    tile = min(S, _PALLAS_QUERY_TILE) if use_pallas else _history_tile(S, G)
+    tile = (min(S, _PALLAS_QUERY_TILE) if use_pallas and G == 1
+            else _history_tile(S, G))
     valid, safe = positions >= 0, jnp.maximum(positions, 0)
     if use_pallas:
         from ..ops.paged_attention import paged_chunk_attention_pallas
@@ -407,6 +445,29 @@ def _history_attend(config: OlmoHybridConfig, ordinal: int, q: jax.Array,
     return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
 
 
+def decode_attend(config, ordinal: int, q: jax.Array, kv: HybridKVState,
+                  slot_ids: jax.Array, seq_lens: jax.Array,
+                  ctx_pages: int | None, paged_impl: str, mesh) -> jax.Array:
+    """One query a row, q [B, 1, H, hd], over the rows' pages of the
+    ``ordinal``-th full-attention layer, this step's token already written:
+    the paged kernel or the gather reference. -> [B, ..] (H * hd values a
+    row). (``models/solar_open2.py`` calls it too.)"""
+    c = config
+    B = q.shape[0]
+    if paged_impl == "pallas":
+        from ..ops.paged_attention import paged_decode_attention_pallas
+        tables = kv.block_tables[slot_ids]
+        if ctx_pages is not None:
+            tables = tables[:, :ctx_pages]
+        qg = q[:, 0].reshape(B, c.n_kv_heads, c.n_heads // c.n_kv_heads,
+                             c.head_dim)
+        return paged_decode_attention_pallas(
+            _pool_heads(c, qg, 1), kv.k_pages, kv.v_pages, tables,
+            seq_lens, layer=ordinal, mesh=mesh)[:, :c.n_kv_heads]
+    keys, values = _gather_kv(c, kv, ordinal, slot_ids, ctx_pages)
+    return _paged_decode_attention(q[:, 0], keys, values, seq_lens, c)
+
+
 def _trunk(params: dict[str, Any], config: OlmoHybridConfig,
            tokens: jax.Array, positions: jax.Array, valid: jax.Array,
            kv: HybridKVState, slot_ids: jax.Array, attend, mesh
@@ -420,9 +481,7 @@ def _trunk(params: dict[str, Any], config: OlmoHybridConfig,
     # the residual stream is float32 (64 unit-RMS terms add up in it); every
     # matmul reads it in the compute dtype, the gates read it as it is
     act, x = h.dtype, h.astype(jnp.float32)
-    counts = jnp.sum(valid.astype(jnp.int32), axis=1)
-    rows = jnp.where(counts > 0, kv.state_rows[slot_ids], 0)
-    fresh = positions[:, 0] <= 0
+    rows, counts, fresh = state_rows(valid, positions, kv, slot_ids)
     impl = delta_impl(mesh, c)
     n_full = n_linear = 0
     for idx, layer in enumerate(params["layers"]):
@@ -480,8 +539,8 @@ def prefill_with_history(params: dict[str, Any], config: OlmoHybridConfig,
     def attend(layer, ordinal, x, kv):
         q, _, _, kv = _project_and_write(layer, config, ordinal, x, kv,
                                          slot_ids, positions)
-        out = _history_attend(config, ordinal, q, kv, slot_ids, positions,
-                              ctx_pages, paged_impl == "pallas", mesh)
+        out = history_attend(config, ordinal, q, kv, slot_ids, positions,
+                             ctx_pages, paged_impl == "pallas", mesh)
         return qmm(out.reshape(*out.shape[:2], -1), layer["wo"]), kv
 
     x, kv, aux = _trunk(params, config, tokens, positions, positions >= 0, kv,
@@ -509,19 +568,8 @@ def decode_step(params: dict[str, Any], config: OlmoHybridConfig,
         kv = write_decode_kv(kv, ordinal, _pool_heads(c, k[:, 0], 1),
                              _pool_heads(c, v[:, 0], 1), slot_ids, positions,
                              valid=write_mask)
-        if paged_impl == "pallas":
-            from ..ops.paged_attention import paged_decode_attention_pallas
-            tables = kv.block_tables[slot_ids]
-            if ctx_pages is not None:
-                tables = tables[:, :ctx_pages]
-            qg = q[:, 0].reshape(B, c.n_kv_heads, c.n_heads // c.n_kv_heads,
-                                 c.head_dim)
-            out = paged_decode_attention_pallas(
-                _pool_heads(c, qg, 1), kv.k_pages, kv.v_pages, tables,
-                seq_lens, layer=ordinal, mesh=mesh)[:, :c.n_kv_heads]
-        else:
-            keys, values = _gather_kv(c, kv, ordinal, slot_ids, ctx_pages)
-            out = _paged_decode_attention(q[:, 0], keys, values, seq_lens, c)
+        out = decode_attend(c, ordinal, q, kv, slot_ids, seq_lens,
+                            ctx_pages, paged_impl, mesh)
         return qmm(out.reshape(B, 1, -1), layer["wo"]), kv
 
     # a decode token never starts a sequence: its position is at least 1

@@ -8,6 +8,13 @@ exp(g)`` and write strength ``beta`` does
 
 so a padding token is the identity step ``alpha = 1, beta = 0``.
 
+**Two forms of the decay**, told apart by the SHAPE of ``g`` and traced apart
+(a static specialisation: neither form's program carries anything of the
+other's). ``g`` of ``[B, S, H]`` is one decay a head (the gated delta rule as
+published). ``g`` of ``[B, S, H, d_k]`` is a decay a head AND a key channel,
+``S <- Diag(alpha) S`` (Kimi Delta Attention, arXiv:2510.26692): row ``i`` of
+a head's state decays by ``alpha_i``, and the rest of the step is the same.
+
 **Layout.** The state pool is ``[layers, rows, d_k, H * d_v]``: a row's heads
 lie side by side on the lane axis (5760 = 45 x 128 lanes for 30 heads of 192),
 where ``[.., H, d_k, d_v]`` would pad every head's 192 lanes to 256 in HBM and
@@ -22,15 +29,31 @@ once; tokens past the row's length cost nothing. A token's per-head vectors
 arrive as one ``[2 d_k + 8, H]`` tile (``k^T``, ``q^T``, ``alpha``, ``beta``):
 a head's key is then a sublane column that broadcasts along that head's lanes,
 so ``S^T k`` is a multiply and a sublane reduction, and the rank-1 update an
-outer product of a column and a row. Heads whose ``d_v`` is not a multiple of
-128 are taken ``G`` at a time (2 for 192) so that every slice of the state is
-lane-aligned. The state row is found through scalar-prefetched row ids and
-updated in place (``input_output_aliases``); a row that starts a sequence
-(``fresh``) starts from zero without reading what its last tenant left.
+outer product of a column and a row. The channel form's tile is ``[3 d_k + 8,
+H]`` (``alpha^T`` a column like ``k^T``), its decay one more spread column in
+place of a broadcast row, and its kernels are named ``kda_chunk`` /
+``kda_step`` in a trace, so that what reads ``gated_delta_*`` there reads the
+scalar form alone. It walks ``_CHANNEL_TOKEN_TILE`` = 32 tokens a grid step:
+a 4.2 MB state of 64 x 128 x 128 in and out, double-buffered, beside 64
+tokens of 392 rows (their 64 lanes padded to 128) asks for 48.5 MB of scoped
+VMEM against ``_VMEM_LIMIT`` = 48, which the chip's compiler refuses inside a
+step program although a compile of the kernel alone for a DESCRIBED v5e lets
+it pass (PERF.md section 6, PR 50); 32 tokens ask for 34 MB. Heads whose
+``d_v`` is not a multiple of 128 are taken ``G`` at a time (2 for 192) so
+that every slice of the state is lane-aligned. The state row is found through
+scalar-prefetched row ids and updated in place (``input_output_aliases``); a
+row that starts a sequence (``fresh``) starts from zero without reading what
+its last tenant left.
 
 The chunked WY form on the MXU is ``gated_delta_chunked`` (``jax.numpy``): the
-path off the TPU, and the twin the kernel is held to. ``gated_delta_recurrence``
-is the token-by-token definition.
+scalar form's path off the TPU, and the twin its kernel is held to.
+``gated_delta_recurrence`` is the token-by-token definition of both forms, and
+the channel form's path off the TPU: a WY form that factors the decay out of
+the chunk (``q * e^gamma``, ``k * e^-gamma``) overflows float32 inside one
+64-token chunk where a channel's ``g`` reaches -10 a token, the safe one forms
+``exp(gamma_i - gamma_j)`` a channel under the mask (a ``[C, C, d_k]``
+intermediate a head), and a twin that nothing times is not worth a second
+derivation to hold: the Pallas body is token-sequential and has no such term.
 """
 
 from __future__ import annotations
@@ -45,6 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64                 # tokens a WY chunk (jnp path)
 _TOKEN_TILE = 64           # tokens a grid step of the kernel holds
+_CHANNEL_TOKEN_TILE = 32   # ... of the channel form (module docstring)
 _VMEM_LIMIT = 48 << 20
 
 
@@ -52,13 +76,15 @@ _VMEM_LIMIT = 48 << 20
 
 def gated_delta_recurrence(q, k, v, g, beta, state):
     """The definition, token by token. q, k: [B, S, H, dk]; v: [B, S, H, dv];
-    g, beta: [B, S, H]; state: [B, H, dk, dv]. Float32 throughout.
-    -> (o [B, S, H, dv], state)."""
+    beta: [B, S, H]; g: [B, S, H], or [B, S, H, dk] for a decay a key channel;
+    state: [B, H, dk, dv]. Float32 throughout. -> (o [B, S, H, dv], state)."""
     f32 = lambda a: a.astype(jnp.float32)
+    channel = g.ndim == 4
 
     def step(S, xs):
         qt, kt, vt, gt, bt = xs                      # [B, H, ..]
-        S = S * jnp.exp(gt)[..., None, None]
+        S = S * (jnp.exp(gt)[..., None] if channel
+                 else jnp.exp(gt)[..., None, None])
         err = vt - jnp.einsum("bhkv,bhk->bhv", S, kt)
         S = S + jnp.einsum("bhk,bhv->bhkv", kt, err * bt[..., None])
         return S, jnp.einsum("bhkv,bhk->bhv", S, qt)
@@ -73,9 +99,12 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     chunk the ``chunk`` rank-1 updates collapse to ``T = (I + A)^-1
     diag(beta)`` with ``A = strict_lower(diag(beta) (K K^T * Gamma))``, and the
     state moves a chunk at a time. Arguments and result as
-    :func:`gated_delta_recurrence`; S is padded to a multiple of ``chunk`` with
-    identity steps."""
+    :func:`gated_delta_recurrence` with one decay a head; S is padded to a
+    multiple of ``chunk`` with identity steps."""
     B, S, H, dk = q.shape
+    if g.ndim != 3:
+        raise ValueError("the chunked form takes one decay a head: a decay a "
+                         "channel runs the recurrence (module docstring)")
     pad = -S % chunk
     f32 = lambda a: a.astype(jnp.float32)
     q, k, v, g, beta = (f32(a) for a in (q, k, v, g, beta))
@@ -141,7 +170,7 @@ def gated_delta_reference(q, k, v, g, beta, pool, rows, fresh, *, layer: int,
     H = q.shape[2]
     state = jnp.where(fresh[:, None, None, None], 0.0,
                       pool_rows(pool, layer, rows, H))
-    if q.shape[1] == 1 or not chunked:
+    if q.shape[1] == 1 or not chunked or g.ndim == 4:
         o, state = gated_delta_recurrence(q, k, v, g, beta, state)
     else:
         o, state = gated_delta_chunked(q, k, v, g, beta, state)
@@ -159,7 +188,7 @@ def head_group(n_heads: int, dv: int) -> int | None:
 
 def _kernel(rows_ref, count_ref, fresh_ref, tile_ref, v_ref, s_in_ref,
             o_ref, s_out_ref, *, dk: int, dv: int, n_heads: int, group: int,
-            tokens: int):
+            tokens: int, channel: bool):
     del rows_ref                                    # rides the index maps
     b, ti = pl.program_id(0), pl.program_id(1)
 
@@ -182,22 +211,36 @@ def _kernel(rows_ref, count_ref, fresh_ref, tile_ref, v_ref, s_in_ref,
             out = jnp.where(lane >= i * dv, col, out)
         return out
 
+    decay_rows = dk if channel else 1
+    whole_rows = width == 128 and tokens % 8 == 0
+    if whole_rows:
+        sublane = jax.lax.broadcasted_iota(jnp.int32, (8, width), 0)
+
     def token(t, carry):
-        tile = tile_ref[0, t]                                   # [2 dk + 8, H]
+        tile = tile_ref[0, t]                        # [(2 or 3) dk + 8, H]
         v_row = v_ref[0, pl.ds(t, 1), :]                        # [1, H * dv]
         for p in range(n_heads // group):
             lanes = slice(p * width, (p + 1) * width)
             head = p * group
             k_col = spread(tile, 0, dk, head)
             q_col = spread(tile, dk, dk, head)
-            alpha = spread(tile, 2 * dk, 1, head)
-            beta = spread(tile, 2 * dk + 1, 1, head)
+            # one row all of a head's lanes share, or a column like k_col
+            alpha = spread(tile, 2 * dk, decay_rows, head)
+            beta = spread(tile, 2 * dk + decay_rows, 1, head)
             S = s_out_ref[:, lanes] * alpha
             err = v_row[:, lanes] - jnp.sum(S * k_col, axis=0, keepdims=True)
             S = S + k_col * (err * beta)
             s_out_ref[:, lanes] = S
-            o_ref[0, pl.ds(t, 1), lanes] = jnp.sum(S * q_col, axis=0,
-                                                   keepdims=True)
+            out = jnp.sum(S * q_col, axis=0, keepdims=True)
+            if whole_rows:
+                # Mosaic stores no single [1, 128] row at a dynamic sublane
+                # ("dynamic store with unaligned indices"; wider rows it
+                # does): rewrite the aligned 8 rows that hold token t
+                base = pl.multiple_of(t // 8 * 8, 8)
+                o_ref[0, pl.ds(base, 8), lanes] = jnp.where(
+                    sublane == t % 8, out, o_ref[0, pl.ds(base, 8), lanes])
+            else:
+                o_ref[0, pl.ds(t, 1), lanes] = out
         return carry
 
     real = jnp.clip(count_ref[b] - ti * tokens, 0, tokens)
@@ -206,28 +249,37 @@ def _kernel(rows_ref, count_ref, fresh_ref, tile_ref, v_ref, s_in_ref,
 
 def pack_token_tiles(q, k, g, beta):
     """q, k: [B, S, H, dk]; g, beta: [B, S, H] -> [B, S, 2 dk + 8, H] float32:
-    rows ``k^T``, ``q^T``, ``exp(g)``, ``beta``, six rows of zeros."""
+    rows ``k^T``, ``q^T``, ``exp(g)``, ``beta``, six rows of zeros. With g
+    [B, S, H, dk], [B, S, 3 dk + 8, H]: ``k^T``, ``q^T``, ``exp(g)^T``,
+    ``beta``, seven rows of zeros."""
     f32 = lambda a: a.astype(jnp.float32)
     B, S, H, _ = q.shape
-    gates = jnp.stack([jnp.exp(f32(g)), f32(beta)], axis=2)      # [B, S, 2, H]
+    if g.ndim == 4:
+        gates = jnp.concatenate([jnp.swapaxes(jnp.exp(f32(g)), 2, 3),
+                                 f32(beta)[:, :, None]], axis=2)
+    else:
+        gates = jnp.stack([jnp.exp(f32(g)), f32(beta)], axis=2)  # [B, S, 2, H]
     return jnp.concatenate(
         [jnp.swapaxes(f32(k), 2, 3), jnp.swapaxes(f32(q), 2, 3), gates,
-         jnp.zeros((B, S, 6, H), jnp.float32)], axis=2)
+         jnp.zeros((B, S, -gates.shape[2] % 8, H), jnp.float32)], axis=2)
 
 
 @functools.partial(jax.jit, static_argnames=("layer", "interpret"))
 def gated_delta_pallas(q, k, v, g, beta, pool, rows, counts, fresh, *,
                        layer: int, interpret: bool = False):
-    """q, k: [B, S, H, dk]; v: [B, S, H, dv]; g, beta: [B, S, H]; pool
-    [L, R, dk, H * dv] float32; rows, counts (a row's real tokens, a prefix of
-    its S), fresh: [B] int32. -> (o [B, S, H, dv] float32, pool). The kernel is
-    ``gated_delta_step`` where S == 1 and ``gated_delta_chunk`` elsewhere."""
+    """q, k: [B, S, H, dk]; v: [B, S, H, dv]; beta: [B, S, H]; g: [B, S, H],
+    or [B, S, H, dk] for a decay a key channel; pool [L, R, dk, H * dv]
+    float32; rows, counts (a row's real tokens, a prefix of its S), fresh: [B]
+    int32. -> (o [B, S, H, dv] float32, pool). The kernel is
+    ``gated_delta_step`` where S == 1 and ``gated_delta_chunk`` elsewhere
+    (``kda_step`` / ``kda_chunk`` with a decay a channel)."""
     B, S, H, dk = q.shape
     dv = v.shape[-1]
+    channel = g.ndim == 4
     group = head_group(H, dv)
     if group is None:
         raise ValueError(f"{H} heads of d_v={dv} have no lane-aligned grouping")
-    tokens = min(S, _TOKEN_TILE)
+    tokens = min(S, _CHANNEL_TOKEN_TILE if channel else _TOKEN_TILE)
     if S % tokens:
         raise ValueError(f"S={S} must be a multiple of {tokens}")
     tiles = pack_token_tiles(q, k, g, beta)
@@ -236,12 +288,12 @@ def gated_delta_pallas(q, k, v, g, beta, pool, rows, counts, fresh, *,
     tok_map = lambda b, t, *_: (b, t, 0)
     o, pool = pl.pallas_call(
         functools.partial(_kernel, dk=dk, dv=dv, n_heads=H, group=group,
-                          tokens=tokens),
+                          tokens=tokens, channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, S // tokens),
             in_specs=[
-                pl.BlockSpec((1, tokens, 2 * dk + 8, H),
+                pl.BlockSpec((1, tokens, tiles.shape[2], H),
                              lambda b, t, *_: (b, t, 0, 0)),
                 pl.BlockSpec((1, tokens, H * dv), tok_map),
                 pl.BlockSpec((None, None, dk, H * dv), row_map),
@@ -259,7 +311,8 @@ def gated_delta_pallas(q, k, v, g, beta, pool, rows, counts, fresh, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-        name="gated_delta_step" if S == 1 else "gated_delta_chunk",
+        name=(("kda" if channel else "gated_delta")
+              + ("_step" if S == 1 else "_chunk")),
         interpret=interpret,
     )(rows.astype(jnp.int32), counts.astype(jnp.int32),
       fresh.astype(jnp.int32), tiles, values, pool)

@@ -177,6 +177,53 @@ def _gated_delta_case(batch: int, seq: int):
     return partial(gated_delta.gated_delta_pallas, layer=7), shapes
 
 
+def _kda_case(batch: int, seq: int):
+    """solar-open2-d8-ep8.mixedctx-open: the channel-decay form, 64 heads of a
+    128 x 128 float32 state (4.19 MB a row in and out of VMEM beside a tile
+    of 32 tokens x 392 rows), 6 KDA layers x 33 rows; decode steps of 32
+    rows, chunk rounds of up to 2 x 1024. (A pass here is not the chip's
+    word: at 64 tokens a tile this compile passes and the chip's, inside a
+    step program, refuses 48.5 MB of scoped VMEM against 48.)"""
+    H, dk, dv = 64, 128, 128
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [((batch, seq, H, dk), f32)] * 2 + [((batch, seq, H, dv), f32)] \
+        + [((batch, seq, H, dk), f32), ((batch, seq, H), f32),
+           ((6, 33, dk, H * dv), f32)] + [((batch,), i32)] * 3
+    return partial(gated_delta.gated_delta_pallas, layer=3), shapes
+
+
+def _solar_moe_case(tokens: int, block: int):
+    """The row-block kernel over the 40 held int8 experts of 4096 x 1280: a
+    step's plan holds a block for every one of its tokens x 8 pairs (any may
+    land here) and a spare one a held expert, at the row-block the published
+    320 give the step (``llama.expert_block``)."""
+    held, dim, width = 40, 4096, 1280
+    n_blocks = -(-tokens * 8 // block) + held
+    up = [((held, dim, width), jnp.int8), ((held, width), jnp.bfloat16)]
+    down = [((held, width, dim), jnp.int8), ((held, dim), jnp.bfloat16)]
+
+    def fn(x, q1, s1, q3, s3, q2, s2, *tail):
+        return grouped_moe._expert_blocks_pallas(
+            x, {"q": q1, "s": s1}, {"q": q3, "s": s3}, {"q": q2, "s": s2},
+            *tail, block=block)
+    return fn, [((n_blocks, block, dim), jnp.bfloat16), *up, *up, *down,
+                ((n_blocks,), jnp.int32), ((1,), jnp.int32)]
+
+
+def _solar_paged_case(chunk: int | None, batch: int):
+    """The paged kernel over the 2 GQA layers' 4352 pages, 8 kv heads x 8
+    query heads each, the full 132-page table of a 16896-token sequence."""
+    pool = ((2, 4352, PAGE, 8, HD), jnp.bfloat16)
+    shapes = [pool, pool, ((batch, 132), jnp.int32)]
+    if chunk is None:
+        return (partial(paged.paged_decode_attention_pallas, layer=1),
+                [((batch, 8, 8, HD), jnp.bfloat16)] + shapes
+                + [((batch,), jnp.int32)])
+    return (partial(paged.paged_chunk_attention_pallas, layer=1),
+            [((batch, chunk, 8, 8, HD), jnp.bfloat16)] + shapes
+            + [((batch, chunk), jnp.int32)])
+
+
 def _hybrid_paged_case(chunk: int | None, batch: int, table: int):
     """The paged kernel over the hybrid's pool: 8 attending layers, 32 kv
     heads a page (30 padded to whole tiles), one query head a kv head."""
@@ -334,6 +381,18 @@ KERNEL_CASES = {
     # window layers' calls of the cell, decode width and a chunk round's tile
     "paged_decode_bf16_window_32x32": lambda: _window_paged_case(None, 32),
     "paged_chunk_bf16_window_tile256x32": lambda: _window_paged_case(256, 2),
+    # solar-open2-d8-ep8.mixedctx-open (PR 50): the channel-decay recurrence's
+    # two kernels, the held experts at a decode step's, a half prefill's and
+    # both chunk rounds' row-blocks, the paged kernel at 8 x 8 heads
+    "kda_step_cell_32": lambda: _kda_case(32, 1),
+    "kda_chunk_cell_2x1024": lambda: _kda_case(2, 1024),
+    "kda_chunk_half_1x512": lambda: _kda_case(1, 512),
+    "grouped_moe_int8_solar_decode_32_b16": lambda: _solar_moe_case(32, 16),
+    "grouped_moe_int8_solar_half_1x512_b16": lambda: _solar_moe_case(512, 16),
+    "grouped_moe_int8_solar_chunk_1x1024_b32": lambda: _solar_moe_case(1024, 32),
+    "grouped_moe_int8_solar_chunk_2x1024_b64": lambda: _solar_moe_case(2048, 64),
+    "paged_decode_bf16_solar_32x132": lambda: _solar_paged_case(None, 32),
+    "paged_chunk_bf16_solar_tile256x132": lambda: _solar_paged_case(256, 2),
 }
 
 
