@@ -21,39 +21,93 @@ where ``[.., H, d_k, d_v]`` would pad every head's 192 lanes to 256 in HBM and
 move a third more bytes on every step. Row 0 is the trash row, as page 0 is the
 trash page: idle decode rows and a batch's padding rows read and write it.
 
-**Kernels.** ``gated_delta_chunk`` (prefill, chunk rounds) and
-``gated_delta_step`` (decode) are ONE Pallas body: a grid step holds one row's
-whole state in VMEM (the output block, resident across the row's token tiles)
-and walks the row's REAL tokens one by one on the VPU in float32, all heads at
-once; tokens past the row's length cost nothing. A token's per-head vectors
-arrive as one ``[2 d_k + 8, H]`` tile (``k^T``, ``q^T``, ``alpha``, ``beta``):
-a head's key is then a sublane column that broadcasts along that head's lanes,
-so ``S^T k`` is a multiply and a sublane reduction, and the rank-1 update an
-outer product of a column and a row. The channel form's tile is ``[3 d_k + 8,
-H]`` (``alpha^T`` a column like ``k^T``), its decay one more spread column in
-place of a broadcast row, and its kernels are named ``kda_chunk`` /
-``kda_step`` in a trace, so that what reads ``gated_delta_*`` there reads the
-scalar form alone. It walks ``_CHANNEL_TOKEN_TILE`` = 32 tokens a grid step:
-a 4.2 MB state of 64 x 128 x 128 in and out, double-buffered, beside 64
-tokens of 392 rows (their 64 lanes padded to 128) asks for 48.5 MB of scoped
-VMEM against ``_VMEM_LIMIT`` = 48, which the chip's compiler refuses inside a
-step program although a compile of the kernel alone for a DESCRIBED v5e lets
-it pass (PERF.md section 6, PR 50); 32 tokens ask for 34 MB. Heads whose
-``d_v`` is not a multiple of 128 are taken ``G`` at a time (2 for 192) so
-that every slice of the state is lane-aligned. The state row is found through
-scalar-prefetched row ids and updated in place (``input_output_aliases``); a
-row that starts a sequence (``fresh``) starts from zero without reading what
-its last tenant left.
+**Kernels: the token walk.** ``gated_delta_chunk`` (prefill, chunk rounds)
+and ``gated_delta_step`` (decode) are ONE Pallas body: a grid step holds one
+row's whole state in VMEM (the output block, resident across the row's token
+tiles) and walks the row's REAL tokens one by one on the VPU in float32, all
+heads at once; tokens past the row's length cost nothing. A token's per-head
+vectors arrive as one ``[2 d_k + 8, H]`` tile (``k^T``, ``q^T``, ``alpha``,
+``beta``): a head's key is then a sublane column that broadcasts along that
+head's lanes, so ``S^T k`` is a multiply and a sublane reduction, and the
+rank-1 update an outer product of a column and a row. The channel form's tile
+is ``[3 d_k + 8, H]`` (``alpha^T`` a column like ``k^T``), its decay one more
+spread column in place of a broadcast row, and its kernels are named
+``kda_chunk`` / ``kda_step`` in a trace, so that what reads ``gated_delta_*``
+there reads the scalar form alone. Heads whose ``d_v`` is not a multiple of
+128 are taken ``G`` at a time (2 for 192) so that every slice of the state is
+lane-aligned. The state row is found through scalar-prefetched row ids and
+updated in place (``input_output_aliases``); a row that starts a sequence
+(``fresh``) starts from zero without reading what its last tenant left.
 
-The chunked WY form on the MXU is ``gated_delta_chunked`` (``jax.numpy``): the
-scalar form's path off the TPU, and the twin its kernel is held to.
-``gated_delta_recurrence`` is the token-by-token definition of both forms, and
-the channel form's path off the TPU: a WY form that factors the decay out of
-the chunk (``q * e^gamma``, ``k * e^-gamma``) overflows float32 inside one
-64-token chunk where a channel's ``g`` reaches -10 a token, the safe one forms
-``exp(gamma_i - gamma_j)`` a channel under the mask (a ``[C, C, d_k]``
-intermediate a head), and a twin that nothing times is not worth a second
-derivation to hold: the Pallas body is token-sequential and has no such term.
+**Which shapes take which body** (:func:`chunk_heads`, a rule of ``S, H,
+d_k, d_v`` beside :func:`head_group`; no setting). The scalar form always
+walks. The channel form walks a decode step (``kda_step``), a bucket that is
+not whole 64-token chunks, and heads whose ``d_k`` or ``d_v`` is not a
+multiple of 128, ``_CHANNEL_TOKEN_TILE`` = 32 tokens a grid step (64 tokens
+of 392 rows beside the whole 4.2 MB state of 64 x 128 x 128, double-buffered,
+ask for 48.5 MB of scoped VMEM against ``_VMEM_LIMIT`` = 48, which the chip's
+compiler refuses inside a step program: PERF.md section 6, PR 50). Every
+other call of the channel form, which is every prefill bucket and chunk round
+of the cell that has it, takes the chunkwise body, under the same name
+``kda_chunk``.
+
+**The chunkwise channel body** (PR 51). For a chunk of ``C`` = 64 tokens of
+one head with entry state ``S_0`` and cumulative log-decay ``gamma_t = sum_{s
+<= t} g_s`` (a ``[d_k]`` vector a token, non-increasing) the recurrence is
+
+    A_ij = beta_i sum_c k_ic k_jc e^(gamma_ic - gamma_jc)   (j < i, else 0)
+    P_ij =        sum_c q_ic k_jc e^(gamma_ic - gamma_jc)   (j <= i, else 0)
+    (I + A) V_new = diag(beta) (V - (K * e^gamma) S_0)
+    O = (Q * e^gamma) S_0 + P V_new
+    S_C = Diag(e^gamma_C) S_0 + (K * e^(gamma_C - gamma))^T V_new
+
+(the WY form's ``W`` and ``U`` are not formed: one kernel does a chunk whole,
+so the system is solved once, for ``V_new``). Every exponent above is ``<= 0``
+except in a FACTORED form of the pairwise terms: ``q * e^gamma`` against ``k *
+e^-gamma`` overflows float32 inside one chunk once a channel's ``g`` reaches
+-1.4 a token (the model's draws reach -30), so they are formed as the
+published chunkwise KDA algorithm forms them (arXiv:2510.26692 and its open
+kernels), in sub-blocks of ``_SUB`` = 16 tokens. A query sub-block ``a``
+against an EARLIER key sub-block ``s`` takes as reference ``r`` the ``gamma``
+of the last token of ``s``: ``e^(gamma_i - gamma_j) = e^(gamma_i - r) e^(r -
+gamma_j)`` with both exponents ``<= 0``, and an underflow is a true zero; the
+keys are rescaled once, the queries (and the keys in their role as rows of
+``A``) once a pair, and all six pairs are ONE product of ``[192, d_k]`` by
+``[d_k, 64]``. Inside a sub-block ``e^(gamma_i - gamma_j)`` is formed a
+channel, a key column ``j`` at a time (at most 16 tokens x ``d_k``
+exponentials and two lane reductions a column), and the column is used at
+once: ``(I + A)`` is unit lower triangular and is solved by column-oriented
+forward substitution (column ``j`` of ``A`` times the finished row ``j``
+leaves the rows below, and column ``j`` of ``P`` times it joins the outputs;
+earlier sub-blocks leave and join by one product of ``[P; A]`` rows), never by
+a product of powers of ``A``, whose entries reach 2 with ``beta`` up to 2 and
+repeated keys. All products take float32 operands at the MXU's full float32
+precision (``Precision.HIGHEST``) and accumulate in float32; the cumulative
+sum is six shifted adds down the sublanes. ``e^gamma_C`` reaches the state's
+rows through the one transpose that ``(K * e^(gamma_C - gamma))^T`` needs.
+
+The grid is (row, block of up to 8 heads, chunk): a block's ``[d_k, 8 d_v]``
+state stays in VMEM across the row's chunks and is read and written once a
+row a call, in place. q, k, g, v are read AS THE MIXER MADE THEM, ``[B, S, H,
+d]`` in blocks of ``[64, 8, d]``: a head's ``[64, d]`` is one row a token, 8
+rows apart (a strided load), and o is written a row a token too, so a prefill
+program builds no token tile and relays out nothing (``[B, S, H * d]`` is NOT
+a view of ``[B, S, H, d]`` on the TPU: that reshape alone moved every operand
+once more and cost as much as the products). beta comes as ``[64, H]``. The
+heads of a block are worked on ``_ABREAST`` = 4 at a time, stage by stage:
+their chains of small products are independent, and in program order one
+head's waits are the next one's work (one head at a time takes 1.8 x as
+long, PERF.md section 6). A chunk wholly past the row's count costs nothing,
+a partly filled one runs whole with its tokens past the count made identity
+steps (``g = 0, beta = 0``). About 5 MB of VMEM whatever the number of heads.
+The layer index is a prefetched scalar, not a static one, and the call is
+jitted on its own: the body is traced once a shape and lowered once a step
+program, which calls it once a KDA layer (the walk is lowered once a layer).
+
+The chunked WY form on the MXU in ``jax.numpy`` is ``gated_delta_chunked``:
+the scalar form's path off the TPU, and the twin its kernel is held to.
+``gated_delta_recurrence`` is the token-by-token definition of both forms, the
+channel form's path off the TPU and the twin both of its bodies are held to.
 """
 
 from __future__ import annotations
@@ -247,6 +301,208 @@ def _kernel(rows_ref, count_ref, fresh_ref, tile_ref, v_ref, s_in_ref,
     jax.lax.fori_loop(0, real, token, 0)
 
 
+# ------------------------------------------- the channel form's chunk, on the MXU
+
+_SUB = 16                  # tokens a sub-block of a chunk (module docstring)
+_ABREAST = 4               # heads of a grid step worked on in step
+
+
+def chunk_heads(seq: int, n_heads: int, dk: int, dv: int) -> int | None:
+    """Heads a grid step of the chunkwise channel body holds, or None where a
+    call of this shape takes the token walk: the body wants whole chunks and
+    every head's keys and values on whole 128-lane tiles."""
+    if seq % CHUNK or dk % 128 or dv % 128:
+        return None
+    return math.gcd(n_heads, 8)
+
+
+def _mm(a, b, contract=((1,), (0,))):
+    """A float32 product at the MXU's full float32 precision."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _chunk_of_heads(heads):
+    """A chunk of a few heads, on values and in step: heads is a list of (q, k
+    [C, dk]; gam [C, dk] the inclusive cumulative log-decay; v [C, dv]; beta
+    [C, 1]; state [dk, dv] on entry) -> a list of (o [C, dv], the state on
+    leaving). Module docstring, "the chunkwise channel body". The heads'
+    chains of products are independent; written stage by stage over all of
+    them, one head's waits are the next one's work."""
+    f32, C, c = jnp.float32, CHUNK, _SUB
+    subs = C // c
+    block = lambda a, n: a[n * c:(n + 1) * c]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) // c   # key sub-block
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    pairs = [(a, s) for a in range(1, subs) for s in range(a)]
+    through, rhs, cross = [], [], []
+    for q, k, gam, v, beta, state in heads:
+        decay = jnp.exp(gam)
+        through.append(_mm(jnp.concatenate([k * decay, q * decay], axis=0), state))
+        rhs.append(beta * (v - through[-1][:C]))     # (I + A) V_new = rhs
+        # every (query sub-block a, key sub-block s < a) in ONE product: both
+        # sides around gamma at the END of s, both exponents <= 0
+        ends = [gam[(s + 1) * c - 1:(s + 1) * c] for s in range(subs - 1)]
+        keys = jnp.concatenate(
+            [block(k, s) * jnp.exp(ends[s] - block(gam, s))
+             for s in range(subs - 1)] + [jnp.zeros((c, q.shape[1]), f32)], axis=0)
+        scaled = []
+        for a, s in pairs:
+            into = jnp.exp(block(gam, a) - ends[s])
+            scaled += [block(q, a) * into, block(k, a) * into]
+        cross.append(_mm(jnp.concatenate(scaled, axis=0), keys, ((1,), (1,))))
+    solved, outs = [[] for _ in heads], [[] for _ in heads]
+    for a in range(subs):
+        xs, os_ = [block(r, a) for r in rhs], [block(t, subs + a) for t in through]
+        if a:
+            for h, (_, _, _, _, beta, _) in enumerate(heads):
+                # rows [P; A] of this sub-block against all earlier ones
+                before = jnp.zeros((2 * c, C), f32)
+                for n, (a_, s) in enumerate(pairs):
+                    if a_ == a:
+                        before = jnp.where(
+                            lane == s, cross[h][2 * c * n:2 * c * (n + 1)], before)
+                moved = _mm(jnp.concatenate(
+                    [before[:c], block(beta, a) * before[c:]], axis=0)[:, :a * c],
+                    jnp.concatenate(solved[h], axis=0))
+                xs[h], os_[h] = xs[h] - moved[c:], os_[h] + moved[:c]
+        # inside the sub-block: exp(gamma_i - gamma_j) a channel, a key
+        # column j at a time; the column leaves the rows below it at once
+        # (forward substitution) and row j, finished, joins the outputs
+        here = [(block(q, a), block(k, a), block(gam, a), block(beta, a))
+                for q, k, gam, _, beta, _ in heads]
+        for j in range(c):
+            top = c // 2 if j >= c // 2 else 0            # rows above: masked
+            for h, (qa, ka, ga, ba) in enumerate(here):
+                w = ka[j:j + 1] * jnp.exp(ga[top:] - ga[j:j + 1])
+                col_a = jnp.sum(ka[top:] * w, axis=1, keepdims=True)
+                col_p = jnp.sum(qa[top:] * w, axis=1, keepdims=True)
+                if top:
+                    zeros = jnp.zeros((top, 1), f32)
+                    col_a = jnp.concatenate([zeros, col_a], axis=0)
+                    col_p = jnp.concatenate([zeros, col_p], axis=0)
+                x = xs[h]
+                x = x - jnp.where(row > j, col_a * ba, 0.0) * x[j:j + 1]
+                os_[h] = os_[h] + jnp.where(row >= j, col_p, 0.0) * x[j:j + 1]
+                xs[h] = x
+        for h in range(len(heads)):
+            solved[h].append(xs[h])
+            outs[h].append(os_[h])
+    done = []
+    for h, (q, k, gam, _, _, state) in enumerate(heads):
+        # K * e^(gamma_C - gamma) with e^gamma_C in the rows below it,
+        # transposed: the chunk's decay lands down the state's rows
+        last = gam[C - 1:C]
+        out = jnp.concatenate([k * jnp.exp(last - gam), jnp.broadcast_to(
+            jnp.exp(last), gam.shape)], axis=0).T
+        done.append((jnp.concatenate(outs[h], axis=0),
+                     out[:, C:C + 1] * state
+                     + _mm(out[:, :C], jnp.concatenate(solved[h], axis=0))))
+    return done
+
+
+def _chunk_kernel(rows_ref, count_ref, fresh_ref, layer_ref, q_ref, k_ref,
+                  g_ref, v_ref, beta_ref, s_in_ref, o_ref, s_out_ref, *,
+                  dv: int, heads: int):
+    """One chunk of ``CHUNK`` tokens of ``heads`` heads of one row,
+    ``_ABREAST`` heads at a time (:func:`_chunk_of_heads`: why)."""
+    del rows_ref, layer_ref                         # ride the index maps
+    b, hb, ci = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    f32, C = jnp.float32, CHUNK
+    side = math.gcd(heads, _ABREAST)
+
+    @pl.when(ci == 0)
+    def _():
+        s_out_ref[...] = jnp.where(fresh_ref[b] > 0, 0.0, s_in_ref[...])
+
+    real = count_ref[b] - ci * C                    # the chunk's real tokens
+
+    @pl.when(real <= 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(real > 0)
+    def _():
+        iota = jax.lax.broadcasted_iota
+        token = iota(jnp.int32, (C, 1), 0)
+        valid = token < real
+        head_lane = iota(jnp.int32, (C, beta_ref.shape[2]), 1)
+        betas = beta_ref[0].astype(f32)                       # [C, H]
+
+        def of_head(ref, i):
+            """Head i's [C, d] of a [1, C, heads, d] block: a row a token,
+            ``heads`` rows apart, as ONE strided load (``ref[0, :, i, :]``
+            loads a sublane at a time and doubles the kernel's time; as a
+            store it costs 2 %, and the interpreter has no store through a
+            reshaped ref)."""
+            return ref.reshape(C * heads, ref.shape[3])[
+                pl.ds(i, C, stride=heads), :]
+
+        def load(i):
+            lv = pl.ds(pl.multiple_of(i * dv, 128), dv)
+            # a token past the row's count is the identity step
+            gam = jnp.where(valid, of_head(g_ref, i).astype(f32), 0.0)
+            shift = 1
+            while shift < C:                                  # inclusive cumsum
+                gam = gam + jnp.where(token >= shift,
+                                      pltpu.roll(gam, shift, 0), 0.0)
+                shift *= 2
+            beta = jnp.where(valid, jnp.sum(
+                jnp.where(head_lane == hb * heads + i, betas, 0.0), axis=1,
+                keepdims=True), 0.0)                          # [C, 1]
+            return (of_head(q_ref, i).astype(f32), of_head(k_ref, i).astype(f32),
+                    gam, of_head(v_ref, i).astype(f32), beta, s_out_ref[:, lv])
+
+        def some_heads(n, carry):
+            each = [n * side + i for i in range(side)]
+            done = _chunk_of_heads([load(i) for i in each])
+            for i, (o, state) in zip(each, done):
+                o_ref[0, :, i, :] = o
+                s_out_ref[:, pl.ds(pl.multiple_of(i * dv, 128), dv)] = state
+            return carry
+
+        jax.lax.fori_loop(0, heads // side, some_heads, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _chunk_call(q, k, v, g, beta, pool, rows, counts, fresh, layer, *,
+                heads: int, interpret: bool):
+    """The chunkwise body over a bucket. ``layer`` is an int32 [1] ARRAY (a
+    prefetched scalar like the row ids, not a static index as the walk's):
+    jitted on its own, the body is traced once a shape and lowered once a
+    step program, which calls it once a KDA layer."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    tok = lambda width: pl.BlockSpec((1, CHUNK, heads, width),
+                                     lambda b, h, c, *_: (b, c, h, 0))
+    row_spec = pl.BlockSpec((None, None, dk, heads * dv),
+                            lambda b, h, c, rows, counts, fresh, layer:
+                            (layer[0], rows[b], 0, h))
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, dv=dv, heads=heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, H // heads, S // CHUNK),
+            in_specs=[tok(dk), tok(dk), tok(dk), tok(dv),
+                      pl.BlockSpec((1, CHUNK, H), lambda b, h, c, *_: (b, c, 0)),
+                      row_spec],
+            out_specs=[tok(dv), row_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, S, H, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 9 of the call (four prefetched scalars, q, k, g, v, beta)
+        # is the pool: updated in place
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="kda_chunk",
+        interpret=interpret,
+    )(rows.astype(jnp.int32), counts.astype(jnp.int32),
+      fresh.astype(jnp.int32), layer, q, k, g, v, beta, pool)
+
+
 def pack_token_tiles(q, k, g, beta):
     """q, k: [B, S, H, dk]; g, beta: [B, S, H] -> [B, S, 2 dk + 8, H] float32:
     rows ``k^T``, ``q^T``, ``exp(g)``, ``beta``, six rows of zeros. With g
@@ -279,6 +535,11 @@ def gated_delta_pallas(q, k, v, g, beta, pool, rows, counts, fresh, *,
     group = head_group(H, dv)
     if group is None:
         raise ValueError(f"{H} heads of d_v={dv} have no lane-aligned grouping")
+    heads = chunk_heads(S, H, dk, dv) if channel else None
+    if heads:
+        return _chunk_call(q, k, v, g, beta, pool, rows, counts, fresh,
+                           jnp.full((1,), layer, jnp.int32), heads=heads,
+                           interpret=interpret)
     tokens = min(S, _CHANNEL_TOKEN_TILE if channel else _TOKEN_TILE)
     if S % tokens:
         raise ValueError(f"S={S} must be a multiple of {tokens}")

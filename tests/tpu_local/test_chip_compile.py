@@ -179,11 +179,16 @@ def _gated_delta_case(batch: int, seq: int):
 
 def _kda_case(batch: int, seq: int):
     """solar-open2-d8-ep8.mixedctx-open: the channel-decay form, 64 heads of a
-    128 x 128 float32 state (4.19 MB a row in and out of VMEM beside a tile
-    of 32 tokens x 392 rows), 6 KDA layers x 33 rows; decode steps of 32
-    rows, chunk rounds of up to 2 x 1024. (A pass here is not the chip's
-    word: at 64 tokens a tile this compile passes and the chip's, inside a
-    step program, refuses 48.5 MB of scoped VMEM against 48.)"""
+    128 x 128 float32 state, 6 KDA layers x 33 rows; decode steps of 32 rows
+    (the token walk: a row's whole 4.19 MB state in and out of VMEM beside one
+    token's 392-row tile), chunk rounds of up to 2 x 1024 and a half bucket of
+    512 (the chunkwise body since PR 51: 8 heads a grid step, so 0.5 MB of
+    state in and out, four 256 KB token blocks of [64, 8, 128] and one of
+    output, all double-buffered: about 5 MB against the limit of 48, whatever
+    the number of heads). (A pass here is not the chip's word:
+    the walk at 64 tokens a tile passed this compile, and the chip's, inside a
+    step program, refused 48.5 MB of scoped VMEM against 48; PR 51 ran the
+    chunkwise body inside the chunk round on the chip.)"""
     H, dk, dv = 64, 128, 128
     f32, i32 = jnp.float32, jnp.int32
     shapes = [((batch, seq, H, dk), f32)] * 2 + [((batch, seq, H, dv), f32)] \

@@ -7,6 +7,7 @@ other families' step programs held to what they were before it."""
 import asyncio
 import dataclasses
 import hashlib
+import math
 import os
 import sys
 from functools import partial
@@ -215,15 +216,20 @@ def test_the_chunked_twin_refuses_a_channel_decay_and_the_reference_scans():
     np.testing.assert_allclose(o, want, atol=1e-6, rtol=1e-6)
 
 
-@pytest.mark.parametrize("tokens, dv", [(1, 128), (128, 128), (4, 128), (128, 64)],
-                         ids=["step", "chunk", "short_bucket", "two_heads_a_tile"])
-def test_channel_kernel_is_the_recurrence(tokens, dv):
+@pytest.mark.parametrize(
+    "tokens, dk, dv",
+    [(1, 16, 128), (128, 16, 128), (4, 16, 128), (128, 16, 64), (96, 128, 128)],
+    ids=["step", "chunk", "short_bucket", "two_heads_a_tile", "walk_at_the_cells_heads"])
+def test_channel_kernel_is_the_recurrence(tokens, dk, dv):
     """The Pallas body (interpreted) with a [d_k] decay against the
     token-by-token definition: rows with unequal lengths (one shorter than a
     token tile), a fresh row, a padding row on the trash row; other rows and
     layers untouched. 128-lane heads take the aligned 8-row store, 64-lane
-    heads in pairs and a bucket under 8 tokens the single-row one."""
-    H, dk = 4, 16
+    heads in pairs and a bucket under 8 tokens the single-row one. All of
+    these shapes stay on the token walk: 16-wide keys, or a bucket that is
+    not whole chunks."""
+    H = 4
+    assert gated_delta.chunk_heads(tokens, H, dk, dv) is None
     q, k, v, g, beta, _ = _delta_inputs(3, tokens, H, dk, dv, seed=1)
     counts = jnp.asarray([tokens, max(1, tokens - 58), 0])
     valid = jnp.arange(tokens)[None] < counts[:, None]
@@ -245,6 +251,106 @@ def test_channel_kernel_is_the_recurrence(tokens, dv):
     np.testing.assert_array_equal(got_pool[0], pool[0])
     np.testing.assert_array_equal(got_pool[1, jnp.asarray([1, 3])],
                                   pool[1, jnp.asarray([1, 3])])
+
+
+def _through_the_pool(q, k, v, g, beta, pool, rows, counts, fresh, layer=1):
+    """(o, pool) of the kernel (interpreted), and what the recurrence gives
+    from the rows' states with tokens past a row's count the identity."""
+    H = q.shape[2]
+    valid = jnp.arange(q.shape[1])[None] < counts[:, None]
+    got = gated_delta.gated_delta_pallas(
+        q, k, v, g, beta, pool, rows, counts, fresh, layer=layer, interpret=True)
+    state = jnp.where((fresh > 0)[:, None, None, None], 0.0,
+                      gated_delta.pool_rows(pool, layer, rows, H))
+    want_o, want_state = gated_delta.gated_delta_recurrence(
+        q, k, v, jnp.where(valid[..., None, None], g, 0.0),
+        jnp.where(valid[..., None], beta, 0.0), state)
+    return got, (want_o, gated_delta.flat_rows(want_state)), valid
+
+
+CHUNKWISE = ("unequal_rows", "strong_decay", "repeated_keys_beta_2",
+             "split_prompt", "two_head_blocks")
+
+
+@pytest.mark.parametrize("case", CHUNKWISE)
+def test_chunkwise_channel_body_is_the_recurrence(case):
+    """The chunkwise body (whole 64-token chunks at the cell's head geometry,
+    d_k = d_v = 128; interpreted) against the token-by-token definition, at
+    the scalar form's chunked twin's tolerance. ``unequal_rows``: a full row,
+    one ending inside its second chunk, a fresh one ending inside the first
+    sub-block, a padding row on the trash row (tokens past a count are the
+    identity whatever g and beta say there); other rows and layers untouched.
+    ``strong_decay``: a quarter of the channels at g = -10 a token for a
+    whole chunk and more, where the factored WY form overflows float32.
+    ``repeated_keys_beta_2``: three keys in turn with beta 1.95 and hardly
+    any decay, the triangular system's worst case. ``split_prompt``: 1024
+    tokens as one call and as two of 512, the state carried by the pool.
+    ``two_head_blocks``: 16 heads, two grid blocks of 8 (three heads: one a
+    block)."""
+    tol = dict(atol=2e-5, rtol=2e-5)
+    B, S, H, d = {"unequal_rows": (4, 128, 2, 128), "split_prompt": (1, 1024, 2, 128),
+                  "two_head_blocks": (1, 64, 16, 128),
+                  "strong_decay": (2, 128, 2, 128)}.get(case, (2, 128, 3, 128))
+    assert gated_delta.chunk_heads(S, H, d, d) == math.gcd(H, 8)
+    q, k, v, g, beta, _ = _delta_inputs(B, S, H, d, d, seed=5)
+    counts = jnp.full((B,), S)
+    rows, fresh = jnp.arange(1, B + 1), jnp.zeros((B,), jnp.int32)
+    if case == "unequal_rows":
+        counts, rows = jnp.asarray([S, 70, 5, 0]), jnp.asarray([2, 4, 5, 0])
+        fresh = jnp.asarray([0, 0, 1, 0])
+    elif case == "strong_decay":
+        strong = (jnp.arange(d) % 4 == 0) & (jnp.arange(S) < 80)[:, None]
+        g = jnp.where(strong[None, :, None, :], -10.0, g)
+    elif case == "repeated_keys_beta_2":
+        k = k[:, jnp.arange(S) % 3]
+        g, beta = g * 0.01, jnp.full_like(beta, 1.95)
+    pool = jax.random.normal(jax.random.PRNGKey(9), (2, 7, d, H * d))
+    (got_o, got_pool), (want_o, want_rows), valid = _through_the_pool(
+        q, k, v, g, beta, pool, rows, counts, fresh)
+    assert bool(jnp.all(jnp.isfinite(got_o))) and bool(jnp.all(jnp.isfinite(got_pool)))
+    np.testing.assert_allclose(jnp.where(valid[..., None, None], got_o, 0),
+                               jnp.where(valid[..., None, None], want_o, 0), **tol)
+    live = np.flatnonzero(np.asarray(rows))
+    np.testing.assert_allclose(got_pool[1, rows[live]], want_rows[live], **tol)
+    others = jnp.asarray(sorted(set(range(1, 7)) - set(np.asarray(rows).tolist())))
+    np.testing.assert_array_equal(got_pool[0], pool[0])
+    np.testing.assert_array_equal(got_pool[1, others], pool[1, others])
+    if case == "split_prompt":
+        half, p = S // 2, pool
+        outs = []
+        for lo in (0, half):
+            part = slice(lo, lo + half)
+            o, p = gated_delta.gated_delta_pallas(
+                q[:, part], k[:, part], v[:, part], g[:, part], beta[:, part], p,
+                rows, jnp.full((B,), half), fresh, layer=1, interpret=True)
+            outs.append(o)
+        np.testing.assert_allclose(jnp.concatenate(outs, axis=1), got_o, **tol)
+        np.testing.assert_allclose(p, got_pool, **tol)
+
+
+@pytest.mark.parametrize("S, H, dk, dv, heads", [
+    (1024, 64, 128, 128, 8), (512, 64, 128, 128, 8), (64, 2, 128, 256, 2),
+    (64, 12, 128, 128, 4), (1, 64, 128, 128, None), (32, 64, 128, 128, None),
+    (96, 64, 128, 128, None), (128, 4, 16, 128, None), (128, 4, 128, 64, None)])
+def test_the_chunkwise_body_is_chosen_by_shape_alone(S, H, dk, dv, heads):
+    """Whole chunks of heads whose keys and values fill whole 128-lane tiles
+    take the chunkwise body, up to 8 heads a grid step; a decode step, a
+    bucket shorter than or not whole chunks and every other head geometry
+    stay on the token walk."""
+    assert gated_delta.chunk_heads(S, H, dk, dv) == heads
+
+
+def test_a_chunk_of_the_channel_form_builds_no_token_tile():
+    """The chunkwise body reads q, k, g, v and beta as the mixer made them:
+    its program has no [B, S, 3 d_k + 8, H] tile, the walk's still has."""
+    q, k, v, g, beta, _ = _delta_inputs(1, 64, 2, 128, 128)
+    pool, one = jnp.zeros((1, 2, 128, 2 * 128)), jnp.ones((1,), jnp.int32)
+    text = lambda S: str(jax.make_jaxpr(partial(
+        gated_delta.gated_delta_pallas, layer=0, interpret=True))(
+            q[:, :S], k[:, :S], v[:, :S], g[:, :S], beta[:, :S], pool, one, one, one))
+    tile = f"{3 * 128 + 8},2]"
+    assert "kda_chunk" in text(64) and tile not in text(64)
+    assert "kda_chunk" in text(32) and tile in text(32)
 
 
 def test_the_two_forms_are_two_programs_with_their_own_names():
