@@ -888,6 +888,13 @@ document.getElementById("f").onsubmit = async (e) => {
                 "grouped_steps": stats.moe_grouped_steps,
                 "scan_steps": stats.moe_scan_steps,
             },
+            # a family with per-sequence state: prefill dispatches and chunk
+            # rounds by the body their delta-rule kernel took (zeros for any
+            # other family, and where the jax.numpy twin runs)
+            "delta_rule": {
+                "chunkwise_steps": stats.delta_chunkwise_steps,
+                "walk_steps": stats.delta_walk_steps,
+            },
             # a family whose decode dispatch is a block step (generation by
             # diffusion over blocks): dispatches, the passes inside them that
             # sampled, tokens emitted, positions the threshold filled; and

@@ -366,6 +366,12 @@ class EngineStats:
         # ``expert_path``: static a program, so counted at dispatch)
         self.moe_grouped_steps = 0    # chosen experts only, row-blocks
         self.moe_scan_steps = 0       # every held expert, gate-masked
+        # prefill dispatches and chunk rounds of a family with per-sequence
+        # state by the body their delta-rule kernel took (the family's
+        # ``delta_body``: a rule of the bucket's shape, static a program, so
+        # counted at dispatch as the two above)
+        self.delta_chunkwise_steps = 0  # whole chunks on the MXU
+        self.delta_walk_steps = 0       # token by token
         # sampled steps by the work their rows' parameters asked of
         # ``sample_tokens`` (counted on the host from the same rows)
         self.sample_argmax_steps = 0    # no row samples: the argmax alone
@@ -2556,6 +2562,7 @@ class TPUEngine:
                     self.metrics.llm_half_prefill_batches.labels(
                         replica=rid).inc()
         self._count_expert_path(width * length)
+        self._count_delta_body(length)
         tl.step(seq, kind, width, len(admitted), length, dispatch.t0, sync.t1,
                 counts)
         self._record_step("prefill", seq=seq, batch=len(admitted),
@@ -2675,6 +2682,7 @@ class TPUEngine:
         self.stats.prefill_ms_total += elapsed_ms
         width = call.shape[0]
         self._count_expert_path(width * S)
+        self._count_delta_body(S)
         tl.step(seq, "chunk", width, len(batch), S, dispatch.t0, sync.t1,
                 counts)
         self._record_step(
@@ -3479,6 +3487,17 @@ class TPUEngine:
             self.stats.moe_grouped_steps += steps
         elif path == "scan":
             self.stats.moe_scan_steps += steps
+
+    def _count_delta_body(self, seq: int) -> None:
+        """Count a prefill dispatch or a chunk round of ``seq`` positions a
+        row by the body its delta-rule kernel traced (nothing for a family
+        without per-sequence state, or where the ``jax.numpy`` twin runs)."""
+        rule = getattr(self._family, "delta_body", None)
+        body = rule(self.model_config, self.mesh, seq) if rule else None
+        if body == "chunkwise":
+            self.stats.delta_chunkwise_steps += 1
+        elif body == "walk":
+            self.stats.delta_walk_steps += 1
 
     def _sampling_params(self, fields: dict[str, np.ndarray],
                          temperature: np.ndarray, top_k: np.ndarray,

@@ -39,7 +39,10 @@ counts its step programs return beside the tokens (``engine._step_counts``:
 ``[tokens through expert layers, pairs on held experts, a summed share, the
 rows]``, then ``[live state rows, real tokens scanned]`` from a family with a
 state a sequence, ``solar_open2`` filling both halves, then a window family's
-two key counts);
+two key counts); ``delta_body(config, mesh, seq)``, a family with delta-rule
+layers saying which body of their kernel a prefill of ``seq`` positions a
+row traces (``chunkwise`` | ``walk`` | None: the engine counts its prefill
+dispatches by it);
 and ``drafts_on_device(config) -> bool``: WHERE A SPECULATIVE DRAFT COMES FROM.
 A family without the name, or one that answers False, gets the engine's
 prompt-lookup drafts (``engine._draft_tokens``) and the plain verify step. A

@@ -219,6 +219,20 @@ def delta_impl(mesh, config) -> str:
     return "pallas" if on_tpu(mesh) and aligned else "jnp"
 
 
+def delta_body(config, mesh, seq: int, channel: bool = False) -> str | None:
+    """Which body of the delta-rule kernel a prefill or a chunk round of
+    ``seq`` positions a row traces, from what is visible before tracing:
+    ``"chunkwise"`` (whole chunks on the MXU) or ``"walk"`` (token by token),
+    ``ops/gated_delta.py``'s rule of shape for the form (``channel``: a decay a
+    key channel); None where the ``jax.numpy`` twin runs (:func:`delta_impl`).
+    The engine counts its prefill dispatches by the same call."""
+    if delta_impl(mesh, config) != "pallas":
+        return None
+    return gated_delta.chunk_body(seq, config.linear_n_heads,
+                                  config.linear_key_dim,
+                                  config.linear_value_dim, channel)
+
+
 def expert_path(config: OlmoHybridConfig, mesh, tokens: int,
                 dtype=None) -> None:
     """No routed experts: as the dense trunk answers."""

@@ -70,6 +70,7 @@ from .afmoe import ROUTER_BIAS_SCALE, expert_path, gated_output
 from .configs import SolarOpen2Config
 from .deepseek import route
 from .llama import _dense, _ffn, lm_logits, rms_norm, routed_experts
+from .olmo_hybrid import delta_body as olmo_delta_body
 from .olmo_hybrid import (conv_qkv, decode_attend, delta_impl, delta_rule,
                           history_attend, init_keys, init_trunk,  # noqa: F401 (family names)
                           prefill_impl, prefill_unit, state_rows)
@@ -203,6 +204,11 @@ def param_count(config: SolarOpen2Config) -> int:
 def paged_impl(mesh, config: SolarOpen2Config, kv: HybridKVState) -> str:
     return select_paged_attention(mesh, config.head_dim, kv.page_size,
                                   config.n_kv_heads, False)
+
+
+def delta_body(config: SolarOpen2Config, mesh, seq: int) -> str | None:
+    """``olmo_hybrid.delta_body`` for the channel form of the rule."""
+    return olmo_delta_body(config, mesh, seq, channel=True)
 
 
 def refusals(config: SolarOpen2Config, engine_config, mesh,

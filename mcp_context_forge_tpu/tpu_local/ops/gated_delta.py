@@ -21,11 +21,12 @@ where ``[.., H, d_k, d_v]`` would pad every head's 192 lanes to 256 in HBM and
 move a third more bytes on every step. Row 0 is the trash row, as page 0 is the
 trash page: idle decode rows and a batch's padding rows read and write it.
 
-**Kernels: the token walk.** ``gated_delta_chunk`` (prefill, chunk rounds)
-and ``gated_delta_step`` (decode) are ONE Pallas body: a grid step holds one
-row's whole state in VMEM (the output block, resident across the row's token
-tiles) and walks the row's REAL tokens one by one on the VPU in float32, all
-heads at once; tokens past the row's length cost nothing. A token's per-head
+**Kernels: the token walk.** ``gated_delta_step`` (decode) and the
+``gated_delta_chunk`` of a bucket that is not whole chunks are ONE Pallas
+body: a grid step holds one row's whole state in VMEM (the output block,
+resident across the row's token tiles) and walks the row's REAL tokens one
+by one on the VPU in float32, all heads at once; tokens past the row's length
+cost nothing. A token's per-head
 vectors arrive as one ``[2 d_k + 8, H]`` tile (``k^T``, ``q^T``, ``alpha``,
 ``beta``): a head's key is then a sublane column that broadcasts along that
 head's lanes, so ``S^T k`` is a multiply and a sublane reduction, and the
@@ -39,17 +40,21 @@ lane-aligned. The state row is found through scalar-prefetched row ids and
 updated in place (``input_output_aliases``); a row that starts a sequence
 (``fresh``) starts from zero without reading what its last tenant left.
 
-**Which shapes take which body** (:func:`chunk_heads`, a rule of ``S, H,
-d_k, d_v`` beside :func:`head_group`; no setting). The scalar form always
-walks. The channel form walks a decode step (``kda_step``), a bucket that is
-not whole 64-token chunks, and heads whose ``d_k`` or ``d_v`` is not a
-multiple of 128, ``_CHANNEL_TOKEN_TILE`` = 32 tokens a grid step (64 tokens
-of 392 rows beside the whole 4.2 MB state of 64 x 128 x 128, double-buffered,
-ask for 48.5 MB of scoped VMEM against ``_VMEM_LIMIT`` = 48, which the chip's
-compiler refuses inside a step program: PERF.md section 6, PR 50). Every
-other call of the channel form, which is every prefill bucket and chunk round
-of the cell that has it, takes the chunkwise body, under the same name
-``kda_chunk``.
+**Which shapes take which body** (a rule of ``S, H, d_k, d_v`` a form,
+beside :func:`head_group`; no setting). A decode step walks in both forms
+(``gated_delta_step`` / ``kda_step``), and so does a bucket that is not whole
+64-token chunks. Whole chunks of the SCALAR form take the chunkwise body
+(``gated_delta_chunk`` still, PR 52) wherever the heads have a lane-aligned
+grouping and the keys fill whole sublane tiles (:func:`scalar_chunk_group`:
+Olmo's 30 heads of 96 x 192, two a 384-lane slab; the test preset's 4 of 16 x
+32, all four a slab). Whole chunks of the CHANNEL form take theirs where
+``d_k`` and ``d_v`` are multiples of 128 (:func:`chunk_heads`); other heads
+walk, ``_CHANNEL_TOKEN_TILE`` = 32 tokens a grid step (64 tokens of 392 rows
+beside the whole 4.2 MB state of 64 x 128 x 128, double-buffered, ask for
+48.5 MB of scoped VMEM against ``_VMEM_LIMIT`` = 48, which the chip's
+compiler refuses inside a step program: PERF.md section 6, PR 50).
+:func:`chunk_body` names the answer, and the engine counts its prefill
+dispatches by it (``EngineStats.delta_chunkwise_steps`` / ``.delta_walk_steps``).
 
 **The chunkwise channel body** (PR 51). For a chunk of ``C`` = 64 tokens of
 one head with entry state ``S_0`` and cumulative log-decay ``gamma_t = sum_{s
@@ -104,10 +109,58 @@ The layer index is a prefetched scalar, not a static one, and the call is
 jitted on its own: the body is traced once a shape and lowered once a step
 program, which calls it once a KDA layer (the walk is lowered once a layer).
 
+**The chunkwise scalar body** (PR 52). With one decay a head ``gamma_t`` is a
+scalar a token and the pairwise factor a ``[C, C]`` mask a head:
+
+    Gamma_ij = exp(gamma_i - gamma_j)   (j <= i, else 0; every exponent <= 0)
+    A = strict_lower(diag(beta) (K K^T * Gamma)),   P = lower(Q K^T * Gamma)
+    (I + A) V_new = diag(beta) (V - (K * e^gamma) S_0)
+    O = (Q * e^gamma) S_0 + P V_new
+    S_C = e^gamma_C S_0 + (K * e^(gamma_C - gamma))^T V_new
+
+(``gated_delta_chunked`` with one solve, for ``V_new``). ``Gamma`` is formed
+as the masked difference, never factored (Olmo's ``g`` sums below -88, the
+end of float32's ``exp``, inside one chunk); ``gamma`` and ``gamma_C - gamma``
+are each a masked lane reduction of the chunk's ``g`` (sums of terms of one
+sign, no difference of large numbers), and a token's scalar reaches the lanes
+from the sublanes through the diagonal of a ``[C, C]`` select, exactly.
+``(I + A)`` is solved by forward substitution in 16-row blocks: earlier
+blocks leave by one product, inside a block column ``j`` times the finished
+row ``j`` leaves the rows below; ``Q K^T`` and ``K K^T`` are one product,
+``K e^gamma`` and ``Q e^gamma`` against ``S_0`` another, and a slab's heads
+update their states in ONE product (their tokens one below the other, each
+head's ``V_new`` on its own lanes), so the slab is written as it lies in the
+pool. Float32 operands at ``Precision.HIGHEST``, float32 accumulation.
+
+The geometry is Olmo's: 30 heads of ``d_k`` = 96, ``d_v`` = 192. The grid is
+(row, chunk) and a grid step holds ALL heads of a chunk: q and k come AS THE
+MIXER MADE THEM in ``[64, H, d_k]`` blocks, g and beta as ``[64, H]``; v
+comes and o leaves AS THE STATE LIES, ``[64, H d_v]`` with a slab's heads
+side by side on the lanes (the mixer's v is a slice of the convolution's flat
+output and its o is normed and gated flat, so neither is relaid out for the
+kernel's sake: ``[64, H, d_v]`` blocks measured 0.8 ms a prefill more in the
+copies around the kernel and nothing less inside it), and the row's whole
+``[d_k, H d_v]`` state stays in VMEM across its chunks (21 MB of
+``_VMEM_LIMIT`` = 48 at Olmo's widths before Mosaic's own temporaries: 1 MB a
+q or k block padded to 32 x 128 a token, 1.5 MB a v or o block, 2.2 MB the
+state in and out, all double-buffered, and 2 MB of heads-first copies).
+Mosaic's strided load, which the channel body reads a head with, wants a last
+dimension of exactly 128 (refused at 96, 192 and 256), so a grid step first
+turns its q and k blocks heads-first into scratch (``swapaxes``) and a head
+is then one plain load (``ref[0, :, i, :]`` a head: 8 % slower). The heads
+are worked on in slabs of :func:`head_group` heads (lane-aligned in the
+pool), ``_SCALAR_ABREAST`` = 10 heads in step a turn of the loop (4: 12 %
+slower, all 30: no faster and three times the Mosaic compile), the slabs a
+turn does not divide in a last, shorter turn. A chunk wholly past the row's
+count names the row's last real chunk in its index maps, so nothing is
+fetched for it, and writes zeros; a partly filled one runs whole with its
+tokens past the count made identity steps. The layer index is a prefetched
+scalar and the call jitted on its own, as the channel body's.
+
 The chunked WY form on the MXU in ``jax.numpy`` is ``gated_delta_chunked``:
-the scalar form's path off the TPU, and the twin its kernel is held to.
+the scalar form's path off the TPU, and the twin its kernels are held to.
 ``gated_delta_recurrence`` is the token-by-token definition of both forms, the
-channel form's path off the TPU and the twin both of its bodies are held to.
+channel form's path off the TPU and the twin all chunkwise bodies are held to.
 """
 
 from __future__ import annotations
@@ -503,6 +556,206 @@ def _chunk_call(q, k, v, g, beta, pool, rows, counts, fresh, layer, *,
       fresh.astype(jnp.int32), layer, q, k, g, v, beta, pool)
 
 
+# -------------------------------------------- the scalar form's chunk, on the MXU
+
+_SCALAR_ABREAST = 10       # heads of a grid step worked on in step
+
+
+def scalar_chunk_group(seq: int, n_heads: int, dk: int, dv: int) -> int | None:
+    """Heads a lane-aligned slab of the state holds (:func:`head_group`) where
+    a call of the scalar form of this shape takes the chunkwise body, or None
+    where it takes the token walk: the body wants whole chunks, keys on whole
+    sublane tiles (they are the state's rows) and a grouping of the heads."""
+    if seq % CHUNK or dk % 8:
+        return None
+    return head_group(n_heads, dv)
+
+
+def chunk_body(seq: int, n_heads: int, dk: int, dv: int, channel: bool) -> str:
+    """``chunkwise`` or ``walk``: the body a call of ``seq`` tokens of this
+    head geometry traces, by its form's rule of shape."""
+    rule = chunk_heads if channel else scalar_chunk_group
+    return "chunkwise" if rule(seq, n_heads, dk, dv) else "walk"
+
+
+def _scalar_chunk_of_heads(heads, slabs):
+    """A chunk of a few heads of the scalar form, on values and in step: heads
+    is a list of (q, k [C, dk]; g [C, 1] the log-decay a token; v [C, dv];
+    beta [C, 1]), slabs the states on entry of every ``group`` heads in turn,
+    side by side on the lanes [dk, group * dv] -> (a list of o [C, dv], the
+    slabs on leaving). Module docstring, "the chunkwise scalar body"."""
+    f32, C, c = jnp.float32, CHUNK, _SUB
+    group = len(heads) // len(slabs)
+    dv = slabs[0].shape[1] // group
+    iota = jax.lax.broadcasted_iota
+    row, col = iota(jnp.int32, (C, C), 0), iota(jnp.int32, (C, C), 1)
+    block = lambda a, n: a[n * c:(n + 1) * c]
+    down = lambda column: jnp.sum(                   # [C, 1] -> [1, C], exactly
+        jnp.where(row == col, column, 0.0), axis=0, keepdims=True)
+    through, rhs, lower_p, lower_a, keys, totals = [], [], [], [], [], []
+    for h, (q, k, g, v, beta) in enumerate(heads):
+        state = slabs[h // group][:, h % group * dv:(h % group + 1) * dv]
+        # gamma_i, the sum of the g up to token i, and gamma_C - gamma_i, the
+        # sum of those after it: each a lane reduction a token, no difference
+        g_row = down(g)
+        gam = jnp.sum(jnp.where(col <= row, g_row, 0.0), axis=1, keepdims=True)
+        after = jnp.sum(jnp.where(col > row, g_row, 0.0), axis=1, keepdims=True)
+        keys.append(k * jnp.exp(after))
+        totals.append(jnp.exp(jnp.sum(g, axis=0, keepdims=True)))     # [1, 1]
+        decay = jnp.exp(gam)
+        through.append(_mm(jnp.concatenate([k * decay, q * decay], axis=0), state))
+        rhs.append(beta * (v - through[-1][:C]))     # (I + A) V_new = rhs
+        # exp(gamma_i - gamma_j) as the masked difference: every exponent <= 0
+        pair = jnp.exp(jnp.where(col <= row, gam - down(gam), -jnp.inf))
+        both = _mm(jnp.concatenate([q, k], axis=0), k, ((1,), (1,)))
+        lower_p.append(both[:C] * pair)
+        lower_a.append(jnp.where(col < row, beta * both[C:] * pair, 0.0))
+    # (I + A) is unit lower triangular: forward substitution, a 16-row block
+    # at a time; earlier blocks leave by a product, inside a block column j
+    # times the finished row j leaves the rows below it
+    solved = [[] for _ in heads]
+    for a in range(C // c):
+        xs = [block(r, a) for r in rhs]
+        if a:
+            xs = [x - _mm(block(A, a)[:, :a * c], jnp.concatenate(done, axis=0))
+                  for x, A, done in zip(xs, lower_a, solved)]
+        here = [block(A, a)[:, a * c:(a + 1) * c] for A in lower_a]
+        for j in range(c - 1):
+            xs = [x - A[:, j:j + 1] * x[j:j + 1] for x, A in zip(xs, here)]
+        for done, x in zip(solved, xs):
+            done.append(x)
+    v_new = [jnp.concatenate(rows, axis=0) for rows in solved]
+    outs = [t[C:] + _mm(P, x) for t, P, x in zip(through, lower_p, v_new)]
+    # the states: K * e^(gamma_C - gamma) against V_new, a slab's heads in ONE
+    # product: their tokens one below the other, each head's V_new on its own
+    # lanes of the slab, which is so written as it lies in the pool
+    lanes = iota(jnp.int32, (1, group * dv), 1) // dv
+    zeros = lambda n: [jnp.zeros((C, n * dv), f32)] if n else []
+    left = []
+    for s, slab in enumerate(slabs):
+        mine = range(s * group, (s + 1) * group)
+        values = [jnp.concatenate(zeros(i) + [v_new[h]] + zeros(group - 1 - i),
+                                  axis=1) for i, h in enumerate(mine)]
+        decay = jnp.zeros((1, group * dv), f32)
+        for i, h in enumerate(mine):
+            decay = jnp.where(lanes == i, totals[h], decay)
+        left.append(decay * slab + _mm(
+            jnp.concatenate([keys[h] for h in mine], axis=0),
+            jnp.concatenate(values, axis=0), ((0,), (0,))))
+    return outs, left
+
+
+def _scalar_chunk_kernel(rows_ref, count_ref, fresh_ref, layer_ref, q_ref,
+                         k_ref, v_ref, g_ref, beta_ref, s_in_ref, o_ref,
+                         s_out_ref, q_heads, k_heads, *, dv: int, group: int):
+    """One chunk of ``CHUNK`` tokens of ALL heads of one row, the slabs of
+    ``group`` heads ``_SCALAR_ABREAST`` heads at a time
+    (:func:`_scalar_chunk_of_heads`)."""
+    del rows_ref, layer_ref                         # ride the index maps
+    b, ci = pl.program_id(0), pl.program_id(1)
+    f32, C = jnp.float32, CHUNK
+    H = beta_ref.shape[2]
+    slabs, width = H // group, group * dv
+    abreast = max(1, _SCALAR_ABREAST // group)      # slabs a turn
+
+    @pl.when(ci == 0)
+    def _():
+        s_out_ref[...] = jnp.where(fresh_ref[b] > 0, 0.0, s_in_ref[...])
+
+    real = count_ref[b] - ci * C                    # the chunk's real tokens
+
+    @pl.when(real <= 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(real > 0)
+    def _():
+        iota = jax.lax.broadcasted_iota
+        valid = iota(jnp.int32, (C, 1), 0) < real
+        head_lane = iota(jnp.int32, (C, H), 1)
+        # a token past the row's count is the identity step
+        gs = jnp.where(valid, g_ref[0].astype(f32), 0.0)          # [C, H]
+        betas = jnp.where(valid, beta_ref[0].astype(f32), 0.0)
+        # a head's [C, dk] is a row a token, H rows apart: the strided load
+        # that reads it wants a last dimension of exactly 128 (Mosaic), so
+        # the blocks are turned heads-first once and a head is one plain load
+        for ref, turned in ((q_ref, q_heads), (k_ref, k_heads)):
+            turned[...] = jnp.swapaxes(ref[0].astype(f32), 0, 1)
+
+        def column(table, i):
+            return jnp.sum(jnp.where(head_lane == i, table, 0.0), axis=1,
+                           keepdims=True)                         # [C, 1]
+
+        def some_slabs(first, n):
+            lanes = [pl.ds(pl.multiple_of((first + s) * width, 128), width)
+                     for s in range(n)]
+            each = [(first + s) * group + i for s in range(n) for i in range(group)]
+            # v and o lie as the state does, a slab's heads side by side
+            values = [v_ref[0, :, at].astype(f32) for at in lanes]
+            outs, left = _scalar_chunk_of_heads(
+                [(q_heads[i], k_heads[i], column(gs, i),
+                  values[m // group][:, m % group * dv:(m % group + 1) * dv],
+                  column(betas, i)) for m, i in enumerate(each)],
+                [s_out_ref[:, at] for at in lanes])
+            for s, (at, slab) in enumerate(zip(lanes, left)):
+                o_ref[0, :, at] = jnp.concatenate(
+                    outs[s * group:(s + 1) * group], axis=1)
+                s_out_ref[:, at] = slab
+
+        def turn(n, carry):
+            some_slabs(n * abreast, abreast)
+            return carry
+
+        jax.lax.fori_loop(0, slabs // abreast, turn, 0)
+        if slabs % abreast:
+            some_slabs(slabs - slabs % abreast, slabs % abreast)
+
+
+@functools.partial(jax.jit, static_argnames=("group", "interpret"))
+def _scalar_chunk_call(q, k, v, g, beta, pool, rows, counts, fresh, layer, *,
+                       group: int, interpret: bool):
+    """The chunkwise body of the scalar form over a bucket; ``layer`` an
+    int32 [1] array and the call jitted on its own, as :func:`_chunk_call`."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    # a chunk wholly past the row's count names the row's last real chunk
+    # again: a block is not fetched twice, so such a chunk moves nothing in
+    live = lambda b, c, counts: jnp.minimum(
+        c, jnp.maximum(counts[b] - 1, 0) // CHUNK)
+    keys = pl.BlockSpec((1, CHUNK, H, dk), lambda b, c, rows, counts, *_:
+                        (b, live(b, c, counts), 0, 0))
+    flat = lambda width: pl.BlockSpec(
+        (1, CHUNK, width),
+        lambda b, c, rows, counts, *_: (b, live(b, c, counts), 0))
+    row_spec = pl.BlockSpec((None, None, dk, H * dv),
+                            lambda b, c, rows, counts, fresh, layer:
+                            (layer[0], rows[b], 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_scalar_chunk_kernel, dv=dv, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, S // CHUNK),
+            in_specs=[keys, keys, flat(H * dv), flat(H), flat(H), row_spec],
+            out_specs=[pl.BlockSpec((1, CHUNK, H * dv),
+                                    lambda b, c, *_: (b, c, 0)), row_spec],
+            scratch_shapes=[pltpu.VMEM((H, CHUNK, dk), jnp.float32)] * 2,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, S, H * dv), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 9 of the call (four prefetched scalars, q, k, v, g, beta)
+        # is the pool: updated in place
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="gated_delta_chunk",
+        interpret=interpret,
+    )(rows.astype(jnp.int32), counts.astype(jnp.int32),
+      fresh.astype(jnp.int32), layer, q, k, v.reshape(B, S, H * dv), g, beta,
+      pool)
+    return o.reshape(B, S, H, dv), pool
+
+
 def pack_token_tiles(q, k, g, beta):
     """q, k: [B, S, H, dk]; g, beta: [B, S, H] -> [B, S, 2 dk + 8, H] float32:
     rows ``k^T``, ``q^T``, ``exp(g)``, ``beta``, six rows of zeros. With g
@@ -540,6 +793,10 @@ def gated_delta_pallas(q, k, v, g, beta, pool, rows, counts, fresh, *,
         return _chunk_call(q, k, v, g, beta, pool, rows, counts, fresh,
                            jnp.full((1,), layer, jnp.int32), heads=heads,
                            interpret=interpret)
+    if not channel and scalar_chunk_group(S, H, dk, dv):
+        return _scalar_chunk_call(q, k, v, g, beta, pool, rows, counts, fresh,
+                                  jnp.full((1,), layer, jnp.int32),
+                                  group=group, interpret=interpret)
     tokens = min(S, _CHANNEL_TOKEN_TILE if channel else _TOKEN_TILE)
     if S % tokens:
         raise ValueError(f"S={S} must be a multiple of {tokens}")

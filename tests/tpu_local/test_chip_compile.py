@@ -168,7 +168,19 @@ def _held_experts_case():
 
 def _gated_delta_case(batch: int, seq: int):
     """olmo-hybrid-7b.chat: 30 heads of a 96 x 192 float32 state, 24 linear
-    layers x 33 rows; decode steps of 32 rows, prefills of up to 4 x 512."""
+    layers x 33 rows; decode steps of 32 rows (the token walk), prefills of up
+    to 4 x 512 and the half program's 1 x 256 (the chunkwise body since PR 52:
+    a grid step holds all 30 heads of a 64-token chunk: a q and a k block of
+    1.05 MB each (a token's [30, 96] padded to [32, 128]), a v and an o block
+    of 1.47 MB ([64, 5760], as the state lies), the row's whole 2.21 MB state
+    in and out, all double-buffered, and 2 MB of heads-first scratch: 21 MB
+    against the limit of 48 before Mosaic's own temporaries). A pass here is not the chip's word on scoped
+    VMEM (``_kda_case``): PR 52 compiled the cell's four dense prefill
+    programs ([1, 256], [1, 512], [2, 512], [4, 512]) and its history
+    programs ON the chip, inside the cell's warm-up, before measuring; what
+    this compile did say first is that Mosaic's strided load wants a last
+    dimension of exactly 128 (refused at 96, 192 and 256), which is why the
+    body turns its blocks heads-first."""
     H, dk, dv = 30, 96, 192
     f32, i32 = jnp.float32, jnp.int32
     shapes = [((batch, seq, H, dk), f32)] * 2 + [((batch, seq, H, dv), f32)] \

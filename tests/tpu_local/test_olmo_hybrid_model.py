@@ -202,6 +202,147 @@ def test_kernel_is_its_jnp_twin(tokens):
                                   pool[1, jnp.asarray([1, 3])])
 
 
+def _through_the_pool(q, k, v, g, beta, pool, rows, counts, fresh, layer=1):
+    """(o, pool) of the kernel (interpreted), and what the recurrence gives
+    from the rows' states with tokens past a row's count the identity."""
+    H = q.shape[2]
+    valid = jnp.arange(q.shape[1])[None] < counts[:, None]
+    got = gated_delta.gated_delta_pallas(
+        q, k, v, g, beta, pool, rows, counts, fresh, layer=layer, interpret=True)
+    state = jnp.where((fresh > 0)[:, None, None, None], 0.0,
+                      gated_delta.pool_rows(pool, layer, rows, H))
+    want_o, want_state = gated_delta.gated_delta_recurrence(
+        q, k, v, jnp.where(valid[..., None], g, 0.0),
+        jnp.where(valid[..., None], beta, 0.0), state)
+    return got, (want_o, gated_delta.flat_rows(want_state)), valid
+
+
+CHUNKWISE = ("unequal_rows", "strong_decay", "repeated_keys_beta_2",
+             "split_prompt", "odd_pairing")
+
+
+@pytest.mark.parametrize("case", CHUNKWISE)
+def test_chunkwise_scalar_body_is_the_recurrence(case):
+    """The chunkwise body of the scalar form (whole 64-token chunks at the
+    cell's head geometry, 96 x 192, two heads a 384-lane slab; interpreted)
+    against the token-by-token definition, at the chunked twin's tolerance.
+    ``unequal_rows``: a full row, one ending inside its second chunk, a fresh
+    one ending in the first 16 tokens, a padding row on the trash row (tokens
+    past a count are the identity whatever g and beta say there); other rows
+    and layers untouched. ``strong_decay``: g = -10 a token for a chunk and
+    more, a chunk's summed g at -640, where the factored WY form overflows
+    float32. ``repeated_keys_beta_2``: three keys in turn with beta 1.95 and
+    hardly any decay, the triangular system's worst case. ``split_prompt``:
+    512 tokens as one call and as two of 256, the state carried by the pool.
+    ``odd_pairing``: fourteen heads (2 mod 4) are seven slabs, five in step
+    in the loop's one turn and two after it."""
+    tol = dict(atol=2e-5, rtol=2e-5)
+    dk, dv = 96, 192
+    B, S, H = {"unequal_rows": (4, 128, 2), "split_prompt": (1, 512, 2),
+               "odd_pairing": (1, 64, 14)}.get(case, (2, 128, 2))
+    assert gated_delta.scalar_chunk_group(S, H, dk, dv) == 2
+    q, k, v, g, beta, _ = _delta_inputs(B, S, H, dk, dv, seed=5)
+    counts = jnp.full((B,), S)
+    rows, fresh = jnp.arange(1, B + 1), jnp.zeros((B,), jnp.int32)
+    if case == "unequal_rows":
+        counts, rows = jnp.asarray([S, 70, 5, 0]), jnp.asarray([2, 4, 5, 0])
+        fresh = jnp.asarray([0, 0, 1, 0])
+    elif case == "strong_decay":
+        g = jnp.where((jnp.arange(S) < 80)[None, :, None], -10.0, g)
+    elif case == "repeated_keys_beta_2":
+        k = k[:, jnp.arange(S) % 3]
+        g, beta = g * 0.01, jnp.full_like(beta, 1.95)
+    pool = jax.random.normal(jax.random.PRNGKey(9), (2, 7, dk, H * dv))
+    (got_o, got_pool), (want_o, want_rows), valid = _through_the_pool(
+        q, k, v, g, beta, pool, rows, counts, fresh)
+    assert bool(jnp.all(jnp.isfinite(got_o))) and bool(jnp.all(jnp.isfinite(got_pool)))
+    np.testing.assert_allclose(jnp.where(valid[..., None, None], got_o, 0),
+                               jnp.where(valid[..., None, None], want_o, 0), **tol)
+    live = np.flatnonzero(np.asarray(rows))
+    np.testing.assert_allclose(got_pool[1, rows[live]], want_rows[live], **tol)
+    others = jnp.asarray(sorted(set(range(1, 7)) - set(np.asarray(rows).tolist())))
+    np.testing.assert_array_equal(got_pool[0], pool[0])
+    np.testing.assert_array_equal(got_pool[1, others], pool[1, others])
+    if case == "split_prompt":
+        half, p = S // 2, pool
+        outs = []
+        for lo in (0, half):
+            part = slice(lo, lo + half)
+            o, p = gated_delta.gated_delta_pallas(
+                q[:, part], k[:, part], v[:, part], g[:, part], beta[:, part], p,
+                rows, jnp.full((B,), half), fresh, layer=1, interpret=True)
+            outs.append(o)
+        np.testing.assert_allclose(jnp.concatenate(outs, axis=1), got_o, **tol)
+        np.testing.assert_allclose(p, got_pool, **tol)
+
+
+@pytest.mark.parametrize("S, H, dk, dv, group", [
+    (256, 30, 96, 192, 2), (512, 30, 96, 192, 2),       # the cell's buckets
+    (128, 4, 16, 32, 4), (64, 4, 16, 128, 1),
+    (1, 30, 96, 192, None), (32, 30, 96, 192, None), (96, 30, 96, 192, None),
+    (128, 3, 96, 192, None), (128, 4, 12, 128, None)])
+def test_the_scalar_chunkwise_body_is_chosen_by_shape_alone(S, H, dk, dv, group):
+    """Whole chunks of heads that have a lane-aligned grouping (and keys on
+    whole sublane tiles) take the chunkwise body, all heads a grid step; a
+    decode step, a bucket shorter than or not whole chunks and a geometry
+    without a grouping stay on the token walk. The cell's four dense prefill
+    programs ([1, 256], [1, 512], [2, 512], [4, 512]) are the first two."""
+    assert gated_delta.scalar_chunk_group(S, H, dk, dv) == group
+    assert gated_delta.chunk_body(S, H, dk, dv, channel=False) == (
+        "chunkwise" if group else "walk")
+
+
+def test_a_chunk_of_the_scalar_form_builds_no_token_tile():
+    """The chunkwise body reads q, k, g and beta as the mixer made them (v and
+    o as the state lies, as the walk does): its program has no
+    [B, S, 2 d_k + 8, H] tile, the walk's still has; the kernel's name is
+    the same."""
+    H, dk, dv = 2, 96, 192
+    q, k, v, g, beta, _ = _delta_inputs(1, 64, H, dk, dv)
+    pool, one = jnp.zeros((1, 2, dk, H * dv)), jnp.ones((1,), jnp.int32)
+    text = lambda S: str(jax.make_jaxpr(partial(
+        gated_delta.gated_delta_pallas, layer=0, interpret=True))(
+            q[:, :S], k[:, :S], v[:, :S], g[:, :S], beta[:, :S], pool, one, one, one))
+    tile = f"{2 * dk + 8},{H}]"
+    assert "gated_delta_chunk" in text(64) and tile not in text(64)
+    assert "gated_delta_chunk" in text(32) and tile in text(32)
+
+
+def test_the_engine_counts_prefills_by_the_body_their_bucket_takes(monkeypatch):
+    """``EngineStats.delta_chunkwise_steps`` / ``.delta_walk_steps``: a
+    prefill dispatch and a chunk round count by the family's ``delta_body``,
+    a rule of the bucket's length; nothing where the ``jax.numpy`` twin runs
+    (every CPU engine), so the rule is stood in for here."""
+    big = dataclasses.replace(CFG, linear_n_heads=30, linear_key_dim=96,
+                              linear_value_dim=192)
+    mesh = _engine().mesh
+    assert olmo_hybrid.delta_body(big, mesh, 512) is None           # the twin
+    monkeypatch.setattr(olmo_hybrid, "on_tpu", lambda mesh: True)
+    assert [olmo_hybrid.delta_body(big, mesh, S) for S in (256, 512, 16, 96)] == [
+        "chunkwise", "chunkwise", "walk", "walk"]
+    monkeypatch.undo()
+    monkeypatch.setattr(olmo_hybrid, "delta_body", lambda c, mesh, seq: (
+        gated_delta.chunk_body(seq, c.linear_n_heads, c.linear_key_dim,
+                               c.linear_value_dim, False)))
+
+    async def run():
+        engine = _engine(prefill_buckets=(32, BUCKET))
+        await engine.start()
+        try:
+            await _generate(engine, [1] + prompt_of(20, 20), 3)    # a prefill
+            await _generate(engine, [1] + prompt_of(150, 150), 3)  # chunk rounds
+            stats = engine.stats
+            return (stats.prefill_batches, stats.delta_chunkwise_steps,
+                    stats.delta_walk_steps)
+        finally:
+            await engine.stop()
+
+    # the short prompt's bucket of 32 is no whole chunk (the walk); the long
+    # one's rounds of 64 are, but for a last round in the shorter bucket
+    batches, chunkwise, walk = asyncio.run(run())
+    assert chunkwise + walk == batches and chunkwise >= 2 and walk >= 1
+
+
 # ------------------------------------------------------------ pools and rows
 
 def test_pools_declare_their_layers_and_page_bytes_are_unchanged():

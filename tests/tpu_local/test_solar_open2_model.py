@@ -531,7 +531,10 @@ PARENT_PROGRAMS = {
         "chunk": "209df41fbd2f9a2ef2d6f3e8d148eac9107f66423f4f7851c52cb7f20057df05",
         "decode": "a1167faea08c4f5651b8a763ff3eeaab8d0c5a93fce3730e41953f5efd6d8b53",
         "kernel_step": "5665504a8d8b629a0c2e11c960efc38147ff5f62b1fbe1e6d2c04b05f2884bae",
-        "kernel_chunk": "205b33bd9aa80ad61ac11bbc9eb036b8af0cd2c62128e5812762aa8accbc8578"},
+        # PR 52: a scalar-form chunk of whole 64-token chunks takes the
+        # chunkwise body (``gated_delta.scalar_chunk_group``); the preset's
+        # bucket of 16 and every step stay on the walk, as pinned above
+        "kernel_chunk": "fb2c80152d0159b0c4c4ce433fcc9cdf6365f78f7eee4fd28268b6844e6efc11"},
     "afmoe-test": {
         "prefill": "67ad0465620984a1cb42a5e6b1ee43fc7aa6a8c4b9df24264e844e35f431d1cf",
         "chunk": "ed47d8b83208e679f14f291d9f03a2c7c7848873a4322049c1b8a3ca1b821d7c",
@@ -540,6 +543,14 @@ PARENT_PROGRAMS = {
         "prefill": "33b858de91c4a2748df3675eec15924c3ee8f868cfe6ff3f55765cfb4bf7962e",
         "chunk": "89c51e9ff8ef4261d95c88b0e7e675a5aed36ca8c70a44fdc6a5496d87b89500",
         "decode": "2e8df2c387373d42292974342ec1a6fe93f49a0f635051ccbe3d164599d34968"},
+    # PR 52 (the scalar form's chunkwise body beside the channel form's, one
+    # `_mm` between them): this family's own programs and its chunkwise
+    # kernel, traced at PR 51's commit
+    "solar-open2-test": {
+        "prefill": "c1a45b31933be23126b274502c6c8552989f0ec993407bd882efa9353b2dea3c",
+        "chunk": "a75d48b141579bb353c3e373c73a1531e4142fee972b97e23c6ecda7b7d252e7",
+        "decode": "9165bde125bd9beaea06373154ad61b32768c634bf01fba2f92dc313bf3d36e6",
+        "kernel_chunk": "153b3235c94f9f243fca7032537d89347711779606e4952df4d3c51b06ed933e"},
 }
 
 
@@ -567,11 +578,17 @@ def _step_programs(model):
                                              ctx_pages=4, k=2))(
                 engine.params, engine.kv, rows, rows, rows, rows + 1, rows,
                 jnp.full((B, 4), -1), samp, key)}
+    f32 = lambda *s: jnp.zeros(s, jnp.float32)
+    if model == "solar-open2-test":     # the channel form's chunkwise body
+        i32, H, d, S = jnp.zeros((2,), jnp.int32), 8, 128, 128
+        out["kernel_chunk"] = jax.make_jaxpr(partial(
+            gated_delta.gated_delta_pallas, layer=1, interpret=True))(
+            f32(2, S, H, d), f32(2, S, H, d), f32(2, S, H, d), f32(2, S, H, d),
+            f32(2, S, H), f32(2, 5, d, H * d), i32, i32, i32)
     if model != "olmo-hybrid-test":
         return out
     H, dk, dv = 4, 16, 192
     for name, S in (("kernel_step", 1), ("kernel_chunk", 128)):
-        f32 = lambda *s: jnp.zeros(s, jnp.float32)
         i32 = jnp.zeros((3,), jnp.int32)
         out[name] = jax.make_jaxpr(partial(
             gated_delta.gated_delta_pallas, layer=1, interpret=True))(

@@ -233,9 +233,12 @@ async def test_gateway_http_span_is_ancestor_of_llm_request():
         # expert step on either formulation
         resp = await gateway.get("/admin/engine/stats", auth=auth)
         assert resp.status == 200
-        assert (await resp.json())["moe"] == {
+        body = await resp.json()
+        assert body["moe"] == {
             "tokens": 0, "local_pairs": 0, "grouped_steps": 0,
             "scan_steps": 0}
+        # nor a delta-rule body: it keeps no state a sequence
+        assert body["delta_rule"] == {"chunkwise_steps": 0, "walk_steps": 0}
 
         # profiler capture is opt-in: default-off config gates it
         resp = await gateway.post("/admin/engine/profile/start", auth=auth)
