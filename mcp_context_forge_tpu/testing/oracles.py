@@ -312,6 +312,18 @@ def quantize_oracle(mod: types.ModuleType) -> None:
     np.testing.assert_allclose(np.asarray(mod.qmm_t(xt, jnp.asarray(emb))),
                                np.asarray(xt) @ emb.T, rtol=1e-6)
 
+    # ... left in ``out_dtype``: float32 logits from bfloat16 operands (the
+    # values of the bfloat16 inputs, no rounding of the product), quantized
+    # and plain, over a [B, S, D] input too
+    xb = jnp.asarray(np.array([[[1.0, -1.0], [0.5, 3.0]]], np.float32),
+                     jnp.bfloat16)
+    for table, want in ((leaf_e, recon_e), (jnp.asarray(emb), emb)):
+        out = mod.qmm_t(xb, table, jnp.float32)
+        assert out.dtype == jnp.float32 and out.shape == (1, 2, 3)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(xb, np.float32) @ want.T, rtol=1e-6)
+    assert mod.qmm_t(xb, leaf_e).dtype == jnp.bfloat16     # without it: x's
+
     # gather: quantized rows reconstruct; plain rows pass through exactly
     rows = np.asarray(mod.embed_rows(leaf_e, jnp.asarray([2, 0])))
     np.testing.assert_allclose(rows, recon_e[[2, 0]], rtol=1e-5)

@@ -24,6 +24,7 @@ mirroring the reference's session-affinity routing (SURVEY.md §7.1 phase 4).
 
 from __future__ import annotations
 
+import gc
 import os
 import asyncio
 import logging
@@ -58,6 +59,7 @@ from .sampling import SamplingParams, sample_tokens
 from .tokenizer import load_tokenizer
 
 logger = logging.getLogger(__name__)
+_HEAP_FROZEN = False    # TPUEngine.warmup freezes the build's heap once
 
 # smoothing factor for the tokens-per-dispatch EWMA gauge twin (and the
 # signal-bus copy): ~last 10 dispatches dominate, long enough to ride out
@@ -1212,6 +1214,17 @@ class TPUEngine:
             self._warmup_impl(mode)
         finally:
             restore_thread(token)
+        # what the build left on the heap (every program's jaxpr and
+        # executable, the weights' wrappers) lives as long as the engine:
+        # out of the collector's reach, so that a full collection in traffic
+        # walks what traffic made and not the build. With fifteen step
+        # programs of 40 layers on the heap one full collection stopped the
+        # whole process for 4.2 s in mid-window (PERF.md section 6, PR 53)
+        global _HEAP_FROZEN
+        if not _HEAP_FROZEN:        # once a process: the first warm engine's
+            _HEAP_FROZEN = True
+            gc.collect()
+            gc.freeze()
 
     def _warmup_impl(self, mode: str | None = None) -> None:
         mode = mode or self.config.warmup_mode

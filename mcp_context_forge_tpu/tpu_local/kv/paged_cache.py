@@ -91,7 +91,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..models.configs import (AfmoeConfig, DeepseekConfig, LlamaConfig,
+from ..models.configs import (AfmoeConfig, DeepseekConfig,
+                              GraniteHybridConfig, LlamaConfig,
                               OlmoHybridConfig, SolarOpen2Config)
 from ..quantize import KV_SCALE_EPS, kv_dequantize, kv_int8_scale, kv_quantize
 
@@ -137,9 +138,11 @@ class PoolSpec(NamedTuple):
 
 
 AnyConfig = (LlamaConfig | DeepseekConfig | OlmoHybridConfig | AfmoeConfig
-             | SolarOpen2Config)
-# families whose linear layers keep a recurrent state and a convolution tail
-_DELTA_RULE = (OlmoHybridConfig, SolarOpen2Config)
+             | SolarOpen2Config | GraniteHybridConfig)
+# families some of whose layers keep a recurrent state and a convolution tail
+# a SEQUENCE (the delta rule's two, and the state-space family) beside the
+# K/V pages of their attending layers
+_STATE_AND_TAIL = (OlmoHybridConfig, SolarOpen2Config, GraniteHybridConfig)
 LANES = 128     # the minor dimension of the chip's tiles
 
 
@@ -166,8 +169,10 @@ def kv_pools(config: AnyConfig) -> tuple[PoolSpec, ...]:
         return (latent, PoolSpec("index_key", (config.index_head_dim,),
                                  config.n_cache_layers))
     heads = (config.n_kv_heads, config.head_dim)
-    if isinstance(config, _DELTA_RULE):
-        heads = (config.kv_pool_heads, config.head_dim)
+    if isinstance(config, _STATE_AND_TAIL):
+        # a family may store a head wider than it is (``kv_head_dim``)
+        heads = (config.kv_pool_heads,
+                 getattr(config, "kv_head_dim", config.head_dim))
         full = len(config.layers_of("full_attention"))
         linear = config.n_layers - full
         return (PoolSpec("k", heads, full), PoolSpec("v", heads, full),
@@ -262,7 +267,7 @@ class HybridKVState(NamedTuple):
 def _full_precision_only(config, quant: str) -> bool:
     """True for the families of :class:`HybridKVState` (which then refuse
     ``quant``)."""
-    hybrid = isinstance(config, (*_DELTA_RULE, AfmoeConfig))
+    hybrid = isinstance(config, (*_STATE_AND_TAIL, AfmoeConfig))
     if hybrid and quant:
         raise NotImplementedError(
             f"kv_quant={quant!r}: the hybrid family's pools are full "

@@ -11,7 +11,10 @@ layers that keep every page, a QK-normed gated attention, and dense or
 sigmoid-routed expert FFNs with a shared expert; ``solar_open2``: delta-rule
 layers whose decay is a vector a head (Kimi Delta Attention) beside gated
 un-rotated GQA layers, every layer routing over a HELD RANGE of many small
-experts beside a shared one) with
+experts beside a shared one; ``granite_hybrid``, the seventh: Mamba-2
+state-space layers (one group of B and C shared by all heads, a convolution
+with bias, a gated norm) beside un-rotated GQA layers by a LIST of mixer
+kinds, four multipliers and a tied head) with
 the same set of names: ``init_keys``, ``init_layer``,
 ``init_trunk``, ``params_logical``, ``param_count``, ``prefill``,
 ``prefill_with_history``, ``decode_step``, the cache's ``init_kv_state`` /
@@ -32,7 +35,9 @@ engine compiles one weight-init program a kind) that names the layer's FFN
 (``deepseek``: dense | experts), its MIXER (``olmo_hybrid``:
 linear_attention | full_attention) or BOTH (``afmoe``: window | full, dense |
 experts, as ``window.experts``; ``solar_open2``: ``gqa.experts`` |
-``kda.experts``); cache pools that only some layers hold,
+``kda.experts``), read from an interval, a tuple of layer indices or, in
+``granite_hybrid``, the model configuration's own list of kinds a layer
+(``mamba`` | ``attention``: no interval need hold); cache pools that only some layers hold,
 and pools of a fixed size a sequence beside the per-token ones
 (``kv/paged_cache.py: kv_pools``); with ``STEP_AUX``, a float32 vector of
 counts its step programs return beside the tokens (``engine._step_counts``:
@@ -40,7 +45,7 @@ counts its step programs return beside the tokens (``engine._step_counts``:
 rows]``, then ``[live state rows, real tokens scanned]`` from a family with a
 state a sequence, ``solar_open2`` filling both halves, then a window family's
 two key counts); ``delta_body(config, mesh, seq)``, a family with delta-rule
-layers saying which body of their kernel a prefill of ``seq`` positions a
+or state-space layers saying which body of their kernel a prefill of ``seq`` positions a
 row traces (``chunkwise`` | ``walk`` | None: the engine counts its prefill
 dispatches by it);
 and ``drafts_on_device(config) -> bool``: WHERE A SPECULATIVE DRAFT COMES FROM.
@@ -61,13 +66,15 @@ drafts are the engine's, the same for both answers (docs/adr/008)."""
 from importlib import import_module
 from types import ModuleType
 
-from .configs import (AfmoeConfig, DeepseekConfig, EncoderConfig, LlamaConfig,
-                      OlmoHybridConfig, SdarConfig, SolarOpen2Config,
-                      ENCODER_CONFIGS, MODEL_CONFIGS)
+from .configs import (AfmoeConfig, DeepseekConfig, EncoderConfig,
+                      GraniteHybridConfig, LlamaConfig, OlmoHybridConfig,
+                      SdarConfig, SolarOpen2Config, ENCODER_CONFIGS,
+                      MODEL_CONFIGS)
 
 _FAMILY_MODULES = {LlamaConfig: "llama", DeepseekConfig: "deepseek",
                    OlmoHybridConfig: "olmo_hybrid", SdarConfig: "sdar",
-                   AfmoeConfig: "afmoe", SolarOpen2Config: "solar_open2"}
+                   AfmoeConfig: "afmoe", SolarOpen2Config: "solar_open2",
+                   GraniteHybridConfig: "granite_hybrid"}
 
 
 def family_of(model_config) -> ModuleType:
@@ -79,6 +86,7 @@ def family_of(model_config) -> ModuleType:
 
 
 __all__ = ["LlamaConfig", "DeepseekConfig", "OlmoHybridConfig",
-           "SdarConfig", "AfmoeConfig", "SolarOpen2Config", "EncoderConfig",
+           "SdarConfig", "AfmoeConfig", "SolarOpen2Config",
+           "GraniteHybridConfig", "EncoderConfig",
            "MODEL_CONFIGS",
            "ENCODER_CONFIGS", "family_of"]

@@ -419,8 +419,111 @@ class SolarOpen2Config:
                                       + self.linear_value_dim)
 
 
+@dataclass(frozen=True)
+class GraniteHybridConfig:
+    """Geometry of the hybrid family whose layers are Mamba-2 state-space
+    mixers or un-rotated GQA by a LIST (``granitemoehybrid`` ``config.json``
+    keys in brackets). Layer ``i``'s mixer is ``layer_types[i]``
+    [layer_types]: ``mamba`` | ``attention``. An attention layer has
+    ``n_heads`` query and ``n_kv_heads`` kv heads of ``head_dim``, no rotary
+    embedding [position_embedding_type nope], no bias, and softmax at
+    ``attention_multiplier`` (NOT ``head_dim^-0.5``). A Mamba layer has
+    ``mamba_n_heads`` heads of ``mamba_head_dim`` channels [mamba_n_heads,
+    mamba_d_head; their product is mamba_expand x hidden_size], ONE group of
+    ``mamba_d_state`` B and C channels that all heads share [mamba_n_groups
+    1], a causal depthwise convolution with bias of ``conv_kernel`` taps
+    [mamba_d_conv, mamba_conv_bias] over x, B and C together, and keeps a
+    ``mamba_d_state`` x ``mamba_n_heads * mamba_head_dim`` float32 state a
+    SEQUENCE beside the last ``conv_kernel - 1`` pre-convolution inputs; it
+    keeps nothing a token. Every layer: a pre-norm residual block whose two
+    sublayers add ``residual_multiplier`` times their output, a dense SwiGLU
+    MLP of ``ffn_hidden`` [shared_intermediate_size; num_local_experts 0].
+    The embedding is times ``embedding_multiplier``, the logits are divided
+    by ``logits_scaling``, and the head is the embedding [tie_word_embeddings].
+    The field names the cache and the state-row plumbing read are
+    :class:`OlmoHybridConfig`'s (``linear_*``, ``conv_dim``, ``layers_of``)."""
+
+    name: str
+    vocab_size: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    ffn_hidden: int
+    mamba_n_heads: int
+    mamba_head_dim: int
+    mamba_d_state: int
+    layer_types: tuple[str, ...]
+    conv_kernel: int = 4
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-5
+    max_seq_len: int = 131_072
+    hidden_act: str = "silu"
+
+    def __post_init__(self) -> None:
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad or len(self.layer_types) != self.n_layers:
+            raise ValueError(f"{self.name}: layer_types must name {self.n_layers} "
+                             f"mixers, each mamba or attention; got {self.layer_types}")
+
+    def mixer_kind(self, layer: int) -> str:
+        """``linear_attention`` (a Mamba-2 layer: a state a sequence) |
+        ``full_attention``, the names ``kv/paged_cache.py: kv_pools`` counts
+        layers by."""
+        return ("full_attention" if self.layer_types[layer] == "attention"
+                else "linear_attention")
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.mixer_kind(i) == kind)
+
+    @property
+    def kv_pool_heads(self) -> int:
+        """kv heads a K/V page holds (:class:`OlmoHybridConfig`'s name)."""
+        return self.n_kv_heads
+
+    @property
+    def kv_head_dim(self) -> int:
+        """Lanes a K or V head is STORED in, and attended over: whole
+        128-lane tiles, a zero tail past ``head_dim`` (64 -> 128). A pool whose
+        minor dimension is half a tile is kept compressed by the chip's
+        compiler, which then copies it whole around every write and read (read
+        on the chip: ``PERF.md`` section 6, PR 53), and the flash and paged
+        kernels take whole tiles only. The scores and the first ``head_dim``
+        output lanes are what the narrow head's would be."""
+        return -(-self.head_dim // 128) * 128
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of x, z and the state's lane axis: heads x head_dim."""
+        return self.mamba_n_heads * self.mamba_head_dim
+
+    # the state pool's geometry under the names the cache reads
+    @property
+    def linear_n_heads(self) -> int:
+        return self.mamba_n_heads
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.mamba_d_state
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C (one group)."""
+        return self.mamba_inner + 2 * self.mamba_d_state
+
+
 MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
-                    | SdarConfig | AfmoeConfig | SolarOpen2Config] = {
+                    | SdarConfig | AfmoeConfig | SolarOpen2Config
+                    | GraniteHybridConfig] = {
     # Llama-3-8B geometry (the BASELINE.json flagship)
     "llama3-8b": LlamaConfig(
         name="llama3-8b", vocab_size=128_256, dim=4096, n_layers=32,
@@ -551,6 +654,17 @@ MODEL_CONFIGS: dict[str, LlamaConfig | DeepseekConfig | OlmoHybridConfig
         n_experts=16, experts_held=(4, 8), moe_top_k=4, linear_n_heads=4,
         linear_key_dim=16, linear_value_dim=16, gate_rank=16,
         gqa_layers=(0, 4), max_seq_len=512, moe_impl="grouped", moe_block=8),
+    # the Mamba-2 / un-rotated GQA family at CI scale, with the published
+    # RATIOS: one group of B and C, heads x head_dim = 2 x dim, attention at an
+    # interior position of each period, a tied head, the four multipliers
+    "granite-hybrid-test": GraniteHybridConfig(
+        name="granite-hybrid-test", vocab_size=512, dim=64, n_layers=8,
+        n_heads=4, n_kv_heads=2, head_dim=16, ffn_hidden=128,
+        mamba_n_heads=8, mamba_head_dim=16, mamba_d_state=16,
+        layer_types=("mamba", "mamba", "attention", "mamba") * 2,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0625, logits_scaling=8.0,
+        max_seq_len=512),
 }
 
 

@@ -288,32 +288,51 @@ def state_rows(valid: jax.Array, positions: jax.Array, kv: HybridKVState,
     return rows, counts, positions[:, 0] <= 0
 
 
-def conv_qkv(layer: dict[str, Any], config, ordinal: int, x: jax.Array,
-             rows: jax.Array, counts: jax.Array, fresh: jax.Array,
-             kv: HybridKVState):
-    """A delta-rule mixer's q, k, v of x [B, S, D]: the three projections
-    through the causal depthwise convolution (continued from the rows' stored
-    tails, which are replaced by the last REAL inputs) and SiLU, q and k
-    L2-normed a head. -> (q [B, S, H, dk] scaled by dk^-0.5, k, v [B, S, H,
-    dv], kv). ``ordinal``: the layer's index among the linear layers."""
-    c = config
-    B, S, _ = x.shape
-    H, dk, dv = c.linear_n_heads, c.linear_key_dim, c.linear_value_dim
-    taps = c.conv_kernel - 1
-    raw = jnp.concatenate([qmm(x, layer["wq"]), qmm(x, layer["wk"]),
-                           qmm(x, layer["wv"])], axis=-1)        # [B, S, C]
+def conv_with_tail(raw: jax.Array, weight: jax.Array, bias: jax.Array | None,
+                   conv_kernel: int, ordinal: int, rows: jax.Array,
+                   counts: jax.Array, fresh: jax.Array, kv: HybridKVState):
+    """The causal depthwise convolution of raw [B, S, C] over time, CONTINUED
+    from the rows' stored tails (zero under ``fresh``) and through SiLU, in
+    float32: the sum over ``conv_kernel`` taps of weight [taps, C], plus
+    ``bias`` [C] where the family's convolution has one. The rows' tails are
+    replaced by the last REAL inputs. -> (SiLU(conv) [B, S, C] float32, kv).
+    Every family with a convolution tail calls it (this one and
+    ``models/solar_open2.py`` through :func:`conv_qkv`,
+    ``models/granite_hybrid.py`` with its bias)."""
+    S = raw.shape[1]
+    taps = conv_kernel - 1
     tail = jnp.where(fresh[:, None, None], 0,
                      kv.conv_tail[ordinal, rows]).astype(raw.dtype)
     padded = jnp.concatenate([tail, raw], axis=1)                # [B, taps + S, C]
-    weight = layer["conv"].astype(jnp.float32)
+    weight = weight.astype(jnp.float32)
     conv = sum(weight[i] * padded[:, i:i + S].astype(jnp.float32)
-               for i in range(c.conv_kernel))
+               for i in range(conv_kernel))
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
     conv = jax.nn.silu(conv)
     # the last ``taps`` REAL inputs: entries counts .. counts + taps - 1
     last = counts[:, None] + jnp.arange(taps)[None, :]           # [B, taps]
     new_tail = jnp.take_along_axis(padded, last[:, :, None], axis=1)
     kv = kv._replace(conv_tail=kv.conv_tail.at[ordinal, rows].set(
         new_tail.astype(kv.conv_tail.dtype)))
+    return conv, kv
+
+
+def conv_qkv(layer: dict[str, Any], config, ordinal: int, x: jax.Array,
+             rows: jax.Array, counts: jax.Array, fresh: jax.Array,
+             kv: HybridKVState):
+    """A delta-rule mixer's q, k, v of x [B, S, D]: the three projections
+    through the causal depthwise convolution (:func:`conv_with_tail`, no
+    bias), q and k L2-normed a head. -> (q [B, S, H, dk] scaled by dk^-0.5,
+    k, v [B, S, H, dv], kv). ``ordinal``: the layer's index among the linear
+    layers."""
+    c = config
+    B, S, _ = x.shape
+    H, dk, dv = c.linear_n_heads, c.linear_key_dim, c.linear_value_dim
+    raw = jnp.concatenate([qmm(x, layer["wq"]), qmm(x, layer["wk"]),
+                           qmm(x, layer["wv"])], axis=-1)        # [B, S, C]
+    conv, kv = conv_with_tail(raw, layer["conv"], None, c.conv_kernel, ordinal,
+                              rows, counts, fresh, kv)
     q, k, v = jnp.split(conv, [H * dk, 2 * H * dk], axis=-1)
     q = _l2norm(q.reshape(B, S, H, dk)) * dk ** -0.5
     k = _l2norm(k.reshape(B, S, H, dk))

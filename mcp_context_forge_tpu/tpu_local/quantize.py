@@ -97,10 +97,19 @@ def qmm(x: jax.Array, w: Any) -> jax.Array:
     return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
 
 
-def qmm_t(x: jax.Array, w: Any) -> jax.Array:
+def qmm_t(x: jax.Array, w: Any, out_dtype: Any = None) -> jax.Array:
     """x @ W.T (tied lm head: embed is (vocab, dim), logits need dim->vocab).
     Per-row scales of the embedding become per-COLUMN scales of the head,
-    so they still apply to the output: (x @ q.T) * s."""
+    so they still apply to the output: (x @ q.T) * s. ``out_dtype``: the
+    product is accumulated AND LEFT in that dtype (float32 logits from
+    bfloat16 operands: the MXU accumulates in float32 anyway, and a logit
+    near 8 rounded to bfloat16 is 0.03 off) and the scales applied in it."""
+    if out_dtype is not None:
+        q, s = (w["q"], w["s"]) if is_quant(w) else (w, None)
+        out = jax.lax.dot_general(x, q.astype(x.dtype),
+                                  (((x.ndim - 1,), (1,)), ((), ())),
+                                  preferred_element_type=out_dtype)
+        return out if s is None else out * s.astype(out_dtype)
     if not is_quant(w):
         return x @ w.T
     return (x @ w["q"].T.astype(x.dtype)) * w["s"].astype(x.dtype)

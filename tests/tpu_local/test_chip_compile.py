@@ -27,7 +27,8 @@ import pytest
 
 from mcp_context_forge_tpu.tpu_local.kv import (LatentKVState, stored_width,
                                                 write_latent_kv)
-from mcp_context_forge_tpu.tpu_local.ops import attention, gated_delta, grouped_moe
+from mcp_context_forge_tpu.tpu_local.ops import (attention, gated_delta,
+                                                 grouped_moe, ssd)
 from mcp_context_forge_tpu.tpu_local.ops import mla_attention as mla
 from mcp_context_forge_tpu.tpu_local.ops import paged_attention as paged
 
@@ -209,6 +210,25 @@ def _kda_case(batch: int, seq: int):
     return partial(gated_delta.gated_delta_pallas, layer=3), shapes
 
 
+def _ssd_case(batch: int, seq: int):
+    """granite-4.0-h-micro.longgen-open: 64 heads of 64 over ONE group of 128
+    B and C channels, a 128 x 4096 float32 state a row, 36 Mamba layers x 65
+    rows; decode steps of 64 rows (``ssd_step``: a row's 2.1 MB state in and
+    out, double-buffered, beside two [2, 4096] token rows: 8.5 MB), prefills
+    of up to 4 x 512 and the half program's 1 x 256 (``ssd_chunk``: the state
+    in and out double-buffered 8.4 MB, a chunk's [64, 4096] x, y and dt x
+    blocks 5 MB, the 0/1 spread matrix 2 MB, ~7 MB of [64, 4096] and
+    [192, 4096] temporaries: ~22 MB against the limit of 48). A pass here is
+    not the chip's word on scoped VMEM (``_kda_case``): the cell's warm-up
+    compiles both inside its step programs on the chip."""
+    H, P, N = 64, 64, 128
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [((batch, seq, H * P), f32), ((batch, seq, H), f32), ((H,), f32)] \
+        + [((batch, seq, N), f32)] * 2 + [((36, 65, N, H * P), f32)] \
+        + [((batch,), i32)] * 3
+    return partial(ssd.ssd_pallas, layer=7), shapes
+
+
 def _solar_moe_case(tokens: int, block: int):
     """The row-block kernel over the 40 held int8 experts of 4096 x 1280: a
     step's plan holds a block for every one of its tokens x 8 pairs (any may
@@ -238,6 +258,22 @@ def _solar_paged_case(chunk: int | None, batch: int):
                 + [((batch,), jnp.int32)])
     return (partial(paged.paged_chunk_attention_pallas, layer=1),
             [((batch, chunk, 8, 8, HD), jnp.bfloat16)] + shapes
+            + [((batch, chunk), jnp.int32)])
+
+
+def _granite_paged_case(chunk: int | None, batch: int):
+    """granite-4.0-h-micro.longgen-open: the paged kernel over the 4 attention
+    layers' 1025 pages, 8 kv heads x 4 query heads of 64 STORED in 128 lanes
+    (``GraniteHybridConfig.kv_head_dim``), the 16-page table of a 2048-token
+    sequence, at the decode width of 64 and a chunk round's 512-query tile."""
+    pool = ((4, 1025, PAGE, 8, HD), jnp.bfloat16)
+    shapes = [pool, pool, ((batch, 16), jnp.int32)]
+    if chunk is None:
+        return (partial(paged.paged_decode_attention_pallas, layer=2),
+                [((batch, 8, 4, HD), jnp.bfloat16)] + shapes
+                + [((batch,), jnp.int32)])
+    return (partial(paged.paged_chunk_attention_pallas, layer=2),
+            [((batch, chunk, 8, 4, HD), jnp.bfloat16)] + shapes
             + [((batch, chunk), jnp.int32)])
 
 
@@ -410,6 +446,14 @@ KERNEL_CASES = {
     "grouped_moe_int8_solar_chunk_2x1024_b64": lambda: _solar_moe_case(2048, 64),
     "paged_decode_bf16_solar_32x132": lambda: _solar_paged_case(None, 32),
     "paged_chunk_bf16_solar_tile256x132": lambda: _solar_paged_case(256, 2),
+    # granite-4.0-h-micro.longgen-open (PR 53): the state-space recurrence's
+    # two kernels at the cell's decode width, its widest prefill and its half;
+    # the paged kernel over its lane-padded heads at width 64 and a chunk tile
+    "paged_decode_bf16_granite_64x16": lambda: _granite_paged_case(None, 64),
+    "paged_chunk_bf16_granite_tile512x16": lambda: _granite_paged_case(512, 4),
+    "ssd_step_cell_64": lambda: _ssd_case(64, 1),
+    "ssd_chunk_cell_4x512": lambda: _ssd_case(4, 512),
+    "ssd_chunk_half_1x256": lambda: _ssd_case(1, 256),
 }
 
 
