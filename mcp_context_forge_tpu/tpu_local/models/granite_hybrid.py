@@ -53,6 +53,17 @@ XLA paths ran, and the chip's compiler kept the half-tile pools compressed
 and copied them whole around every layer's write and gather: a decode step of
 91 ms (PERF.md section 6, PR 53; section 7: a kernel for narrower heads).
 
+**A decode token is a ROW.** From the in-projections to ``W_o`` a decode
+step's one token a sequence is a row of a ``[B, C]`` array with the batch on
+the sublanes: ``ssd_step`` takes and returns ``[B, 4096]`` arrays in blocks of
+8 rows (``ops/ssd.py``, "ssd_step": why a ``[B, 1, C]`` operand would put
+every array around the call in one-sublane tiles), the head scalars are spread
+over their lanes by one exact 0/1 product for all rows, the convolution takes
+its four taps as four ``[B, C]`` terms (``olmo_hybrid.conv_with_tail`` at
+``S == 1``), and the skip, the gate, the norm and the cast run on what the
+kernel returns, outside it. ``tests/tpu_local/test_chip_compile.py`` compiles
+the step for a described v5e and looks for a narrow tile.
+
 Every step function also returns a float32 vector of counts (``STEP_AUX``,
 laid out as ``models/olmo_hybrid.py``'s): zeros for the expert and selector
 counts, the rows, then the live state rows and the real tokens scanned.

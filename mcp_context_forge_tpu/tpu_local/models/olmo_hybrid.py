@@ -303,16 +303,33 @@ def conv_with_tail(raw: jax.Array, weight: jax.Array, bias: jax.Array | None,
     taps = conv_kernel - 1
     tail = jnp.where(fresh[:, None, None], 0,
                      kv.conv_tail[ordinal, rows]).astype(raw.dtype)
-    padded = jnp.concatenate([tail, raw], axis=1)                # [B, taps + S, C]
-    weight = weight.astype(jnp.float32)
-    conv = sum(weight[i] * padded[:, i:i + S].astype(jnp.float32)
-               for i in range(conv_kernel))
+    if S == 1:
+        # a decode token: the window is the tail's rows and the one input, each
+        # a [B, C] term with the batch on the sublanes (a [B, taps + 1, C]
+        # concatenation puts four rows in a tile of eight on the chip, and
+        # every slice of it inherits that)
+        weight = weight.astype(jnp.float32)
+        window = [tail[:, i] for i in range(taps)] + [raw[:, 0]]
+        conv = sum(weight[i] * window[i].astype(jnp.float32)
+                   for i in range(conv_kernel))[:, None]
+        # entries counts .. counts + taps - 1 of that window: the tail as it
+        # was (counts 0), or moved up by the one real input
+        new_tail = jnp.where((counts > 0)[:, None, None],
+                             jnp.stack(window[1:], axis=1), tail)
+    else:
+        # many tokens: equation for equation what it was before the branch
+        # (the accepted families' prefill and chunk programs are pinned)
+        padded = jnp.concatenate([tail, raw], axis=1)            # [B, taps + S, C]
+        weight = weight.astype(jnp.float32)
+        conv = sum(weight[i] * padded[:, i:i + S].astype(jnp.float32)
+                   for i in range(conv_kernel))
     if bias is not None:
         conv = conv + bias.astype(jnp.float32)
     conv = jax.nn.silu(conv)
-    # the last ``taps`` REAL inputs: entries counts .. counts + taps - 1
-    last = counts[:, None] + jnp.arange(taps)[None, :]           # [B, taps]
-    new_tail = jnp.take_along_axis(padded, last[:, :, None], axis=1)
+    if S > 1:
+        # the last ``taps`` REAL inputs: entries counts .. counts + taps - 1
+        last = counts[:, None] + jnp.arange(taps)[None, :]       # [B, taps]
+        new_tail = jnp.take_along_axis(padded, last[:, :, None], axis=1)
     kv = kv._replace(conv_tail=kv.conv_tail.at[ordinal, rows].set(
         new_tail.astype(kv.conv_tail.dtype)))
     return conv, kv

@@ -19,16 +19,31 @@ state lies, ``[B, S, H * P]``: the mixer's ``x`` is a slice of the
 convolution's flat output and its ``y`` is gated and normed flat.
 
 **ssd_step** (decode, one token a row). A grid step holds one row's whole
-``[N, H P]`` state in VMEM, decays a head's lanes by its scalar (``a``
-arrives spread over the lanes), adds the rank-one ``B (dt x)^T`` and
-contracts the sublanes with ``C``, all on the VPU in float32: ~3 MFLOP
-against 2 x 2.1 MB at the published widths, bound by HBM. ``B`` and ``C``
-arrive as lane rows and reach the sublanes through the diagonal of a
+``[N, H P]`` state in VMEM, decays a head's lanes by its scalar, adds the
+rank-one ``B (dt x)^T`` and contracts the sublanes with ``C``, all on the VPU
+in float32: ~3 MFLOP against 2 x 2.1 MB at the published widths, bound by
+HBM. **What a row brings and takes are 2-D arrays** ``[B, H P]`` (``x``, the
+decay and ``dt`` spread over the lanes, ``y``) and ``[B, N]`` (``B``, ``C``),
+moved in blocks of ``ROWS`` = 8 rows: the block index is ``b // 8``, so eight
+consecutive grid steps name one block, which is fetched and written back once,
+and a step picks its row with a dynamic sublane slice (``b % 8``); ``dt x`` is
+formed in the kernel. A width that is not whole blocks is padded with idle
+steps. **A ``[B, 1, C]`` operand is refused by design**: a Mosaic call's
+operands keep their row-major layout, so the one token would be the
+second-minor dimension, the compiler would tile the array ``T(1,128)`` (one
+sublane of a vector register's eight) and every fusion around the call would
+inherit the tile (3.3 ms of a 17.7 ms decode step on the chip: PERF.md
+section 6, PR 54). A head's scalar reaches its ``P`` lanes OUTSIDE the kernel,
+for all rows at once, through one product with a 0/1 matrix (``_spread``) at
+``Precision.HIGHEST``: exact, because a column has one non-zero and the
+three bfloat16 pieces of a float32 add back to it; the same product inside a
+grid step would push the 1 MB matrix through the MXU once a row. ``B`` and
+``C`` arrive as lane rows and reach the sublanes through the diagonal of a
 ``[N, N]`` select, exactly. **A row on the trash row moves no state**: its
 grid step names the state block of the live row before it (the one after it
 for the first rows), which is therefore neither fetched nor written back
-again, and does nothing but zero its output; with no live row at all the one
-trash block is copied through.
+again, and does nothing but zero its row of the output; with no live row at
+all the one trash block is copied through.
 
 **ssd_chunk** (prefill, chunks of ``CHUNK`` = 64 tokens, every exponent <=
 0). With ``gamma`` the inclusive cumulative sum of ``dt A`` a head inside the
@@ -77,6 +92,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .gated_delta import _mm     # a float32 product at Precision.HIGHEST
 
 CHUNK = 64                 # tokens a chunk, kernel and twin
+ROWS = 8                   # decode rows a block of ssd_step: a tile's sublanes
 _VMEM_LIMIT = 48 << 20
 _HI = jax.lax.Precision.HIGHEST
 
@@ -187,6 +203,13 @@ def live_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
     return rows[named].astype(jnp.int32), live.astype(jnp.int32)
 
 
+def _spread(n_heads: int, head_dim: int) -> jax.Array:
+    """[H, H P] 0/1: a product with it (``_mm``) puts a head's scalar on each
+    of the head's lanes, exactly: one non-zero a column."""
+    lanes = jnp.arange(n_heads * head_dim)[None, :] // head_dim
+    return (lanes == jnp.arange(n_heads)[:, None]).astype(jnp.float32)
+
+
 def _column(row, n: int):
     """A lane row [1, n] as a sublane column [n, 1], exactly: the diagonal of
     a select."""
@@ -195,24 +218,28 @@ def _column(row, n: int):
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
-def _step_kernel(named_ref, live_ref, fresh_ref, layer_ref, tok_ref, bc_ref,
-                 s_in_ref, y_ref, s_out_ref):
+def _step_kernel(named_ref, live_ref, fresh_ref, layer_ref, x_ref, decay_ref,
+                 dt_ref, b_ref, c_ref, s_in_ref, y_ref, s_out_ref):
+    """One decode row. x, decay, dt, y: the [ROWS, H P] block the row lies in;
+    b, c: [ROWS, N]; the row is sublane ``b % ROWS`` of each."""
     del named_ref, layer_ref                        # ride the index maps
     b = pl.program_id(0)
     n = s_in_ref.shape[0]
+    row = pl.ds(b % ROWS, 1)
 
     @pl.when(live_ref[b] > 0)
     def _():
-        decay, dtx = tok_ref[0, 0:1, :], tok_ref[0, 1:2, :]      # [1, H P]
-        b_col = _column(bc_ref[0, 0:1, :], n)
-        c_col = _column(bc_ref[0, 1:2, :], n)
+        decay = decay_ref[row, :]                                # [1, H P]
+        dtx = dt_ref[row, :] * x_ref[row, :]
+        b_col = _column(b_ref[row, :], n)
+        c_col = _column(c_ref[row, :], n)
         S = jnp.where(fresh_ref[b] > 0, 0.0, s_in_ref[...]) * decay + b_col * dtx
         s_out_ref[...] = S
-        y_ref[0] = jnp.sum(S * c_col, axis=0, keepdims=True)
+        y_ref[row, :] = jnp.sum(S * c_col, axis=0, keepdims=True)
 
     @pl.when(live_ref[b] <= 0)
     def _():
-        y_ref[...] = jnp.zeros_like(y_ref)
+        y_ref[row, :] = jnp.zeros((1, y_ref.shape[1]), y_ref.dtype)
 
     @pl.when((live_ref[b] <= 0) & (b == 0))
     def _():
@@ -222,33 +249,42 @@ def _step_kernel(named_ref, live_ref, fresh_ref, layer_ref, tok_ref, bc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_call(tok, bc, pool, named, live, fresh, layer, *, interpret: bool):
-    B, _, HP = tok.shape
-    N = bc.shape[-1]
+def _step_call(x, over, b, c, pool, named, live, fresh, layer, *,
+               interpret: bool):
+    """x [B, H P], b, c [B, N] float32, B whole blocks of ``ROWS``; over
+    [2 B, H P]: the rows' decay, then their dt, spread over the lanes (one
+    array read through two block maps)."""
+    B, HP = x.shape
+    N = b.shape[-1]
     row_spec = pl.BlockSpec((None, None, N, HP),
                             lambda b, named, live, fresh, layer:
                             (layer[0], named[b], 0, 0))
-    tok_map = lambda b, *_: (b, 0, 0)
+    # eight consecutive grid steps name one block: fetched, and written back,
+    # once
+    block = lambda b, *_: (b // ROWS, 0)
+    lanes, group = pl.BlockSpec((ROWS, HP), block), pl.BlockSpec((ROWS, N), block)
     return pl.pallas_call(
         _step_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B,),
-            in_specs=[pl.BlockSpec((1, 2, HP), tok_map),
-                      pl.BlockSpec((1, 2, N), tok_map), row_spec],
-            out_specs=[pl.BlockSpec((1, 1, HP), tok_map), row_spec],
+            in_specs=[lanes, lanes,
+                      pl.BlockSpec((ROWS, HP),
+                                   lambda b, *_: (B // ROWS + b // ROWS, 0)),
+                      group, group, row_spec],
+            out_specs=[lanes, row_spec],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, 1, HP), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((B, HP), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # operand 6 of the call (four prefetched scalars, tok, bc) is the
-        # pool: updated in place
-        input_output_aliases={6: 1},
+        # operand 9 of the call (four prefetched scalars, x, over twice, b,
+        # c) is the pool: updated in place
+        input_output_aliases={9: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         name="ssd_step",
         interpret=interpret,
-    )(named, live, fresh, layer, tok, bc, pool)
+    )(named, live, fresh, layer, x, over, over, b, c, pool)
 
 
 def _chunk_kernel(named_ref, live_ref, count_ref, fresh_ref, layer_ref, x_ref,
@@ -376,16 +412,24 @@ def ssd_pallas(x, dt, a_log, b, c, pool, rows, counts, fresh, *, layer: int,
         raise ValueError(f"{H} heads of {P} over a state of {N} rows: the "
                          f"kernels take an even number of heads of {CHUNK}")
     f32 = jnp.float32
-    named, live = live_rows(jnp.where(counts > 0, rows, 0))
-    fresh, layer_no = fresh.astype(jnp.int32), jnp.full((1,), layer, jnp.int32)
+    layer_no = jnp.full((1,), layer, jnp.int32)
+    rows = jnp.where(counts > 0, rows, 0)
     dt = dt.astype(f32)
     if S == 1:
-        over = lambda v: jnp.repeat(v, P, axis=-1)               # [B, H P]
+        # a width that is not whole blocks gets idle steps up to one (trash
+        # row, nothing moved: live_rows)
+        wide = lambda v: jnp.pad(v, [(0, -B % ROWS)] + [(0, 0)] * (v.ndim - 1))
+        named, live = live_rows(wide(rows))
         decay = jnp.exp(dt[:, 0] * -jnp.exp(a_log.astype(f32)))
-        tok = jnp.stack([over(decay), over(dt[:, 0]) * x[:, 0].astype(f32)], axis=1)
-        bc = jnp.stack([b[:, 0], c[:, 0]], axis=1).astype(f32)
-        return _step_call(tok, bc, pool, named, live, fresh, layer_no,
-                          interpret=interpret)
+        over = _mm(jnp.concatenate([wide(decay), wide(dt[:, 0])], axis=0),
+                   _spread(H, P))                                # [2 B, H P]
+        tok = lambda v: wide(v[:, 0].astype(f32))
+        y, pool = _step_call(tok(x), over, tok(b), tok(c), pool, named, live,
+                             wide(fresh.astype(jnp.int32)), layer_no,
+                             interpret=interpret)
+        return y[:B, None], pool
+    named, live = live_rows(rows)
+    fresh = fresh.astype(jnp.int32)
     if S % CHUNK:
         raise ValueError(f"S={S} must be whole chunks of {CHUNK}")
     gamma, after = chunk_decays(dt, a_log)
@@ -393,7 +437,6 @@ def ssd_pallas(x, dt, a_log, b, c, pool, rows, counts, fresh, *, layer: int,
     # a pair of heads' gamma as lane rows: [B * chunks, H / 2, 2 L]
     pairs = gamma.reshape(B * (S // CHUNK), CHUNK, H // 2, 2).transpose(0, 2, 3, 1)
     pairs = pairs.reshape(B * (S // CHUNK), H // 2, 2 * CHUNK)
-    spread = (jnp.arange(HP)[None, :] // P == jnp.arange(H)[:, None]).astype(f32)
-    return _chunk_call(x, scal, pairs, jnp.stack([b, c], axis=1), spread, pool,
-                       named, live, counts.astype(jnp.int32), fresh, layer_no,
-                       interpret=interpret)
+    return _chunk_call(x, scal, pairs, jnp.stack([b, c], axis=1), _spread(H, P),
+                       pool, named, live, counts.astype(jnp.int32), fresh,
+                       layer_no, interpret=interpret)

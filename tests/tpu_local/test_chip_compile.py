@@ -15,10 +15,12 @@ and the check that the script refuses to run without a TPU.
 import asyncio
 import dataclasses
 import json
+import math
 import os
 import re
 import subprocess
 import sys
+import unittest.mock as mock
 from functools import partial
 
 import jax
@@ -696,6 +698,106 @@ def test_window_family_decode_step_compiles_grouped_for_v5e(v5e):
     # what the check tells apart: the same step on the scan
     scan = lowered(dataclasses.replace(cfg, moe_impl="dense"))
     assert "grouped_moe_q8" not in scan and len(stack_loops(scan)) == routed
+
+
+_TILED = re.compile(r"(\w+)\[([\d,]+)\]\{[\d,]*:T\((\d+),128\)")
+
+
+def _narrow_tiles(text: str, least: int = 64 * 4096) -> set[tuple[str, tuple, int]]:
+    """(dtype, shape, sublanes a tile) of every array of ``least`` elements or
+    more that an instruction of the compiled module's ENTRY computation
+    yields in tiles of fewer than 8 sublanes (``T(1,128)``, ``T(2,128)``,
+    ``T(4,128)``): a vector register is 8 sublanes by 128 lanes, and every
+    load of such an array fills that share of it."""
+    entry = text[text.index("\nENTRY "):]
+    found = set()
+    for line in entry.splitlines()[1:]:
+        name, eq, rest = line.partition(" = ")
+        if not eq:
+            continue
+        # the result's shape, or the tuple of them: what stands before the
+        # operation's name and its operands
+        result = rest[:rest.index(") ") + 1] if rest.startswith("(") else \
+            rest.split(" ", 1)[0]
+        for dtype, dims, sublanes in _TILED.findall(result):
+            shape = tuple(int(d) for d in dims.split(","))
+            if math.prod(shape) >= least and int(sublanes) < 8:
+                found.add((dtype, shape, int(sublanes)))
+    return found
+
+
+def test_granite_decode_step_hands_its_kernel_rows_not_one_token_tiles(v5e):
+    """granite-4.0-h-micro.longgen-open's decode step, whole, at the published
+    widths and three layers deep (Mamba, attention, Mamba; 64 rows, int8
+    weights, the paged kernel): ``ssd_step`` once a Mamba layer, and no array
+    of a Mamba layer's token path in tiles of fewer than 8 sublanes. A Mosaic
+    call's operand keeps its row-major layout, so a ``[64, 1, 4096]`` operand
+    is tiled ``T(1,128)`` and every fusion around the call inherits the tile
+    (read on the chip at PR 53: 3.3 ms of a 17.7 ms step, PERF.md section 6,
+    PR 54); the counter-example below is such a call. What stays narrow and
+    is not a Mamba layer's arithmetic: the convolution tails as the cache
+    holds them, ``[rows, taps = 3, 4352]`` (the row gather and the scatter are
+    row-major by DMA; one select turns the gathered rows rows-on-sublanes),
+    and the paged kernel's ``[64, 8, 4, 128]`` query block of 4 heads a kv
+    head."""
+    from jax.experimental import pallas as pl
+
+    from mcp_context_forge_tpu.tpu_local.kv import init_kv_state
+    from mcp_context_forge_tpu.tpu_local.models import granite_hybrid
+    from mcp_context_forge_tpu.tpu_local.models.configs import GraniteHybridConfig
+    from mcp_context_forge_tpu.tpu_local.parallel.mesh import make_mesh
+    from mcp_context_forge_tpu.tpu_local.quantize import quantize_tree
+
+    cfg = GraniteHybridConfig(
+        name="granite-d3", vocab_size=100352, dim=2048, n_layers=3, n_heads=32,
+        n_kv_heads=8, head_dim=64, ffn_hidden=8192, mamba_n_heads=64,
+        mamba_head_dim=64, mamba_d_state=128,
+        layer_types=("mamba", "attention", "mamba"), embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=1 / 64, logits_scaling=8.0)
+    mesh = make_mesh("", devices=[next(iter(v5e.device_set))])
+    like = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), tree)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    params = like(jax.eval_shape(lambda: quantize_tree(
+        granite_hybrid.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16),
+        granite_hybrid.params_logical(cfg), scale_dtype=jnp.bfloat16)))
+    B = 64
+    kv = like(jax.eval_shape(partial(init_kv_state, cfg, 1025, PAGE, B, 16,
+                                     dtype=jnp.bfloat16)))
+    step = jax.jit(
+        lambda p, kv, tok, pos, slots, lens, live: granite_hybrid.decode_step(
+            p, cfg, tok, pos, kv, slots, lens, ctx_pages=16, write_mask=live,
+            paged_impl="pallas", mesh=mesh)[:2], donate_argnums=(1,))
+    # the described device is not ``jax.devices()``: steer the family's
+    # question to the branch the chip takes, here and not in the program
+    with mesh, mock.patch.object(granite_hybrid, "on_tpu", lambda mesh: True):
+        assert granite_hybrid.delta_impl(mesh, cfg) == "pallas"
+        text = step.lower(
+            params, kv, spec((B,), jnp.int32), spec((B,), jnp.int32),
+            spec((B,), jnp.int32), spec((B,), jnp.int32),
+            spec((B,), jnp.bool_)).compile().as_text()
+    kernels = re.findall(r"%(\w+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                         text[text.index("\nENTRY "):])
+    assert sorted(kernels) == ["paged_attention", "ssd_step", "ssd_step"]
+    narrow = _narrow_tiles(text)
+    tails = {n for n in narrow if n[0] == "bf16" and n[1][-2:] == (3, 4352)}
+    assert narrow - tails <= {("bf16", (B, 8, 4, 128), 4)}, sorted(narrow - tails)
+    assert not any(dtype == "f32" for dtype, _, _ in narrow)
+
+    # the detector fires on what this guards against: a Mosaic call handed a
+    # [64, 1, 4096] operand, and the fusion that feeds it
+    def one_token_blocks(x):
+        def kernel(x_ref, o_ref):
+            o_ref[...] = x_ref[...] * 2.0
+        block = pl.BlockSpec((1, 1, 4096), lambda b: (b, 0, 0))
+        return pl.pallas_call(
+            kernel, grid=(B,), in_specs=[block], out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((B, 1, 4096), jnp.float32),
+        )(jnp.tanh(x)[:, None, :])
+
+    text = jax.jit(one_token_blocks).lower(
+        spec((B, 4096), jnp.float32)).compile().as_text()
+    assert ("f32", (B, 1, 4096), 1) in _narrow_tiles(text)
 
 
 # ------------------------------------------------- chip_smoke.py, rehearsed

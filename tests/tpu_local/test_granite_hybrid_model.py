@@ -344,6 +344,159 @@ def test_the_kernels_carry_their_names_in_a_program():
     assert "ssd_step" in text(1) and "ssd_chunk" not in text(1)
 
 
+# ------------------------------------------- the one-token path of the mixer
+
+# heads of 64, which the kernels take; the other widths stay tiny
+KCFG = GraniteHybridConfig(**{**CFG.__dict__, "name": "granite-kernel-test",
+                              "mamba_n_heads": 4, "mamba_head_dim": 64,
+                              "dim": 128})
+
+# the decode widths the engine is built with here and in the cell (max_batch:
+# 64 there, 1 to 8 in the tests) and one that is not whole blocks of 8 rows
+TOKEN_CASES = {
+    # rows (0: idle, on the trash row), fresh
+    "one_block_live_idle_fresh": ([3, 0, 5, 0, 0, 7, 2, 9],
+                                  [False, True, True, False, False, False, True, False]),
+    "all_idle": ([0] * 8, [False] * 8),
+    "first_block_idle": ([0] * 8 + [4, 0, 11, 0, 0, 6, 0, 1], [False] * 16),
+    "width_64": 64,         # drawn: six rows in ten live, three in ten fresh
+    "width_12": 12,
+    "width_4": ([2, 0, 4, 1], [False, False, True, False]),
+    "width_2": ([0, 2], [True, True]),
+    "width_1": ([1], [False]),
+}
+
+
+def _token_case(case):
+    if isinstance(TOKEN_CASES[case], int):
+        B = TOKEN_CASES[case]
+        rng = np.random.default_rng(B)
+        rows = np.where(rng.random(B) < 0.6, 1 + rng.permutation(B), 0)
+        fresh = rng.random(B) < 0.3
+    else:
+        rows, fresh = TOKEN_CASES[case]
+    return np.asarray(rows, np.int32), np.asarray(fresh, bool)
+
+
+def _plain_token_mixer(layer, cfg, a, tail, state):
+    """One row's decode token through the Mamba mixer, written plainly in
+    float32: a [D] the normed stream; tail [taps, C] (zeros for a fresh row);
+    state [N, H, P]. -> (mixed [D], the new tail, the new state)."""
+    H, P, N, inner = (cfg.mamba_n_heads, cfg.mamba_head_dim, cfg.mamba_d_state,
+                      cfg.mamba_inner)
+    hi = jax.lax.Precision.HIGHEST
+    dot = partial(jnp.dot, precision=hi)
+    window = jnp.concatenate([tail, dot(a, layer["wxbc"])[None]])       # [4, C]
+    conv = jax.nn.silu(jnp.sum(layer["conv"] * window, axis=0)
+                       + layer["conv_bias"])
+    x, b, c = conv[:inner], conv[inner:inner + N], conv[inner + N:]
+    dt = jax.nn.softplus(dot(a, layer["wdt"]) + layer["dt_bias"])       # [H]
+    y, state = ssd.ssd_recurrence(x.reshape(1, 1, H, P), dt[None, None],
+                                  layer["A_log"], b[None, None], c[None, None],
+                                  state[None])
+    y = y.reshape(inner) + jnp.repeat(layer["D"], P) * x                # the skip
+    gated = y * jax.nn.silu(dot(a, layer["wz"]))                        # the gate
+    normed = (gated * jax.lax.rsqrt(jnp.mean(gated * gated) + cfg.norm_eps)
+              * layer["o_norm"])                           # the norm, all channels
+    return dot(normed, layer["wo"]), window[1:], state[0]
+
+
+@pytest.mark.parametrize("case", sorted(TOKEN_CASES))
+def test_the_one_token_path_is_the_recurrence_and_its_plain_epilogue(
+        case, monkeypatch):
+    """A decode token through ``_mamba_mixer`` with ``ssd_step`` (interpreted)
+    against ``ssd_recurrence`` with the convolution, the skip, the gate and
+    the norm written plainly, at 1e-5 of the largest entry: live, idle and
+    fresh rows inside one block of 8 rows, a call with no live row, a first
+    block that is all idle, the cell's width of 64 and narrower ones, 12
+    among them (not whole blocks: idle steps fill the last one)."""
+    cfg = KCFG
+    rows, fresh = _token_case(case)
+    B = len(rows)
+    H, P, N = cfg.mamba_n_heads, cfg.mamba_head_dim, cfg.mamba_d_state
+    layer = granite_hybrid.init_layer(cfg, jax.random.PRNGKey(7), jnp.float32)
+    k = jax.random.split(jax.random.PRNGKey(B), 6)
+    layer["D"] = 0.5 + jax.random.uniform(k[0], (H,))
+    layer["o_norm"] = 0.5 + jax.random.uniform(k[1], (cfg.mamba_inner,))
+    n_rows = max(int(rows.max()), B) + 1
+    kv = init_kv_state(cfg, 3, PAGE, B, 1, dtype=jnp.float32)
+    kv = kv._replace(
+        state=jax.random.normal(k[2], (2, n_rows, N, H * P)),
+        conv_tail=jax.random.normal(k[3], (2, n_rows, 3, cfg.conv_dim)))
+    stream = jax.random.normal(k[4], (B, 1, cfg.dim))
+    live = rows > 0
+    counts = jnp.asarray(live.astype(np.int32))
+    monkeypatch.setattr(ssd, "ssd_pallas", partial(ssd.ssd_pallas, interpret=True))
+    mixed, out, tails = granite_hybrid._mamba_mixer(
+        layer, cfg, 1, stream, stream, jnp.asarray(live)[:, None],
+        jnp.asarray(rows), counts, jnp.asarray(fresh), kv, "pallas")
+    assert mixed.shape == (B, 1, cfg.dim) and tails.shape == kv.conv_tail.shape[1:]
+    want_state, want_tails = np.array(kv.state[1]), np.array(kv.conv_tail[1])
+    for i in np.flatnonzero(live):
+        zero = 0.0 if fresh[i] else 1.0
+        want, tail, state = _plain_token_mixer(
+            layer, cfg, stream[i, 0], zero * kv.conv_tail[1, rows[i]],
+            (zero * kv.state[1, rows[i]]).reshape(N, H, P))
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(mixed[i, 0], want, atol=1e-5 * scale, rtol=0)
+        want_state[rows[i]], want_tails[rows[i]] = state.reshape(N, H * P), tail
+    np.testing.assert_allclose(out.state[1], want_state,
+                               atol=1e-5 * float(np.abs(want_state).max()), rtol=0)
+    np.testing.assert_allclose(tails[1:], want_tails[1:],
+                               atol=1e-5 * float(np.abs(want_tails).max()), rtol=0)
+    # nothing else moved: the other layer, the trash row, the rows no live
+    # step owns (bit for bit)
+    np.testing.assert_array_equal(out.state[0], kv.state[0])
+    idle_rows = sorted(set(range(n_rows)) - set(rows[live].tolist()))
+    np.testing.assert_array_equal(np.asarray(out.state[1])[idle_rows],
+                                  np.asarray(kv.state[1])[idle_rows])
+    np.testing.assert_array_equal(np.asarray(tails)[idle_rows[1:]],
+                                  np.asarray(kv.conv_tail[1])[idle_rows[1:]])
+    assert np.isfinite(np.asarray(mixed)).all()
+
+
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_one_token_convolution_is_the_many_token_one_bit_for_bit(bias, dtype):
+    """``conv_with_tail`` at S == 1 (four [B, C] terms, the new tail by a
+    select) against its S > 1 code on the same inputs (the token and one
+    padding token after it): the convolution and the new tails bit for bit,
+    for rows with a real token (counts 1), without (counts 0) and fresh ones;
+    with Granite's bias and without, as Olmo and Solar call it."""
+    cfg = KCFG
+    B, C = 8, cfg.conv_dim
+    k = jax.random.split(jax.random.PRNGKey(11), 5)
+    kv = init_kv_state(cfg, 3, PAGE, B, 1, dtype=dtype)
+    kv = kv._replace(conv_tail=jax.random.normal(
+        k[0], (2, 12, 3, C)).astype(dtype))
+    raw = jax.random.normal(k[1], (B, 2, C)).astype(dtype)
+    weight = jax.random.normal(k[2], (4, C)).astype(dtype)
+    b = jax.random.normal(k[3], (C,)) if bias else None
+    rows = jnp.asarray([3, 0, 1, 5, 0, 2, 8, 4])
+    counts = jnp.asarray([1, 0, 1, 1, 0, 1, 1, 1])
+    fresh = jnp.asarray([False, False, True, False, True, False, False, True])
+    call = lambda raw: olmo_hybrid.conv_with_tail(raw, weight, b, 4, 1, rows,
+                                                  counts, fresh, kv)
+    # compiled too where the taps' products are exact in float32 (bfloat16
+    # operands), so that no fused multiply-add can round them differently
+    runs = [lambda f, x: f(x)] + ([lambda f, x: jax.jit(f)(x)]
+                                  if dtype == jnp.bfloat16 else [])
+    for run in runs:
+        one, kv_one = run(call, raw[:, :1])
+        many, kv_many = run(call, raw)
+        assert one.shape == (B, 1, C) and one.dtype == jnp.float32
+        np.testing.assert_array_equal(one[:, 0], many[:, 0])
+        np.testing.assert_array_equal(kv_one.conv_tail.astype(jnp.float32),
+                                      kv_many.conv_tail.astype(jnp.float32))
+    # a row without a token keeps its tail, a row with one moves up by it
+    np.testing.assert_array_equal(kv_one.conv_tail[1, 3, 2].astype(jnp.float32),
+                                  raw[0, 0].astype(jnp.float32))
+    np.testing.assert_array_equal(kv_one.conv_tail[1, 3, :2], kv.conv_tail[1, 3, 1:])
+    np.testing.assert_array_equal(kv_one.conv_tail[1, 9], kv.conv_tail[1, 9])
+    np.testing.assert_array_equal(kv_one.conv_tail[0], kv.conv_tail[0])
+
+
 # ------------------------------------------------------------ pools and rows
 
 def test_the_family_declares_the_hybrid_pools():

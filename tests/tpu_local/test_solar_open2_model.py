@@ -529,7 +529,11 @@ PARENT_PROGRAMS = {
     "olmo-hybrid-test": {
         "prefill": "124af6090c8c35d79ca8480993ba26b81bfa06d4e5b6819d4904e0e6cb77a03f",
         "chunk": "209df41fbd2f9a2ef2d6f3e8d148eac9107f66423f4f7851c52cb7f20057df05",
-        "decode": "a1167faea08c4f5651b8a763ff3eeaab8d0c5a93fce3730e41953f5efd6d8b53",
+        # PR 54 (``conv_with_tail`` at S == 1: four [B, C] terms and the new
+        # tail by a select, for every family that calls it): the two decode
+        # programs traced again on purpose; prefill and chunk (S > 1) stay as
+        # pinned, equation for equation
+        "decode": "1254a96b81c83375da92b78ce061316224497f2d2c7117c866fffb5df9c30bb9",
         "kernel_step": "5665504a8d8b629a0c2e11c960efc38147ff5f62b1fbe1e6d2c04b05f2884bae",
         # PR 52: a scalar-form chunk of whole 64-token chunks takes the
         # chunkwise body (``gated_delta.scalar_chunk_group``); the preset's
@@ -549,7 +553,7 @@ PARENT_PROGRAMS = {
     "solar-open2-test": {
         "prefill": "c1a45b31933be23126b274502c6c8552989f0ec993407bd882efa9353b2dea3c",
         "chunk": "a75d48b141579bb353c3e373c73a1531e4142fee972b97e23c6ecda7b7d252e7",
-        "decode": "9165bde125bd9beaea06373154ad61b32768c634bf01fba2f92dc313bf3d36e6",
+        "decode": "a396c1a057534114b1a66828566a3543e88522d614dbfc9d0b5d1c7a640f9ee2",  # PR 54: above
         "kernel_chunk": "153b3235c94f9f243fca7032537d89347711779606e4952df4d3c51b06ed933e"},
 }
 
