@@ -25,7 +25,11 @@ instrument panel:
   ``X-Queue-Depth`` / ``Retry-After`` response headers.
 
 Everything here runs on the gateway's asyncio loop; nothing is touched
-from engine dispatch threads.
+from engine dispatch threads. The one thing written for the engine's
+readers: a loop lag of a millisecond or more goes onto every live step
+timeline as a ``pause`` of cause ``loop_lag`` (``observability/timeline.py``),
+on the ring's own clock, so a reader of the ring sees a loop that could not
+run beside the collections and the dispatch thread's spans.
 """
 
 from __future__ import annotations
@@ -35,6 +39,8 @@ import bisect
 import itertools
 import logging
 import math
+import sys
+import threading
 import time
 from collections import deque
 from typing import Any
@@ -42,6 +48,12 @@ from typing import Any
 from ..observability.logging import trace_extra
 
 logger = logging.getLogger(__name__)
+
+# the step timelines' module, looked up and never imported from here: it
+# pulls in jax, and a process that holds an engine has imported it already
+# (tpu_local/engine.py). No module, no ring to write a pause to
+_TIMELINE = __name__.replace("gateway.flight_recorder",
+                             "observability.timeline")
 
 
 class FlightRecorder:
@@ -255,17 +267,23 @@ class LoopLagSampler:
                 pass
 
     async def _run(self) -> None:
-        loop = asyncio.get_running_loop()
+        # perf_counter, the step timelines' clock (and finer than a loop's
+        # own time() where that is cached a loop iteration)
         while True:
-            before = loop.time()
+            due = time.perf_counter() + self.interval_s
             await asyncio.sleep(self.interval_s)
-            lag = max(0.0, loop.time() - before - self.interval_s)
-            self._observe(lag)
+            ran = time.perf_counter()
+            self._observe(max(0.0, ran - due), ran)
 
-    def _observe(self, lag: float) -> None:
+    def _observe(self, lag: float, ran: float) -> None:
+        """One tick that ran at ``ran``, ``lag`` seconds after it was due."""
         self.samples += 1
         self.last_lag_s = lag
         self.max_lag_s = max(self.max_lag_s, lag)
+        timeline = sys.modules.get(_TIMELINE)
+        if timeline is not None and lag >= timeline.PAUSE_S:
+            timeline.gc_watch.add_pause("loop_lag", ran - lag, ran, 0,
+                                        threading.current_thread().name)
         if self.metrics is not None:
             self.metrics.gw_loop_lag.observe(lag)
         if self.signals is not None:

@@ -283,7 +283,10 @@ async def flight_recorder_middleware(request: web.Request,
     span = current_span()
     trace = span.context() if span is not None else None
     rid = recorder.start_request(request.path, trace)
-    started = time.perf_counter()
+    # the row's start and the request's first mark are one reading: what
+    # lies before it (socket, aiohttp's parse, the middleware outside this
+    # one) is the client's ``sent -> recv``
+    started = clock.mark("recv")
     response: web.StreamResponse | None = None
     error: str | None = None
     disconnected = False
@@ -529,7 +532,7 @@ async def auth_middleware(request: web.Request, handler: Handler) -> web.StreamR
     # plugin resolve, DB-backed bearer/basic lookups) charges the "auth"
     # phase; the plugin hooks inside charge "plugins" via PluginManager
     # and self-time accounting keeps the two from double-counting
-    with request_phases.phase("auth"):
+    with request_phases.phase("auth", mark="authed"):
         header = request.headers.get(settings.auth_header_name, "")
         auth_ctx: AuthContext | None = None
         pm = ctx.plugin_manager

@@ -25,6 +25,18 @@ Attribution semantics:
   the honest per-request latency attribution (it is what the client
   waited), and the loop-lag sampler is the signal that separates "slow
   phase" from "starved loop".
+
+Beside the sums the clock keeps **marks**: the FIRST ``time.perf_counter()``
+under a name, taken where a bucket is already bracketed (a ``phase()`` block
+hands its end reading to a mark, so one instant is read once). A sum cannot
+say what happened before the first token; the marks can: ``recv`` (the
+middleware's entry), ``authed``, ``parsed`` (JSON, shed check and model
+resolved), ``tokenized`` (template rendered and tokenised), ``chunk`` (the
+first content chunk built) and ``written`` (its SSE write returned). Once
+the provider has the engine's request it ties the clock to the request's id
+(:meth:`PhaseClock.tie`), and the marks become request stamps on the
+engine's step timeline, between the ones the engine takes itself
+(``docs/observability.md``, "Step timeline").
 """
 
 from __future__ import annotations
@@ -32,7 +44,7 @@ from __future__ import annotations
 import contextvars
 import time
 from contextlib import contextmanager
-from typing import Iterator
+from typing import Callable, Iterator
 
 _current_clock: contextvars.ContextVar["PhaseClock | None"] = \
     contextvars.ContextVar("mcpforge_phase_clock", default=None)
@@ -41,12 +53,42 @@ _current_clock: contextvars.ContextVar["PhaseClock | None"] = \
 class PhaseClock:
     """Named wall-time buckets for one request, self-time on nesting."""
 
-    __slots__ = ("phases", "_stack")
+    __slots__ = ("phases", "_stack", "marks", "_stamp", "_request_id")
 
     def __init__(self) -> None:
         self.phases: dict[str, float] = {}
         # (name, start, child_seconds) of every open phase() block
         self._stack: list[list] = []
+        # name -> the first perf_counter() taken under it
+        self.marks: dict[str, float] = {}
+        # set by tie(): a step timeline's ``stamp`` and the engine's id of
+        # this request; from then on a mark is a request stamp on that ring
+        self._stamp: Callable[..., float] | None = None
+        self._request_id = ""
+
+    def mark(self, name: str, t: float | None = None) -> float:
+        """Keep the FIRST instant under ``name`` (``t``, or now) and return
+        what is kept; a later call under the same name changes nothing."""
+        kept = self.marks.get(name)
+        if kept is not None:
+            return kept
+        if t is None:
+            t = time.perf_counter()
+        self.marks[name] = t
+        if self._stamp is not None:
+            self._stamp(name, self._request_id, -1, t)
+        return t
+
+    def tie(self, stamp: Callable[..., float], request_id: str) -> None:
+        """From here on the marks are request stamps under ``request_id``
+        (``stamp`` is ``StepTimeline.stamp``); the ones taken before the id
+        existed are handed over now, with their own times. A request that
+        never reaches an engine is never tied and leaves no stamp."""
+        if self._stamp is not None:
+            return
+        self._stamp, self._request_id = stamp, request_id
+        for name, t in self.marks.items():
+            stamp(name, request_id, -1, t)
 
     def add(self, name: str, seconds: float) -> None:
         """Charge ``seconds`` to ``name`` directly (pre-measured work,
@@ -59,15 +101,19 @@ class PhaseClock:
             self._stack[-1][2] += seconds
 
     @contextmanager
-    def phase(self, name: str) -> Iterator[None]:
+    def phase(self, name: str, mark: str | None = None) -> Iterator[None]:
         """Charge the block's SELF time to ``name`` (elapsed minus any
-        nested phase()/add() time)."""
+        nested phase()/add() time); the block's end is also the mark
+        ``mark``, where one is named."""
         frame = [name, time.perf_counter(), 0.0]
         self._stack.append(frame)
         try:
             yield
         finally:
-            elapsed = time.perf_counter() - frame[1]
+            ended = time.perf_counter()
+            elapsed = ended - frame[1]
+            if mark is not None:
+                self.mark(mark, ended)
             # tolerate mis-nesting from concurrent same-request tasks:
             # pop OUR frame wherever it sits rather than corrupting the
             # stack (attribution degrades, accounting never crashes)
@@ -112,12 +158,12 @@ def add_phase(name: str, seconds: float) -> None:
 
 
 @contextmanager
-def phase(name: str) -> Iterator[None]:
+def phase(name: str, mark: str | None = None) -> Iterator[None]:
     """Self-time phase block against the current clock; no-op without
     one (the same code path serves instrumented and bare calls)."""
     clock = _current_clock.get()
     if clock is None:
         yield
         return
-    with clock.phase(name):
+    with clock.phase(name, mark):
         yield

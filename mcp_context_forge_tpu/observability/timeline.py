@@ -29,19 +29,29 @@ and nowhere else. Four kinds of event share one bounded ring:
   rows and the real tokens its recurrence scanned, and for a family whose
   decode dispatch is a block step (``models/sdar.py``) its denoise passes,
   the tokens it emitted and the positions its threshold filled);
-- **request stamps** — ``("req", phase, t, request_id, slot, replica)`` for
-  ``submit`` / ``admit`` / ``first`` / ``deliver`` / ``done`` (``deliver``:
-  the request's first token entered ``request.stream`` on the loop's thread);
+- **request stamps** — ``("req", phase, t, request_id, slot, replica)``. The
+  engine takes ``submit`` / ``admit`` / ``first`` / ``emit`` / ``deliver`` /
+  ``done`` (``emit``: the dispatch thread's flush hands the request's first
+  token to the loop; ``deliver``: it entered ``request.stream`` on the loop's
+  thread). Around them stand the gateway's marks of the same request, handed
+  over by its ``PhaseClock`` once the provider has the request's id
+  (``observability/phases.py``; slot -1, all on the loop's thread): ``recv``,
+  ``authed``, ``parsed``, ``tokenized`` before ``submit``, ``chunk`` and
+  ``written`` after ``deliver``;
 - **pauses** — ``("pause", cause, t0, t1, detail, thread)``: something held
-  the whole process. The one cause today is ``"gc"`` (``detail`` its
-  generation): a process-wide ``gc.callbacks`` hook writes every collection
-  of at least ``PAUSE_S`` to every live timeline (:class:`_GcWatch`). A pause
-  is NOT a span: it may come from any thread and cut across the dispatch
-  thread's nesting.
+  the whole process, or one thread that everything waits for. ``"gc"``
+  (``detail`` its generation): a process-wide ``gc.callbacks`` hook writes
+  every collection of at least ``PAUSE_S`` to every live timeline
+  (:class:`_GcWatch`). ``"loop_lag"`` (``detail`` 0): the gateway's
+  ``LoopLagSampler`` found the event loop at least ``PAUSE_S`` late for its
+  tick (``t0`` when the tick was due, ``t1`` when it ran; ``thread`` is the
+  loop's, which tells a loop that could not run from a collection that held
+  every thread). A pause is NOT a span: it may come from any thread and cut
+  across the dispatch thread's nesting.
 
 All stamps are ``time.perf_counter()`` seconds. The ring is always on and
 has no setting: it is a ``deque`` appended from the dispatch thread (and,
-for ``submit`` / ``deliver`` and pauses, other threads) and only ever copied
+for the loop's stamps and pauses, other threads) and only ever copied
 by readers. A span, eleven of a decode step's thirteen events, is held as one
 packed ``bytes`` (:data:`_SPAN`), not a tuple: a tuple is a container the
 collector counts, and while the ring grows every one kept is one more towards
@@ -131,7 +141,9 @@ class StepEvent(NamedTuple):
 
 
 class RequestEvent(NamedTuple):
-    phase: str              # submit | admit | first | deliver | done
+    phase: str              # recv | authed | parsed | tokenized | submit |
+    #                         admit | first | emit | deliver | chunk |
+    #                         written | done
     t: float
     request_id: str
     slot: int
@@ -139,10 +151,10 @@ class RequestEvent(NamedTuple):
 
 
 class PauseEvent(NamedTuple):
-    cause: str              # "gc"
+    cause: str              # "gc" | "loop_lag"
     t0: float
     t1: float
-    detail: int             # gc: the generation collected
+    detail: int             # gc: the generation collected; loop_lag: 0
     thread: str             # the thread the pause ran on
 
 
@@ -331,9 +343,14 @@ class _GcWatch:
         if took > self.longest_s[generation]:
             self.longest_s[generation] = took
         if took >= PAUSE_S:
-            thread = current_thread().name
-            for timeline in list(self._live):
-                timeline.add_pause("gc", self._t0, t1, generation, thread)
+            self.add_pause("gc", self._t0, t1, generation,
+                           current_thread().name)
+
+    def add_pause(self, cause: str, t0: float, t1: float, detail: int,
+                  thread: str) -> None:
+        """One pause onto every live timeline."""
+        for timeline in list(self._live):
+            timeline.add_pause(cause, t0, t1, detail, thread)
 
     def stats(self) -> dict[str, dict[str, float]]:
         """Count, total and longest by generation (``/admin/engine/stats``)."""

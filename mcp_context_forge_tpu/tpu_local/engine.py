@@ -268,11 +268,13 @@ class GenRequest:
     # twin of ``created``, so pool shadows inherit both and a failover
     # continuation's queue wait and TTFT still span the failed attempt.
     # The engine stamps the others: slot won, first token, that token
-    # handed to ``stream`` on the loop's thread, retired.
+    # handed to the loop by the dispatch thread's flush, put into ``stream``
+    # on the loop's thread, retired.
     # queue_ms / prefill_ms and the llm.* span durations derive from these
     t_submit: float = field(default_factory=time.perf_counter)
     t_admit: float = 0.0
     t_first: float = 0.0
+    t_emit: float = 0.0
     t_deliver: float = 0.0
     t_done: float = 0.0
     # filled by the engine
@@ -3961,8 +3963,6 @@ class TPUEngine:
         def _put() -> None:
             for request, tokens, done in batch:
                 if tokens and request.t_deliver == 0.0:
-                    # the first token's way out: t_first -> here is the
-                    # wait in the buffer and the hop to the loop
                     request.t_deliver = stamp("deliver", request.request_id,
                                               request.slot)
                 for token in tokens:
@@ -3971,6 +3971,13 @@ class TPUEngine:
                     request.stream.put_nowait(None)
 
         with self.timeline.span("loop.flush"):
+            # the first token's way out, split at the hop: t_first -> t_emit
+            # is the dispatch thread finishing its step before it flushes,
+            # t_emit -> t_deliver the loop's latency for the callback
+            for request, tokens, _done in batch:
+                if tokens and request.t_emit == 0.0:
+                    request.t_emit = stamp("emit", request.request_id,
+                                           request.slot)
             if loop is not None and not loop.is_closed():
                 try:
                     loop.call_soon_threadsafe(_put)

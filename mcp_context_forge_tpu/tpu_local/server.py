@@ -139,7 +139,9 @@ def setup_llm_routes(app: web.Application, registry: LLMProviderRegistry,
             span.set_attribute("llm.stream", bool(body.get("stream")))
         try:
             if body.get("stream"):
-                with request_phases.phase("routing"):
+                # ``parsed``: the body read, the shed check passed and the
+                # model resolved: what is left is the provider's
+                with request_phases.phase("routing", mark="parsed"):
                     registry.resolve(body.get("model"))  # fail before the stream starts
                 # the FIRST chunk is awaited BEFORE prepare() — but only
                 # for a BOUNDED window: a request the pool refuses
@@ -198,7 +200,10 @@ def setup_llm_routes(app: web.Application, registry: LLMProviderRegistry,
                                 except StopAsyncIteration:
                                     chunk = None
                         while chunk is not None:
-                            with request_phases.phase("serialize"):
+                            # ``written`` keeps the FIRST chunk's write (for
+                            # one awaited before prepare(), after that too)
+                            with request_phases.phase("serialize",
+                                                      mark="written"):
                                 await resp.write(sse_event(chunk))
                             with request_phases.phase("engine"):
                                 try:

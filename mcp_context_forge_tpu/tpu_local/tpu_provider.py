@@ -23,6 +23,7 @@ from .models import ENCODER_CONFIGS
 from .models.encoder import encode as encoder_forward, init_encoder_params
 from .provider import LLMProvider, make_chat_response
 from .tokenizer import load_tokenizer, render_chat
+from ..observability.phases import current_phases
 from ..utils.ids import new_id
 
 
@@ -173,7 +174,13 @@ class TPULocalProvider(LLMProvider):
         # middleware set (team → API key → user); engine-internal callers
         # (plugins, warmup) have none and account as unattributed
         from ..observability.tenant import current_tenant
-        return GenRequest(
+        # the gateway's clock of this request (the flight recorder's; None
+        # for engine-internal callers): the prompt is rendered and tokenised
+        # here, and the GenRequest's birth just below is the ring's ``submit``
+        clock = current_phases()
+        if clock is not None:
+            clock.mark("tokenized")
+        gen = GenRequest(
             request_id=new_id(),
             prompt_ids=prompt_ids,
             max_tokens=max(1, max_tokens),
@@ -183,6 +190,13 @@ class TPULocalProvider(LLMProvider):
             priority=priority,
             tenant=current_tenant() or "",
         )
+        # the request has its id: the clock's marks are stamps of that id
+        # on the engine's step timeline from here on. A pool picks its
+        # replica, and so the ring, inside submit: its marks stay marks
+        timeline = getattr(self.engine, "timeline", None)
+        if clock is not None and timeline is not None:
+            clock.tie(timeline.stamp, gen.request_id)
+        return gen
 
     def _request_span(self, request: dict[str, Any], gen: GenRequest):
         """Open the llm.request span (parent = whatever is current on the
@@ -303,6 +317,16 @@ class TPULocalProvider(LLMProvider):
         await self.engine.submit(gen)
         created = int(time.time())
         chunk_id = f"chatcmpl-{new_id()[:24]}"
+        clock = current_phases()
+
+        def content(text: str) -> dict[str, Any]:
+            """A content chunk; the first one built is the mark ``chunk``
+            (detokenised and built, on the loop, not yet yielded)."""
+            chunk = self._content_chunk(chunk_id, created, model, text)
+            if clock is not None:
+                clock.mark("chunk")
+            return chunk
+
         # function calling: a completion that OPENS with JSON is (probably)
         # a tool call — buffer it instead of streaming fragments the client
         # would render; plain text streams token-by-token as usual
@@ -327,12 +351,11 @@ class TPULocalProvider(LLMProvider):
                         buffering = False  # plain answer: replay + stream
                         for chunk in emitted:
                             delivered = True
-                            yield self._content_chunk(chunk_id, created,
-                                                      model, chunk)
+                            yield content(chunk)
                         emitted = []
                     continue
                 delivered = True
-                yield self._content_chunk(chunk_id, created, model, text)
+                yield content(text)
         if gen.finish_reason == "unavailable":
             if not delivered:
                 # nothing reached the client yet: raise so the HTTP
@@ -379,7 +402,7 @@ class TPULocalProvider(LLMProvider):
                                  "finish_reason": "tool_calls"}],
                 }
                 return
-            yield self._content_chunk(chunk_id, created, model, full)
+            yield content(full)
         yield {
             "id": chunk_id, "object": "chat.completion.chunk", "created": created,
             "model": model,

@@ -1,6 +1,6 @@
 """The engine's step timeline (observability/timeline.py): one recorder on
 the profiler's clock that every dispatch kind writes its phase spans, its
-step record and its requests' four stamps to — and that the engine's own
+step record and its requests' stamps to — and that the engine's own
 step numbers (``queue_ms``, ``prefill_ms``, ``dispatch_gap_ms_total``,
 ``decode_ms_total``, the llm.* span durations) are now read from.
 
@@ -18,6 +18,11 @@ Falsifiable form:
   read-back inside, emit after;
 - ``t_submit <= t_admit <= t_first <= t_done`` for every request, on the
   dense, chunked, overlapped and serial paths;
+- behind the gateway a streamed request's marks (``recv`` .. ``tokenized``,
+  ``chunk``, ``written``) stand on the ring under the engine's id, around
+  the engine's own stamps and in order; a shed request leaves none; a first
+  token that falls into a blocked loop reads the block between ``emit`` and
+  ``deliver``, beside the ``loop_lag`` pause the sampler left of it;
 - the ring is bounded, and found through the registry;
 - each jitted step function lowers to a module that the benchmark's
   ``trace_reduce.program_kind`` classifies by NAME, not by kernel shape.
@@ -499,14 +504,16 @@ def test_request_stamps_are_ordered_on_every_path(path, request):
         assert 0 < finished.t_submit <= finished.t_admit \
             <= finished.t_first <= finished.t_done
         # the first token reaches the request's stream after it was stamped
-        # ``first``, once (the hop to the loop's thread lies between)
-        assert finished.t_first <= finished.t_deliver
+        # ``first``, once: the dispatch thread's flush (``emit``) and the
+        # hop to the loop's thread lie between
+        assert finished.t_first <= finished.t_emit <= finished.t_deliver
         assert stamps[finished.request_id] == {
             "submit": finished.t_submit, "admit": finished.t_admit,
-            "first": finished.t_first, "deliver": finished.t_deliver,
-            "done": finished.t_done}
-        assert sum(1 for e in ring["req"] if e.phase == "deliver"
-                   and e.request_id == finished.request_id) == 1
+            "first": finished.t_first, "emit": finished.t_emit,
+            "deliver": finished.t_deliver, "done": finished.t_done}
+        for phase in ("emit", "deliver"):
+            assert sum(1 for e in ring["req"] if e.phase == phase
+                       and e.request_id == finished.request_id) == 1
     slots = {e.slot for e in ring["req"] if e.phase != "submit"}
     assert slots <= set(range(engine.config.max_batch))
 
@@ -521,7 +528,7 @@ def test_chunked_request_is_stamped_once_and_prefill_ms_adds_rounds(overlapped):
     assert chunked.t_first >= rounds[-1].t_retired
     assert sorted(e.phase for e in ring["req"]
                   if e.request_id == chunked.request_id) == [
-        "admit", "deliver", "done", "first", "submit"]
+        "admit", "deliver", "done", "emit", "first", "submit"]
     # prefill_ms accumulates each round's build -> first-tokens-on-host wall
     walls = []
     for step in rounds:
@@ -661,8 +668,11 @@ def test_ring_holds_two_minutes_of_the_busiest_cell(overlapped):
     prefill = events_of(("prefill", "prefill_hist", "chunk")) + 1   # + admit
     assert 12 <= decode <= 14 and 11 <= prefill <= 13, (decode, prefill)
     stamps = len({e.phase for e in ring["req"]})
-    assert stamps == 5
-    a_second = (4300 * decode + 215 * (prefill + 2) + 250 * stamps) / 50.0
+    assert stamps == 6
+    # behind a gateway a request leaves six more (recv .. tokenized, chunk,
+    # written) and the loop's lag at most a pause a tick, four a second
+    a_second = (4300 * decode + 215 * (prefill + 2)
+                + 250 * (stamps + 6)) / 50.0 + 4
     assert 120.0 * a_second <= tl_mod.RING_EVENTS, a_second
     assert 120.0 * a_second > tl_mod.RING_EVENTS // 2    # and not by 4 x
 
@@ -782,6 +792,194 @@ def test_registry_finds_the_newest_live_engine(serial):
     import gc
     gc.collect()
     assert tl_mod.get_timeline("timeline-test-7") is None
+
+
+# --------------------------------- a request's way through the gateway
+
+WAY = ("recv", "authed", "parsed", "tokenized", "submit", "admit", "first",
+       "emit", "deliver", "chunk", "written")
+BLOCK_S = 0.03
+
+
+class _RenderEveryToken:
+    """The engine's tokenizer with a ``decode`` that renders every id (the
+    byte-level one drops most ids of a random model: no content chunk)."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def decode(self, ids):
+        return "".join(chr(33 + i % 94) for i in ids)
+
+
+@pytest.fixture(scope="module")
+def through_the_gateway():
+    """The in-process gateway on the toy model, one loop: a streamed request,
+    a shed one, and a streamed one whose first token finds the loop blocked
+    for ``BLOCK_S`` (a ``time.sleep`` in a callback queued the moment the
+    dispatch thread stamps ``first``: before the flush's own callback)."""
+    import json
+    import time
+
+    import aiohttp
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from mcp_context_forge_tpu.config import load_settings
+    from mcp_context_forge_tpu.gateway.app import build_app
+
+    admin = aiohttp.BasicAuth("admin", "changeme")
+    user = aiohttp.BasicAuth("shed@example.com", "Vq8#mRt2xW!s")
+    env = {"MCPFORGE_DATABASE_URL": "sqlite:///:memory:",
+           "MCPFORGE_PLUGINS_ENABLED": "false",
+           "MCPFORGE_GATEWAY_HEALTH_INTERVAL": "3600",
+           "MCPFORGE_TPU_LOCAL_ENABLED": "true",
+           "MCPFORGE_TPU_LOCAL_MODEL": "llama3-test",
+           "MCPFORGE_TPU_LOCAL_MAX_BATCH": "4",
+           "MCPFORGE_TPU_LOCAL_MAX_SEQ_LEN": "128",
+           "MCPFORGE_TPU_LOCAL_PAGE_SIZE": "16",
+           "MCPFORGE_TPU_LOCAL_NUM_PAGES": "64",
+           "MCPFORGE_TPU_LOCAL_PREFILL_BUCKETS": "64",
+           "MCPFORGE_TPU_LOCAL_DTYPE": "float32",
+           "MCPFORGE_GW_LOOP_LAG_INTERVAL_S": "0.01",
+           # the class every tenant has unless mapped sheds at any load;
+           # the admin is mapped out of it
+           "MCPFORGE_GW_SHED_SATURATION_AT": "0.0",
+           "MCPFORGE_GW_SHED_CLASS_ORDER": '["default"]',
+           "MCPFORGE_SLO_TENANT_CLASSES": json.dumps(
+               {"user:admin@example.com": "premium"})}
+
+    async def main():
+        app = await build_app(load_settings(env=env, env_file=None))
+        engine = app["tpu_engine"]
+        engine.tokenizer = _RenderEveryToken(engine.tokenizer)
+        client = TestClient(TestServer(app))
+        await client.start_server()
+        out = {}
+
+        async def chat(auth, content, stream=True):
+            body = {"model": "llama3-test", "stream": stream, "max_tokens": 24,
+                    "messages": [{"role": "user", "content": content}]}
+            before = {e.request_id for e in engine.timeline.snapshot()["req"]}
+            resp = await client.post("/v1/chat/completions", auth=auth,
+                                     json=body)
+            text = await resp.text()
+            ring = engine.timeline.snapshot()
+            new = {}
+            for e in ring["req"]:
+                if e.request_id not in before:
+                    new.setdefault(e.request_id, []).append(e)
+            return {"status": resp.status, "text": text, "new": new,
+                    "ring": ring}
+
+        try:
+            await chat(admin, "compile the programs")         # cold
+            out["streamed"] = await chat(admin, "stream me")
+            out["unary"] = await chat(admin, "answer me", stream=False)
+            made = await client.post("/admin/users", auth=admin, json={
+                "email": "shed@example.com", "password": "Vq8#mRt2xW!s",
+                "full_name": "Shed Target"})
+            assert made.status in (201, 409), await made.text()
+            out["shed"] = await chat(user, "shed me")
+
+            loop, block, stamp = asyncio.get_running_loop(), [], \
+                engine.timeline.stamp
+
+            def blocked():
+                block.append(time.perf_counter())
+                time.sleep(BLOCK_S)
+                block.append(time.perf_counter())
+
+            def stamp_and_block(phase, request_id, slot, t=None):
+                if phase == "first" and not block:
+                    loop.call_soon_threadsafe(blocked)
+                return stamp(phase, request_id, slot, t)
+
+            engine.timeline.stamp = stamp_and_block
+            try:
+                out["blocked"] = await chat(admin, "block me")
+            finally:
+                del engine.timeline.stamp
+            out["blocked"]["block"] = tuple(block)
+            await asyncio.sleep(0.03)            # the late tick has landed
+            out["blocked"]["ring"] = engine.timeline.snapshot()
+            out["rows"] = list(app["flight_recorder"].recent)
+        finally:
+            await client.close()
+        return out
+
+    return asyncio.run(main())
+
+
+def _one(result):
+    """The one request a call left on the ring: (id, phase -> t)."""
+    assert result["status"] == 200, result["text"]
+    (request_id, events), = result["new"].items()
+    stamps = {e.phase: e.t for e in events}
+    assert len(stamps) == len(events)            # each phase once
+    return request_id, stamps, events
+
+
+def test_a_streamed_request_leaves_its_way_under_one_id_in_order(
+        through_the_gateway):
+    _id, stamps, events = _one(through_the_gateway["streamed"])
+    assert "data: [DONE]" in through_the_gateway["streamed"]["text"]
+    assert set(stamps) == set(WAY) | {"done"}
+    times = [stamps[phase] for phase in WAY]
+    assert times == sorted(times), dict(zip(WAY, times))
+    assert stamps["written"] <= stamps["done"]   # 24 tokens outlast a write
+    # the gateway's stamps carry no slot, the engine's past ``submit`` one
+    assert {e.phase for e in events if e.slot == -1} == {
+        "recv", "authed", "parsed", "tokenized", "submit", "chunk", "written"}
+    # the flight recorder's row starts at the same reading as ``recv``, and
+    # shows what it showed
+    row = next(r for r in reversed(through_the_gateway["rows"])
+               if r["status"] == 200 and "engine" in r["phases_ms"])
+    assert {"auth", "routing", "engine", "serialize", "handler"} \
+        <= set(row["phases_ms"])
+    assert "marks" not in row
+
+
+def test_an_unary_request_leaves_the_way_in_and_no_way_out(
+        through_the_gateway):
+    """``chat`` (no stream) goes through the same ``_prepare``: the way in is
+    stamped; there is no chunk and no SSE write to stamp."""
+    _id, stamps, _events = _one(through_the_gateway["unary"])
+    assert set(stamps) == {"recv", "authed", "tokenized", "submit", "admit",
+                           "first", "emit", "deliver", "done"}
+    assert stamps["recv"] <= stamps["authed"] <= stamps["tokenized"] \
+        <= stamps["submit"]
+
+
+def test_a_shed_request_leaves_no_stamp(through_the_gateway):
+    shed = through_the_gateway["shed"]
+    assert shed["status"] == 429, shed["text"]
+    assert shed["new"] == {}
+
+
+def test_a_first_token_that_falls_into_a_blocked_loop_reads_it_as_the_hop(
+        through_the_gateway):
+    blocked = through_the_gateway["blocked"]
+    _id, stamps, _events = _one(blocked)
+    start, end = blocked["block"]
+    assert end - start >= BLOCK_S
+    # the flush's callback was queued behind the block: the token was handed
+    # over before the block ended and entered the stream after it
+    assert stamps["first"] <= stamps["emit"] <= end <= stamps["deliver"]
+    hop = stamps["deliver"] - stamps["emit"]
+    assert (end - start) - 0.005 <= hop <= (end - start) + 0.025
+    assert stamps["emit"] - stamps["first"] < 0.010
+    # and the sampler (a tick every 10 ms here) left the same block as a
+    # pause: due inside it, run after it, no longer than the hop
+    lags = [p for p in blocked["ring"]["pause"]
+            if p.cause == "loop_lag" and p.t0 < end and p.t1 > start]
+    assert len(lags) == 1
+    lag = lags[0]
+    assert start - 0.002 <= lag.t0 <= start + 0.012 and lag.t1 >= end
+    assert (end - start) - 0.013 <= lag.t1 - lag.t0 <= hop + 0.012
+    assert lag.thread == "MainThread" and lag.detail == 0
 
 
 def test_spans_reach_a_profiler_capture(tmp_path):

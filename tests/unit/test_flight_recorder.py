@@ -8,6 +8,8 @@ import asyncio
 import logging
 import time
 
+import pytest
+
 from mcp_context_forge_tpu.gateway.flight_recorder import (FlightRecorder,
                                                            LoopLagSampler,
                                                            queue_state,
@@ -73,6 +75,71 @@ def test_contextvar_clock_reaches_producers():
     assert clock.phases["db"] == 0.25
     assert clock.phases["plugins"] > 0.0
     assert phases.current_phases() is None
+
+
+# ------------------------------------------------------- PhaseClock marks
+
+def test_a_mark_keeps_the_first_instant_under_its_name():
+    clock = phases.PhaseClock()
+    before = time.perf_counter()
+    first = clock.mark("recv")
+    assert before <= first <= time.perf_counter()
+    assert clock.mark("recv") == first and clock.mark("recv", 1.0) == first
+    assert clock.mark("authed", 7.5) == 7.5
+    assert clock.marks == {"recv": first, "authed": 7.5}
+    assert clock.phases == {}               # a mark charges no bucket
+
+
+def test_a_phase_block_hands_its_end_to_the_mark_it_names():
+    """One reading for the bucket's end and the mark: the mark plus nothing
+    is the block's end, and a second block under the same mark keeps the
+    first (``serialize`` runs a chunk; ``written`` is the first chunk's)."""
+    clock = phases.PhaseClock()
+    token = phases.set_phase_clock(clock)
+    try:
+        started = time.perf_counter()
+        with phases.phase("serialize", mark="written"):
+            time.sleep(0.002)
+        ended = time.perf_counter()
+        with phases.phase("serialize", mark="written"):
+            pass
+        with phases.phase("auth"):
+            pass
+    finally:
+        phases.reset_phase_clock(token)
+    written = clock.marks["written"]
+    assert started + 0.002 <= written <= ended
+    assert set(clock.marks) == {"written"}
+    # the bucket saw the same end: it holds at most start -> mark (+ the
+    # second, empty block)
+    assert clock.phases["serialize"] <= written - started + 1e-4
+
+
+class _Ring:
+    """What a clock is tied to: ``StepTimeline.stamp``'s signature."""
+
+    def __init__(self):
+        self.stamps = []
+
+    def stamp(self, phase, request_id, slot, t=None):
+        self.stamps.append((phase, request_id, slot, t))
+        return t
+
+
+def test_tie_hands_over_the_marks_taken_before_the_id_with_their_own_times():
+    clock, ring = phases.PhaseClock(), _Ring()
+    clock.mark("recv", 1.0)
+    clock.mark("authed", 2.0)
+    assert ring.stamps == []                # never tied: no stamp, as a shed
+    clock.tie(ring.stamp, "q7")
+    assert ring.stamps == [("recv", "q7", -1, 1.0), ("authed", "q7", -1, 2.0)]
+    clock.mark("chunk", 3.0)                # from here on straight to the ring
+    clock.mark("chunk", 4.0)                # the first is kept, and sent once
+    assert ring.stamps[2:] == [("chunk", "q7", -1, 3.0)]
+    other = _Ring()
+    clock.tie(other.stamp, "q8")            # one request, one id
+    clock.mark("written", 5.0)
+    assert other.stamps == [] and ring.stamps[-1] == ("written", "q7", -1, 5.0)
 
 
 # ---------------------------------------------------------- FlightRecorder
@@ -236,6 +303,91 @@ def test_loop_lag_quiet_loop_stays_quiet(caplog):
     assert sampler.long_callbacks == 0
     assert not [r for r in caplog.records if "event loop lagged" in
                 r.message]
+
+
+def _blocked_tick(interval_s=0.01, block_s=0.03, lead_s=0.003):
+    """Run a sampler beside a live step timeline and block the loop for
+    ``block_s`` from ``lead_s`` before a tick is due. Returns the sampler,
+    the timeline, the block's (start, end) and when the tick before it ran;
+    None where the loop was too late to set that up (a loaded machine)."""
+    from mcp_context_forge_tpu.observability.timeline import StepTimeline
+
+    async def main():
+        timeline = StepTimeline("lag-test")
+        sampler = LoopLagSampler(interval_s=interval_s, warn_s=0.0)
+        ticks = []
+        observe = sampler._observe
+
+        def observed(lag, ran):
+            ticks.append(ran)
+            observe(lag, ran)
+
+        sampler._observe = observed
+        await sampler.start()
+        try:
+            seen = len(ticks)
+            while len(ticks) == seen:           # a tick has just run
+                await asyncio.sleep(0)
+            await asyncio.sleep(interval_s - lead_s)
+            start = time.perf_counter()
+            if start > ticks[-1] + interval_s or len(ticks) != seen + 1:
+                return None
+            time.sleep(block_s)                 # the bug class: a blocked loop
+            end = time.perf_counter()
+            await asyncio.sleep(interval_s)     # the late tick lands
+        finally:
+            await sampler.stop()
+        return sampler, timeline, (start, end), ticks[seen]
+
+    return asyncio.run(main())
+
+
+def test_a_blocked_loop_leaves_one_loop_lag_pause_that_covers_the_block():
+    """30 ms of ``time.sleep`` in a callback, begun 3 ms before a tick was
+    due: one ``loop_lag`` pause of 25-40 ms on the live ring, from when the
+    tick was due (inside the block's first milliseconds) to when it ran
+    (after the block), on ``perf_counter``, with the loop's thread."""
+    import threading
+
+    for _attempt in range(5):
+        ran = _blocked_tick()
+        if ran is not None and ran[2][1] - ran[2][0] < 0.036:
+            break
+    else:
+        pytest.skip("the loop never woke in time to place the block")
+    sampler, timeline, (start, end), tick = ran
+    lags = [p for p in timeline.snapshot()["pause"]
+            if p.cause == "loop_lag" and p.t1 > start and p.t0 < end]
+    assert len(lags) == 1
+    lag = lags[0]
+    assert 0.025 <= lag.t1 - lag.t0 <= 0.040
+    assert lag.t0 == pytest.approx(tick + 0.01, abs=0.002)      # when due
+    assert start <= lag.t0 <= start + 0.005 and lag.t1 >= end   # covers it
+    assert lag.detail == 0 and lag.thread == threading.current_thread().name
+    assert timeline.pauses_between(start, end) == [lag]
+    assert sampler.max_lag_s == pytest.approx(lag.t1 - lag.t0, abs=1e-9)
+
+
+def test_a_quiet_loop_and_a_process_without_rings_leave_no_pause(monkeypatch):
+    """Below the ring's floor nothing is written; and where no step timeline
+    module was ever imported (a gateway without an engine) the sampler
+    imports none and counts as before."""
+    import sys
+
+    from mcp_context_forge_tpu.observability import timeline as tl
+
+    ring = tl.StepTimeline("lag-quiet")
+    sampler = LoopLagSampler()
+    sampler._observe(tl.PAUSE_S * 0.9, 50.0)
+    assert ring.snapshot()["pause"] == []
+    sampler._observe(tl.PAUSE_S, 50.0)
+    assert [(p.cause, p.t0, p.t1) for p in ring.snapshot()["pause"]] == [
+        ("loop_lag", 50.0 - tl.PAUSE_S, 50.0)]
+    monkeypatch.delitem(sys.modules, tl.__name__)
+    sampler._observe(0.5, 60.0)
+    assert tl.__name__ not in sys.modules
+    assert len(ring.snapshot()["pause"]) == 1
+    assert sampler.samples == 3 and sampler.max_lag_s == 0.5
 
 
 # ------------------------------------------------------------ backpressure
