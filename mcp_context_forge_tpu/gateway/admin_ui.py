@@ -205,7 +205,8 @@ async function renderEngine(stats){
                  "tier_hits_disk","tier_hits_object",
                  "tier_hit_tokens_spilled",
                  "spec_steps","spec_tokens",
-                 "overlap_steps","pipeline_drains","dispatch_gap_ms_total",
+                 "overlap_steps","pipeline_drains","first_flushes",
+                 "dispatch_gap_ms_total",
                  "prefill_ms_total","decode_ms_total","engine_restarts"];
   const cards = order.filter(k => k in stats).map(k =>
     `<div class="card"><b>${cell(stats[k])}</b><span>${k}</span></div>`).join("");

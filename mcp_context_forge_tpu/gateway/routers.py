@@ -919,6 +919,11 @@ document.getElementById("f").onsubmit = async (e) => {
             # the collector's pauses of the whole process by generation
             "dispatch_stalls": stats.dispatch_stalls,
             "gc": engine.timeline.gc_stats(),
+            # the way out: barriers that retired the step in flight, and
+            # flushes made early for first tokens alone (one a prefill or
+            # chunk round that emitted, ahead of the iteration's decode)
+            "pipeline_drains": stats.pipeline_drains,
+            "first_flushes": stats.first_flushes,
         })
 
     @routes.get("/admin/slo")

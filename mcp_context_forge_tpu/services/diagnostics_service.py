@@ -91,6 +91,9 @@ def engine_introspection(engine: Any, limit: int = 64) -> dict[str, Any]:
         # pipeline exists to hide
         "overlap_steps": stats.overlap_steps,
         "pipeline_drains": stats.pipeline_drains,
+        # flushes made early for first tokens alone, ahead of the
+        # iteration's decode or verify dispatch
+        "first_flushes": stats.first_flushes,
         "dispatch_gap_ms_total": round(stats.dispatch_gap_ms_total, 3),
         "device_idle_fraction": round(engine.device_idle_fraction(), 4),
         # step attribution + live roofline + compile tracking

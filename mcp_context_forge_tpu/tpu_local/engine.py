@@ -358,6 +358,9 @@ class EngineStats:
         self.chunking = 0             # long prompts mid-chunk-prefill
         self.overlap_steps = 0        # decode dispatches fed from device tokens
         self.pipeline_drains = 0      # overlap barriers that forced a drain
+        # flushes made EARLY, for first tokens alone: after a prefill or a
+        # chunk round, ahead of the iteration's decode or verify dispatch
+        self.first_flushes = 0
         self.dispatch_gap_ms_total = 0.0  # host-side stall between dispatches
         # host-fed dispatches whose build.t0 -> dispatch.t1 passed
         # timeline.STALL_S (each also logged, with the part that held it)
@@ -637,6 +640,9 @@ class TPUEngine:
         # step emission buffer: tokens accumulate here during a step and
         # flush to the asyncio loop in ONE call_soon_threadsafe per step
         self._emit_buf: list[list[Any]] = []  # lint: thread[dispatch]
+        # the buffer holds a request's first token (an entry with tokens
+        # whose request nothing has been flushed of yet)
+        self._emit_first = False  # lint: thread[dispatch]
         # dispatch-gap telemetry: (gap_s, step_wall_s) per decode step
         self._gap_window: deque[tuple[float, float]] = deque(maxlen=256)  # lint: thread[dispatch]
         # liveness heartbeat: bumped once per dispatch-loop iteration (the
@@ -1910,8 +1916,10 @@ class TPUEngine:
 
     def _device_loop(self) -> None:  # lint: runs-on[dispatch]  # lint: hot-path
         """Owns every jax call + device sync. Never touched by the asyncio
-        loop; results hop back via loop.call_soon_threadsafe (one flush
-        per step, not one wakeup per token).
+        loop; results hop back via loop.call_soon_threadsafe: one flush per
+        iteration, not one wakeup per token, and one more, early, where a
+        prefill or a chunk round made a request's first token — it leaves
+        before the iteration's decode or verify dispatch is built.
 
         With ``decode_overlap`` the decode phase runs a depth-2 pipeline:
         one decode step is always in flight, fed by the previous step's
@@ -1980,8 +1988,10 @@ class TPUEngine:
                         did_work = True
                     if can_admit:
                         did_work = self._admit_batch() or did_work
+                        self._flush_emits(first_only=True)
                     if self._chunking:
                         self._chunk_round()
+                        self._flush_emits(first_only=True)
                         did_work = True
                     if self._running:
                         if (self._verify_fns is not None
@@ -3943,20 +3953,45 @@ class TPUEngine:
         not one per token (the old per-token wakeups were measurable
         scheduler pressure at superstep/spec widths > 1)."""
         buf = self._emit_buf
+        if tokens and request.t_emit == 0.0:
+            self._emit_first = True
         if buf and buf[-1][0] is request and not buf[-1][2]:
             buf[-1][1].extend(tokens)
             buf[-1][2] = done
         else:
             buf.append([request, list(tokens), done])
 
-    def _flush_emits(self) -> None:
-        """Deliver everything buffered by _post_tokens in one loop hop.
+    def _flush_emits(self, first_only: bool = False) -> None:
+        """Deliver what _post_tokens buffered in one loop hop, entries that
+        hold a request's FIRST token (nothing of the request has been
+        flushed yet) ahead of the rest: a stream's order is a property of
+        its own request, a first token has nothing of its request ahead of
+        it, and its handler then does not queue on the loop behind the live
+        streams' wake-ups. The split is stable, so every request's tokens
+        keep their order and its ``done`` stays behind them.
+
         Called once per dispatch-loop iteration and at the end of every
-        termination path (fail/crash/stop), so no consumer can strand on
-        an unflushed buffer."""
-        if not self._emit_buf:
+        termination path (fail/crash/stop), so no consumer can strand on an
+        unflushed buffer. ``first_only`` is the early flush where a first
+        token is made (after a prefill, after a chunk round): those entries
+        alone leave, before the iteration's decode or verify dispatch is
+        built; the rest keeps its place and leaves with the iteration's
+        flush; with no first token in the buffer it does nothing."""
+        if not self._emit_buf or (first_only and not self._emit_first):
             return
         batch, self._emit_buf = self._emit_buf, []
+        first: list[list[Any]] = []
+        if self._emit_first:
+            self._emit_first = False
+            rest: list[list[Any]] = []
+            for entry in batch:
+                fresh = entry[1] and entry[0].t_emit == 0.0
+                (first if fresh else rest).append(entry)
+            if first_only:
+                batch, self._emit_buf = first, rest
+                self.stats.first_flushes += 1
+            else:
+                batch = first + rest
         loop = self._loop
         stamp = self.timeline.stamp
 
@@ -3970,12 +4005,13 @@ class TPUEngine:
                 if done:
                     request.stream.put_nowait(None)
 
-        with self.timeline.span("loop.flush"):
+        with self.timeline.span("loop.flush",
+                                kind="first" if first_only else ""):
             # the first token's way out, split at the hop: t_first -> t_emit
-            # is the dispatch thread finishing its step before it flushes,
+            # is the dispatch thread between sampling it and this flush,
             # t_emit -> t_deliver the loop's latency for the callback
-            for request, tokens, _done in batch:
-                if tokens and request.t_emit == 0.0:
+            for request, _tokens, _done in first:
+                if request.t_emit == 0.0:   # once, of two entries too
                     request.t_emit = stamp("emit", request.request_id,
                                            request.slot)
             if loop is not None and not loop.is_closed():

@@ -155,6 +155,7 @@ class EngineReplica:
             "completion_tokens": stats.completion_tokens,
             "decode_steps": stats.decode_steps,
             "engine_restarts": stats.engine_restarts,
+            "first_flushes": stats.first_flushes,
             "routed": self.routed,
             "requeued_off": self.requeued_off,
             "migrations_out": self.migrations_out,

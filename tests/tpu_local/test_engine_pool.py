@@ -260,6 +260,11 @@ def test_kill_one_replica_mid_decode_loses_nothing():
         for card in status["replicas"]:
             assert {"warmup", "serving"} <= set(card["xla_compiles"])
             assert "cost_entries" in card["roofline"]
+            # and, beside the engine's other counts, the early flushes of
+            # first tokens (every request here had its first from a prefill)
+            assert card["first_flushes"] == \
+                pool.replicas[int(card["id"])].engine.stats.first_flushes
+        assert sum(card["first_flushes"] for card in status["replicas"]) >= 1
 
     asyncio.run(main())
 

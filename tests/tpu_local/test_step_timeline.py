@@ -483,6 +483,14 @@ def test_loop_spans_and_drain_barrier(overlapped):
     _engine, _done, ring = overlapped
     names = {s.name for s in ring["span"]}
     assert {"loop.wait", "loop.flush", "loop.drain", "admit"} <= names
+    # an iteration's flush has no kind; the early one of first tokens alone,
+    # once a prefill or chunk round that emitted, is of kind "first"
+    flushes = [s.kind for s in ring["span"] if s.name == "loop.flush"]
+    assert set(flushes) == {"", "first"}
+    assert flushes.count("first") == _engine.stats.first_flushes == sum(
+        1 for s in ring["span"] if s.name == "prefill.emit"
+        and any(e.phase == "first" and s.t0 <= e.t <= s.t1
+                for e in ring["req"]))
     # a drain barrier retires the in-flight step: its read-back and emit
     # nest inside the loop.drain span of that step
     drains = [s for s in ring["span"] if s.name == "loop.drain"]
@@ -507,6 +515,12 @@ def test_request_stamps_are_ordered_on_every_path(path, request):
         # ``first``, once: the dispatch thread's flush (``emit``) and the
         # hop to the loop's thread lie between
         assert finished.t_first <= finished.t_emit <= finished.t_deliver
+        # and it leaves when it is made: the dispatch thread builds no
+        # dispatch (the iteration's decode or verify step, a chunk round)
+        # between sampling a first token and handing it to the loop
+        assert not [s for s in ring["span"]
+                    if s.name in ("prefill.build", "decode.build")
+                    and finished.t_first <= s.t0 <= finished.t_emit]
         assert stamps[finished.request_id] == {
             "submit": finished.t_submit, "admit": finished.t_admit,
             "first": finished.t_first, "emit": finished.t_emit,
@@ -665,8 +679,9 @@ def test_ring_holds_two_minutes_of_the_busiest_cell(overlapped):
         return max(counts)
 
     decode = events_of(("decode", "decode_fb"))
-    prefill = events_of(("prefill", "prefill_hist", "chunk")) + 1   # + admit
-    assert 12 <= decode <= 14 and 11 <= prefill <= 13, (decode, prefill)
+    # + admit + the early flush of its first tokens
+    prefill = events_of(("prefill", "prefill_hist", "chunk")) + 2
+    assert 12 <= decode <= 14 and 12 <= prefill <= 14, (decode, prefill)
     stamps = len({e.phase for e in ring["req"]})
     assert stamps == 6
     # behind a gateway a request leaves six more (recv .. tokenized, chunk,

@@ -3,6 +3,7 @@ barriers (admission / EOS / crash mid-pipeline), dirty block-table sync,
 batched emission, and the event-driven idle wait."""
 
 import asyncio
+import functools
 import threading
 
 import jax
@@ -307,12 +308,9 @@ def test_engine_skips_table_upload_when_clean():
 
 # --------------------------------------------------------- batched emission
 
-def test_one_loop_wakeup_per_step():
-    """_post_tokens buffers and _flush_emits posts once per dispatch-loop
-    iteration: a superstep=4 generation must produce far fewer
-    call_soon_threadsafe hops than tokens."""
-    engine = TPUEngine(_config(superstep=4, decode_overlap=False,
-                               max_batch=2))
+def _count_wakeups(engine, ids, max_tokens):
+    """Generate through ``engine``; returns the tokens and how many times
+    the dispatch thread woke the loop (``call_soon_threadsafe`` calls)."""
     counted = {"n": 0}
 
     async def main():
@@ -325,16 +323,198 @@ def test_one_loop_wakeup_per_step():
 
         loop.call_soon_threadsafe = counting
         try:
-            ids = engine.tokenizer.encode("count wakeups")
-            return [t async for t in engine.generate(ids, max_tokens=16)]
+            return [t async for t in engine.generate(ids,
+                                                     max_tokens=max_tokens)]
         finally:
             loop.call_soon_threadsafe = real
 
-    out = _run(engine, main())
+    return _run(engine, main()), counted["n"]
+
+
+def test_one_loop_wakeup_per_step():
+    """_post_tokens buffers and _flush_emits posts once per dispatch-loop
+    iteration: a superstep=4 generation must produce far fewer
+    call_soon_threadsafe hops than tokens."""
+    engine = TPUEngine(_config(superstep=4, decode_overlap=False,
+                               max_batch=2))
+    out, wakeups = _count_wakeups(
+        engine, engine.tokenizer.encode("count wakeups"), 16)
     assert len(out) >= 8
     # old behavior: one hop per token (>= len(out)); new: one per step
     # (prefill + ~len/4 decode blocks + slack for the done sentinel)
-    assert counted["n"] <= len(out) // 2 + 4, counted["n"]
+    assert wakeups <= len(out) // 2 + 4, wakeups
+
+
+# a first token leaves when it is made: the early flush after a prefill (or a
+# chunk round), and first tokens first inside any flush
+
+_PATHS = {"overlapped": dict(decode_overlap=True),
+          "serial": dict(decode_overlap=False),
+          "spec": dict(spec_decode=True, spec_k=4)}
+
+
+def _late_arrival(engine, early_tokens=40, late_tokens=6):
+    """A second request admitted while the first decodes; returns both
+    (finished) and their token streams."""
+    early = GenRequest(request_id="early", prompt_ids=[7, 8, 9, 7, 8, 9, 7, 8],
+                       max_tokens=early_tokens)
+    late = GenRequest(request_id="late", prompt_ids=list(range(60, 72)),
+                      max_tokens=late_tokens)
+
+    async def main():
+        streams = {early.request_id: [], late.request_id: []}
+        await engine.submit(early)
+        for _ in range(3):      # the first stream is decoding
+            streams["early"].append(await early.stream.get())
+        await engine.submit(late)
+        for request in (early, late):
+            while (token := await request.stream.get()) is not None:
+                streams[request.request_id].append(token)
+        return streams
+
+    return early, late, _run(engine, main())
+
+
+@functools.lru_cache(maxsize=None)
+def _served(path):
+    """The late arrival on ``path``, served as built (True) and with the
+    early flush switched off (False: one flush an iteration, the parent's
+    rule), seeded alike: ``{early_flush: (engine, early, late, streams)}``."""
+    out = {}
+    for early_flush in (False, True):
+        engine = TPUEngine(_config(max_batch=2, **_PATHS[path]))
+        engine._rng = jax.random.PRNGKey(99)
+        if not early_flush:
+            flush = engine._flush_emits
+            engine._flush_emits = \
+                lambda first_only=False: None if first_only else flush()
+        out[early_flush] = (engine, *_late_arrival(engine))
+    return out
+
+
+@pytest.mark.parametrize("path", sorted(_PATHS))
+def test_first_token_is_flushed_before_the_iterations_decode_dispatch(path):
+    """The late request's prefill and the running row's next decode (or
+    verify) dispatch share one iteration of the dispatch loop: the first
+    token is handed to the loop (``emit``) before that dispatch is built,
+    by a flush of its own that the ring tells from the iteration's; under
+    the parent's rule it waited for the dispatch."""
+    for early_flush, (engine, early, late, _streams) in _served(path).items():
+        ring = engine.timeline.snapshot()
+        prefill = [s for s in ring["step"] if s.kind == "prefill"][-1]
+        after = next(s for s in ring["step"] if s.seq == prefill.seq + 1)
+        assert after.kind == ("spec" if path == "spec" else "decode")
+        assert early.t_done > after.t_retired     # the running row rode it
+        build = next(s for s in ring["span"]
+                     if s.name == "decode.build" and s.step == after.seq)
+        assert prefill.t_retired <= late.t_first <= late.t_emit
+        flushes = [s for s in ring["span"] if s.name == "loop.flush"]
+        first = [s for s in flushes if s.kind == "first"]
+        if not early_flush:
+            assert late.t_emit > build.t1 and not first
+            continue
+        assert late.t_emit <= build.t0
+        assert len(first) == 2 and len(flushes) > len(first)
+        assert first[-1].t0 <= late.t_emit <= first[-1].t1 <= build.t0
+
+
+@pytest.mark.parametrize("path", sorted(_PATHS))
+def test_streams_are_the_parents_with_and_without_the_early_flush(path):
+    """Byte-identical streams with the early flush and without it."""
+    served = _served(path)
+    for early_flush, (engine, _early, _late, _streams) in served.items():
+        assert engine.allocator.pages_in_use == 0
+        assert (engine.stats.first_flushes > 0) == early_flush
+    streams = served[True][3]
+    assert streams == served[False][3]
+    assert len(streams["late"]) >= 1 and len(streams["early"]) >= 4
+
+
+class _Recorder:
+    """A request's stream that writes every put to one shared list."""
+
+    def __init__(self, name, order):
+        self.name, self.order = name, order
+
+    def put_nowait(self, token):
+        self.order.append((self.name, token))
+
+
+def _buffered(engine):
+    """An emit buffer as a step leaves it: two live rows' tokens, a rejected
+    request's sentinel, and a fresh request's first tokens among them."""
+    order = []
+    requests = {name: GenRequest(request_id=name, prompt_ids=[1])
+                for name in ("a", "b", "rejected", "fresh")}
+    for name, request in requests.items():
+        request.stream = _Recorder(name, order)
+    requests["a"].t_emit = requests["b"].t_emit = 1.0   # flushed before
+    for name, tokens, done in (("a", [1, 2], False), ("rejected", [], True),
+                               ("b", [3], True), ("fresh", [9], False),
+                               ("a", [4], False), ("fresh", [10], True)):
+        engine._post_tokens(requests[name], tokens, done)
+    return requests, order
+
+
+_FRESH = [("fresh", 9), ("fresh", 10), ("fresh", None)]
+_REST = [("a", 1), ("a", 2), ("rejected", None), ("b", 3), ("b", None),
+         ("a", 4)]
+
+
+@pytest.mark.parametrize("early", [False, True])
+def test_first_tokens_are_put_first_and_every_stream_keeps_its_order(early):
+    """One flush that holds live rows' tokens and a first token: the first
+    token's ``put_nowait`` comes first, the rest in buffer order, every
+    request's own tokens in order and its ``None`` last. The early flush
+    takes the first tokens alone and leaves the rest where it was."""
+    engine = TPUEngine(_config())       # never started: _put runs in place
+    requests, order = _buffered(engine)
+    if early:
+        engine._flush_emits(first_only=True)
+        assert order == _FRESH and engine.stats.first_flushes == 1
+        assert [(r.request_id, t, d) for r, t, d in engine._emit_buf] == [
+            ("a", [1, 2], False), ("rejected", [], True), ("b", [3], True),
+            ("a", [4], False)]
+        # nothing fresh is left: a second early flush does not wake the loop
+        engine._flush_emits(first_only=True)
+        assert order == _FRESH and engine.stats.first_flushes == 1
+    engine._flush_emits()
+    assert order == _FRESH + _REST
+    assert engine._emit_buf == [] and not engine._emit_first
+    assert engine.stats.first_flushes == int(early)
+    assert requests["fresh"].t_emit > 0.0 == requests["rejected"].t_emit
+    kinds = [s.kind for s in engine.timeline.snapshot()["span"]
+             if s.name == "loop.flush"]
+    assert kinds == (["first", ""] if early else [""])
+
+
+def test_first_flushes_counts_admissions_that_made_a_token_only():
+    """One early flush per prefill that emitted (two requests admitted in
+    one prefill share it), none for a decode step, none for an admission
+    that only rejected."""
+    engine = TPUEngine(_config(decode_overlap=False, prefill_buckets=(64,)))
+    prompts = [list(range(10, 44)), list(range(50, 90))]   # one bucket, no half
+    outs = _gen_preloaded(engine, prompts, max_tokens=6)
+    assert all(len(out) >= 1 for out in outs)
+    assert engine.stats.prefill_batches == 1
+    assert engine.stats.first_flushes == 1
+    assert engine.stats.decode_dispatches >= 2
+    oversized = GenRequest(request_id="big", prompt_ids=list(range(500)),
+                           max_tokens=4)
+
+    async def main():
+        await engine.submit(oversized)
+        return await asyncio.wait_for(oversized.stream.get(), 60)
+
+    assert _run(engine, main()) is None and oversized.finish_reason == "length"
+    assert engine.stats.first_flushes == 1
+    out, wakeups = _count_wakeups(engine, prompts[0], 8)
+    assert engine.stats.first_flushes == 2
+    # an idle engine's first token is all its buffer holds: the early flush
+    # carries it and the iteration's own flush has the first decode's token
+    # alone, so a request costs at most one wake-up more than its steps
+    steps = 1 + len(out) - 1            # the prefill, a decode a token after
+    assert wakeups <= steps + 2, (wakeups, len(out))
 
 
 def test_submit_wakes_idle_dispatch_thread():

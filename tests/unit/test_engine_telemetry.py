@@ -309,6 +309,10 @@ async def test_gateway_slo_and_step_attribution_surfaces():
         resp = await gateway.get("/admin/engine/stats", auth=auth)
         stats = await resp.json()
         assert stats["dispatch_stalls"] == intro["dispatch_stalls"]
+        # the way out's counts, in both views: one chat served, whose first
+        # token left by a flush of its own
+        assert stats["first_flushes"] == intro["first_flushes"] == 1
+        assert stats["pipeline_drains"] == intro["pipeline_drains"]
         assert set(stats["gc"]) == {"gen0", "gen1", "gen2"}
         assert stats["gc"]["gen0"]["collections"] >= 1
         assert set(stats["gc"]["gen2"]) == {"collections", "total_ms",
